@@ -335,6 +335,7 @@ def _layer_step(
     stacked: Optional[Dict[str, Any]] = None,  # quantized weights kept whole
     dense_attn_fn=None,           # (q, k, v dense chunk) → attn; see below
     emit_hidden: bool = False,    # scan-emit this layer's hidden (features)
+    pallas: bool = True,          # False: projections stay on the XLA path
 ) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array], Optional[jax.Array]]:
     """One transformer layer over paged KV — shared by the causal decode path
     and the speculative tree-verify path (they differ only in the attention
@@ -374,8 +375,8 @@ def _layer_step(
 
     def proj(x_, name):
         if stacked is not None and name in stacked:
-            return matmul_stacked(x_, stacked[name], layer_idx)
-        return qmm(x_, lp[name])
+            return matmul_stacked(x_, stacked[name], layer_idx, pallas)
+        return qmm(x_, lp[name], pallas)
 
     x = rms_norm(hidden, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     q = proj(x, "wq")
@@ -507,14 +508,17 @@ def forward_chunk(
                           # these layers' post-layer hiddens (EAGLE-3 draft
                           # features) — costs L x hidden activation memory,
                           # request only on small spec/distill shapes
-    allow_fused: bool = True,
-                          # gate for the fused Pallas decode path: an
-                          # engine serving over a GSPMD mesh must pass
-                          # False — a pallas_call has no partitioning
-                          # rules, and the kernel's in-VMEM per-token
-                          # quantize amax (int8 pools) would reduce over
-                          # LOCAL heads only, breaking the all-reduce-max
-                          # scale contract (parallel/sharding.py)
+    pallas: bool = True,
+                          # gate for EVERY Pallas kernel in the graph (the
+                          # fused decode kernel, the ragged kernel, the int8
+                          # matmul): an engine serving over a GSPMD mesh
+                          # must pass False — a pallas_call has no
+                          # partitioning rule (XLA refuses the graph), and
+                          # the fused kernel's in-VMEM per-token quantize
+                          # amax (int8 pools) would reduce over LOCAL heads
+                          # only, breaking the all-reduce-max scale contract
+                          # (parallel/sharding.py). Dispatch cannot see the
+                          # mesh from inside a trace, so the caller says it.
 ) -> ChunkOutput:
     """Run S tokens per sequence through all layers against the paged cache.
 
@@ -546,7 +550,8 @@ def forward_chunk(
         def attn_fn(q, layer_k, layer_v, layer_ks=None, layer_vs=None):
             return paged_attention(
                 q, layer_k, layer_v, block_tables, positions, kv_lens,
-                block_size, window=cfg.sliding_window,
+                block_size, impl="auto" if pallas else "xla",
+                window=cfg.sliding_window,
                 k_scale=layer_ks, v_scale=layer_vs,
             )
 
@@ -561,7 +566,7 @@ def forward_chunk(
         sin=sin,
         attn_fn=attn_fn,
         fused_decode=(
-            allow_fused
+            pallas
             and _use_fused_decode(cfg, s, block_tables, block_size)
             and dense_attn_fn is None
             and attn_override is None
@@ -570,6 +575,7 @@ def forward_chunk(
         stacked=stacked,
         dense_attn_fn=dense_attn_fn,
         emit_hidden=collect_layers is not None,
+        pallas=pallas,
     )
     k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
     v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]

@@ -19,6 +19,7 @@ Set ``TPU_NATIVE=0`` to force the Python fallbacks.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -93,15 +94,20 @@ def _load_locked() -> Optional[ctypes.CDLL]:
     if os.environ.get("TPU_NATIVE", "1") == "0":
         return None
     src = _SRC_DIR / "radix_index.cpp"
-    out = _BUILD_DIR / "libtpu_native.so"
-    # a prebuilt .so without sources (shipped wheel) must load as-is
-    stale = src.exists() and (
-        not out.exists() or out.stat().st_mtime < src.stat().st_mtime
-    )
-    if stale and not _compile(src, out):
-        return None
-    if not out.exists():
-        return None
+    if src.exists():
+        # the library is named by the hash of the source it was built
+        # from: a build left behind by another checkout (the directory is
+        # git-ignored, and tools that copy the tree copy it along with
+        # mtimes of their own) can never be loaded in place of this source
+        digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+        out = _BUILD_DIR / f"libtpu_native-{digest}.so"
+        if not out.exists() and not _compile(src, out):
+            return None
+    else:
+        # a prebuilt .so without sources (shipped wheel) must load as-is
+        out = _BUILD_DIR / "libtpu_native.so"
+        if not out.exists():
+            return None
     try:
         lib = ctypes.CDLL(str(out))
     except OSError as exc:

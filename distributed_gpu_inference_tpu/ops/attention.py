@@ -21,7 +21,8 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+import re
+from typing import Optional, Set
 
 import jax
 import jax.numpy as jnp
@@ -29,13 +30,24 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
-def _use_pallas() -> bool:
+def pallas_backend() -> bool:
+    """THE backend gate of kernel dispatch (attention here, the int8 matmul
+    in ``ops/quantization.py``): Pallas TPU kernels are eligible when the
+    default backend is a TPU. A backend that cannot be read raises — that
+    is an error, not a reason to serve from another path. What this gate
+    cannot see is partitioning: a caller whose operands are GSPMD-sharded
+    over a mesh must ask for the XLA path itself (``forward_chunk``'s
+    ``pallas=False``), because a ``pallas_call`` has no partitioning rule."""
     if os.environ.get("DGI_DISABLE_PALLAS"):
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def pallas_kernels(lowered) -> Set[str]:
+    """Names of the Pallas kernels a lowered program (``jit(f).lower(...)``)
+    calls — what dispatch resolved to, read from the program itself rather
+    than inferred from the backend."""
+    return set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
 
 
 # Measured model-level crossover on v5e (llama3-3b, batch 8, round 2): the
@@ -97,7 +109,7 @@ def resolve_impl(
     :func:`micro_read_xla_min_batch` falls back to the one-gather XLA path.
     """
     if backend_is_tpu is None:
-        backend_is_tpu = _use_pallas()
+        backend_is_tpu = pallas_backend()
     if (
         backend_is_tpu
         and head_dim % 128 == 0

@@ -55,15 +55,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Pallas-TPU API drift shims: older releases name the off-chip memory space
-# ANY (HBM arrived later) and the compiler-params dataclass TPUCompilerParams.
-# Semantics are identical for our usage (full-array HBM-resident operands the
-# kernels DMA page-wise), so alias rather than pin a jax version.
-_HBM = getattr(pltpu, "HBM", pltpu.ANY)
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 _NEG_INF = -1e30
 # VMEM budget for the four KV staging buffers (2 pools x 2 slots); the rest
 # of VMEM stays free for q/out blocks and compute temporaries.
@@ -121,7 +112,7 @@ def _decode_kernel(
     q_ref,         # [1, 1, Nh, D] — this sequence's query heads
     newk_ref,      # [B, Hkv, D] new K rows (VMEM; whole-batch block)
     newv_ref,      # [B, Hkv, D]
-    k_hbm,         # [L, N, Hkv, Bk, D] full stacked pool (ANY/HBM, aliased)
+    k_hbm,         # [L, N, Hkv, Bk, D] full stacked pool (HBM, aliased)
     v_hbm,         # [L, N, Hkv, Bk, D]
     *rest,         # [ks_hbm, vs_hbm,] out_ref, ko_hbm, vo_hbm, scratch...
     batch: int,
@@ -530,10 +521,10 @@ def _call_decode_kernel(
         ),
         pl.BlockSpec(memory_space=pltpu.VMEM),   # new_k (whole array)
         pl.BlockSpec(memory_space=pltpu.VMEM),   # new_v
-        # pools must STAY in HBM (ANY lets the compiler pull the whole
-        # pool into VMEM, where the padded lane dim breaks page slices)
-        pl.BlockSpec(memory_space=_HBM),
-        pl.BlockSpec(memory_space=_HBM),
+        # pools must STAY in HBM (left to the compiler, the whole pool can
+        # land in VMEM, where the padded lane dim breaks page slices)
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     scratch = [
         pltpu.VMEM((2, gp, hkv, block_size, d), k_pool.dtype),
@@ -545,17 +536,17 @@ def _call_decode_kernel(
             lambda i, j, *_refs: (i, 0, 0, 0),
             memory_space=pltpu.VMEM,
         ),
-        pl.BlockSpec(memory_space=_HBM),
-        pl.BlockSpec(memory_space=_HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     if quantized:
         in_specs += [
-            pl.BlockSpec(memory_space=_HBM),   # k_scale
-            pl.BlockSpec(memory_space=_HBM),   # v_scale
+            pl.BlockSpec(memory_space=pltpu.HBM),   # k_scale
+            pl.BlockSpec(memory_space=pltpu.HBM),   # v_scale
         ]
         out_specs += [
-            pl.BlockSpec(memory_space=_HBM),   # k_scale (aliased)
-            pl.BlockSpec(memory_space=_HBM),   # v_scale (aliased)
+            pl.BlockSpec(memory_space=pltpu.HBM),   # k_scale (aliased)
+            pl.BlockSpec(memory_space=pltpu.HBM),   # v_scale (aliased)
         ]
         scratch += [
             pltpu.VMEM((2, gp, block_size, d), jnp.bfloat16),    # ksbuf
@@ -631,7 +622,7 @@ def _call_decode_kernel(
         out_shape=out_shape,
         grid_spec=grid_spec,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -730,8 +721,9 @@ def _ragged_kernel(
     init_ref,      # [1] int32 1 until the first live chunk issues its DMA
     # blocked operands
     q_ref,         # [1, Hkv, qpk*T, D] — this row's query tile, GQA-grouped
-    pos_ref,       # [1, T] int32 per-query positions (-1 = pad)
-    k_hbm,         # [N, Hkv, Bk, D] single-layer pool (ANY/HBM)
+    pos_ref,       # [1, qpk*T, 1] int32 per-query positions (-1 = pad),
+                   # tiled over the GQA slots in q_ref's row order
+    k_hbm,         # [N, Hkv, Bk, D] single-layer pool (HBM)
     v_hbm,
     *rest,         # [ks_hbm, vs_hbm,] out_ref, kbuf, vbuf, [ksbuf, vsbuf,]
                    # sems, [ssems,] m_scr, l_scr, acc_scr
@@ -859,9 +851,9 @@ def _ragged_kernel(
 
         @pl.when(i == start_r)
         def _():
-            m_scr[...] = jnp.full((hkv, qpk * q_tile), _NEG_INF, jnp.float32)
-            l_scr[...] = jnp.zeros((hkv, qpk * q_tile), jnp.float32)
-            acc_scr[...] = jnp.zeros((hkv, qpk * q_tile, d), jnp.float32)
+            m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
         kv_len = lens_ref[r // q_tiles]
         # the dot runs in the pool dtype (bf16 in, f32 accumulation) — the
@@ -881,35 +873,33 @@ def _ragged_kernel(
             qf, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * scale                                   # [Hkv, qpk*T, gsz]
-        # per-query causal/in-length mask: split the flattened (qpk, T) row
-        # axis (minor dim untouched — layout-free reshape), broadcast the
-        # tile's position vector along it. THE per-row-group path selection:
-        # a decode row (q_len = 1) and a prefill chunk row differ only in
-        # this mask and in how many groups the walk gave them.
-        scores4 = scores.reshape(hkv, qpk, q_tile, gsz)
+        # per-query causal/in-length mask in the flattened [qpk*T, gsz]
+        # layout the scores already have: positions arrive pre-tiled per
+        # (GQA slot, query) as a [qpk*T, 1] column, so the mask is one lane
+        # broadcast — no reshape of the score tile (Mosaic has no shape
+        # cast that splits the sublane axis or inserts a minor dim). THE
+        # per-row path selection: a decode row (q_len = 1) and a prefill
+        # chunk row differ only in this mask and in how many groups the
+        # walk gave them.
         col = i * gsz + lax.broadcasted_iota(
-            jnp.int32, (hkv, qpk, q_tile, gsz), 3
+            jnp.int32, (1, qpk * q_tile, gsz), 2
         )
-        pos_b = pos_ref[0][None, None, :, None]     # [1, 1, T, 1]
+        pos_b = pos_ref[0][None]                    # [1, qpk*T, 1]
         valid = (col < kv_len) & (col <= pos_b)
         if window is not None:
             valid &= col > pos_b - window
-        scores4 = jnp.where(valid, scores4, _NEG_INF)
+        scores = jnp.where(valid, scores, _NEG_INF)
 
+        # softmax state keeps a size-1 minor dim ([Hkv, qpk*T, 1]) so every
+        # update below is a lane broadcast against the score tile
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(
-            m_prev, jnp.max(scores4, axis=-1).reshape(hkv, qpk * q_tile)
+            m_prev, jnp.max(scores, axis=-1, keepdims=True)
         )
         alpha = jnp.exp(m_prev - m_new)
-        probs4 = jnp.exp(
-            scores4 - m_new.reshape(hkv, qpk, q_tile)[..., None]
-        )
-        probs4 = jnp.where(valid, probs4, 0.0)
-        l_new = l_prev * alpha + jnp.sum(probs4, axis=-1).reshape(
-            hkv, qpk * q_tile
-        )
-        probs = probs4.reshape(hkv, qpk * q_tile, gsz)
-        acc_new = acc_scr[...] * alpha[..., None] + lax.dot_general(
+        probs = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
+        l_new = l_prev * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        acc_new = acc_scr[...] * alpha + lax.dot_general(
             probs.astype(cdt), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )                                           # [Hkv, qpk*T, D]
@@ -917,11 +907,11 @@ def _ragged_kernel(
 
         @pl.when(i == ng_r - 1)
         def _():
-            safe_l = jnp.where(l_new > 0, l_new, 1.0)[..., None]
-            out = jnp.where(safe_l > 0, acc_new / safe_l, 0.0)
             # fully-masked queries (padding inside a live tile) → exact 0,
             # the XLA-path contract
-            out = jnp.where(l_new[..., None] > 0, out, 0.0)
+            out = jnp.where(
+                l_new > 0, acc_new / jnp.where(l_new > 0, l_new, 1.0), 0.0
+            )
             out_ref[0] = out.astype(out_ref.dtype)
 
 
@@ -989,6 +979,9 @@ def ragged_paged_attention(
     qmax_r = jnp.max(pos_r, axis=1)
     qmin_r = jnp.min(jnp.where(pos_r >= 0, pos_r, jnp.int32(2**30)), axis=1)
     qmin_r = jnp.where(qmax_r >= 0, qmin_r, 0)
+    # one position per score-tile row: q_r's row index is slot * T + query,
+    # so the tile's T positions repeat once per GQA slot
+    pos_q = jnp.tile(pos_r, (1, qpk))[:, :, None]
 
     scale_page_bytes = block_size * d * 2 if quantized else 0
     gp = _pages_per_group(
@@ -1004,15 +997,16 @@ def ragged_paged_attention(
             memory_space=pltpu.VMEM,
         ),
         pl.BlockSpec(
-            (1, t), lambda i, j, *_refs: (i, 0), memory_space=pltpu.VMEM,
+            (1, qpk * t, 1), lambda i, j, *_refs: (i, 0, 0),
+            memory_space=pltpu.VMEM,
         ),
-        pl.BlockSpec(memory_space=_HBM),   # k_pool
-        pl.BlockSpec(memory_space=_HBM),   # v_pool
+        pl.BlockSpec(memory_space=pltpu.HBM),   # k_pool
+        pl.BlockSpec(memory_space=pltpu.HBM),   # v_pool
     ]
     if quantized:
         in_specs += [
-            pl.BlockSpec(memory_space=_HBM),   # k_scale
-            pl.BlockSpec(memory_space=_HBM),   # v_scale
+            pl.BlockSpec(memory_space=pltpu.HBM),   # k_scale
+            pl.BlockSpec(memory_space=pltpu.HBM),   # v_scale
         ]
     out_specs = pl.BlockSpec(
         (1, hkv, qpk * t, d),
@@ -1032,8 +1026,8 @@ def ragged_paged_attention(
     if quantized:
         scratch += [pltpu.SemaphoreType.DMA((2, 2, gp))]         # ssems
     scratch += [
-        pltpu.VMEM((hkv, qpk * t), jnp.float32),                 # m
-        pltpu.VMEM((hkv, qpk * t), jnp.float32),                 # l
+        pltpu.VMEM((hkv, qpk * t, 1), jnp.float32),              # m
+        pltpu.VMEM((hkv, qpk * t, 1), jnp.float32),              # l
         pltpu.VMEM((hkv, qpk * t, d), jnp.float32),              # acc
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1066,7 +1060,7 @@ def ragged_paged_attention(
         qmax_r, qmin_r,
         jnp.zeros((1,), jnp.int32),   # buffer_index
         jnp.ones((1,), jnp.int32),    # init_flag
-        q_r, pos_r, k_pool, v_pool,
+        q_r, pos_q, k_pool, v_pool,
     ]
     if quantized:
         operands += [k_scale.astype(jnp.bfloat16),
@@ -1075,7 +1069,7 @@ def ragged_paged_attention(
         kernel,
         out_shape=jax.ShapeDtypeStruct((rows, hkv, qpk * t, d), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,

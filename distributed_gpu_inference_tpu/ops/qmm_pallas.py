@@ -37,13 +37,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed the compiler-params dataclass TPUCompilerParams →
-# CompilerParams across releases; resolve whichever this jax ships (same
-# shim as ops/paged_attention_pallas.py) so import/trace never AttributeErrors
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 # Tile menu. BN/BK must divide N/K exactly (no ragged K/N tiles: an
 # out-of-bounds K read would contract garbage into real outputs). The lane
 # dim of every block must be a multiple of 128.
@@ -128,7 +121,7 @@ def qmm_stacked_pallas(
         functools.partial(_qmm_kernel, num_k=num_k),
         out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # out blocks are revisited across the K walk (accumulator), so K
             # must be sequential; N tiles are independent
             dimension_semantics=("parallel", "arbitrary"),

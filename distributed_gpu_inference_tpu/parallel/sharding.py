@@ -126,7 +126,7 @@ def batch_shardings(mesh: Mesh) -> Dict[str, NamedSharding]:
     }
 
 
-def _quantized_leaf_rules(rule: NamedSharding, leaf: Dict[str, Any]) -> Dict[str, Any]:
+def quantized_leaf_rules(rule: NamedSharding, leaf: Dict[str, Any]) -> Dict[str, Any]:
     """Expand a weight's sharding rule over a quantized ``{"qw","scale"}``
     sub-dict: qw keeps the weight spec; the scale drops axis names wherever
     its (size-1, reduced) dims can't carry a shard."""
@@ -149,7 +149,7 @@ def prune_rules(rules: Dict[str, Any], params: Dict[str, Any]) -> Dict[str, Any]
     they cannot drift."""
     rules = dict(rules)
     rules["layers"] = {
-        k: (_quantized_leaf_rules(v, params["layers"][k])
+        k: (quantized_leaf_rules(v, params["layers"][k])
             if isinstance(params["layers"][k], dict) else v)
         for k, v in rules["layers"].items() if k in params["layers"]
     }

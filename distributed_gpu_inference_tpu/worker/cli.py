@@ -161,11 +161,19 @@ def cmd_setup(args: argparse.Namespace) -> int:
 def cmd_start(args: argparse.Namespace) -> int:
     import logging
 
+    from ..utils.device import enable_compile_cache, require_backend
     from .main import Worker
 
     logging.basicConfig(
         level=getattr(logging, args.log_level.upper(), logging.INFO),
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
+    )
+    # a worker serves from the chip or from a CPU it was told to use —
+    # never from the CPU JAX falls back to when it finds no chip
+    backend = require_backend()
+    cache_dir = enable_compile_cache()
+    logging.getLogger("tpu_worker").info(
+        "backend %s, compile cache %s", backend, cache_dir
     )
     cfg = load_worker_config(args.config, missing_ok=True)
     path = Path(args.config)

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import logging
 import random
+import re
 import signal
 import threading
 import time
@@ -36,21 +37,13 @@ import numpy as np
 
 from ..utils.config import WorkerConfig
 from ..utils.data_structures import TpuTopology, WorkerState
+from ..utils.device import ChipSpec, chip_spec
 from .api_client import APIClient, APIError
 from .engines import EngineLoadError, create_engine
 from .engines.base import JobMigrated
 from .machine_id import MachineFingerprint
 
 log = logging.getLogger("tpu_worker")
-
-
-# per-generation chip facts: HBM GB, per-link ICI GB/s, peak bf16 TFLOP/s
-_TPU_GEN = {
-    "v4":  (32.0, 300.0, 275.0),
-    "v5e": (16.0, 400.0, 197.0),
-    "v5p": (95.0, 600.0, 459.0),
-    "v6e": (32.0, 900.0, 918.0),
-}
 
 
 def probe_tpu_runtime() -> dict:
@@ -85,77 +78,73 @@ def probe_tpu_runtime() -> dict:
     }
 
 
-def _gen_from_string(s: str) -> str:
-    s = s.lower()
-    if "v5p" in s or "v5 pod" in s:
-        return "v5p"
-    if "v5lite" in s or "v5e" in s or "v5" in s:
-        return "v5e"
-    if "v6" in s:
-        return "v6e"
-    if "v4" in s:
-        return "v4"
-    return "v5e"
+def _chip_topology(spec: ChipSpec, num_chips: int,
+                   mesh_shape: tuple) -> TpuTopology:
+    return TpuTopology(
+        chip_type=spec.chip_type, num_chips=num_chips,
+        hbm_gb_per_chip=spec.hbm_gb, mesh_shape=mesh_shape,
+        mesh_axis_names=tuple(f"ici{i}" for i in range(len(mesh_shape)))
+        if len(mesh_shape) > 1 else ("data",),
+        ici_bandwidth_gbps=spec.ici_gbps,
+        peak_bf16_tflops=spec.peak_bf16_tflops,
+    )
 
 
 def probe_topology() -> TpuTopology:
     """Describe local accelerators (the TPU analogue of the reference's
-    nvidia-smi probe, ``cli.py:77``): libtpu/env runtime facts first
-    (``probe_tpu_runtime``), then jax device enumeration with physical
-    mesh-shape discovery from device coords. Falls back to a CPU topology
-    when no accelerator is reachable. The result rides in worker
-    registration (``Worker.register`` → ``topology``) so schedulers see
-    generation, chip count, HBM, and mesh shape (VERDICT r2 next #10)."""
+    nvidia-smi probe, ``cli.py:77``): jax device enumeration with physical
+    mesh-shape discovery from device coords, figures from the published
+    table keyed by ``device_kind`` (``utils/device.py``). The result rides
+    in worker registration (``Worker.register`` → ``topology``) so
+    schedulers see generation, chip count, HBM, and mesh shape.
+
+    Nothing here guesses. An accelerator the table does not know is an
+    error, not a v5e. A CPU backend — which JAX also falls into unasked
+    when it finds no chip — is reported as ``cpu`` with no device figures.
+    A backend that fails to start raises, unless the environment itself
+    declares a TPU host (libtpu + accelerator type): that host registers
+    as what the platform says it is, so a broken driver shows up as a TPU
+    worker needing repair."""
     runtime = probe_tpu_runtime()
+    import jax
+
     try:
-        import jax
-
         devices = jax.devices()
-        kind = devices[0].device_kind.lower()
-        is_tpu = any(t in kind for t in ("tpu", "v4", "v5", "v6"))
-        if is_tpu:
-            chip = _gen_from_string(runtime["accelerator_type"] or kind)
-            hbm, ici, tflops = _TPU_GEN[chip]
-            # physical mesh from device coords (bounding box of the slice);
-            # fall back to a flat axis when coords are unavailable
-            try:
-                coords = [d.coords for d in devices]
-                dims = tuple(
-                    max(c[i] for c in coords) - min(c[i] for c in coords) + 1
-                    for i in range(len(coords[0]))
-                )
-                dims = tuple(d for d in dims if d > 1) or (len(devices),)
-                if int(np.prod(dims)) != len(devices):
-                    dims = (len(devices),)
-            except Exception:
-                dims = (len(devices),)
-            return TpuTopology(
-                chip_type=chip, num_chips=len(devices), hbm_gb_per_chip=hbm,
-                mesh_shape=dims,
-                mesh_axis_names=tuple(f"ici{i}" for i in range(len(dims)))
-                if len(dims) > 1 else ("data",),
-                ici_bandwidth_gbps=ici, peak_bf16_tflops=tflops,
-            )
-        return TpuTopology(chip_type="cpu", num_chips=len(devices),
-                           hbm_gb_per_chip=4.0, ici_bandwidth_gbps=10.0,
-                           dcn_bandwidth_gbps=10.0, peak_bf16_tflops=0.2)
-    except Exception:
-        # no jax backend — if the runtime probe still smells TPU hardware,
-        # report what the environment declares instead of "cpu" (a worker
-        # with a broken driver should register as a TPU host needing repair)
-        if runtime["libtpu"] and runtime["accelerator_type"]:
-            chip = _gen_from_string(runtime["accelerator_type"])
-            hbm, ici, tflops = _TPU_GEN[chip]
-            import re as _re
-
-            m = _re.search(r"-(\d+)$", runtime["accelerator_type"])
-            chips = int(m.group(1)) if m else 1
-            return TpuTopology(
-                chip_type=chip, num_chips=chips, hbm_gb_per_chip=hbm,
-                mesh_shape=(chips,), ici_bandwidth_gbps=ici,
-                peak_bf16_tflops=tflops,
-            )
-        return TpuTopology(chip_type="cpu", num_chips=1, hbm_gb_per_chip=4.0)
+    except RuntimeError:
+        declared = runtime["accelerator_type"] if runtime["libtpu"] else ""
+        spec = chip_spec(declared)
+        if spec is None:
+            raise
+        m = re.search(r"-(\d+)$", declared)
+        chips = int(m.group(1)) if m else 1
+        return _chip_topology(spec, chips, (chips,))
+    kind = devices[0].device_kind
+    if kind.lower() == "cpu":
+        return TpuTopology(
+            chip_type="cpu", num_chips=len(devices), hbm_gb_per_chip=0.0,
+            ici_bandwidth_gbps=0.0, dcn_bandwidth_gbps=0.0,
+            peak_bf16_tflops=0.0,
+        )
+    spec = chip_spec(kind) or chip_spec(runtime["accelerator_type"])
+    if spec is None:
+        raise RuntimeError(
+            f"unknown accelerator {kind!r}: no published figures for it in "
+            "utils/device.py — add the chip there instead of borrowing "
+            "another chip's numbers"
+        )
+    # physical mesh from device coords (bounding box of the slice); a flat
+    # axis when the devices carry no coords or the box is not the slice
+    dims: tuple = (len(devices),)
+    coords = [getattr(d, "coords", None) for d in devices]
+    if all(c is not None for c in coords):
+        box = tuple(
+            max(c[i] for c in coords) - min(c[i] for c in coords) + 1
+            for i in range(len(coords[0]))
+        )
+        box = tuple(d for d in box if d > 1) or (len(devices),)
+        if int(np.prod(box)) == len(devices):
+            dims = box
+    return _chip_topology(spec, len(devices), dims)
 
 
 class _PDReceiverShim:
@@ -264,6 +253,12 @@ class Worker:
                 "machine_fingerprint": MachineFingerprint().get_or_create(),
                 "supported_types": list(self.config.task_types),
                 "topology": self.topology.to_dict(),
+                # the row's own columns (worker list, remote-config HBM
+                # caps) — the plane defaults them to one 16 GB chip
+                "chip_generation": self.topology.chip_type,
+                "num_chips": self.topology.num_chips,
+                "hbm_gb_per_chip": self.topology.hbm_gb_per_chip,
+                "mesh_shape": list(self.topology.mesh_shape),
                 "supports_direct": self.config.direct.enabled,
                 "direct_url": self.config.direct.public_url,
                 "role": self.config.role,

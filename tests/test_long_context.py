@@ -493,19 +493,8 @@ def test_streamed_handoff_many_pieces_at_long_context_block_counts():
 # --------------------------------------------------------------------- #
 
 
-def _pallas_tpu_usable() -> bool:
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return hasattr(pltpu, "VMEM")
-    except Exception:  # noqa: BLE001
-        return False
-
-
 @pytest.mark.slow
 @pytest.mark.ragged
-@pytest.mark.skipif(not _pallas_tpu_usable(),
-                    reason="pallas TPU memory-space API unavailable")
 def test_ragged_kernel_long_chunk_rows_split_across_q_tiles():
     """A long prefill chunk row splits host-side into multiple query
     tiles that all index ONE per-sequence block-table row (the round-17

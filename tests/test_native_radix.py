@@ -32,6 +32,24 @@ def test_factory_fallback_forced(monkeypatch):
 
 
 @needs_native
+def test_library_is_built_from_this_source(tmp_path, monkeypatch):
+    """The library is named by the hash of the source it was built from, so
+    a build left in the (git-ignored) directory by another checkout is
+    never loaded in its place."""
+    import hashlib
+
+    from distributed_gpu_inference_tpu import native
+
+    monkeypatch.setattr(native, "_BUILD_DIR", tmp_path)
+    (tmp_path / "libtpu_native.so").write_bytes(b"not this source")
+    lib = native._load_locked()
+    assert lib is not None and lib.radix_new(4)
+    src = native._SRC_DIR / "radix_index.cpp"
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    assert (tmp_path / f"libtpu_native-{digest}.so").exists()
+
+
+@needs_native
 def test_native_builds_and_loads():
     from distributed_gpu_inference_tpu.native.radix import (
         NativeRadixPrefixIndex,

@@ -23,24 +23,6 @@ from distributed_gpu_inference_tpu.ops.attention import (
 )
 
 
-def _pallas_tpu_usable() -> bool:
-    """Same build gap as test_spec_multiquery_attention: the kernel needs
-    the TPU pallas memory-space API even in interpret mode (HBM itself is
-    shimmed to ANY; only VMEM is a hard requirement)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return hasattr(pltpu, "VMEM")
-    except Exception:  # noqa: BLE001
-        return False
-
-
-needs_pallas = pytest.mark.skipif(
-    not _pallas_tpu_usable(),
-    reason="pallas TPU memory-space API unavailable in this jax build",
-)
-
-
 # --------------------------------------------------------------------- #
 # kernel level: ragged row batches vs the XLA oracle (interpret mode)
 # --------------------------------------------------------------------- #
@@ -94,7 +76,6 @@ def _compare(args, block, window=None, atol=2e-5):
     return got
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_decode_only_rows():
     # a ragged round with no admission in flight degenerates to the decode
@@ -103,7 +84,6 @@ def test_decode_only_rows():
                            nh=4, hkv=2, d=64, block=16, m=4), 16)
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_prefill_only_row():
     # one wide chunk row alone (multi-page context, multiple page groups)
@@ -111,7 +91,6 @@ def test_prefill_only_row():
                            nh=8, hkv=4, d=64, block=16, m=20), 16)
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_mixed_decode_verify_prefill_rows():
     # THE tentpole batch shape: decode rows, a spec verify row (q_len =
@@ -121,7 +100,6 @@ def test_mixed_decode_verify_prefill_rows():
                            nh=4, hkv=2, d=64, block=16, m=8), 16)
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_mid_prompt_chunk_row():
     # an admission's NON-final chunk: queries end mid-prompt (kv_len =
@@ -131,7 +109,6 @@ def test_mid_prompt_chunk_row():
                            nh=4, hkv=2, d=64, block=16, m=8), 16)
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_inactive_row_zero_output():
     args = _ragged_setup([(1, 12), (0, 0), (4, 20)],
@@ -140,7 +117,6 @@ def test_inactive_row_zero_output():
     assert np.all(np.asarray(got)[1] == 0.0)
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_padded_tail_queries_zero():
     # rows narrower than the batch width: their padded tail queries must
@@ -152,7 +128,6 @@ def test_padded_tail_queries_zero():
     assert np.all(got[2, 1:] == 0.0)
 
 
-@needs_pallas
 @pytest.mark.slow
 @pytest.mark.parametrize("window", [4, 16])
 def test_sliding_window_fences(window):
@@ -163,7 +138,6 @@ def test_sliding_window_fences(window):
              window=window)
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_q_tile_split():
     # span wider than the per-cell query tile (qpk=2 → T=32 at the default
@@ -173,7 +147,6 @@ def test_q_tile_split():
                            nh=4, hkv=2, d=64, block=16, m=8), 16)
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_int8_pool_mixed_rows():
     from distributed_gpu_inference_tpu.ops.attention import dequantize_kv
@@ -198,7 +171,6 @@ def test_int8_pool_mixed_rows():
                                rtol=2e-2, atol=2e-2)
 
 
-@needs_pallas
 @pytest.mark.slow
 def test_ragged_matches_multiquery_alias():
     # the pre-round-6 small-q entry point is now a thin alias — uniform

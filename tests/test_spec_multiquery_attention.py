@@ -7,27 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-def _pallas_tpu_usable() -> bool:
-    """The kernel surface needs the TPU pallas memory-space API; older/
-    newer jax builds that lack it fail at trace time even in interpret
-    mode (the same build gap test_qmm_pallas.py hits). The off-chip space
-    itself is shimmed (HBM falls back to ANY in the kernel module), so
-    only VMEM is a hard requirement."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return hasattr(pltpu, "VMEM")
-    except Exception:  # noqa: BLE001
-        return False
-
-
 # compile-heavy (jit/interpret kernels): excluded from the fast CI gate
 pytestmark = pytest.mark.slow
-
-needs_pallas = pytest.mark.skipif(
-    not _pallas_tpu_usable(),
-    reason="pallas TPU memory-space API unavailable in this jax build",
-)
 
 from distributed_gpu_inference_tpu.ops.attention import (
     paged_attention_xla,
@@ -79,18 +60,15 @@ def _compare(args, block, window=None, atol=2e-5):
                                rtol=2e-5, atol=atol)
 
 
-@needs_pallas
 def test_verify_window_basic():
     _compare(_setup(2, 4, [9, 23], nh=4, hkv=2, d=64, block=16, m=4), 16)
 
 
-@needs_pallas
 def test_multi_group_context():
     # 300 tokens -> multiple page groups per query row
     _compare(_setup(2, 5, [300, 37], nh=8, hkv=4, d=64, block=16, m=20), 16)
 
 
-@needs_pallas
 def test_padded_tail_queries_are_zero():
     from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
         paged_attention_pallas_multiquery,
@@ -106,14 +84,12 @@ def test_padded_tail_queries_are_zero():
     assert np.all(np.asarray(got)[:, -2:] == 0.0)
 
 
-@needs_pallas
 @pytest.mark.parametrize("window", [4, 16])
 def test_sliding_window(window):
     _compare(_setup(2, 3, [33, 50], nh=4, hkv=2, d=64, block=16, m=4), 16,
              window=window)
 
 
-@needs_pallas
 def test_int8_pool_parity():
     from distributed_gpu_inference_tpu.ops.attention import dequantize_kv
     from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (

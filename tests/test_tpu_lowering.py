@@ -1,0 +1,274 @@
+"""Compile the TPU kernels and the serving graph for a REAL v5e target from
+the CPU sandbox.
+
+Every other test here runs with ``JAX_PLATFORMS=cpu``, where kernel dispatch
+turns the Pallas kernels off and the kernel tests run them in interpret
+mode — which checks arithmetic, never whether Mosaic accepts the kernel.
+``jax.experimental.topologies`` gives a compile-only v5e target with no
+chip attached; lowering against it runs the real Pallas → Mosaic → XLA:TPU
+pipeline, so block-shape rules, unsupported shape casts, VMEM limits and
+"cannot be automatically partitioned" all surface here instead of on the
+first request a TPU worker serves.
+
+Skips only when ``libtpu`` is absent or the target cannot be made. A kernel
+the compiler refuses is a failure.
+"""
+
+import functools
+import importlib.util
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from distributed_gpu_inference_tpu.models import llama
+from distributed_gpu_inference_tpu.models.configs import get_model_config
+from distributed_gpu_inference_tpu.ops import attention
+from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+    paged_decode_attention_fused,
+    ragged_paged_attention,
+)
+from distributed_gpu_inference_tpu.ops.qmm_pallas import qmm_stacked_pallas
+from distributed_gpu_inference_tpu.ops.quantization import quantize_params
+from distributed_gpu_inference_tpu.parallel import sharding as sh
+
+MODELS = ("mistral-7b", "qwen2.5-7b")
+# what the worker path produces: max_batch_size 8 rows, max_seq_len 2048
+BATCH, CTX = 8, 2048
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 — any failure to MAKE the target
+        pytest.skip(f"compile-only v5e target unavailable: {exc}")
+    return topo.devices
+
+
+@pytest.fixture()
+def tpu_dispatch(monkeypatch):
+    """Kernel dispatch as it decides on a TPU backend (the one gate both
+    attention and the int8 matmul read)."""
+    monkeypatch.setattr(attention, "pallas_backend", lambda: True)
+
+
+def _on(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding
+    )
+
+
+_kernels = attention.pallas_kernels
+
+
+def _pools(sds, cfg, block, quantized, layers=None):
+    n = 1 + BATCH * (CTX // block)
+    lead = () if layers is None else (layers,)
+    dt = jnp.int8 if quantized else jnp.bfloat16
+    pool = sds(lead + (n, cfg.num_kv_heads, block, cfg.head_dim), dt)
+    scale = (
+        sds(lead + (n, block, cfg.head_dim), jnp.bfloat16)
+        if quantized else None
+    )
+    return pool, scale
+
+
+# --------------------------------------------------------------------- #
+# (a) each kernel alone, at the served geometry
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("block", [16, 32])
+@pytest.mark.parametrize("model", MODELS)
+def test_ragged_kernel_compiles(v5e, model, block, quantized):
+    cfg = get_model_config(model)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    pool, scale = _pools(sds, cfg, block, quantized)
+    fn = functools.partial(
+        ragged_paged_attention, block_size=block, window=cfg.sliding_window
+    )
+    # a decode-heavy round (narrowest chunk bucket) and a full ragged_chunk
+    for s in (16, 256):
+        jax.jit(fn).lower(
+            sds((BATCH, s, cfg.num_heads, cfg.head_dim), jnp.bfloat16),
+            pool, pool,
+            sds((BATCH, CTX // block), jnp.int32),
+            sds((BATCH, s), jnp.int32),
+            sds((BATCH,), jnp.int32),
+            k_scale=scale, v_scale=scale,
+        ).compile()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("block", [16, 32])
+@pytest.mark.parametrize("model", MODELS)
+def test_fused_decode_kernel_compiles(v5e, model, block, quantized):
+    cfg = get_model_config(model)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    layers = 2
+    pool, scale = _pools(sds, cfg, block, quantized, layers)
+    new = sds((BATCH, 1, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
+    fn = functools.partial(
+        paged_decode_attention_fused, block_size=block,
+        window=cfg.sliding_window,
+    )
+    jax.jit(fn).lower(
+        sds((BATCH, 1, cfg.num_heads, cfg.head_dim), jnp.bfloat16),
+        new, new, pool, pool, sds((), jnp.int32),
+        sds((BATCH, CTX // block), jnp.int32),
+        sds((BATCH, 1), jnp.int32),
+        sds((BATCH,), jnp.int32),
+        k_scale=scale, v_scale=scale,
+    ).compile()
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_qmm_kernel_compiles(v5e, model):
+    cfg = get_model_config(model)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    h, i = cfg.hidden_size, cfg.intermediate_size
+    q_out, kv_out = (cfg.num_heads * cfg.head_dim,
+                     cfg.num_kv_heads * cfg.head_dim)
+    # every projection of the layer: wq, wk/wv, wo, w_gate/w_up, w_down
+    shapes = {(h, q_out), (h, kv_out), (q_out, h), (h, i), (i, h)}
+
+    def all_projections(xs, ws, idx):
+        return [
+            qmm_stacked_pallas(x, w["qw"], w["scale"], idx)
+            for x, w in zip(xs, ws)
+        ]
+
+    for m in (BATCH, 256):      # a decode step; the widest row count served
+        xs = [sds((m, k), jnp.bfloat16) for k, _ in shapes]
+        ws = [{"qw": sds((2, k, n), jnp.int8),
+               "scale": sds((2, 1, n), jnp.float32)} for k, n in shapes]
+        jax.jit(all_projections).lower(xs, ws, sds((), jnp.int32)).compile()
+
+
+# --------------------------------------------------------------------- #
+# (b) the whole serving graph, one chip and a model=4 mesh
+# --------------------------------------------------------------------- #
+
+def _forward_chunk_lowered(cfg, s, mesh, devices):
+    """``forward_chunk`` for int8 ``cfg`` at [BATCH, s], lowered for one
+    device (``mesh=None``) or sharded over ``mesh`` by the engine's own
+    rules, with ``pallas`` set the way ``TPUEngine._build_jit_fns`` sets
+    it."""
+    params = jax.eval_shape(
+        lambda: quantize_params(
+            llama.init_params(cfg, jax.random.PRNGKey(0)), "int8"
+        )
+    )
+    kv = jax.eval_shape(
+        lambda: llama.init_kv_pools(cfg, 1 + BATCH * (CTX // 16), 16)
+    )
+    if mesh is None:
+        one = SingleDeviceSharding(devices[0])
+        p_sh = jax.tree.map(lambda _: one, params)
+        kv_sh = jax.tree.map(lambda _: one, kv)
+        rep = one
+    else:
+        p_sh = sh.prune_rules(sh.param_shardings(mesh), params)
+        kv_sh = jax.tree.map(lambda _: sh.kv_sharding(mesh), kv)
+        rep = NamedSharding(mesh, P())
+    place = lambda tree, shard: jax.tree.map(
+        lambda a, s_: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s_),
+        tree, shard,
+    )
+    sds = _on(rep)
+
+    def step(params, kv, toks, pos, tables, lens):
+        out = llama.forward_chunk(
+            cfg, params, toks, pos, kv, tables, lens, block_size=16,
+            pallas=mesh is None,
+        )
+        return out.logits, out.kv
+
+    return jax.jit(step, donate_argnums=(1,)).lower(
+        place(params, p_sh), place(kv, kv_sh),
+        sds((BATCH, s), jnp.int32), sds((BATCH, s), jnp.int32),
+        sds((BATCH, CTX // 16), jnp.int32), sds((BATCH,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("s", [1, 256])
+def test_forward_chunk_compiles_one_chip(v5e, tpu_dispatch, s):
+    lowered = _forward_chunk_lowered(
+        get_model_config("mistral-7b"), s, None, v5e
+    )
+    found = _kernels(lowered)
+    if s == 1:
+        # a decode step: fused write+attend, projections through the int8
+        # kernel
+        assert found == {"_decode_kernel", "_qmm_kernel"}, found
+    else:
+        # a ragged round at the full chunk: 8 x 256 rows is past the int8
+        # kernel's bandwidth-bound regime, so projections take the XLA path
+        assert found == {"_ragged_kernel"}, found
+    lowered.compile()
+
+
+@pytest.mark.parametrize("s", [1, 256])
+def test_forward_chunk_compiles_on_model4_mesh(v5e, tpu_dispatch, s):
+    mesh = Mesh(np.array(v5e).reshape(4), ("model",))
+    lowered = _forward_chunk_lowered(
+        get_model_config("mistral-7b"), s, mesh, v5e
+    )
+    # a pallas_call has no partitioning rule: under a mesh the engine asks
+    # for the XLA paths, and nothing else may slip a kernel in
+    assert _kernels(lowered) == set()
+    lowered.compile()
+
+
+def test_mesh_engine_traces_no_pallas_kernel(tpu_dispatch, cpu_devices):
+    """The engine — not the test — decides ``pallas=False`` under a mesh.
+    Geometry chosen so that dispatch WOULD pick every kernel (head_dim 128,
+    512-token tables, tileable int8 projections): a mesh engine that let
+    one through would fail to lower it for the CPU devices it runs on."""
+    from distributed_gpu_inference_tpu.models.configs import ModelConfig
+    from distributed_gpu_inference_tpu.runtime.engine import (
+        EngineConfig,
+        TPUEngine,
+    )
+    from distributed_gpu_inference_tpu.utils.data_structures import (
+        InferenceRequest,
+        SamplingParams,
+    )
+
+    cfg = ModelConfig(
+        name="lowering-probe", vocab_size=256, hidden_size=256, num_layers=1,
+        num_heads=2, num_kv_heads=2, intermediate_size=256, head_dim=128,
+    )
+    mesh = Mesh(np.array(cpu_devices[:2]), ("model",))
+    eng = TPUEngine(
+        cfg,
+        EngineConfig(max_batch_size=2, max_seq_len=512, quantization="int8",
+                     multi_step=4),
+        mesh=mesh,
+    )
+    # what chip_smoke.py reads the implementations from
+    graphs = eng.lower_serving_graphs([4], [16])
+    assert set(graphs) == {"decode_multi[T=4]", "ragged_round[S=16]"}
+    for lowered in graphs.values():
+        assert _kernels(lowered) == set()
+    out = eng.generate([
+        InferenceRequest(
+            prompt_token_ids=list(range(1, 20)),
+            sampling=SamplingParams(max_new_tokens=10, temperature=0.0),
+        )
+    ])
+    assert len(out[0].token_ids) == 10
+    # three decode rounds, one program: slot state uploaded by the host and
+    # slot state carried from the last round have the same (replicated)
+    # sharding, so the round graph does not compile a second time
+    assert eng._decode_multi_fn._cache_size() == 1

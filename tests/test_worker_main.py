@@ -15,7 +15,10 @@ from distributed_gpu_inference_tpu.utils.config import (
     EngineModelConfig,
     WorkerConfig,
 )
-from distributed_gpu_inference_tpu.utils.data_structures import WorkerState
+from distributed_gpu_inference_tpu.utils.data_structures import (
+    TpuTopology,
+    WorkerState,
+)
 from distributed_gpu_inference_tpu.worker.api_client import APIError
 from distributed_gpu_inference_tpu.worker.engines import register_engine
 from distributed_gpu_inference_tpu.worker.engines.base import BaseEngine
@@ -449,6 +452,51 @@ def test_probe_topology_env_fallback_without_jax(monkeypatch):
     assert t.chip_type == "v5e"
     assert t.num_chips == 8
     assert t.hbm_gb_per_chip == 16.0
+
+
+def test_probe_topology_unknown_chip_is_an_error(monkeypatch):
+    """A chip the published table does not know has no figures — never
+    another chip's."""
+    import distributed_gpu_inference_tpu.worker.main as wm
+
+    class FakeDev:
+        device_kind = "TPU v9 hyper"
+
+    class FakeJax:
+        @staticmethod
+        def devices():
+            return [FakeDev()]
+
+    monkeypatch.setattr(wm, "probe_tpu_runtime", lambda: {
+        "libtpu": True, "accel_devices": [], "accelerator_type": "",
+        "worker_id": "", "hosts": [],
+    })
+    import sys
+    monkeypatch.setitem(sys.modules, "jax", FakeJax())
+    with pytest.raises(RuntimeError, match="unknown accelerator"):
+        wm.probe_topology()
+
+
+def test_probe_topology_cpu_has_no_device_figures():
+    topo = probe_topology()         # the suite runs on the CPU
+    assert topo.chip_type == "cpu"
+    assert topo.hbm_gb_per_chip == topo.peak_bf16_tflops == 0.0
+    assert topo.ici_bandwidth_gbps == 0.0
+
+
+def test_register_sends_the_row_columns():
+    """The plane's worker row has chip columns of its own (worker list,
+    remote-config HBM caps) that default to one 16 GB chip."""
+    api = FakeAPI(creds_valid=False)
+    w = _worker(api)
+    w.topology = TpuTopology(chip_type="v5e", num_chips=4,
+                             hbm_gb_per_chip=16.0, mesh_shape=(2, 2))
+    w.register()
+    info = api.registered_info
+    assert info["chip_generation"] == "v5e"
+    assert info["num_chips"] == 4
+    assert info["mesh_shape"] == [2, 2]
+    assert info["topology"]["num_chips"] == 4
 
 
 def test_wizard_reports_runtime(monkeypatch):
