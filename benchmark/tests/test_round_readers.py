@@ -1,0 +1,98 @@
+"""The readers of the round spans' counters and of the named decode kernel,
+each on a hand-made ``run``; and what each gives for a program that has no
+such counter or name (the parent of the PR that added them): nothing."""
+
+import pytest
+
+from harness import layers, spec
+
+CELL = {"name": "c", "end_to_end": {"out_tok_s": {}, "itl_p99_ms": {}}}
+
+
+def reader(name):
+    entry = {"name": name, "moves": "out_tok_s"}
+    return layers.readers(dict(CELL, per_layer=[entry]))[0][1]
+
+
+def window(engine0, engine1, batcher0=None, batcher1=None, seconds=50.0):
+    ends = lambda e, b: {"engine": e, "batcher": b or {}, "direct": {}}
+    return {"w0": 100.0, "w1": 100.0 + seconds,
+            "c0": ends(engine0, batcher0), "c1": ends(engine1, batcher1)}
+
+
+def test_round_host_ms_is_build_dispatch_and_commit_over_rounds():
+    read = reader("engine.round_host_ms")
+    before = {"rounds": 10, "round_build_s": 1.0, "round_dispatch_s": 0.5,
+              "round_readback_s": 9.0, "round_commit_s": 0.25}
+    after = {"rounds": 110, "round_build_s": 1.2, "round_dispatch_s": 0.6,
+             "round_readback_s": 13.0, "round_commit_s": 0.3}
+    run = {"win": window(before, after)}
+    # (0.2 + 0.1 + 0.05) s over 100 rounds; the readback's wait is left out
+    assert read(run) == pytest.approx(3.5)
+    assert read({"win": window({"requests": 1}, {"requests": 9})}) is None
+
+
+def test_between_rounds_ms_is_seconds_over_gaps():
+    read = reader("batcher.between_rounds_ms")
+    run = {"win": window({}, {},
+                         {"between_rounds_s": 2.0, "between_rounds": 100},
+                         {"between_rounds_s": 2.6, "between_rounds": 500})}
+    assert read(run) == pytest.approx(1.5)
+    assert read({"win": window({}, {}, {"decode_rounds": 1},
+                               {"decode_rounds": 9})}) is None
+
+
+def test_prefill_padding_share_is_what_the_rectangle_did_not_hold():
+    read = reader("engine.prefill_padding_share")
+    run = {"win": window(
+        {"ragged_positions_dispatched": 2048, "ragged_positions_live": 300},
+        {"ragged_positions_dispatched": 2048 * 11,
+         "ragged_positions_live": 300 + 2048})}
+    assert read(run) == pytest.approx(90.0)
+    assert read({"win": window({"ragged_rounds": 0},
+                               {"ragged_rounds": 7})}) is None
+
+
+def test_top_scan_time_share_reads_the_highest_configured_level():
+    read = reader("batcher.top_scan_time_share")
+    geometry = {"horizon_levels": [1, 4, 16, 64]}
+    counted = {"scan_s_t1": 0.0, "scan_s_t4": 3.0, "scan_s_t16": 9.0,
+               "scan_s_t64": 1.0}
+    run = {"geometry": geometry,
+           "win": window({}, {}, counted, dict(counted, scan_s_t64=8.5))}
+    assert read(run) == pytest.approx(15.0)             # 7.5 s of 50
+    # the level was never reached: a reading of zero, not nothing
+    assert read({"geometry": geometry,
+                 "win": window({}, {}, counted, counted)}) == 0.0
+    # a program that does not count its levels
+    assert read({"geometry": geometry,
+                 "win": window({}, {}, {"horizon": 4.0},
+                               {"horizon": 4.0})}) is None
+
+
+def test_decode_attention_step_ms_finds_the_kernel_by_its_name():
+    read = reader("kernels.decode_attention_step_ms")
+    modules = [
+        {"name": "jit_decode_multi(123)", "seconds": 0.045, "steps": 4},
+        {"name": "jit_decode_multi(123)", "seconds": 0.045, "steps": "4"},
+        {"name": "jit_ragged_round(9)", "seconds": 0.2, "widest_piece": 200},
+        {"name": "jit_decode_multi(123)", "seconds": 0.01},   # no annotation
+    ]
+    ops = {"dgi_paged_decode.12": 0.006, "dgi_paged_decode.31": 0.002,
+           "dgi_paged_decode_other.1": 5.0, "dgi_qmm.88": 0.05,
+           "fusion.156": 0.3}
+    run = {"trace": {"op_seconds": ops, "modules": modules}}
+    assert read(run) == pytest.approx(1.0)              # 8 ms over 8 steps
+    old = {"closed_call.31": 0.008, "qmm_stacked_pallas.88": 0.05}
+    assert read({"trace": {"op_seconds": old, "modules": modules}}) is None
+    assert read({"trace": None}) is None
+
+
+def test_every_reader_of_the_manifest_is_a_file_with_read():
+    import json
+
+    manifest = json.loads((spec.CHECKOUT / "BENCHMARK.json").read_text())
+    for entry in manifest["per_layer"]:
+        path = spec.BENCH / "layer_metrics" \
+            / f"{entry['name'].replace('.', '_')}.py"
+        assert path.is_file(), entry["name"]

@@ -16,8 +16,8 @@ It fails (exit code != 0, no result line) when any phase fails:
 - a Pallas kernel disagrees with its XLA reference at the served shapes;
 - the ``llm`` engine did not load, or the plane's worker row does not show
   the devices JAX reports;
-- on one chip, the compiled round graphs do not hold ``_ragged_kernel``,
-  ``_decode_kernel`` and ``_qmm_kernel``; on a mesh, they hold any Pallas
+- on one chip, the compiled round graphs do not hold ``dgi_ragged_attention``,
+  ``dgi_paged_decode`` and ``dgi_qmm``; on a mesh, they hold any Pallas
   call (a ``pallas_call`` has no partitioning rule — the mesh engine serves
   from the XLA paths and says so here);
 - a request is not answered in full by the entry point it was sent to, the
@@ -44,7 +44,8 @@ from typing import Any, Dict, List, Optional
 
 ROOT = Path(__file__).resolve().parent
 WORK = ROOT / ".cache" / "chip_smoke"       # git-ignored scratch of one run
-KERNELS = ("_ragged_kernel", "_decode_kernel", "_qmm_kernel")
+MATMUL_KERNEL = "dgi_qmm"
+KERNELS = ("dgi_ragged_attention", "dgi_paged_decode", MATMUL_KERNEL)
 DEADLINE_S = 1150                           # the contract allows 1200
 
 
@@ -58,38 +59,6 @@ def say(msg: str) -> None:
 def need(ok: Any, msg: str) -> None:
     if not ok:
         raise SystemExit(f"chip_smoke: FAILED — {msg}")
-
-
-# --------------------------------------------------------------------- #
-# compile accounting
-# --------------------------------------------------------------------- #
-
-class CompileLog:
-    """Every XLA compile request of the process, by jitted function, with
-    what the persistent cache did with it (``hit`` = loaded, nothing
-    compiled; ``miss`` = compiled and stored)."""
-
-    def __init__(self) -> None:
-        from jax import monitoring
-
-        self.rows: List[Dict[str, Any]] = []
-        self._outcome: Dict[int, str] = {}
-        monitoring.register_event_listener(self._on_event)
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-
-    def _on_event(self, name: str, **_: Any) -> None:
-        if name.endswith("/cache_hits"):
-            self._outcome[threading.get_ident()] = "hit"
-        elif name.endswith("/cache_misses"):
-            self._outcome[threading.get_ident()] = "miss"
-
-    def _on_duration(self, name: str, secs: float, **kw: Any) -> None:
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.rows.append({
-                "fn": str(kw.get("fun_name")), "secs": secs,
-                "cache": self._outcome.pop(threading.get_ident(),
-                                           "uncached"),
-            })
 
 
 def cache_entries(directory: str) -> int:
@@ -202,11 +171,11 @@ def phase_graphs(llm: Any, widths: List[int], mesh: bool,
     for name, lowered in eng.lower_serving_graphs(levels, widths).items():
         found = pallas_kernels(lowered)
         found_all |= found
-        attn = sorted(found & {"_ragged_kernel", "_decode_kernel"})
+        attn = sorted(found & set(KERNELS) - {MATMUL_KERNEL})
         t1 = time.monotonic()
         lowered.compile()
         say(f"  {name}: attention={attn[0] if attn else 'xla'} "
-            f"matmul={'_qmm_kernel' if '_qmm_kernel' in found else 'xla'} "
+            f"matmul={MATMUL_KERNEL if MATMUL_KERNEL in found else 'xla'} "
             f"compile={time.monotonic() - t1:.1f}s")
     if mesh:
         need(not found_all, f"mesh graphs hold Pallas calls {found_all}")
@@ -327,6 +296,7 @@ def main() -> int:
     )
     from distributed_gpu_inference_tpu.utils.device import (
         chip_spec,
+        compile_log,
         enable_compile_cache,
     )
     from distributed_gpu_inference_tpu.worker.main import Worker
@@ -334,7 +304,7 @@ def main() -> int:
     cache_dir = enable_compile_cache()
     entries0 = cache_entries(cache_dir)
     say(f"compile cache: {cache_dir} ({entries0} entries)")
-    compiles = CompileLog()
+    compiles = compile_log()
 
     model = "llama3-mini" if dry else "mistral-7b"
     tp = len(dev)

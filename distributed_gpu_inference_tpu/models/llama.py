@@ -378,105 +378,109 @@ def _layer_step(
             return matmul_stacked(x_, stacked[name], layer_idx, pallas)
         return qmm(x_, lp[name], pallas)
 
-    x = rms_norm(hidden, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-    q = proj(x, "wq")
-    k = proj(x, "wk")
-    v = proj(x, "wv")
-    if "bq" in lp:  # Qwen2-style attention biases (static at trace time)
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(b, s, nh, d)
-    k = k.reshape(b, s, nkv, d)
-    v = v.reshape(b, s, nkv, d)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    # scopes name the layer's two halves in a device trace's op metadata;
+    # the kernels inside carry their own (innermost) names
+    with jax.named_scope("dgi_attention"):
+        x = rms_norm(hidden, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+        q = proj(x, "wq")
+        k = proj(x, "wk")
+        v = proj(x, "wv")
+        if "bq" in lp:  # Qwen2-style attention biases (static at trace time)
+            q = q + lp["bq"]
+            k = k + lp["bk"]
+            v = v + lp["bv"]
+        q = q.reshape(b, s, nh, d)
+        k = k.reshape(b, s, nkv, d)
+        v = v.reshape(b, s, nkv, d)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if fused_decode:
-        from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
-            paged_decode_attention_fused,
-        )
+        if fused_decode:
+            from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+                paged_decode_attention_fused,
+            )
 
-        if quant_kv:
-            # the kernel quantizes the new rows in place (shared contract)
-            attn, k_pool, v_pool, k_scale_pool, v_scale_pool = \
-                paged_decode_attention_fused(
-                    q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
+            if quant_kv:
+                # the kernel quantizes the new rows in place (shared contract)
+                attn, k_pool, v_pool, k_scale_pool, v_scale_pool = \
+                    paged_decode_attention_fused(
+                        q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
+                        k_pool, v_pool, layer_idx, block_tables,
+                        write_positions, kv_lens, block_size,
+                        window=cfg.sliding_window,
+                        k_scale=k_scale_pool, v_scale=v_scale_pool,
+                    )
+            else:
+                attn, k_pool, v_pool = paged_decode_attention_fused(
+                    q, k.astype(k_pool.dtype), v.astype(v_pool.dtype),
                     k_pool, v_pool, layer_idx, block_tables,
                     write_positions, kv_lens, block_size,
                     window=cfg.sliding_window,
-                    k_scale=k_scale_pool, v_scale=v_scale_pool,
                 )
         else:
-            attn, k_pool, v_pool = paged_decode_attention_fused(
-                q, k.astype(k_pool.dtype), v.astype(v_pool.dtype),
-                k_pool, v_pool, layer_idx, block_tables,
-                write_positions, kv_lens, block_size,
-                window=cfg.sliding_window,
-            )
-    else:
-        layer_k = lax.dynamic_index_in_dim(k_pool, layer_idx, 0, keepdims=False)
-        layer_v = lax.dynamic_index_in_dim(v_pool, layer_idx, 0, keepdims=False)
-        layer_ks = layer_vs = None
-        if quant_kv:
-            from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
-                _quantize_token_rows,
-            )
-
-            # per-token quantize over (Hkv, D), scale rows lane-replicated
-            k_q, k_s = _quantize_token_rows(k.astype(jnp.float32), (2, 3))
-            v_q, v_s = _quantize_token_rows(v.astype(jnp.float32), (2, 3))
-            layer_ks = lax.dynamic_index_in_dim(
-                k_scale_pool, layer_idx, 0, keepdims=False)
-            layer_vs = lax.dynamic_index_in_dim(
-                v_scale_pool, layer_idx, 0, keepdims=False)
-            layer_k = _write_kv_pages(
-                layer_k, k_q, block_tables, write_positions, block_size)
-            layer_v = _write_kv_pages(
-                layer_v, v_q, block_tables, write_positions, block_size)
-            layer_ks = _write_scale_pages(
-                layer_ks, jnp.broadcast_to(k_s[:, :, 0, :], (b, s, d)),
-                block_tables, write_positions, block_size)
-            layer_vs = _write_scale_pages(
-                layer_vs, jnp.broadcast_to(v_s[:, :, 0, :], (b, s, d)),
-                block_tables, write_positions, block_size)
-            k_scale_pool = lax.dynamic_update_index_in_dim(
-                k_scale_pool, layer_ks, layer_idx, 0)
-            v_scale_pool = lax.dynamic_update_index_in_dim(
-                v_scale_pool, layer_vs, layer_idx, 0)
-        else:
-            layer_k = _write_kv_pages(layer_k, k, block_tables, write_positions, block_size)
-            layer_v = _write_kv_pages(layer_v, v, block_tables, write_positions, block_size)
-        k_pool = lax.dynamic_update_index_in_dim(k_pool, layer_k, layer_idx, 0)
-        v_pool = lax.dynamic_update_index_in_dim(v_pool, layer_v, layer_idx, 0)
-        if dense_attn_fn is not None:
-            # pages written above for decode; attention itself runs over the
-            # chunk's dense K/V (== whole context for a from-scratch prefill)
+            layer_k = lax.dynamic_index_in_dim(k_pool, layer_idx, 0, keepdims=False)
+            layer_v = lax.dynamic_index_in_dim(v_pool, layer_idx, 0, keepdims=False)
+            layer_ks = layer_vs = None
             if quant_kv:
-                # int8 pools: attend over the quantize→dequantize roundtrip
-                # of the chunk's K/V (THE shared dequant arithmetic) so a
-                # dense seq-sharded prefill matches a single-chip engine's
-                # paged-read prefill
-                from distributed_gpu_inference_tpu.ops.attention import (
-                    dequantize_kv,
+                from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+                    _quantize_token_rows,
                 )
 
-                attn = dense_attn_fn(
-                    q, dequantize_kv(k_q, k_s), dequantize_kv(v_q, v_s)
-                )
+                # per-token quantize over (Hkv, D), scale rows lane-replicated
+                k_q, k_s = _quantize_token_rows(k.astype(jnp.float32), (2, 3))
+                v_q, v_s = _quantize_token_rows(v.astype(jnp.float32), (2, 3))
+                layer_ks = lax.dynamic_index_in_dim(
+                    k_scale_pool, layer_idx, 0, keepdims=False)
+                layer_vs = lax.dynamic_index_in_dim(
+                    v_scale_pool, layer_idx, 0, keepdims=False)
+                layer_k = _write_kv_pages(
+                    layer_k, k_q, block_tables, write_positions, block_size)
+                layer_v = _write_kv_pages(
+                    layer_v, v_q, block_tables, write_positions, block_size)
+                layer_ks = _write_scale_pages(
+                    layer_ks, jnp.broadcast_to(k_s[:, :, 0, :], (b, s, d)),
+                    block_tables, write_positions, block_size)
+                layer_vs = _write_scale_pages(
+                    layer_vs, jnp.broadcast_to(v_s[:, :, 0, :], (b, s, d)),
+                    block_tables, write_positions, block_size)
+                k_scale_pool = lax.dynamic_update_index_in_dim(
+                    k_scale_pool, layer_ks, layer_idx, 0)
+                v_scale_pool = lax.dynamic_update_index_in_dim(
+                    v_scale_pool, layer_vs, layer_idx, 0)
             else:
-                attn = dense_attn_fn(q, k, v)
-        elif quant_kv:
-            attn = attn_fn(q, layer_k, layer_v, layer_ks, layer_vs)
-        else:
-            attn = attn_fn(q, layer_k, layer_v)
+                layer_k = _write_kv_pages(layer_k, k, block_tables, write_positions, block_size)
+                layer_v = _write_kv_pages(layer_v, v, block_tables, write_positions, block_size)
+            k_pool = lax.dynamic_update_index_in_dim(k_pool, layer_k, layer_idx, 0)
+            v_pool = lax.dynamic_update_index_in_dim(v_pool, layer_v, layer_idx, 0)
+            if dense_attn_fn is not None:
+                # pages written above for decode; attention itself runs over the
+                # chunk's dense K/V (== whole context for a from-scratch prefill)
+                if quant_kv:
+                    # int8 pools: attend over the quantize→dequantize roundtrip
+                    # of the chunk's K/V (THE shared dequant arithmetic) so a
+                    # dense seq-sharded prefill matches a single-chip engine's
+                    # paged-read prefill
+                    from distributed_gpu_inference_tpu.ops.attention import (
+                        dequantize_kv,
+                    )
 
-    hidden = hidden + proj(attn.reshape(b, s, nh * d), "wo").astype(hidden.dtype)
-    mlp_in = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-    if "w_router" in lp:
-        hidden = hidden + _moe_mlp(mlp_in, lp, cfg)
-    else:
-        hidden = hidden + _mlp(mlp_in, proj, cfg.activation)
+                    attn = dense_attn_fn(
+                        q, dequantize_kv(k_q, k_s), dequantize_kv(v_q, v_s)
+                    )
+                else:
+                    attn = dense_attn_fn(q, k, v)
+            elif quant_kv:
+                attn = attn_fn(q, layer_k, layer_v, layer_ks, layer_vs)
+            else:
+                attn = attn_fn(q, layer_k, layer_v)
+
+        hidden = hidden + proj(attn.reshape(b, s, nh * d), "wo").astype(hidden.dtype)
+    with jax.named_scope("dgi_experts" if "w_router" in lp else "dgi_mlp"):
+        mlp_in = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+        if "w_router" in lp:
+            hidden = hidden + _moe_mlp(mlp_in, lp, cfg)
+        else:
+            hidden = hidden + _mlp(mlp_in, proj, cfg.activation)
     k_out = (k_pool, k_scale_pool) if quant_kv else k_pool
     v_out = (v_pool, v_scale_pool) if quant_kv else v_pool
     return (hidden, k_out, v_out, layer_idx + 1), (
@@ -610,7 +614,8 @@ def forward_chunk(
         )  # [B, 1, H]
     else:
         logits_in = hidden
-    logits = project_logits(cfg, params, logits_in)
+    with jax.named_scope("dgi_head"):
+        logits = project_logits(cfg, params, logits_in)
     return ChunkOutput(hidden=hidden, kv=new_kv,
                        logits=logits, features=features)
 

@@ -56,6 +56,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+# fixed names (see ops/qmm_pallas.py KERNEL_NAME): ``dgi_paged_decode.<n>``
+# on a device trace's XLA Ops line where the fused decode kernel used to
+# show as the enclosing ``closed_call.<n>``
+DECODE_KERNEL_NAME = "dgi_paged_decode"
+RAGGED_KERNEL_NAME = "dgi_ragged_attention"
 # VMEM budget for the four KV staging buffers (2 pools x 2 slots); the rest
 # of VMEM stays free for q/out blocks and compute temporaries.
 _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
@@ -626,6 +631,7 @@ def _call_decode_kernel(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name=DECODE_KERNEL_NAME,
     )(*operands)
     return results  # (out, k, v[, k_scale, v_scale])
 
@@ -1073,6 +1079,7 @@ def ragged_paged_attention(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name=RAGGED_KERNEL_NAME,
     )(*operands)
     out = out.reshape(b, qt, hkv, qpk, t, d).transpose(0, 1, 4, 2, 3, 5) \
         .reshape(b, s_pad, nh, d)

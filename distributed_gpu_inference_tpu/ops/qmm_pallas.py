@@ -58,6 +58,12 @@ def pick_tiles(k: int, n: int) -> Optional[tuple]:
     return bk, bn
 
 
+# fixed: the Mosaic kernel's name (``kernel_name`` in the lowered text,
+# ``ops.attention.pallas_kernels``) and, as the innermost scope, the custom
+# call's name on a device trace's XLA Ops line (``dgi_qmm.<n>``)
+KERNEL_NAME = "dgi_qmm"
+
+
 def _qmm_kernel(idx_ref, x_ref, qw_ref, scale_ref, o_ref, acc_ref, *, num_k):
     kk = pl.program_id(1)
 
@@ -127,6 +133,7 @@ def qmm_stacked_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
         x,

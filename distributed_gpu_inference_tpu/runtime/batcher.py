@@ -35,6 +35,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from distributed_gpu_inference_tpu.runtime import flight
 from distributed_gpu_inference_tpu.runtime.engine import (
     ChunkedAdmission,
     PreemptedSequence,
@@ -311,6 +312,13 @@ class ContinuousBatcher:
         # different admission subset receives the scarce tokens each
         # round (split_prefill_budget's starvation-freedom)
         self._prefill_rr = 0
+        # the number of the last round dispatched: the engine's ``rounds``
+        # count where it keeps one, so that the batcher's and the engine's
+        # spans of one round (two threads) carry the same ``round=<n>``
+        self._round = 0
+        # perf_counter at the last round's end, stamped on the engine
+        # thread; None once the loop has parked (no work owned in between)
+        self._round_end: Optional[float] = None
         self.stats: Dict[str, Any] = {
             "submitted": 0, "completed": 0, "rejected": 0, "timeouts": 0,
             "decode_rounds": 0, "admitted": 0, "queue_peak": 0,
@@ -323,7 +331,13 @@ class ContinuousBatcher:
             "preempted_too_often": 0,
             "cancelled": 0, "migrated": 0, "adopted": 0,
             "abandoned": 0, "abandoned_predictive": 0,
+            # round spans' time counters (docs/observability.md): the gap
+            # from one round's end to the next one's start while work was
+            # owned throughout, and the loop's two halves that split it
+            "between_rounds_s": 0.0, "between_rounds": 0,
+            "admit_s": 0.0, "deliver_s": 0.0,
         }
+        self._level_counters()
 
     @property
     def use_ragged(self) -> bool:
@@ -377,6 +391,15 @@ class ContinuousBatcher:
         self._horizon = float(levels[self._level])
         if hasattr(self, "stats"):
             self.stats["horizon"] = self._horizon
+            self._level_counters()
+
+    def _level_counters(self) -> None:
+        """``scans_t<T>`` / ``scan_s_t<T>`` for every configured level T:
+        scans dispatched at that length and their seconds. The non-zero
+        ones are the levels the traffic reached."""
+        for t in self._levels:
+            self.stats.setdefault(f"scans_t{t}", 0)
+            self.stats.setdefault(f"scan_s_t{t}", 0.0)
 
     # ---------------------------------------------------- speculative routing
 
@@ -969,7 +992,7 @@ class ContinuousBatcher:
                 self._ragged.append((adm, item))
                 self.stats["ragged_admissions"] += 1
                 self._note(item, "batcher.admitted", slot=adm.slot,
-                           mode="ragged",
+                           mode="ragged", round=self._round + 1,
                            tokens=len(item.request.prompt_token_ids or []))
                 continue
             n_prompt = len(item.request.prompt_token_ids or [])
@@ -1005,7 +1028,8 @@ class ContinuousBatcher:
                 self._chunked = (adm, item)
                 self.stats["chunked_admissions"] += 1
                 self._note(item, "batcher.admitted", slot=adm.slot,
-                           mode="chunked", tokens=n_prompt)
+                           mode="chunked", round=self._round + 1,
+                           tokens=n_prompt)
                 continue
             free.pop(0)
             wave.append(item)
@@ -1059,6 +1083,7 @@ class ContinuousBatcher:
                     self._admit_stamp[slot] = next(self._stamp)
                     self._note(item, "batcher.admitted", at=t_admit,
                                slot=slot, mode="wave",
+                               round=self._round + 1,
                                tokens=len(item.request.prompt_token_ids
                                           or []))
                     self._note_first_token(item, slot)
@@ -1071,6 +1096,7 @@ class ContinuousBatcher:
                     self._admit_stamp[slot] = next(self._stamp)
                     self._note(item, "batcher.admitted", at=t_admit,
                                slot=slot, mode="wave",
+                               round=self._round + 1,
                                tokens=len(item.request.prompt_token_ids
                                           or []))
                     self._note_first_token(item, slot)
@@ -1087,16 +1113,18 @@ class ContinuousBatcher:
         self.stats["admitted"] += admitted
         return admitted
 
-    def _note_first_token(self, item: "_QueueItem", slot: int) -> None:
+    def _note_first_token(self, item: "_QueueItem", slot: int,
+                          **attrs: Any) -> None:
         """Note the first-token boundary at the ENGINE's wall-clock stamp
         (``SequenceSlot.first_token_time`` — the instant the token was
         sampled) rather than the loop's observation time, so ttft on the
-        timeline matches the engine's own ttft_ms."""
+        timeline matches the engine's own ttft_ms. ``attrs``: the
+        ``round`` that sampled it, where a round did."""
         if item.flight is None:
             return
         s = self.engine.slots[slot]
         t = getattr(s, "first_token_time", None) if s is not None else None
-        self._note(item, "batcher.first_token", at=t)
+        self._note(item, "batcher.first_token", at=t, **attrs)
 
     async def _step_chunked(self) -> None:
         """Advance the in-flight chunk-interleaved admission by ONE chunk."""
@@ -1575,13 +1603,16 @@ class ContinuousBatcher:
         the multi-step scan (horizon amortization of the host RTT) is the
         better dispatch for the identical math and runs instead."""
         t0 = time.perf_counter()
-        if self._ragged:
-            adms = [adm for adm, _ in self._ragged]
-            self.engine.ragged_round(adms, self._prefill_chunk_caps(adms))
-            self.stats["ragged_rounds"] += 1
-            return (time.perf_counter() - t0) * 1000.0
-        steps = self._levels[self._level]
-        if self._heap or self._chunked is not None:
+        st = self.stats
+        if self._round_end is not None:
+            st["between_rounds_s"] += t0 - self._round_end
+            st["between_rounds"] += 1
+        engine_stats = getattr(self.engine, "stats", None) or {}
+        n = self._round = int(engine_stats.get("rounds", self._round)) + 1
+        level = self._levels[self._level]
+        ragged = bool(self._ragged)
+        steps = 1 if ragged else level
+        if not ragged and (self._heap or self._chunked is not None):
             # work is waiting (queued requests or a mid-prefill chunked
             # admission): bounded horizon so admission latency stays low
             # without falling back to one-RTT-per-token stepping; snap
@@ -1590,8 +1621,23 @@ class ContinuousBatcher:
             cap = min(steps, self.cfg.busy_multi_step)
             eligible = [t for t in self._levels if t <= cap]
             steps = max(eligible) if eligible else min(self._levels)
-        self.engine.decode_multi(steps)
-        return (time.perf_counter() - t0) * 1000.0
+        try:
+            with flight.span("dgi.batcher.round", st,
+                             None if ragged else f"scan_s_t{steps}",
+                             round=n, kind="ragged" if ragged else "scan",
+                             steps=steps, level=level,
+                             queue_depth=len(self._heap)):
+                if ragged:
+                    adms = [adm for adm, _ in self._ragged]
+                    self.engine.ragged_round(
+                        adms, self._prefill_chunk_caps(adms))
+                    st["ragged_rounds"] += 1
+                else:
+                    st[f"scans_t{steps}"] = st.get(f"scans_t{steps}", 0) + 1
+                    self.engine.decode_multi(steps)
+            return (time.perf_counter() - t0) * 1000.0
+        finally:
+            self._round_end = time.perf_counter()
 
     def _retune(self, latency_ms: float) -> None:
         """AdaptiveBatcher analogue (reference :413-431): one quantized
@@ -1624,6 +1670,7 @@ class ContinuousBatcher:
                 self._wake.clear()
                 if self._stopping:
                     return
+                self._round_end = None      # parked: the next gap is idle
                 await self._wake.wait()
                 # admission latch: give co-arriving requests a window to form
                 # a batch (reference max_wait trigger :177-199)
@@ -1631,27 +1678,31 @@ class ContinuousBatcher:
             while time.time() < latch_until and \
                     len(self._heap) < len(self.engine.slots):
                 await asyncio.sleep(0.001)
-            # cancel/interrupt events land at this quiescent boundary:
-            # aborted requests release their slots BEFORE admission so the
-            # freed capacity admits waiting work this very pass
-            await self._scan_signals()
-            # hopeless deadline work drops at the same boundary, so its
-            # freed blocks admit waiting on-time work this very pass
-            await self._scan_deadlines()
-            # low-depth all-greedy load routes through the spec tree BEFORE
-            # paged admission claims it; requests arriving mid-wave admit to
-            # paged slots below and the two interleave round for round
-            await self._maybe_start_spec_wave()
-            await self._admit()
-            # admission-sourced KV pressure: deferred requests wait, or a
-            # higher-priority arrival preempts the lowest-priority victim
-            await self._check_pressure()
-            # one prefill chunk of the in-flight long admission per loop
-            # iteration — decode rounds below run between chunks, so active
-            # slots stall at most one chunk per round
-            await self._step_chunked()
-            # one bounded fused dispatch of the in-flight spec wave
-            await self._step_spec_wave()
+            with flight.span("dgi.batcher.admit", self.stats, "admit_s",
+                             queue_depth=len(self._heap)):
+                # cancel/interrupt events land at this quiescent boundary:
+                # aborted requests release their slots BEFORE admission so
+                # the freed capacity admits waiting work this very pass
+                await self._scan_signals()
+                # hopeless deadline work drops at the same boundary, so its
+                # freed blocks admit waiting on-time work this very pass
+                await self._scan_deadlines()
+                # low-depth all-greedy load routes through the spec tree
+                # BEFORE paged admission claims it; requests arriving
+                # mid-wave admit to paged slots below and the two interleave
+                # round for round
+                await self._maybe_start_spec_wave()
+                await self._admit()
+                # admission-sourced KV pressure: deferred requests wait, or
+                # a higher-priority arrival preempts the lowest-priority
+                # victim
+                await self._check_pressure()
+                # one prefill chunk of the in-flight long admission per
+                # loop iteration — decode rounds below run between chunks, so
+                # active slots stall at most one chunk per round
+                await self._step_chunked()
+                # one bounded fused dispatch of the in-flight spec wave
+                await self._step_spec_wave()
             if not self._slot_items and self._chunked is None \
                     and not self._ragged:
                 # no batcher-owned slot decodes: no frozen slot of OURS is
@@ -1667,50 +1718,61 @@ class ContinuousBatcher:
                 latency = await loop.run_in_executor(
                     self._exec, self._engine_round
                 )
-                self.stats["decode_rounds"] += 1
-                self.stats["occupancy_sum"] += self.engine.num_active
-                self._retune(latency)
-                # admission-chunk rounds on the timeline: one bounded note
-                # per in-flight traced admission per round (saturates at
-                # the per-request event cap on pathological prompts)
-                for adm, item in self._ragged:
-                    if item.flight is not None:
-                        self._note(item, "batcher.chunk_round", off=adm.off)
-                # ragged admissions whose final chunk sampled its first
-                # token this round join the batch (the finished-slot sweep
-                # below then resolves any that immediately hit stop/length)
-                for adm, item in [p for p in self._ragged if p[0].done]:
-                    self._ragged.remove((adm, item))
-                    self._slot_items[adm.slot] = item
-                    self._admit_stamp[adm.slot] = next(self._stamp)
-                    self.stats["admitted"] += 1
-                    self._note_first_token(item, adm.slot)
-                for i, s in enumerate(list(self.engine.slots)):
-                    if s is not None and s.finish_reason is not None \
-                            and i in self._slot_items:
-                        # OWNED slots only: a foreign sequence that finished
-                        # while sharing our rounds (PD retained/awaiting
-                        # adoption) keeps its slot until its owner collects
-                        # it — finishing it here would discard the response
-                        resp = await loop.run_in_executor(
-                            self._exec, self.engine.finish_slot, i
-                        )
-                        item = self._slot_items.pop(i, None)
-                        if item and not item.future.done():
-                            self._note(item, "batcher.completed",
-                                       finish_reason=resp.finish_reason,
-                                       tokens=resp.completion_tokens)
-                            item.future.set_result(resp)
-                            self.stats["completed"] += 1
-                # streaming observers see each surviving slot's monotonic
-                # token list once per round (finished slots resolved above)
-                self._notify_observers()
-                # decode-sourced KV pressure: slots froze this round —
-                # preempt the policy victim so the next round progresses
-                # (completions above may already have freed blocks; the
-                # check skips if every frozen slot resolved). An
-                # unpressured round releases the resume hold.
-                await self._check_pressure(after_round=True)
+                with flight.span("dgi.batcher.deliver", self.stats,
+                                 "deliver_s") as delivered:
+                    finished = 0
+                    self.stats["decode_rounds"] += 1
+                    self.stats["occupancy_sum"] += self.engine.num_active
+                    self._retune(latency)
+                    # admission-chunk rounds on the timeline: one bounded
+                    # note per in-flight traced admission per round
+                    # (saturates at the per-request event cap on
+                    # pathological prompts)
+                    for adm, item in self._ragged:
+                        if item.flight is not None:
+                            self._note(item, "batcher.chunk_round",
+                                       off=adm.off, round=self._round)
+                    # ragged admissions whose final chunk sampled its first
+                    # token this round join the batch (the finished-slot
+                    # sweep below then resolves any that immediately hit
+                    # stop/length)
+                    for adm, item in [p for p in self._ragged if p[0].done]:
+                        self._ragged.remove((adm, item))
+                        self._slot_items[adm.slot] = item
+                        self._admit_stamp[adm.slot] = next(self._stamp)
+                        self.stats["admitted"] += 1
+                        self._note_first_token(item, adm.slot,
+                                               round=self._round)
+                    for i, s in enumerate(list(self.engine.slots)):
+                        if s is not None and s.finish_reason is not None \
+                                and i in self._slot_items:
+                            # OWNED slots only: a foreign sequence that
+                            # finished while sharing our rounds (PD
+                            # retained/awaiting adoption) keeps its slot
+                            # until its owner collects it — finishing it
+                            # here would discard the response
+                            resp = await loop.run_in_executor(
+                                self._exec, self.engine.finish_slot, i
+                            )
+                            item = self._slot_items.pop(i, None)
+                            finished += 1
+                            if item and not item.future.done():
+                                self._note(item, "batcher.completed",
+                                           finish_reason=resp.finish_reason,
+                                           tokens=resp.completion_tokens)
+                                item.future.set_result(resp)
+                                self.stats["completed"] += 1
+                    # streaming observers see each surviving slot's
+                    # monotonic token list once per round (finished slots
+                    # resolved above)
+                    self._notify_observers()
+                    # decode-sourced KV pressure: slots froze this round —
+                    # preempt the policy victim so the next round progresses
+                    # (completions above may already have freed blocks; the
+                    # check skips if every frozen slot resolved). An
+                    # unpressured round releases the resume hold.
+                    await self._check_pressure(after_round=True)
+                    delivered.set(finished=finished)
             except asyncio.CancelledError:
                 raise
             except Exception as e:

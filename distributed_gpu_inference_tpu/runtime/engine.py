@@ -41,6 +41,7 @@ from distributed_gpu_inference_tpu.ops.quantization import quantize_params
 from distributed_gpu_inference_tpu.ops.sampling import (
     sample_tokens_per_slot,
 )
+from distributed_gpu_inference_tpu.runtime import flight
 from distributed_gpu_inference_tpu.runtime.kv_cache import (
     HostKVStore,
     OutOfBlocksError,
@@ -52,6 +53,7 @@ from distributed_gpu_inference_tpu.runtime.speculative import (
     draft_apply,
     init_draft_params,
 )
+from distributed_gpu_inference_tpu.utils.device import compile_log
 from distributed_gpu_inference_tpu.utils.data_structures import (
     InferenceRequest,
     InferenceResponse,
@@ -566,7 +568,15 @@ class TPUEngine:
             "prefill_tokens": 0, "prefill_calls": 0, "decode_calls": 0,
             "preemptions": 0, "resumes": 0, "kv_pressure_events": 0,
             "ragged_rounds": 0,
+            # the batcher's two round calls (docs/observability.md, "Round
+            # spans and counters"): calls, what a ragged rectangle held,
+            # and the host's seconds in each phase of a round
+            "rounds": 0,
+            "ragged_positions_dispatched": 0, "ragged_positions_live": 0,
+            "round_build_s": 0.0, "round_dispatch_s": 0.0,
+            "round_readback_s": 0.0, "round_commit_s": 0.0,
         }
+        self._compile_log = compile_log()
         if self.cfg.speculative is not None:
             self.stats.update({
                 "spec_steps": 0, "spec_slot_steps": 0, "spec_drafted": 0,
@@ -2312,9 +2322,27 @@ class TPUEngine:
         no reservation — it retries next round). Chunked prefill is
         chunk-width-invariant, so any cap schedule yields byte-identical
         outputs; caps only shape WHEN prefill work lands."""
-        if self.cfg.speculative is not None:
-            return self._spec_ragged_round(admissions, chunk_caps)
-        return self._plain_ragged_round(admissions, chunk_caps)
+        st = self.stats
+        st["rounds"] += 1
+        with flight.span("dgi.engine.ragged_round", round=st["rounds"],
+                         steps=1) as sp:
+            if self.cfg.speculative is not None:
+                return self._spec_ragged_round(admissions, chunk_caps, sp)
+            return self._plain_ragged_round(admissions, chunk_caps, sp)
+
+    def _count_ragged(self, sp: flight.span, bucket: int, decode_rows: int,
+                      decode_tokens: int, ready: Sequence[Any]) -> None:
+        """What one ragged rectangle held, onto the round's span and into
+        the counters: ``positions`` = rows x bucket dispatched, of which
+        the decode rows' tokens and the admission pieces are live."""
+        live_prompt = sum(len(piece) for _, piece, _ in ready)
+        positions = len(self.slots) * bucket
+        sp.set(bucket=bucket, decode_rows=decode_rows,
+               admission_rows=len(ready), live_prompt_tokens=live_prompt,
+               positions=positions)
+        st = self.stats
+        st["ragged_positions_dispatched"] += positions
+        st["ragged_positions_live"] += decode_tokens + live_prompt
 
     def _ragged_admission_rows(
         self, admissions: Sequence[ChunkedAdmission], chunk_cap: int,
@@ -2398,8 +2426,8 @@ class TPUEngine:
                 self._release_prefill_window(adm)
 
     def _plain_ragged_round(
-        self, admissions: Sequence[ChunkedAdmission] = (),
-        chunk_caps: Optional[Dict[int, int]] = None,
+        self, admissions: Sequence[ChunkedAdmission],
+        chunk_caps: Optional[Dict[int, int]], sp: flight.span,
     ) -> Dict[int, List[int]]:
         """ONE device dispatch serving a ragged row batch: every active
         decode slot advances one token AND every in-flight admission
@@ -2415,7 +2443,54 @@ class TPUEngine:
         contract, including the pending-block pre-reservation; a pressured
         final chunk is NOT consumed and retries next round). Returns
         {slot: [token]} for every row that sampled. Admissions are mutated
-        in place; ``adm.done`` flips when the first token lands."""
+        in place; ``adm.done`` flips when the first token lands. ``sp`` is
+        the round's open span (``ragged_round``): it gets what the
+        rectangle held, and the four phases nest inside it."""
+        with flight.span("dgi.engine.ragged_round.build", self.stats,
+                         "round_build_s"):
+            built = self._build_plain_ragged(admissions, chunk_caps, sp)
+        if built is None:
+            return {}
+        kept, ready, operands, mode = built
+        with flight.span("dgi.engine.ragged_round.dispatch", self.stats,
+                         "round_dispatch_s"):
+            try:
+                self.kv, self._dev_core, toks = self._ragged_round_fn(
+                    self.params, self.kv, *operands, mode,
+                )
+            except Exception:
+                self._invalidate_device_state()
+                raise
+        with flight.span("dgi.engine.ragged_round.readback", self.stats,
+                         "round_readback_s"):
+            toks = np.asarray(toks)     # the wait for the device
+        with flight.span("dgi.engine.ragged_round.commit", self.stats,
+                         "round_commit_s"):
+            self.stats["ragged_rounds"] += 1
+            if kept:
+                self.stats["decode_calls"] += 1
+            if ready:
+                # ONE device dispatch served every admission row — the
+                # counter means device calls everywhere else (wave
+                # admission asserts one per bucket), so it must not scale
+                # with the row count
+                self.stats["prefill_calls"] += 1
+            out: Dict[int, List[int]] = {}
+            for i in kept:
+                self._kv_lens[i] += 1   # the fed token's KV is now committed
+                tok = int(toks[i])
+                out[i] = [tok]
+                self._record_token(i, tok, device_synced=True)
+            self._commit_ragged_admissions(ready, toks, out)
+        return out
+
+    def _build_plain_ragged(
+        self, admissions: Sequence[ChunkedAdmission],
+        chunk_caps: Optional[Dict[int, int]], sp: flight.span,
+    ) -> Optional[Tuple[List[int], List[Any], Tuple[Any, ...], str]]:
+        """The host's half of a plain ragged round before the dispatch:
+        block reservation, the row batch, pending pool ops, the uploads.
+        None when no row is left to run."""
         admissions = [a for a in admissions if not a.done]
         for adm in admissions:
             s = self.slots[adm.slot]
@@ -2458,10 +2533,11 @@ class TPUEngine:
         ready, width = self._ragged_admission_rows(admissions, chunk_cap,
                                                    chunk_caps)
         if not kept and not ready:
-            return {}
+            return None
 
         self._apply_pending()
         s_w = self._bucket_len(width)
+        self._count_ragged(sp, s_w, len(kept), len(kept), ready)
         toks_pos = np.zeros((2, b, s_w), np.int32)
         toks_pos[1] = -1
         lens_after = np.zeros((b,), np.int32)
@@ -2481,35 +2557,12 @@ class TPUEngine:
             mode = "mixed"
         core = self._sync_core()
         tables, _act, flag_d = self._sched_arrays(row_mask, sample_flag)
-        try:
-            self.kv, self._dev_core, toks = self._ragged_round_fn(
-                self.params, self.kv, toks_pos, tables,
-                jnp.asarray(lens_after), core, flag_d, mode,
-            )
-        except Exception:
-            self._invalidate_device_state()
-            raise
-        toks = np.asarray(toks)
-        self.stats["ragged_rounds"] += 1
-        if kept:
-            self.stats["decode_calls"] += 1
-        if ready:
-            # ONE device dispatch served every admission row — the counter
-            # means device calls everywhere else (wave admission asserts
-            # one per bucket), so it must not scale with the row count
-            self.stats["prefill_calls"] += 1
-        out: Dict[int, List[int]] = {}
-        for i in kept:
-            self._kv_lens[i] += 1   # the fed token's KV is now committed
-            tok = int(toks[i])
-            out[i] = [tok]
-            self._record_token(i, tok, device_synced=True)
-        self._commit_ragged_admissions(ready, toks, out)
-        return out
+        return kept, ready, (toks_pos, tables, jnp.asarray(lens_after),
+                             core, flag_d), mode
 
     def _spec_ragged_round(
-        self, admissions: Sequence[ChunkedAdmission] = (),
-        chunk_caps: Optional[Dict[int, int]] = None,
+        self, admissions: Sequence[ChunkedAdmission],
+        chunk_caps: Optional[Dict[int, int]], sp: flight.span,
     ) -> Dict[int, List[int]]:
         """Spec-integrated ragged round: ONE dispatch serving VERIFY rows
         (per active decode slot: the draft chain + pending token,
@@ -2589,7 +2642,7 @@ class TPUEngine:
             # reservation can fit where K+2 did not — graceful
             # degradation, still target-greedy so outputs are unchanged;
             # only the stale draft hidden costs next-round acceptance).
-            return self._plain_ragged_round(admissions, chunk_caps)
+            return self._plain_ragged_round(admissions, chunk_caps, sp)
 
         # --- admission chunk rows: identical contract to the plain path
         # (shared helper — the retry/reservation rules cannot drift)
@@ -2602,6 +2655,8 @@ class TPUEngine:
         # bucket; wider chunk rows bucket as usual — the compiled width
         # set stays {K+1} ∪ buckets
         s_w = k + 1 if width <= k + 1 else self._bucket_len(width)
+        self._count_ragged(sp, s_w, int(spec_rows.sum()),
+                           int((ks_sel[spec_rows] + 1).sum()), ready)
         toks_pos = np.zeros((2, b, s_w), np.int32)
         toks_pos[1] = -1
         lens_after = np.zeros((b,), np.int32)
@@ -3088,15 +3143,78 @@ class TPUEngine:
         With ``EngineConfig.speculative`` set, the T steps are fused
         draft→verify→accept rounds instead — each commits 1..K+1 tokens per
         slot, amortizing the weight stream over the accepted tokens."""
-        num_steps = num_steps or self.cfg.multi_step
-        if self.cfg.speculative is not None:
-            return self._spec_decode_rounds(int(num_steps))
+        num_steps = int(num_steps or self.cfg.multi_step)
+        st = self.stats
+        st["rounds"] += 1
+        with flight.span("dgi.engine.decode_multi", round=st["rounds"],
+                         steps=num_steps) as sp:
+            if self.cfg.speculative is not None:
+                return self._spec_decode_rounds(num_steps)
+            return self._plain_decode_multi(num_steps, sp)
+
+    def _plain_decode_multi(self, num_steps: int, sp: flight.span
+                            ) -> Dict[int, List[int]]:
+        """The plain scan of ``decode_multi``, in the four phases of a
+        round (build, dispatch, readback, commit), each a span inside
+        ``sp`` and a time counter."""
+        with flight.span("dgi.engine.decode_multi.build", self.stats,
+                         "round_build_s"):
+            built = self._build_decode_multi(num_steps)
+        if built is None:
+            return {}
+        active_mask, operands, mode = built
+        rows = int(active_mask.sum())
+        sp.set(decode_rows=rows, positions=len(self.slots) * num_steps)
+        with flight.span("dgi.engine.decode_multi.dispatch", self.stats,
+                         "round_dispatch_s"):
+            try:
+                self.kv, self._dev_core, emitted = self._decode_multi_fn(
+                    self.params, self.kv, *operands, num_steps, mode,
+                )
+            except Exception:
+                self._invalidate_device_state()
+                raise
+        self.stats["decode_calls"] += num_steps
+        with flight.span("dgi.engine.decode_multi.readback", self.stats,
+                         "round_readback_s"):
+            # [B, T], -1 = masked-out step: the wait for the device
+            emitted = np.asarray(emitted)
+        with flight.span("dgi.engine.decode_multi.commit", self.stats,
+                         "round_commit_s"):
+            out: Dict[int, List[int]] = {}
+            for i, s in enumerate(self.slots):
+                if not active_mask[i] or s is None:
+                    continue
+                toks = [int(t) for t in emitted[i] if t >= 0]
+                out[i] = toks
+                # each emitted token corresponds to one scan step that fed
+                # (and thus committed) the previous pending token
+                self._kv_lens[i] += len(toks)
+                for t in toks:
+                    if s.finish_reason is not None:
+                        break
+                    self._record_token(i, t, already_committed=True,
+                                       device_synced=True)
+                # manager bookkeeping: seq_tokens ← tokens that are committed
+                # or pending-with-reserved-block (stop/length-trigger
+                # excluded, as in the per-step path)
+                commit = toks if s.finish_reason is None else toks[:-1]
+                self.manager.commit_tokens(s.seq_id, commit)
+                self._maybe_release_window(i)
+        return out
+
+    def _build_decode_multi(self, num_steps: int
+                            ) -> Optional[Tuple[np.ndarray, Tuple[Any, ...],
+                                                str]]:
+        """The host's half of a scan before the dispatch: who decodes, the
+        budgets, block reservation for the horizon, pending pool ops, the
+        uploads. None when no row is left to run."""
         active_mask = np.array(
             [s is not None and s.finish_reason is None and not s.prefilling
              for s in self.slots]
         )
         if not active_mask.any():
-            return {}
+            return None
         # per-slot token budgets enforced ON DEVICE (scan masks a slot once
         # it emits its allowance) — num_steps stays the compiled constant
         # instead of shrinking to the shortest slot and recompiling per
@@ -3114,7 +3232,7 @@ class TPUEngine:
         budgets = np.maximum(budgets, 0)
         active_mask &= budgets > 0
         if not active_mask.any():
-            return {}
+            return None
         # pre-reserve KV blocks for each slot's actual horizon (no host
         # alloc mid-scan). A slot whose reservation exhausts the pool is
         # FROZEN for this round (masked out, partial reservation trimmed
@@ -3144,44 +3262,13 @@ class TPUEngine:
         if pressured:
             self._signal_pressure("decode", slots=pressured)
         if not active_mask.any():
-            return {}
+            return None
         self._apply_pending()
         core = self._sync_core()
         tables, act_d, bud_d = self._sched_arrays(
             active_mask, budgets.astype(np.int32)
         )
-        mode = self._decode_mode()
-        try:
-            self.kv, self._dev_core, emitted = self._decode_multi_fn(
-                self.params, self.kv, core, tables, act_d, bud_d,
-                int(num_steps), mode,
-            )
-        except Exception:
-            self._invalidate_device_state()
-            raise
-        self.stats["decode_calls"] += num_steps
-        emitted = np.asarray(emitted)  # [B, T], -1 = masked-out step
-        out: Dict[int, List[int]] = {}
-        for i, s in enumerate(self.slots):
-            if not active_mask[i] or s is None:
-                continue
-            toks = [int(t) for t in emitted[i] if t >= 0]
-            out[i] = toks
-            # each emitted token corresponds to one scan step that fed (and
-            # thus committed) the previous pending token
-            self._kv_lens[i] += len(toks)
-            for t in toks:
-                if s.finish_reason is not None:
-                    break
-                self._record_token(i, t, already_committed=True,
-                                   device_synced=True)
-            # manager bookkeeping: seq_tokens ← tokens that are committed or
-            # pending-with-reserved-block (stop/length-trigger excluded, as in
-            # the per-step path)
-            commit = toks if s.finish_reason is None else toks[:-1]
-            self.manager.commit_tokens(s.seq_id, commit)
-            self._maybe_release_window(i)
-        return out
+        return active_mask, (core, tables, act_d, bud_d), self._decode_mode()
 
     def finish_slot(self, slot: int, cache: bool = True) -> InferenceResponse:
         s = self.slots[slot]
@@ -3359,6 +3446,10 @@ class TPUEngine:
         out = dict(self.stats)
         out["kv_cache"] = self.manager.get_stats()
         out["active_slots"] = self.num_active
+        # XLA compile requests of the PROCESS (one log for all engines): a
+        # worker with no warm-up compiles inside requests, and this shows it
+        out["compiles"] = self._compile_log.count
+        out["compile_s"] = self._compile_log.seconds
         if self.cfg.speculative is not None:
             drafted = out.get("spec_drafted", 0)
             slot_steps = out.get("spec_slot_steps", 0)

@@ -21,6 +21,12 @@ Worker-side events ship to the control plane through the existing result
 payload (``result["timeline"]``) and heartbeat (``engine_stats["flight"]``)
 channels; ``server/flight_recorder.py`` merges the per-source lists into
 one causally-ordered timeline per trace.
+
+Per-ROUND spans (:func:`span`) are the other half: a request's timeline
+says when it was admitted and by which round (``round=<n>``); the round's
+``dgi.*`` spans say what the batcher and the engine did in it, on the
+profiler's clock, beside the device's ops. docs/observability.md has the
+table of both.
 """
 
 from __future__ import annotations
@@ -85,6 +91,68 @@ class _NullTimeline:
 
 
 NULL_TIMELINE = _NullTimeline()
+
+
+# ---------------------------------------------------------------------------
+# round spans: the profiler's annotation + a time-busy counter
+# ---------------------------------------------------------------------------
+
+# jax.profiler.TraceAnnotation, looked up at the first span: this module is
+# imported by the JAX-free control plane, which opens no span. None = not
+# looked up yet, False = JAX is absent (spans then only count time).
+_annotation: Any = None
+
+
+def _annotation_class() -> Any:
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class span:
+    """``with span("dgi.<layer>.<what>", stats, key, **attrs) as sp:``
+
+    Opens a ``jax.profiler.TraceAnnotation`` — while a profiler session
+    runs (the benchmark's traced slice, an operator's capture) the span
+    lands in the host plane of the same ``.xplane.pb``, on the same clock,
+    as the device's ops; with no session it is a flag check — and on exit
+    adds the elapsed ``time.perf_counter()`` seconds to ``stats[key]``
+    when both are given (a time-busy counter, always on). The span's parent
+    is the span open on its thread when it starts; spans of one round on
+    different threads carry the same ``round=<n>``. ``sp.set(**attrs)``
+    adds attributes found out while the span is open. Like the timelines,
+    a span never fails what it measures: JAX absent means counters only."""
+
+    __slots__ = ("_note", "_stats", "_key", "_t0")
+
+    def __init__(self, name: str, stats: Optional[Dict[str, Any]] = None,
+                 key: Optional[str] = None, **attrs: Any) -> None:
+        cls = _annotation_class()
+        self._note = cls(name, **attrs) if cls else None
+        self._stats = stats if key is not None else None
+        self._key = key
+
+    def set(self, **attrs: Any) -> None:
+        if self._note is not None:
+            self._note.set_metadata(**attrs)
+
+    def __enter__(self) -> "span":
+        if self._note is not None:
+            self._note.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        if self._stats is not None:
+            self._stats[self._key] = self._stats.get(self._key, 0.0) + (
+                time.perf_counter() - self._t0)
+        if self._note is not None:
+            self._note.__exit__(*exc)
 
 
 def _safe_attrs(attrs: Dict[str, Any]) -> Optional[Dict[str, Any]]:

@@ -10,9 +10,11 @@ Behavioral parity with the reference's ``server/app/services/observability.py``:
 - ``MetricsCollector`` facade (:255-405), ``/metrics`` text endpoint factory
   (:410-450), ``StructuredLogger`` with bound context (:455-488).
 
-TPU additions: ``tpu_profiler_trace`` context manager wraps
-``jax.profiler.trace`` for on-device timeline capture, and memory gauges read
-HBM (device memory stats) instead of nvidia-smi.
+TPU additions: memory gauges read HBM (device memory stats) instead of
+nvidia-smi. Device timelines are not this module's: the batcher and the
+engine put their own round spans on the profiler's clock
+(``runtime/flight.py`` ``span``; docs/observability.md, "Round spans and
+counters", says how to capture a trace on a live worker).
 """
 
 from __future__ import annotations
@@ -97,7 +99,10 @@ class Metrics:
                 "batcher_occupancy", "batcher_horizon",
                 "batcher_decode_rounds", "batcher_completed",
                 "batcher_chunked_admissions", "batcher_preemptions",
-                "batcher_migrated",
+                "batcher_migrated", "batcher_round_gaps",
+                "batcher_loop_seconds", "batcher_scans",
+                "engine_round_seconds", "worker_compiles",
+                "worker_compile_seconds",
                 "prefix_route_hits", "prefix_route_spillover",
                 "prefix_summary_entries", "prefix_summary_age",
                 "heartbeat_payload_rejected",
@@ -256,6 +261,43 @@ class Metrics:
             "batcher_requests_migrated_total",
             "In-flight requests frozen into checkpoints on graceful "
             "drain", ["worker"], registry=r)
+        # round spans' counters (runtime/flight.py span): the host's time
+        # around the engine's rounds, over the worker's whole life where a
+        # profiler trace shows a slice. seconds{part=between_rounds} over
+        # batcher_between_rounds_total is the mean gap from one round's
+        # end to the next one's start; admit and deliver split it.
+        self.batcher_round_gaps = Counter(
+            "batcher_between_rounds_total",
+            "Gaps between two engine rounds with work owned throughout",
+            ["worker"], registry=r)
+        self.batcher_loop_seconds = Counter(
+            "batcher_loop_seconds_total",
+            "Seconds of the batcher loop by part: between_rounds (one "
+            "round's end to the next one's start), and the loop's admit "
+            "and deliver steps, which split it in ragged mode (in chunked, "
+            "wave and speculative modes admit includes engine dispatches)",
+            ["worker", "part"], registry=r)
+        self.batcher_scans = Counter(
+            "batcher_scans_total",
+            "decode_multi rounds dispatched, by scan length (the horizon "
+            "levels the traffic reached)", ["worker", "steps"], registry=r)
+        # readback is the engine thread waiting for the device; its share
+        # of the four says whether the host or the chip bounds the rounds
+        self.engine_round_seconds = Counter(
+            "engine_round_seconds_total",
+            "Engine-thread seconds inside plain rounds, by phase (build, "
+            "dispatch, readback = the wait for the device, commit)",
+            ["worker", "phase"], registry=r)
+        # a rise after start-up means a request met a shape nothing warmed
+        # and waited for the compiler inside its round
+        self.worker_compiles = Counter(
+            "worker_compiles_total",
+            "XLA compile requests of the worker's process", ["worker"],
+            registry=r)
+        self.worker_compile_seconds = Counter(
+            "worker_compile_seconds_total",
+            "Seconds of the worker's XLA compile requests", ["worker"],
+            registry=r)
         # cache-aware routing (round 7): hits = placements that landed on
         # a worker advertising the request's prefix; spillover = requests
         # whose warmest worker was passed over (load headroom scaling or
@@ -499,7 +541,7 @@ class MetricsCollector:
         # monotonic totals, Prometheus counters advance by deltas
         self._spec_prev: Dict[str, Dict[str, int]] = {}
         self._pressure_prev: Dict[str, Dict[str, int]] = {}
-        self._batcher_prev: Dict[str, Dict[str, int]] = {}
+        self._batcher_prev: Dict[str, Dict[str, float]] = {}
         self._pd_prev: Dict[str, Dict[str, int]] = {}
         self._kvmig_prev: Dict[str, Dict[str, int]] = {}
         self._kvspill_prev: Dict[str, Dict[str, int]] = {}
@@ -659,6 +701,34 @@ class MetricsCollector:
             delta = cur - prev.get(key, 0)
             if delta > 0:
                 metric.labels(worker).inc(delta)
+            prev[key] = cur
+        # round spans' counters: seconds are floats, scan counts carry their
+        # level in the key (``scans_t<T>``); same delta anchoring
+        for key, value in stats.items():
+            if key in ("between_rounds_s", "admit_s", "deliver_s"):
+                metric = self.metrics.batcher_loop_seconds.labels(
+                    worker, key[:-2])
+            elif key in ("round_build_s", "round_dispatch_s",
+                         "round_readback_s", "round_commit_s"):
+                metric = self.metrics.engine_round_seconds.labels(
+                    worker, key[6:-2])
+            elif key == "compiles":
+                metric = self.metrics.worker_compiles.labels(worker)
+            elif key == "compile_s":
+                metric = self.metrics.worker_compile_seconds.labels(worker)
+            elif key == "between_rounds":
+                metric = self.metrics.batcher_round_gaps.labels(worker)
+            elif key.startswith("scans_t") and key[7:].isdigit():
+                metric = self.metrics.batcher_scans.labels(worker, key[7:])
+            else:
+                continue
+            try:
+                cur = float(value or 0.0)
+            except (TypeError, ValueError):
+                continue
+            delta = cur - prev.get(key, 0)
+            if delta > 0:
+                metric.inc(delta)
             prev[key] = cur
         if "abandoned" in stats:
             # deadline-abandonment (round 18): hopeless slots the batcher
@@ -1095,21 +1165,6 @@ class TracingManager:
             sp.end(end_time=int(float(end_s) * 1e9))
         except Exception:  # noqa: BLE001 — advisory by contract
             pass
-
-
-@contextlib.contextmanager
-def tpu_profiler_trace(log_dir: str = "/tmp/dgi_tpu_profile") -> Iterator[None]:
-    """Wrap a region in a jax.profiler trace (TPU timeline capture).
-
-    No-op when jax is unavailable; safe to leave in production paths.
-    """
-    try:
-        import jax
-
-        with jax.profiler.trace(log_dir):
-            yield
-    except Exception:  # noqa: BLE001 — profiling must never break serving
-        yield
 
 
 # ---------------------------------------------------------------------------

@@ -11,9 +11,10 @@ something the operator asks for (``JAX_PLATFORMS=cpu`` / ``--platform cpu``).
 from __future__ import annotations
 
 import os
+import threading
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Optional
+from typing import Any, Dict, List, Optional
 
 _CHECKOUT = Path(__file__).resolve().parents[2]
 
@@ -96,3 +97,58 @@ def enable_compile_cache() -> str:
         if option.upper() not in os.environ:
             jax.config.update(option, keep_all)
     return directory
+
+
+class CompileLog:
+    """Every XLA compile request of the process: how many (``count``), their
+    seconds (``seconds``) and, in ``rows``, each one's jitted function, its
+    seconds and what the persistent cache did with it (``hit`` = loaded,
+    nothing compiled; ``miss`` = compiled and stored). ``jax.monitoring``
+    listeners cannot be taken off again, so a process has one log
+    (:func:`compile_log`); ``rows`` stops growing at :data:`ROWS_KEPT`, the
+    totals do not."""
+
+    ROWS_KEPT = 4096
+
+    def __init__(self) -> None:
+        from jax import monitoring
+
+        self.count = 0
+        self.seconds = 0.0
+        self.rows: List[Dict[str, Any]] = []
+        self._outcome: Dict[int, str] = {}
+        self._lock = threading.Lock()    # compiles come from any thread
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def _on_event(self, name: str, **_: Any) -> None:
+        if name.endswith("/cache_hits"):
+            self._outcome[threading.get_ident()] = "hit"
+        elif name.endswith("/cache_misses"):
+            self._outcome[threading.get_ident()] = "miss"
+
+    def _on_duration(self, name: str, secs: float, **kw: Any) -> None:
+        if name != "/jax/core/compile/backend_compile_duration":
+            return
+        cache = self._outcome.pop(threading.get_ident(), "uncached")
+        with self._lock:
+            self.count += 1
+            self.seconds += secs
+            if len(self.rows) < self.ROWS_KEPT:
+                self.rows.append({"fn": str(kw.get("fun_name")),
+                                  "secs": secs, "cache": cache})
+
+
+_compile_log: Optional[CompileLog] = None
+_compile_log_lock = threading.Lock()
+
+
+def compile_log() -> CompileLog:
+    """The process's one :class:`CompileLog`, started at the first call
+    (every ``TPUEngine`` makes it, so a serving process counts from its
+    first engine on)."""
+    global _compile_log
+    with _compile_log_lock:
+        if _compile_log is None:
+            _compile_log = CompileLog()
+        return _compile_log

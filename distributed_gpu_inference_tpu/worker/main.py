@@ -478,6 +478,23 @@ class Worker:
                 out[k] = out.get(k, 0) + int(s.get(k, 0) or 0)
             for k in ("queue_depth", "active_slots"):
                 out[k] = out.get(k, 0) + int(s.get(k, 0) or 0)
+            # round spans' counters (runtime/flight.py span): the loop's
+            # seconds outside engine rounds, the scans by level, and from
+            # the engine the seconds of each phase of a round and the
+            # compile requests (the process's: not summed over engines;
+            # after start they rise only at a shape nothing warmed)
+            core = getattr(eng, "engine", None)
+            es = core.get_stats() if core is not None else {}
+            for k in ("compiles", "compile_s"):
+                out[k] = max(out.get(k, 0), round(es.get(k, 0), 3))
+            for k, src in (("between_rounds_s", s), ("admit_s", s),
+                           ("deliver_s", s), ("round_build_s", es),
+                           ("round_dispatch_s", es),
+                           ("round_readback_s", es), ("round_commit_s", es)):
+                out[k] = round(out.get(k, 0.0) + float(src.get(k, 0) or 0), 6)
+            for k in s:
+                if k == "between_rounds" or k.startswith("scans_t"):
+                    out[k] = out.get(k, 0) + int(s[k] or 0)
             if s.get("avg_occupancy") is not None:
                 out["avg_occupancy"] = round(
                     float(s.get("avg_occupancy") or 0.0), 3

@@ -210,11 +210,11 @@ def test_forward_chunk_compiles_one_chip(v5e, tpu_dispatch, s):
     if s == 1:
         # a decode step: fused write+attend, projections through the int8
         # kernel
-        assert found == {"_decode_kernel", "_qmm_kernel"}, found
+        assert found == {"dgi_paged_decode", "dgi_qmm"}, found
     else:
         # a ragged round at the full chunk: 8 x 256 rows is past the int8
         # kernel's bandwidth-bound regime, so projections take the XLA path
-        assert found == {"_ragged_kernel"}, found
+        assert found == {"dgi_ragged_attention"}, found
     lowered.compile()
 
 
