@@ -247,9 +247,12 @@ def _moe_mlp(
     axis over ``model``, each chip runs only its local experts for all
     tokens and XLA inserts the combine all-reduce: expert parallelism
     without hand-written all-to-all (the TPU answer to SURVEY §2.2's
-    "EP: ABSENT"). Single-chip cost is E/k times the active-path FLOPs —
-    acceptable at serving batch sizes; a ragged/blocked Pallas dispatch is
-    the designated upgrade path.
+    "EP: ABSENT"). Single-chip cost is E/k times the active-path FLOPs
+    of the ``T`` tokens it is given: ``T`` is ``B x S`` of a rectangle
+    chunk, the packed ``Tp`` of a plain ragged round (``forward_chunk``
+    ``packing``), so there it shrinks with the round's live tokens. A
+    ragged/blocked Pallas dispatch over the routed tokens is the
+    designated upgrade path.
     """
     act = jax.nn.silu if cfg.activation == "silu" else functools.partial(
         jax.nn.gelu, approximate=True
@@ -319,6 +322,22 @@ class ChunkOutput(NamedTuple):
     features: Optional[jax.Array] = None
 
 
+class Packing(NamedTuple):
+    """A ragged round's live tokens on ONE flat axis of ``Tp`` entries, and
+    where each sits in the ``[B, S]`` rectangle that the page write and
+    the attention kernels take. The dense work of a layer (norms,
+    projections, MLP or experts, the residual stream) runs over ``[Tp,
+    H]``; q/k/v are laid out into the rectangle for attention and its
+    output is read back onto the packed axis. Built by the engine's host
+    code (``TPUEngine._build_plain_ragged``); ``S`` is a function of
+    ``Tp`` alone, so there is one graph per ``Tp``."""
+
+    row: jax.Array    # [Tp] int32 sequence row of each token; B = padding
+    col: jax.Array    # [Tp] int32 column of each token in its row's chunk
+    last: jax.Array   # [B] int32 packed index of each row's last token
+    width: int        # S, static
+
+
 def _layer_step(
     cfg: ModelConfig,
     block_size: int,
@@ -336,6 +355,10 @@ def _layer_step(
     dense_attn_fn=None,           # (q, k, v dense chunk) → attn; see below
     emit_hidden: bool = False,    # scan-emit this layer's hidden (features)
     pallas: bool = True,          # False: projections stay on the XLA path
+    unpack: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+                                  # packed chunk (forward_chunk ``packing``):
+                                  # (rectangle → packed index [B, S], row
+                                  # [Tp], col [Tp])
 ) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array], Optional[jax.Array]]:
     """One transformer layer over paged KV — shared by the causal decode path
     and the speculative tree-verify path (they differ only in the attention
@@ -359,7 +382,13 @@ def _layer_step(
     sequence-parallel entry: the engine passes ring/Ulysses attention
     (``parallel/ring_attention.py``) here so a long prompt's attention
     spreads over the ``seq`` mesh axis while KV pages still land in the
-    same paged pools decode reads (SURVEY §5.7)."""
+    same paged pools decode reads (SURVEY §5.7).
+
+    ``unpack``: ``hidden`` is ``[1, Tp, H]``, a round's live tokens packed
+    on one axis. q/k/v are gathered into the ``[B, S]`` rectangle (empty
+    positions zero, their ``write_positions`` -1) for the page write and
+    attention exactly as an unpacked chunk has them, and the attention
+    output is gathered back; everything else runs over ``Tp`` rows."""
     hidden, k_ent, v_ent, layer_idx = carry
     # int8-KV pools travel as (pool, scale_pool) tuples through the scan
     # carry; bf16 pools stay bare arrays (static structure, zero overhead)
@@ -394,6 +423,12 @@ def _layer_step(
         v = v.reshape(b, s, nkv, d)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+        if unpack is not None:
+            to_rect, tok_row, tok_col = unpack
+            q, k, v = (
+                jnp.take(t[0], to_rect, axis=0, mode="fill", fill_value=0)
+                for t in (q, k, v)
+            )                               # [B, S, heads, D] from here on
 
         if fused_decode:
             from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
@@ -438,10 +473,10 @@ def _layer_step(
                 layer_v = _write_kv_pages(
                     layer_v, v_q, block_tables, write_positions, block_size)
                 layer_ks = _write_scale_pages(
-                    layer_ks, jnp.broadcast_to(k_s[:, :, 0, :], (b, s, d)),
+                    layer_ks, jnp.broadcast_to(k_s[:, :, 0, :], (*k.shape[:2], d)),
                     block_tables, write_positions, block_size)
                 layer_vs = _write_scale_pages(
-                    layer_vs, jnp.broadcast_to(v_s[:, :, 0, :], (b, s, d)),
+                    layer_vs, jnp.broadcast_to(v_s[:, :, 0, :], (*v.shape[:2], d)),
                     block_tables, write_positions, block_size)
                 k_scale_pool = lax.dynamic_update_index_in_dim(
                     k_scale_pool, layer_ks, layer_idx, 0)
@@ -474,6 +509,8 @@ def _layer_step(
             else:
                 attn = attn_fn(q, layer_k, layer_v)
 
+        if unpack is not None:
+            attn = attn.at[tok_row, tok_col].get(mode="fill", fill_value=0)
         hidden = hidden + proj(attn.reshape(b, s, nh * d), "wo").astype(hidden.dtype)
     with jax.named_scope("dgi_experts" if "w_router" in lp else "dgi_mlp"):
         mlp_in = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
@@ -523,6 +560,12 @@ def forward_chunk(
                           # only, breaking the all-reduce-max scale contract
                           # (parallel/sharding.py). Dispatch cannot see the
                           # mesh from inside a trace, so the caller says it.
+    packing: Optional[Packing] = None,
+                          # the chunk's live tokens on one flat axis:
+                          # token_ids and positions are [Tp] (padding at
+                          # position -1, row B), and the dense work runs
+                          # over Tp rows instead of B x S. last_only reads
+                          # each row's last token from the packed axis.
 ) -> ChunkOutput:
     """Run S tokens per sequence through all layers against the paged cache.
 
@@ -533,11 +576,33 @@ def forward_chunk(
     intermediate chunk of a long prefill only needs its KV side effects, and
     the head matmul reads the full [V, H] embedding from HBM (0.77 GB on
     Llama-3 vocab) for logits nobody consumes.
+
+    ``packing`` (the plain ragged round): same per-token mathematics with
+    the padding of the ``[B, S]`` rectangle left out of everything but the
+    page write and attention — a token's row through a matmul does not
+    depend on which other rows share the call. ``hidden`` comes back
+    ``[1, Tp, H]``.
     """
+    unpack = None
+    if packing is not None:
+        tp = token_ids.shape[0]
+        rect = (block_tables.shape[0], packing.width)
+        at = (packing.row, packing.col)
+        # the rectangle's own view of the round, built once for all layers:
+        # where each of its positions reads from on the packed axis (Tp =
+        # nothing there) and the position it holds (-1 = nothing to write)
+        to_rect = jnp.full(rect, tp, jnp.int32).at[at].set(
+            jnp.arange(tp, dtype=jnp.int32), mode="drop")
+        unpack = (to_rect, *at)
+        token_ids, rope_positions = token_ids[None], positions[None]
+        positions = jnp.full(rect, -1, jnp.int32).at[at].set(
+            positions, mode="drop")
+    else:
+        rope_positions = positions
     b, s = token_ids.shape
     hidden = embed_tokens(params, token_ids, cfg)
 
-    safe_pos = jnp.maximum(positions, 0)
+    safe_pos = jnp.maximum(rope_positions, 0)
     cos, sin = _rope_angles(safe_pos, cfg.head_dim, cfg.rope_theta)
 
     quant_kv = "k_scale" in kv
@@ -574,12 +639,14 @@ def forward_chunk(
             and _use_fused_decode(cfg, s, block_tables, block_size)
             and dense_attn_fn is None
             and attn_override is None
+            and packing is None
         ),
         kv_lens=kv_lens,
         stacked=stacked,
         dense_attn_fn=dense_attn_fn,
         emit_hidden=collect_layers is not None,
         pallas=pallas,
+        unpack=unpack,
     )
     k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
     v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
@@ -603,7 +670,9 @@ def forward_chunk(
             hidden=hidden, kv=new_kv, logits=None,
             features=features,
         )
-    if last_only:
+    if last_only and packing is not None:
+        logits_in = jnp.take(hidden[0], packing.last, axis=0)[:, None]
+    elif last_only:
         # last valid token per sequence = kv_lens - 1 mapped into the chunk:
         # chunk covers positions [kv_len - n_valid, kv_len); the last valid
         # chunk index is (number of valid positions in chunk) - 1.

@@ -1422,8 +1422,10 @@ class ContinuousBatcher:
             # so a request reactive mode would carry to its deadline and
             # then drop is dropped now, before burning the rounds
             return False
-        # observed inter-token latency; floor at 1ms so a cold EMA (no
-        # rounds yet) still projects SOME forward progress instead of 0
+        # observed latency of a decode scan (ragged rounds are left out of
+        # the EMA: ``_run``), read as the inter-token latency; floor at 1ms
+        # so a cold EMA (no scan yet) still projects SOME forward progress
+        # instead of 0
         itl_s = max(float(self.stats["step_latency_ema_ms"]), 1.0) / 1000.0
         return now + tokens_left * itl_s > \
             deadline_at + self.cfg.deadline_grace_s
@@ -1715,6 +1717,8 @@ class ContinuousBatcher:
                     await asyncio.sleep(0.001)
                 continue
             try:
+                # read before the round: its final chunks leave _ragged below
+                scan = not self._ragged
                 latency = await loop.run_in_executor(
                     self._exec, self._engine_round
                 )
@@ -1723,7 +1727,12 @@ class ContinuousBatcher:
                     finished = 0
                     self.stats["decode_rounds"] += 1
                     self.stats["occupancy_sum"] += self.engine.num_active
-                    self._retune(latency)
+                    # only a scan's latency steers the scan level: a
+                    # ragged round is as long as the prompt tokens it
+                    # admits, whatever the level, so it says nothing
+                    # about how long a scan may be
+                    if scan:
+                        self._retune(latency)
                     # admission-chunk rounds on the timeline: one bounded
                     # note per in-flight traced admission per round
                     # (saturates at the per-request event cap on

@@ -161,12 +161,12 @@ class EngineConfig:
     # token per step. Single-chip only (no mesh).
     speculative: Optional[SpecDecodeConfig] = None
     # RAGGED rounds (round 6): max prefill-chunk width co-dispatched with
-    # decode rows in one ragged_round() invocation. Bounds the dense
-    # (non-attention) compute padding of the [B, S] round graph — decode
-    # rows carry 1 live token out of S, so a wider chunk trades fewer
-    # admission rounds against more masked matmul work per round. Clamped
-    # to the largest prefill bucket; widths bucket through prefill_buckets
-    # so the compiled round-graph count stays logarithmic.
+    # decode rows in one ragged_round() invocation: the most prompt tokens
+    # one admission adds to a round, so the longest a co-scheduled stream
+    # waits for its next token. Clamped to the largest prefill bucket. The
+    # plain round runs its dense work over the live tokens packed on one
+    # axis (``TPUEngine._ragged_ladder``: five lengths, one graph each);
+    # the [B, S] rectangle attention sees buckets through prefill_buckets.
     ragged_chunk: int = 256
 
     @property
@@ -1034,22 +1034,30 @@ class TPUEngine:
 
         # --- RAGGED round (round 6): ONE dispatch in which decode rows
         # (1 live token each, at position lens) and admission prefill-chunk
-        # rows (up to S live tokens) coexist — per-row positions/-1 padding
-        # select each row's path, and on TPU the attention inside
-        # forward_chunk dispatches to the ragged paged-attention kernel
-        # (ops.attention.resolve_impl → "ragged" for S > 1). Admission
-        # stops being a competing dispatch: appending a chunk row to the
-        # next round IS the admission. Per-row token math is identical to
-        # the split paths (decode rows ≡ decode_multi's step, chunk rows ≡
-        # _prefill_chunk_fn), so greedy outputs are byte-identical and
-        # seeded sampling is stable (the sampler folds the absolute
-        # position, which is per-row here exactly as there).
-        def ragged_round(params, kv, toks_pos, tables, lens_after, core,
-                         sample_flag, mode):
+        # rows (up to S live tokens) coexist. Admission stops being a
+        # competing dispatch: appending a chunk row to the next round IS
+        # the admission. The round's live tokens arrive PACKED on one axis
+        # of Tp entries (``tok_at``: token id, position, row, column;
+        # padding at row B, position -1): the dense work runs over Tp rows,
+        # and forward_chunk lays q/k/v out into the [B, width] rectangle
+        # only for the page write and attention (on TPU the ragged
+        # paged-attention kernel, ops.attention.resolve_impl → "ragged";
+        # per-row positions / -1 padding select each row's path there).
+        # Per-token math is identical to the split paths (decode rows ≡
+        # decode_multi's step, chunk rows ≡ _prefill_chunk_fn), so greedy
+        # outputs are byte-identical and seeded sampling is stable (the
+        # sampler folds the absolute position, which is per-row here
+        # exactly as there). ``width`` is ``_ragged_shape``'s function of
+        # Tp: one graph per Tp.
+        def ragged_round(params, kv, tok_at, tables, lens_last, core,
+                         sample_flag, mode, width):
+            lens_after = lens_last[0]
             out = fwd(
-                cfg, params, toks_pos[0], toks_pos[1], kv, tables,
+                cfg, params, tok_at[0], tok_at[1], kv, tables,
                 lens_after, block_size=bs, last_only=True,
                 attn_override=chunk_attn_override,
+                packing=llama.Packing(tok_at[2], tok_at[3], lens_last[1],
+                                      width),
             )
             toks = sample_mode(
                 out.logits[:, 0, :], core["keys"], lens_after,
@@ -1065,7 +1073,8 @@ class TPUEngine:
             return out.kv, core, toks
 
         self._ragged_round_fn = jax.jit(
-            ragged_round, static_argnames=("mode",), donate_argnums=(1, 5),
+            ragged_round, static_argnames=("mode", "width"),
+            donate_argnums=(1, 5),
         )
 
         # --- integrated speculative decoding: R fused draft→verify→accept
@@ -1441,29 +1450,36 @@ class TPUEngine:
     ) -> Dict[str, Any]:
         """The batcher's two round graphs, lowered from the engine's own
         jitted functions with the operands a round passes them:
-        ``decode_multi`` at each scan length and ``ragged_round`` at each
-        chunk width, all-greedy. What a graph will run is read from the
-        lowered text (``kernel_name = "..."`` marks a Pallas call), and
-        ``.compile()`` on an entry stores the program in the persistent
-        compile cache, where the first real round finds it. Plain
-        (non-speculative) engines; call while no round is in flight."""
+        ``decode_multi`` at each scan length and ``ragged_round`` at every
+        packed length a round can reach while its widest prompt piece is
+        within ``max(ragged_widths)`` (concurrent admissions included: up
+        to ``max_batch_size`` rows of that width), all-greedy. What a
+        graph will run is read from the lowered text (``kernel_name =
+        "..."`` marks a Pallas call), and ``.compile()`` on an entry
+        stores the program in the persistent compile cache, where the
+        first real round finds it. Plain (non-speculative) engines; call
+        while no round is in flight."""
         b = len(self.slots)
         core = self._sync_core()
         tables, active, budgets = self._sched_arrays(
             np.zeros((b,), bool), np.zeros((b,), np.int32)
         )
-        lens = jnp.zeros((b,), jnp.int32)
         out: Dict[str, Any] = {}
         for t in decode_steps:
             out[f"decode_multi[T={t}]"] = self._decode_multi_fn.lower(
                 self.params, self.kv, core, tables, active, budgets,
                 int(t), "greedy",
             )
-        for w in ragged_widths:
-            out[f"ragged_round[S={w}]"] = self._ragged_round_fn.lower(
-                self.params, self.kv, np.zeros((2, b, int(w)), np.int32),
-                tables, lens, core, budgets, "greedy",
+        most, below = b * max(map(int, ragged_widths), default=0), 0
+        for tp in self._ragged_ladder():
+            if most <= below:       # the rung below takes every such round
+                break
+            out[f"ragged_round[Tp={tp}]"] = self._ragged_round_fn.lower(
+                self.params, self.kv, np.zeros((4, tp), np.int32), tables,
+                jnp.zeros((2, b), jnp.int32), core, budgets, "greedy",
+                self._ragged_shape(tp)[1],
             )
+            below = tp
         return out
 
     def _decode_mode(self) -> str:
@@ -2315,7 +2331,13 @@ class TPUEngine:
         self, admissions: Sequence[ChunkedAdmission] = (),
         chunk_caps: Optional[Dict[int, int]] = None,
     ) -> Dict[int, List[int]]:
-        """``chunk_caps``: optional per-admission prefill-token caps for
+        """One dispatch: every decoding slot advances a token and every
+        admission a prompt piece. The plain round packs those live tokens
+        on one axis of ``Tp`` entries, the ladder rung that takes them
+        (``_ragged_shape``), so its dense work costs what the round holds;
+        the speculative round keeps the ``[B, S]`` rectangle.
+
+        ``chunk_caps``: optional per-admission prefill-token caps for
         THIS round, keyed by slot (the scheduler's per-round prefill
         budget — PR 17). A missing slot gets the full ``ragged_chunk``
         cap; a cap <= 0 skips the admission this round entirely (no row,
@@ -2330,13 +2352,40 @@ class TPUEngine:
                 return self._spec_ragged_round(admissions, chunk_caps, sp)
             return self._plain_ragged_round(admissions, chunk_caps, sp)
 
-    def _count_ragged(self, sp: flight.span, bucket: int, decode_rows: int,
-                      decode_tokens: int, ready: Sequence[Any]) -> None:
-        """What one ragged rectangle held, onto the round's span and into
-        the counters: ``positions`` = rows x bucket dispatched, of which
-        the decode rows' tokens and the admission pieces are live."""
+    def _ragged_chunk_cap(self) -> int:
+        """The most prompt tokens one admission runs in one ragged round."""
+        return min(max(int(self.cfg.ragged_chunk), 1),
+                   self.cfg.prefill_buckets[-1])
+
+    def _ragged_ladder(self) -> List[int]:
+        """The packed lengths ``Tp`` a plain ragged round runs at, one
+        graph each: a quarter and a half of a piece for decode rows beside
+        a short piece, one full piece beside every other row decoding, two
+        of those, and every row a full piece (the ``[B, S]`` rectangle at
+        its widest). On the v5e a round costs ~21 ms whatever it holds
+        (PERF.md section 5), so finer rungs at the short end buy little."""
+        b, cap = len(self.slots), self._ragged_chunk_cap()
+        one = -(-(cap + b - 1) // 8) * 8
+        return sorted({min(max(t, 8), b * cap)
+                       for t in (cap // 4, cap // 2, one, 2 * one, b * cap)})
+
+    def _ragged_shape(self, live: int) -> Tuple[int, int]:
+        """(``Tp``, ``S``) of the plain ragged round holding ``live``
+        tokens: the ladder's first rung that takes them, and the width of
+        the rectangle attention sees, a function of ``Tp`` alone (a piece
+        is at most ``ragged_chunk`` tokens, and at most ``Tp``)."""
+        tp = next(t for t in self._ragged_ladder() if t >= live)
+        return tp, self._bucket_len(min(tp, self._ragged_chunk_cap()))
+
+    def _count_ragged(self, sp: flight.span, bucket: int, positions: int,
+                      decode_rows: int, decode_tokens: int,
+                      ready: Sequence[Any]) -> None:
+        """What one ragged round held, onto the round's span and into the
+        counters. ``positions`` is what the dense work ran over: the
+        packed length ``Tp`` of a plain round (``bucket`` is ``Tp`` too),
+        rows x bucket of a spec round's rectangle. Of them the decode
+        rows' tokens and the admission pieces are live."""
         live_prompt = sum(len(piece) for _, piece, _ in ready)
-        positions = len(self.slots) * bucket
         sp.set(bucket=bucket, decode_rows=decode_rows,
                admission_rows=len(ready), live_prompt_tokens=live_prompt,
                positions=positions)
@@ -2386,10 +2435,12 @@ class TPUEngine:
 
     def _fill_ragged_admission_rows(
         self, ready, toks_pos: np.ndarray, lens_after: np.ndarray,
-        sample_flag: np.ndarray, row_mask: Optional[np.ndarray] = None,
+        sample_flag: np.ndarray,
     ) -> bool:
-        """Write the admission chunk rows into a ragged round's host
-        batch arrays; True when any admission samples non-greedily."""
+        """Write the admission chunk rows into the spec ragged round's
+        ``[B, S]`` host batch arrays (the plain round packs its own:
+        ``_build_plain_ragged``); True when any admission samples
+        non-greedily."""
         mixed = False
         for adm, piece, is_last in ready:
             sl, n = adm.slot, len(piece)
@@ -2397,8 +2448,6 @@ class TPUEngine:
             toks_pos[1, sl, :n] = np.arange(adm.off, adm.off + n)
             lens_after[sl] = adm.off + n
             sample_flag[sl] = 1 if is_last else 0
-            if row_mask is not None:
-                row_mask[sl] = True
             if adm.mode != "greedy":
                 mixed = True
         return mixed
@@ -2444,19 +2493,19 @@ class TPUEngine:
         final chunk is NOT consumed and retries next round). Returns
         {slot: [token]} for every row that sampled. Admissions are mutated
         in place; ``adm.done`` flips when the first token lands. ``sp`` is
-        the round's open span (``ragged_round``): it gets what the
-        rectangle held, and the four phases nest inside it."""
+        the round's open span (``ragged_round``): it gets what the round
+        held, and the four phases nest inside it."""
         with flight.span("dgi.engine.ragged_round.build", self.stats,
                          "round_build_s"):
             built = self._build_plain_ragged(admissions, chunk_caps, sp)
         if built is None:
             return {}
-        kept, ready, operands, mode = built
+        kept, ready, operands, mode, width = built
         with flight.span("dgi.engine.ragged_round.dispatch", self.stats,
                          "round_dispatch_s"):
             try:
                 self.kv, self._dev_core, toks = self._ragged_round_fn(
-                    self.params, self.kv, *operands, mode,
+                    self.params, self.kv, *operands, mode, width,
                 )
             except Exception:
                 self._invalidate_device_state()
@@ -2487,18 +2536,17 @@ class TPUEngine:
     def _build_plain_ragged(
         self, admissions: Sequence[ChunkedAdmission],
         chunk_caps: Optional[Dict[int, int]], sp: flight.span,
-    ) -> Optional[Tuple[List[int], List[Any], Tuple[Any, ...], str]]:
+    ) -> Optional[Tuple[List[int], List[Any], Tuple[Any, ...], str, int]]:
         """The host's half of a plain ragged round before the dispatch:
-        block reservation, the row batch, pending pool ops, the uploads.
-        None when no row is left to run."""
+        block reservation, the packed token batch, pending pool ops, the
+        uploads. None when no row is left to run."""
         admissions = [a for a in admissions if not a.done]
         for adm in admissions:
             s = self.slots[adm.slot]
             if s is None or s.seq_id != adm.seq_id:
                 raise RuntimeError("ragged admission slot was freed")
         b = len(self.slots)
-        max_bucket = self.cfg.prefill_buckets[-1]
-        chunk_cap = min(max(int(self.cfg.ragged_chunk), 1), max_bucket)
+        chunk_cap = self._ragged_chunk_cap()
 
         # --- decode rows: pre-reserve each pending token's block exactly
         # as decode_step does; exhaustion freezes the row (nothing decoded,
@@ -2530,35 +2578,49 @@ class TPUEngine:
 
         # --- admission chunk rows: shared slicing + final-chunk
         # pending-block pre-reservation (``_ragged_admission_rows``)
-        ready, width = self._ragged_admission_rows(admissions, chunk_cap,
-                                                   chunk_caps)
+        ready, _ = self._ragged_admission_rows(admissions, chunk_cap,
+                                               chunk_caps)
         if not kept and not ready:
             return None
 
         self._apply_pending()
-        s_w = self._bucket_len(width)
-        self._count_ragged(sp, s_w, len(kept), len(kept), ready)
-        toks_pos = np.zeros((2, b, s_w), np.int32)
-        toks_pos[1] = -1
-        lens_after = np.zeros((b,), np.int32)
+        # the round's live tokens on one axis, row after row: token id,
+        # position, row and column in the rectangle attention sees
+        # (padding: row b, which every scatter drops, at position -1)
+        live = len(kept) + sum(len(piece) for _, piece, _ in ready)
+        tp, s_w = self._ragged_shape(live)
+        self._count_ragged(sp, tp, tp, len(kept), len(kept), ready)
+        tok_at = np.zeros((4, tp), np.int32)
+        tok_at[1], tok_at[2] = -1, b
+        lens_last = np.zeros((2, b), np.int32)
         row_mask = np.zeros((b,), dtype=bool)
         sample_flag = np.zeros((b,), np.int32)
         mode = "greedy"
+        n = 0
         for i in kept:
-            toks_pos[0, i, 0] = self._last_tokens[i]
-            toks_pos[1, i, 0] = self._kv_lens[i]
-            lens_after[i] = self._kv_lens[i] + 1
+            tok_at[:, n] = (self._last_tokens[i], self._kv_lens[i], i, 0)
+            lens_last[:, i] = (self._kv_lens[i] + 1, n)
+            n += 1
             row_mask[i] = True
             sample_flag[i] = 1
             if self._temps[i] > 0:
                 mode = "mixed"
-        if self._fill_ragged_admission_rows(ready, toks_pos, lens_after,
-                                            sample_flag, row_mask):
-            mode = "mixed"
+        for adm, piece, is_last in ready:
+            sl, m = adm.slot, len(piece)
+            tok_at[0, n:n + m] = piece
+            tok_at[1, n:n + m] = np.arange(adm.off, adm.off + m)
+            tok_at[2, n:n + m] = sl
+            tok_at[3, n:n + m] = np.arange(m)
+            n += m
+            lens_last[:, sl] = (adm.off + m, n - 1)
+            row_mask[sl] = True
+            sample_flag[sl] = 1 if is_last else 0
+            if adm.mode != "greedy":
+                mode = "mixed"
         core = self._sync_core()
         tables, _act, flag_d = self._sched_arrays(row_mask, sample_flag)
-        return kept, ready, (toks_pos, tables, jnp.asarray(lens_after),
-                             core, flag_d), mode
+        return kept, ready, (tok_at, tables, jnp.asarray(lens_last),
+                             core, flag_d), mode, s_w
 
     def _spec_ragged_round(
         self, admissions: Sequence[ChunkedAdmission],
@@ -2587,8 +2649,7 @@ class TPUEngine:
             if s is None or s.seq_id != adm.seq_id:
                 raise RuntimeError("ragged admission slot was freed")
         b = len(self.slots)
-        max_bucket = self.cfg.prefill_buckets[-1]
-        chunk_cap = min(max(int(self.cfg.ragged_chunk), 1), max_bucket)
+        chunk_cap = self._ragged_chunk_cap()
 
         # --- verify rows: per-slot depth selection + worst-case
         # reservation (one round: up to K+1 fed tokens plus the
@@ -2655,7 +2716,7 @@ class TPUEngine:
         # bucket; wider chunk rows bucket as usual — the compiled width
         # set stays {K+1} ∪ buckets
         s_w = k + 1 if width <= k + 1 else self._bucket_len(width)
-        self._count_ragged(sp, s_w, int(spec_rows.sum()),
+        self._count_ragged(sp, s_w, b * s_w, int(spec_rows.sum()),
                            int((ks_sel[spec_rows] + 1).sum()), ready)
         toks_pos = np.zeros((2, b, s_w), np.int32)
         toks_pos[1] = -1

@@ -188,10 +188,14 @@ def served(tmp_path_factory):
 def test_counters_ragged_rectangle(served):
     e = served["engine"]
     assert 0 < e["ragged_positions_live"] <= e["ragged_positions_dispatched"]
-    # every ragged round dispatched rows x bucket positions: four rows, a
-    # bucket of 16, 32 or 64
-    assert e["ragged_positions_dispatched"] % (4 * 16) == 0
-    assert e["ragged_positions_dispatched"] >= 4 * 16 * e["ragged_rounds"]
+    # every ragged round dispatched its packed length Tp, a rung of the
+    # ladder for four rows and pieces of at most 64 tokens: what the dense
+    # work ran over, well under the four rows x 32 of the rectangle
+    rungs = [s["bucket"] for s in served["spans"]
+             if s["name"] == "dgi.engine.ragged_round"]
+    assert set(rungs) <= {16, 32, 72, 144, 256}
+    assert e["ragged_positions_dispatched"] == sum(rungs)
+    assert e["ragged_positions_dispatched"] < 4 * 32 * e["ragged_rounds"]
     # a live position is a decode row's token or a prompt token: all six
     # (unshared) prompts went through ragged rounds, the sixth beside the
     # two rows that were decoding
@@ -276,7 +280,7 @@ def test_xplane_holds_engine_spans_inside_their_batcher_round(
         assert s["steps"] == o["steps"] and o["level"] in served["levels"]
         assert s["positions"] > 0 and "queue_depth" in o
         if kind == "ragged":
-            assert s["positions"] == 4 * s["bucket"]
+            assert s["positions"] == s["bucket"]        # the packed length
             assert 0 < s["live_prompt_tokens"] + s["decode_rows"] \
                 <= s["positions"]
             assert s["admission_rows"] >= 1

@@ -16,6 +16,7 @@ the compiler refuses is a failure.
 
 import functools
 import importlib.util
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,11 +160,12 @@ def test_qmm_kernel_compiles(v5e, model):
 # (b) the whole serving graph, one chip and a model=4 mesh
 # --------------------------------------------------------------------- #
 
-def _forward_chunk_lowered(cfg, s, mesh, devices):
+def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None):
     """``forward_chunk`` for int8 ``cfg`` at [BATCH, s], lowered for one
     device (``mesh=None``) or sharded over ``mesh`` by the engine's own
     rules, with ``pallas`` set the way ``TPUEngine._build_jit_fns`` sets
-    it."""
+    it. ``tp``: the plain ragged round's form, ``tp`` live tokens packed
+    on one axis with [BATCH, s] the rectangle attention sees."""
     params = jax.eval_shape(
         lambda: quantize_params(
             llama.init_params(cfg, jax.random.PRNGKey(0)), "int8"
@@ -187,17 +189,24 @@ def _forward_chunk_lowered(cfg, s, mesh, devices):
     )
     sds = _on(rep)
 
-    def step(params, kv, toks, pos, tables, lens):
+    def step(params, kv, toks, pos, tables, lens, *where):
         out = llama.forward_chunk(
             cfg, params, toks, pos, kv, tables, lens, block_size=16,
             pallas=mesh is None,
+            packing=llama.Packing(*where, s) if where else None,
         )
         return out.logits, out.kv
 
+    tokens = (BATCH, s) if tp is None else (tp,)
+    where = () if tp is None else (
+        sds((tp,), jnp.int32), sds((tp,), jnp.int32),
+        sds((BATCH,), jnp.int32),
+    )
     return jax.jit(step, donate_argnums=(1,)).lower(
         place(params, p_sh), place(kv, kv_sh),
-        sds((BATCH, s), jnp.int32), sds((BATCH, s), jnp.int32),
+        sds(tokens, jnp.int32), sds(tokens, jnp.int32),
         sds((BATCH, CTX // 16), jnp.int32), sds((BATCH,), jnp.int32),
+        *where,
     )
 
 
@@ -216,6 +225,41 @@ def test_forward_chunk_compiles_one_chip(v5e, tpu_dispatch, s):
         # kernel's bandwidth-bound regime, so projections take the XLA path
         assert found == {"dgi_ragged_attention"}, found
     lowered.compile()
+
+
+@pytest.mark.parametrize("tp,s,qmm", [(128, 128, True), (264, 256, False)])
+def test_packed_forward_chunk_compiles_one_chip(v5e, tpu_dispatch, tp, s,
+                                                qmm):
+    """The plain ragged round's graph: ``tp`` live tokens on one axis, the
+    ragged kernel over the [8, s] rectangle. Up to 256 packed rows the
+    projections run through the int8 kernel, past it on the XLA path."""
+    lowered = _forward_chunk_lowered(
+        get_model_config("mistral-7b"), s, None, v5e, tp=tp
+    )
+    assert _kernels(lowered) == {"dgi_ragged_attention"} | (
+        {"dgi_qmm"} if qmm else set())
+    lowered.compile()
+
+
+def _collectives(compiled):
+    return sorted(re.findall(
+        r"\b(all-reduce|all-gather|all-to-all|collective-permute|"
+        r"reduce-scatter)(?:-start)?\(", compiled.as_text()))
+
+
+@pytest.mark.parametrize("model", ["mistral-7b", "mixtral-8x7b"])
+def test_packed_forward_chunk_on_model4_mesh_adds_no_collective(
+        v5e, tpu_dispatch, model):
+    """Under the mesh the gathers between the packed axis and the
+    rectangle run on replicated activations and head-sharded q/k/v: the
+    compiled program holds the collectives of the rectangle form (the two
+    all-reduces of a layer) and no other."""
+    mesh = Mesh(np.array(v5e).reshape(4), ("model",))
+    cfg = get_model_config(model)
+    packed = _forward_chunk_lowered(cfg, 256, mesh, v5e, tp=264)
+    assert _kernels(packed) == set()
+    want = _collectives(_forward_chunk_lowered(cfg, 256, mesh, v5e).compile())
+    assert want and _collectives(packed.compile()) == want
 
 
 @pytest.mark.parametrize("s", [1, 256])
@@ -258,7 +302,7 @@ def test_mesh_engine_traces_no_pallas_kernel(tpu_dispatch, cpu_devices):
     )
     # what chip_smoke.py reads the implementations from
     graphs = eng.lower_serving_graphs([4], [16])
-    assert set(graphs) == {"decode_multi[T=4]", "ragged_round[S=16]"}
+    assert set(graphs) == {"decode_multi[T=4]", "ragged_round[Tp=64]"}
     for lowered in graphs.values():
         assert _kernels(lowered) == set()
     out = eng.generate([
