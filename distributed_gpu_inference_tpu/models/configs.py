@@ -37,6 +37,10 @@ class ModelConfig:
     # MoE (Mixtral-style sparse MLP); 0 experts = dense
     num_experts: int = 0
     num_experts_per_tok: int = 2
+    norm_topk_prob: bool = True              # renormalise the kept top-k
+    # OLMoE-style QK-norm: RMSNorm over the whole projected q / k width
+    # (one learned vector per layer each), before the head split and RoPE
+    qk_norm: bool = False
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
@@ -68,6 +72,8 @@ class ModelConfig:
         else:
             mlp = 3 * h * i
         norms = 2 * h
+        if self.qk_norm:
+            norms += (self.num_heads + self.num_kv_heads) * d
         per_layer = attn + mlp + norms
         emb = v * h
         head = 0 if self.tie_word_embeddings else v * h
@@ -253,6 +259,24 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
         num_heads=32, num_kv_heads=8, intermediate_size=14336,
         max_position_embeddings=32768, rope_theta=1000000.0,
         rms_norm_eps=1e-5, num_experts=8, num_experts_per_tok=2,
+    ),
+    # OLMoE — many small experts (top-8 of 64, the kept probabilities NOT
+    # renormalised) and QK-norm; ``intermediate_size`` is the width of one
+    # expert. On one chip the expert layer is routed: it computes and
+    # reads only the experts the router chose (models/llama.py _moe_mlp).
+    "olmoe-tiny": _llama(  # test-scale: 8 experts, top-4
+        "olmoe-tiny", vocab_size=512, hidden_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=4, intermediate_size=32,
+        max_position_embeddings=1024, rope_theta=10000.0,
+        num_experts=8, num_experts_per_tok=4, norm_topk_prob=False,
+        qk_norm=True,
+    ),
+    "olmoe-1b-7b": _llama(  # OLMoE-1B-7B-0125-Instruct: 6.92 B, 1.3 B active
+        "olmoe-1b-7b", vocab_size=50304, hidden_size=2048, num_layers=16,
+        num_heads=16, num_kv_heads=16, intermediate_size=1024,
+        max_position_embeddings=4096, rope_theta=10000.0,
+        rms_norm_eps=1e-5, num_experts=64, num_experts_per_tok=8,
+        norm_topk_prob=False, qk_norm=True,
     ),
 }
 

@@ -88,6 +88,9 @@ def init_params(
         "layers": layers,
         "final_norm": norm_init((h,), dtype),
     }
+    if cfg.qk_norm:  # OLMoE: one norm vector over the whole q / k width
+        layers["q_norm"] = norm_init((L, nh * d), dtype)
+        layers["k_norm"] = norm_init((L, nkv * d), dtype)
     if cfg.attention_bias:  # Qwen2-style QKV biases (random init ~ small)
         bkeys = jax.random.split(keys[1], 3)
         params["layers"]["bq"] = _w(bkeys[0], (L, nh * d), nh * d)
@@ -237,48 +240,100 @@ def _mlp(x: jax.Array, proj, activation: str = "silu") -> jax.Array:
 
 
 def _moe_mlp(
-    x: jax.Array, lp: Dict[str, jax.Array], cfg: ModelConfig
-) -> jax.Array:
-    """Mixtral-style sparse MoE MLP, expert-parallel by sharding.
+    x: jax.Array,
+    lp: Dict[str, jax.Array],
+    cfg: ModelConfig,
+    *,
+    live: Optional[jax.Array] = None,   # [B, S] bool: tokens that are routed
+    pallas: bool = True,
+    stacked: Optional[Dict[str, Any]] = None,
+    layer_idx: Any = 0,
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]], jax.Array]:
+    """Sparse MoE MLP: softmax over all router logits in float32, keep the
+    top-k, renormalise them if ``cfg.norm_topk_prob`` (Mixtral does, OLMoE
+    does not), ``sum_e p_e * down_e(act(gate_e(x)) * up_e(x))`` over the
+    kept experts. Two forms of that mathematics, chosen as the other
+    kernels are (``pallas`` and the backend, no option):
 
-    Routing follows HF Mixtral: softmax over all router logits, keep top-k,
-    renormalize. The combine is expressed as a dense einsum over the expert
-    axis with top-k-masked weights — on a mesh where ``we_*`` shard their E
-    axis over ``model``, each chip runs only its local experts for all
-    tokens and XLA inserts the combine all-reduce: expert parallelism
-    without hand-written all-to-all (the TPU answer to SURVEY §2.2's
-    "EP: ABSENT"). Single-chip cost is E/k times the active-path FLOPs
-    of the ``T`` tokens it is given: ``T`` is ``B x S`` of a rectangle
-    chunk, the packed ``Tp`` of a plain ragged round (``forward_chunk``
-    ``packing``), so there it shrinks with the round's live tokens. A
-    ragged/blocked Pallas dispatch over the routed tokens is the
-    designated upgrade path.
+    - **routed** (``pallas=True``: every one-device path). The ``T x k``
+      (token, expert) pairs are sorted by expert into row tiles that each
+      belong to one expert (``ops/moe_gmm_pallas.route_plan``), gate / up /
+      down run as grouped matmuls over those tiles and the ``k`` rows of a
+      token are combined in float32. Compute follows ``T x k`` rows plus
+      tile padding, no ``[T, E, ...]`` tensor exists, and tokens that are
+      not ``live`` are routed nowhere. On a TPU with quantized expert
+      weights (``stacked``: kept whole, addressed by ``layer_idx``) the
+      grouped matmul is the ``dgi_moe_gmm`` kernel, which reads the int8
+      weights as stored and only the experts that received a row; on the
+      CPU the same plan runs through an XLA gather of each tile's weight.
+      Returns the layer's counters beside the output
+      (``moe_gmm.expert_stats``), and the experts each token was routed to
+      (``[T, k]``) last.
+    - **dense over the expert axis** (``pallas=False``: a GSPMD mesh, which
+      refuses a ``pallas_call``). The combine is an einsum over ``E`` with
+      top-k-masked weights: where ``we_*`` shard their E axis over
+      ``model`` each chip runs its local experts for all tokens and XLA
+      inserts the combine all-reduce — expert parallelism without a
+      hand-written all-to-all, at E/k times the active-path FLOPs (a
+      constant 4x over two local experts for Mixtral on four chips).
+      No counters. The routed form under a mesh (``shard_map`` around the
+      kernel) is the open upgrade.
     """
     act = jax.nn.silu if cfg.activation == "silu" else functools.partial(
         jax.nn.gelu, approximate=True
     )
     b, s, h = x.shape
-    xf = x.reshape(b * s, h)                                   # [T, H]
+    t, k, num_e = b * s, cfg.num_experts_per_tok, cfg.num_experts
+    xf = x.reshape(t, h)                                       # [T, H]
     # router math in float32: top-k selection is precision-sensitive
     logits = (xf.astype(jnp.float32) @ lp["w_router"].astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)                    # [T, E]
-    topv, topi = lax.top_k(probs, cfg.num_experts_per_tok)     # [T, k]
-    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
-    # scatter the renormalized top-k back to a dense [T, E] combine weight
-    weights = jnp.zeros_like(probs).at[
-        jnp.arange(xf.shape[0])[:, None], topi
-    ].set(topv)                                                # [T, E]
+    topv, topi = lax.top_k(probs, k)                           # [T, k]
+    if cfg.norm_topk_prob:
+        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
 
-    gate = act(jnp.einsum("th,ehi->tei", xf, _deq(lp["we_gate"], x.dtype)))
-    up = jnp.einsum("th,ehi->tei", xf, _deq(lp["we_up"], x.dtype))
-    per_expert = jnp.einsum(
-        "tei,eih->teh", gate * up, _deq(lp["we_down"], x.dtype)
-    )                                                          # [T, E, H]
-    out = jnp.einsum(
-        "te,teh->th", weights.astype(jnp.float32),
-        per_expert.astype(jnp.float32),
+    if not pallas:
+        # scatter the kept top-k back to a dense [T, E] combine weight
+        weights = jnp.zeros_like(probs).at[
+            jnp.arange(t)[:, None], topi
+        ].set(topv)                                            # [T, E]
+        gate = act(jnp.einsum("th,ehi->tei", xf, _deq(lp["we_gate"], x.dtype)))
+        up = jnp.einsum("th,ehi->tei", xf, _deq(lp["we_up"], x.dtype))
+        per_expert = jnp.einsum(
+            "tei,eih->teh", gate * up, _deq(lp["we_down"], x.dtype)
+        )                                                      # [T, E, H]
+        out = jnp.einsum(
+            "te,teh->th", weights.astype(jnp.float32),
+            per_expert.astype(jnp.float32),
+        )
+        return out.reshape(b, s, h).astype(x.dtype), None, topi
+
+    # imported where a sparse model needs it: a dense model's start does
+    # not pay for it
+    from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
+
+    live = jnp.ones((t,), bool) if live is None else live.reshape(t)
+    plan = moe_gmm.route_plan(
+        topi, live, num_e,
+        moe_gmm.tile_rows(t * k, num_e, moe_gmm.sublane(x.dtype)),
     )
-    return out.reshape(b, s, h).astype(x.dtype)
+
+    def gmm(rows, name):
+        if stacked is not None and name in stacked:
+            return moe_gmm.grouped_matmul(
+                rows, stacked[name], layer_idx, plan, decode=s == 1)
+        return moe_gmm.grouped_matmul_layer(rows, lp[name], plan)
+
+    rows = jnp.take(xf, plan.row_token, axis=0, mode="fill", fill_value=0)
+    mid = act(gmm(rows, "we_gate")) * gmm(rows, "we_up")       # [R, I]
+    y = gmm(mid.astype(x.dtype), "we_down")                    # [R, H]
+    out = jnp.zeros((t, h), jnp.float32)
+    for j in range(k):      # a token's k rows; dead pairs read nothing
+        out = out + topv[:, j, None] * jnp.take(
+            y, plan.pair_row[:, j], axis=0, mode="fill", fill_value=0
+        ).astype(jnp.float32)
+    return (out.reshape(b, s, h).astype(x.dtype),
+            moe_gmm.expert_stats(plan), topi)
 
 
 def _deq(w: Any, dtype) -> jax.Array:
@@ -289,6 +344,16 @@ def _deq(w: Any, dtype) -> jax.Array:
     )
 
     return dequantize(w, dtype) if is_quantized(w) else w
+
+
+def _split_layers(layers: Dict[str, Any], pallas: bool):
+    """``split_stacked_quant`` for a forward pass: the expert weights stay
+    whole too where the routed layer's kernel will take them."""
+    if not pallas or "we_gate" not in layers:
+        return split_stacked_quant(layers)
+    from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
+
+    return split_stacked_quant(layers, experts=moe_gmm.kernel_ok(layers))
 
 
 # ---------------------------------------------------------------------------
@@ -320,6 +385,13 @@ class ChunkOutput(NamedTuple):
     # unless asked for: stacking every layer's hidden is layer-count x the
     # activation memory, so only small spec/distill shapes request it
     features: Optional[jax.Array] = None
+    # what the routed expert layers did, summed over layers (int32 scalars:
+    # ops/moe_gmm_pallas.expert_stats) — None for a dense model and where
+    # the expert layer runs dense over the expert axis (a mesh)
+    moe: Optional[Dict[str, jax.Array]] = None
+    # [L, T, k] the experts every token was routed to in every layer
+    # (collect_routing; the benchmark's comparison with its reference)
+    routing: Optional[jax.Array] = None
 
 
 class Packing(NamedTuple):
@@ -359,7 +431,12 @@ def _layer_step(
                                   # packed chunk (forward_chunk ``packing``):
                                   # (rectangle → packed index [B, S], row
                                   # [Tp], col [Tp])
-) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array], Optional[jax.Array]]:
+    moe_live: Optional[jax.Array] = None,  # hidden's tokens the experts
+                                  # route (None: all of them)
+    emit_routing: bool = False,   # scan-emit the experts of every token
+) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array],
+           Tuple[Optional[jax.Array], Optional[Dict[str, jax.Array]],
+                 Optional[jax.Array]]]:
     """One transformer layer over paged KV — shared by the causal decode path
     and the speculative tree-verify path (they differ only in the attention
     mask and in where KV rows are written).
@@ -418,6 +495,9 @@ def _layer_step(
             q = q + lp["bq"]
             k = k + lp["bk"]
             v = v + lp["bv"]
+        if "q_norm" in lp:  # OLMoE QK-norm: over the whole width, pre-RoPE
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         q = q.reshape(b, s, nh, d)
         k = k.reshape(b, s, nkv, d)
         v = v.reshape(b, s, nkv, d)
@@ -514,14 +594,20 @@ def _layer_step(
         hidden = hidden + proj(attn.reshape(b, s, nh * d), "wo").astype(hidden.dtype)
     with jax.named_scope("dgi_experts" if "w_router" in lp else "dgi_mlp"):
         mlp_in = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+        moe_stats = routing = None
         if "w_router" in lp:
-            hidden = hidden + _moe_mlp(mlp_in, lp, cfg)
+            moe_out, moe_stats, routing = _moe_mlp(
+                mlp_in, lp, cfg, live=moe_live, pallas=pallas,
+                stacked=stacked, layer_idx=layer_idx,
+            )
+            hidden = hidden + moe_out
         else:
             hidden = hidden + _mlp(mlp_in, proj, cfg.activation)
     k_out = (k_pool, k_scale_pool) if quant_kv else k_pool
     v_out = (v_pool, v_scale_pool) if quant_kv else v_pool
     return (hidden, k_out, v_out, layer_idx + 1), (
-        hidden if emit_hidden else None
+        hidden if emit_hidden else None, moe_stats,
+        routing if emit_routing else None,
     )
 
 
@@ -549,6 +635,8 @@ def forward_chunk(
                           # these layers' post-layer hiddens (EAGLE-3 draft
                           # features) — costs L x hidden activation memory,
                           # request only on small spec/distill shapes
+    collect_routing: bool = False,
+                          # also return ChunkOutput.routing (MoE models)
     pallas: bool = True,
                           # gate for EVERY Pallas kernel in the graph (the
                           # fused decode kernel, the ragged kernel, the int8
@@ -624,7 +712,7 @@ def forward_chunk(
                 k_scale=layer_ks, v_scale=layer_vs,
             )
 
-    scanned, stacked = split_stacked_quant(params["layers"])
+    scanned, stacked = _split_layers(params["layers"], pallas)
     step = functools.partial(
         _layer_step,
         cfg,
@@ -647,14 +735,18 @@ def forward_chunk(
         emit_hidden=collect_layers is not None,
         pallas=pallas,
         unpack=unpack,
+        moe_live=rope_positions >= 0,
+        emit_routing=collect_routing,
     )
     k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
     v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
-    (hidden, k_out, v_out, _), layer_hs = lax.scan(
+    (hidden, k_out, v_out, _), (layer_hs, moe, routing) = lax.scan(
         lambda c, lp: step(c, lp),
         (hidden, k0, v0, jnp.int32(0)),
         scanned,
     )
+    if moe is not None:
+        moe = {name: jnp.sum(v) for name, v in moe.items()}
     new_kv = (
         {"k": k_out[0], "v": v_out[0],
          "k_scale": k_out[1], "v_scale": v_out[1]}
@@ -668,7 +760,7 @@ def forward_chunk(
     if not with_logits:
         return ChunkOutput(
             hidden=hidden, kv=new_kv, logits=None,
-            features=features,
+            features=features, moe=moe, routing=routing,
         )
     if last_only and packing is not None:
         logits_in = jnp.take(hidden[0], packing.last, axis=0)[:, None]
@@ -685,8 +777,8 @@ def forward_chunk(
         logits_in = hidden
     with jax.named_scope("dgi_head"):
         logits = project_logits(cfg, params, logits_in)
-    return ChunkOutput(hidden=hidden, kv=new_kv,
-                       logits=logits, features=features)
+    return ChunkOutput(hidden=hidden, kv=new_kv, logits=logits,
+                       features=features, moe=moe, routing=routing)
 
 
 def forward_tree_chunk(
@@ -736,7 +828,7 @@ def forward_tree_chunk(
         )
 
     quant_kv = "k_scale" in kv
-    scanned, stacked = split_stacked_quant(params["layers"])
+    scanned, stacked = _split_layers(params["layers"], True)
     step = functools.partial(
         _layer_step,
         cfg,
@@ -751,7 +843,7 @@ def forward_tree_chunk(
     )
     k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
     v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
-    (hidden, k_out, v_out, _), layer_hs = lax.scan(
+    (hidden, k_out, v_out, _), (layer_hs, _, _) = lax.scan(
         lambda c, lp: step(c, lp), (hidden, k0, v0, jnp.int32(0)),
         scanned,
     )
@@ -803,7 +895,7 @@ def forward_hidden_chunk(
             window=cfg.sliding_window,
         )
 
-    scanned, stacked = split_stacked_quant(params["layers"])
+    scanned, stacked = _split_layers(params["layers"], True)
     step = functools.partial(
         _layer_step,
         cfg,

@@ -297,6 +297,9 @@ def init_quantized_streamed(
     for name, (shape, fan_in) in leaf_specs.items():
         assert name in QUANT_KEYS
         layers[name] = _q_leaf(name, shape, fan_in)
+    if cfg.qk_norm:
+        layers["q_norm"] = norm_init((L, nh * d), "q_norm")
+        layers["k_norm"] = norm_init((L, nkv * d), "k_norm")
     if cfg.attention_bias:
         layers["bq"] = _dense_leaf("bq", (L, nh * d), nh * d)
         layers["bk"] = _dense_leaf("bk", (L, nkv * d), nkv * d)

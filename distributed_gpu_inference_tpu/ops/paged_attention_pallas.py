@@ -705,10 +705,21 @@ def quantize_kv_pool(pool: jax.Array) -> Tuple[jax.Array, jax.Array]:
 # pages re-stage once per TILE, not once per query — the fix for the old
 # multi-query path's per-query re-staging that capped it at q_len <= 8.
 _RAGGED_QPK_TILE = 256
+# ceiling on (query heads) x (query tile): the q / out blocks, the
+# accumulator and the score tile all carry every head of the cell, so at
+# many KV heads (MHA: OLMoE's 16 of 128) the per-KV-head ceiling alone
+# lets them outgrow VMEM. 2048 is what the GQA models reach (Mistral:
+# 8 KV heads x 256), so their tiles are as they were.
+_RAGGED_HEAD_ROWS = 2048
+# ceiling on the ragged kernel's four KV staging buffers: what the GQA
+# models use at 512-token groups (8 KV heads: 4 MiB). More KV heads take
+# fewer pages a group instead of more VMEM.
+_RAGGED_KV_STAGING_BYTES = 4 * 1024 * 1024
 
 
-def _ragged_q_tile(s: int, qpk: int) -> int:
-    t = max(1, min(s, _RAGGED_QPK_TILE // max(qpk, 1)))
+def _ragged_q_tile(s: int, qpk: int, hkv: int = 1) -> int:
+    t = max(1, min(s, _RAGGED_QPK_TILE // max(qpk, 1),
+                   _RAGGED_HEAD_ROWS // max(qpk * hkv, 1)))
     return 1 << (t.bit_length() - 1)     # power of two so buckets divide
 
 
@@ -967,7 +978,7 @@ def ragged_paged_attention(
         )
     qpk = nh // hkv
     m = block_tables.shape[1]
-    t = _ragged_q_tile(s, qpk)
+    t = _ragged_q_tile(s, qpk, hkv)
     s_pad = -(-s // t) * t
     if s_pad != s:
         q = jnp.pad(q, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
@@ -994,6 +1005,9 @@ def ragged_paged_attention(
         block_size, hkv, d, k_pool.dtype.itemsize, m,
         scale_page_bytes=scale_page_bytes,
     )
+    gp = max(1, min(gp, _RAGGED_KV_STAGING_BYTES // (
+        4 * (hkv * block_size * d * k_pool.dtype.itemsize
+             + scale_page_bytes))))
     max_groups = -(-m // gp)
 
     in_specs = [

@@ -120,24 +120,28 @@ def matmul(x: jax.Array, w: Any, pallas: bool = True) -> jax.Array:
     return (out.astype(jnp.float32) * scale).astype(x.dtype)
 
 
-# weight keys large enough to be worth the stacked-scan treatment (the MoE
-# expert weights route through the einsum combine instead)
+# weight keys large enough to be worth the stacked-scan treatment
 STACKED_KEYS = frozenset(
     {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
 )
+# the MoE expert weights: kept whole for the routed layer's grouped-matmul
+# kernel (ops/moe_gmm_pallas.py), scanned where the layer runs in XLA
+EXPERT_KEYS = frozenset({"we_gate", "we_up", "we_down"})
 
 
-def split_stacked_quant(layers: Dict[str, Any]):
+def split_stacked_quant(layers: Dict[str, Any], experts: bool = False):
     """Partition a stacked layer tree for the scan in ``models/llama.py``:
     quantized matmul weights are pulled OUT of the scan xs (so the Pallas
     kernel can take the whole stacked array + a layer index instead of a
     materialized per-layer slice) and everything else stays scanned.
+    ``experts``: the expert weights ``[L, E, in, out]`` too.
 
     → (scanned_layers, stacked_or_None)
     """
+    keys = STACKED_KEYS | EXPERT_KEYS if experts else STACKED_KEYS
     stacked = {
         k: v for k, v in layers.items()
-        if k in STACKED_KEYS and is_quantized(v)
+        if k in keys and is_quantized(v)
     }
     if not stacked:
         return layers, None

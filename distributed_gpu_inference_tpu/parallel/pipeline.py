@@ -140,6 +140,8 @@ def stage_param_shardings(mesh: Mesh) -> Dict[str, Any]:
             "bq": _l(None),
             "bk": _l(None),
             "bv": _l(None),
+            "q_norm": _l(None),
+            "k_norm": _l(None),
             "mlp_norm": _l(None),
             "w_gate": _l(None, None),
             "w_up": _l(None, None),

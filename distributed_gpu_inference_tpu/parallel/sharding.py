@@ -15,6 +15,7 @@ embedding           [V, H]                       replicated
 layers.attn_norm    [L, H]                       replicated
 layers.wq           [L, H, Nh*D]                 shard out dim on ``model``
 layers.wk / wv      [L, H, Nkv*D]                shard out dim on ``model``
+layers.q_norm/k_norm [L, Nh*D] / [L, Nkv*D]     shard with their projection
 layers.wo           [L, Nh*D, H]                 shard in dim on ``model``
 layers.w_gate/up    [L, H, I]                    shard out dim on ``model``
 layers.w_down       [L, I, H]                    shard in dim on ``model``
@@ -62,6 +63,10 @@ def param_shardings(mesh: Mesh) -> Dict[str, Any]:
             "bq": _ns(mesh, None, AXIS_MODEL),
             "bk": _ns(mesh, None, AXIS_MODEL),
             "bv": _ns(mesh, None, AXIS_MODEL),
+            # OLMoE QK-norm vectors follow their projection's out dim too
+            # (the norm's mean over the whole width is XLA's all-reduce)
+            "q_norm": _ns(mesh, None, AXIS_MODEL),
+            "k_norm": _ns(mesh, None, AXIS_MODEL),
             "mlp_norm": _ns(mesh, None, None),
             "w_gate": _ns(mesh, None, None, AXIS_MODEL),
             "w_up": _ns(mesh, None, None, AXIS_MODEL),

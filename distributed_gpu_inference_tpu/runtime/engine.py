@@ -75,6 +75,22 @@ _BIG_BUDGET = 1 << 30
 _QUANT_DEVICE_BUILD_LIMIT = 8 * 1024**3
 
 
+# the routed expert layers' counters, in the order a round returns them:
+# layer calls that held a live token, live (token, expert) pairs, rows the
+# grouped matmul ran (tile padding included), experts with at least one row
+# summed over layer calls
+# (the keys of ops/moe_gmm_pallas.expert_stats, spelled here so that a dense
+# model's engine does not import that module and Pallas with it at start)
+_MOE_COUNTERS = ("layer_calls", "assignments", "rows_dispatched",
+                 "active_experts")
+
+
+def _moe_vector(moe: Dict[str, Any]) -> Any:
+    """``ChunkOutput.moe`` as one int32 vector, so that a round brings its
+    counters back in one transfer."""
+    return jnp.stack([moe[name] for name in _MOE_COUNTERS]).astype(jnp.int32)
+
+
 def _resolve_kv_dtype(kv_cache_dtype: Optional[str], activation_dtype) -> Any:
     """KV pool storage dtype. ``fp8`` = float8_e4m3 (scale-free: post-RoPE
     K and V magnitudes sit well inside e4m3's ±448 range, the same rationale
@@ -576,6 +592,15 @@ class TPUEngine:
             "round_build_s": 0.0, "round_dispatch_s": 0.0,
             "round_readback_s": 0.0, "round_commit_s": 0.0,
         }
+        if self.model_cfg.num_experts:
+            # what the routed expert layers did (models/llama.py
+            # _moe_mlp), summed on the device and read back beside a
+            # round's tokens; scans and ragged rounds apart. They stay 0
+            # under a mesh, where the layer runs dense over the expert axis
+            self.stats.update({
+                f"moe_{name}_{kind}": 0
+                for kind in ("scan", "ragged") for name in _MOE_COUNTERS
+            })
         self._compile_log = compile_log()
         if self.cfg.speculative is not None:
             self.stats.update({
@@ -1076,6 +1101,82 @@ class TPUEngine:
             ragged_round, static_argnames=("mode", "width"),
             donate_argnums=(1, 5),
         )
+
+        if cfg.num_experts and self.mesh is None:
+            # A sparse model on one device: the same two rounds, which also
+            # sum what the routed expert layers did (``ChunkOutput.moe``) —
+            # the scan in its carry — and return it as a fourth value, so
+            # that it comes back in the transfer that brings the tokens.
+            # They stand BESIDE the plain rounds, not in them: threading an
+            # optional value through the plain bodies (a ``None`` or a
+            # spliced empty tuple in the carry and the outputs: the traced
+            # program was the same) cost a dense model's nine round graphs
+            # 5.2-5.5 s of lowering at every start on the v5e (PERF.md
+            # section 6), as wrapping them in a decorator had in PR 23.
+            def decode_multi_counted(params, kv, core, tables, active,
+                                     budgets, num_steps, mode):
+                stops = core["stops"]
+
+                def step(carry, _):
+                    kv, last, lens, done, n_emit, moe = carry
+                    cur = jnp.where(~done, lens + 1, 0).astype(jnp.int32)
+                    positions = jnp.where(
+                        (~done)[:, None], lens[:, None], -1
+                    ).astype(jnp.int32)
+                    out = fwd(
+                        cfg, params, last[:, None], positions, kv, tables,
+                        cur, block_size=bs, last_only=True,
+                    )
+                    toks = sample_mode(
+                        out.logits[:, 0, :], core["keys"], cur,
+                        core["temps"], core["top_ks"], core["top_ps"], mode,
+                    )
+                    hit_stop = jnp.any(toks[:, None] == stops, axis=1)
+                    emitted = jnp.where(done, -1, toks)
+                    new_emit = n_emit + (~done).astype(jnp.int32)
+                    new_done = done | hit_stop | (new_emit >= budgets)
+                    new_lens = jnp.where(done, lens, lens + 1)
+                    new_last = jnp.where(done, last, toks)
+                    return (out.kv, new_last, new_lens, new_done, new_emit,
+                            moe + _moe_vector(out.moe)), emitted
+
+                moe0 = jnp.zeros((len(_MOE_COUNTERS),), jnp.int32)
+                (kv, last, lens, _done, _, moe), emitted = jax.lax.scan(
+                    step, (kv, core["last"], core["lens"], ~active,
+                           jnp.zeros_like(core["lens"]), moe0),
+                    None, length=num_steps,
+                )
+                core = dict(core)
+                core["last"], core["lens"] = last, lens
+                return kv, core, emitted.T, moe
+
+            def ragged_round_counted(params, kv, tok_at, tables, lens_last,
+                                     core, sample_flag, mode, width):
+                lens_after = lens_last[0]
+                out = fwd(
+                    cfg, params, tok_at[0], tok_at[1], kv, tables,
+                    lens_after, block_size=bs, last_only=True,
+                    packing=llama.Packing(tok_at[2], tok_at[3],
+                                          lens_last[1], width),
+                )
+                toks = sample_mode(
+                    out.logits[:, 0, :], core["keys"], lens_after,
+                    core["temps"], core["top_ks"], core["top_ps"], mode,
+                )
+                sampled = sample_flag > 0
+                core = dict(core)
+                core["last"] = jnp.where(sampled, toks, core["last"])
+                core["lens"] = jnp.where(sampled, lens_after, core["lens"])
+                return out.kv, core, toks, _moe_vector(out.moe)
+
+            self._decode_multi_fn = jax.jit(
+                decode_multi_counted, static_argnames=("num_steps", "mode"),
+                donate_argnums=(1, 2),
+            )
+            self._ragged_round_fn = jax.jit(
+                ragged_round_counted, static_argnames=("mode", "width"),
+                donate_argnums=(1, 5),
+            )
 
         # --- integrated speculative decoding: R fused draft→verify→accept
         # rounds per dispatch (lax.scan — the spec analogue of decode_multi's
@@ -2393,6 +2494,18 @@ class TPUEngine:
         st["ragged_positions_dispatched"] += positions
         st["ragged_positions_live"] += decode_tokens + live_prompt
 
+    def _count_moe(self, sp: flight.span, kind: str,
+                   moe: Sequence[np.ndarray]) -> None:
+        """A round's routed-expert counters (``_MOE_COUNTERS``, summed over
+        its layer calls on the device) onto its span and into the counters
+        of its ``kind``. Empty: the round ran no routed layer."""
+        if not moe:
+            return
+        held = {name: int(v) for name, v in zip(_MOE_COUNTERS, moe[0])}
+        sp.set(**{f"moe_{name}": v for name, v in held.items()})
+        for name, v in held.items():
+            self.stats[f"moe_{name}_{kind}"] += v
+
     def _ragged_admission_rows(
         self, admissions: Sequence[ChunkedAdmission], chunk_cap: int,
         chunk_caps: Optional[Dict[int, int]] = None,
@@ -2504,7 +2617,7 @@ class TPUEngine:
         with flight.span("dgi.engine.ragged_round.dispatch", self.stats,
                          "round_dispatch_s"):
             try:
-                self.kv, self._dev_core, toks = self._ragged_round_fn(
+                self.kv, self._dev_core, toks, *moe = self._ragged_round_fn(
                     self.params, self.kv, *operands, mode, width,
                 )
             except Exception:
@@ -2512,7 +2625,9 @@ class TPUEngine:
                 raise
         with flight.span("dgi.engine.ragged_round.readback", self.stats,
                          "round_readback_s"):
-            toks = np.asarray(toks)     # the wait for the device
+            # the wait for the device; the experts' counters come with it
+            toks, moe = jax.device_get((toks, moe))
+        self._count_moe(sp, "ragged", moe)
         with flight.span("dgi.engine.ragged_round.commit", self.stats,
                          "round_commit_s"):
             self.stats["ragged_rounds"] += 1
@@ -2938,7 +3053,7 @@ class TPUEngine:
         tables, act_d, bud_d = self._sched_arrays(active_mask, budgets)
         mode = self._decode_mode()
         try:
-            self.kv, self._dev_core, emitted = self._decode_multi_fn(
+            self.kv, self._dev_core, emitted, *_ = self._decode_multi_fn(
                 self.params, self.kv, core, tables, act_d, bud_d, 1, mode,
             )
         except Exception:
@@ -3229,7 +3344,7 @@ class TPUEngine:
         with flight.span("dgi.engine.decode_multi.dispatch", self.stats,
                          "round_dispatch_s"):
             try:
-                self.kv, self._dev_core, emitted = self._decode_multi_fn(
+                self.kv, self._dev_core, emitted, *moe = self._decode_multi_fn(
                     self.params, self.kv, *operands, num_steps, mode,
                 )
             except Exception:
@@ -3238,8 +3353,10 @@ class TPUEngine:
         self.stats["decode_calls"] += num_steps
         with flight.span("dgi.engine.decode_multi.readback", self.stats,
                          "round_readback_s"):
-            # [B, T], -1 = masked-out step: the wait for the device
-            emitted = np.asarray(emitted)
+            # [B, T], -1 = masked-out step: the wait for the device; the
+            # experts' counters come with it
+            emitted, moe = jax.device_get((emitted, moe))
+        self._count_moe(sp, "scan", moe)
         with flight.span("dgi.engine.decode_multi.commit", self.stats,
                          "round_commit_s"):
             out: Dict[int, List[int]] = {}

@@ -298,6 +298,24 @@ class Metrics:
             "worker_compile_seconds_total",
             "Seconds of the worker's XLA compile requests", ["worker"],
             registry=r)
+        # the routed expert layer of a sparse model (engine.stats moe_*):
+        # active_experts / (layer_calls x experts) is the share of the
+        # expert weights a round reads, assignments / rows_dispatched what
+        # of the grouped matmul's rows is not tile padding
+        self.worker_moe = {
+            name: Counter(
+                f"worker_moe_{name}_total", help_,
+                ["worker", "round"], registry=r)
+            for name, help_ in (
+                ("layer_calls", "Routed expert-layer calls that held a "
+                 "live token, by round kind (scan, ragged)"),
+                ("assignments", "Live (token, expert) pairs routed"),
+                ("rows_dispatched", "Rows the grouped expert matmul ran, "
+                 "tile padding included"),
+                ("active_experts", "Experts that received at least one "
+                 "row, summed over layer calls"),
+            )
+        }
         # cache-aware routing (round 7): hits = placements that landed on
         # a worker advertising the request's prefix; spillover = requests
         # whose warmest worker was passed over (load headroom scaling or
@@ -720,6 +738,11 @@ class MetricsCollector:
                 metric = self.metrics.batcher_round_gaps.labels(worker)
             elif key.startswith("scans_t") and key[7:].isdigit():
                 metric = self.metrics.batcher_scans.labels(worker, key[7:])
+            elif key.startswith("moe_"):
+                name, _, kind = key[4:].rpartition("_")
+                if name not in self.metrics.worker_moe:
+                    continue
+                metric = self.metrics.worker_moe[name].labels(worker, kind)
             else:
                 continue
             try:

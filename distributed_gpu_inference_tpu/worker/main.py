@@ -495,6 +495,10 @@ class Worker:
             for k in s:
                 if k == "between_rounds" or k.startswith("scans_t"):
                     out[k] = out.get(k, 0) + int(s[k] or 0)
+            # the routed expert layers' counters (MoE engines only)
+            for k in es:
+                if k.startswith("moe_"):
+                    out[k] = out.get(k, 0) + int(es[k] or 0)
             if s.get("avg_occupancy") is not None:
                 out["avg_occupancy"] = round(
                     float(s.get("avg_occupancy") or 0.0), 3

@@ -49,7 +49,7 @@ def test_moe_mlp_matches_per_token_oracle():
     p = llama.init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
     lp = jax.tree.map(lambda a: a[0], p["layers"])  # layer 0 (scan view)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 3, 64), jnp.float32)
-    got = np.asarray(llama._moe_mlp(x, lp, cfg))
+    got = np.asarray(llama._moe_mlp(x, lp, cfg)[0])
 
     xf = np.asarray(x, np.float64).reshape(-1, 64)
     wr = np.asarray(lp["w_router"], np.float64)
@@ -161,5 +161,5 @@ def test_moe_combine_weights_sum_to_one():
     # experts that each compute ~0 → output ≈ 0 regardless of routing
     zeros = jax.tree.map(jnp.zeros_like, lp["we_down"])
     ident["we_down"] = zeros
-    out = llama._moe_mlp(x, ident, cfg)
+    out = llama._moe_mlp(x, ident, cfg)[0]
     np.testing.assert_allclose(np.asarray(out), 0.0, atol=1e-6)

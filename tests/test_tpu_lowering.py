@@ -160,19 +160,20 @@ def test_qmm_kernel_compiles(v5e, model):
 # (b) the whole serving graph, one chip and a model=4 mesh
 # --------------------------------------------------------------------- #
 
-def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None):
+def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
     """``forward_chunk`` for int8 ``cfg`` at [BATCH, s], lowered for one
     device (``mesh=None``) or sharded over ``mesh`` by the engine's own
     rules, with ``pallas`` set the way ``TPUEngine._build_jit_fns`` sets
     it. ``tp``: the plain ragged round's form, ``tp`` live tokens packed
-    on one axis with [BATCH, s] the rectangle attention sees."""
+    on one axis with [BATCH, s] the rectangle attention sees. ``ctx``:
+    tokens of context a row's block table and the pool hold."""
     params = jax.eval_shape(
         lambda: quantize_params(
             llama.init_params(cfg, jax.random.PRNGKey(0)), "int8"
         )
     )
     kv = jax.eval_shape(
-        lambda: llama.init_kv_pools(cfg, 1 + BATCH * (CTX // 16), 16)
+        lambda: llama.init_kv_pools(cfg, 1 + BATCH * (ctx // 16), 16)
     )
     if mesh is None:
         one = SingleDeviceSharding(devices[0])
@@ -205,7 +206,7 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None):
     return jax.jit(step, donate_argnums=(1,)).lower(
         place(params, p_sh), place(kv, kv_sh),
         sds(tokens, jnp.int32), sds(tokens, jnp.int32),
-        sds((BATCH, CTX // 16), jnp.int32), sds((BATCH,), jnp.int32),
+        sds((BATCH, ctx // 16), jnp.int32), sds((BATCH,), jnp.int32),
         *where,
     )
 
@@ -241,6 +242,37 @@ def test_packed_forward_chunk_compiles_one_chip(v5e, tpu_dispatch, tp, s,
     lowered.compile()
 
 
+@pytest.mark.parametrize("tp,s,qmm", [(None, 1, True), (128, 128, True),
+                                      (264, 256, False)])
+def test_olmoe_graphs_compile_one_chip(v5e, tpu_dispatch, tp, s, qmm):
+    """OLMoE at its published widths (64 experts of 1024, top-8, 16 KV
+    heads): a decode step and the packed round. The expert layer is the
+    grouped-matmul kernel over the stacked int8 weights — named apart in a
+    decode step, so that a trace tells a scan's expert time from a ragged
+    round's — and the attention kernels take 16 KV heads inside VMEM."""
+    lowered = _forward_chunk_lowered(
+        get_model_config("olmoe-1b-7b"), s, None, v5e, tp=tp
+    )
+    want = {"dgi_paged_decode", "dgi_moe_gmm_step"} if tp is None \
+        else {"dgi_ragged_attention", "dgi_moe_gmm"}
+    assert _kernels(lowered) == want | ({"dgi_qmm"} if qmm else set())
+    lowered.compile()
+
+
+def test_olmoe_packed_round_holds_no_token_by_expert_tensor(v5e,
+                                                            tpu_dispatch):
+    """At ``Tp`` = 264 the dense einsum form needs a ``[264, 64, 2048]``
+    float32 combine tensor (138 MB a layer) and bf16 copies of the expert
+    weights (805 MB a layer); the routed form's temporaries are the tiled
+    rows (at most 130 tiles of 32). The pools are sized to 512 tokens a
+    row here, so that the ragged round's copies of a pool layer (67 MB
+    each at the served 2048: PERF.md section 5) do not hide the bound."""
+    compiled = _forward_chunk_lowered(
+        get_model_config("olmoe-1b-7b"), 256, None, v5e, tp=264, ctx=512
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 264 * 64 * 2048 * 4
+
+
 def _collectives(compiled):
     return sorted(re.findall(
         r"\b(all-reduce|all-gather|all-to-all|collective-permute|"
@@ -260,6 +292,10 @@ def test_packed_forward_chunk_on_model4_mesh_adds_no_collective(
     assert _kernels(packed) == set()
     want = _collectives(_forward_chunk_lowered(cfg, 256, mesh, v5e).compile())
     assert want and _collectives(packed.compile()) == want
+    # the two all-reduces of a layer (attention out, MLP or expert
+    # combine), in the layer scan's body: what it held before the routed
+    # expert layer existed, which a mesh does not take
+    assert want == ["all-reduce", "all-reduce"]
 
 
 @pytest.mark.parametrize("s", [1, 256])
