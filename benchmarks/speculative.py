@@ -209,11 +209,6 @@ def main() -> None:
                          "go straight to the serving comparison (the "
                          "micro vanilla baseline decodes per-token, which "
                          "dominates wall-clock at long max-tokens)")
-    ap.add_argument("--serving-target-step-ms", type=float, default=400.0,
-                    help="batcher round-latency target for the serving "
-                         "comparison; must exceed one host round (not "
-                         "measured on the current chip) or the paged "
-                         "horizon collapses to 1 and BOTH sides crawl")
     ap.add_argument("--spec-max-batch", type=int, default=2,
                     help="batcher routing knob: spec fires only when the "
                          "entire waiting load is <= this many greedy "
@@ -263,8 +258,6 @@ def main() -> None:
                     "--rounds-per-dispatch", str(args.rounds_per_dispatch),
                     "--spec-max-batch", str(args.spec_max_batch),
                     "--spec-max-active", str(args.spec_max_active),
-                    "--serving-target-step-ms",
-                    str(args.serving_target_step_ms),
                     "--serving-requests", str(args.serving_requests),
                     "--distill-data", args.distill_data]
             if args.serving_rate:
@@ -474,7 +467,6 @@ def main() -> None:
             default_timeout_s=600.0,
             spec_max_batch=args.spec_max_batch,
             spec_max_active=args.spec_max_active,
-            target_step_latency_ms=args.serving_target_step_ms,
         )
         # warm every wave width the router can start (each is a distinct
         # scan-graph batch shape) — with the SERVING budget, so the same

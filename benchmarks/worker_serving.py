@@ -39,7 +39,7 @@ without trained draft weights.
 Usage (SLO row / throughput row / ragged-vs-knob-tuned):
     python -m benchmarks.worker_serving --arrival-rate 1.5 --requests 64 \
         --prompt-len 512 --max-tokens 128 --concurrency 16 \
-        --target-step-ms 400 --subwave 2 --interleave 2 --max-horizon 4 \
+        --subwave 2 --interleave 2 --max-horizon 4 \
         --compare
     python -m benchmarks.worker_serving --requests 64 --concurrency 32 \
         --prompt-len 128 --max-tokens 64 --compare
@@ -2529,7 +2529,6 @@ def _build_serving_llm(args: Any, model: str, spec_k: int = 0,
         + max(args.spec_k, 1) + 2,
         "quantization": args.quantization,
         "serving": {
-            "target_step_ms": args.target_step_ms,
             "queue_limit": max(4096, args.requests * 2),
             "default_timeout_s": 600.0,
         },
@@ -2913,7 +2912,6 @@ def run_long_context(args: Any, backend: str, model: str) -> None:
         # compiled and bill cold XLA compiles to the budgeted leg
         "prefill_buckets": tuple(sorted({bud_w, chunk})),
         "serving": {
-            "target_step_ms": args.target_step_ms,
             "queue_limit": max(4096, args.requests * 2),
             "default_timeout_s": 1800.0,
             "ragged_chunk": chunk,
@@ -3050,7 +3048,6 @@ def main() -> None:
                     "sweep one engine); omit for the closed-loop "
                     "throughput row")
     ap.add_argument("--seed", type=int, default=7)
-    ap.add_argument("--target-step-ms", type=float, default=400.0)
     ap.add_argument("--subwave", type=int, default=0)
     ap.add_argument("--interleave", type=int, default=0)
     ap.add_argument("--max-horizon", type=int, default=64)
@@ -3296,7 +3293,6 @@ def main() -> None:
         "max_seq_len": args.prompt_len + args.max_tokens + 16,
         "quantization": args.quantization,
         "serving": {
-            "target_step_ms": args.target_step_ms,
             "max_horizon": args.max_horizon,
             "subwave": args.subwave,
             "interleave": args.interleave,
@@ -3339,7 +3335,6 @@ def main() -> None:
                 "prompt_len": args.prompt_len,
                 "max_tokens": args.max_tokens,
                 "arrival_rate_rps": rate,
-                "target_step_ms": args.target_step_ms,
                 "subwave": args.subwave, "interleave": args.interleave,
                 "max_horizon": args.max_horizon,
                 "deployed": deployed,

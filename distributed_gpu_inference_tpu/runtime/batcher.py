@@ -12,10 +12,11 @@ TPU serving:
   joins/leaves the batch between steps, nothing waits for stragglers).
 - Prefix grouping doesn't reorder a Python batch; it orders *admission* so
   sequences sharing cached prefix blocks land while those pages are hot.
-- The adaptive knob is the **multi-step scan horizon** (device steps per host
+- What adapts is the **multi-step scan horizon** (device steps per host
   round-trip): deep horizon = throughput, shallow = admission latency. The
-  reference tunes batch size ±20% against a latency target; we tune the
-  horizon by the same rule.
+  reference tunes batch size ±20% against a latency target; here one rule
+  over two measured times chooses every scan's length
+  (``ContinuousBatcher._choose_steps``) and no configuration names a number.
 
 Engine calls execute on a single dedicated thread (the engine is not
 thread-safe); the asyncio side only schedules and resolves futures.
@@ -50,6 +51,38 @@ from distributed_gpu_inference_tpu.utils.data_structures import (
 from distributed_gpu_inference_tpu.utils.data_structures import KV_BLOCK_TOKENS
 
 log = logging.getLogger(__name__)
+
+# The horizon rule's one constant (``ContinuousBatcher._retune``): a scan of T
+# steps is long enough when it takes at least this many times what a round
+# costs the host whatever it holds, so that the host's fixed cost is at most
+# 1 / (1 + _HOST_AMORTISE) of the time. Not a configuration key: the step time
+# and the host's cost are measured, and a model's own numbers choose its level.
+# (Fixed on the v5e, PERF.md PR 26: at 3 a dense 7B with all 8 rows decoding
+# sat on the edge between T=1 and T=4, its one-step round costing the host
+# 4.0-4.3 ms against an 11.5 ms step.)
+_HOST_AMORTISE = 4.0
+# a level changes only when that inequality holds or fails with this much to
+# spare, so a step time that wanders with the occupancy does not flap it
+_LEVEL_SLACK = 0.1
+# the two measured times are means (a share of time is bought with the mean
+# cost, not the typical one) over about ten scans; one sample counts as at
+# most twice and at least half of the mean it joins, so that one stalled
+# round (on the v5e a third of a saturated worker's rounds cost the host
+# 20 ms where the others cost 6) cannot carry the level past a threshold
+_EMA_WEIGHT = 0.1
+# what the engine thread pays around the device's work in a plain round
+# (``engine.stats``; readback, the wait for the device, is not among them)
+_HOST_PHASES = ("round_build_s", "round_dispatch_s", "round_commit_s")
+# why a scan got its length, counted as ``scans_<reason>``
+_SCAN_REASONS = ("amortise", "raised_waiting", "capped_by_budget")
+
+
+def _mean(mean: float, sample: float) -> float:
+    """``mean`` moved toward ``sample`` by ``_EMA_WEIGHT``; the first sample
+    is the mean, a later one counts as at most twice and at least half of it."""
+    if mean == 0:
+        return sample
+    return mean + _EMA_WEIGHT * (min(max(sample, mean / 2), mean * 2) - mean)
 
 
 class RequestMigrated(Exception):
@@ -94,8 +127,7 @@ class BatcherConfig:
     multi_step: int = 8               # initial decode horizon
     min_multi_step: int = 1
     max_multi_step: int = 64
-    adaptive: bool = True
-    target_step_latency_ms: float = 100.0  # per host round-trip
+    adaptive: bool = True             # False: every scan runs multi_step
     queue_limit: int = 1024
     default_timeout_s: float = 300.0
     # KV-pressure preemption policy: a request preempted more than this
@@ -106,11 +138,6 @@ class BatcherConfig:
     # with their full generated context, so resume restores spilled/cached
     # pages instead of recomputing.
     max_preemptions: int = 3
-    # horizon when admission work is waiting: bounded so a queued request
-    # never waits more than this many decode steps for a slot, while still
-    # amortizing host round-trips (decode_step per token would pay one
-    # host round per token)
-    busy_multi_step: int = 4
     # adaptive speculation (VERDICT r3 #7): when a SpeculativeDecoder is
     # attached and the ENTIRE waiting load is <= this many greedy requests,
     # they decode through the spec tree — the low-depth regime where
@@ -171,7 +198,7 @@ class BatcherConfig:
     def horizon_levels(self) -> Tuple[int, ...]:
         """The ONLY decode horizons the batcher may request. decode_multi
         compiles one scan per distinct T — an unquantized adaptive horizon
-        triggers an XLA compile mid-serving for nearly every retune. Powers
+        triggers an XLA compile mid-serving for nearly every change. Powers
         of four between the min/max bound the graph count at 4."""
         levels = [t for t in (1, 4, 16, 64)
                   if self.min_multi_step <= t <= self.max_multi_step]
@@ -286,6 +313,9 @@ class ContinuousBatcher:
         self._levels: Tuple[int, ...] = ()
         self._level = 0
         self._horizon = 0.0
+        # the horizon rule's ``h`` by scan length: what a round of that
+        # many steps costs the host, in ms (``_retune``)
+        self._host_ms: Dict[int, float] = {}
         self._rebuild_levels(float(self.cfg.multi_step))
         self._slot_items: Dict[int, _QueueItem] = {}
         # admission stamps for LIFO victim selection (slot indices recycle,
@@ -322,7 +352,16 @@ class ContinuousBatcher:
         self.stats: Dict[str, Any] = {
             "submitted": 0, "completed": 0, "rejected": 0, "timeouts": 0,
             "decode_rounds": 0, "admitted": 0, "queue_peak": 0,
-            "step_latency_ema_ms": 0.0, "occupancy_sum": 0, "horizon": self._horizon,
+            # the horizon rule's two measured times (means over scans): a
+            # scan's device time per step, and what a round at the
+            # ``horizon`` level costs the host whatever it holds;
+            # ``horizon`` is the level they amortise at
+            "step_latency_ema_ms": 0.0, "round_host_ema_ms": 0.0,
+            "horizon": self._horizon, "occupancy_sum": 0,
+            # why each scan got its length, and the row-steps scans ran
+            # for rows that had already finished inside them
+            **{f"scans_{reason}": 0 for reason in _SCAN_REASONS},
+            "scan_row_steps_masked": 0,
             "chunked_admissions": 0, "batched_waves": 0,
             "ragged_admissions": 0, "ragged_rounds": 0,
             "budgeted_rounds": 0, "budget_skipped_admissions": 0,
@@ -375,8 +414,8 @@ class ContinuousBatcher:
         """THE quantized-horizon level-set derivation (init + live
         reconfigure): adaptive mode exposes the power-of-4 levels, fixed
         mode honors the clamped ``multi_step`` verbatim; the current level
-        snaps to the one nearest ``anchor`` so a retune never requests an
-        uncompiled scan length mid-flight."""
+        snaps to the one nearest ``anchor`` (``multi_step`` before anything
+        is measured) so no scan ever asks for an uncompiled length."""
         if self.cfg.adaptive:
             levels = self.cfg.horizon_levels
         else:
@@ -754,8 +793,8 @@ class ContinuousBatcher:
         any :class:`BatcherConfig` field by name (None values are ignored).
         Horizon-shaping fields (``max_multi_step``, ``min_multi_step``,
         ``multi_step``, ``adaptive``) rebuild the quantized level set; the
-        current level snaps to the nearest surviving horizon so retuning
-        never requests an uncompiled scan length mid-flight.
+        current level snaps to the nearest surviving horizon so no scan
+        requests an uncompiled length mid-flight.
 
         ``ragged_chunk`` is the one ENGINE knob accepted here (PR 17):
         the per-admission chunk-row width of ragged rounds. It is read
@@ -1422,10 +1461,9 @@ class ContinuousBatcher:
             # so a request reactive mode would carry to its deadline and
             # then drop is dropped now, before burning the rounds
             return False
-        # observed latency of a decode scan (ragged rounds are left out of
-        # the EMA: ``_run``), read as the inter-token latency; floor at 1ms
-        # so a cold EMA (no scan yet) still projects SOME forward progress
-        # instead of 0
+        # observed time of one step of a decode scan (``_retune``), read as
+        # the inter-token latency; floor at 1ms so a cold EMA (no scan yet)
+        # still projects SOME forward progress instead of 0
         itl_s = max(float(self.stats["step_latency_ema_ms"]), 1.0) / 1000.0
         return now + tokens_left * itl_s > \
             deadline_at + self.cfg.deadline_grace_s
@@ -1593,8 +1631,46 @@ class ContinuousBatcher:
             )
         return {adm.slot: g for adm, g in zip(adms, grants)}
 
-    def _engine_round(self) -> float:
-        """One blocking engine round on the worker thread. Returns latency ms.
+    def _choose_steps(self) -> Tuple[int, str]:
+        """THE horizon rule: how many steps the next scan runs, and why.
+
+        ``amortise`` is the level ``_retune`` keeps: the shortest scan in
+        which the host's fixed cost of a round is a bounded share of the
+        time. Nobody waits for a slot: the device has slack, so the scan is
+        that short — a stream stalls for one short scan, an arrival waits
+        for at most one. Requests wait (the heap is non-empty at a scan:
+        every slot is full, or the pool is) and nothing can be admitted
+        before a row ends: the device is what is short, so the scan runs one
+        level longer to buy the host's share down — unless a decoding row
+        can end inside it (its remaining budget, short of a stop token),
+        because that is the first moment a slot can come free. A legacy
+        chunked admission in flight advances between rounds, so the queue
+        behind it waits for rounds to end, not for a slot."""
+        levels = self._levels
+        amortise = levels[self._level]
+        raised = levels[min(self._level + 1, len(levels) - 1)]
+        if not self._heap or self._chunked is not None or raised == amortise:
+            return amortise, "amortise"
+        budgets = self.engine.decode_budgets()
+        if raised > budgets[budgets > 0].min(initial=raised):
+            return amortise, "capped_by_budget"
+        return raised, "raised_waiting"
+
+    @staticmethod
+    def _host_phases_s(engine_stats: Dict[str, Any]) -> float:
+        return sum(engine_stats.get(k, 0.0) for k in _HOST_PHASES)
+
+    def _engine_round(self) -> Optional[Tuple[int, float, float]]:
+        """One blocking engine round on the worker thread. Returns what a
+        scan measured for ``_retune`` — (steps, the scan's seconds less the
+        engine's host phases, the host's seconds: the gap since the last
+        round plus those phases) — and None after a ragged round, which is
+        as long as the prompt tokens it admits, whatever the level, and so
+        says nothing about how long a scan should be. (The gap *before* a
+        scan, not the one after: a row that ends in a scan is followed by
+        its slot's next admission, which is no cost of a round; that gap
+        falls to the ragged round it precedes. On the v5e, charged to the
+        scan, it carried two cells' level to T=16: PERF.md, PR 26.)
 
         Ragged mode with admissions in flight dispatches ONE
         ``engine.ragged_round``: every active decode slot advances one
@@ -1606,56 +1682,82 @@ class ContinuousBatcher:
         better dispatch for the identical math and runs instead."""
         t0 = time.perf_counter()
         st = self.stats
+        gap = 0.0
         if self._round_end is not None:
-            st["between_rounds_s"] += t0 - self._round_end
+            gap = t0 - self._round_end
+            st["between_rounds_s"] += gap
             st["between_rounds"] += 1
         engine_stats = getattr(self.engine, "stats", None) or {}
         n = self._round = int(engine_stats.get("rounds", self._round)) + 1
-        level = self._levels[self._level]
         ragged = bool(self._ragged)
-        steps = 1 if ragged else level
-        if not ragged and (self._heap or self._chunked is not None):
-            # work is waiting (queued requests or a mid-prefill chunked
-            # admission): bounded horizon so admission latency stays low
-            # without falling back to one-RTT-per-token stepping; snap
-            # to the largest level ≤ the cap, or the smallest level when
-            # every level exceeds it (only compiled lengths may run)
-            cap = min(steps, self.cfg.busy_multi_step)
-            eligible = [t for t in self._levels if t <= cap]
-            steps = max(eligible) if eligible else min(self._levels)
+        steps, reason = (1, "ragged") if ragged else self._choose_steps()
         try:
             with flight.span("dgi.batcher.round", st,
                              None if ragged else f"scan_s_t{steps}",
                              round=n, kind="ragged" if ragged else "scan",
-                             steps=steps, level=level,
-                             queue_depth=len(self._heap)):
+                             steps=steps, level=self._levels[self._level],
+                             reason=reason, queue_depth=len(self._heap)):
                 if ragged:
                     adms = [adm for adm, _ in self._ragged]
                     self.engine.ragged_round(
                         adms, self._prefill_chunk_caps(adms))
                     st["ragged_rounds"] += 1
-                else:
-                    st[f"scans_t{steps}"] = st.get(f"scans_t{steps}", 0) + 1
-                    self.engine.decode_multi(steps)
-            return (time.perf_counter() - t0) * 1000.0
+                    return None
+                st[f"scans_t{steps}"] = st.get(f"scans_t{steps}", 0) + 1
+                st[f"scans_{reason}"] += 1
+                host = -self._host_phases_s(engine_stats)
+                emitted = self.engine.decode_multi(steps)
+                host += self._host_phases_s(engine_stats)
+                # a row that started the scan and emitted fewer tokens than
+                # it has steps had finished inside it (a speculative step
+                # emits several: never counted negative)
+                st["scan_row_steps_masked"] += sum(
+                    max(0, steps - len(toks)) for toks in emitted.values())
+            return steps, time.perf_counter() - t0 - host, gap + host
         finally:
             self._round_end = time.perf_counter()
 
-    def _retune(self, latency_ms: float) -> None:
-        """AdaptiveBatcher analogue (reference :413-431): one quantized
-        horizon level up/down against the latency target — levels only, so
-        the set of compiled decode graphs stays bounded."""
-        ema = self.stats["step_latency_ema_ms"]
-        ema = latency_ms if ema == 0 else 0.8 * ema + 0.2 * latency_ms
-        self.stats["step_latency_ema_ms"] = ema
-        if not self.cfg.adaptive:
-            return
-        if ema > self.cfg.target_step_latency_ms * 1.1:
-            self._level = max(0, self._level - 1)
-        elif ema < self.cfg.target_step_latency_ms * 0.9:
-            self._level = min(len(self._levels) - 1, self._level + 1)
-        self._horizon = float(self._levels[self._level])
-        self.stats["horizon"] = self._horizon
+    def _retune(self, steps: int, scan_s: float, host_s: float) -> None:
+        """Keep the rule's measured times — ``s``, a scan's time per step,
+        and ``h``, what a round costs the host whatever it holds — and the
+        level they amortise at: the smallest T with
+        T · s ≥ ``_HOST_AMORTISE`` · h, changed only when the inequality
+        holds or fails with ``_LEVEL_SLACK`` to spare. Levels only, so the
+        set of compiled decode graphs stays bounded; a fixed horizon
+        (``adaptive: false``) has one level and keeps it.
+
+        ``h`` is kept per scan length, and a level is judged by its own:
+        the host's time around a longer scan also holds work that grows
+        with its steps (block reservation, streaming its tokens), which no
+        longer scan buys down — judged by a raised scan's cost the level
+        would call for a longer scan still, and stay there. The level below
+        is judged by its own cost or the current level's, whichever is less
+        (a shorter scan costs the host no more, so what is measured now
+        corrects a figure from a busier or emptier moment), and without
+        the slack while it has never run: one visit measures it. Until the
+        current level has run, the level stays. (Nothing refreshes the cost
+        of a level the batcher has left upward, so a level that failed in a
+        disturbed moment stays failed while the current one costs more:
+        letting that figure fade was tried on the v5e and cost the decode
+        cell 3 % in visits to T=1; PERF.md section 7.)"""
+        st = self.stats
+        st["step_latency_ema_ms"] = s = _mean(
+            st["step_latency_ema_ms"], scan_s * 1e3 / steps)
+        self._host_ms[steps] = _mean(
+            self._host_ms.get(steps, 0.0), host_s * 1e3)
+        levels, level, h = self._levels, self._level, self._host_ms.get
+        inf = float("inf")
+        while level + 1 < len(levels) and levels[level] * s < \
+                _HOST_AMORTISE * h(levels[level], 0.0) * (1.0 - _LEVEL_SLACK):
+            level += 1
+        while level > 0 and levels[level - 1] * s >= _HOST_AMORTISE * min(
+                h(levels[level - 1], inf), h(levels[level], inf)
+        ) * (1.0 + _LEVEL_SLACK * (levels[level - 1] in self._host_ms)):
+            level -= 1
+        self._level = level
+        self._horizon = float(levels[level])
+        st["horizon"] = self._horizon
+        st["round_host_ema_ms"] = h(levels[level], 0.0)
 
     async def _run(self) -> None:
         loop = asyncio.get_running_loop()
@@ -1717,9 +1819,7 @@ class ContinuousBatcher:
                     await asyncio.sleep(0.001)
                 continue
             try:
-                # read before the round: its final chunks leave _ragged below
-                scan = not self._ragged
-                latency = await loop.run_in_executor(
+                measured = await loop.run_in_executor(
                     self._exec, self._engine_round
                 )
                 with flight.span("dgi.batcher.deliver", self.stats,
@@ -1727,12 +1827,9 @@ class ContinuousBatcher:
                     finished = 0
                     self.stats["decode_rounds"] += 1
                     self.stats["occupancy_sum"] += self.engine.num_active
-                    # only a scan's latency steers the scan level: a
-                    # ragged round is as long as the prompt tokens it
-                    # admits, whatever the level, so it says nothing
-                    # about how long a scan may be
-                    if scan:
-                        self._retune(latency)
+                    # only what a scan measured steers the scan level
+                    if measured:
+                        self._retune(*measured)
                     # admission-chunk rounds on the timeline: one bounded
                     # note per in-flight traced admission per round
                     # (saturates at the per-request event cap on

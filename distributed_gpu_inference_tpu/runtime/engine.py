@@ -3387,28 +3387,12 @@ class TPUEngine:
         """The host's half of a scan before the dispatch: who decodes, the
         budgets, block reservation for the horizon, pending pool ops, the
         uploads. None when no row is left to run."""
-        active_mask = np.array(
-            [s is not None and s.finish_reason is None and not s.prefilling
-             for s in self.slots]
-        )
-        if not active_mask.any():
-            return None
         # per-slot token budgets enforced ON DEVICE (scan masks a slot once
         # it emits its allowance) — num_steps stays the compiled constant
         # instead of shrinking to the shortest slot and recompiling per
         # distinct tail length
-        budgets = np.array(
-            [
-                min(
-                    s.request.sampling.max_new_tokens - len(s.generated),
-                    self.cfg.max_seq_len - int(self._kv_lens[i]),
-                ) if active_mask[i] and s is not None else 0
-                for i, s in enumerate(self.slots)
-            ],
-            dtype=np.int32,
-        )
-        budgets = np.maximum(budgets, 0)
-        active_mask &= budgets > 0
+        budgets = self.decode_budgets()
+        active_mask = budgets > 0
         if not active_mask.any():
             return None
         # pre-reserve KV blocks for each slot's actual horizon (no host
@@ -3447,6 +3431,25 @@ class TPUEngine:
             active_mask, budgets.astype(np.int32)
         )
         return active_mask, (core, tables, act_d, bud_d), self._decode_mode()
+
+    def decode_budgets(self) -> np.ndarray:
+        """Tokens each slot may still emit, ``[max_batch_size]`` int32: what
+        is left of ``max_new_tokens`` and of the context, 0 for a slot that
+        does not decode (empty, finished, mid-prefill). The next scan masks
+        a row after that many steps; the smallest positive entry is the
+        first step at which a slot can come free short of a stop token
+        (the batcher's horizon rule reads it)."""
+        return np.array(
+            [
+                max(0, min(
+                    s.request.sampling.max_new_tokens - len(s.generated),
+                    self.cfg.max_seq_len - int(self._kv_lens[i]),
+                )) if s is not None and s.finish_reason is None
+                and not s.prefilling else 0
+                for i, s in enumerate(self.slots)
+            ],
+            dtype=np.int32,
+        )
 
     def finish_slot(self, slot: int, cache: bool = True) -> InferenceResponse:
         s = self.slots[slot]

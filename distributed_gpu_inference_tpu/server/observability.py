@@ -101,6 +101,8 @@ class Metrics:
                 "batcher_chunked_admissions", "batcher_preemptions",
                 "batcher_migrated", "batcher_round_gaps",
                 "batcher_loop_seconds", "batcher_scans",
+                "batcher_scan_step_ms", "batcher_round_host_ms",
+                "batcher_scan_reasons", "batcher_scan_row_steps_masked",
                 "engine_round_seconds", "worker_compiles",
                 "worker_compile_seconds",
                 "prefix_route_hits", "prefix_route_spillover",
@@ -239,8 +241,22 @@ class Metrics:
             registry=r)
         self.batcher_horizon = Gauge(
             "batcher_horizon",
-            "Current adaptive decode horizon (device steps per host "
-            "round-trip)", ["worker"], registry=r)
+            "The scan length (device steps per host round-trip) at which "
+            "the batcher's horizon rule amortises the host's cost of a "
+            "round; a scan runs one level above it while requests wait "
+            "for a slot", ["worker"], registry=r)
+        self.batcher_scan_step_ms = Gauge(
+            "batcher_scan_step_ms",
+            "A decode scan's time per step less the engine's host phases "
+            "(mean over about ten scans): the horizon rule's s", ["worker"],
+            registry=r)
+        self.batcher_round_host_ms = Gauge(
+            "batcher_round_host_ms",
+            "What a round costs the host whatever it holds: the gap since "
+            "the last round plus the engine's build, dispatch and commit "
+            "(mean over the scans that ran batcher_horizon steps): the "
+            "horizon rule's h", ["worker"],
+            registry=r)
         self.batcher_decode_rounds = Counter(
             "batcher_decode_rounds_total",
             "Engine decode rounds driven by the batcher", ["worker"],
@@ -281,6 +297,18 @@ class Metrics:
             "batcher_scans_total",
             "decode_multi rounds dispatched, by scan length (the horizon "
             "levels the traffic reached)", ["worker", "steps"], registry=r)
+        self.batcher_scan_reasons = Counter(
+            "batcher_scan_reasons_total",
+            "decode_multi rounds by why they got their length: amortise "
+            "(nobody waits for a slot), raised_waiting (one level longer "
+            "while requests wait), capped_by_budget (requests wait, but a "
+            "row can end inside the longer scan)", ["worker", "reason"],
+            registry=r)
+        self.batcher_scan_row_steps_masked = Counter(
+            "batcher_scan_row_steps_masked_total",
+            "Row-steps scans ran for rows that had already finished "
+            "inside them (a slot held past its row's end)", ["worker"],
+            registry=r)
         # readback is the engine thread waiting for the device; its share
         # of the four says whether the host or the chip bounds the rounds
         self.engine_round_seconds = Counter(
@@ -695,6 +723,8 @@ class MetricsCollector:
             ("active_slots", self.metrics.batcher_active_slots),
             ("avg_occupancy", self.metrics.batcher_occupancy),
             ("horizon", self.metrics.batcher_horizon),
+            ("step_latency_ema_ms", self.metrics.batcher_scan_step_ms),
+            ("round_host_ema_ms", self.metrics.batcher_round_host_ms),
         ):
             if key not in stats:
                 continue
@@ -738,6 +768,12 @@ class MetricsCollector:
                 metric = self.metrics.batcher_round_gaps.labels(worker)
             elif key.startswith("scans_t") and key[7:].isdigit():
                 metric = self.metrics.batcher_scans.labels(worker, key[7:])
+            elif key.startswith("scans_"):
+                metric = self.metrics.batcher_scan_reasons.labels(
+                    worker, key[6:])
+            elif key == "scan_row_steps_masked":
+                metric = self.metrics.batcher_scan_row_steps_masked.labels(
+                    worker)
             elif key.startswith("moe_"):
                 name, _, kind = key[4:].rpartition("_")
                 if name not in self.metrics.worker_moe:

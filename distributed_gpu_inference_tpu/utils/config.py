@@ -31,10 +31,11 @@ ENV_PREFIX = "TPU_WORKER_"
 # Serving knobs obsoleted by the round-6 ragged serving path (one kernel
 # invocation carrying prefill-chunk AND decode rows — admission appends
 # rows to the next round instead of scheduling competing dispatches, so
-# the admission-stall shaping these knobs tuned no longer exists). They
-# stay ACCEPTED in worker YAML and remote pushes (rolling fleets, saved
-# SLO configs) but are warned once per process; only the legacy path
-# (``serving.ragged: false``) still reads them.
+# the admission-stall shaping these knobs tuned no longer exists) and by
+# the batcher's horizon rule. They stay ACCEPTED in worker YAML and remote
+# pushes (rolling fleets, saved SLO configs) but are warned once per
+# process; only the legacy path (``serving.ragged: false``) still reads
+# the first two, and nothing reads ``target_step_ms``.
 DEPRECATED_SERVING_KEYS: Dict[str, str] = {
     "subwave": (
         "the ragged serving path admits by appending chunk rows to the "
@@ -51,6 +52,11 @@ DEPRECATED_SERVING_KEYS: Dict[str, str] = {
         "TTFT-shaping knob: admission latency is bounded by the ragged "
         "round itself, not by capping decode-scan depth"
     ),
+    "target_step_ms": (
+        "ignored: the batcher chooses a scan's length from the step time "
+        "and the host's cost per round that it measures (runtime/batcher.py "
+        "_choose_steps), not from a latency target"
+    ),
 }
 _deprecated_serving_warned: Set[str] = set()
 
@@ -65,7 +71,7 @@ def warn_deprecated_serving_key(key: str, source: str) -> None:
         return
     _deprecated_serving_warned.add(key)
     log.warning(
-        "serving.%s (%s) is deprecated since the ragged serving round: %s",
+        "serving.%s (%s) is deprecated: %s",
         key, source, DEPRECATED_SERVING_KEYS[key],
     )
 
@@ -127,18 +133,19 @@ class ServingConfig(BaseModel):
     the admission-stall shaping knobs: ``subwave`` / ``interleave`` /
     ``max_horizon`` are still accepted (and ``max_horizon`` still caps the
     pure-decode scan) but log a one-time deprecation warning when set —
-    see ``DEPRECATED_SERVING_KEYS``. ``target_step_ms`` / ``queue_limit``
-    / ``max_wait_ms`` / ``ragged`` are remote-pushable (server
+    see ``DEPRECATED_SERVING_KEYS``, which also holds ``target_step_ms``
+    (accepted, ignored). ``queue_limit`` / ``max_wait_ms`` / ``ragged`` /
+    ``max_horizon`` are remote-pushable (server
     ``WorkerRemoteConfig.serving``) and retune a LIVE batcher;
     ``subwave`` / ``interleave`` / ``mode`` are compile-affecting and
     apply at engine load only."""
 
     mode: str = "batcher"               # batcher | direct (legacy driving)
-    target_step_ms: float = 100.0       # adaptive round-latency target
+    target_step_ms: Optional[float] = None   # DEPRECATED: read by nothing
     max_horizon: int = 64               # decode-scan cap (DEPRECATED knob)
     min_horizon: int = 1
     multi_step: int = 8                 # initial decode horizon
-    adaptive: bool = True
+    adaptive: bool = True               # False: every scan runs multi_step
     max_wait_ms: float = 5.0            # admission latch
     queue_limit: int = 1024
     default_timeout_s: float = 300.0

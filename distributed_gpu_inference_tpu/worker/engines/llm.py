@@ -37,7 +37,7 @@ from ...utils.backoff import full_jitter_delay
 from ...runtime.engine import EngineConfig, PreemptedSequence, TPUEngine
 from ...runtime.flight import NULL_TIMELINE, timeline_for
 from ...runtime.prefix_summary import TIER_HOST, TIER_SPILL, PrefixHotSet
-from ...utils.config import ServingConfig
+from ...utils.config import ServingConfig, warn_deprecated_serving_key
 from ...utils.data_structures import InferenceRequest, SamplingParams
 from .base import (
     EngineLoadError,
@@ -68,7 +68,6 @@ SERVING_DEFAULTS: Dict[str, Any] = ServingConfig().model_dump()
 # remote-config ``serving`` keys that may retune a LIVE batcher (pushed via
 # WorkerRemoteConfig; the compile-affecting admission knobs are excluded)
 SERVING_REMOTE_KEYS: Dict[str, str] = {
-    "target_step_ms": "target_step_latency_ms",
     "max_horizon": "max_multi_step",
     "min_horizon": "min_multi_step",
     "multi_step": "multi_step",
@@ -616,8 +615,6 @@ class TPULLMEngine(LLMBaseEngine):
     def _serving_config(self) -> Dict[str, Any]:
         """Merged serving knobs: defaults < ``config['serving']`` (worker
         YAML ``engines.llm.serving.*``) < ``extra['serving']``."""
-        from ...utils.config import warn_deprecated_serving_key
-
         out = dict(SERVING_DEFAULTS)
         for src in (self.config.get("serving"),
                     (self.config.get("extra") or {}).get("serving")):
@@ -643,7 +640,6 @@ class TPULLMEngine(LLMBaseEngine):
             min_multi_step=int(sv["min_horizon"]),
             max_multi_step=int(sv["max_horizon"]),
             adaptive=bool(sv["adaptive"]),
-            target_step_latency_ms=float(sv["target_step_ms"]),
             queue_limit=int(sv["queue_limit"]),
             default_timeout_s=float(sv["default_timeout_s"]),
             max_preemptions=int(sv["max_preemptions"]),
@@ -661,9 +657,12 @@ class TPULLMEngine(LLMBaseEngine):
         """Server-pushed SLO retune (remote config ``serving`` section):
         applied to the LIVE batcher between rounds. Compile-affecting
         admission knobs (``subwave``/``interleave``) and ``mode`` are
-        load-time only and ignored here."""
+        load-time only and ignored here, as is ``target_step_ms``, which
+        nothing reads any more (warned once)."""
         if self.serving is None or not updates:
             return
+        for key in updates:
+            warn_deprecated_serving_key(key, "remote config push")
         kw = {
             SERVING_REMOTE_KEYS[k]: v
             for k, v in updates.items()

@@ -303,8 +303,8 @@ class Worker:
             )
         serving = remote.get("serving")
         if isinstance(serving, dict) and serving:
-            # server-pushed SLO retune: batcher knobs (target_step_ms,
-            # max_horizon, queue limits) apply to LIVE batchers between
+            # server-pushed SLO retune: batcher knobs (max_horizon,
+            # max_wait_ms, queue limits) apply to LIVE batchers between
             # rounds — no engine reload, no dropped requests
             for eng in self.engines.values():
                 apply = getattr(eng, "apply_serving_config", None)
@@ -492,8 +492,11 @@ class Worker:
                            ("round_dispatch_s", es),
                            ("round_readback_s", es), ("round_commit_s", es)):
                 out[k] = round(out.get(k, 0.0) + float(src.get(k, 0) or 0), 6)
+            # scans by level (scans_t<T>) and by why they got their length
+            # (scans_<reason>), and the row-steps they ran past a row's end
             for k in s:
-                if k == "between_rounds" or k.startswith("scans_t"):
+                if k in ("between_rounds", "scan_row_steps_masked") \
+                        or k.startswith("scans_"):
                     out[k] = out.get(k, 0) + int(s[k] or 0)
             # the routed expert layers' counters (MoE engines only)
             for k in es:
@@ -503,8 +506,11 @@ class Worker:
                 out["avg_occupancy"] = round(
                     float(s.get("avg_occupancy") or 0.0), 3
                 )
-            if s.get("horizon") is not None:
-                out["horizon"] = float(s["horizon"])
+            # the horizon rule's gauges: the level the host's cost is
+            # amortised at, and the two measured times that choose it
+            for k in ("horizon", "step_latency_ema_ms", "round_host_ema_ms"):
+                if s.get(k) is not None:
+                    out[k] = round(float(s[k]), 3)
         if out:
             # shared-claim ceiling: lets the scheduler GRADE this worker's
             # load (active + queued vs capacity) instead of reading the

@@ -26,6 +26,7 @@ import sys
 import types
 from typing import Dict, List, Optional
 
+import numpy as np
 import pytest
 
 from distributed_gpu_inference_tpu.runtime.batcher import (
@@ -281,12 +282,26 @@ class FakeRaggedEngine:
                 self._adm.pop(adm.slot, None)
         self.round_grants.append(grants)
 
-    def decode_multi(self, steps) -> None:
+    def _decoding(self) -> List[int]:
+        return [i for i, s in enumerate(self.slots)
+                if s is not None and s.finish_reason is None
+                and i not in self._adm]
+
+    def decode_budgets(self) -> np.ndarray:
+        out = np.zeros(len(self.slots), dtype=np.int32)
+        for i in self._decoding():
+            s = self.slots[i]
+            out[i] = s.request.sampling.max_new_tokens - len(s.generated)
+        return out
+
+    def decode_multi(self, steps) -> Dict[int, List[int]]:
+        rows = self._decoding()
+        before = {i: len(self.slots[i].generated) for i in rows}
         for _ in range(max(1, int(steps))):
-            for i, s in enumerate(self.slots):
-                if s is not None and s.finish_reason is None \
-                        and i not in self._adm:
+            for i in rows:
+                if self.slots[i].finish_reason is None:
                     self._decode_one(i)
+        return {i: self.slots[i].generated[n:] for i, n in before.items()}
 
     def finish_slot(self, slot: int) -> InferenceResponse:
         s = self.slots[slot]

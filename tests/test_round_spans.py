@@ -217,6 +217,30 @@ def test_counters_scans_by_level(served):
     assert "scans_t16" not in b         # only configured levels are counted
 
 
+def test_counters_and_spans_say_why_each_scan_got_its_length(served):
+    b = served["batcher"]
+    reasons = ("amortise", "raised_waiting", "capped_by_budget")
+    rounds = [s for s in served["spans"] if s["name"] == "dgi.batcher.round"]
+    for reason in reasons:
+        assert b[f"scans_{reason}"] == sum(
+            1 for s in rounds if s["reason"] == reason)
+    assert sum(b[f"scans_{r}"] for r in reasons) == sum(
+        b[f"scans_t{t}"] for t in served["levels"])
+    assert all(s["reason"] == "ragged" for s in rounds
+               if s["kind"] == "ragged")
+    # a scan runs the level the rule is at, or the one above it
+    assert all(s["steps"] in (s["level"], 4 * s["level"]) for s in rounds
+               if s["kind"] == "scan")
+    # the rule's two measured times were sampled from the engine's phases
+    assert b["step_latency_ema_ms"] > 0 and b["round_host_ema_ms"] > 0
+    # six requests of a few tokens each on scans of 1 or 4 steps: rows
+    # ended inside scans, by less than a scan each
+    scans = [s for s in served["spans"]
+             if s["name"] == "dgi.engine.decode_multi"]
+    assert 0 <= b["scan_row_steps_masked"] < sum(
+        s["steps"] * s["decode_rows"] for s in scans)
+
+
 def test_counters_time_adds_up(served):
     e, b = served["engine"], served["batcher"]
     phases = sum(e[f"round_{p}_s"] for p in PHASES)
@@ -333,7 +357,10 @@ def test_worker_ships_round_counters_and_the_plane_counts_their_deltas():
 
     one = {"decode_rounds": 10, "between_rounds_s": 0.5, "between_rounds": 9,
            "admit_s": 0.2, "deliver_s": 0.25, "scans_t1": 0, "scans_t4": 7,
-           "scan_s_t4": 0.3, "horizon": 4.0}
+           "scan_s_t4": 0.3, "horizon": 4.0, "step_latency_ema_ms": 11.25,
+           "round_host_ema_ms": 8.0, "scans_amortise": 4,
+           "scans_raised_waiting": 2, "scans_capped_by_budget": 1,
+           "scan_row_steps_masked": 5}
     worker = Worker.__new__(Worker)
     worker.engines = {"a": Eng(one), "b": Eng(dict(one, scans_t4=1))}
     worker.serving_capacity = lambda: 8
@@ -341,6 +368,10 @@ def test_worker_ships_round_counters_and_the_plane_counts_their_deltas():
     assert sent["between_rounds"] == 18 and sent["scans_t4"] == 8
     assert sent["between_rounds_s"] == 1.0 and sent["admit_s"] == 0.4
     assert "scan_s_t4" not in sent          # seconds by level stay local
+    # the horizon rule's counters are summed, its gauges are the last's
+    assert sent["scans_raised_waiting"] == 4 and sent["scans_amortise"] == 8
+    assert sent["scan_row_steps_masked"] == 10
+    assert sent["step_latency_ema_ms"] == 11.25 and sent["horizon"] == 4.0
     assert sent["round_readback_s"] == 4.0 and sent["round_build_s"] == 1.0
     # compiles are the process's, not an engine's: not summed over engines
     assert sent["compiles"] == 7 and sent["compile_s"] == 1.5
@@ -350,6 +381,9 @@ def test_worker_ships_round_counters_and_the_plane_counts_their_deltas():
     mc.record_batcher_engine("w1", dict(sent, between_rounds=20,
                                         between_rounds_s=1.25, scans_t4=11,
                                         scans_t16=2, deliver_s="garbage",
+                                        scans_capped_by_budget=3,
+                                        scan_row_steps_masked=12,
+                                        round_host_ema_ms=7.5,
                                         round_readback_s=5.5,
                                         compiles=10))
     text = mc.metrics.render().decode()
@@ -362,6 +396,13 @@ def test_worker_ships_round_counters_and_the_plane_counts_their_deltas():
         in text
     assert 'batcher_scans_total{steps="4",worker="w1"} 11.0' in text
     assert 'batcher_scans_total{steps="16",worker="w1"} 2.0' in text
+    assert ('batcher_scan_reasons_total{reason="capped_by_budget",'
+            'worker="w1"} 3.0') in text
+    assert ('batcher_scan_reasons_total{reason="raised_waiting",'
+            'worker="w1"} 4.0') in text
+    assert 'batcher_scan_row_steps_masked_total{worker="w1"} 12.0' in text
+    assert 'batcher_scan_step_ms{worker="w1"} 11.25' in text
+    assert 'batcher_round_host_ms{worker="w1"} 7.5' in text
     assert 'engine_round_seconds_total{phase="readback",worker="w1"} 5.5' \
         in text
     assert 'engine_round_seconds_total{phase="build",worker="w1"} 1.0' in text

@@ -2,7 +2,7 @@
 
 The ContinuousBatcher is the worker's front door: queued jobs and
 direct/SSE requests share decode rounds through one batcher, the SLO
-knobs (`target_step_ms`, `subwave`, `interleave`, `max_horizon`, queue
+knobs (`max_wait_ms`, `subwave`, `interleave`, `max_horizon`, queue
 limits) are worker YAML + server-pushable remote config, and batcher
 stats ride heartbeats into `/metrics`.
 
@@ -73,27 +73,78 @@ def test_serving_yaml_and_env_keys(tmp_path):
     yml = tmp_path / "config.yaml"
     yml.write_text(
         "engines:\n  llm:\n    engine: jax\n    model: llama3-tiny\n"
-        "    serving:\n      target_step_ms: 400\n      max_horizon: 4\n"
+        "    serving:\n      max_wait_ms: 40\n      max_horizon: 4\n"
         "      subwave: 2\n      interleave: 2\n"
     )
     cfg = load_worker_config(yml, environ={})
     sv = cfg.engines["llm"].serving
-    assert sv.target_step_ms == 400.0
+    assert sv.max_wait_ms == 40.0
     assert sv.max_horizon == 4
     assert sv.subwave == 2 and sv.interleave == 2
     assert sv.mode == "batcher"          # default
     # env overrides YAML (precedence env > yaml > defaults)
     cfg2 = load_worker_config(yml, environ={
-        "TPU_WORKER_ENGINES__LLM__SERVING__TARGET_STEP_MS": "250",
+        "TPU_WORKER_ENGINES__LLM__SERVING__MAX_WAIT_MS": "25",
         "TPU_WORKER_ENGINES__LLM__SERVING__QUEUE_LIMIT": "64",
     })
     sv2 = cfg2.engines["llm"].serving
-    assert sv2.target_step_ms == 250.0
+    assert sv2.max_wait_ms == 25.0
     assert sv2.queue_limit == 64
     assert sv2.max_horizon == 4          # yaml value survives
     # the engine receives the serving block through model_dump
     dumped = cfg.engines["llm"].model_dump()
-    assert dumped["serving"]["target_step_ms"] == 400.0
+    assert dumped["serving"]["max_wait_ms"] == 40.0
+
+
+@pytest.mark.parametrize("surface", ["yaml", "engine dict", "remote push"])
+def test_target_step_ms_is_accepted_warned_once_and_ignored(
+        surface, tmp_path, monkeypatch, caplog):
+    """The horizon rule reads no latency target (PR 26): saved worker YAML,
+    plain-dict engine configs (the OLMoE benchmark configuration carries
+    ``target_step_ms: 50``) and remote pushes that still name one keep
+    loading, say so once a process, and change nothing."""
+    import logging
+
+    from distributed_gpu_inference_tpu.runtime.batcher import BatcherConfig
+    from distributed_gpu_inference_tpu.utils import config as config_mod
+    from distributed_gpu_inference_tpu.worker.engines.llm import (
+        SERVING_REMOTE_KEYS,
+        TPULLMEngine,
+    )
+
+    monkeypatch.setattr(config_mod, "_deprecated_serving_warned", set())
+    caplog.set_level(logging.WARNING)
+
+    class Serving:
+        pushed: List[Dict[str, Any]] = []
+
+        def reconfigure(self, **kw):
+            self.pushed.append(kw)
+
+    eng = TPULLMEngine({"model": "llama3-tiny",
+                        "serving": {"target_step_ms": 50.0}})
+
+    def load():
+        if surface == "yaml":
+            yml = tmp_path / "config.yaml"
+            yml.write_text(
+                "engines:\n  llm:\n    engine: jax\n    model: llama3-tiny\n"
+                "    serving:\n      target_step_ms: 50\n")
+            sv = load_worker_config(yml, environ={}).engines["llm"].serving
+            return sv.model_dump()
+        if surface == "engine dict":
+            return eng._serving_config()
+        eng.serving = Serving()
+        eng.apply_serving_config({"target_step_ms": 50.0})
+        assert Serving.pushed == []             # nothing reached the batcher
+        return eng._serving_config()
+
+    for _ in range(3):
+        sv = load()
+    said = [r for r in caplog.records if "target_step_ms" in r.getMessage()]
+    assert len(said) == 1 and "deprecated" in said[0].getMessage()
+    assert "target_step_ms" not in SERVING_REMOTE_KEYS
+    assert TPULLMEngine._batcher_config(sv) == BatcherConfig()
 
 
 def test_remote_config_serving_merge_and_version_bump():
@@ -111,9 +162,9 @@ def test_remote_config_serving_merge_and_version_bump():
         await store.upsert_worker({"id": wid, "name": "w"})
         svc = WorkerConfigService(store)
         cfg = await svc.update_config(wid, {
-            "serving": {"target_step_ms": 400.0, "max_horizon": 4},
+            "serving": {"max_wait_ms": 40.0, "max_horizon": 4},
         })
-        assert cfg.serving == {"target_step_ms": 400.0, "max_horizon": 4}
+        assert cfg.serving == {"max_wait_ms": 40.0, "max_horizon": 4}
         v1 = cfg.version
         # partial update MERGES (max_horizon survives) and bumps version
         cfg2 = await svc.update_config(wid, {
@@ -142,10 +193,10 @@ def test_worker_pushes_remote_serving_to_engines():
     w = _worker({"llm": eng})
     w.api.fetch_remote_config = lambda: {
         "version": 3,
-        "serving": {"target_step_ms": 250.0, "max_horizon": 16},
+        "serving": {"max_wait_ms": 25.0, "max_horizon": 16},
     }
     w._fetch_remote_config()
-    assert eng.applied == [{"target_step_ms": 250.0, "max_horizon": 16}]
+    assert eng.applied == [{"max_wait_ms": 25.0, "max_horizon": 16}]
     assert w.config.config_version == 3
 
 
@@ -554,7 +605,7 @@ def test_drain_freezes_batcher_job_into_resumable_checkpoint(llm):
 
 
 def test_apply_serving_config_retunes_live_batcher(llm):
-    llm.apply_serving_config({"target_step_ms": 123.0, "max_horizon": 4,
+    llm.apply_serving_config({"max_wait_ms": 12.5, "max_horizon": 4,
                               "queue_limit": 77,
                               "subwave": 9})     # load-time key: ignored
     deadline = time.time() + 5.0
@@ -562,13 +613,13 @@ def test_apply_serving_config_retunes_live_batcher(llm):
             llm.serving.batcher.cfg.queue_limit != 77:
         time.sleep(0.01)
     cfg = llm.serving.batcher.cfg
-    assert cfg.target_step_latency_ms == 123.0
+    assert cfg.max_wait_ms == 12.5
     assert cfg.max_multi_step == 4
     assert cfg.queue_limit == 77
     assert llm.engine.cfg.admission_subwave == 0   # untouched
     assert max(llm.serving.batcher._levels) <= 4
     # restore for the other tests in this module
-    llm.apply_serving_config({"target_step_ms": 100.0, "max_horizon": 64,
+    llm.apply_serving_config({"max_wait_ms": 5.0, "max_horizon": 64,
                               "queue_limit": 1024})
 
 
