@@ -5,8 +5,8 @@ bench) honestly.
 
 Every scenario produces a deterministic, seed-stable open-loop trace: the
 same ``(scenario, seed, knobs)`` always generates byte-identical requests
-and arrival times, so two benchmark legs (routing ON vs OFF, ragged vs
-legacy, one replica vs four) replay the EXACT same offered load.
+and arrival times, so two benchmark legs (routing ON vs OFF, one replica
+vs four) replay the EXACT same offered load.
 
 Scenarios:
 
@@ -57,7 +57,7 @@ matching priorities. Untiered traces omit the ``tier`` field entirely,
 keeping their JSONL byte-identical to pre-tier builds.
 
 Usage (CLI emits JSONL for external drivers; ``generate()`` is the
-library surface ``benchmarks/worker_serving.py --workers`` drives):
+library surface):
 
     python -m benchmarks.workloads --scenario chat --seed 0
     python -m benchmarks.workloads --scenario rag --seed 3 --requests 64

@@ -121,37 +121,6 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
         head_dim=64, tie_word_embeddings=True,
         max_position_embeddings=131072,
     ),
-    # llama3-1b body with a bench-sized vocab: the speculative harness must
-    # TRAIN its target for real accept rates (benchmarks/speculative.py),
-    # and the f32 logits tensor of a 128k vocab dominates the training
-    # peak (whether it trains on the current chip: not measured).
-    # Same per-token transformer compute as llama3-1b; only the LM head
-    # shrinks. num_params ~1.0B.
-    "llama3-1b-bench": _llama(
-        "llama3-1b-bench", vocab_size=8192, hidden_size=2048, num_layers=16,
-        num_heads=16, num_kv_heads=8, intermediate_size=8192,
-        head_dim=128, tie_word_embeddings=True,
-        max_position_embeddings=8192,
-    ),
-    # ~200M sibling: a TRAINED speculative-decoding measurement point
-    # whose f32 training fits one chip with room to spare
-    "llama3-200m-bench": _llama(
-        "llama3-200m-bench", vocab_size=8192, hidden_size=1024,
-        num_layers=12, num_heads=8, num_kv_heads=4, intermediate_size=4096,
-        head_dim=128, tie_word_embeddings=True,
-        max_position_embeddings=8192,
-    ),
-    # untied sibling: round-3 probes showed EAGLE-head distillation
-    # acceptance collapses on TIED-embedding targets specifically (the
-    # draft must hit embedding rows rather than a trained discriminative
-    # head) — this variant isolates the serving-stack speedup from that
-    # draft-modeling limitation at 200M scale
-    "llama3-200m-bench-untied": _llama(
-        "llama3-200m-bench-untied", vocab_size=8192, hidden_size=1024,
-        num_layers=12, num_heads=8, num_kv_heads=4, intermediate_size=4096,
-        head_dim=128, tie_word_embeddings=False,
-        max_position_embeddings=8192,
-    ),
     # Llama 3.2 3B geometry
     "llama3-3b": _llama(
         "llama3-3b", vocab_size=128256, hidden_size=3072, num_layers=28,
@@ -168,18 +137,6 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
     # Llama 3 70B geometry (BASELINE.json config 4-5)
     "llama3-70b": _llama(
         "llama3-70b", vocab_size=128256, hidden_size=8192, num_layers=80,
-        num_heads=64, num_kv_heads=8, intermediate_size=28672,
-        max_position_embeddings=8192,
-    ),
-    # 70B PIPELINE-SCHEDULE geometry for the 8-device virtual-mesh dryrun
-    # (benchmarks/distributed.py --mode spmd): true per-
-    # layer width (hidden 8192, GQA 64/8, intermediate 28672 — the shapes
-    # every ppermute hop and per-stage matmul see) with 8 layers (1 per
-    # stage) and a cut vocab so the f32 host tree stays ~27 GB. The CHIP
-    # slice measurement uses the full llama3-70b config with num_layers
-    # overridden (benchmarks/pipeline_70b.py).
-    "llama3-70b-micro": _llama(
-        "llama3-70b-micro", vocab_size=2048, hidden_size=8192, num_layers=8,
         num_heads=64, num_kv_heads=8, intermediate_size=28672,
         max_position_embeddings=8192,
     ),

@@ -25,7 +25,6 @@ thread-safe); the asyncio side only schedules and resolves futures.
 from __future__ import annotations
 
 import asyncio
-import functools
 import heapq
 import itertools
 import logging
@@ -151,17 +150,6 @@ class BatcherConfig:
     # steady low rates (the first paged request kept the engine active
     # when each next one arrived, so no wave ever started again).
     spec_max_active: int = 2
-    # RAGGED rounds (round 6, the default): admission appends prefill-chunk
-    # rows to the next engine round instead of scheduling competing prefill
-    # dispatches — the round loop collapses to build-ragged-batch →
-    # dispatch → commit, and the subwave/interleave admission-stall knobs
-    # are obsolete. None = auto (ragged whenever the engine supports it:
-    # every paged engine including spec-integrated since round 8 — their
-    # rounds carry verify rows; only seq-sharded pools keep the split
-    # paths). False forces the legacy wave/chunk-interleaved admission —
-    # kept for A/B benchmarking (worker_serving --compare-legacy), not
-    # production.
-    ragged: Optional[bool] = None
     # per-ROUND prefill token budget for ragged rounds (PR 17, long-context
     # serving): the total prefill-chunk tokens all in-flight admissions may
     # land in one ragged round, split fairly across them (water-fill, with
@@ -298,7 +286,16 @@ class ContinuousBatcher:
                 "(EngineConfig.speculative); attaching a standalone "
                 "SpeculativeDecoder would draft twice — pick one"
             )
-        self._check_ragged_supported(self.cfg.ragged)
+        if getattr(engine, "supports_ragged", True) is False:
+            # the one admission path is the ragged round; an engine that
+            # says it has none cannot be served (a stub without the
+            # attribute is taken to speak the protocol)
+            raise ValueError(
+                "the batcher admits through ragged rounds and this engine "
+                "has none (supports_ragged is False): kv_seq_sharded "
+                "engines are fenced — their decode rows read through a "
+                "dedicated shard_map op with no ragged variant"
+            )
         # (wave, items) while a speculative wave is in flight
         self._spec_wave: Optional[Tuple[Any, List["_QueueItem"]]] = None
         # True while start_wave runs on the executor: the requests are off
@@ -327,15 +324,11 @@ class ContinuousBatcher:
         # first, or the resume takes them straight back and the pressure
         # recurs every round until the victim dies preempted_too_often
         self._resume_hold = False
-        # legacy path only: at most one chunk-interleaved long-prompt
-        # admission in flight; its prefill advances one chunk per loop
-        # iteration, between decode rounds (VERDICT r1 next-step #4)
-        self._chunked: Optional[Tuple[ChunkedAdmission, _QueueItem]] = None
-        # ragged mode (the default): EVERY admission — short or long — is a
-        # bound-but-unprefilled engine slot whose chunk rows ride the next
-        # ragged round(s) co-dispatched with the active decodes. Several
-        # may be in flight at once; an admission leaves this list for
-        # _slot_items when its final chunk samples the first token.
+        # EVERY admission — short or long — is a bound-but-unprefilled
+        # engine slot whose chunk rows ride the next ragged round(s)
+        # co-dispatched with the active decodes. Several may be in flight
+        # at once; an admission leaves this list for _slot_items when its
+        # final chunk samples the first token.
         self._ragged: List[Tuple[ChunkedAdmission, _QueueItem]] = []
         # rotating start offset for the per-round prefill-budget split:
         # when the budget floors below one token per admission, a
@@ -362,7 +355,6 @@ class ContinuousBatcher:
             # for rows that had already finished inside them
             **{f"scans_{reason}": 0 for reason in _SCAN_REASONS},
             "scan_row_steps_masked": 0,
-            "chunked_admissions": 0, "batched_waves": 0,
             "ragged_admissions": 0, "ragged_rounds": 0,
             "budgeted_rounds": 0, "budget_skipped_admissions": 0,
             "spec_waves": 0, "spec_completed": 0, "spec_errors": 0,
@@ -377,38 +369,6 @@ class ContinuousBatcher:
             "admit_s": 0.0, "deliver_s": 0.0,
         }
         self._level_counters()
-
-    @property
-    def use_ragged(self) -> bool:
-        """Ragged rounds are the DEFAULT serving path: admission appends
-        rows to the next round instead of dispatching competing prefills.
-        ``cfg.ragged=False`` forces the legacy path (A/B benches);
-        ``cfg.ragged=True`` REQUIRES it (init/reconfigure reject engines
-        that cannot serve it — a silent legacy fallback would make every
-        A/B ratio downstream a lie); ``None`` = auto: engines without
-        ragged support (seq-sharded, fakes) fall back automatically.
-        Spec-integrated engines serve ragged since round 8 — a round with
-        admissions in flight dispatches verify rows + chunk rows in one
-        invocation."""
-        if self.cfg.ragged is False:
-            return False
-        return bool(getattr(self.engine, "supports_ragged", False))
-
-    def _check_ragged_supported(self, requested: Any) -> None:
-        """``ragged=True`` is REQUIRE, not prefer — reject it loudly on an
-        engine that keeps the split admission paths. Spec-integrated
-        engines are an explicit ACCEPT since round 8 (their ragged rounds
-        carry verify rows); only seq-sharded pools remain fenced."""
-        if requested is True and \
-                not getattr(self.engine, "supports_ragged", False):
-            raise ValueError(
-                "serving.ragged=true requires an engine with ragged-round "
-                "support (paged engines, spec-integrated included since "
-                "round 8); kv_seq_sharded engines keep the split "
-                "admission paths — their decode rows read through a "
-                "dedicated shard_map op with no ragged variant. Use "
-                "ragged=null (auto) to fall back silently"
-            )
 
     def _rebuild_levels(self, anchor: float) -> None:
         """THE quantized-horizon level-set derivation (init + live
@@ -463,10 +423,9 @@ class ContinuousBatcher:
         max_bucket = s.prefill_buckets[-1]
         eng_buckets = getattr(self.engine.cfg, "prefill_buckets", None)
         if eng_buckets:
-            # prompts beyond the PAGED engine's largest bucket take the
-            # chunk-interleaved admission; spec routing honors the same
-            # boundary so the long-prompt path is one contract across
-            # serving modes (the worker's legacy driver gated on it too)
+            # prompts beyond the PAGED engine's largest bucket prefill over
+            # several rounds; spec routing keeps them off the wave so the
+            # long-prompt path is one contract across serving modes
             max_bucket = min(max_bucket, eng_buckets[-1])
         if len(ids) > max_bucket:
             return False
@@ -491,7 +450,6 @@ class ContinuousBatcher:
             self.spec is None
             or spec_cap <= 0
             or self._spec_wave is not None
-            or self._chunked is not None
             or self._ragged
             or not self._heap
             or len(self._heap) > spec_cap
@@ -729,8 +687,7 @@ class ContinuousBatcher:
         if drain:
             # drain batcher-OWNED work only: a foreign engine slot (e.g. a
             # PD sequence retained between stages) is not ours to wait on
-            while self._heap or self._slot_items or self._chunked is not None \
-                    or self._ragged \
+            while self._heap or self._slot_items or self._ragged \
                     or self._spec_wave is not None or self._spec_starting:
                 await asyncio.sleep(0.01)
         if self._run_task:
@@ -744,22 +701,10 @@ class ContinuousBatcher:
         self._slot_items.clear()
         self._heap.clear()
         loop = asyncio.get_running_loop()
-        if self._chunked is not None:
-            # a request mid chunk-interleaved prefill is in NEITHER
-            # collection above — abort its engine state and resolve it,
-            # or its submit() would wait on a dead loop forever
-            adm, chunk_item = self._chunked
-            self._chunked = None
-            try:
-                await loop.run_in_executor(
-                    self._exec, self.engine.abort_chunked, adm
-                )
-            except Exception:  # noqa: BLE001 — shutdown is best-effort
-                pass
-            pending.append(chunk_item)
         for adm, rag_item in self._ragged:
-            # mid-prefill ragged admissions are in NEITHER collection above
-            # either — abort their engine state and resolve them too
+            # a request mid prefill is in NEITHER collection above — abort
+            # its engine state and resolve it, or its submit() would wait
+            # on a dead loop forever
             try:
                 await loop.run_in_executor(
                     self._exec, self.engine.abort_chunked, adm
@@ -814,18 +759,12 @@ class ContinuousBatcher:
             if val is None or not hasattr(self.cfg, key):
                 continue
             cur = getattr(self.cfg, key)
-            if (isinstance(cur, bool) or key == "ragged") \
-                    and isinstance(val, str):
+            if isinstance(cur, bool) and isinstance(val, str):
                 # remote pushes arrive through an untyped dict and env/YAML
                 # tooling stringifies scalars — bool("false") is True, so
-                # coerce by content, not constructor ("ragged" is tri-state
-                # Optional[bool], so its current value may be None)
+                # coerce by content, not constructor
                 val = val.strip().lower() in ("1", "true", "yes", "on")
-            if key == "ragged":
-                self._check_ragged_supported(bool(val))
-                coerced[key] = bool(val)
-                continue
-            coerced[key] = type(cur)(val) if cur is not None else val
+            coerced[key] = type(cur)(val)
         # all-or-nothing: coercion above raised before any cfg mutation,
         # so one bad value can't leave a half-applied retune
         for key, val in coerced.items():
@@ -875,17 +814,11 @@ class ContinuousBatcher:
         the heap are not thread-safe); only the engine call itself runs on the
         engine executor thread.
 
-        Ragged mode (the default): every fresh admission binds its slot NOW
-        (``engine.submit_chunked_start``) and its prompt rides the next
-        ragged round(s) as chunk rows co-dispatched with the active decodes
-        — admission IS "append rows to the next round"; several may be in
-        flight at once. Legacy mode (``cfg.ragged=False`` or an engine
-        without ragged support): short prompts are collected into a WAVE
-        and admitted through ``engine.submit_batch`` — one batched prefill
-        device call per bucket instead of one per request (VERDICT r1
-        next-step #3) — while prompts longer than the largest prefill
-        bucket start a chunk-interleaved admission instead (one at a time);
-        their chunks run between decode rounds in ``_run``."""
+        Every fresh admission binds its slot NOW
+        (``engine.submit_chunked_start``) and runs no prefill: its prompt
+        rides the next ragged round(s) as chunk rows co-dispatched with the
+        active decodes — admission IS "append rows to the next round";
+        several may be in flight at once, short or long alike."""
         admitted = 0
         if self._resume_hold:
             # the round after a preemption belongs to the FROZEN slots:
@@ -897,8 +830,6 @@ class ContinuousBatcher:
         if not free or not self._heap:
             return 0
         loop = asyncio.get_running_loop()
-        max_bucket = self.engine.cfg.prefill_buckets[-1]
-        wave: List[_QueueItem] = []
         requeue: List[_QueueItem] = []
 
         def _defer(item: "_QueueItem") -> bool:
@@ -947,8 +878,7 @@ class ContinuousBatcher:
                         self._exec, self.engine.resume, item.preempted,
                     )
                 except OutOfBlocksError:
-                    if self.engine.num_active == 0 and \
-                            self._chunked is None:
+                    if self.engine.num_active == 0:
                         # an IDLE pool that STATICALLY cannot hold the
                         # sequence never will (nothing left to free):
                         # after a few consecutive tries, deliver the
@@ -1001,149 +931,36 @@ class ContinuousBatcher:
                 self._note(item, "batcher.resumed", slot=slot)
                 admitted += 1
                 continue
-            if self.use_ragged:
-                # ragged admission (the default): bind the slot NOW, run no
-                # prefill — the prompt's chunk rows ride the next ragged
-                # round(s) co-dispatched with the active decodes, so there
-                # is no competing prefill dispatch and no short/long split
-                try:
-                    adm = await loop.run_in_executor(
-                        self._exec, self.engine.submit_chunked_start,
-                        item.request,
-                    )
-                except OutOfBlocksError:
-                    _defer(item)
-                    continue
-                except Exception as e:
-                    if not item.future.done():
-                        item.future.set_result(
-                            InferenceResponse(
-                                request_id=item.request.request_id,
-                                error=str(e),
-                                # typed admission failures (e.g. the
-                                # engine's over_length rejection) stay
-                                # machine-readable through the batcher
-                                error_code=getattr(e, "error_code", None),
-                            )
-                        )
-                    continue
-                free.pop(0)
-                self._ragged.append((adm, item))
-                self.stats["ragged_admissions"] += 1
-                self._note(item, "batcher.admitted", slot=adm.slot,
-                           mode="ragged", round=self._round + 1,
-                           tokens=len(item.request.prompt_token_ids or []))
-                continue
-            n_prompt = len(item.request.prompt_token_ids or [])
-            if n_prompt > max_bucket:
-                if self._chunked is not None:
-                    # one interleaved admission at a time — requeue this one
-                    # and keep admitting the rest (a second long prompt must
-                    # not starve short requests behind it)
-                    heapq.heappush(self._heap, item)
-                    continue
-                try:
-                    adm = await loop.run_in_executor(
-                        self._exec, self.engine.submit_chunked_start,
-                        item.request,
-                    )
-                except OutOfBlocksError:
-                    _defer(item)
-                    continue
-                except Exception as e:
-                    if not item.future.done():
-                        item.future.set_result(
-                            InferenceResponse(
-                                request_id=item.request.request_id,
-                                error=str(e),
-                                error_code=getattr(e, "error_code", None),
-                            )
-                        )
-                    continue
-                # consume the slot only on SUCCESS: a failed chunked start
-                # rolled the engine back, and burning a free slot for it
-                # would under-admit the rest of this pass (the slot leak)
-                free.pop(0)
-                self._chunked = (adm, item)
-                self.stats["chunked_admissions"] += 1
-                self._note(item, "batcher.admitted", slot=adm.slot,
-                           mode="chunked", round=self._round + 1,
-                           tokens=n_prompt)
-                continue
-            free.pop(0)
-            wave.append(item)
-
-        if wave:
-            # admission instant for the whole wave: submit_batch prefills
-            # AND samples the first token before returning, so noting
-            # "admitted" after it would land LATER than first_token and
-            # phase derivation would drop prefill and inflate queue_wait
-            t_admit = time.time()
             try:
-                slots = await loop.run_in_executor(
-                    self._exec,
-                    functools.partial(
-                        self.engine.submit_batch,
-                        [it.request for it in wave], partial=True,
-                    ),
+                adm = await loop.run_in_executor(
+                    self._exec, self.engine.submit_chunked_start,
+                    item.request,
                 )
             except OutOfBlocksError:
-                # pool can't hold the wave right now: requeue silently —
-                # completions/preemptions free blocks and the requests
-                # retry; clients never see the pressure
-                for item in wave:
-                    _defer(item)
-                slots = None
-            except Exception:
-                # the wave is all-or-nothing (engine rolls back); isolate the
-                # failing request(s) by falling back to per-request admission
-                slots = None
-                for item in wave:
-                    t_admit = time.time()
-                    try:
-                        slot = await loop.run_in_executor(
-                            self._exec, self.engine.submit, item.request
+                _defer(item)
+                continue
+            except Exception as e:
+                if not item.future.done():
+                    item.future.set_result(
+                        InferenceResponse(
+                            request_id=item.request.request_id,
+                            error=str(e),
+                            # typed admission failures (e.g. the engine's
+                            # over_length rejection) stay machine-readable
+                            # through the batcher
+                            error_code=getattr(e, "error_code", None),
                         )
-                    except OutOfBlocksError:
-                        _defer(item)
-                        continue
-                    except Exception as e:
-                        if not item.future.done():
-                            item.future.set_result(
-                                InferenceResponse(
-                                    request_id=item.request.request_id,
-                                    error=str(e),
-                                    error_code=getattr(e, "error_code",
-                                                       None),
-                                )
-                            )
-                        continue
-                    self._slot_items[slot] = item
-                    self._admit_stamp[slot] = next(self._stamp)
-                    self._note(item, "batcher.admitted", at=t_admit,
-                               slot=slot, mode="wave",
-                               round=self._round + 1,
-                               tokens=len(item.request.prompt_token_ids
-                                          or []))
-                    self._note_first_token(item, slot)
-                    admitted += 1
-            if slots is not None:
-                if slots:
-                    self.stats["batched_waves"] += 1
-                for item, slot in zip(wave, slots):
-                    self._slot_items[slot] = item
-                    self._admit_stamp[slot] = next(self._stamp)
-                    self._note(item, "batcher.admitted", at=t_admit,
-                               slot=slot, mode="wave",
-                               round=self._round + 1,
-                               tokens=len(item.request.prompt_token_ids
-                                          or []))
-                    self._note_first_token(item, slot)
-                admitted += len(slots)
-                # pressure deferred the wave's tail (possibly the whole
-                # wave): requeue without error
-                for item in wave[len(slots):]:
-                    _defer(item)
+                    )
+                continue
+            # consume the slot only on SUCCESS: a failed start rolled the
+            # engine back, and burning a free slot for it would under-admit
+            # the rest of this pass (the slot leak)
+            free.pop(0)
+            self._ragged.append((adm, item))
+            self.stats["ragged_admissions"] += 1
+            self._note(item, "batcher.admitted", slot=adm.slot,
+                       mode="ragged", round=self._round + 1,
+                       tokens=len(item.request.prompt_token_ids or []))
 
         for item in requeue:
             heapq.heappush(self._heap, item)
@@ -1164,39 +981,6 @@ class ContinuousBatcher:
         s = self.engine.slots[slot]
         t = getattr(s, "first_token_time", None) if s is not None else None
         self._note(item, "batcher.first_token", at=t, **attrs)
-
-    async def _step_chunked(self) -> None:
-        """Advance the in-flight chunk-interleaved admission by ONE chunk."""
-        if self._chunked is None:
-            return
-        adm, item = self._chunked
-        loop = asyncio.get_running_loop()
-        if item.future.done():  # caller gave up (timeout/cancel): release
-            await loop.run_in_executor(
-                self._exec, self.engine.abort_chunked, adm
-            )
-            self._chunked = None
-            return
-        try:
-            done = await loop.run_in_executor(
-                self._exec, self.engine.submit_chunked_step, adm
-            )
-        except Exception as e:
-            self._chunked = None
-            if not item.future.done():
-                item.future.set_result(
-                    InferenceResponse(
-                        request_id=item.request.request_id,
-                        error=str(e),
-                        error_code=getattr(e, "error_code", None),
-                    )
-                )
-            return
-        if done:
-            self._slot_items[adm.slot] = item
-            self._chunked = None
-            self.stats["admitted"] += 1
-            self._note_first_token(item, adm.slot)
 
     async def _check_pressure(self, after_round: bool = False) -> None:
         """Consume the engine's KV-pressure signal and apply the preemption
@@ -1340,51 +1124,19 @@ class ContinuousBatcher:
                 self.stats["migrated"] += 1
         if changed:
             heapq.heapify(self._heap)
-        if self._chunked is not None:
-            adm, item = self._chunked
-            cancelled = item.cancel is not None and item.cancel.is_set()
-            interrupted = item.interrupt is not None \
-                and item.interrupt.is_set()
-            if cancelled or interrupted:
-                # a request mid chunk-interleaved prefill holds no
-                # resumable engine state yet: abort the admission (frees
-                # its slot + staged blocks) and either resolve with an
-                # empty abort or migrate with a synthesized zero-token
-                # checkpoint — burning the remaining prefill rounds on an
-                # abandoned/draining request would stall everyone else
-                self._chunked = None
-                try:
-                    await loop.run_in_executor(
-                        self._exec, self.engine.abort_chunked, adm
-                    )
-                except Exception:  # noqa: BLE001 — abort is best-effort
-                    pass
-                if not item.future.done():
-                    if cancelled:
-                        item.future.set_result(InferenceResponse(
-                            request_id=item.request.request_id,
-                            finish_reason="abort",
-                            prompt_tokens=len(
-                                item.request.prompt_token_ids or []),
-                        ))
-                        self.stats["completed"] += 1
-                        self.stats["cancelled"] += 1
-                    else:
-                        pre = synthesize_checkpoint(item.request)
-                        pre.preempt_count = item.preempt_count
-                        item.future.set_exception(RequestMigrated(pre))
-                        self.stats["migrated"] += 1
         for adm, item in list(self._ragged):
             cancelled = item.cancel is not None and item.cancel.is_set()
             interrupted = item.interrupt is not None \
                 and item.interrupt.is_set()
             if not (cancelled or interrupted or item.future.done()):
                 continue
-            # same contract as the legacy chunk-interleaved admission: a
-            # request mid ragged prefill holds no resumable engine state
-            # yet — abort (frees the slot + staged blocks) and resolve /
-            # migrate with a synthesized zero-token checkpoint; a done
-            # future (caller timeout) just releases the engine side
+            # a request mid prefill holds no resumable engine state yet:
+            # abort the admission (frees its slot + staged blocks) and
+            # either resolve with an empty abort or migrate with a
+            # synthesized zero-token checkpoint — burning the remaining
+            # prefill rounds on an abandoned/draining request would stall
+            # everyone else; a done future (caller timeout) just releases
+            # the engine side
             self._ragged.remove((adm, item))
             try:
                 await loop.run_in_executor(
@@ -1526,24 +1278,6 @@ class ContinuousBatcher:
             self._count_abandon(req, now)
         if changed:
             heapq.heapify(self._heap)
-        if self._chunked is not None:
-            adm, item = self._chunked
-            if not item.future.done() and self._deadline_hopeless(
-                    item.request,
-                    int(item.request.sampling.max_new_tokens), now):
-                self._chunked = None
-                try:
-                    await loop.run_in_executor(
-                        self._exec, self.engine.abort_chunked, adm
-                    )
-                except Exception:  # noqa: BLE001 — abort is best-effort
-                    pass
-                if not item.future.done():
-                    item.future.set_result(self._abandon_response(
-                        item.request, [],
-                        len(item.request.prompt_token_ids or []),
-                    ))
-                    self._count_abandon(item.request, now)
         for adm, item in list(self._ragged):
             if item.future.done() or not self._deadline_hopeless(
                     item.request,
@@ -1643,13 +1377,11 @@ class ContinuousBatcher:
         before a row ends: the device is what is short, so the scan runs one
         level longer to buy the host's share down — unless a decoding row
         can end inside it (its remaining budget, short of a stop token),
-        because that is the first moment a slot can come free. A legacy
-        chunked admission in flight advances between rounds, so the queue
-        behind it waits for rounds to end, not for a slot."""
+        because that is the first moment a slot can come free."""
         levels = self._levels
         amortise = levels[self._level]
         raised = levels[min(self._level + 1, len(levels) - 1)]
-        if not self._heap or self._chunked is not None or raised == amortise:
+        if not self._heap or raised == amortise:
             return amortise, "amortise"
         budgets = self.engine.decode_budgets()
         if raised > budgets[budgets > 0].min(initial=raised):
@@ -1672,11 +1404,10 @@ class ContinuousBatcher:
         falls to the ragged round it precedes. On the v5e, charged to the
         scan, it carried two cells' level to T=16: PERF.md, PR 26.)
 
-        Ragged mode with admissions in flight dispatches ONE
-        ``engine.ragged_round``: every active decode slot advances one
-        token and every admission advances one prefill chunk in the same
-        invocation — build-ragged-batch → dispatch → commit, no competing
-        prefill dispatch, no subwave/interleave stall shaping. With no
+        Admissions in flight dispatch ONE ``engine.ragged_round``: every
+        active decode slot advances one token and every admission advances
+        one prefill chunk in the same invocation — build-ragged-batch →
+        dispatch → commit, no competing prefill dispatch. With no
         admission in flight a ragged round degenerates to pure decode, so
         the multi-step scan (horizon amortization of the host RTT) is the
         better dispatch for the identical math and runs instead."""
@@ -1769,8 +1500,7 @@ class ContinuousBatcher:
             # nor be decoded/finished behind its owner's back — it joins the
             # batch only through adopt_slot().
             if not self._heap and not self._slot_items \
-                    and self._chunked is None and not self._ragged \
-                    and self._spec_wave is None:
+                    and not self._ragged and self._spec_wave is None:
                 self._wake.clear()
                 if self._stopping:
                     return
@@ -1801,14 +1531,9 @@ class ContinuousBatcher:
                 # a higher-priority arrival preempts the lowest-priority
                 # victim
                 await self._check_pressure()
-                # one prefill chunk of the in-flight long admission per
-                # loop iteration — decode rounds below run between chunks, so
-                # active slots stall at most one chunk per round
-                await self._step_chunked()
                 # one bounded fused dispatch of the in-flight spec wave
                 await self._step_spec_wave()
-            if not self._slot_items and self._chunked is None \
-                    and not self._ragged:
+            if not self._slot_items and not self._ragged:
                 # no batcher-owned slot decodes: no frozen slot of OURS is
                 # waiting on freed blocks, so resumes may flow immediately
                 # (foreign slots are left untouched for their owner)
@@ -1885,28 +1610,9 @@ class ContinuousBatcher:
                 # a failed round must not wedge the batcher: fail every
                 # in-flight request, abort its slot, keep serving the queue
                 self.stats["engine_errors"] = self.stats.get("engine_errors", 0) + 1
-                if self._chunked is not None:
-                    # the mid-prefill admission isn't in _slot_items yet —
-                    # release its slot and resolve its future here or the
-                    # caller hangs until timeout
-                    adm, chunk_item = self._chunked
-                    self._chunked = None
-                    try:
-                        await loop.run_in_executor(
-                            self._exec, self.engine.abort_chunked, adm
-                        )
-                    except Exception:
-                        pass
-                    if not chunk_item.future.done():
-                        chunk_item.future.set_result(
-                            InferenceResponse(
-                                request_id=chunk_item.request.request_id,
-                                error=f"engine error: {e}",
-                            )
-                        )
-                        self.stats["completed"] += 1
-                # mid-prefill ragged admissions likewise aren't in
-                # _slot_items yet — release their slots and resolve
+                # mid-prefill admissions aren't in _slot_items yet —
+                # release their slots and resolve their futures here or
+                # the callers hang until timeout
                 for adm, rag_item in list(self._ragged):
                     try:
                         await loop.run_in_executor(
@@ -1949,7 +1655,6 @@ class ContinuousBatcher:
         out = dict(self.stats)
         out["queue_depth"] = len(self._heap)
         out["active_slots"] = self.engine.num_active
-        out["ragged_mode"] = self.use_ragged
         out["ragged_in_flight"] = len(self._ragged)
         out["spec_wave_active"] = self._spec_wave is not None
         if self.spec is not None:

@@ -143,16 +143,6 @@ class EngineConfig:
     # so later cold starts skip quantization entirely — VERDICT r2 #1's
     # startup fix for serving near-HBM-capacity models (8B int8 on 16 GB)
     quant_cache_dir: Optional[str] = None
-    # sub-wave admission (VERDICT r2 #3): split a submit_batch wave into
-    # chunks of this many sequences, each prefilled by a narrower compiled
-    # graph, so sequence #1 samples its first token after ONE sub-wave
-    # instead of after the whole wave's prefill. 0 = whole-wave (one call).
-    admission_subwave: int = 0
-    # bounded decode rounds between sub-waves: slots already generating
-    # (earlier sub-waves, previously admitted requests) advance this many
-    # tokens between chunks instead of stalling for the whole admission.
-    # 0 = no interleave (pure TTFT staggering).
-    admission_interleave_steps: int = 0
     # long-context prefill strategy on a mesh with a ``seq`` axis: a fresh
     # prompt longer than the largest prefill bucket runs ONE seq-sharded
     # pass (ring or ulysses attention over the seq axis,
@@ -222,14 +212,14 @@ class _Slot:
 
 @dataclass
 class ChunkedAdmission:
-    """In-flight chunk-interleaved admission (``submit_chunked_start``).
+    """In-flight chunked admission (``submit_chunked_start``): a bound
+    slot whose prompt is still to be prefilled.
 
-    The scheduler runs one prefill chunk at a time via
-    ``submit_chunked_step`` and interleaves bounded decode rounds for the
-    other slots between chunks, so a long prompt never stalls active
-    decodes longer than one chunk (vLLM-style chunked-prefill scheduling;
-    VERDICT r1 next-step #4 — the repo's own benchmarks/pd_separation.py
-    quantifies the interference this removes)."""
+    The batcher hands its admissions to ``ragged_round``, which advances
+    each by one piece beside the decoding rows; ``submit_chunked_step``
+    runs one piece alone (PD streamed prefill, the tests' reference).
+    Either way a long prompt never stalls active decodes longer than one
+    chunk (vLLM-style chunked-prefill scheduling)."""
 
     request: InferenceRequest
     slot: int
@@ -1945,7 +1935,6 @@ class TPUEngine:
         }
         mgr_stats_snapshot = dict(self.manager.stats.__dict__)
         downloads_before = len(self.manager.pending.downloads)
-        interleaved_extra = 0   # decode tokens emitted to non-wave slots
 
         def _rollback() -> None:
             for slot, seq_id in admitted:
@@ -1999,8 +1988,8 @@ class TPUEngine:
                     self.cfg.kv_seq_sharded and cached > 0
                 ):
                     # chunked long-prompt path (per request). Sharded pools
-                    # also route CACHED prompts here: the batched/sub-wave
-                    # prefill graphs attend dense over the chunk only, which
+                    # also route CACHED prompts here: the batched
+                    # prefill graph attends dense over the chunk only, which
                     # cannot see a cached prefix — the chunked path reads it
                     # through the sharded-pool chunk op.
                     self._submit_allocated(request, slot, seq_id, token_ids, cached)
@@ -2011,155 +2000,51 @@ class TPUEngine:
                 )
 
             b = len(self.slots)
-            sw = self.cfg.admission_subwave
-            groups = sorted(grouped.items())
-            if sw > 0:
-                # SUB-WAVE admission (VERDICT r2 #3): chunks of ≤ sw
-                # sequences prefill through a width-bucketed narrow graph;
-                # each chunk samples its first tokens as soon as ITS prefill
-                # lands, so p50 TTFT scales with the sub-wave, not the wave.
-                # Optionally a bounded decode round runs between chunks so
-                # already-generating slots never stall for a whole admission.
-                wave_slots = {s_ for s_, _ in admitted}
-                chunks: List[Tuple[int, list]] = []
-                for bucket, items in groups:
-                    for i0 in range(0, len(items), sw):
-                        chunks.append((bucket, items[i0:i0 + sw]))
-                k = self.cfg.admission_interleave_steps
-                if k > 0:
-                    for ci, (bucket, chunk) in enumerate(chunks):
-                        self._commit_subwave(
-                            chunk, self._prefill_subwave(bucket, chunk)
-                        )
-                        if ci < len(chunks) - 1:
-                            out = self.decode_multi(k)
-                            # count only tokens _record_token counted: an
-                            # emitted stop token ends the slot WITHOUT
-                            # incrementing generated_tokens
-                            for sl, t in out.items():
-                                if sl in wave_slots:
-                                    continue
-                                s_ = self.slots[sl]
-                                stop = (
-                                    1 if s_ is not None
-                                    and s_.finish_reason == "stop" else 0
-                                )
-                                interleaved_extra += len(t) - stop
-                else:
-                    # pipelined staggering: dispatch every narrow prefill
-                    # back-to-back (async dispatch — the device queue runs
-                    # them in order), then read first tokens chunk by chunk.
-                    # Chunk c's tokens reach the host as soon as ITS compute
-                    # lands while later chunks are still running, so the
-                    # TTFT stagger costs ~no wall-clock vs one wide call.
-                    dispatched = [
-                        (chunk, self._prefill_subwave(bucket, chunk))
-                        for bucket, chunk in chunks
-                    ]
-                    for chunk, first in dispatched:
-                        self._commit_subwave(chunk, first)
-            else:
-                for bucket, items in groups:
-                    self._apply_pending()
-                    toks_pos = np.zeros((2, b, bucket), np.int32)
-                    toks_pos[1] = -1
-                    lens = np.zeros((b,), np.int32)
-                    wave = np.zeros((b,), bool)
-                    for request, slot, seq_id, token_ids, cached in items:
-                        s = _Slot(request=request, seq_id=seq_id,
-                                  prompt_len=len(token_ids),
-                                  cached_tokens=cached)
-                        self._bind_slot(slot, s, kv_len=len(token_ids))
-                        fresh = token_ids[cached:]
-                        n = len(fresh)
-                        toks_pos[0, slot, :n] = fresh
-                        toks_pos[1, slot, :n] = np.arange(cached, cached + n)
-                        lens[slot] = cached + n
-                        wave[slot] = True
-                        self.stats["prefill_tokens"] += n
-                    mode = (
-                        "greedy"
-                        if all(it[0].sampling.temperature <= 0 for it in items)
-                        else "mixed"
+            for bucket, items in sorted(grouped.items()):
+                self._apply_pending()
+                toks_pos = np.zeros((2, b, bucket), np.int32)
+                toks_pos[1] = -1
+                lens = np.zeros((b,), np.int32)
+                wave = np.zeros((b,), bool)
+                for request, slot, seq_id, token_ids, cached in items:
+                    s = _Slot(request=request, seq_id=seq_id,
+                              prompt_len=len(token_ids),
+                              cached_tokens=cached)
+                    self._bind_slot(slot, s, kv_len=len(token_ids))
+                    fresh = token_ids[cached:]
+                    n = len(fresh)
+                    toks_pos[0, slot, :n] = fresh
+                    toks_pos[1, slot, :n] = np.arange(cached, cached + n)
+                    lens[slot] = cached + n
+                    wave[slot] = True
+                    self.stats["prefill_tokens"] += n
+                mode = (
+                    "greedy"
+                    if all(it[0].sampling.temperature <= 0 for it in items)
+                    else "mixed"
+                )
+                core = self._sync_core()
+                first, self._dev_core, self.kv = self._prefill_batch_fn(
+                    self.params, self.kv, toks_pos, self._block_tables,
+                    lens, core, wave, mode,
+                )
+                self.stats["prefill_calls"] += 1
+                first_np = np.asarray(first)
+                for request, slot, seq_id, token_ids, cached in items:
+                    self._record_token(
+                        slot, int(first_np[slot]), device_synced=True
                     )
-                    core = self._sync_core()
-                    first, self._dev_core, self.kv = self._prefill_batch_fn(
-                        self.params, self.kv, toks_pos, self._block_tables,
-                        lens, core, wave, mode,
-                    )
-                    self.stats["prefill_calls"] += 1
-                    first_np = np.asarray(first)
-                    for request, slot, seq_id, token_ids, cached in items:
-                        self._record_token(
-                            slot, int(first_np[slot]), device_synced=True
-                        )
         except Exception as exc:
             # a failed wave must not leak: every sequence this call admitted
             # (bound or not) is freed so a retry sees clean state
             self._invalidate_device_state()
             _rollback()
-            # interleaved decode tokens that went to slots OUTSIDE this wave
-            # really happened and survive the rollback
-            self.stats["generated_tokens"] += interleaved_extra
             if isinstance(exc, OutOfBlocksError):
                 self._signal_pressure(
                     "admission", requests=len(requests)
                 )
             raise
         return slots_out
-
-    def _prefill_subwave(self, bucket: int, chunk: list):
-        """Prefill ≤ admission_subwave sequences through a width-bucketed
-        narrow graph (the width-generic ``_prefill_chunk_fn``), sampling
-        their first tokens in-graph. Pad rows carry position -1 everywhere
-        (KV writes dropped) and their sampled garbage is never read."""
-        self._apply_pending()
-        w = 1
-        while w < len(chunk):
-            w *= 2
-        w = min(w, len(self.slots))
-        mm = self.cfg.max_blocks_per_seq
-        toks_pos = np.zeros((2, w, bucket), np.int32)
-        toks_pos[1] = -1
-        tables = np.zeros((w, mm), np.int32)
-        lens = np.zeros((w,), np.int32)
-        keys = np.zeros((w, 2), np.uint32)
-        temps = np.zeros((w,), np.float32)
-        top_ks = np.zeros((w,), np.int32)
-        top_ps = np.ones((w,), np.float32)
-        for j, (request, slot, seq_id, token_ids, cached) in enumerate(chunk):
-            s = _Slot(request=request, seq_id=seq_id,
-                      prompt_len=len(token_ids), cached_tokens=cached)
-            self._bind_slot(slot, s, kv_len=len(token_ids))
-            fresh = token_ids[cached:]
-            n = len(fresh)
-            toks_pos[0, j, :n] = fresh
-            toks_pos[1, j, :n] = np.arange(cached, cached + n)
-            lens[j] = cached + n
-            tables[j] = self._block_tables[slot]
-            keys[j] = self._slot_keys[slot]
-            temps[j] = self._temps[slot]
-            top_ks[j] = self._top_ks[slot]
-            top_ps[j] = self._top_ps[slot]
-            self.stats["prefill_tokens"] += n
-        mode = (
-            "greedy"
-            if all(it[0].sampling.temperature <= 0 for it in chunk)
-            else "mixed"
-        )
-        first, self.kv = self._prefill_chunk_fn(
-            self.params, self.kv, toks_pos, tables, lens, keys, temps,
-            top_ks, top_ps, mode, True,
-        )
-        self.stats["prefill_calls"] += 1
-        return first
-
-    def _commit_subwave(self, chunk: list, first) -> None:
-        """Read a sub-wave's first tokens (blocks until its prefill lands)
-        and account them — the point each sequence's TTFT clock stops."""
-        first_np = np.asarray(first)
-        for j, (request, slot, seq_id, token_ids, cached) in enumerate(chunk):
-            self._record_token(slot, int(first_np[j]))
 
     def _bind_slot(self, slot: int, s: "_Slot", kv_len: int) -> None:
         """Install slot state (block table, committed length, sampling, stop
@@ -2593,9 +2478,8 @@ class TPUEngine:
     ) -> Dict[int, List[int]]:
         """ONE device dispatch serving a ragged row batch: every active
         decode slot advances one token AND every in-flight admission
-        advances one prefill chunk — the round-6 unification that replaced
-        scheduling competing prefill/decode dispatches (subwave/interleave)
-        with "append rows to the next round".
+        advances one prefill chunk — "append rows to the next round" in
+        place of competing prefill and decode dispatches.
 
         Per-row semantics are exactly the split paths': decode rows feed
         their pending token at position ``_kv_lens`` (block pre-reserved,

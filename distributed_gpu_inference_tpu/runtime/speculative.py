@@ -157,9 +157,9 @@ class SpecDecodeConfig:
     # length to ``rate * K`` (fractional rates dither deterministically)
     # instead of matching against the target. Draft cost, verify cost, KV
     # writes, commits, and rollback are all REAL — only the acceptance
-    # decision is forced — so the serving bench can measure the
-    # tok/s-vs-acceptance curve without trained draft weights
-    # (``benchmarks/worker_serving.py --spec``). Committed tokens are the
+    # decision is forced — so a benchmark cell can measure the
+    # tok/s-vs-acceptance curve without trained draft weights (ROADMAP R5's
+    # ``*.spec``; not measured on the chip yet). Committed tokens are the
     # (garbage) drafts: outputs are meaningless, pair with ignore_eos
     # requests. None = real acceptance (the only production value).
     oracle_accept_rate: Optional[float] = None

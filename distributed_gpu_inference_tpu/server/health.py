@@ -57,7 +57,7 @@ class HealthConfig:
     (admin ``PUT /api/v1/admin/health``)."""
 
     # master switch: OFF keeps discovery/claim byte-identical to the
-    # pre-health build (the A/B flip for BENCH_r16)
+    # pre-health build (not measured on the chip: no cell has a fleet)
     enabled: bool = False
     # hedged dispatch for deadline-carrying direct requests: discovery
     # returns a second-ranked candidate + a p95-derived fire delay and

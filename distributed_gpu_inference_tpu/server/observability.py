@@ -98,7 +98,7 @@ class Metrics:
                 "batcher_queue_depth", "batcher_active_slots",
                 "batcher_occupancy", "batcher_horizon",
                 "batcher_decode_rounds", "batcher_completed",
-                "batcher_chunked_admissions", "batcher_preemptions",
+                "batcher_preemptions",
                 "batcher_migrated", "batcher_round_gaps",
                 "batcher_loop_seconds", "batcher_scans",
                 "batcher_scan_step_ms", "batcher_round_host_ms",
@@ -265,10 +265,6 @@ class Metrics:
             "batcher_requests_completed_total",
             "Requests completed through the batcher serving path",
             ["worker"], registry=r)
-        self.batcher_chunked_admissions = Counter(
-            "batcher_chunked_admissions_total",
-            "Long prompts admitted chunk-interleaved", ["worker"],
-            registry=r)
         self.batcher_preemptions = Counter(
             "batcher_preemptions_total",
             "KV-pressure preemptions applied by the batcher's victim "
@@ -290,8 +286,8 @@ class Metrics:
             "batcher_loop_seconds_total",
             "Seconds of the batcher loop by part: between_rounds (one "
             "round's end to the next one's start), and the loop's admit "
-            "and deliver steps, which split it in ragged mode (in chunked, "
-            "wave and speculative modes admit includes engine dispatches)",
+            "and deliver steps, which split it (with a speculative wave "
+            "in flight admit includes its engine dispatches)",
             ["worker", "part"], registry=r)
         self.batcher_scans = Counter(
             "batcher_scans_total",
@@ -736,7 +732,6 @@ class MetricsCollector:
         for key, metric in (
             ("decode_rounds", self.metrics.batcher_decode_rounds),
             ("completed", self.metrics.batcher_completed),
-            ("chunked_admissions", self.metrics.batcher_chunked_admissions),
             ("preemptions", self.metrics.batcher_preemptions),
             ("migrated", self.metrics.batcher_migrated),
         ):

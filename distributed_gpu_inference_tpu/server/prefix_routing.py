@@ -67,7 +67,8 @@ class RoutingConfig:
     # -- cluster-wide KV migration (round 13) -------------------------------
     # master switch for the per-request route-to-warm / migrate-KV /
     # recompute cost model. OFF by default: routing behaves byte-identically
-    # to the round-7 advisory scoring (the A/B flip for BENCH_r12)
+    # to the round-7 advisory scoring (not measured on the chip: no cell
+    # has a fleet)
     kv_migrate: bool = False
     # matches shallower than this never migrate (the transfer setup isn't
     # worth a block or two of saved prefill)

@@ -92,14 +92,13 @@ class WorkerRemoteConfig:
     # utils.config.ServingConfig that retune a RUNNING batcher between
     # decode rounds: max_horizon, min_horizon, multi_step, adaptive,
     # max_wait_ms, queue_limit, default_timeout_s, max_preemptions,
-    # spec_max_batch, spec_max_active, ragged, prefill_budget, ...:
+    # spec_max_batch, spec_max_active, prefill_budget, ...:
     # worker/engines/llm.py SERVING_REMOTE_KEYS).
-    # Compile-affecting admission knobs (subwave/interleave) and `mode`
-    # are load-time-only worker YAML and silently ignored by the worker if
-    # pushed. The round-6 ragged serving path made subwave/interleave/
-    # max_horizon degenerate, and the batcher's horizon rule reads no
-    # target_step_ms: still accepted (saved SLO configs keep
-    # deploying) but deprecation-warned once on ingest — see
+    # `mode` is load-time-only worker YAML and silently ignored by the
+    # worker if pushed. The keys of the admission path that is gone
+    # (ragged, subwave, interleave) and target_step_ms are read by nothing
+    # and max_horizon is degenerate: still accepted (saved SLO configs
+    # keep deploying) but deprecation-warned once on ingest — see
     # utils.config.DEPRECATED_SERVING_KEYS. Empty dict = no override (the
     # worker keeps its local config).
     serving: Dict[str, Any] = field(default_factory=dict)

@@ -7,12 +7,26 @@ slot binding — with real conservation semantics (blocks leave a free list
 on allocate and return on free) but no device, no model, no jit. Chaos
 scenarios replay streamed-handoff failures across dozens of seeds in
 milliseconds while still driving the production receiver code.
+
+:class:`FakeRaggedEngine` is the one fake of the batcher's one admission
+protocol (``submit_chunked_start`` → ``ragged_round`` / ``decode_multi`` →
+``finish_slot``): the scheduler tests drive the real
+``ContinuousBatcher`` loop over it.
 """
 
 from __future__ import annotations
 
+import itertools
+import types
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
+
+from ..utils.data_structures import InferenceRequest, InferenceResponse
+
+if TYPE_CHECKING:
+    from ..runtime.engine import ChunkedAdmission
 
 
 @dataclass
@@ -145,8 +159,6 @@ def make_stream_messages(
     page payloads are tiny synthetic tensors. Chaos scenarios mangle this
     sequence (loss / reorder / duplication / truncation) and assert the
     receiver's cleanup invariants."""
-    import numpy as np
-
     from ..runtime.kv_handoff import (  # deferred: pulls jax via engine deps
         _KIND_BEGIN,
         _KIND_COMMIT,
@@ -198,3 +210,147 @@ def make_stream_messages(
         "finish_reason": None,
     }))
     return msgs
+
+
+class _FakeRaggedSlot:
+    def __init__(self, request: InferenceRequest) -> None:
+        self.request = request
+        self.generated: List[int] = []
+        self.finish_reason: Optional[str] = None
+
+
+class FakeRaggedEngine:
+    """Deterministic in-memory engine speaking the batcher's one admission
+    protocol (ragged rounds): admissions bind slots immediately and
+    their prompts drain chunk-by-chunk through ``ragged_round``, honoring
+    the per-round ``chunk_caps`` the budgeted scheduler passes. Records
+    every round's granted prefill widths so tests can assert the budget
+    actually shaped the rounds. Token ids are position-deterministic, so
+    budgeted and unbudgeted runs must produce identical outputs."""
+
+    def __init__(self, *, max_batch_size=4, max_seq_len=4096,
+                 ragged_chunk=8, prefill_buckets=(8, 16)) -> None:
+        self.cfg = types.SimpleNamespace(
+            max_batch_size=max_batch_size, max_seq_len=max_seq_len,
+            ragged_chunk=ragged_chunk,
+            prefill_buckets=tuple(prefill_buckets),
+        )
+        self.slots: List[Optional[_FakeRaggedSlot]] = [None] * max_batch_size
+        self._adm: Dict[int, "ChunkedAdmission"] = {}
+        self.round_grants: List[Dict[int, int]] = []
+        self.caps_seen: List[Optional[Dict[int, int]]] = []
+        self._seq = itertools.count()
+
+    # ---- pool / introspection surface
+    def free_slots(self) -> List[int]:
+        return [i for i, s in enumerate(self.slots) if s is None]
+
+    @property
+    def num_active(self) -> int:
+        return sum(1 for s in self.slots if s is not None)
+
+    def request_fits_pool(self, request) -> bool:
+        return True
+
+    def resume_fits_pool(self, pre) -> bool:
+        return True
+
+    def take_pressure(self):
+        return None
+
+    def get_stats(self):
+        return {}
+
+    # ---- ragged admission surface
+    def submit_chunked_start(self, request) -> "ChunkedAdmission":
+        # imported here: the rest of this module stays free of JAX
+        from ..runtime.engine import ChunkedAdmission, RequestOverLength
+
+        toks = list(request.prompt_token_ids or [])
+        max_new = request.sampling.max_new_tokens
+        if len(toks) + max_new > self.cfg.max_seq_len:
+            raise RequestOverLength(
+                f"prompt {len(toks)} + max_new {max_new} exceeds "
+                f"max_seq_len {self.cfg.max_seq_len}"
+            )
+        slot = self.free_slots()[0]
+        self.slots[slot] = _FakeRaggedSlot(request)
+        adm = ChunkedAdmission(
+            request=request, slot=slot, seq_id=f"fk{next(self._seq)}",
+            fresh=toks, off=0, mode="fake",
+        )
+        self._adm[slot] = adm
+        return adm
+
+    def abort_chunked(self, adm) -> None:
+        self.slots[adm.slot] = None
+        self._adm.pop(adm.slot, None)
+
+    def _decode_one(self, slot: int) -> None:
+        s = self.slots[slot]
+        s.generated.append(1000 + len(s.generated))
+        if len(s.generated) >= s.request.sampling.max_new_tokens:
+            s.finish_reason = "length"
+
+    def ragged_round(self, admissions=(), chunk_caps=None) -> None:
+        self.caps_seen.append(
+            None if chunk_caps is None else dict(chunk_caps)
+        )
+        grants: Dict[int, int] = {}
+        chunk = max(1, int(self.cfg.ragged_chunk))
+        live = [a for a in admissions if not a.done]
+        for adm in live:
+            cap = chunk
+            if chunk_caps is not None and adm.slot in chunk_caps:
+                cap = min(cap, int(chunk_caps[adm.slot]))
+            if cap <= 0:
+                continue  # the budget skipped this admission this round
+            piece = adm.fresh[:cap]
+            adm.fresh = adm.fresh[len(piece):]
+            adm.off += len(piece)
+            grants[adm.slot] = len(piece)
+            if not adm.fresh:
+                adm.done = True
+                self._decode_one(adm.slot)  # final chunk samples token 0
+        # decode rows ride the same round for every non-admitting slot
+        for i, s in enumerate(self.slots):
+            if s is not None and s.finish_reason is None \
+                    and i not in self._adm:
+                self._decode_one(i)
+        for adm in live:
+            if adm.done:
+                self._adm.pop(adm.slot, None)
+        self.round_grants.append(grants)
+
+    def _decoding(self) -> List[int]:
+        return [i for i, s in enumerate(self.slots)
+                if s is not None and s.finish_reason is None
+                and i not in self._adm]
+
+    def decode_budgets(self) -> np.ndarray:
+        out = np.zeros(len(self.slots), dtype=np.int32)
+        for i in self._decoding():
+            s = self.slots[i]
+            out[i] = s.request.sampling.max_new_tokens - len(s.generated)
+        return out
+
+    def decode_multi(self, steps) -> Dict[int, List[int]]:
+        rows = self._decoding()
+        before = {i: len(self.slots[i].generated) for i in rows}
+        for _ in range(max(1, int(steps))):
+            for i in rows:
+                if self.slots[i].finish_reason is None:
+                    self._decode_one(i)
+        return {i: self.slots[i].generated[n:] for i, n in before.items()}
+
+    def finish_slot(self, slot: int) -> InferenceResponse:
+        s = self.slots[slot]
+        self.slots[slot] = None
+        self._adm.pop(slot, None)
+        return InferenceResponse(
+            request_id=s.request.request_id,
+            token_ids=list(s.generated),
+            finish_reason=s.finish_reason,
+            prompt_tokens=len(s.request.prompt_token_ids or []),
+            completion_tokens=len(s.generated),
+        )

@@ -28,24 +28,26 @@ log = logging.getLogger(__name__)
 
 ENV_PREFIX = "TPU_WORKER_"
 
-# Serving knobs obsoleted by the round-6 ragged serving path (one kernel
+# Serving knobs obsoleted by the ragged serving path (one kernel
 # invocation carrying prefill-chunk AND decode rows — admission appends
 # rows to the next round instead of scheduling competing dispatches, so
 # the admission-stall shaping these knobs tuned no longer exists) and by
-# the batcher's horizon rule. They stay ACCEPTED in worker YAML and remote
-# pushes (rolling fleets, saved SLO configs) but are warned once per
-# process; only the legacy path (``serving.ragged: false``) still reads
-# the first two, and nothing reads ``target_step_ms``.
+# the batcher's horizon rule. They stay ACCEPTED in worker YAML, plain-dict
+# engine configs and remote pushes (rolling fleets, saved SLO configs) but
+# are warned once per process; nothing reads ``ragged``, ``subwave``,
+# ``interleave`` or ``target_step_ms``.
 DEPRECATED_SERVING_KEYS: Dict[str, str] = {
+    "ragged": (
+        "ignored: ragged rounds are the one admission path — the legacy "
+        "wave / chunk-interleaved admission this key selected is gone"
+    ),
     "subwave": (
-        "the ragged serving path admits by appending chunk rows to the "
-        "next decode round — there are no admission sub-waves to shape; "
-        "only the legacy path (serving.ragged: false) reads this"
+        "ignored: admission appends chunk rows to the next round — there "
+        "are no admission sub-waves to shape"
     ),
     "interleave": (
-        "prefill chunks co-dispatch WITH decode rows in a ragged round — "
-        "there are no separate dispatches left to interleave; only the "
-        "legacy path (serving.ragged: false) reads this"
+        "ignored: prefill chunks co-dispatch WITH decode rows in a ragged "
+        "round — there are no separate dispatches left to interleave"
     ),
     "max_horizon": (
         "still caps the pure-decode scan horizon, but it is no longer the "
@@ -128,17 +130,16 @@ class ServingConfig(BaseModel):
     the SLO knobs, now first-class worker YAML keys
     (``worker/engines/llm.py`` SERVING_DEFAULTS mirrors these).
 
-    Since round 6 the default serving path runs RAGGED rounds (prefill
-    chunk rows and decode rows in one kernel dispatch), which obsoletes
-    the admission-stall shaping knobs: ``subwave`` / ``interleave`` /
-    ``max_horizon`` are still accepted (and ``max_horizon`` still caps the
-    pure-decode scan) but log a one-time deprecation warning when set —
-    see ``DEPRECATED_SERVING_KEYS``, which also holds ``target_step_ms``
-    (accepted, ignored). ``queue_limit`` / ``max_wait_ms`` / ``ragged`` /
-    ``max_horizon`` are remote-pushable (server
-    ``WorkerRemoteConfig.serving``) and retune a LIVE batcher;
-    ``subwave`` / ``interleave`` / ``mode`` are compile-affecting and
-    apply at engine load only."""
+    The serving path runs RAGGED rounds (prefill chunk rows and decode
+    rows in one kernel dispatch) and nothing else, so the keys that chose
+    or shaped the old admission — ``ragged`` / ``subwave`` /
+    ``interleave`` — and ``target_step_ms`` are accepted and ignored, and
+    ``max_horizon`` still caps the pure-decode scan; each logs a one-time
+    deprecation warning when set (``DEPRECATED_SERVING_KEYS``).
+    ``queue_limit`` / ``max_wait_ms`` / ``max_horizon`` and the rest of
+    ``SERVING_REMOTE_KEYS`` are remote-pushable (server
+    ``WorkerRemoteConfig.serving``) and retune a LIVE batcher; ``mode``
+    applies at engine load only."""
 
     mode: str = "batcher"               # batcher | direct (legacy driving)
     target_step_ms: Optional[float] = None   # DEPRECATED: read by nothing
@@ -150,14 +151,11 @@ class ServingConfig(BaseModel):
     queue_limit: int = 1024
     default_timeout_s: float = 300.0
     max_preemptions: int = 3
-    subwave: int = 0                    # DEPRECATED (legacy path only)
-    interleave: int = 0                 # DEPRECATED (legacy path only)
+    subwave: int = 0                    # DEPRECATED: read by nothing
+    interleave: int = 0                 # DEPRECATED: read by nothing
     spec_max_batch: int = 2
     spec_max_active: int = 2
-    # ragged rounds: None = auto (ragged whenever the engine supports it —
-    # THE default serving path), False = force the legacy wave/chunk-
-    # interleaved admission (A/B benchmarking), True = require ragged
-    ragged: Optional[bool] = None
+    ragged: Optional[bool] = None       # DEPRECATED: read by nothing
     # per-ROUND prefill token budget for ragged rounds: caps how many fresh
     # prompt tokens all concurrent admissions may prefill in one round
     # combined (fair water-fill split), so a 32k admission streams in over

@@ -66,7 +66,7 @@ def _raise_serving(resp: Any) -> None:
 SERVING_DEFAULTS: Dict[str, Any] = ServingConfig().model_dump()
 
 # remote-config ``serving`` keys that may retune a LIVE batcher (pushed via
-# WorkerRemoteConfig; the compile-affecting admission knobs are excluded)
+# WorkerRemoteConfig)
 SERVING_REMOTE_KEYS: Dict[str, str] = {
     "max_horizon": "max_multi_step",
     "min_horizon": "min_multi_step",
@@ -78,9 +78,6 @@ SERVING_REMOTE_KEYS: Dict[str, str] = {
     "max_preemptions": "max_preemptions",
     "spec_max_batch": "spec_max_batch",
     "spec_max_active": "spec_max_active",
-    # ragged rounds (round 6): remote-flippable so a fleet can A/B the
-    # ragged vs legacy admission path live (None = auto, the default)
-    "ragged": "ragged",
     # long-context round shaping: the per-round prefill token budget and
     # the per-admission chunk width are both read per-round (widths bucket
     # through compiled prefill_buckets), so they retune live without a
@@ -464,9 +461,6 @@ class TPULLMEngine(LLMBaseEngine):
                 self.config.get("kv_remote_url"),
                 ttl_s=float(self.config.get("kv_remote_ttl_s", 3600.0)),
             ),
-            # SLO admission shaping (compile-affecting: load-time only)
-            admission_subwave=int(sv["subwave"]),
-            admission_interleave_steps=int(sv["interleave"]),
             # long-context pool sizing: the default rule (1.5x batch x
             # max_blocks_per_seq) assumes every slot can run max_seq_len
             # deep — at 32k that is mostly pad, so deployments size the
@@ -645,8 +639,6 @@ class TPULLMEngine(LLMBaseEngine):
             max_preemptions=int(sv["max_preemptions"]),
             spec_max_batch=int(sv["spec_max_batch"]),
             spec_max_active=int(sv["spec_max_active"]),
-            ragged=(None if sv.get("ragged") is None
-                    else bool(sv["ragged"])),
             prefill_budget=int(sv.get("prefill_budget") or 0),
             abandon_deadlines=bool(sv.get("abandon_deadlines") or False),
             deadline_grace_s=float(sv.get("deadline_grace_s") or 0.5),
@@ -655,10 +647,9 @@ class TPULLMEngine(LLMBaseEngine):
 
     def apply_serving_config(self, updates: Optional[Dict[str, Any]]) -> None:
         """Server-pushed SLO retune (remote config ``serving`` section):
-        applied to the LIVE batcher between rounds. Compile-affecting
-        admission knobs (``subwave``/``interleave``) and ``mode`` are
-        load-time only and ignored here, as is ``target_step_ms``, which
-        nothing reads any more (warned once)."""
+        applied to the LIVE batcher between rounds. ``mode`` is load-time
+        only and ignored here, as are the keys nothing reads any more
+        (``DEPRECATED_SERVING_KEYS``: warned once)."""
         if self.serving is None or not updates:
             return
         for key in updates:
@@ -672,7 +663,7 @@ class TPULLMEngine(LLMBaseEngine):
             self.serving.reconfigure(**kw)
 
     def serving_stats(self) -> Optional[Dict[str, Any]]:
-        """Live batcher stats (occupancy, queue depth, chunked admissions,
+        """Live batcher stats (occupancy, queue depth, ragged admissions,
         preemption counters, horizon) — ride the worker heartbeat into the
         control plane's ``/metrics``. None when serving mode is direct."""
         if self.serving is None or not self.serving.active:

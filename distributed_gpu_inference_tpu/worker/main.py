@@ -471,8 +471,7 @@ class Worker:
             if not s:
                 continue
             for k in ("submitted", "completed", "rejected", "admitted",
-                      "decode_rounds", "chunked_admissions",
-                      "batched_waves", "preemptions", "resumes",
+                      "decode_rounds", "preemptions", "resumes",
                       "preempted_too_often", "cancelled", "migrated",
                       "abandoned", "abandoned_predictive"):
                 out[k] = out.get(k, 0) + int(s.get(k, 0) or 0)

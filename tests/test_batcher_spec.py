@@ -181,11 +181,9 @@ def test_mid_wave_arrivals_decode_paged_concurrently(stack):
     assert done_long.token_ids == want_long
     assert [r.token_ids for r in done_mid] == want_mid
     assert stats["spec_waves"] == 1
-    # mid-wave arrivals must go PAGED while the wave continues — via
-    # ragged admission rounds (the round-6 default) or, on engines
-    # without ragged support, a batched prefill wave
-    assert stats["ragged_admissions"] >= 3 or stats["batched_waves"] >= 1, \
-        "mid-wave arrivals must go paged"
+    # mid-wave arrivals must go PAGED (ragged admission rounds) while
+    # the wave continues
+    assert stats["ragged_admissions"] >= 3, "mid-wave arrivals must go paged"
 
 
 def test_spec_max_active_unsticks_routing(stack):

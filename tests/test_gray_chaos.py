@@ -531,7 +531,6 @@ class _PoolEngine:
     """The minimal engine surface an UNSTARTED batcher touches: items
     stay in the heap, so the deadline scan is exercised in isolation."""
 
-    supports_ragged = False
     slots: List[Any] = []
 
     def request_fits_pool(self, request: InferenceRequest) -> bool:

@@ -18,13 +18,11 @@ deployed-path run additionally carries ``longctx`` (HEAVY CI shard).
 """
 
 import asyncio
-import itertools
 import json
 import os
 import subprocess
 import sys
-import types
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 import pytest
@@ -35,13 +33,12 @@ from distributed_gpu_inference_tpu.runtime.batcher import (
     split_prefill_budget,
 )
 from distributed_gpu_inference_tpu.runtime.engine import (
-    ChunkedAdmission,
     PreemptedSequence,
     RequestOverLength,
 )
+from distributed_gpu_inference_tpu.testing.fakes import FakeRaggedEngine
 from distributed_gpu_inference_tpu.utils.data_structures import (
     InferenceRequest,
-    InferenceResponse,
     SamplingParams,
 )
 from distributed_gpu_inference_tpu.utils.prefixes import (
@@ -168,154 +165,6 @@ class TestPrefixFingerprintDepth:
         assert out.stdout.split() == ["96", "96"]
 
 
-# --------------------------------------------------------------------- #
-# fake ragged engine: the minimal surface the batcher's ragged loop uses
-# --------------------------------------------------------------------- #
-
-
-class _FakeSlot:
-    def __init__(self, request: InferenceRequest) -> None:
-        self.request = request
-        self.generated: List[int] = []
-        self.finish_reason: Optional[str] = None
-
-
-class FakeRaggedEngine:
-    """Deterministic in-memory engine speaking the batcher's ragged-round
-    protocol (``supports_ragged``): admissions bind slots immediately and
-    their prompts drain chunk-by-chunk through ``ragged_round``, honoring
-    the per-round ``chunk_caps`` the budgeted scheduler passes. Records
-    every round's granted prefill widths so tests can assert the budget
-    actually shaped the rounds. Token ids are position-deterministic, so
-    budgeted and unbudgeted runs must produce identical outputs."""
-
-    supports_ragged = True
-
-    def __init__(self, *, max_batch_size=4, max_seq_len=4096,
-                 ragged_chunk=8, prefill_buckets=(8, 16)) -> None:
-        self.cfg = types.SimpleNamespace(
-            max_batch_size=max_batch_size, max_seq_len=max_seq_len,
-            ragged_chunk=ragged_chunk,
-            prefill_buckets=tuple(prefill_buckets),
-        )
-        self.slots: List[Optional[_FakeSlot]] = [None] * max_batch_size
-        self._adm: Dict[int, ChunkedAdmission] = {}
-        self.round_grants: List[Dict[int, int]] = []
-        self.caps_seen: List[Optional[Dict[int, int]]] = []
-        self._seq = itertools.count()
-
-    # ---- pool / introspection surface
-    def free_slots(self) -> List[int]:
-        return [i for i, s in enumerate(self.slots) if s is None]
-
-    @property
-    def num_active(self) -> int:
-        return sum(1 for s in self.slots if s is not None)
-
-    def request_fits_pool(self, request) -> bool:
-        return True
-
-    def resume_fits_pool(self, pre) -> bool:
-        return True
-
-    def take_pressure(self):
-        return None
-
-    def get_stats(self):
-        return {}
-
-    # ---- ragged admission surface
-    def submit_chunked_start(self, request) -> ChunkedAdmission:
-        toks = list(request.prompt_token_ids or [])
-        max_new = request.sampling.max_new_tokens
-        if len(toks) + max_new > self.cfg.max_seq_len:
-            raise RequestOverLength(
-                f"prompt {len(toks)} + max_new {max_new} exceeds "
-                f"max_seq_len {self.cfg.max_seq_len}"
-            )
-        slot = self.free_slots()[0]
-        self.slots[slot] = _FakeSlot(request)
-        adm = ChunkedAdmission(
-            request=request, slot=slot, seq_id=f"fk{next(self._seq)}",
-            fresh=toks, off=0, mode="fake",
-        )
-        self._adm[slot] = adm
-        return adm
-
-    def abort_chunked(self, adm) -> None:
-        self.slots[adm.slot] = None
-        self._adm.pop(adm.slot, None)
-
-    def _decode_one(self, slot: int) -> None:
-        s = self.slots[slot]
-        s.generated.append(1000 + len(s.generated))
-        if len(s.generated) >= s.request.sampling.max_new_tokens:
-            s.finish_reason = "length"
-
-    def ragged_round(self, admissions=(), chunk_caps=None) -> None:
-        self.caps_seen.append(
-            None if chunk_caps is None else dict(chunk_caps)
-        )
-        grants: Dict[int, int] = {}
-        chunk = max(1, int(self.cfg.ragged_chunk))
-        live = [a for a in admissions if not a.done]
-        for adm in live:
-            cap = chunk
-            if chunk_caps is not None and adm.slot in chunk_caps:
-                cap = min(cap, int(chunk_caps[adm.slot]))
-            if cap <= 0:
-                continue  # the budget skipped this admission this round
-            piece = adm.fresh[:cap]
-            adm.fresh = adm.fresh[len(piece):]
-            adm.off += len(piece)
-            grants[adm.slot] = len(piece)
-            if not adm.fresh:
-                adm.done = True
-                self._decode_one(adm.slot)  # final chunk samples token 0
-        # decode rows ride the same round for every non-admitting slot
-        for i, s in enumerate(self.slots):
-            if s is not None and s.finish_reason is None \
-                    and i not in self._adm:
-                self._decode_one(i)
-        for adm in live:
-            if adm.done:
-                self._adm.pop(adm.slot, None)
-        self.round_grants.append(grants)
-
-    def _decoding(self) -> List[int]:
-        return [i for i, s in enumerate(self.slots)
-                if s is not None and s.finish_reason is None
-                and i not in self._adm]
-
-    def decode_budgets(self) -> np.ndarray:
-        out = np.zeros(len(self.slots), dtype=np.int32)
-        for i in self._decoding():
-            s = self.slots[i]
-            out[i] = s.request.sampling.max_new_tokens - len(s.generated)
-        return out
-
-    def decode_multi(self, steps) -> Dict[int, List[int]]:
-        rows = self._decoding()
-        before = {i: len(self.slots[i].generated) for i in rows}
-        for _ in range(max(1, int(steps))):
-            for i in rows:
-                if self.slots[i].finish_reason is None:
-                    self._decode_one(i)
-        return {i: self.slots[i].generated[n:] for i, n in before.items()}
-
-    def finish_slot(self, slot: int) -> InferenceResponse:
-        s = self.slots[slot]
-        self.slots[slot] = None
-        self._adm.pop(slot, None)
-        return InferenceResponse(
-            request_id=s.request.request_id,
-            token_ids=list(s.generated),
-            finish_reason=s.finish_reason,
-            prompt_tokens=len(s.request.prompt_token_ids or []),
-            completion_tokens=len(s.generated),
-        )
-
-
 async def _drive(engine: FakeRaggedEngine, cfg: BatcherConfig,
                  prompts: List[List[int]], max_new=4):
     b = ContinuousBatcher(engine, cfg)
@@ -428,6 +277,65 @@ class TestBudgetedScheduler:
         assert RequestOverLength.error_code == "over_length"
         err = RequestOverLength("too big")
         assert getattr(err, "error_code", None) == "over_length"
+
+
+# --------------------------------------------------------------------- #
+# one admission path: no switch, no second set of stats, nobody starved
+# --------------------------------------------------------------------- #
+
+
+def test_one_admission_path_has_no_switch_and_no_legacy_stats():
+    """``BatcherConfig`` cannot select an admission path and the batcher
+    counts one: what the wave / chunk-interleaved admission counted is
+    gone with it, and every admission is a ragged one."""
+    assert not hasattr(BatcherConfig(), "ragged")
+    resps, stats = _run(_drive(
+        FakeRaggedEngine(), BatcherConfig(max_wait_ms=5),
+        [list(range(30)), list(range(40, 46))],
+    ))
+    assert all(r.ok for r in resps)
+    for gone in ("chunked_admissions", "batched_waves", "ragged_mode"):
+        assert gone not in stats
+    assert stats["ragged_admissions"] == 2 and stats["ragged_rounds"] > 0
+    assert stats["admitted"] == 2
+
+
+def test_two_long_prompts_in_flight_do_not_starve_shorts():
+    """While one long prompt is mid prefill, a second long prompt at the
+    head of the admission order takes a slot beside it — both prefill in
+    the same rounds — and the short requests behind them ride those rounds
+    too: they finish while the long prompts are still streaming in."""
+    eng = FakeRaggedEngine(max_batch_size=4, ragged_chunk=8)
+    finished: List[str] = []
+
+    async def go():
+        b = ContinuousBatcher(eng, BatcherConfig(max_wait_ms=1))
+        b.start()
+
+        async def one(name, prompt, priority=0):
+            resp = await b.submit(_req(prompt, max_new=3, priority=priority))
+            finished.append(name)
+            return resp
+
+        long_a = asyncio.ensure_future(one("a", range(120), priority=1))
+        while not eng.round_grants:         # A's prefill is under way
+            await asyncio.sleep(0.001)
+        rest = [one("b", range(200, 320), priority=9)] + [
+            one(f"short{i}", range(400 + 10 * i, 406 + 10 * i))
+            for i in range(2)]
+        resps = await asyncio.gather(long_a, *rest)
+        stats = b.get_stats()
+        await b.stop()
+        return resps, stats
+
+    resps, stats = _run(go())
+    assert all(r.ok and r.completion_tokens == 3 for r in resps)
+    assert stats["ragged_admissions"] == 4
+    # both long prompts landed a full piece in one and the same round
+    assert any(sum(1 for w in g.values() if w == 8) >= 2
+               for g in eng.round_grants)
+    # and the shorts did not wait for either of them
+    assert set(finished[:2]) == {"short0", "short1"}
 
 
 # --------------------------------------------------------------------- #

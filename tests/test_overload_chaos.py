@@ -420,7 +420,6 @@ class _StubEngineCfg:
 
 class _StubEngine:
     cfg = _StubEngineCfg()
-    supports_ragged = False
     num_active = 0
 
     def __init__(self) -> None:

@@ -615,7 +615,6 @@ def test_refresh_worker_preserves_preflip_and_active_counts():
 
 class _PoolEngine:
     max_num_seqs = 8
-    supports_ragged = False
 
     def request_fits_pool(self, request: InferenceRequest) -> bool:
         return True

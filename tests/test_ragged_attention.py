@@ -256,8 +256,8 @@ def test_paged_attention_routes_ragged_impl(monkeypatch):
 
 
 # --------------------------------------------------------------------- #
-# serving level: ragged rounds are the default and byte-identical to the
-# split dispatches (the PR 3-5 machinery rides on this equivalence)
+# serving level: the batcher's ragged rounds are byte-identical to
+# generate()'s whole-wave prefill (checkpoints and failover ride on it)
 # --------------------------------------------------------------------- #
 
 from distributed_gpu_inference_tpu.models.configs import get_model_config
@@ -269,6 +269,7 @@ from distributed_gpu_inference_tpu.runtime.engine import (
     EngineConfig,
     TPUEngine,
 )
+from distributed_gpu_inference_tpu.testing.fakes import FakeRaggedEngine
 from distributed_gpu_inference_tpu.utils.data_structures import (
     InferenceRequest,
     SamplingParams,
@@ -298,11 +299,18 @@ def _req(prompt, max_new=8, temperature=0.0, seed=None):
     )
 
 
-def _serve(params, reqs, ragged):
+def _reference(params, reqs):
+    """The same requests through ``TPUEngine.generate()`` on a fresh
+    engine: the whole-wave and single-chunk prefill, no ragged round."""
+    return TPUEngine(CFG, _ecfg(), params=params).generate(
+        reqs, use_multi_step=True)
+
+
+def _serve(params, reqs):
     """Run one request set through a fresh batcher; returns (responses in
     submit order, batcher stats)."""
     eng = TPUEngine(CFG, _ecfg(), params=params)
-    cfg = BatcherConfig(max_wait_ms=2, ragged=None if ragged else False)
+    cfg = BatcherConfig(max_wait_ms=2)
 
     async def go():
         b = ContinuousBatcher(eng, cfg)
@@ -327,19 +335,14 @@ def _mixed_workload():
 
 @pytest.mark.slow
 def test_ragged_is_default_and_greedy_byte_identical(params):
-    got, gs = _serve(params, _mixed_workload(), ragged=True)
-    want, ws = _serve(params, _mixed_workload(), ragged=False)
+    got, gs = _serve(params, _mixed_workload())
+    want = _reference(params, _mixed_workload())
     assert all(r.ok for r in got) and all(r.ok for r in want)
     for g, w in zip(got, want):
         assert g.token_ids == w.token_ids      # byte-identical greedy
-    # the default path actually ran ragged rounds (admissions appended to
-    # rounds, no competing prefill dispatch)...
+    # every admission was appended to rounds: no prefill dispatch of its own
     assert gs["ragged_admissions"] == len(_mixed_workload())
     assert gs["ragged_rounds"] > 0
-    assert gs["chunked_admissions"] == 0 and gs["batched_waves"] == 0
-    # ...and the legacy run used the split machinery it A/Bs against
-    assert ws["ragged_rounds"] == 0
-    assert ws["chunked_admissions"] > 0 or ws["batched_waves"] > 0
 
 
 @pytest.mark.slow
@@ -351,8 +354,8 @@ def test_ragged_seeded_sampling_stable(params):
              temperature=0.7, seed=42, max_new=6),
         _req([(i * 11 + 2) % 500 for i in range(20)]),   # greedy alongside
     ]
-    got, _ = _serve(params, reqs, ragged=True)
-    want, _ = _serve(params, reqs, ragged=False)
+    got, _ = _serve(params, reqs)
+    want = _reference(params, reqs)
     for g, w in zip(got, want):
         assert g.ok and w.ok
         assert g.token_ids == w.token_ids      # sampler folds position
@@ -361,70 +364,64 @@ def test_ragged_seeded_sampling_stable(params):
 @pytest.mark.slow
 def test_ragged_long_prompt_admitted_mid_decode(params):
     """A long prompt arriving while decodes are active rides the shared
-    rounds as chunk rows — outputs match the legacy chunk-interleaved
-    admission byte for byte."""
+    rounds as chunk rows — both outputs match ``generate()``'s byte for
+    byte (greedy rows do not depend on what shares their round)."""
+    early_req = _req([(i * 7 + 1) % 500 for i in range(12)], max_new=12)
+    late_req = _req([(i * 23 + 4) % 500 for i in range(90)], max_new=5)
+    eng = TPUEngine(CFG, _ecfg(), params=params)
 
-    def run(ragged):
-        eng = TPUEngine(CFG, _ecfg(), params=params)
-        cfg = BatcherConfig(max_wait_ms=1,
-                            ragged=None if ragged else False)
+    async def go():
+        b = ContinuousBatcher(eng, BatcherConfig(max_wait_ms=1))
+        b.start()
+        first = asyncio.ensure_future(b.submit(early_req))
+        await asyncio.sleep(0.05)   # let decoding start
+        late = await b.submit(late_req)
+        early = await first
+        await b.stop()
+        return early, late
 
-        async def go():
-            b = ContinuousBatcher(eng, cfg)
-            b.start()
-            first = asyncio.ensure_future(
-                b.submit(_req([(i * 7 + 1) % 500 for i in range(12)],
-                              max_new=12)))
-            await asyncio.sleep(0.05)   # let decoding start
-            late = await b.submit(
-                _req([(i * 23 + 4) % 500 for i in range(90)], max_new=5))
-            early = await first
-            await b.stop()
-            return early, late
-
-        return asyncio.run(go())
-
-    ge, gl = run(True)
-    we, wl = run(False)
+    ge, gl = asyncio.run(go())
+    we, wl = _reference(params, [early_req, late_req])
     assert ge.ok and gl.ok and ge.token_ids == we.token_ids
     assert gl.token_ids == wl.token_ids
 
 
 def test_use_ragged_resolution():
-    """Default resolution facts the chaos suites lean on: a DEFAULT
-    BatcherConfig on a plain paged engine serves ragged (so the
-    pressure/failover/batcher_serving suites — which construct default
-    batchers — exercised ragged rounds), cfg.ragged=False forces legacy,
-    and engines without ragged support fall back automatically."""
-    assert BatcherConfig().ragged is None    # auto, not force-off
+    """The batcher has one admission path and names its protocol once: a
+    DEFAULT BatcherConfig on an engine that speaks it serves ragged rounds
+    (so the pressure/failover/batcher_serving suites — which construct
+    default batchers — exercise them); an engine that says
+    ``supports_ragged`` is False (today only ``kv_seq_sharded``) is
+    refused with the fence named; a stub without the attribute is taken to
+    speak the protocol."""
+    assert not hasattr(BatcherConfig(), "ragged")
+
+    async def serve():
+        eng = FakeRaggedEngine()
+        b = ContinuousBatcher(eng, BatcherConfig(max_wait_ms=1))
+        b.start()
+        resp = await b.submit(_req(range(20), max_new=3))
+        stats = b.get_stats()
+        await b.stop()
+        return resp, stats, eng
+
+    resp, stats, eng = asyncio.run(serve())
+    assert resp.ok and resp.completion_tokens == 3
+    assert stats["ragged_admissions"] == 1 and stats["ragged_rounds"] >= 1
+    assert sum(sum(g.values()) for g in eng.round_grants) == 20
 
     class _Cfg:
         speculative = None
 
-    class _Eng:
+    class _Stub:
         cfg = _Cfg()
 
-    class _RaggedEng(_Eng):
-        supports_ragged = True
+    class _ShardedEng(_Stub):
+        supports_ragged = False
 
-    assert ContinuousBatcher(_RaggedEng(), BatcherConfig()).use_ragged
-    assert not ContinuousBatcher(
-        _RaggedEng(), BatcherConfig(ragged=False)).use_ragged
-    # fakes / seq-sharded engines: no supports_ragged (spec-integrated
-    # engines serve ragged since round 8 — tests/test_spec_serving.py)
-    assert not ContinuousBatcher(_Eng(), BatcherConfig()).use_ragged
-    # ragged=True is REQUIRE, not prefer: a silent legacy fallback would
-    # make every downstream A/B ratio a lie — rejected at init and at
-    # live reconfigure
-    assert ContinuousBatcher(
-        _RaggedEng(), BatcherConfig(ragged=True)).use_ragged
-    with pytest.raises(ValueError, match="ragged"):
-        ContinuousBatcher(_Eng(), BatcherConfig(ragged=True))
-    b = ContinuousBatcher(_Eng(), BatcherConfig())
-    with pytest.raises(ValueError, match="ragged"):
-        b.reconfigure(ragged=True)
-    b.reconfigure(ragged=False)      # forcing legacy is always allowed
-    assert b.cfg.ragged is False
+    ContinuousBatcher(_Stub(), BatcherConfig())     # accepted
+    with pytest.raises(ValueError, match="kv_seq_sharded"):
+        ContinuousBatcher(_ShardedEng(), BatcherConfig())
 
 
 @pytest.mark.slow
@@ -433,9 +430,9 @@ def test_supports_ragged_engine_facts(params):
 
     eng = TPUEngine(CFG, _ecfg(), params=params)
     assert eng.supports_ragged
-    # seq-sharded pools keep the split paths (their decode rows read
-    # through a dedicated shard_map op); spec-integrated engines serve
-    # ragged since round 8. Flip the config fact on the live object —
+    # seq-sharded pools have no ragged round (their decode rows read
+    # through a dedicated shard_map op) and the batcher refuses them;
+    # spec-integrated engines serve ragged since round 8. Flip the config fact on the live object —
     # constructing a seq-sharded engine needs a mesh
     orig = eng.cfg
     try:

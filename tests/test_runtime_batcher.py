@@ -132,21 +132,16 @@ def test_priority_admission(engine):
     )
 
     order = []
-    orig_submit_batch = small.submit_batch
+    orig_start = small.submit_chunked_start
 
-    def tracking_submit_batch(requests, partial=False):
-        order.extend(r.priority for r in requests)
-        return orig_submit_batch(requests, partial=partial)
+    def tracking_start(request, slot=None):
+        order.append(request.priority)
+        return orig_start(request, slot)
 
-    small.submit_batch = tracking_submit_batch
+    small.submit_chunked_start = tracking_start
 
     async def go():
-        # ragged=False: this test spies on engine.submit_batch, the LEGACY
-        # wave-admission entry point (ragged admissions bind through
-        # submit_chunked_start instead; priority order under ragged is
-        # covered in tests/test_ragged_attention.py)
-        b = ContinuousBatcher(small, BatcherConfig(max_wait_ms=30,
-                                                   ragged=False))
+        b = ContinuousBatcher(small, BatcherConfig(max_wait_ms=30))
         lo = asyncio.ensure_future(
             b.submit(_req(list(range(16)), max_new=3, priority=0))
         )
@@ -240,102 +235,14 @@ def test_non_adaptive_honors_configured_multi_step():
 
 
 # ---------------------------------------------------------------------------
-# Round 2: batched wave admission + chunk-interleaved long prompts
+# long prompts in flight beside short requests
 # ---------------------------------------------------------------------------
 
 
-def test_wave_admission_one_prefill_call_per_bucket():
-    """A same-bucket wave admits via ONE batched prefill device call
-    (engine.submit_batch), not one per request (VERDICT r1 #3)."""
-    eng = TPUEngine(
-        "llama3-tiny",
-        EngineConfig(max_batch_size=4, max_seq_len=128,
-                     prefill_buckets=(16, 32), multi_step=4),
-    )
-
-    async def drive():
-        # ragged=False pins the LEGACY wave path this test is about
-        # (ragged-mode admission never calls submit_batch)
-        b = ContinuousBatcher(eng, BatcherConfig(max_wait_ms=20.0,
-                                                 multi_step=4,
-                                                 ragged=False))
-        b.start()
-        before = eng.stats["prefill_calls"]
-        reqs = [
-            InferenceRequest(
-                prompt_token_ids=list(range(10 + i, 26 + i)),
-                sampling=SamplingParams(max_new_tokens=4),
-            )
-            for i in range(4)
-        ]
-        outs = await asyncio.gather(*(b.submit(r) for r in reqs))
-        await b.stop()
-        return outs, eng.stats["prefill_calls"] - before, b.get_stats()
-
-    outs, prefill_calls, stats = asyncio.run(drive())
-    assert all(o.error is None and o.completion_tokens == 4 for o in outs)
-    # all 4 prompts share the 16-token bucket → exactly one prefill call
-    assert prefill_calls == 1, prefill_calls
-    assert stats["batched_waves"] == 1
-
-
-def test_chunked_admission_interleaves_decode():
-    """A long prompt admits chunk by chunk, and decode rounds for the other
-    slots run BETWEEN its chunks — no decode stall longer than one chunk
-    (VERDICT r1 #4)."""
-    eng = TPUEngine(
-        "llama3-tiny",
-        EngineConfig(max_batch_size=2, max_seq_len=256,
-                     prefill_buckets=(16, 32), multi_step=2,
-                     enable_prefix_cache=False),
-    )
-    decode_calls_at_chunk = []
-    orig_step = eng.submit_chunked_step
-
-    def spy_step(adm):
-        decode_calls_at_chunk.append(eng.stats["decode_calls"])
-        return orig_step(adm)
-
-    eng.submit_chunked_step = spy_step
-
-    async def drive():
-        # ragged=False pins the LEGACY chunk-interleaved admission this
-        # test spies on (ragged mode co-dispatches chunk rows WITH decode
-        # rows instead of interleaving separate dispatches)
-        b = ContinuousBatcher(eng, BatcherConfig(max_wait_ms=1.0,
-                                                 multi_step=2,
-                                                 ragged=False))
-        b.start()
-        # short request keeps decoding while the long one admits
-        short = b.submit(InferenceRequest(
-            prompt_token_ids=list(range(10, 26)),
-            sampling=SamplingParams(max_new_tokens=40),
-        ))
-        await asyncio.sleep(0.05)  # let the short one start decoding
-        long = b.submit(InferenceRequest(
-            prompt_token_ids=[(i * 7) % 500 for i in range(150)],
-            sampling=SamplingParams(max_new_tokens=4),
-        ))
-        outs = await asyncio.gather(short, long)
-        await b.stop()
-        return outs, b.get_stats()
-
-    (short_out, long_out), stats = asyncio.run(drive())
-    assert short_out.error is None and short_out.completion_tokens == 40
-    assert long_out.error is None and long_out.completion_tokens == 4
-    assert long_out.prompt_tokens == 150
-    assert stats["chunked_admissions"] == 1
-    # 150 fresh tokens / 32-token max bucket → 5 chunk steps
-    assert len(decode_calls_at_chunk) == 5, decode_calls_at_chunk
-    # decode progressed between chunk steps (strictly increasing somewhere)
-    assert decode_calls_at_chunk[-1] > decode_calls_at_chunk[0], \
-        decode_calls_at_chunk
-
-
 def test_second_long_prompt_does_not_starve_shorts():
-    """While one chunked admission is in flight, a second long prompt at the
+    """While one long prompt is mid prefill, a second long prompt at the
     head of the admission order must not block short requests from free
-    slots (round-2 review finding)."""
+    slots: all of them ride the same rounds."""
     eng = TPUEngine(
         "llama3-tiny",
         EngineConfig(max_batch_size=3, max_seq_len=256,
@@ -344,12 +251,8 @@ def test_second_long_prompt_does_not_starve_shorts():
     )
 
     async def drive():
-        # ragged=False: the one-chunked-admission-at-a-time bottleneck this
-        # test guards only exists on the legacy path (ragged admissions
-        # all ride the same round, so there is nothing to starve)
         b = ContinuousBatcher(eng, BatcherConfig(max_wait_ms=1.0,
-                                                 multi_step=2,
-                                                 ragged=False))
+                                                 multi_step=2))
         b.start()
         long_a = b.submit(InferenceRequest(
             prompt_token_ids=[(i * 5) % 500 for i in range(120)],
@@ -372,4 +275,4 @@ def test_second_long_prompt_does_not_starve_shorts():
 
     outs, stats = asyncio.run(drive())
     assert all(o.error is None and o.completion_tokens == 3 for o in outs)
-    assert stats["chunked_admissions"] == 2
+    assert stats["ragged_admissions"] == 4

@@ -188,64 +188,6 @@ def test_submit_batch_rollback_scrubs_pending_and_stats():
                for c in eng.manager.pending.copies)
 
 
-# ---------------------------------------------------------------------------
-# sub-wave admission (VERDICT r2 #3)
-# ---------------------------------------------------------------------------
-
-
-def test_subwave_admission_matches_whole_wave():
-    """Splitting a wave into narrow sub-wave prefills must not change a
-    single greedy token vs the one-wide-call path."""
-    base = EngineConfig(
-        max_batch_size=6, max_seq_len=128, prefill_buckets=(16, 32, 64),
-        multi_step=8, dtype="float32",
-    )
-    sub = EngineConfig(
-        max_batch_size=6, max_seq_len=128, prefill_buckets=(16, 32, 64),
-        multi_step=8, dtype="float32", admission_subwave=2,
-    )
-    e1 = TPUEngine("llama3-tiny", base)
-    e2 = TPUEngine("llama3-tiny", sub)
-    prompts = [list(range(7 + i, 27 + 2 * i)) for i in range(6)]
-    r1 = e1.generate([_req(p) for p in prompts], use_multi_step=True)
-    r2 = e2.generate([_req(p) for p in prompts], use_multi_step=True)
-    for a, b in zip(r1, r2):
-        assert a.token_ids == b.token_ids
-    # the sub-wave engine really ran narrow prefills (3 calls of width 2
-    # per admission wave, not 1 wide call)
-    assert e2.stats["prefill_calls"] > e1.stats["prefill_calls"]
-
-
-def test_subwave_interleave_advances_existing_slots():
-    """With admission_interleave_steps set, slots that were already
-    generating advance between sub-waves instead of stalling for the whole
-    admission — and their tokens match an uninterleaved run."""
-    cfg = EngineConfig(
-        max_batch_size=6, max_seq_len=128, prefill_buckets=(16, 32, 64),
-        multi_step=8, dtype="float32", admission_subwave=1,
-        admission_interleave_steps=2,
-    )
-    eng = TPUEngine("llama3-tiny", cfg)
-    first = _req(list(range(30, 50)), max_new=24)
-    s0 = eng.submit(first)
-    gen_before = len(eng.slots[s0].generated)
-    wave = [_req(list(range(60 + i, 80 + i)), max_new=4) for i in range(4)]
-    eng.submit_batch(wave)
-    # the pre-existing slot advanced during admission (3 interleave gaps)
-    assert len(eng.slots[s0].generated) > gen_before
-    while any(s is not None and s.finish_reason is None for s in eng.slots):
-        eng.decode_multi()
-    resp0 = eng.finish_slot(s0)
-    # interleaved decode must not corrupt the sequence: same tokens as a
-    # clean engine generating solo
-    ref = TPUEngine("llama3-tiny", EngineConfig(
-        max_batch_size=6, max_seq_len=128, prefill_buckets=(16, 32, 64),
-        multi_step=8, dtype="float32",
-    ))
-    solo = ref.generate([_req(list(range(30, 50)), max_new=24)])[0]
-    assert resp0.token_ids == solo.token_ids
-
-
 def test_fp8_kv_cache_serves():
     """kv_cache_dtype="fp8": pools store float8_e4m3, generation still works
     and is deterministic; spill round-trips keep the fp8 dtype."""

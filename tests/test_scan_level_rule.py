@@ -22,7 +22,8 @@ from distributed_gpu_inference_tpu.runtime.batcher import (
     BatcherConfig,
     ContinuousBatcher,
 )
-from tests.test_long_context import FakeRaggedEngine, _req
+from distributed_gpu_inference_tpu.testing.fakes import FakeRaggedEngine
+from tests.test_long_context import _req
 
 # (s, h) in ms and the level they must give: the five cells as the chip
 # measured them (PERF.md section 6, PR 26: ``step_latency_ema_ms`` and
@@ -132,33 +133,30 @@ def test_a_fixed_horizon_keeps_its_one_level():
     assert b._choose_steps() == (8, "amortise")
 
 
-@pytest.mark.parametrize("waiting, chunked, budgets, multi_step, want", [
-    (False, False, [100, 100], 4, (4, "amortise")),
-    (True, False, [100, 50], 4, (16, "raised_waiting")),
-    (True, False, [100, 16], 4, (16, "raised_waiting")),
-    (True, False, [100, 15], 4, (4, "capped_by_budget")),
+@pytest.mark.parametrize("waiting, budgets, multi_step, want", [
+    (False, [100, 100], 4, (4, "amortise")),
+    (True, [100, 50], 4, (16, "raised_waiting")),
+    (True, [100, 16], 4, (16, "raised_waiting")),
+    (True, [100, 15], 4, (4, "capped_by_budget")),
     # never below amortise, whatever the budget
-    (True, False, [3, 100], 4, (4, "capped_by_budget")),
-    (True, False, [0, 0, 40, 0], 4, (16, "raised_waiting")),
-    (True, False, [4, 9], 1, (4, "raised_waiting")),
-    (True, False, [2, 3], 1, (1, "capped_by_budget")),
+    (True, [3, 100], 4, (4, "capped_by_budget")),
+    (True, [0, 0, 40, 0], 4, (16, "raised_waiting")),
+    (True, [4, 9], 1, (4, "raised_waiting")),
+    (True, [2, 3], 1, (1, "capped_by_budget")),
     # one level above, never two
-    (True, False, [500, 500], 1, (4, "raised_waiting")),
+    (True, [500, 500], 1, (4, "raised_waiting")),
     # nothing above the top level
-    (True, False, [500, 500], 64, (64, "amortise")),
-    # a legacy chunked admission advances between rounds: nobody waits
-    # for a slot
-    (True, True, [100, 100], 4, (4, "amortise")),
+    (True, [500, 500], 64, (64, "amortise")),
+    # the top level is reached from the one below, at the budget's edge
+    (True, [500, 64], 16, (64, "raised_waiting")),
 ], ids=["idle", "raised", "raised-at-the-budget", "capped", "capped-low",
         "empty-slots-ignored", "raised-from-1", "capped-at-1",
-        "one-level-only", "top-level", "chunked-in-flight"])
+        "one-level-only", "top-level", "raised-to-the-top"])
 def test_waiting_raises_one_level_capped_by_the_first_rows_end(
-        waiting, chunked, budgets, multi_step, want):
+        waiting, budgets, multi_step, want):
     b = _batcher(budgets, multi_step=multi_step)
     if waiting:
         b._heap.append(object())
-    if chunked:
-        b._chunked = (object(), object())
     assert b._choose_steps() == want
 
 

@@ -1,7 +1,7 @@
 """Spec decoding as a first-class serving path (round 8): spec ragged
 rounds (verify rows + prefill chunk rows in ONE dispatch), the deleted
 int8/sliding-window verify fences, acceptance-adaptive draft depth, and
-the oracle draft behind ``benchmarks/worker_serving.py --spec``.
+the oracle draft a ``*.spec`` benchmark cell will drive (ROADMAP R5).
 
 Tier-1 keeps the cheap contracts (config validation, oracle dither,
 depth selection, op-level tree-mask/int8 identities, one tiny smoke);
@@ -117,9 +117,9 @@ def test_spec_k_choices_static_set():
 
 
 def test_batcher_accepts_ragged_true_on_spec_engine():
-    """serving.ragged=true on a spec-integrated engine is an explicit
-    ACCEPT (spec ragged rounds are the serving path); seq-sharded-style
-    engines without ragged support still reject, naming the fence."""
+    """A spec-integrated engine is accepted (spec ragged rounds are its
+    serving path, and the batcher has no other); an engine that says it
+    has no ragged rounds is refused, naming the fence."""
     from distributed_gpu_inference_tpu.runtime.batcher import (
         BatcherConfig,
         ContinuousBatcher,
@@ -136,10 +136,9 @@ def test_batcher_accepts_ragged_true_on_spec_engine():
         cfg = _SpecCfg()
         supports_ragged = False
 
-    assert ContinuousBatcher(_SpecEng(), BatcherConfig(ragged=True)) \
-        .use_ragged
+    ContinuousBatcher(_SpecEng(), BatcherConfig())
     with pytest.raises(ValueError, match="kv_seq_sharded"):
-        ContinuousBatcher(_ShardedEng(), BatcherConfig(ragged=True))
+        ContinuousBatcher(_ShardedEng(), BatcherConfig())
 
 
 def test_oracle_dither_deterministic():
@@ -475,9 +474,8 @@ def test_tree_decoder_int8_greedy_equivalence():
 
 @pytest.mark.slow
 def test_batcher_serves_spec_engine_ragged():
-    """End to end: a ContinuousBatcher over a spec engine defaults to
-    ragged admission (explicit ragged=True accepted) and produces the
-    vanilla engine's greedy streams."""
+    """End to end: a ContinuousBatcher over a spec engine admits through
+    ragged rounds and produces the vanilla engine's greedy streams."""
     from distributed_gpu_inference_tpu.runtime.batcher import (
         BatcherConfig,
         ContinuousBatcher,
@@ -491,7 +489,7 @@ def test_batcher_serves_spec_engine_ragged():
     )
 
     async def run():
-        b = ContinuousBatcher(eb, BatcherConfig(ragged=True))
+        b = ContinuousBatcher(eb, BatcherConfig())
         b.start()
         rs = await asyncio.gather(*(b.submit(_req(p)) for p in PROMPTS))
         await b.stop()
@@ -502,5 +500,5 @@ def test_batcher_serves_spec_engine_ragged():
         assert g.error is None
         assert g.token_ids == w.token_ids
     assert st["ragged_admissions"] == len(PROMPTS)
-    assert st["ragged_mode"] is True
+    assert st["ragged_rounds"] > 0
     assert st["spec_integrated"]["steps"] > 0

@@ -1,5 +1,5 @@
-"""Draft-head distillation + toy-task target training (the speculative
-benchmark's methodology: real trained weights, no simulated accept rates)."""
+"""Draft-head distillation + toy-task target training (real trained
+weights, no simulated accept rates)."""
 
 import pytest
 
@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import train_toy_lm
 from distributed_gpu_inference_tpu.models import llama
 from distributed_gpu_inference_tpu.models.configs import get_model_config
 from distributed_gpu_inference_tpu.runtime.speculative import (
@@ -17,6 +16,7 @@ from distributed_gpu_inference_tpu.runtime.speculative import (
     draft_apply,
     init_draft_params,
 )
+from distributed_gpu_inference_tpu.testing.toy_lm import train_toy_lm
 
 CFG = get_model_config("llama3-tiny", dtype="float32")
 

@@ -2,7 +2,7 @@
 
 The ContinuousBatcher is the worker's front door: queued jobs and
 direct/SSE requests share decode rounds through one batcher, the SLO
-knobs (`max_wait_ms`, `subwave`, `interleave`, `max_horizon`, queue
+knobs (`max_wait_ms`, `max_horizon`, `prefill_budget`, queue
 limits) are worker YAML + server-pushable remote config, and batcher
 stats ride heartbeats into `/metrics`.
 
@@ -96,13 +96,20 @@ def test_serving_yaml_and_env_keys(tmp_path):
     assert dumped["serving"]["max_wait_ms"] == 40.0
 
 
+@pytest.mark.parametrize("key, value", [
+    ("target_step_ms", 50.0), ("ragged", False), ("subwave", 2),
+    ("interleave", 2),
+])
 @pytest.mark.parametrize("surface", ["yaml", "engine dict", "remote push"])
 def test_target_step_ms_is_accepted_warned_once_and_ignored(
-        surface, tmp_path, monkeypatch, caplog):
-    """The horizon rule reads no latency target (PR 26): saved worker YAML,
-    plain-dict engine configs (the OLMoE benchmark configuration carries
-    ``target_step_ms: 50``) and remote pushes that still name one keep
-    loading, say so once a process, and change nothing."""
+        surface, key, value, tmp_path, monkeypatch, caplog):
+    """The horizon rule reads no latency target (PR 26) and the batcher
+    has one admission path (PR 27): saved worker YAML, plain-dict engine
+    configs (the OLMoE benchmark configuration carries
+    ``target_step_ms: 50``) and remote pushes that still name
+    ``target_step_ms``, ``ragged``, ``subwave`` or ``interleave`` keep
+    loading, say so once a process, and change nothing — the batcher they
+    build is the default one, which serves ragged rounds."""
     import logging
 
     from distributed_gpu_inference_tpu.runtime.batcher import BatcherConfig
@@ -121,30 +128,31 @@ def test_target_step_ms_is_accepted_warned_once_and_ignored(
         def reconfigure(self, **kw):
             self.pushed.append(kw)
 
-    eng = TPULLMEngine({"model": "llama3-tiny",
-                        "serving": {"target_step_ms": 50.0}})
+    eng = TPULLMEngine({"model": "llama3-tiny", "serving": {key: value}})
 
     def load():
         if surface == "yaml":
             yml = tmp_path / "config.yaml"
             yml.write_text(
                 "engines:\n  llm:\n    engine: jax\n    model: llama3-tiny\n"
-                "    serving:\n      target_step_ms: 50\n")
+                f"    serving:\n      {key}: {json.dumps(value)}\n")
             sv = load_worker_config(yml, environ={}).engines["llm"].serving
             return sv.model_dump()
         if surface == "engine dict":
             return eng._serving_config()
         eng.serving = Serving()
-        eng.apply_serving_config({"target_step_ms": 50.0})
+        eng.apply_serving_config({key: value})
         assert Serving.pushed == []             # nothing reached the batcher
         return eng._serving_config()
 
     for _ in range(3):
         sv = load()
-    said = [r for r in caplog.records if "target_step_ms" in r.getMessage()]
+    said = [r for r in caplog.records if f"serving.{key} " in r.getMessage()]
     assert len(said) == 1 and "deprecated" in said[0].getMessage()
-    assert "target_step_ms" not in SERVING_REMOTE_KEYS
+    assert sv[key] == value                     # accepted as written
+    assert key not in SERVING_REMOTE_KEYS
     assert TPULLMEngine._batcher_config(sv) == BatcherConfig()
+    assert not hasattr(BatcherConfig(), key)
 
 
 def test_remote_config_serving_merge_and_version_bump():
@@ -201,8 +209,8 @@ def test_worker_pushes_remote_serving_to_engines():
 
 
 def test_remote_pushable_keys_match_serving_config():
-    """Every live-pushable key is a real ServingConfig field, and the
-    compile-affecting knobs are NOT pushable."""
+    """Every live-pushable key is a real ServingConfig field; ``mode``
+    (load-time only) and the keys nothing reads are NOT pushable."""
     from distributed_gpu_inference_tpu.worker.engines.llm import (
         SERVING_DEFAULTS,
         SERVING_REMOTE_KEYS,
@@ -211,8 +219,9 @@ def test_remote_pushable_keys_match_serving_config():
     fields = set(ServingConfig.model_fields)
     assert set(SERVING_REMOTE_KEYS) <= fields
     assert set(SERVING_DEFAULTS) == fields
-    for load_time_only in ("subwave", "interleave", "mode"):
-        assert load_time_only not in SERVING_REMOTE_KEYS
+    for unpushable in ("ragged", "subwave", "interleave", "target_step_ms",
+                       "mode"):
+        assert unpushable not in SERVING_REMOTE_KEYS
 
 
 # ---------------------------------------------------------------------------
@@ -267,7 +276,7 @@ def test_batcher_stats_heartbeat_payload():
         def serving_stats(self):
             return {
                 "submitted": 10, "completed": 9, "decode_rounds": 40,
-                "chunked_admissions": 2, "queue_depth": 3,
+                "queue_depth": 3,
                 "active_slots": 4, "avg_occupancy": 3.4, "horizon": 16.0,
                 "preemptions": 1, "resumes": 1, "migrated": 0,
             }
@@ -290,7 +299,7 @@ def test_record_batcher_engine_delta_anchoring():
     mc = MetricsCollector()
     mc.record_batcher_engine("w1", {
         "queue_depth": 2, "avg_occupancy": 3.0, "decode_rounds": 10,
-        "completed": 5, "chunked_admissions": 1, "preemptions": 0,
+        "completed": 5, "preemptions": 0,
         "migrated": 0, "horizon": 4.0, "active_slots": 3,
     })
     mc.record_batcher_engine("w1", {"decode_rounds": 25, "completed": 7})
@@ -355,10 +364,8 @@ def test_synthesize_checkpoint_seed_roundtrip():
 
 
 def test_micro_read_impl_crossover_and_serving_label(monkeypatch):
-    # since round 6 the micro-bench read crossover lives in resolve_impl
-    # itself (fused=False + rows); MICRO_READ_XLA_MIN_BATCH survives as an
-    # env OVERRIDE only, and benchmarks/paged_attention_micro.py no longer
-    # duplicates the resolution logic
+    # the bare-read crossover lives in resolve_impl itself (fused=False +
+    # rows); MICRO_READ_XLA_MIN_BATCH survives as an env OVERRIDE only
     from distributed_gpu_inference_tpu.ops.attention import (
         micro_read_xla_min_batch,
         resolve_impl,
@@ -391,13 +398,11 @@ def test_micro_read_impl_crossover_and_serving_label(monkeypatch):
                         backend_is_tpu=False) == "xla"
 
 
-@pytest.mark.parametrize("ragged", [True, False])
-def test_cancel_aborts_chunked_admission(ragged):
-    """A cancel landing while a long prompt is mid prefill must abort the
-    admission (freeing its slot and staged blocks), not burn the remaining
-    chunks for an abandoned client — on BOTH the ragged path (chunk rows
-    riding shared rounds, the default) and the legacy chunk-interleaved
-    path."""
+def test_cancel_aborts_chunked_admission():
+    """A cancel landing while a long prompt is mid prefill (its chunk rows
+    riding shared rounds) must abort the admission (freeing its slot and
+    staged blocks), not burn the remaining chunks for an abandoned
+    client."""
     import asyncio
 
     from distributed_gpu_inference_tpu.runtime.batcher import (
@@ -417,8 +422,7 @@ def test_cancel_aborts_chunked_admission(ragged):
     )
 
     async def go():
-        b = ContinuousBatcher(eng, BatcherConfig(max_wait_ms=1.0,
-                                                 ragged=ragged))
+        b = ContinuousBatcher(eng, BatcherConfig(max_wait_ms=1.0))
         b.start()
         cancel = threading.Event()
         fut = asyncio.ensure_future(b.submit(
@@ -429,11 +433,9 @@ def test_cancel_aborts_chunked_admission(ragged):
             cancel=cancel,
         ))
         deadline = time.time() + 20.0
-        while b._chunked is None and not b._ragged \
-                and time.time() < deadline:
+        while not b._ragged and time.time() < deadline:
             await asyncio.sleep(0.005)
-        assert b._chunked is not None or b._ragged, \
-            "admission never started"
+        assert b._ragged, "admission never started"
         cancel.set()
         resp = await fut
         stats = dict(b.stats)
@@ -607,7 +609,7 @@ def test_drain_freezes_batcher_job_into_resumable_checkpoint(llm):
 def test_apply_serving_config_retunes_live_batcher(llm):
     llm.apply_serving_config({"max_wait_ms": 12.5, "max_horizon": 4,
                               "queue_limit": 77,
-                              "subwave": 9})     # load-time key: ignored
+                              "subwave": 9})     # read by nothing
     deadline = time.time() + 5.0
     while time.time() < deadline and \
             llm.serving.batcher.cfg.queue_limit != 77:
@@ -616,7 +618,6 @@ def test_apply_serving_config_retunes_live_batcher(llm):
     assert cfg.max_wait_ms == 12.5
     assert cfg.max_multi_step == 4
     assert cfg.queue_limit == 77
-    assert llm.engine.cfg.admission_subwave == 0   # untouched
     assert max(llm.serving.batcher._levels) <= 4
     # restore for the other tests in this module
     llm.apply_serving_config({"max_wait_ms": 5.0, "max_horizon": 64,
