@@ -375,6 +375,71 @@ def _use_fused_decode(
     ) == "pallas"
 
 
+def ragged_kv_path(
+    cfg: ModelConfig, padded_ctx: int, quantized_kv: bool, pallas: bool = True
+) -> str:
+    """Which KV path a multi-token chunk's graph is built with — a
+    trace-time fact, from what dispatch can see and no knob:
+
+    - ``in_place``: the page write (``dgi_paged_write``) and the ragged
+      kernel address the stacked pools by layer index, so a round moves
+      its tokens. Taken where the ragged kernel is taken
+      (``ops.attention.resolve_impl``: a TPU backend, ``head_dim % 128 ==
+      0``, a padded context of at least 512) and the caller allows kernels
+      (``pallas``: no mesh).
+    - ``layer_copy``: the layer is sliced out of the stack, scattered into
+      and written back — pool-sized copies in every layer. Everything
+      else: a mesh, the CPU, head widths the kernels refuse, int8 pools
+      (their scale pools have no in-place write; no worker can serve
+      them at block 16)."""
+    from distributed_gpu_inference_tpu.ops.attention import resolve_impl
+
+    ragged = resolve_impl(
+        q_seq=2, head_dim=cfg.head_dim, padded_ctx=padded_ctx
+    ) == "ragged"
+    return "in_place" if pallas and ragged and not quantized_kv \
+        else "layer_copy"
+
+
+def _in_place_kv(
+    cfg: ModelConfig,
+    kv: KVPools,
+    block_tables: jax.Array,
+    positions: jax.Array,       # [B, S] the rectangle's positions (-1 = pad)
+    kv_lens: jax.Array,
+    block_size: int,
+    token_index: Optional[jax.Array] = None,
+    num_tokens: Optional[int] = None,
+):
+    """``_layer_step``'s ``in_place`` for a multi-token chunk on the kernel
+    path, or None where ``ragged_kv_path`` says ``layer_copy``: the page
+    write plan (the same for every layer, so built here, outside the
+    scan) and attention over the stacked pools."""
+    if positions.shape[1] == 1 or ragged_kv_path(
+        cfg, block_tables.shape[1] * block_size, "k_scale" in kv
+    ) != "in_place":
+        return None
+    from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+        page_write_plan, ragged_paged_attention,
+    )
+
+    pool = kv["k"]
+    plan = page_write_plan(
+        block_tables, positions, block_size,
+        page_bytes=pool.shape[2] * pool.shape[3] * pool.shape[4]
+        * pool.dtype.itemsize,
+        token_index=token_index, num_tokens=num_tokens,
+    )
+
+    def attn_stacked(q, k_pool, v_pool, layer_idx):
+        return ragged_paged_attention(
+            q, k_pool, v_pool, block_tables, positions, kv_lens, block_size,
+            window=cfg.sliding_window, layer_idx=layer_idx,
+        )
+
+    return plan, attn_stacked
+
+
 class ChunkOutput(NamedTuple):
     hidden: jax.Array       # [B, S, H] final-layer hidden states (pre-norm)
     kv: KVPools             # updated pools
@@ -434,6 +499,10 @@ def _layer_step(
     moe_live: Optional[jax.Array] = None,  # hidden's tokens the experts
                                   # route (None: all of them)
     emit_routing: bool = False,   # scan-emit the experts of every token
+    in_place=None,                # (page write plan, (q, k_pool, v_pool,
+                                  # layer_idx) → attn): a multi-token chunk
+                                  # writes and reads the STACKED pools
+                                  # (``_in_place_kv``)
 ) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array],
            Tuple[Optional[jax.Array], Optional[Dict[str, jax.Array]],
                  Optional[jax.Array]]]:
@@ -441,12 +510,19 @@ def _layer_step(
     and the speculative tree-verify path (they differ only in the attention
     mask and in where KV rows are written).
 
-    ``fused_decode`` routes the whole KV path through the Pallas fused
-    write+attention kernel on the STACKED pools (ops/paged_attention_pallas).
-    The alternative — XLA scatter into a dynamically-indexed layer slice —
-    forced two pool-sized HBM copies per decode step at serving pool sizes
-    (scatter-preferred vs kernel-required layout, plus custom-call operand
-    materialization; round-2 profiling).
+    The KV path has three forms, chosen at trace time by shape and backend.
+    ``fused_decode`` (S = 1 on the kernel path) routes it through the Pallas
+    fused write+attention kernel on the STACKED pools
+    (ops/paged_attention_pallas). ``in_place`` (S > 1 on the kernel path:
+    ``ragged_kv_path``) does the same for a multi-token chunk with two
+    kernels: the page write goes into the stacked pools by layer index
+    (``dgi_paged_write``) and the ragged kernel reads them there. The third
+    form — XLA scatter into a dynamically-indexed layer slice, then the
+    write-back — is what a mesh, the CPU and int8 pools take: on a TPU it
+    costs pool-sized HBM copies in every layer (scatter-preferred vs
+    kernel-required layout, plus custom-call operand materialization;
+    round-2 profiling for decode, PERF.md section 5 for the ragged round:
+    ~21 ms of a 34 ms Mistral round).
 
     ``stacked`` holds quantized matmul weights with their layer axis intact
     (``split_stacked_quant``): projections then run through the Pallas
@@ -465,7 +541,9 @@ def _layer_step(
     on one axis. q/k/v are gathered into the ``[B, S]`` rectangle (empty
     positions zero, their ``write_positions`` -1) for the page write and
     attention exactly as an unpacked chunk has them, and the attention
-    output is gathered back; everything else runs over ``Tp`` rows."""
+    output is gathered back; everything else runs over ``Tp`` rows. With
+    ``in_place`` only q takes the rectangle: the page write gathers K and V
+    from the packed axis straight into page-shaped updates."""
     hidden, k_ent, v_ent, layer_idx = carry
     # int8-KV pools travel as (pool, scale_pool) tuples through the scan
     # carry; bf16 pools stay bare arrays (static structure, zero overhead)
@@ -505,10 +583,14 @@ def _layer_step(
         k = apply_rope(k, cos, sin)
         if unpack is not None:
             to_rect, tok_row, tok_col = unpack
-            q, k, v = (
-                jnp.take(t[0], to_rect, axis=0, mode="fill", fill_value=0)
-                for t in (q, k, v)
-            )                               # [B, S, heads, D] from here on
+
+            def rectangle(t):               # → [B, S, heads, D]
+                return jnp.take(t[0], to_rect, axis=0, mode="fill",
+                                fill_value=0)
+
+            q = rectangle(q)
+            if in_place is None:    # the in-place write reads the packed axis
+                k, v = rectangle(k), rectangle(v)
 
         if fused_decode:
             from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
@@ -532,6 +614,17 @@ def _layer_step(
                     write_positions, kv_lens, block_size,
                     window=cfg.sliding_window,
                 )
+        elif in_place is not None:
+            from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+                write_kv_pages_in_place,
+            )
+
+            plan, attn_stacked = in_place
+            k_pool, v_pool = write_kv_pages_in_place(
+                k.reshape(-1, nkv, d), v.reshape(-1, nkv, d),
+                k_pool, v_pool, layer_idx, plan,
+            )
+            attn = attn_stacked(q, k_pool, v_pool, layer_idx)
         else:
             layer_k = lax.dynamic_index_in_dim(k_pool, layer_idx, 0, keepdims=False)
             layer_v = lax.dynamic_index_in_dim(v_pool, layer_idx, 0, keepdims=False)
@@ -671,7 +764,7 @@ def forward_chunk(
     depend on which other rows share the call. ``hidden`` comes back
     ``[1, Tp, H]``.
     """
-    unpack = None
+    unpack = to_rect = tp = None
     if packing is not None:
         tp = token_ids.shape[0]
         rect = (block_tables.shape[0], packing.width)
@@ -687,6 +780,12 @@ def forward_chunk(
             positions, mode="drop")
     else:
         rope_positions = positions
+    in_place = None
+    if pallas and dense_attn_fn is None and attn_override is None:
+        in_place = _in_place_kv(
+            cfg, kv, block_tables, positions, kv_lens, block_size,
+            token_index=to_rect, num_tokens=tp,
+        )
     b, s = token_ids.shape
     hidden = embed_tokens(params, token_ids, cfg)
 
@@ -737,6 +836,7 @@ def forward_chunk(
         unpack=unpack,
         moe_live=rope_positions >= 0,
         emit_routing=collect_routing,
+        in_place=in_place,
     )
     k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
     v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
@@ -910,6 +1010,9 @@ def forward_hidden_chunk(
         ),
         kv_lens=kv_lens,
         stacked=stacked,
+        in_place=_in_place_kv(
+            cfg, kv, block_tables, positions, kv_lens, block_size
+        ),
     )
     (hidden, k_pool, v_pool, _), _ = lax.scan(
         lambda c, lp: step(c, lp),

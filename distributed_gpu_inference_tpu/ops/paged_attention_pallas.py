@@ -42,12 +42,17 @@ Correctness contract is identical to ``paged_attention_xla`` over the
 written pool (same masking semantics, including window and padded-query
 handling); parametrized parity tests drive both through the same cases
 (CPU: interpret mode).
+
+A multi-token chunk (S > 1: the ragged round) has the same KV path in two
+kernels, further down: ``write_kv_pages_in_place`` (``dgi_paged_write``)
+puts the chunk's rows into the stacked pools by layer index, and
+``ragged_paged_attention(..., layer_idx=)`` reads them there.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +66,7 @@ _NEG_INF = -1e30
 # show as the enclosing ``closed_call.<n>``
 DECODE_KERNEL_NAME = "dgi_paged_decode"
 RAGGED_KERNEL_NAME = "dgi_ragged_attention"
+WRITE_KERNEL_NAME = "dgi_paged_write"
 # VMEM budget for the four KV staging buffers (2 pools x 2 slots); the rest
 # of VMEM stays free for q/out blocks and compute temporaries.
 _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
@@ -685,6 +691,266 @@ def quantize_kv_pool(pool: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
 
 # --------------------------------------------------------------------------
+# In-place page write: a multi-token chunk's K/V rows go into the STACKED
+# pools where they lie, as the decode kernel's fused write does for one
+# token a row. The XLA scatter it replaces needs one layer's pool as its
+# operand: sliced out of the stack, copied into the layout the scatter
+# prefers and back, written back into the stack — about five passes over
+# the whole pool in every multi-token round, whatever the round held
+# (PERF.md section 5).
+# --------------------------------------------------------------------------
+
+# VMEM the write kernel's page buffers may take: the K and V update blocks
+# (double-buffered by the pipeline) and the two staging buffers, six
+# page-tiles in all. A row's span that needs more is split into tiles.
+_WRITE_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+
+
+class PageWritePlan(NamedTuple):
+    """Where a chunk's rows land in the paged pool, page by page. The same
+    for every layer: built once a forward pass (:func:`page_write_plan`),
+    outside the layer scan. A *cell* is one page a row of the chunk can
+    touch: ``cells`` of them a row (the pages a span of S tokens covers at
+    any offset, rounded up to whole tiles), row-major."""
+
+    page: jax.Array   # [B * cells] int32 physical page of each cell
+    kind: jax.Array   # [B * cells] int32 0 = nothing written to the page,
+                      # 1 = some of its slots (read-modify-write), 2 = all
+    slots: jax.Array  # [B * cells * words] int32 bit s of word w: slot
+                      # 32 w + s of the page is written
+    src: jax.Array    # [B * cells * Bk] int32 the chunk token every slot
+                      # takes, on the caller's flat token axis (its length
+                      # = none)
+    tile: int         # cells a grid step handles (static)
+
+
+def page_write_plan(
+    block_tables: jax.Array,     # [B, M] int32
+    write_positions: jax.Array,  # [B, S] int32 (-1 = nothing to write)
+    block_size: int,
+    page_bytes: int,             # one page of one pool: Hkv * Bk * D * size
+    token_index: Optional[jax.Array] = None,  # [B, S] where each position
+                                 # of the rectangle lies on the caller's
+                                 # flat token axis (a packed round's
+                                 # ``to_rect``); None: row-major, b * S + s
+    num_tokens: Optional[int] = None,         # length of that axis
+) -> PageWritePlan:
+    """The chunk's own view of the page write, in the semantics of the
+    scatter it replaces (``models/llama._page_scatter_indices``): token
+    ``(b, s)`` with ``write_positions[b, s] >= 0`` lands in slot ``pos %
+    Bk`` of page ``block_tables[b, pos // Bk]``, a negative position writes
+    nothing. One restriction: a row's written positions lie within S of
+    each other (every caller writes a span; positions further than the
+    pages counted from the row's first are dropped)."""
+    b, s = write_positions.shape
+    m = block_tables.shape[1]
+    if block_size > 32 and block_size % 32:
+        raise ValueError(f"block_size {block_size}: want <= 32 or whole words")
+    if token_index is None:
+        token_index = jnp.arange(b * s, dtype=jnp.int32).reshape(b, s)
+        num_tokens = b * s
+    # pages a span of s tokens touches when it starts at a page's last slot
+    need = (s + 2 * block_size - 2) // block_size
+    tiles = -(-need // max(1, _WRITE_VMEM_BUDGET_BYTES // (6 * page_bytes)))
+    tile = -(-need // tiles)
+    cells = tiles * tile
+    valid = write_positions >= 0
+    # the row's first written page (a row with nothing to write: far past
+    # any table, so none of its cells is live)
+    first = jnp.min(
+        jnp.where(valid, write_positions, jnp.int32(2**30)), axis=1,
+        keepdims=True,
+    ) // block_size                                            # [B, 1]
+    # slot of the row's span each token takes; pads go out of range
+    rel = jnp.where(
+        valid, write_positions - first * block_size, cells * block_size
+    )
+    src = jnp.full((b, cells * block_size), num_tokens, jnp.int32).at[
+        jnp.arange(b, dtype=jnp.int32)[:, None], rel
+    ].set(token_index.astype(jnp.int32), mode="drop")
+    logical = first + jnp.arange(cells, dtype=jnp.int32)       # [B, cells]
+    # a page past the row's table is never written (the scatter drops it)
+    live = (src < num_tokens).reshape(b, cells, block_size) \
+        & (logical < m)[:, :, None]
+    count = jnp.sum(live, axis=2, dtype=jnp.int32)
+    kind = (count > 0).astype(jnp.int32) + (count == block_size)
+    width = min(block_size, 32)                 # slots a mask word holds
+    slots = jnp.sum(
+        live.reshape(b, -1, width).astype(jnp.int32)
+        << jnp.arange(width, dtype=jnp.int32), axis=2, dtype=jnp.int32,
+    )                       # bit 31 wraps into the sign: two's complement
+    page = jnp.take_along_axis(
+        block_tables.astype(jnp.int32), jnp.minimum(logical, m - 1), axis=1
+    )
+    return PageWritePlan(
+        page=page.reshape(-1), kind=kind.reshape(-1),
+        slots=slots.reshape(-1), src=src.reshape(-1), tile=tile,
+    )
+
+
+def _page_write_kernel(
+    # scalar prefetch (SMEM)
+    page_ref,      # [C] int32 physical page of each cell
+    kind_ref,      # [C] int32 0 untouched / 1 partly written / 2 whole
+    slots_ref,     # [C * words] int32 written-slot bit masks
+    layer_ref,     # [1] int32 layer index into the stacked pools
+    # blocked operands
+    newk_ref,      # [tile, Hkv, Bk, D] this step's cells' new rows, page-
+    newv_ref,      # shaped (VMEM; slots nothing is written to hold zeros)
+    _k_in,         # [L, N, Hkv, Bk, D] stacked pools (HBM), aliased to the
+    _v_in,         # outputs: every access goes through ko_hbm / vo_hbm
+    ko_hbm,
+    vo_hbm,
+    stage_k,       # [tile, Hkv, Bk, D] VMEM
+    stage_v,
+    sems,          # DMA [2, tile]
+    *,
+    tile: int,
+    words: int,
+):
+    """One grid step writes ``tile`` cells. The HBM pool is (8, 128)-tiled
+    on its last two dims, so a token slot is not DMA-addressable: a page is
+    the unit. A page written whole goes out as its update block; a page
+    written in part is staged into VMEM first, the written slots are
+    blended in with a vector select (no dynamic sublane store) and the
+    page goes back whole, so its other slots keep their bytes. Cells of
+    one call never share a page (a sequence owns its block chain, and a
+    row's cells are distinct entries of its table), so whole-page
+    write-back cannot clobber a sibling's write."""
+    base = pl.program_id(0) * tile
+    layer = layer_ref[0]
+    _, hkv, bk, d = stage_k.shape
+
+    def copies(j, read):
+        """Cell j's two page DMAs, K and V: HBM → staging, or back."""
+        page = page_ref[base + j]
+        out = []
+        for c, (hbm, stage) in enumerate(((ko_hbm, stage_k),
+                                          (vo_hbm, stage_v))):
+            src, dst = hbm.at[layer, page], stage.at[j]
+            if not read:
+                src, dst = dst, src
+            out.append(pltpu.make_async_copy(src, dst, sems.at[c, j]))
+        return out
+
+    def start_reads(j, carry):
+        @pl.when(kind_ref[base + j] == 1)
+        def _():
+            for c in copies(j, read=True):
+                c.start()
+
+        return carry
+
+    def blend(j, carry):
+        kind = kind_ref[base + j]
+
+        @pl.when(kind == 1)
+        def _():
+            for c in copies(j, read=True):
+                c.wait()
+
+        @pl.when(kind != 0)
+        def _():
+            slot = lax.broadcasted_iota(jnp.int32, (hkv, bk, d), 1)
+            sel = None
+            for w in range(words):      # static: one word a 32 slots
+                mask = jnp.right_shift(
+                    slots_ref[(base + j) * words + w], (slot - 32 * w) & 31
+                ) & 1
+                hit = mask == 1
+                if words > 1:
+                    hit &= (slot >= 32 * w) & (slot < 32 * (w + 1))
+                sel = hit if sel is None else sel | hit
+            # a whole page was not staged: every slot selects the new row
+            stage_k[j] = jnp.where(sel, newk_ref[j], stage_k[j])
+            stage_v[j] = jnp.where(sel, newv_ref[j], stage_v[j])
+            for c in copies(j, read=False):
+                c.start()
+
+        return carry
+
+    def wait_writes(j, carry):
+        @pl.when(kind_ref[base + j] != 0)
+        def _():
+            for c in copies(j, read=False):
+                c.wait()
+
+        return carry
+
+    # three passes, each over every cell of the tile, so the page DMAs of a
+    # phase are in flight together and their latency is paid once a phase
+    lax.fori_loop(0, tile, start_reads, 0)
+    lax.fori_loop(0, tile, blend, 0)
+    lax.fori_loop(0, tile, wait_writes, 0)
+
+
+def write_kv_pages_in_place(
+    new_k: jax.Array,         # [T, Hkv, D] the chunk's K rows, one flat axis
+    new_v: jax.Array,
+    k_pool: jax.Array,        # [L, N, Hkv, Bk, D] stacked pools
+    v_pool: jax.Array,
+    layer_idx: jax.Array,     # scalar int32
+    plan: PageWritePlan,
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """Write a chunk's K/V rows into layer ``layer_idx`` of the stacked
+    pools, in place (the pools are aliased to the outputs) → (k_pool,
+    v_pool). What lands where is ``plan``'s; the bytes after the call are
+    what ``_write_kv_pages`` into the sliced layer and the write-back
+    leave, other layers, other pages and the unwritten slots of written
+    pages included. XLA gathers the tokens into page-shaped updates (a few
+    hundred KB, tokens only); the kernel moves whole pages by DMA."""
+    _, _, hkv, bk, d = k_pool.shape
+    if d % 128 != 0 and not interpret:
+        raise ValueError(f"in-place page write needs head_dim % 128 == 0, got {d}")
+    cells = plan.page.shape[0]
+    tile = plan.tile
+    words = plan.slots.shape[0] // cells
+
+    def pages(x, pool):
+        g = jnp.take(x, plan.src, axis=0, mode="fill", fill_value=0)
+        return g.reshape(cells, bk, hkv, d).transpose(0, 2, 1, 3) \
+            .astype(pool.dtype)
+
+    block = pl.BlockSpec(
+        (tile, hkv, bk, d), lambda i, *_refs: (i, 0, 0, 0),
+        memory_space=pltpu.VMEM,
+    )
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(cells // tile,),
+        in_specs=[block, block, hbm, hbm],
+        out_specs=[hbm, hbm],
+        scratch_shapes=[
+            pltpu.VMEM((tile, hkv, bk, d), k_pool.dtype),
+            pltpu.VMEM((tile, hkv, bk, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, tile)),
+        ],
+    )
+    # operand order: 4 scalar-prefetch args, the two update arrays, then
+    # k_pool (idx 6), v_pool (idx 7) → aliased to outputs 0, 1
+    return tuple(pl.pallas_call(
+        functools.partial(_page_write_kernel, tile=tile, words=words),
+        out_shape=[
+            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
+        ],
+        grid_spec=grid_spec,
+        input_output_aliases={6: 0, 7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name=WRITE_KERNEL_NAME,
+    )(
+        plan.page, plan.kind, plan.slots,
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
+        pages(new_k, k_pool), pages(new_v, v_pool), k_pool, v_pool,
+    ))
+
+
+# --------------------------------------------------------------------------
 # Ragged paged attention: one kernel invocation over a flattened row batch
 # where decode rows (q_len = 1), speculative verify rows (q_len = 2..K+1)
 # and prefill chunk rows (q_len up to the chunk width) coexist — the
@@ -734,14 +1000,15 @@ def _ragged_kernel(
     lens_ref,      # [B] int32 effective kv length per sequence
     qmax_ref,      # [R] int32 max valid query position (-1 = inactive row)
     qmin_ref,      # [R] int32 min valid query position (0 when inactive)
+    layer_ref,     # [1] int32 layer index into the stacked pools
     bidx_ref,      # [1] int32 current double-buffer slot
     init_ref,      # [1] int32 1 until the first live chunk issues its DMA
     # blocked operands
     q_ref,         # [1, Hkv, qpk*T, D] — this row's query tile, GQA-grouped
     pos_ref,       # [1, qpk*T, 1] int32 per-query positions (-1 = pad),
                    # tiled over the GQA slots in q_ref's row order
-    k_hbm,         # [N, Hkv, Bk, D] single-layer pool (HBM)
-    v_hbm,
+    k_hbm,         # [L, N, Hkv, Bk, D] full stacked pool (HBM, read in
+    v_hbm,         # place: a page DMA is k_hbm.at[layer, page])
     *rest,         # [ks_hbm, vs_hbm,] out_ref, kbuf, vbuf, [ksbuf, vsbuf,]
                    # sems, [ssems,] m_scr, l_scr, acc_scr
     rows: int,
@@ -765,9 +1032,10 @@ def _ragged_kernel(
     i = pl.program_id(1)
     gp = pages_per_group
     gsz = gp * block_size
-    hkv = k_hbm.shape[1]
+    hkv = k_hbm.shape[2]
     d = q_ref.shape[3]
     qpk = q_ref.shape[2] // q_tile
+    layer = layer_ref[0]
     max_groups = pl.num_programs(1)
 
     def num_groups(s_):
@@ -788,41 +1056,45 @@ def _ragged_kernel(
     start_r = start_group(r)
     live = (i >= start_r) & (i < ng_r)
 
+    def group_copies(s_, j, slot, p):
+        """The page DMAs of page p of group j of row s_ into buffer slot."""
+        idx = jnp.minimum(j * gp + p, max_pages - 1)
+        page = bt_ref[jnp.clip(s_, 0, rows - 1) // q_tiles, idx]
+        out = [
+            pltpu.make_async_copy(
+                k_hbm.at[layer, page], kbuf.at[slot, p], sems.at[0, slot, p]),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, page], vbuf.at[slot, p], sems.at[1, slot, p]),
+        ]
+        if quantized:
+            out += [
+                pltpu.make_async_copy(
+                    ks_hbm.at[layer, page], ksbuf.at[slot, p],
+                    ssems.at[0, slot, p]),
+                pltpu.make_async_copy(
+                    vs_hbm.at[layer, page], vsbuf.at[slot, p],
+                    ssems.at[1, slot, p]),
+            ]
+        return out
+
+    # G paired page DMAs a group, unrolled where the kernel is lowered: the
+    # body is traced once and not G times (a ragged graph's trace was
+    # mostly these loops: PERF.md PR 28)
     def start_dma(s_, j, slot):
-        for p in range(gp):  # static unroll: G paired page DMAs
-            idx = jnp.minimum(j * gp + p, max_pages - 1)
-            page = bt_ref[jnp.clip(s_, 0, rows - 1) // q_tiles, idx]
-            pltpu.make_async_copy(
-                k_hbm.at[page], kbuf.at[slot, p], sems.at[0, slot, p]
-            ).start()
-            pltpu.make_async_copy(
-                v_hbm.at[page], vbuf.at[slot, p], sems.at[1, slot, p]
-            ).start()
-            if quantized:
-                pltpu.make_async_copy(
-                    ks_hbm.at[page], ksbuf.at[slot, p], ssems.at[0, slot, p]
-                ).start()
-                pltpu.make_async_copy(
-                    vs_hbm.at[page], vsbuf.at[slot, p], ssems.at[1, slot, p]
-                ).start()
+        def body(p, carry):
+            for c in group_copies(s_, j, slot, p):
+                c.start()
+            return carry
+
+        lax.fori_loop(0, gp, body, 0, unroll=True)
 
     def wait_dma(s_, j, slot):
-        for p in range(gp):
-            idx = jnp.minimum(j * gp + p, max_pages - 1)
-            page = bt_ref[jnp.clip(s_, 0, rows - 1) // q_tiles, idx]
-            pltpu.make_async_copy(
-                k_hbm.at[page], kbuf.at[slot, p], sems.at[0, slot, p]
-            ).wait()
-            pltpu.make_async_copy(
-                v_hbm.at[page], vbuf.at[slot, p], sems.at[1, slot, p]
-            ).wait()
-            if quantized:
-                pltpu.make_async_copy(
-                    ks_hbm.at[page], ksbuf.at[slot, p], ssems.at[0, slot, p]
-                ).wait()
-                pltpu.make_async_copy(
-                    vs_hbm.at[page], vsbuf.at[slot, p], ssems.at[1, slot, p]
-                ).wait()
+        def body(p, carry):
+            for c in group_copies(s_, j, slot, p):
+                c.wait()
+            return carry
+
+        lax.fori_loop(0, gp, body, 0, unroll=True)
 
     def next_chunk(s_, j):
         """Grid-order successor of live chunk (s_, j) — same walk as the
@@ -938,8 +1210,9 @@ def _ragged_kernel(
 )
 def ragged_paged_attention(
     q: jax.Array,             # [B, S, Nh, D] — per-row spans padded to S
-    k_pool: jax.Array,        # [N, Hkv, Bk, D] (head-major pages, 1 layer)
-    v_pool: jax.Array,
+    k_pool: jax.Array,        # [N, Hkv, Bk, D] one layer's pool (head-major
+    v_pool: jax.Array,        # pages), or with ``layer_idx`` the stacked
+                              # [L, N, Hkv, Bk, D] pool, read in place
     block_tables: jax.Array,  # [B, M] int32
     positions: jax.Array,     # [B, S] int32 (-1 = pad)
     kv_lens: jax.Array,       # [B] int32 effective context per row
@@ -947,7 +1220,9 @@ def ragged_paged_attention(
     window: Optional[int] = None,
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,   # [N, Bk, D] bf16 lane-replicated
-    v_scale: Optional[jax.Array] = None,
+    v_scale: Optional[jax.Array] = None,   # ([L, N, Bk, D] with layer_idx)
+    layer_idx: Optional[jax.Array] = None,  # scalar int32: the pools are
+                              # the stacked ones and this is the layer
 ) -> jax.Array:
     """Ragged paged attention: ONE kernel invocation over a flattened token
     batch in which each row carries its own (block table, query-span
@@ -962,14 +1237,28 @@ def ragged_paged_attention(
     Rows are split host-side into independent query tiles (softmax state
     is per query) sized so the f32 score tile stays inside VMEM; pages
     re-stage once per TILE — this replaces the old multi-query path, which
-    re-staged pages once per QUERY and therefore capped q_len at 8."""
+    re-staged pages once per QUERY and therefore capped q_len at 8.
+
+    ``layer_idx``: the pools are the model's stacked ``[L, N, Hkv, Bk, D]``
+    pools and the kernel reads layer ``layer_idx`` of them where they lie
+    (one more scalar-prefetch operand; a page DMA is ``pool.at[layer,
+    page]``). A caller inside the layer scan must pass them so: a
+    ``dynamic_slice`` of the layer is a custom-call operand XLA has to
+    materialise, pool bytes / L a layer a pool. Without it the pools are
+    one layer's, as the bare-read callers hold them."""
     if (k_scale is None) != (v_scale is None):
         raise ValueError(
             "int8-KV pools need BOTH k_scale and v_scale (or neither)"
         )
     quantized = k_scale is not None
+    if layer_idx is None:
+        # a leading axis of one is a relabel, not a copy
+        layer_idx = 0
+        k_pool, v_pool = k_pool[None], v_pool[None]
+        if quantized:
+            k_scale, v_scale = k_scale[None], v_scale[None]
     b, s, nh, d = q.shape
-    n, hkv, bk, _ = k_pool.shape
+    _, n, hkv, bk, _ = k_pool.shape
     if bk != block_size:
         raise ValueError(f"pool block dim {bk} != block_size {block_size}")
     if d % 128 != 0 and not interpret:
@@ -1051,7 +1340,7 @@ def ragged_paged_attention(
         pltpu.VMEM((hkv, qpk * t, d), jnp.float32),              # acc
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(rows, max_groups),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1078,6 +1367,7 @@ def ragged_paged_attention(
     operands = [
         block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
         qmax_r, qmin_r,
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
         jnp.zeros((1,), jnp.int32),   # buffer_index
         jnp.ones((1,), jnp.int32),    # init_flag
         q_r, pos_q, k_pool, v_pool,

@@ -581,6 +581,15 @@ class TPUEngine:
             "ragged_positions_dispatched": 0, "ragged_positions_live": 0,
             "round_build_s": 0.0, "round_dispatch_s": 0.0,
             "round_readback_s": 0.0, "round_commit_s": 0.0,
+            # which KV path the multi-token graphs were built with
+            # (in_place / layer_copy): a trace-time fact, from the
+            # predicate forward_chunk itself dispatches on
+            "ragged_kv_path": llama.ragged_kv_path(
+                self.model_cfg,
+                self.cfg.max_blocks_per_seq * self.cfg.block_size,
+                quantized_kv=self.kv_dtype == jnp.int8,
+                pallas=self.mesh is None,
+            ),
         }
         if self.model_cfg.num_experts:
             # what the routed expert layers did (models/llama.py

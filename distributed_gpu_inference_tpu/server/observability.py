@@ -322,6 +322,14 @@ class Metrics:
             "worker_compile_seconds_total",
             "Seconds of the worker's XLA compile requests", ["worker"],
             registry=r)
+        # an info gauge (value 1, the fact in the label): which KV path
+        # the worker's multi-token round graphs were built with
+        self.worker_ragged_kv_path = Gauge(
+            "worker_ragged_kv_path",
+            "1 for the KV path the worker's multi-token rounds take: "
+            "in_place (pages written into and read from the stacked pool "
+            "by layer index) or layer_copy (the layer sliced out, scattered "
+            "into and written back)", ["worker", "path"], registry=r)
         # the routed expert layer of a sparse model (engine.stats moe_*):
         # active_experts / (layer_calls x experts) is the share of the
         # expert weights a round reads, assignments / rows_dispatched what
@@ -728,6 +736,11 @@ class MetricsCollector:
                 gauge.labels(worker).set(float(stats.get(key) or 0.0))
             except (TypeError, ValueError):
                 continue
+        path = stats.get("ragged_kv_path")
+        if isinstance(path, str):
+            for name in ("in_place", "layer_copy"):
+                self.metrics.worker_ragged_kv_path.labels(worker, name).set(
+                    1.0 if name == path else 0.0)
         prev = self._batcher_prev.setdefault(worker, {})
         for key, metric in (
             ("decode_rounds", self.metrics.batcher_decode_rounds),

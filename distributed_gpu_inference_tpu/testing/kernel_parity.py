@@ -7,7 +7,8 @@ what the Mosaic-compiled kernel computes: that needs a TPU. This module
 runs the same row mixes at model geometry (head counts, head_dim, projection
 shapes from ``models/configs.py``), in the pool dtypes serving stores,
 against ``paged_attention_xla`` / the scatter write / the convert-on-read
-matmul:
+matmul (and a chunk's in-place KV path against the scatter into the sliced
+layer):
 
 - compiled, on a TPU backend (``chip_smoke.py`` runs the served subset on
   every run; ``python -m distributed_gpu_inference_tpu.testing.kernel_parity``
@@ -37,9 +38,11 @@ from distributed_gpu_inference_tpu.models.configs import (
 from distributed_gpu_inference_tpu.models.llama import _write_kv_pages
 from distributed_gpu_inference_tpu.ops.attention import paged_attention_xla
 from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+    page_write_plan,
     paged_decode_attention_fused,
     quantize_kv_pool,
     ragged_paged_attention,
+    write_kv_pages_in_place,
 )
 from distributed_gpu_inference_tpu.ops.qmm_pallas import qmm_stacked_pallas
 from distributed_gpu_inference_tpu.ops.quantization import matmul
@@ -170,6 +173,56 @@ def check_fused_decode(geo: ModelConfig, lens: Sequence[int], block: int,
     return _max_err(got, want) if bool(wrote) else float("inf")
 
 
+def check_in_place_chunk(geo: ModelConfig, rows: Rows, block: int, ctx: int,
+                         window: Optional[int], interpret: bool) -> float:
+    """A multi-token chunk's KV path on the stacked pools (the page write
+    by layer index, the ragged kernel reading the layer where it lies)
+    against the scatter into the sliced layer and the same kernel on that
+    slice: the pools must come out equal byte for byte — the written
+    layer, the other layers, block 0 — and so must attention."""
+    rng = np.random.default_rng(3)
+    layers, layer = 3, 1
+    b, m = len(rows), ctx // block
+    s = max(max(span for span, _ in rows), 2)
+    hkv, d = geo.num_kv_heads, geo.head_dim
+    pool_shape = (layers, 1 + b * m, hkv, block, d)
+    k_pool, v_pool = _normal(rng, pool_shape), _normal(rng, pool_shape)
+    q = _normal(rng, (b, s, geo.num_heads, d))
+    new_k, new_v = _normal(rng, (b, s, hkv, d)), _normal(rng, (b, s, hkv, d))
+    positions = np.full((b, s), -1, np.int32)
+    lens = np.zeros((b,), np.int32)
+    for i, (span, kv_len) in enumerate(rows):
+        lens[i] = kv_len
+        if span:
+            positions[i, :span] = np.arange(kv_len - span, kv_len)
+
+    @jax.jit
+    def both(q, new_k, new_v, k_pool, v_pool, tables, positions, lens):
+        ref_k = k_pool.at[layer].set(_write_kv_pages(
+            k_pool[layer], new_k, tables, positions, block))
+        ref_v = v_pool.at[layer].set(_write_kv_pages(
+            v_pool[layer], new_v, tables, positions, block))
+        want = ragged_paged_attention(
+            q, ref_k[layer], ref_v[layer], tables, positions, lens, block,
+            window=window, interpret=interpret,
+        )
+        plan = page_write_plan(tables, positions, block,
+                               hkv * block * d * k_pool.dtype.itemsize)
+        k2, v2 = write_kv_pages_in_place(
+            new_k.reshape(-1, hkv, d), new_v.reshape(-1, hkv, d),
+            k_pool, v_pool, jnp.int32(layer), plan, interpret=interpret,
+        )
+        got = ragged_paged_attention(
+            q, k2, v2, tables, positions, lens, block, window=window,
+            interpret=interpret, layer_idx=jnp.int32(layer),
+        )
+        return want, got, jnp.all(k2 == ref_k) & jnp.all(v2 == ref_v)
+
+    want, got, wrote = both(q, new_k, new_v, k_pool, v_pool, _tables(b, m),
+                            jnp.asarray(positions), jnp.asarray(lens))
+    return _max_err(got, want) if bool(wrote) else float("inf")
+
+
 def check_qmm(k: int, n: int, m: int, interpret: bool) -> float:
     rng = np.random.default_rng(2)
     x = _normal(rng, (m, k))
@@ -219,6 +272,10 @@ def run(models: Sequence[str], blocks: Sequence[int],
                         case(f"ragged/{tag}/{mix}/window={window}",
                              check_ragged, geo, rows, block, ctx,
                              pool == "int8", window, interpret)
+            for mix, rows in ragged_row_mixes(ctx, chunk).items():
+                case(f"in_place_chunk/{model}/block{block}/bf16/{mix}",
+                     check_in_place_chunk, geo, rows, block, ctx,
+                     geo.sliding_window, interpret)
             lens = [33, 5, ctx, 1, 0, ctx // 2, 17, ctx - 1]
             for window in (None, 24):
                 case(f"fused_decode/{model}/block{block}/bf16/"

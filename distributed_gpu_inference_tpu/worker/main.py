@@ -486,6 +486,8 @@ class Worker:
             es = core.get_stats() if core is not None else {}
             for k in ("compiles", "compile_s"):
                 out[k] = max(out.get(k, 0), round(es.get(k, 0), 3))
+            if es.get("ragged_kv_path"):
+                out["ragged_kv_path"] = es["ragged_kv_path"]
             for k, src in (("between_rounds_s", s), ("admit_s", s),
                            ("deliver_s", s), ("round_build_s", es),
                            ("round_dispatch_s", es),

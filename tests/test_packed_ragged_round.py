@@ -268,3 +268,111 @@ def test_lowered_graphs_cover_randomised_rounds(seed):
         shapes.add(eng._ragged_shape(max(live, 1))[0])
     assert len(shapes) > 1                  # more than one rung was run
     assert eng.get_stats()["compiles"] == before
+
+
+# --------------------------------------------------------------------- #
+# the in-place KV path (PR 28): write and read the stacked pools
+# --------------------------------------------------------------------- #
+
+def _packed_round(cfg, params, kv, tables, pieces, width):
+    """One packed ``forward_chunk``: ``pieces`` = (row, first position,
+    tokens). → (updated pools, the logits of every piece's last token)."""
+    b = tables.shape[0]
+    tok = np.concatenate([np.asarray(t) for _, _, t in pieces])
+    tp = 8 * -(-len(tok) // 8)
+    pad = tp - len(tok)
+    row = np.concatenate([np.full(len(t), r) for r, _, t in pieces])
+    col = np.concatenate([np.arange(len(t)) for _, _, t in pieces])
+    pos = np.concatenate([p + np.arange(len(t)) for _, p, t in pieces])
+    last = np.zeros(b, np.int32)
+    lens = np.zeros(b, np.int32)
+    for r, p, t in pieces:
+        last[r] = int(np.flatnonzero(row == r)[-1])
+        lens[r] = p + len(t)
+    i32 = lambda a, fill: jnp.asarray(
+        np.concatenate([a, np.full(pad, fill)]), jnp.int32)
+    out = llama.forward_chunk(
+        cfg, params, i32(tok, 0), i32(pos, -1), kv, tables,
+        jnp.asarray(lens), block_size=16,
+        packing=llama.Packing(i32(row, b), i32(col, 0), jnp.asarray(last),
+                              width),
+    )
+    return out.kv, np.asarray(out.logits[[r for r, _, _ in pieces], 0])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_in_place_kv_path_equals_layer_copy_over_two_rounds(monkeypatch,
+                                                            dtype):
+    """The in-place path (``dgi_paged_write`` into the stacked pools, the
+    ragged kernel reading them by layer index; interpret mode) against
+    the slice / scatter / write-back branch with the same kernel on the
+    sliced layer: the same pools, exactly — every layer, every page, the
+    unwritten slots of written pages — and the same logits, over two
+    rounds of one sequence, so that round 2 reads what round 1 wrote: a
+    21-token piece that ends mid-page, then its decode row beside a
+    second sequence's piece."""
+    from distributed_gpu_inference_tpu.models.configs import ModelConfig
+    from distributed_gpu_inference_tpu.ops import attention
+    from distributed_gpu_inference_tpu.ops import paged_attention_pallas as pap
+
+    cfg = ModelConfig(
+        name="in-place-probe", vocab_size=256, hidden_size=256, num_layers=3,
+        num_heads=4, num_kv_heads=2, intermediate_size=256, head_dim=128,
+        dtype=dtype,
+    )
+    monkeypatch.setattr(attention, "pallas_backend", lambda: True)
+    traced = []                              # kernels traced, by name
+
+    def interpreted(name):
+        kernel = getattr(pap, name)
+
+        def call(*args, **kwargs):
+            traced.append(name)
+            return kernel(*args, interpret=True, **kwargs)
+
+        return call
+
+    for name in ("write_kv_pages_in_place", "ragged_paged_attention"):
+        monkeypatch.setattr(pap, name, interpreted(name))
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    rows, pages = 3, 32                      # 512 tokens of context a row
+    rng = np.random.default_rng(5)
+    tables = jnp.asarray(
+        1 + rng.permutation(rows * pages).reshape(rows, pages), jnp.int32)
+    # pools that are not zero: an untouched byte that moved would show
+    kv0 = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        llama.init_kv_pools(cfg, 1 + rows * pages, 16, dtype=jnp.dtype(dtype)),
+    )
+    first = rng.integers(1, 200, size=21)
+    rounds = [
+        ([(1, 0, first)], 32),
+        ([(1, 21, [7]), (2, 0, rng.integers(1, 200, size=37))], 64),
+    ]
+
+    def run(path):
+        if path == "layer_copy":
+            monkeypatch.setattr(llama, "ragged_kv_path",
+                                lambda *a, **k: "layer_copy")
+        kv, seen = kv0, []
+        for pieces, width in rounds:
+            kv, logits = _packed_round(cfg, params, kv, tables, pieces, width)
+            seen.append((jax.tree.map(np.asarray, kv), logits))
+        return seen
+
+    assert llama.ragged_kv_path(cfg, pages * 16, False) == "in_place"
+    got = run("in_place")
+    assert set(traced) == {"write_kv_pages_in_place",
+                           "ragged_paged_attention"}
+    del traced[:]
+    want = run("layer_copy")
+    assert set(traced) == {"ragged_paged_attention"}
+    for (g_kv, g_logits), (w_kv, w_logits) in zip(got, want):
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(
+                g_kv[name].astype(np.float32), w_kv[name].astype(np.float32))
+        np.testing.assert_allclose(g_logits, w_logits, rtol=1e-5, atol=1e-5)
+    # block 0 (the null block no table names) kept its bytes
+    np.testing.assert_array_equal(
+        got[-1][0]["k"][:, 0].astype(np.float32),
+        np.asarray(kv0["k"][:, 0], np.float32))

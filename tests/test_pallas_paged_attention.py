@@ -447,3 +447,100 @@ def test_int8_fused_write_quantizes_in_kernel():
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-2, atol=2e-2)
+
+
+# --------------------------------------------------------------------- #
+# the in-place page write (PR 28) against the scatter it replaces: what
+# the round-level cases of tests/test_ragged_attention.py do not reach
+# --------------------------------------------------------------------- #
+
+def _write_both(positions, *, hkv=2, d=128, block=16, m=4, layers=2,
+                layer=1, dtype=jnp.bfloat16, token_index=None, seed=0):
+    """→ ((k, v) through ``write_kv_pages_in_place``, (k, v) through
+    ``_write_kv_pages`` into the sliced layer), float32 numpy."""
+    from distributed_gpu_inference_tpu.models.llama import _write_kv_pages
+    from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+        page_write_plan,
+        write_kv_pages_in_place,
+    )
+
+    rng = np.random.default_rng(seed)
+    positions = jnp.asarray(positions, jnp.int32)
+    b, s = positions.shape
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape),
+                                        jnp.float32).astype(dtype)
+    pools = [normal(layers, 1 + b * m, hkv, block, d) for _ in "kv"]
+    new = [normal(b, s, hkv, d) for _ in "kv"]
+    tables = jnp.asarray(1 + rng.permutation(b * m).reshape(b, m), jnp.int32)
+    want = [p.at[layer].set(_write_kv_pages(p[layer], n, tables, positions,
+                                            block))
+            for p, n in zip(pools, new)]
+    flat = [n.reshape(b * s, hkv, d) for n in new]
+    tokens = b * s
+    if token_index is not None:
+        # the packed round's form: the tokens in another order on a flat
+        # axis, and where each position of the rectangle lies on it
+        order = rng.permutation(b * s)
+        flat = [f[order] for f in flat]
+        token_index = jnp.asarray(np.argsort(order).reshape(b, s), jnp.int32)
+    plan = page_write_plan(
+        tables, positions, block, hkv * block * d * pools[0].dtype.itemsize,
+        token_index=token_index, num_tokens=tokens)
+    got = write_kv_pages_in_place(*flat, *pools, jnp.int32(layer), plan,
+                                  interpret=True)
+    f32 = lambda a: np.asarray(a.astype(jnp.float32))
+    return plan, [f32(g) for g in got], [f32(w) for w in want]
+
+
+def _span(start, n, s):
+    return [start + i if i < n else -1 for i in range(s)]
+
+
+def test_page_write_splits_a_row_into_tiles(monkeypatch):
+    """A span whose pages outgrow the VMEM budget: several grid steps a
+    row, the padding cells of the last one written nowhere."""
+    from distributed_gpu_inference_tpu.ops import paged_attention_pallas as pap
+
+    page_bytes = 2 * 16 * 128 * 2
+    monkeypatch.setattr(pap, "_WRITE_VMEM_BUDGET_BYTES", 6 * 2 * page_bytes)
+    plan, got, want = _write_both(
+        [_span(9, 60, 60), _span(0, 1, 60), _span(-1, 0, 60)], m=5)
+    # a 60-token span touches up to five pages: three tiles of two cells
+    assert plan.tile == 2 and plan.page.shape[0] == 3 * 6
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("block,dtype", [(32, jnp.bfloat16),
+                                         (32, jnp.float8_e4m3fn),
+                                         (64, jnp.float32)],
+                         ids=["block32_bf16", "block32_fp8", "block64_f32"])
+def test_page_write_block_sizes_and_pool_dtypes(block, dtype):
+    """Blocks of 32 (one whole mask word) and 64 (two), and a pool that
+    stores a narrower dtype than the rows it is given."""
+    _, got, want = _write_both(
+        [_span(31, 70, 70), _span(64, 1, 70), _span(5, 3, 70)],
+        block=block, m=4, dtype=dtype)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_page_write_keeps_the_scatters_semantics_inside_the_window():
+    """Positions a row's scatter would take in any order, with pads
+    between them, and one past the end of the row's table (dropped by
+    both)."""
+    _, got, want = _write_both(
+        [[19, -1, 3, 4, -1, 17, 30, -1],
+         [-1, -1, 63, 62, 64, -1, -1, 66],      # table holds 0..63
+         [-1] * 8],
+        m=4)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_page_write_reads_tokens_from_a_packed_axis():
+    _, got, want = _write_both(
+        [_span(7, 20, 24), _span(40, 1, 24), _span(16, 16, 24)],
+        token_index=True)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)

@@ -192,6 +192,117 @@ def test_ragged_matches_multiquery_alias():
 
 
 # --------------------------------------------------------------------- #
+# the chunk's KV path in place (PR 28): the page write into the stacked
+# pools by layer index and the ragged kernel reading them there, against
+# the scatter into the sliced layer and the same kernel on that slice
+# --------------------------------------------------------------------- #
+
+def _in_place_against_layer_copy(rows, nh, hkv, m, block=16, d=128,
+                                 layers=3, layer=1, window=None,
+                                 dtype=jnp.float32, seed=0):
+    """``rows``: (span, kv_len) a row, its span at the tail of its context
+    as in ``_ragged_setup``. Stacked pools of random bytes, shuffled
+    tables that never name block 0. The pools must come out EQUAL — the
+    written layer, the layers beside it, the unwritten slots of written
+    pages, block 0 — and attention equal to the existing tolerance."""
+    from distributed_gpu_inference_tpu.models.llama import _write_kv_pages
+    from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+        page_write_plan,
+        ragged_paged_attention,
+        write_kv_pages_in_place,
+    )
+
+    rng = np.random.default_rng(seed)
+    b = len(rows)
+    s = max(max(span for span, _ in rows), 2)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    k_pool = normal(layers, 1 + b * m, hkv, block, d)
+    v_pool = normal(layers, 1 + b * m, hkv, block, d)
+    q, new_k, new_v = normal(b, s, nh, d), normal(b, s, hkv, d), \
+        normal(b, s, hkv, d)
+    tables = jnp.asarray(1 + rng.permutation(b * m).reshape(b, m), jnp.int32)
+    positions = np.full((b, s), -1, np.int32)
+    for i, (span, kv_len) in enumerate(rows):
+        positions[i, :span] = np.arange(kv_len - span, kv_len)
+    positions = jnp.asarray(positions)
+    lens = jnp.asarray([kv_len for _, kv_len in rows], jnp.int32)
+
+    want_k = k_pool.at[layer].set(
+        _write_kv_pages(k_pool[layer], new_k, tables, positions, block))
+    want_v = v_pool.at[layer].set(
+        _write_kv_pages(v_pool[layer], new_v, tables, positions, block))
+    want = ragged_paged_attention(
+        q, want_k[layer], want_v[layer], tables, positions, lens, block,
+        window=window, interpret=True)
+
+    plan = page_write_plan(tables, positions, block,
+                           hkv * block * d * k_pool.dtype.itemsize)
+    got_k, got_v = write_kv_pages_in_place(
+        new_k.reshape(-1, hkv, d), new_v.reshape(-1, hkv, d), k_pool, v_pool,
+        jnp.int32(layer), plan, interpret=True)
+    got = ragged_paged_attention(
+        q, got_k, got_v, tables, positions, lens, block, window=window,
+        interpret=True, layer_idx=jnp.int32(layer))
+
+    f32 = lambda a: np.asarray(a, np.float32)
+    np.testing.assert_array_equal(f32(got_k), f32(want_k))
+    np.testing.assert_array_equal(f32(got_v), f32(want_v))
+    np.testing.assert_allclose(f32(got), f32(want), rtol=2e-5, atol=2e-5)
+    # and the write changed something, where a row had a token
+    assert any(span for span, _ in rows) == bool(
+        np.any(f32(got_k[layer]) != f32(k_pool[layer])))
+
+
+_IN_PLACE_ROUNDS = {
+    # a chunk that starts on a page's first slot and ends on one's last
+    "page_aligned_256_token_chunk": ([(256, 512)], 32),
+    # a span that starts and ends mid-page: first and last page are
+    # read-modify-write, the ones between are written whole
+    "span_from_mid_page_to_mid_page": ([(40, 45)], 4),
+    # one-token rows, as a round's decode rows are: slots 0, 7 and 15
+    "decode_rows_at_slots_0_7_15": ([(1, 17), (1, 24), (1, 32)], 2),
+    # rows with nothing to write, and rows narrower than the rectangle
+    "all_pad_rows_and_pad_tails": ([(0, 0), (9, 30), (0, 0), (2, 2)], 2),
+    "nothing_to_write_at_all": ([(0, 0), (0, 0)], 2),
+    # a round as serving has them: decode rows beside two pieces
+    "decode_rows_beside_two_pieces": (
+        [(1, 101), (50, 50), (1, 38), (33, 97)], 8),
+    # the span ends on the last slot of the table's last page
+    "last_page_of_the_table": ([(20, 64), (1, 64)], 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_IN_PLACE_ROUNDS))
+def test_in_place_chunk_equals_layer_copy(case):
+    rows, m = _IN_PLACE_ROUNDS[case]
+    _in_place_against_layer_copy(rows, nh=4, hkv=2, m=m)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_in_place_chunk_touches_only_its_layer(layer):
+    """A layer of a 3-layer stack: the other two keep every byte (the
+    whole-pool comparison), whichever it is."""
+    _in_place_against_layer_copy([(1, 20), (21, 21)], nh=4, hkv=2, m=2,
+                                 layer=layer)
+
+
+@pytest.mark.parametrize("nh,hkv", [(32, 8), (28, 4), (16, 16)],
+                         ids=["gqa_32_8", "gqa_28_4", "mha_16_16"])
+def test_in_place_chunk_head_geometries(nh, hkv):
+    """Mistral's, Qwen2.5's and OLMoE's heads of 128, in bf16 pools."""
+    _in_place_against_layer_copy([(1, 40), (37, 70), (0, 0)], nh=nh,
+                                 hkv=hkv, m=5, dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("window", [4096, 24])
+def test_in_place_chunk_under_a_sliding_window(window):
+    """Mistral's window (4096: wider than the context served) and one
+    that cuts inside the table."""
+    _in_place_against_layer_copy([(1, 150), (33, 97), (6, 80)], nh=4, hkv=2,
+                                 m=10, window=window)
+
+
+# --------------------------------------------------------------------- #
 # dispatch: resolve_impl owns the crossovers (satellite: the micro-bench
 # read crossover moved here; MICRO_READ_XLA_MIN_BATCH is an override only)
 # --------------------------------------------------------------------- #

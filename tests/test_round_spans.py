@@ -344,7 +344,8 @@ def test_worker_ships_round_counters_and_the_plane_counts_their_deltas():
         def get_stats(self):
             return {"round_build_s": 0.5, "round_dispatch_s": 0.125,
                     "round_readback_s": 2.0, "round_commit_s": 0.25,
-                    "rounds": 10, "compiles": 7, "compile_s": 1.5}
+                    "rounds": 10, "compiles": 7, "compile_s": 1.5,
+                    "ragged_kv_path": "in_place"}
 
     class Eng:
         engine = Core()
@@ -375,6 +376,8 @@ def test_worker_ships_round_counters_and_the_plane_counts_their_deltas():
     assert sent["round_readback_s"] == 4.0 and sent["round_build_s"] == 1.0
     # compiles are the process's, not an engine's: not summed over engines
     assert sent["compiles"] == 7 and sent["compile_s"] == 1.5
+    # which KV path the multi-token rounds were built with: a fact, as is
+    assert sent["ragged_kv_path"] == "in_place"
 
     mc = MetricsCollector()
     mc.record_batcher_engine("w1", sent)
@@ -408,6 +411,8 @@ def test_worker_ships_round_counters_and_the_plane_counts_their_deltas():
     assert 'engine_round_seconds_total{phase="build",worker="w1"} 1.0' in text
     assert 'worker_compiles_total{worker="w1"} 10.0' in text
     assert 'worker_compile_seconds_total{worker="w1"} 1.5' in text
+    assert 'worker_ragged_kv_path{path="in_place",worker="w1"} 1.0' in text
+    assert 'worker_ragged_kv_path{path="layer_copy",worker="w1"} 0.0' in text
     # an engine restart re-anchors: totals fall, nothing is subtracted
     mc.record_batcher_engine("w1", dict(sent, between_rounds=3))
     assert 'batcher_between_rounds_total{worker="w1"} 20.0' \

@@ -29,8 +29,10 @@ from distributed_gpu_inference_tpu.models import llama
 from distributed_gpu_inference_tpu.models.configs import get_model_config
 from distributed_gpu_inference_tpu.ops import attention
 from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+    page_write_plan,
     paged_decode_attention_fused,
     ragged_paged_attention,
+    write_kv_pages_in_place,
 )
 from distributed_gpu_inference_tpu.ops.qmm_pallas import qmm_stacked_pallas
 from distributed_gpu_inference_tpu.ops.quantization import quantize_params
@@ -133,6 +135,32 @@ def test_fused_decode_kernel_compiles(v5e, model, block, quantized):
     ).compile()
 
 
+@pytest.mark.parametrize("block", [16, 32])
+@pytest.mark.parametrize("model", MODELS + ("olmoe-1b-7b",))
+def test_page_write_kernel_compiles(v5e, model, block):
+    """The in-place page write at the rectangles the ladder gives it (64,
+    256) and at a whole-prompt chunk, whose span takes several tiles."""
+    cfg = get_model_config(model)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    hkv, d = cfg.num_kv_heads, cfg.head_dim
+    pool, _ = _pools(sds, cfg, block, False, layers=2)
+
+    def write(k, v, k_pool, v_pool, layer, tables, positions):
+        plan = page_write_plan(tables, positions, block, hkv * block * d * 2)
+        return write_kv_pages_in_place(
+            k.reshape(-1, hkv, d), v.reshape(-1, hkv, d), k_pool, v_pool,
+            layer, plan)
+
+    for s in (64, 256, CTX):
+        new = sds((BATCH, s, hkv, d), jnp.bfloat16)
+        lowered = jax.jit(write, donate_argnums=(2, 3)).lower(
+            new, new, pool, pool, sds((), jnp.int32),
+            sds((BATCH, CTX // block), jnp.int32), sds((BATCH, s), jnp.int32),
+        )
+        assert _kernels(lowered) == {"dgi_paged_write"}
+        lowered.compile()
+
+
 @pytest.mark.parametrize("model", MODELS)
 def test_qmm_kernel_compiles(v5e, model):
     cfg = get_model_config(model)
@@ -224,7 +252,7 @@ def test_forward_chunk_compiles_one_chip(v5e, tpu_dispatch, s):
     else:
         # a ragged round at the full chunk: 8 x 256 rows is past the int8
         # kernel's bandwidth-bound regime, so projections take the XLA path
-        assert found == {"dgi_ragged_attention"}, found
+        assert found == {"dgi_paged_write", "dgi_ragged_attention"}, found
     lowered.compile()
 
 
@@ -237,9 +265,35 @@ def test_packed_forward_chunk_compiles_one_chip(v5e, tpu_dispatch, tp, s,
     lowered = _forward_chunk_lowered(
         get_model_config("mistral-7b"), s, None, v5e, tp=tp
     )
-    assert _kernels(lowered) == {"dgi_ragged_attention"} | (
-        {"dgi_qmm"} if qmm else set())
+    assert _kernels(lowered) == {"dgi_paged_write", "dgi_ragged_attention"} \
+        | ({"dgi_qmm"} if qmm else set())
     lowered.compile()
+
+
+# TPUEngine._ragged_ladder at the worker's geometry: (Tp, the rectangle's S)
+LADDER = ((64, 64), (128, 128), (264, 256), (528, 256), (2048, 256))
+
+
+@pytest.mark.parametrize("tp,s", LADDER, ids=[f"Tp{t}" for t, _ in LADDER])
+@pytest.mark.parametrize("model", MODELS + ("olmoe-1b-7b",))
+def test_packed_round_moves_no_pool_layer(v5e, tpu_dispatch, model, tp, s):
+    """Every rung of the ladder, every one-chip configuration: the round
+    writes its pages into the stacked pools and reads them there. The
+    compiled program holds no array of a pool layer's shape — no slice out
+    of the stack, no copy between the scatter's layout and the kernel's,
+    no write-back — and, up to the rungs whose own activations are
+    smaller, less temporary memory than one layer's K."""
+    cfg = get_model_config(model)
+    lowered = _forward_chunk_lowered(cfg, s, None, v5e, tp=tp)
+    assert {"dgi_paged_write", "dgi_ragged_attention"} <= _kernels(lowered)
+    compiled = lowered.compile()
+    blocks = 1 + BATCH * (CTX // 16)
+    layer = f"[{blocks},{cfg.num_kv_heads},16,{cfg.head_dim}]"
+    assert f"[{cfg.num_layers},{blocks}," in compiled.as_text()
+    assert layer not in compiled.as_text()
+    if tp <= 528:       # at 2048 the MLP's own [Tp, I] temporaries are more
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < blocks * cfg.num_kv_heads * 16 * cfg.head_dim * 2
 
 
 @pytest.mark.parametrize("tp,s,qmm", [(None, 1, True), (128, 128, True),
@@ -254,7 +308,7 @@ def test_olmoe_graphs_compile_one_chip(v5e, tpu_dispatch, tp, s, qmm):
         get_model_config("olmoe-1b-7b"), s, None, v5e, tp=tp
     )
     want = {"dgi_paged_decode", "dgi_moe_gmm_step"} if tp is None \
-        else {"dgi_ragged_attention", "dgi_moe_gmm"}
+        else {"dgi_paged_write", "dgi_ragged_attention", "dgi_moe_gmm"}
     assert _kernels(lowered) == want | ({"dgi_qmm"} if qmm else set())
     lowered.compile()
 
@@ -264,11 +318,9 @@ def test_olmoe_packed_round_holds_no_token_by_expert_tensor(v5e,
     """At ``Tp`` = 264 the dense einsum form needs a ``[264, 64, 2048]``
     float32 combine tensor (138 MB a layer) and bf16 copies of the expert
     weights (805 MB a layer); the routed form's temporaries are the tiled
-    rows (at most 130 tiles of 32). The pools are sized to 512 tokens a
-    row here, so that the ragged round's copies of a pool layer (67 MB
-    each at the served 2048: PERF.md section 5) do not hide the bound."""
+    rows (at most 130 tiles of 32). At the served context of 2048."""
     compiled = _forward_chunk_lowered(
-        get_model_config("olmoe-1b-7b"), 256, None, v5e, tp=264, ctx=512
+        get_model_config("olmoe-1b-7b"), 256, None, v5e, tp=264
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 264 * 64 * 2048 * 4
 
