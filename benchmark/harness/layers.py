@@ -28,9 +28,22 @@ from __future__ import annotations
 
 import importlib.util
 import sys
+from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .spec import BENCH, SpecError
+
+
+def reader_path(name: str) -> Optional[Path]:
+    """The file that reads ``name``: ``layer_metrics/<name, dots as
+    underscores>.py``, or, for a quantity split by the end-to-end metric
+    its cells report (``client.gap_p90_ms.tpot`` beside
+    ``client.gap_p90_ms``), the file of the name without its last part."""
+    for stem in (name, name.rpartition(".")[0]):
+        path = BENCH / "layer_metrics" / f"{stem.replace('.', '_')}.py"
+        if stem and path.is_file():
+            return path
+    return None
 
 
 def readers(cell: Dict[str, Any]
@@ -44,9 +57,10 @@ def readers(cell: Dict[str, Any]
         if entry["moves"] not in cell["end_to_end"]:
             raise SpecError(f"{name} moves {entry['moves']}, which "
                             f"{cell['name']} does not report")
-        path = BENCH / "layer_metrics" / f"{name.replace('.', '_')}.py"
-        if not path.is_file():
-            raise SpecError(f"no reader layer_metrics/{path.name} for {name}")
+        path = reader_path(name)
+        if path is None:
+            raise SpecError("no reader layer_metrics/"
+                            f"{name.replace('.', '_')}.py for {name}")
         spec = importlib.util.spec_from_file_location(
             f"layer_metrics.{path.stem}", path
         )

@@ -17,7 +17,8 @@ MIN_TOKENS_FOR_TPOT = 8
 # (``setup_s`` is the harness's own); which of them a cell is judged by is
 # the manifest's business
 END_TO_END = ("ttft_p50_ms", "ttft_mean_ms", "ttft_p90_ms", "tpot_p50_ms",
-              "tpot_p90_ms", "itl_p99_ms", "gap_p90_ms", "out_tok_s")
+              "tpot_p90_ms", "itl_p50_ms", "itl_p98_ms", "itl_p99_ms",
+              "gap_p90_ms", "out_tok_s")
 
 
 def percentile(values: Sequence[float], q: float) -> Optional[float]:
@@ -100,7 +101,7 @@ def summarize(rows: List[Dict[str, Any]], w0: float, w1: float,
     tpot = [x for x in map(tpot_ms, ok) if x is not None]
     # every wait between two events of every stream, pooled: thousands of
     # readings where the requests are a hundred, so its tail holds still
-    waits = [g for r in ok for g in gaps_ms(r)]
+    waits = sorted(g for r in ok for g in gaps_ms(r))
     out: Dict[str, Any] = {
         "attempted": len(sample),
         "failed": len(sample) - len(ok),
@@ -111,7 +112,10 @@ def summarize(rows: List[Dict[str, Any]], w0: float, w1: float,
         "ttft_p90_ms": percentile(ttft, 90),
         "tpot_p50_ms": percentile(tpot, 50),
         "tpot_p90_ms": percentile(tpot, 90),
+        "itl_p50_ms": percentile(waits, 50),
+        "itl_p98_ms": percentile(waits, 98),
         "itl_p99_ms": percentile(waits, 99),
+        "n_waits": len(waits),
         "gap_p90_ms": percentile(
             [x for x in map(longest_gap_ms, ok) if x is not None], 90),
         "gen_late_p90_ms": percentile(late, 90),

@@ -244,10 +244,13 @@ class Session:
                 env=_child_env(), stdout=log, stderr=subprocess.STDOUT,
             )
         self.loadgen = LoadGenerator()
+        self.timing["children_started_s"] = time.monotonic() - t0
 
         import jax
 
         devices = jax.devices()
+        # importing JAX and bringing up the chip's runtime, from the start
+        self.timing["jax_devices_s"] = time.monotonic() - t0
         self.device = {"platform": devices[0].platform,
                        "kind": devices[0].device_kind, "count": len(devices)}
         say(t0, f"device: {self.device}")

@@ -8,10 +8,13 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, List, Optional
 
+from . import metrics
 from .session import Session
 
 # the traced run profiles this long a slice, not the whole window
 TRACE_SLICE_S = 5.0
+# an open loop's generator may send this late at its 90th percentile
+MAX_GEN_LATE_P90_MS = 10.0
 
 
 def sleep_until(t: float) -> None:
@@ -89,6 +92,53 @@ def run_window(s: Session, plan: Dict[str, Any], seconds: float,
     after = s.counters()
     return {"rows": rows, "w0": w0, "w1": w1, "t0": t0, "c0": c0, "c1": c1,
             "before": before, "after": after, "samples": samples}
+
+
+def window_compared(s: Session, win: Dict[str, Any], plan: Dict[str, Any],
+                    summary: Dict[str, Any], vocab: int
+                    ) -> Dict[str, Dict[str, float]]:
+    """What one window has to show whatever the cell, each as a number
+    beside the limit it may not pass: requests of the window not answered
+    in full, rows of the whole plan not answered in full, the distance
+    between the direct server's counters and the generator's, engine
+    errors, compile requests inside the window, and how late the generator
+    ran (a closed loop has no schedule to be late for)."""
+    direct = win["after"]["direct"]
+    served = direct.get("requests", 0) + direct.get("rejected", 0)
+    numbers = {
+        "failed": (summary["failed"], 0),
+        "rows_incomplete": (sum(1 for r in win["rows"]
+                                if not metrics.complete(r, vocab)), 0),
+        "direct_count_gap": (
+            abs(served - s.rows_sent)
+            + abs(direct.get("rejected", 0) - s.refusals_seen), 0),
+        "engine_errors": (delta(win, "batcher", "engine_errors",
+                                whole=True), 0),
+        "compiles_in_window": (
+            len(s.compiles.between(win["w0"], win["w1"])), 0),
+    }
+    if plan["loop"] != "closed":
+        numbers["gen_late_p90_ms"] = (summary["gen_late_p90_ms"] or 0.0,
+                                      MAX_GEN_LATE_P90_MS)
+    return {k: {"value": v, "limit": limit}
+            for k, (v, limit) in numbers.items()}
+
+
+def within(compared: Dict[str, Dict[str, float]]) -> Dict[str, bool]:
+    """Each compared number against its limit."""
+    return {k: c["value"] <= c["limit"] for k, c in compared.items()}
+
+
+def detail_requests(rows: List[Dict[str, Any]], w0: float
+                    ) -> List[Dict[str, Any]]:
+    """The rows as a detail file keeps them: every event's instant counted
+    from the window's opening, the token ids and timelines left out."""
+    return [
+        {k: v for k, v in r.items() if k not in ("ids", "timeline")}
+        | {"due": r["due"] - w0, "sent": r["sent"] - w0,
+           "t": [round(t - w0, 5) for t in r["t"]]}
+        for r in rows
+    ]
 
 
 def delta(win: Dict[str, Any], part: str, key: str,

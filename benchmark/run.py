@@ -35,9 +35,10 @@ sys.path[:0] = [str(HERE), str(HERE.parent)]
 
 from harness import layers, metrics, reference, spec, trace_reduce  # noqa: E402
 from harness.session import RunFailed, Session, check_spec, say  # noqa: E402
-from harness.window import delta, run_window  # noqa: E402
+from harness.window import (  # noqa: E402
+    detail_requests, run_window, window_compared, within,
+)
 
-MAX_GEN_LATE_P90_MS = 10.0
 ROUND_PROGRAMS = ("ragged_round", "decode_multi")
 
 
@@ -47,7 +48,8 @@ def probe_check(s: Session, cell: Dict[str, Any]) -> Dict[str, Any]:
     path = cell["_golden"]
     if not path.is_file():
         return {"ok": False, "why": f"no golden file {path.name}: make it "
-                "with benchmark/make_golden.py", "probes": []}
+                "with benchmark/make_golden.py", "probes": [],
+                "compared": {"probes_outside_top": {"value": 1, "limit": 0}}}
     with open(path) as f:
         golden = json.load(f)
     by_name = {p["name"]: p for p in golden["probes"]}
@@ -64,7 +66,15 @@ def probe_check(s: Session, cell: Dict[str, Any]) -> Dict[str, Any]:
         out.append({"name": row["id"], "first_token": row["ids"][0],
                     "ttft_ms": metrics.ttft_ms(row), **verdict})
         ok = ok and verdict["ok"]
-    return {"ok": ok, "margin": golden["margin"], "probes": out}
+    # a probe's first token outside the reference's top ids has no deficit
+    deficits = [p["deficit"] for p in out if p.get("deficit") is not None]
+    compared = {
+        "probes_outside_top": {"value": len(out) - len(deficits), "limit": 0},
+        "probe_deficit_max": {"value": max(deficits, default=0.0),
+                              "limit": float(golden["margin"])},
+    }
+    return {"ok": ok, "margin": golden["margin"], "probes": out,
+            "compared": compared}
 
 
 def in_flight_on_trace(rows: List[Dict[str, Any]], offset: float
@@ -145,23 +155,9 @@ def main() -> int:
     summary = metrics.summarize(rows, w0, w1, vocab, cell.get("limits"))
     sample = [r for r in rows if w0 <= r["due"] < w1]
     compiles_in = s.compiles.between(w0, w1)
-    direct = win["after"]["direct"]
-    checks = {
-        "answered_in_full": summary["failed"] == 0 and all(
-            metrics.complete(r, vocab) for r in rows
-        ),
-        "direct_server_counts": (
-            direct.get("requests", 0) + direct.get("rejected", 0)
-            == s.rows_sent and direct.get("rejected", 0) == s.refusals_seen
-        ),
-        "no_engine_errors": delta(win, "batcher", "engine_errors",
-                                  whole=True) == 0,
-        "no_compile_in_window": not compiles_in,
-        # a closed loop has no schedule to be late for
-        "generator_on_time": plan["loop"] == "closed"
-        or (summary["gen_late_p90_ms"] or 0.0) < MAX_GEN_LATE_P90_MS,
-        "probes": probes["ok"],
-    }
+    compared = window_compared(s, win, plan, summary, vocab)
+    compared.update(probes["compared"])
+    checks = within(compared)
     correct = all(checks.values())
 
     red: Optional[Dict[str, Any]] = None
@@ -218,7 +214,8 @@ def main() -> int:
     detail = {
         "cell": cell["name"], "seed": args.seed, "seconds": args.seconds,
         "trace": args.trace, "rate_rps": cell.get("rate_rps"),
-        "device": device, "checks": checks, "summary": summary,
+        "device": device, "checks": checks, "compared": compared,
+        "summary": summary,
         "end_to_end": e2e, "per_layer": per_layer,
         "per_layer_not_read": not_read, "notes": notes,
         "timing": s.timing, "warmed": s.warmed, "geometry": s.geometry,
@@ -233,12 +230,7 @@ def main() -> int:
                                 "idle_seconds")
         } | {"op_seconds_top": trace_reduce.breakdown(red, 40)["device_ops"],
              "modules": red["modules"][:2000]},
-        "requests": [
-            {k: v for k, v in r.items() if k not in ("ids", "timeline")}
-            | {"due": r["due"] - w0, "sent": r["sent"] - w0,
-               "t": [round(t - w0, 5) for t in r["t"]]}
-            for r in rows
-        ],
+        "requests": detail_requests(rows, w0),
     }
     path = out_dir / f"{cell['name']}.seed{args.seed}.trace{args.trace}.json"
     with open(path, "w") as f:
@@ -259,6 +251,14 @@ def main() -> int:
         if not_read:
             say(T0, "per layer, NOTHING TO READ in this slice: "
                 + ", ".join(not_read))
+    # each number compared beside its limit: last in the line, and the last
+    # lines on standard error
+    line["compared"] = compared
+    sys.stdout.flush()
+    for name, c in compared.items():
+        print(f"compared {name}={c['value']:.6g} limit={c['limit']:.6g}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

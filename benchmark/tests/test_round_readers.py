@@ -91,8 +91,18 @@ def test_decode_attention_step_ms_finds_the_kernel_by_its_name():
 def test_every_reader_of_the_manifest_is_a_file_with_read():
     import json
 
+    from harness import layers
+
     manifest = json.loads((spec.CHECKOUT / "BENCHMARK.json").read_text())
     for entry in manifest["per_layer"]:
-        path = spec.BENCH / "layer_metrics" \
-            / f"{entry['name'].replace('.', '_')}.py"
-        assert path.is_file(), entry["name"]
+        assert layers.reader_path(entry["name"]) is not None, entry["name"]
+
+
+def test_a_split_metric_is_read_by_the_file_of_its_name_without_the_last_part():
+    from harness import layers
+
+    own = layers.reader_path("client.gap_p90_ms")
+    assert own is not None and own.name == "client_gap_p90_ms.py"
+    assert layers.reader_path("client.gap_p90_ms.tpot") == own
+    assert layers.reader_path("client.no_such_ms") is None
+    assert layers.reader_path("client") is None

@@ -66,6 +66,7 @@ def test_the_harness_names_no_cell_model_or_mix():
     pattern = re.compile("|".join(re.escape(n) for n in sorted(names)))
     code = sorted((spec.BENCH / "harness").rglob("*.py")) + [
         spec.BENCH / "run.py", spec.BENCH / "sweep.py",
+        spec.BENCH / "spread.py",
         spec.BENCH / "make_golden.py", spec.BENCH / "compile_only.py"]
     for path in code:
         body = path.read_text()
