@@ -41,9 +41,38 @@ class ModelConfig:
     # OLMoE-style QK-norm: RMSNorm over the whole projected q / k width
     # (one learned vector per layer each), before the head split and RoPE
     qk_norm: bool = False
+    # Latent attention (MLA): the cache holds one ``kv_lora_rank`` latent and
+    # ``qk_rope_head_dim`` rope values a token a layer instead of per-head K
+    # and V (models/mla.py). 0 = the K/V layout. ``head_dim`` is then the
+    # width of a query/key head, nope + rope.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # per-layer description of the MLP: the first ``first_k_dense`` layers are
+    # dense (width ``intermediate_size``), every later one routed experts of
+    # width ``moe_intermediate_size`` beside ``n_shared_experts`` every token
+    # takes
+    first_k_dense: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    # the router of a latent-attention model scores by sigmoid, keeps the
+    # top-k of all experts and scales the normalised weights by this
+    routed_scaling_factor: float = 1.0
+    # the chip's share of an expert-parallel deployment: (first, count) of the
+    # ``num_experts`` routed experts this chip holds. The router keeps its
+    # published width and top-k; the layer computes its own experts' part.
+    held_experts: Optional[Tuple[int, int]] = None
+    # RMSNorm after each sub-block as well as before it (four a layer)
+    sandwich_norm: bool = False
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
+        if self.kv_lora_rank and self.head_dim is None:
+            object.__setattr__(
+                self, "head_dim", self.qk_nope_head_dim + self.qk_rope_head_dim
+            )
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden_size // self.num_heads)
         if self.num_heads % self.num_kv_heads != 0:
@@ -54,6 +83,36 @@ class ModelConfig:
             )
         if self.num_experts and self.num_experts_per_tok > self.num_experts:
             raise ValueError("num_experts_per_tok exceeds num_experts")
+        if not self.kv_lora_rank:
+            # read by models/mla.py alone: models/llama.py would drop them
+            # without a word (a softmax router, every expert held, two
+            # norms a layer, every layer alike)
+            only_latent = [name for name, default in (
+                ("first_k_dense", 0), ("n_shared_experts", 0),
+                ("routed_scaling_factor", 1.0), ("held_experts", None),
+                ("sandwich_norm", False),
+            ) if getattr(self, name) != default]
+            if only_latent:
+                raise ValueError(
+                    f"{self.name}: {', '.join(only_latent)} without "
+                    "kv_lora_rank: only the latent-attention model "
+                    "(models/mla.py) reads them")
+        if self.held_experts is not None:
+            first, count = self.held_experts
+            if first < 0 or count < 1 or first + count > self.num_experts:
+                raise ValueError(
+                    f"held_experts {self.held_experts} outside the "
+                    f"{self.num_experts} routed experts"
+                )
+
+    @property
+    def latent_kv(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def num_held_experts(self) -> int:
+        """Routed experts whose weights this chip stores."""
+        return self.held_experts[1] if self.held_experts else self.num_experts
 
     @property
     def q_per_kv(self) -> int:
@@ -62,6 +121,11 @@ class ModelConfig:
     @property
     def num_params(self) -> int:
         """Approximate parameter count (embeddings + layers + head)."""
+        if self.latent_kv:
+            head = 0 if self.tie_word_embeddings else self.vocab_size
+            return (self.vocab_size + head) * self.hidden_size + sum(
+                self.layer_params(li) for li in range(self.num_layers)
+            ) + self.hidden_size
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         d = self.head_dim
         attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) + (
@@ -83,10 +147,35 @@ class ModelConfig:
         return self.num_params * dtype_bytes
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
+        if self.latent_kv:
+            return self.num_layers * (
+                self.kv_lora_rank + self.qk_rope_head_dim) * dtype_bytes
         return 2 * self.num_layers * self.num_kv_heads * self.head_dim * dtype_bytes
+
+    def layer_params(self, layer: int) -> int:
+        """Parameters layer ``layer`` of a latent-attention model stores
+        here (the held experts only)."""
+        h, nh = self.hidden_size, self.num_heads
+        attn = (
+            h * self.q_lora_rank + self.q_lora_rank * nh * self.head_dim
+            + h * (self.kv_lora_rank + self.qk_rope_head_dim)
+            + self.kv_lora_rank * nh
+            * (self.qk_nope_head_dim + self.v_head_dim)
+            + nh * self.v_head_dim * h
+        )
+        norms = (4 if self.sandwich_norm else 2) * h \
+            + self.q_lora_rank + self.kv_lora_rank
+        if layer < self.first_k_dense or not self.num_experts:
+            mlp = 3 * h * self.intermediate_size
+        else:
+            mlp = h * self.num_experts + 3 * h * self.moe_intermediate_size * (
+                self.num_held_experts + self.n_shared_experts)
+        return attn + norms + mlp
 
     def layer_param_bytes(self, dtype_bytes: int = 2) -> int:
         """Per-layer weight bytes — the shard planner's unit of placement."""
+        if self.latent_kv:
+            return self.layer_params(self.num_layers - 1) * dtype_bytes
         h, i, d = self.hidden_size, self.intermediate_size, self.head_dim
         attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) + (
             self.num_heads * d
@@ -234,6 +323,39 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
         max_position_embeddings=4096, rope_theta=10000.0,
         rms_norm_eps=1e-5, num_experts=64, num_experts_per_tok=8,
         norm_topk_prob=False, qk_norm=True,
+    ),
+    # openPangu-Ultra-MoE — latent attention (MLA: the cache holds a 512-wide
+    # latent and 64 rope values a token a layer), leading dense layers, then
+    # routed experts (sigmoid scores, top-8 normalised and scaled) beside a
+    # shared expert, four norms a layer (models/mla.py). One multi-token-
+    # prediction layer is published and not loaded.
+    "openpangu-ultra-moe-tiny": _llama(  # test-scale, every mechanism
+        "openpangu-ultra-moe-tiny", vocab_size=512, hidden_size=64,
+        num_layers=3, num_heads=4, num_kv_heads=4, intermediate_size=96,
+        max_position_embeddings=1024, rope_theta=10000.0,
+        kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, first_k_dense=1,
+        moe_intermediate_size=32, n_shared_experts=1, num_experts=8,
+        num_experts_per_tok=3, norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        sandwich_norm=True,
+    ),
+    # one chip's share of the published model where 16 chips share each
+    # layer: 16 of the 256 routed experts, an eighth of the vocabulary, one
+    # leading dense layer and eight expert layers of the 61 (the others lie
+    # on further chips); every width as published
+    # (benchmark/configs/openpangu-ultra-moe-718b-ep16-int8.json)
+    "openpangu-ultra-moe-718b-ep16": _llama(
+        "openpangu-ultra-moe-718b-ep16", vocab_size=19200, hidden_size=7680,
+        num_layers=9, num_heads=128, num_kv_heads=128,
+        intermediate_size=18432, max_position_embeddings=4096,
+        rope_theta=25600000.0, rms_norm_eps=1e-5,
+        kv_lora_rank=512, q_lora_rank=1536, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, first_k_dense=1,
+        moe_intermediate_size=2048, n_shared_experts=1, num_experts=256,
+        num_experts_per_tok=8, norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        held_experts=(0, 16), sandwich_norm=True,
     ),
 }
 

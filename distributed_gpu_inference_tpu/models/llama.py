@@ -50,6 +50,10 @@ def init_params(
     cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = None
 ) -> Params:
     """Random-init params with the exact pytree layout the engine shards."""
+    if cfg.latent_kv:
+        from distributed_gpu_inference_tpu.models import mla
+
+        return mla.init_params(cfg, key, dtype)
     dtype = dtype or jnp.dtype(cfg.dtype)
     h, d = cfg.hidden_size, cfg.head_dim
     nh, nkv, i = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
@@ -120,7 +124,14 @@ def init_kv_pools(
     ``dtype=int8``: quantized pools — the dict additionally carries
     ``k_scale``/``v_scale`` ([L, N, Bk, D] bf16, lane-replicated): one
     scale per (page, token) shared across KV heads (real = int * scale;
-    contract: ``ops.paged_attention_pallas._quantize_token_rows``)."""
+    contract: ``ops.paged_attention_pallas._quantize_token_rows``).
+
+    A latent-attention model (``cfg.latent_kv``) has one pool and no head
+    axis instead: ``{"ckv": [L, N, Bk, latent + rope]}`` (models/mla.py)."""
+    if cfg.latent_kv:
+        from distributed_gpu_inference_tpu.models import mla
+
+        return mla.init_kv_pools(cfg, num_blocks, block_size, dtype)
     dtype = jnp.dtype(dtype or cfg.dtype)
     shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size, cfg.head_dim)
     pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -231,11 +242,14 @@ def _write_scale_pages(
     return pool.at[flat_phys, flat_slot].set(flat_new, mode="drop")
 
 
-def _mlp(x: jax.Array, proj, activation: str = "silu") -> jax.Array:
-    act = jax.nn.silu if activation == "silu" else functools.partial(
+def _mlp_act(activation: str):
+    return jax.nn.silu if activation == "silu" else functools.partial(
         jax.nn.gelu, approximate=True  # Gemma GeGLU (gelu_pytorch_tanh)
     )
-    gate = act(proj(x, "w_gate"))
+
+
+def _mlp(x: jax.Array, proj, activation: str = "silu") -> jax.Array:
+    gate = _mlp_act(activation)(proj(x, "w_gate"))
     return proj(gate * proj(x, "w_up"), "w_down").astype(x.dtype)
 
 
@@ -279,9 +293,7 @@ def _moe_mlp(
       No counters. The routed form under a mesh (``shard_map`` around the
       kernel) is the open upgrade.
     """
-    act = jax.nn.silu if cfg.activation == "silu" else functools.partial(
-        jax.nn.gelu, approximate=True
-    )
+    act = _mlp_act(cfg.activation)
     b, s, h = x.shape
     t, k, num_e = b * s, cfg.num_experts_per_tok, cfg.num_experts
     xf = x.reshape(t, h)                                       # [T, H]
@@ -308,32 +320,62 @@ def _moe_mlp(
         )
         return out.reshape(b, s, h).astype(x.dtype), None, topi
 
+    from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
+
+    live = jnp.ones((t,), bool) if live is None else live.reshape(t)
+    out, plan = _routed_sum(xf, lp, topv, topi, live, num_e, t * k, act,
+                            stacked=stacked, layer_idx=layer_idx,
+                            decode=s == 1)
+    return (out.reshape(b, s, h).astype(x.dtype),
+            moe_gmm.expert_stats(plan), topi)
+
+
+def _routed_sum(
+    xf: jax.Array,              # [T, H]
+    lp: Dict[str, jax.Array],
+    topv: jax.Array,            # [T, k] float32 weight of each kept pair
+    experts: jax.Array,         # [T, k] its expert, among the STORED ones
+    live: jax.Array,            # [T] or [T, k] bool: pairs that are routed
+    num_stored: int,
+    pairs_hint: int,            # the live pairs expected: sizes the row tiles
+    act,
+    *,
+    stacked: Optional[Dict[str, Any]],
+    layer_idx: Any,
+    decode: bool,               # one token a row: names the kernel
+) -> Tuple[jax.Array, Any]:
+    """``sum_e w_e * down_e(act(gate_e(x)) * up_e(x))`` over a token's live
+    pairs as grouped matmuls (``ops/moe_gmm_pallas``) → (``[T, H]`` float32,
+    the plan). The routed form of ``_moe_mlp`` and the held share of
+    ``models/mla._experts`` (whose ``live`` is per pair: a pair on an expert
+    held elsewhere is routed nowhere and reads nothing)."""
     # imported where a sparse model needs it: a dense model's start does
     # not pay for it
     from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
 
-    live = jnp.ones((t,), bool) if live is None else live.reshape(t)
+    (t, h), k = xf.shape, topv.shape[1]
     plan = moe_gmm.route_plan(
-        topi, live, num_e,
-        moe_gmm.tile_rows(t * k, num_e, moe_gmm.sublane(x.dtype)),
+        experts, live, num_stored,
+        moe_gmm.tile_rows(pairs_hint, num_stored, moe_gmm.sublane(xf.dtype)),
     )
 
     def gmm(rows, name):
+        # whole in ``stacked`` exactly where the kernel takes them, else
+        # this layer's slice through XLA's gather
         if stacked is not None and name in stacked:
             return moe_gmm.grouped_matmul(
-                rows, stacked[name], layer_idx, plan, decode=s == 1)
+                rows, stacked[name], layer_idx, plan, decode=decode)
         return moe_gmm.grouped_matmul_layer(rows, lp[name], plan)
 
     rows = jnp.take(xf, plan.row_token, axis=0, mode="fill", fill_value=0)
     mid = act(gmm(rows, "we_gate")) * gmm(rows, "we_up")       # [R, I]
-    y = gmm(mid.astype(x.dtype), "we_down")                    # [R, H]
+    y = gmm(mid.astype(xf.dtype), "we_down")                   # [R, H]
     out = jnp.zeros((t, h), jnp.float32)
     for j in range(k):      # a token's k rows; dead pairs read nothing
         out = out + topv[:, j, None] * jnp.take(
             y, plan.pair_row[:, j], axis=0, mode="fill", fill_value=0
         ).astype(jnp.float32)
-    return (out.reshape(b, s, h).astype(x.dtype),
-            moe_gmm.expert_stats(plan), topi)
+    return out, plan
 
 
 def _deq(w: Any, dtype) -> jax.Array:
@@ -394,6 +436,12 @@ def ragged_kv_path(
       them at block 16)."""
     from distributed_gpu_inference_tpu.ops.attention import resolve_impl
 
+    if cfg.latent_kv:
+        # the latent pool has no layer slice on any path: the latent kernels
+        # address the stacked pool by layer index, and the XLA path scatters
+        # into it and gathers its pages with the layer as an index
+        # (models/mla.py)
+        return "in_place"
     ragged = resolve_impl(
         q_seq=2, head_dim=cfg.head_dim, padded_ctx=padded_ctx
     ) == "ragged"
@@ -764,6 +812,20 @@ def forward_chunk(
     depend on which other rows share the call. ``hidden`` comes back
     ``[1, Tp, H]``.
     """
+    if cfg.latent_kv:   # latent cache, layers of two kinds: models/mla.py
+        from distributed_gpu_inference_tpu.models import mla
+
+        if (dense_attn_fn is not None or attn_override is not None
+                or collect_layers is not None):
+            raise NotImplementedError(
+                "a latent-attention model has no sequence-parallel, "
+                "overridden or feature-collecting forward")
+        return mla.forward_chunk(
+            cfg, params, token_ids, positions, kv, block_tables, kv_lens,
+            block_size=block_size, last_only=last_only,
+            with_logits=with_logits, collect_routing=collect_routing,
+            pallas=pallas, packing=packing,
+        )
     unpack = to_rect = tp = None
     if packing is not None:
         tp = token_ids.shape[0]

@@ -212,6 +212,13 @@ def init_quantized_streamed(
         quantize_weight,
     )
 
+    if cfg.latent_kv:
+        # its init draws a layer at a time whatever the mode (models/mla.py)
+        from distributed_gpu_inference_tpu.models import mla
+
+        if mesh is not None:
+            raise ValueError("a latent-attention model is one-chip")
+        return mla.init_params(cfg, jax.random.PRNGKey(seed), dtype, mode)
     dtype = jnp.dtype(dtype or cfg.dtype)
     h, d = cfg.hidden_size, cfg.head_dim
     nh, nkv, i = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size

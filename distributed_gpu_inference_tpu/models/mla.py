@@ -1,0 +1,542 @@
+"""Latent-attention (MLA) decoder with a shared expert beside routed ones,
+sandwich norms and leading dense layers — the openPangu-Ultra-MoE recipe —
+as ``forward_chunk`` over a **latent paged cache**.
+
+What differs from ``models/llama.py``, which dispatches here on
+``cfg.latent_kv`` (same signatures, same ``ChunkOutput``, so the engine, the
+batcher and the cache manager serve this model as they serve the others):
+
+- **The cache** is one pool ``{"ckv": [L, N, Bk, W]}``: a token's normed KV
+  latent and its one shared, rotated rope key, no head axis: 576 values,
+  ``L x 576 x 2`` bytes a token at the published widths, in rows of ``W`` =
+  640 lanes (``pool_width``: whole 128-lane tiles, as the chip stores them
+  anyway). It is written and read in place in the stacked pool by layer
+  index (``ragged_kv_path`` reads ``in_place``).
+- **Attention has two forms of the same numbers.** *Expanded*: the cached
+  latents are up-projected through ``W_UK`` / ``W_UV`` into per-head keys
+  and values. *Absorbed*: the query is folded into the latent space
+  (``q~ = q_n W_UK^T``), all heads share the one 576-wide key, and the
+  result is lifted by ``W_UV``. The XLA path (the CPU, ``pallas=False``)
+  takes expanded for a multi-token chunk and absorbed for one token a row;
+  the kernel path (``ops/mla_attention_pallas.py``) is the absorbed form for
+  both, since at the 256-token pieces the engine cuts a prompt into,
+  re-expanding a growing prefix for every piece costs what absorbing the
+  piece's queries costs and needs ``[ctx, heads, 256]`` temporaries.
+- **Layers are described per layer**: ``params["dense_layers"]`` stacks the
+  ``cfg.first_k_dense`` leading layers (dense SwiGLU), ``params["layers"]``
+  the rest (router over all ``num_experts``, the ``held_experts`` this chip
+  stores, a shared expert). Two scans, one layer body.
+- **The expert layer computes the chip's share**: sigmoid scores over all
+  published experts, top-k kept, normalised and scaled as published; pairs that fall on experts held elsewhere are routed nowhere,
+  and nothing stands in for them.
+"""
+
+from __future__ import annotations
+
+import functools
+import zlib
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from distributed_gpu_inference_tpu.models.configs import ModelConfig
+from distributed_gpu_inference_tpu.ops.quantization import (
+    matmul as qmm,
+    matmul_stacked,
+    split_stacked_quant,
+)
+
+Params = Dict[str, Any]
+POOL = "ckv"
+_NEG_INF = -1e30
+# norm vectors are drawn around one, not set to it: four norms a layer are
+# otherwise interchangeable and a misplaced one is invisible
+_NORM_SPREAD = 0.25
+
+
+_LANES = 128
+
+
+def latent_width(cfg: ModelConfig) -> int:
+    """Values cached a token a layer: the latent and the rope key."""
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def pool_width(cfg: ModelConfig) -> int:
+    """Lanes of a pool row: ``latent_width`` rounded up to whole 128-lane
+    tiles, the pad lanes zero. A TPU array is tiled in 128 lanes (a
+    576-wide one takes 640 in HBM whatever its shape says), and a page DMA
+    must cover whole tiles, so the pool says what it takes."""
+    return -(-latent_width(cfg) // _LANES) * _LANES
+
+
+def layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, int], ...]:
+    """(params key, layers) of the homogeneous stacks, in layer order."""
+    lead = cfg.first_k_dense if cfg.num_experts else 0
+    groups = (("dense_layers", lead), ("layers", cfg.num_layers - lead))
+    return tuple(g for g in groups if g[1])
+
+
+def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]]:
+    """name → (shape of ONE layer, fan-in, kind) for a group's leaves.
+    kind: ``q`` a matmul weight (quantized where the engine quantizes),
+    ``d`` a dense bf16 weight, ``n`` a norm vector."""
+    h, nh = cfg.hidden_size, cfg.num_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    spec = {
+        "attn_norm": ((h,), 0, "n"),
+        "wq_a": ((h, rq), h, "q"),
+        "q_a_norm": ((rq,), 0, "n"),
+        "wq_b": ((rq, nh * (dn + dr)), rq, "q"),
+        "wkv_a": ((h, rkv + dr), h, "q"),
+        "kv_a_norm": ((rkv,), 0, "n"),
+        # W_kvb split a head into W_UK and W_UV; bf16: the absorbed products
+        # are batched over heads, not the int8 kernel's shape
+        "w_uk": ((nh, rkv, dn), rkv, "d"),
+        "w_uv": ((nh, rkv, dv), rkv, "d"),
+        "wo": ((nh * dv, h), nh * dv, "q"),
+        "mlp_norm": ((h,), 0, "n"),
+    }
+    if cfg.sandwich_norm:
+        spec["post_attn_norm"] = ((h,), 0, "n")
+        spec["post_mlp_norm"] = ((h,), 0, "n")
+    if group == "layers" and cfg.num_experts:
+        mi, held = cfg.moe_intermediate_size, cfg.num_held_experts
+        spec.update({
+            "w_router": ((h, cfg.num_experts), h, "d"),
+            "we_gate": ((held, h, mi), h, "q"),
+            "we_up": ((held, h, mi), h, "q"),
+            "we_down": ((held, mi, h), mi, "q"),
+        })
+        if cfg.n_shared_experts:
+            ms = mi * cfg.n_shared_experts
+            spec.update({
+                "ws_gate": ((h, ms), h, "q"),
+                "ws_up": ((h, ms), h, "q"),
+                "ws_down": ((ms, h), ms, "q"),
+            })
+    else:
+        i = cfg.intermediate_size
+        spec.update({
+            "w_gate": ((h, i), h, "q"),
+            "w_up": ((h, i), h, "q"),
+            "w_down": ((i, h), i, "q"),
+        })
+    return spec
+
+
+def name_key(root: jax.Array, name: str) -> jax.Array:
+    """The key a named leaf is drawn from (stable across processes)."""
+    return jax.random.fold_in(root, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+
+
+def draw_leaf(key: jax.Array, shape: tuple, fan_in: int, kind: str
+              ) -> jax.Array:
+    """ONE layer's float32 draw of a leaf: what both the program's init and
+    the benchmark's reference (``harness/reference_mla_moe.py``) round."""
+    x = jax.random.normal(key, shape, jnp.float32)
+    if kind == "n":
+        return 1.0 + _NORM_SPREAD * x
+    return x * (fan_in ** -0.5)
+
+
+def init_params(
+    cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = None,
+    mode: Optional[str] = None,
+) -> Params:
+    """Random weights, a leaf a layer at a time (one float32 layer slice
+    live): the same draws whether kept in ``dtype`` or, with ``mode``,
+    quantized as the engine quantizes (``ops.quantization.quantize_weight``)
+    — so a model whose full-precision tree outgrows the chip starts the way
+    a small one does."""
+    from distributed_gpu_inference_tpu.ops.quantization import quantize_weight
+
+    dtype = jnp.dtype(dtype or cfg.dtype)
+    h, v = cfg.hidden_size, cfg.vocab_size
+
+    @functools.lru_cache(maxsize=None)
+    def gen(shape, fan_in, kind):
+        def body(carry, k):
+            w = draw_leaf(k, shape, fan_in, kind)
+            if kind == "q" and mode is not None:
+                q = quantize_weight(w, mode)
+                return carry, (q["qw"], q["scale"])
+            return carry, w.astype(dtype)
+
+        return jax.jit(lambda keys: lax.scan(body, 0, keys)[1])
+
+    params: Params = {}
+    for group, n in layer_groups(cfg):
+        leaves: Dict[str, Any] = {}
+        for name, (shape, fan_in, kind) in leaf_specs(cfg, group).items():
+            keys = jax.random.split(name_key(key, f"{group}.{name}"), n)
+            out = gen(shape, fan_in, kind)(keys)
+            if isinstance(out, tuple):
+                out = {"qw": out[0], "scale": out[1]}
+            jax.block_until_ready(out)   # bound the float32 slice's life
+            leaves[name] = out
+        params[group] = leaves
+    for name in ("embedding",) + (
+            () if cfg.tie_word_embeddings else ("lm_head",)):
+        params[name] = draw_leaf(
+            name_key(key, name), (v, h), h, "d").astype(dtype)
+    params["final_norm"] = draw_leaf(
+        name_key(key, "final_norm"), (h,), 0, "n").astype(dtype)
+    return params
+
+
+def init_kv_pools(cfg: ModelConfig, num_blocks: int, block_size: int = 16,
+                  dtype: Optional[jnp.dtype] = None) -> Dict[str, jax.Array]:
+    """The latent paged pool ``[L, N, Bk, W]``; block 0 is the pad block.
+    A one-byte float dtype (fp8) stores narrower rows; int8 with scales is
+    not built."""
+    dtype = jnp.dtype(dtype or cfg.dtype)
+    if dtype == jnp.int8:
+        raise NotImplementedError("int8 latent pools (scaled) are not built")
+    return {POOL: jnp.zeros(
+        (cfg.num_layers, num_blocks, block_size, pool_width(cfg)), dtype)}
+
+
+# ---------------------------------------------------------------------------
+# attention over the latent pool: the XLA forms
+# ---------------------------------------------------------------------------
+
+
+def _visible(positions: jax.Array, kv_lens: jax.Array, ctx: int) -> jax.Array:
+    key_pos = jnp.arange(ctx, dtype=jnp.int32)[None, None, :]
+    return (positions[:, :, None] >= key_pos) \
+        & (key_pos < kv_lens[:, None, None])                    # [B, S, J]
+
+
+def _softmax_rows(scores: jax.Array, visible: jax.Array) -> jax.Array:
+    """Float32 softmax over the last axis; a query that sees nothing (a pad)
+    gives zeros. scores [B, H, S, J], visible [B, S, J]."""
+    scores = jnp.where(visible[:, None], scores, _NEG_INF)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    p = jnp.where(visible[:, None], jnp.exp(scores - m), 0.0)
+    denom = jnp.sum(p, axis=-1, keepdims=True)
+    return p / jnp.where(denom > 0, denom, 1.0)
+
+
+def latent_attention_xla(
+    cfg: ModelConfig,
+    q_n: jax.Array,            # [B, S, Nh, dn] nope half of the queries
+    q_r: jax.Array,            # [B, S, Nh, dr] rotated rope half
+    w_uk: jax.Array,           # [Nh, rkv, dn]
+    w_uv: jax.Array,           # [Nh, rkv, dv]
+    ctx: jax.Array,            # [B, J, W] the rows' cached (latent ; rope ;
+                               # pad lanes)
+    positions: jax.Array,      # [B, S] int32, -1 = pad
+    kv_lens: jax.Array,        # [B]
+    form: str,                 # "expanded" | "absorbed"
+) -> jax.Array:
+    """Both forms of the one attention, in float32 → [B, S, Nh, dv]."""
+    f32 = jnp.float32
+    rkv = cfg.kv_lora_rank
+    c = ctx[..., :rkv].astype(f32)
+    k_r = ctx[..., rkv:rkv + cfg.qk_rope_head_dim].astype(f32)
+    q_n, q_r = q_n.astype(f32), q_r.astype(f32)
+    w_uk, w_uv = w_uk.astype(f32), w_uv.astype(f32)
+    scale = cfg.head_dim ** -0.5
+    visible = _visible(positions, kv_lens, ctx.shape[1])
+    rope_scores = jnp.einsum("bshr,bjr->bhsj", q_r, k_r)
+    if form == "expanded":
+        k_n = jnp.einsum("bjc,hcd->bjhd", c, w_uk)
+        v = jnp.einsum("bjc,hcd->bjhd", c, w_uv)
+        scores = jnp.einsum("bshd,bjhd->bhsj", q_n, k_n) + rope_scores
+        p = _softmax_rows(scores * scale, visible)
+        return jnp.einsum("bhsj,bjhd->bshd", p, v)
+    q_abs = jnp.einsum("bshd,hcd->bshc", q_n, w_uk)
+    scores = jnp.einsum("bshc,bjc->bhsj", q_abs, c) + rope_scores
+    p = _softmax_rows(scores * scale, visible)
+    u = jnp.einsum("bhsj,bjc->bshc", p, c)
+    return jnp.einsum("bshc,hcd->bshd", u, w_uv)
+
+
+def kernels_on(cfg: ModelConfig, padded_ctx: int, pool_dtype,
+               pallas: bool = True) -> bool:
+    """Trace-time choice of the latent kernels (``ops/mla_attention_pallas``),
+    from what dispatch can see: a TPU backend, the caller's ``pallas`` (no
+    mesh), a two-byte pool whose latent is lane-aligned, a padded context of
+    at least the crossover the K/V kernels use."""
+    from distributed_gpu_inference_tpu.ops import attention as _attention
+
+    return (
+        pallas and _attention.pallas_backend()
+        and jnp.dtype(pool_dtype).itemsize == 2
+        and cfg.kv_lora_rank % 128 == 0
+        and padded_ctx >= _attention._PALLAS_MIN_PADDED_CTX
+    )
+
+
+# ---------------------------------------------------------------------------
+# the expert layer: the chip's share
+# ---------------------------------------------------------------------------
+
+
+def route(cfg: ModelConfig, x: jax.Array, w_router: jax.Array
+          ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores over ALL published experts in float32, the top-k
+    kept (no groups, no selection bias), normalised and scaled → (weights
+    [T, k], experts [T, k])."""
+    scores = jax.nn.sigmoid(
+        x.astype(jnp.float32) @ w_router.astype(jnp.float32))
+    topv, topi = lax.top_k(scores, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+    return topv * cfg.routed_scaling_factor, topi
+
+
+def _experts(
+    x: jax.Array, lp: Dict[str, Any], cfg: ModelConfig, proj, *,
+    live: Optional[jax.Array], stacked: Optional[Dict[str, Any]],
+    layer_idx: Any,
+) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """``E_shared(m) + sum over the kept experts held HERE of w_e E_e(m)``,
+    the counters of the routed part, and every token's experts ``[T, k]``.
+    The routed part is ``models/llama._routed_sum`` over the pairs that
+    fell on held experts."""
+    from distributed_gpu_inference_tpu.models.llama import (
+        _mlp_act, _routed_sum,
+    )
+    from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
+
+    b, s, h = x.shape
+    t, k = b * s, cfg.num_experts_per_tok
+    act = _mlp_act(cfg.activation)
+    xf = x.reshape(t, h)
+    topv, topi = route(cfg, xf, lp["w_router"])
+    first, count = cfg.held_experts or (0, cfg.num_experts)
+    local = topi - first
+    live = jnp.ones((t,), bool) if live is None else live.reshape(t)
+    held = live[:, None] & (local >= 0) & (local < count)         # [T, k]
+    out, plan = _routed_sum(
+        xf, lp, topv, jnp.clip(local, 0, count - 1), held, count,
+        max(t * k * count // cfg.num_experts, 1), act,
+        stacked=stacked, layer_idx=layer_idx, decode=s == 1)
+    if "ws_gate" in lp or (stacked is not None and "ws_gate" in stacked):
+        shared = proj(act(proj(x, "ws_gate")) * proj(x, "ws_up"), "ws_down")
+        out = out + shared.reshape(t, h).astype(jnp.float32)
+    stats = moe_gmm.expert_stats(plan)
+    stats["pairs_routed"] = jnp.sum(live, dtype=jnp.int32) * k
+    return out.reshape(b, s, h).astype(x.dtype), stats, topi
+
+
+# ---------------------------------------------------------------------------
+# the layer and the forward pass
+# ---------------------------------------------------------------------------
+
+
+def _layer_step(
+    cfg: ModelConfig, block_size: int, carry, lp: Dict[str, Any], *,
+    block_tables, write_positions, kv_lens, cos, sin, stacked, pallas,
+    kernels, unpack, tiles, moe_live, emit_routing, write_plan, pool_offset,
+):
+    from distributed_gpu_inference_tpu.models.llama import (
+        _mlp, apply_rope, rms_norm,
+    )
+
+    hidden, pool, layer_idx = carry
+    b, s, _ = hidden.shape
+    nh, rkv = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    eps = cfg.rms_norm_eps
+    pool_layer = layer_idx + pool_offset
+
+    def proj(x_, name):
+        if stacked is not None and name in stacked:
+            return matmul_stacked(x_, stacked[name], layer_idx, pallas)
+        return qmm(x_, lp[name], pallas)
+
+    with jax.named_scope("dgi_attention"):
+        x = rms_norm(hidden, lp["attn_norm"], eps)
+        c_q = rms_norm(proj(x, "wq_a"), lp["q_a_norm"], eps)
+        q = proj(c_q, "wq_b").reshape(b, s, nh, dn + dr)
+        q_n, q_r = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+        ckr = proj(x, "wkv_a")
+        c = rms_norm(ckr[..., :rkv], lp["kv_a_norm"], eps)
+        k_r = apply_rope(ckr[..., None, rkv:], cos, sin)[..., 0, :]
+        pad = jnp.zeros((b, s, pool.shape[-1] - rkv - dr), c.dtype)
+        new_rows = jnp.concatenate([c, k_r, pad], axis=-1).astype(pool.dtype)
+        positions = write_positions
+        if unpack is not None and not kernels:
+            # the XLA path attends over the [B, S] rectangle; the kernels
+            # take the packed axis as it is (page write) or as query tiles
+            to_rect, tok_row, tok_col = unpack
+
+            def rectangle(t_):
+                return jnp.take(t_[0], to_rect, axis=0, mode="fill",
+                                fill_value=0)
+
+            q_n, q_r, new_rows = (rectangle(q_n), rectangle(q_r),
+                                  rectangle(new_rows))
+        if kernels:
+            from distributed_gpu_inference_tpu.ops import (
+                mla_attention_pallas as mla_k,
+            )
+
+            pool = mla_k.write_latent_pages_in_place(
+                new_rows.reshape(-1, new_rows.shape[-1]), pool, pool_layer,
+                write_plan)
+            q_abs = jnp.einsum("bshd,hcd->bshc", q_n, lp["w_uk"],
+                               preferred_element_type=jnp.float32)
+            q_cat = jnp.concatenate(
+                [q_abs.astype(pool.dtype), q_r.astype(pool.dtype),
+                 jnp.zeros((*q_r.shape[:3], pool.shape[-1] - rkv - dr),
+                           pool.dtype)], axis=-1)
+            common = dict(scale=cfg.head_dim ** -0.5, latent=rkv)
+            if tiles is not None:
+                u = mla_k.latent_paged_attention_packed(
+                    q_cat[0], tiles, pool, pool_layer, block_tables,
+                    kv_lens, block_size, **common)[None]
+            else:
+                u = mla_k.latent_paged_attention(
+                    q_cat, pool, pool_layer, block_tables, positions,
+                    kv_lens, block_size, decode=s == 1, **common)
+            attn = jnp.einsum("bshc,hcd->bshd", u, lp["w_uv"],
+                              preferred_element_type=jnp.float32)
+        else:
+            from distributed_gpu_inference_tpu.models.llama import (
+                _page_scatter_indices,
+            )
+
+            n_blocks = pool.shape[1]
+            phys, slot = _page_scatter_indices(
+                n_blocks, block_tables, positions, block_size)
+            # straight into the stacked pool: no layer slice, no write-back
+            pool = pool.at[pool_layer, phys, slot].set(
+                new_rows.reshape(-1, new_rows.shape[-1]), mode="drop")
+            ctx = pool[pool_layer, block_tables].reshape(
+                block_tables.shape[0], -1, pool.shape[-1])
+            attn = latent_attention_xla(
+                cfg, q_n, q_r, lp["w_uk"], lp["w_uv"], ctx, positions,
+                kv_lens, "absorbed" if positions.shape[1] == 1
+                else "expanded",
+            )
+            if unpack is not None:
+                attn = attn.at[tok_row, tok_col].get(mode="fill",
+                                                     fill_value=0)
+        attn = attn.astype(hidden.dtype)
+        attn = proj(attn.reshape(b, s, nh * dv), "wo").astype(hidden.dtype)
+        if "post_attn_norm" in lp:
+            attn = rms_norm(attn, lp["post_attn_norm"], eps)
+        hidden = hidden + attn
+    routed = "w_router" in lp
+    with jax.named_scope("dgi_experts" if routed else "dgi_mlp"):
+        m = rms_norm(hidden, lp["mlp_norm"], eps)
+        stats = routing = None
+        if routed:
+            out, stats, routing = _experts(
+                m, lp, cfg, proj, live=moe_live, stacked=stacked,
+                layer_idx=layer_idx)
+        else:
+            out = _mlp(m, proj, cfg.activation)
+        if "post_mlp_norm" in lp:
+            out = rms_norm(out, lp["post_mlp_norm"], eps)
+        hidden = hidden + out
+    return (hidden, pool, layer_idx + 1), (
+        stats, routing if emit_routing else None)
+
+
+def forward_chunk(
+    cfg: ModelConfig, params: Params, token_ids, positions, kv, block_tables,
+    kv_lens, *, block_size: int = 16, last_only: bool = True,
+    with_logits: bool = True, collect_routing: bool = False,
+    pallas: bool = True, packing=None,
+):
+    """``models/llama.forward_chunk`` for a latent-attention model (its
+    docstring holds the contract of every argument; sequence-parallel
+    attention, attention overrides and feature collection are not this
+    model's)."""
+    from distributed_gpu_inference_tpu.models import llama
+
+    pool = kv[POOL]
+    unpack = to_rect = tp = None
+    if packing is not None:
+        tp = token_ids.shape[0]
+        rect = (block_tables.shape[0], packing.width)
+        at = (packing.row, packing.col)
+        to_rect = jnp.full(rect, tp, jnp.int32).at[at].set(
+            jnp.arange(tp, dtype=jnp.int32), mode="drop")
+        unpack = (to_rect, *at)
+        token_ids, rope_positions = token_ids[None], positions[None]
+        positions = jnp.full(rect, -1, jnp.int32).at[at].set(
+            positions, mode="drop")
+    else:
+        rope_positions = positions
+    kernels = kernels_on(
+        cfg, block_tables.shape[1] * block_size, pool.dtype, pallas)
+    write_plan = tiles = None
+    if kernels:
+        from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+            page_write_plan,
+        )
+
+        if packing is not None:
+            from distributed_gpu_inference_tpu.ops.mla_attention_pallas import (
+                packed_tiles,
+            )
+
+            tiles = packed_tiles(
+                packing.row, packing.col, rope_positions[0],
+                block_tables.shape[0], packing.width, cfg.num_heads)
+
+        write_plan = page_write_plan(
+            block_tables, positions, block_size,
+            page_bytes=pool.shape[2] * pool.shape[3] * pool.dtype.itemsize,
+            token_index=to_rect, num_tokens=tp,
+        )
+    hidden = llama.embed_tokens(params, token_ids, cfg)
+    cos, sin = llama._rope_angles(
+        jnp.maximum(rope_positions, 0), cfg.qk_rope_head_dim, cfg.rope_theta)
+
+    moe = None
+    routes = []
+    offset = 0
+    for group, n in layer_groups(cfg):
+        scanned, stacked = _split_group(params[group], pallas)
+        step = functools.partial(
+            _layer_step, cfg, block_size,
+            block_tables=block_tables, write_positions=positions,
+            kv_lens=kv_lens, cos=cos, sin=sin, stacked=stacked,
+            pallas=pallas, kernels=kernels, unpack=unpack, tiles=tiles,
+            moe_live=rope_positions >= 0, emit_routing=collect_routing,
+            write_plan=write_plan, pool_offset=offset,
+        )
+        (hidden, pool, _), (stats, routing) = lax.scan(
+            lambda c, lp: step(c, lp), (hidden, pool, jnp.int32(0)), scanned)
+        if stats is not None:
+            moe = {name: jnp.sum(v) for name, v in stats.items()}
+        if routing is not None:
+            routes.append(routing)
+        offset += n
+    new_kv = {POOL: pool}
+    routing = jnp.concatenate(routes, axis=0) if routes else None
+    if not with_logits:
+        return llama.ChunkOutput(hidden=hidden, kv=new_kv, logits=None,
+                                 moe=moe, routing=routing)
+    if last_only and packing is not None:
+        logits_in = jnp.take(hidden[0], packing.last, axis=0)[:, None]
+    elif last_only:
+        n_valid = jnp.sum((positions >= 0).astype(jnp.int32), axis=1)
+        logits_in = jnp.take_along_axis(
+            hidden, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)
+    else:
+        logits_in = hidden
+    with jax.named_scope("dgi_head"):
+        logits = llama.project_logits(cfg, params, logits_in)
+    return llama.ChunkOutput(hidden=hidden, kv=new_kv, logits=logits,
+                             moe=moe, routing=routing)
+
+
+def _split_group(layers: Dict[str, Any], pallas: bool):
+    """``split_stacked_quant`` for one group: the expert weights stay whole
+    too where the grouped-matmul kernel will take them."""
+    if not pallas or "we_gate" not in layers:
+        return split_stacked_quant(layers)
+    from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
+
+    return split_stacked_quant(layers, experts=moe_gmm.kernel_ok(layers))

@@ -1,0 +1,472 @@
+"""Pallas TPU kernels over the latent (MLA) paged pool.
+
+The pool is ``[L, N, Bk, W]``: a token's normed KV latent (``latent`` wide)
+and its shared rotated rope key side by side, no head axis
+(``models/mla.py``). Both kernels take the STACKED pool and a layer index,
+as the K/V kernels do (``ops/paged_attention_pallas.py`` says why: a layer
+slice as a custom-call operand is a copy of the layer).
+
+- :func:`write_latent_pages_in_place` (``dgi_mla_write``): a chunk's rows
+  into their pages, the pool aliased to the output. The K/V page write with
+  one pool; the plan is the same ``page_write_plan``.
+- :func:`latent_paged_attention` (``dgi_mla_decode`` for one token a row:
+  a scan step; ``dgi_mla_ragged`` for a round's mixed rows): **absorbed**
+  attention. Every head's query, already folded into the latent space and
+  concatenated with its rope half, meets the ONE ``W``-wide key of a cached
+  token, so the heads are the rows of a single matmul against a page group
+  and a page is read once for all of them; the value is the key's first
+  ``latent`` lanes. 2 x heads x (W + latent) FLOP a cached token against
+  ``W x 2`` bytes: about the chip's ridge at 128 heads, neither the
+  memory-bound decode kernel nor the compute-bound prefill kernel.
+  The walk over live page groups, the double-buffered page DMAs and the
+  per-query causal mask are the ragged K/V kernel's.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+    PageWritePlan,
+)
+
+_NEG_INF = -1e30
+# fixed names: the custom calls' names on a device trace's XLA Ops line
+DECODE_KERNEL_NAME = "dgi_mla_decode"
+RAGGED_KERNEL_NAME = "dgi_mla_ragged"
+WRITE_KERNEL_NAME = "dgi_mla_write"
+# ceiling on (heads) x (query tile): the rows of the score tile and of the
+# float32 accumulator a grid cell carries (1024 x 512 x 4 B = 2 MiB)
+_HEAD_ROWS = 1024
+# a full tile's blocks, score tile and accumulator take about 18 MiB: over
+# the compiler's default scoped limit (16 MiB), a fraction of the 128 MiB a
+# v5e core has
+_VMEM_LIMIT_BYTES = 40 * 1024 * 1024
+# tokens a page group stages (two slots of [group, W] in the pool dtype)
+_GROUP_TOKENS = 512
+
+
+def _page_write_kernel(page_ref, kind_ref, slots_ref, layer_ref,
+                       new_ref, _pool_in, pool_hbm, stage, sems, *,
+                       tile: int, words: int):
+    """One grid step writes ``tile`` cells (pages a row's span touches): a
+    page written whole goes out as its update block, a page written in part
+    is staged, blended by slot mask and written back whole
+    (``ops/paged_attention_pallas._page_write_kernel``, one pool)."""
+    base = pl.program_id(0) * tile
+    layer = layer_ref[0]
+    _, bk, w = stage.shape
+
+    def copy(j, read):
+        src, dst = pool_hbm.at[layer, page_ref[base + j]], stage.at[j]
+        if not read:
+            src, dst = dst, src
+        return pltpu.make_async_copy(src, dst, sems.at[j])
+
+    def start_reads(j, carry):
+        @pl.when(kind_ref[base + j] == 1)
+        def _():
+            copy(j, True).start()
+
+        return carry
+
+    def blend(j, carry):
+        kind = kind_ref[base + j]
+
+        @pl.when(kind == 1)
+        def _():
+            copy(j, True).wait()
+
+        @pl.when(kind != 0)
+        def _():
+            slot = lax.broadcasted_iota(jnp.int32, (bk, w), 0)
+            sel = None
+            for wd in range(words):
+                hit = (jnp.right_shift(
+                    slots_ref[(base + j) * words + wd], (slot - 32 * wd) & 31
+                ) & 1) == 1
+                if words > 1:
+                    hit &= (slot >= 32 * wd) & (slot < 32 * (wd + 1))
+                sel = hit if sel is None else sel | hit
+            stage[j] = jnp.where(sel, new_ref[j], stage[j])
+            copy(j, False).start()
+
+        return carry
+
+    def wait_writes(j, carry):
+        @pl.when(kind_ref[base + j] != 0)
+        def _():
+            copy(j, False).wait()
+
+        return carry
+
+    lax.fori_loop(0, tile, start_reads, 0)
+    lax.fori_loop(0, tile, blend, 0)
+    lax.fori_loop(0, tile, wait_writes, 0)
+
+
+def write_latent_pages_in_place(
+    new_rows: jax.Array,      # [T, W] the chunk's rows, one flat axis
+    pool: jax.Array,          # [L, N, Bk, W] stacked latent pool
+    layer_idx: jax.Array,     # scalar int32
+    plan: PageWritePlan,
+    interpret: bool = False,
+) -> jax.Array:
+    """Write a chunk's rows into layer ``layer_idx`` of the stacked pool, in
+    place → pool. The bytes after the call are what a scatter of the rows
+    leaves: other layers, other pages and the unwritten slots of written
+    pages keep theirs."""
+    _, _, bk, w = pool.shape
+    cells = plan.page.shape[0]
+    tile = plan.tile
+    words = plan.slots.shape[0] // cells
+    pages = jnp.take(new_rows, plan.src, axis=0, mode="fill", fill_value=0) \
+        .reshape(cells, bk, w).astype(pool.dtype)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(cells // tile,),
+        in_specs=[
+            pl.BlockSpec((tile, bk, w), lambda i, *_refs: (i, 0, 0),
+                         memory_space=pltpu.VMEM),
+            hbm,
+        ],
+        out_specs=hbm,
+        scratch_shapes=[
+            pltpu.VMEM((tile, bk, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((tile,)),
+        ],
+    )
+    # operands: 4 scalar-prefetch args, the update array, the pool (idx 5)
+    return pl.pallas_call(
+        functools.partial(_page_write_kernel, tile=tile, words=words),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        grid_spec=grid_spec,
+        input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=WRITE_KERNEL_NAME,
+    )(
+        plan.page, plan.kind, plan.slots,
+        jnp.asarray(layer_idx, jnp.int32).reshape(1), pages, pool,
+    )
+
+
+def _attention_kernel(
+    # scalar prefetch (SMEM; bidx/init persist across the sequential grid)
+    bt_ref,        # [B, M] int32 per-sequence block tables
+    lens_ref,      # [B] int32 kv length per sequence
+    seq_ref,       # [R] int32 the sequence each query tile belongs to
+    qmax_ref,      # [R] int32 max valid query position (-1 = inactive tile)
+    layer_ref,     # [1] int32 layer index into the stacked pool
+    bidx_ref,      # [1] int32 current double-buffer slot
+    init_ref,      # [1] int32 1 until the first live chunk issues its DMA
+    # blocked operands
+    q_ref,         # [1, Nh*T, W] this tile's absorbed queries, head-major
+    pos_ref,       # [1, Nh*T, 1] int32 per-query positions (-1 = pad)
+    pool_hbm,      # [L, N, Bk, W]
+    out_ref,       # [1, Nh*T, latent]
+    buf,           # [2, G, Bk, W] page staging
+    sems,          # DMA [2, G]
+    m_scr, l_scr,  # [Nh*T, 1] float32 softmax state
+    acc_scr,       # [Nh*T, latent] float32
+    *, rows: int, block_size: int, pages_per_group: int,
+    max_pages: int, scale: float, latent: int,
+):
+    r = pl.program_id(0)
+    i = pl.program_id(1)
+    gp = pages_per_group
+    gsz = gp * block_size
+    layer = layer_ref[0]
+    max_groups = pl.num_programs(1)
+
+    def num_groups(s_):
+        s_ = jnp.clip(s_, 0, rows - 1)
+        needed = jnp.minimum(qmax_ref[s_] + 1, lens_ref[seq_ref[s_]])
+        # clamped to the grid: a length past the table must not leave a
+        # prefetched DMA un-waited at kernel exit
+        return jnp.minimum(pl.cdiv(needed, gsz), max_groups)
+
+    ng_r = num_groups(r)
+    live = i < ng_r
+
+    def page_copy(s_, j, slot, p):
+        idx = jnp.minimum(j * gp + p, max_pages - 1)
+        page = bt_ref[seq_ref[jnp.clip(s_, 0, rows - 1)], idx]
+        return pltpu.make_async_copy(
+            pool_hbm.at[layer, page], buf.at[slot, p], sems.at[slot, p])
+
+    def start_dma(s_, j, slot):
+        def body(p, carry):
+            page_copy(s_, j, slot, p).start()
+            return carry
+
+        lax.fori_loop(0, gp, body, 0, unroll=True)
+
+    def wait_dma(s_, j, slot):
+        def body(p, carry):
+            page_copy(s_, j, slot, p).wait()
+            return carry
+
+        lax.fori_loop(0, gp, body, 0, unroll=True)
+
+    def next_chunk(s_, j):
+        """Grid-order successor of live chunk (s_, j): the next group of the
+        tile, else the first group of the next tile that has any."""
+
+        def advance_row():
+            def step(_, ss):
+                return jnp.where(
+                    (ss < rows) & (num_groups(ss) == 0), ss + 1, ss)
+
+            return lax.fori_loop(0, rows, step, s_ + 1), jnp.int32(0)
+
+        return lax.cond(
+            j + 1 < num_groups(s_), lambda: (s_, j + 1), advance_row)
+
+    @pl.when((ng_r == 0) & (i == 0))
+    def _():
+        out_ref[0] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
+
+    @pl.when(live)
+    def _():
+        slot = bidx_ref[0]
+
+        @pl.when(init_ref[0] == 1)
+        def _():
+            start_dma(r, i, slot)
+
+        init_ref[0] = 0
+        nr, ni = next_chunk(r, i)
+
+        @pl.when(nr < rows)
+        def _():
+            start_dma(nr, ni, 1 - slot)
+
+        bidx_ref[0] = 1 - slot
+        wait_dma(r, i, slot)
+
+        @pl.when(i == 0)
+        def _():
+            m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
+            l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+            acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        kv_len = lens_ref[seq_ref[r]]
+        kv = buf[slot].reshape(gsz, buf.shape[-1])            # [gsz, W]
+        # every head against the one key: [Nh*T, W] x [gsz, W]^T
+        scores = lax.dot_general(
+            q_ref[0], kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        col = i * gsz + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        pos = pos_ref[0]                                       # [Nh*T, 1]
+        valid = (col < kv_len) & (col <= pos)
+        scores = jnp.where(valid, scores, _NEG_INF)
+        m_prev, l_prev = m_scr[...], l_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        probs = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
+        l_new = l_prev * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        # the value is the key's latent lanes (a lane-aligned slice)
+        acc_new = acc_scr[...] * alpha + lax.dot_general(
+            probs.astype(kv.dtype), kv[:, :latent],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        m_scr[...], l_scr[...], acc_scr[...] = m_new, l_new, acc_new
+
+        @pl.when(i == ng_r - 1)
+        def _():
+            out = jnp.where(
+                l_new > 0, acc_new / jnp.where(l_new > 0, l_new, 1.0), 0.0)
+            out_ref[0] = out.astype(out_ref.dtype)
+
+
+def _q_tile(s: int, nh: int) -> int:
+    t = max(1, min(s, _HEAD_ROWS // max(nh, 1)))
+    return 1 << (t.bit_length() - 1)
+
+
+def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
+                  block_tables, kv_lens, *, block_size, scale, latent, name,
+                  interpret):
+    """The kernel over query tiles: ``q_tiles [R, T, Nh, W]`` (``T``
+    consecutive queries of ONE sequence a tile, ``tile_seq [R]`` says
+    which), ``pos_tiles [R, T]`` their positions (-1 = no query) →
+    ``[R, T, Nh, latent]``."""
+    rows, t, nh, w = q_tiles.shape
+    m = block_tables.shape[1]
+    # [R, T, Nh, W] → [R, Nh*T, W], the query index fastest inside a head
+    q_r = q_tiles.transpose(0, 2, 1, 3).reshape(rows, nh * t, w) \
+        .astype(pool.dtype)
+    pos_r = pos_tiles.astype(jnp.int32)
+    pos_q = jnp.tile(pos_r, (1, nh))[:, :, None]
+    gp = max(1, min(_GROUP_TOKENS // block_size, m))
+
+    def tile_spec(width):
+        return pl.BlockSpec((1, nh * t, width), lambda i, j, *_refs: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7,
+        grid=(rows, -(-m // gp)),
+        in_specs=[tile_spec(w), tile_spec(1),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=tile_spec(latent),
+        scratch_shapes=[
+            pltpu.VMEM((2, gp, block_size, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, gp)),
+            pltpu.VMEM((nh * t, 1), jnp.float32),
+            pltpu.VMEM((nh * t, 1), jnp.float32),
+            pltpu.VMEM((nh * t, latent), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _attention_kernel, rows=rows, block_size=block_size,
+        pages_per_group=gp, max_pages=m, scale=scale, latent=latent,
+    )
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, nh * t, latent), q_tiles.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name=name,
+    )(
+        block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
+        tile_seq.astype(jnp.int32), jnp.max(pos_r, axis=1),
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
+        jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
+        q_r, pos_q, pool,
+    )
+    return out.reshape(rows, nh, t, latent).transpose(0, 2, 1, 3)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "scale", "latent", "decode", "interpret"),
+)
+def latent_paged_attention(
+    q: jax.Array,             # [B, S, Nh, W] absorbed queries (q~ ; q_r)
+    pool: jax.Array,          # [L, N, Bk, W] stacked latent pool
+    layer_idx: jax.Array,     # scalar int32
+    block_tables: jax.Array,  # [B, M] int32
+    positions: jax.Array,     # [B, S] int32 (-1 = pad)
+    kv_lens: jax.Array,       # [B] int32
+    block_size: int = 16,
+    *,
+    scale: float,
+    latent: int,
+    decode: bool = False,
+    interpret: bool = False,
+) -> jax.Array:
+    """Absorbed attention of ``S`` queries a row against the row's cached
+    latents → ``[B, S, Nh, latent]`` (the caller lifts it through ``W_UV``).
+    Masking is the XLA form's (``models/mla.latent_attention_xla``): a query
+    at position p sees cached positions ``j <= p`` inside ``kv_lens``, a
+    padded query gives zeros. ``decode`` only names the kernel."""
+    b, s, nh, w = q.shape
+    _check(pool, block_size, w, latent, interpret)
+    t = _q_tile(s, nh)
+    s_pad = -(-s // t) * t
+    if s_pad != s:
+        q = jnp.pad(q, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
+        positions = jnp.pad(
+            positions, ((0, 0), (0, s_pad - s)), constant_values=-1)
+    qt = s_pad // t
+    out = _attend_tiles(
+        q.reshape(b * qt, t, nh, w), positions.reshape(b * qt, t),
+        jnp.arange(b * qt, dtype=jnp.int32) // qt, pool, layer_idx,
+        block_tables, kv_lens, block_size=block_size, scale=scale,
+        latent=latent, interpret=interpret,
+        name=DECODE_KERNEL_NAME if decode else RAGGED_KERNEL_NAME,
+    )
+    return out.reshape(b, s_pad, nh, latent)[:, :s]
+
+
+def _check(pool, block_size, w, latent, interpret):
+    if pool.shape[2] != block_size or pool.shape[3] != w:
+        raise ValueError(f"pool {pool.shape} against queries {w} wide, "
+                         f"block {block_size}")
+    if latent % 128 and not interpret:
+        raise ValueError(f"latent width {latent} is not lane-aligned")
+
+
+class PackedTiles(NamedTuple):
+    """A packed round's tokens as query tiles, the same for every layer
+    (:func:`packed_tiles`): ``T`` consecutive tokens of one sequence a
+    tile, a sequence's last tile padded. ``ceil(Tp / T) + B`` tiles hold
+    any round, where the ``[B, S]`` rectangle takes ``B x S / T``."""
+
+    token: jax.Array    # [R, T] int32 packed index of each query; Tp = none
+    seq: jax.Array      # [R] int32 sequence of each tile
+    pos: jax.Array      # [R, T] int32 position of each query (-1 = none)
+    slot: jax.Array     # [Tp] int32 where each packed token sits, R*T flat
+
+
+def packed_tiles(row: jax.Array, col: jax.Array, positions: jax.Array,
+                 num_seqs: int, width: int, heads: int) -> PackedTiles:
+    """Tiles of a packed round (``models/llama.Packing``): token ``i`` is
+    query ``col[i]`` of sequence ``row[i]`` (``num_seqs`` = padding), a
+    sequence's tokens lie side by side with ``col`` counting from 0."""
+    tp = row.shape[0]
+    t = _q_tile(width, heads)
+    n_tiles = -(-tp // t) + num_seqs
+    live = row < num_seqs
+    count = jnp.zeros((num_seqs + 1,), jnp.int32).at[row].max(
+        jnp.where(live, col + 1, 0))[:num_seqs]
+    tiles_of = -(-count // t)
+    base = jnp.cumsum(tiles_of) - tiles_of                       # [B]
+    safe = jnp.minimum(row, num_seqs - 1)
+    slot = jnp.where(live, (base[safe] + col // t) * t + col % t,
+                     n_tiles * t)
+    token = jnp.full((n_tiles * t,), tp, jnp.int32).at[slot].set(
+        jnp.arange(tp, dtype=jnp.int32), mode="drop")
+    pos = jnp.full((n_tiles * t,), -1, jnp.int32).at[slot].set(
+        positions.astype(jnp.int32), mode="drop")
+    seq = jnp.zeros((n_tiles,), jnp.int32).at[slot // t].set(
+        safe.astype(jnp.int32), mode="drop")
+    return PackedTiles(token.reshape(n_tiles, t), seq,
+                       pos.reshape(n_tiles, t), slot.astype(jnp.int32))
+
+
+def latent_paged_attention_packed(
+    q: jax.Array,             # [Tp, Nh, W] absorbed queries, packed axis
+    tiles: PackedTiles,
+    pool: jax.Array,
+    layer_idx: jax.Array,
+    block_tables: jax.Array,  # [B, M]
+    kv_lens: jax.Array,       # [B]
+    block_size: int = 16,
+    *,
+    scale: float,
+    latent: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """:func:`latent_paged_attention` for a packed round, without the
+    rectangle: the queries are gathered straight into their tiles and the
+    result back onto the packed axis → ``[Tp, Nh, latent]``. At ``Tp`` 264
+    on 8 sequences of width 256 that is 41 tiles where the rectangle has
+    256, most of them empty."""
+    tp, nh, w = q.shape
+    _check(pool, block_size, w, latent, interpret)
+    rows, t = tiles.token.shape
+    q_tiles = jnp.take(q, tiles.token.reshape(-1), axis=0, mode="fill",
+                       fill_value=0).reshape(rows, t, nh, w)
+    out = _attend_tiles(
+        q_tiles, tiles.pos, tiles.seq, pool, layer_idx, block_tables,
+        kv_lens, block_size=block_size, scale=scale, latent=latent,
+        name=RAGGED_KERNEL_NAME, interpret=interpret,
+    )
+    return jnp.take(out.reshape(rows * t, nh, latent), tiles.slot, axis=0,
+                    mode="fill", fill_value=0)

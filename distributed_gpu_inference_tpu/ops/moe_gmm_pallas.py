@@ -58,10 +58,22 @@ _WHOLE_BLOCK_BYTES = 2 * 1024 * 1024
 
 
 def weight_tiles(k: int, n: int):
-    """(bk, bn) of the weight blocks, or None if K x N does not tile."""
+    """(bk, bn) of the weight blocks, or None if K x N does not tile. A
+    matrix too large for one block takes the widest column tile and then
+    the LONGEST contraction tile that keeps the block inside
+    ``_WHOLE_BLOCK_BYTES``: a grid step costs the same whether it moves a
+    block or skips a tile, and a layer that holds a share of its experts
+    skips most of its tiles (7680 x 2048: blocks of 3840 x 512, two steps a
+    tile where 512 x 512 took fifteen)."""
     if k % 128 == 0 and n % 128 == 0 and k * n <= _WHOLE_BLOCK_BYTES:
         return k, n
-    return pick_tiles(k, n)
+    tiles = pick_tiles(k, n)
+    if tiles is None:
+        return None
+    bn = tiles[1]
+    bk = next((d for d in range(_WHOLE_BLOCK_BYTES // bn // 128 * 128, 0,
+                                -128) if k % d == 0), tiles[0])
+    return max(bk, tiles[0]), bn
 
 
 class RoutePlan(NamedTuple):
@@ -95,12 +107,15 @@ def route_plan(topi: jax.Array, live: jax.Array, num_experts: int,
                tm: int) -> RoutePlan:
     """Tiled layout of the pairs ``topi [T, k]`` (expert of each pair),
     grouped by expert in token order. ``live [T]`` masks tokens that are
-    routed at all."""
+    routed at all; ``[T, k]`` masks single pairs (a chip that holds a share
+    of the experts routes only the pairs that fall on its own:
+    models/mla.py)."""
     t, k = topi.shape
     p, e_n = t * k, num_experts
     n_tiles = num_tiles(p, e_n, tm)
     r = n_tiles * tm
-    expert = jnp.where(live[:, None], topi, e_n).reshape(p).astype(jnp.int32)
+    pair_live = live if live.ndim == 2 else live[:, None]
+    expert = jnp.where(pair_live, topi, e_n).reshape(p).astype(jnp.int32)
     onehot = expert[:, None] == jnp.arange(e_n, dtype=jnp.int32)[None, :]
     sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)              # [E]
     safe = jnp.minimum(expert, e_n - 1)

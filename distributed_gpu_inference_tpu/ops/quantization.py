@@ -43,7 +43,10 @@ QUANT_KEYS = frozenset(
      # MoE expert weights (stacked [L, E, in, out]) share the same scheme;
      # the router projection stays high-precision — quantizing it perturbs
      # top-k expert selection far more than it saves in bytes
-     "we_gate", "we_up", "we_down"}
+     "we_gate", "we_up", "we_down",
+     # latent attention's low-rank projections and the shared expert
+     # (models/mla.py); W_UK / W_UV stay bf16 like the router
+     "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down"}
 )
 
 _FP8_MAX = 448.0  # float8_e4m3 largest finite value
@@ -122,7 +125,8 @@ def matmul(x: jax.Array, w: Any, pallas: bool = True) -> jax.Array:
 
 # weight keys large enough to be worth the stacked-scan treatment
 STACKED_KEYS = frozenset(
-    {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"}
+    {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+     "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down"}
 )
 # the MoE expert weights: kept whole for the routed layer's grouped-matmul
 # kernel (ops/moe_gmm_pallas.py), scanned where the layer runs in XLA
@@ -201,8 +205,17 @@ def quantize_params(
     if mode is None:
         return params
     out = dict(params)
+    # a model whose layers are of two kinds keeps a second stack
+    # (models/mla.py: the leading dense layers)
+    for group in ("layers", "dense_layers"):
+        if group in params:
+            out[group] = _quantize_group(params[group], mode, consume)
+    return out
+
+
+def _quantize_group(src: Dict[str, Any], mode: str, consume: bool
+                    ) -> Dict[str, Any]:
     if consume:
-        src = params["layers"]
         new_layers: Dict[str, Any] = {}
         for k in list(src.keys()):
             v = src.pop(k)
@@ -216,14 +229,12 @@ def quantize_params(
                 del v
             else:
                 new_layers[k] = v
-        out["layers"] = new_layers
-        return out
-    out["layers"] = {
+        return new_layers
+    return {
         k: (quantize_weight(v, mode)
             if (k in QUANT_KEYS and not is_quantized(v)) else v)
-        for k, v in params["layers"].items()
+        for k, v in src.items()
     }
-    return out
 
 
 def param_bytes(params: Dict[str, Any]) -> int:

@@ -72,6 +72,11 @@ _EMA_WEIGHT = 0.1
 # what the engine thread pays around the device's work in a plain round
 # (``engine.stats``; readback, the wait for the device, is not among them)
 _HOST_PHASES = ("round_build_s", "round_dispatch_s", "round_commit_s")
+# a scan that takes this many times what its steps take by the running mean
+# is counted (``scans_stalled``, ``scan_stall_s``) and logged with the
+# engine's phases: on the v5e one scan in some 12 minutes of a long-context
+# decode cell waited 5-8 s for the device (PERF.md, PR 31)
+_STALL_FACTOR = 10.0
 # why a scan got its length, counted as ``scans_<reason>``
 _SCAN_REASONS = ("amortise", "raised_waiting", "capped_by_budget")
 
@@ -355,6 +360,7 @@ class ContinuousBatcher:
             # for rows that had already finished inside them
             **{f"scans_{reason}": 0 for reason in _SCAN_REASONS},
             "scan_row_steps_masked": 0,
+            "scans_stalled": 0, "scan_stall_s": 0.0,
             "ragged_admissions": 0, "ragged_rounds": 0,
             "budgeted_rounds": 0, "budget_skipped_admissions": 0,
             "spec_waves": 0, "spec_completed": 0, "spec_errors": 0,
@@ -1437,14 +1443,26 @@ class ContinuousBatcher:
                 st[f"scans_t{steps}"] = st.get(f"scans_t{steps}", 0) + 1
                 st[f"scans_{reason}"] += 1
                 host = -self._host_phases_s(engine_stats)
+                wait = -engine_stats.get("round_readback_s", 0.0)
                 emitted = self.engine.decode_multi(steps)
                 host += self._host_phases_s(engine_stats)
+                wait += engine_stats.get("round_readback_s", 0.0)
                 # a row that started the scan and emitted fewer tokens than
                 # it has steps had finished inside it (a speculative step
                 # emits several: never counted negative)
                 st["scan_row_steps_masked"] += sum(
                     max(0, steps - len(toks)) for toks in emitted.values())
-            return steps, time.perf_counter() - t0 - host, gap + host
+            scan_s = time.perf_counter() - t0 - host
+            usual = steps * st["step_latency_ema_ms"] * 1e-3
+            if 0.0 < usual * _STALL_FACTOR < scan_s:
+                st["scans_stalled"] += 1
+                st["scan_stall_s"] += scan_s - usual
+                log.warning(
+                    "round %d: a %d-step scan of %d rows took %.3f s where "
+                    "%.3f is usual: host phases %.3f s, wait for the device "
+                    "%.3f s, gap before it %.3f s", n, steps, len(emitted),
+                    scan_s + host, usual, host, wait, gap)
+            return steps, scan_s, gap + host
         finally:
             self._round_end = time.perf_counter()
 

@@ -85,10 +85,16 @@ _MOE_COUNTERS = ("layer_calls", "assignments", "rows_dispatched",
                  "active_experts")
 
 
+# one more where the chip holds a share of the experts: every (token, expert)
+# pair the router kept, on held experts or not (models/mla.py _experts)
+_MOE_SHARE_COUNTERS = _MOE_COUNTERS + ("pairs_routed",)
+
+
 def _moe_vector(moe: Dict[str, Any]) -> Any:
     """``ChunkOutput.moe`` as one int32 vector, so that a round brings its
     counters back in one transfer."""
-    return jnp.stack([moe[name] for name in _MOE_COUNTERS]).astype(jnp.int32)
+    names = _MOE_SHARE_COUNTERS if "pairs_routed" in moe else _MOE_COUNTERS
+    return jnp.stack([moe[name] for name in names]).astype(jnp.int32)
 
 
 def _resolve_kv_dtype(kv_cache_dtype: Optional[str], activation_dtype) -> Any:
@@ -431,6 +437,8 @@ class TPUEngine:
         # (runtime/kv_cache.py store_spilled/_probe_spill).
         self.mesh = mesh
         self._seq_axis = 1
+        if self.model_cfg.latent_kv:
+            self._refuse_latent(mesh)
         if mesh is not None:
             sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
             tp = sizes.get("model", 1)
@@ -590,7 +598,23 @@ class TPUEngine:
                 quantized_kv=self.kv_dtype == jnp.int8,
                 pallas=self.mesh is None,
             ),
+            # what a cached token is: per-head K and V, or one latent
+            "kv_layout": "latent" if self.model_cfg.latent_kv else "kv",
         }
+        self._moe_names = (
+            _MOE_SHARE_COUNTERS if self.model_cfg.latent_kv
+            else _MOE_COUNTERS
+        )
+        if self.model_cfg.latent_kv:
+            # cached tokens the scans' rows attended, and the row-steps they
+            # took: what the absorbed decode kernel read (host arithmetic
+            # at a scan's commit); what the absorbed kernel of the plain
+            # ragged rounds held (at a round's build): its (query, cached
+            # token) pairs, and its rows' cached tokens, a row's once
+            self.stats.update({"mla_context_tokens_scan": 0,
+                               "mla_row_steps_scan": 0,
+                               "mla_pairs_ragged": 0,
+                               "mla_context_tokens_ragged": 0})
         if self.model_cfg.num_experts:
             # what the routed expert layers did (models/llama.py
             # _moe_mlp), summed on the device and read back beside a
@@ -598,7 +622,7 @@ class TPUEngine:
             # under a mesh, where the layer runs dense over the expert axis
             self.stats.update({
                 f"moe_{name}_{kind}": 0
-                for kind in ("scan", "ragged") for name in _MOE_COUNTERS
+                for kind in ("scan", "ragged") for name in self._moe_names
             })
         self._compile_log = compile_log()
         if self.cfg.speculative is not None:
@@ -606,6 +630,29 @@ class TPUEngine:
                 "spec_steps": 0, "spec_slot_steps": 0, "spec_drafted": 0,
                 "spec_accepted": 0, "spec_emitted": 0,
             })
+
+    def _refuse_latent(self, mesh: Optional[Any]) -> None:
+        """A latent-attention model's cache is one pool of latent pages
+        (models/mla.py). What carries K/V pages or a Llama draft refuses it
+        here, when the engine is configured, not inside a request."""
+        name = self.model_cfg.name
+        if mesh is not None:
+            raise ValueError(
+                f"{name}: a latent-attention model is served on one chip "
+                "(no sharding rule for the latent pool or the routed share)")
+        if self.cfg.speculative is not None:
+            raise ValueError(
+                f"{name}: speculative decoding drafts with a Llama head over "
+                "K/V pages; this model's multi-token-prediction layer is "
+                "not loaded")
+        if self.cfg.spill_host_blocks > 0 or \
+                self.cfg.spill_remote_store is not None:
+            raise ValueError(
+                f"{name}: the spill tiers carry K/V pages, not latent pages")
+        if self.kv_dtype.itemsize != jnp.dtype(self.dtype).itemsize:
+            raise ValueError(
+                f"{name}: the latent pool is served in the activation "
+                f"dtype, not kv_cache_dtype={self.cfg.kv_cache_dtype!r}")
 
     # -------------------------------------------------- sharded weight init
 
@@ -1139,7 +1186,7 @@ class TPUEngine:
                     return (out.kv, new_last, new_lens, new_done, new_emit,
                             moe + _moe_vector(out.moe)), emitted
 
-                moe0 = jnp.zeros((len(_MOE_COUNTERS),), jnp.int32)
+                moe0 = jnp.zeros((len(self._moe_names),), jnp.int32)
                 (kv, last, lens, _done, _, moe), emitted = jax.lax.scan(
                     step, (kv, core["last"], core["lens"], ~active,
                            jnp.zeros_like(core["lens"]), moe0),
@@ -1496,16 +1543,10 @@ class TPUEngine:
             # page copies (CoW): dst = -1 entries are dropped. Scale pools
             # (int8 KV) copy with their pages — a page without its scale is
             # garbage
-            out = {
-                "k": kv["k"].at[:, dsts].set(kv["k"][:, srcs], mode="drop"),
-                "v": kv["v"].at[:, dsts].set(kv["v"][:, srcs], mode="drop"),
+            return {
+                name: pool.at[:, dsts].set(pool[:, srcs], mode="drop")
+                for name, pool in kv.items()
             }
-            for sk in ("k_scale", "v_scale"):
-                if sk in kv:
-                    out[sk] = kv[sk].at[:, dsts].set(
-                        kv[sk][:, srcs], mode="drop"
-                    )
-            return out
 
         self._apply_ops_fn = jax.jit(apply_ops, donate_argnums=(0,))
 
@@ -2395,7 +2436,7 @@ class TPUEngine:
         of its ``kind``. Empty: the round ran no routed layer."""
         if not moe:
             return
-        held = {name: int(v) for name, v in zip(_MOE_COUNTERS, moe[0])}
+        held = {name: int(v) for name, v in zip(self._moe_names, moe[0])}
         sp.set(**{f"moe_{name}": v for name, v in held.items()})
         for name, v in held.items():
             self.stats[f"moe_{name}_{kind}"] += v
@@ -2598,6 +2639,16 @@ class TPUEngine:
         live = len(kept) + sum(len(piece) for _, piece, _ in ready)
         tp, s_w = self._ragged_shape(live)
         self._count_ragged(sp, tp, tp, len(kept), len(kept), ready)
+        if "mla_pairs_ragged" in self.stats:
+            # a decode row's token sees its cache and itself; query j of a
+            # piece written at ``off`` sees ``off + j + 1``
+            pairs = ctx = sum(int(self._kv_lens[i]) + 1 for i in kept)
+            for adm, piece, _ in ready:
+                m = len(piece)
+                pairs += m * adm.off + m * (m + 1) // 2
+                ctx += adm.off + m
+            self.stats["mla_pairs_ragged"] += pairs
+            self.stats["mla_context_tokens_ragged"] += ctx
         tok_at = np.zeros((4, tp), np.int32)
         tok_at[1], tok_at[2] = -1, b
         lens_last = np.zeros((2, b), np.int32)
@@ -3258,6 +3309,13 @@ class TPUEngine:
                     continue
                 toks = [int(t) for t in emitted[i] if t >= 0]
                 out[i] = toks
+                if "mla_row_steps_scan" in self.stats:
+                    # step t of the row attended its cache and the token
+                    # the step wrote: len + 1 ... len + n
+                    n = len(toks)
+                    self.stats["mla_row_steps_scan"] += n
+                    self.stats["mla_context_tokens_scan"] += (
+                        n * int(self._kv_lens[i]) + n * (n + 1) // 2)
                 # each emitted token corresponds to one scan step that fed
                 # (and thus committed) the previous pending token
                 self._kv_lens[i] += len(toks)

@@ -34,6 +34,7 @@ from distributed_gpu_inference_tpu.testing import faults as _faults
 from distributed_gpu_inference_tpu.utils.data_structures import (
     KV_BLOCK_TOKENS,
     KVBlockMeta,
+    block_prefix_hashes,
     compute_prefix_hash,
 )
 
@@ -482,10 +483,11 @@ class PagedKVCacheManager:
 
     def _evict_one(self) -> int:
         """Evict the LRU cached *leaf* block (reference LRU evict :229-238)."""
-        for bid in list(self.cached_lru.keys()):
-            if self.radix.is_leaf(bid):
-                self._evict_block(bid)
-                return bid
+        is_leaf = self.radix.is_leaf
+        bid = next((b for b in self.cached_lru if is_leaf(b)), None)
+        if bid is not None:
+            self._evict_block(bid)
+            return bid
         raise OutOfBlocksError(
             f"KV pool exhausted: 0 free, {len(self.cached_lru)} cached "
             "(all interior), all others pinned by active sequences"
@@ -940,7 +942,13 @@ class PagedKVCacheManager:
                 # one bulk conversion → zero-copy across the native ABI
                 idx_tokens = np.asarray(tokens, np.int32)
             self.radix.insert(idx_tokens, blocks[:n_full])
-        for i, bid in enumerate(blocks):
+        hashes: Optional[List[str]] = None
+        # leaf first: of one chain only its deepest cached block can be
+        # evicted, so with the leaf ahead of its ancestors in the LRU
+        # ``_evict_one`` meets it at once and not after the whole chain
+        # (which blocks go, and in which order, is the same either way)
+        for i in range(len(blocks) - 1, -1, -1):
+            bid = blocks[i]
             meta = self.metas.get(bid)
             if meta is None:
                 continue
@@ -948,8 +956,10 @@ class PagedKVCacheManager:
             if remaining == 0:
                 if cache and self.enable_prefix_cache and i < n_full and \
                         self.radix.contains_block(bid):
-                    full_tokens = (i + 1) * self.block_size
-                    meta.prefix_hash = compute_prefix_hash(tokens, full_tokens)
+                    if hashes is None:
+                        hashes = block_prefix_hashes(
+                            tokens, self.block_size, n_full)
+                    meta.prefix_hash = hashes[i]
                 self._deactivate_block(bid)
 
     def _scrub_pending_for(self, bid: int) -> None:

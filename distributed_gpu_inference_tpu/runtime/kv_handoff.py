@@ -95,9 +95,22 @@ class KVHandoff:
         return 0 if self.pages is None else int(self.pages.nbytes)
 
 
+def require_kv_pages(engine: "TPUEngine") -> None:
+    """The wire formats here carry K and V pages. An engine whose cache is
+    latent pages (``ModelConfig.latent_kv``; ``engine.stats["kv_layout"]``
+    says ``latent``) is refused by every entry point — and, before any
+    request, where a worker is configured for the handoff
+    (``worker/main.py load_engines``)."""
+    if getattr(getattr(engine, "model_cfg", None), "latent_kv", False):
+        raise ValueError(
+            f"{engine.model_cfg.name}: the KV handoff and migration wire "
+            "carries K/V pages; this engine caches latent pages")
+
+
 def export_slot_kv(engine: "TPUEngine", slot: int) -> KVHandoff:
     """Snapshot ``slot``'s sequence out of ``engine`` (slot stays live; callers
     that migrate should ``finish_slot(slot, cache=...)`` afterwards)."""
+    require_kv_pages(engine)
     import jax.numpy as jnp
 
     s = engine.slots[slot]
@@ -191,6 +204,7 @@ def adopt_kv(engine: "TPUEngine", handoff: KVHandoff,
     """Materialize ``handoff`` into ``engine``: allocate blocks, stage page
     uploads, bind a slot. Returns the slot index; the next ``decode_step``
     resumes the generation."""
+    require_kv_pages(engine)
     if engine.model_cfg.name != handoff.model_name:
         raise ValueError(
             f"model mismatch: engine={engine.model_cfg.name} "
@@ -382,6 +396,7 @@ def migrate_kv_device(src: "TPUEngine", dst: "TPUEngine", slot: int,
     The donor slot stays live (caller decides ``finish_slot`` semantics,
     matching :func:`export_slot_kv`).
     """
+    require_kv_pages(src)
     import jax.numpy as jnp
 
     s = src.slots[slot]
@@ -590,6 +605,7 @@ class StreamedExport:
     def __init__(self, engine: "TPUEngine", request: InferenceRequest,
                  key: str, piece_blocks: int = 4,
                  compress: bool = False) -> None:
+        require_kv_pages(engine)
         if engine.model_cfg.sliding_window is not None:
             raise ValueError(
                 "streamed handoff does not support sliding-window models "
@@ -869,6 +885,7 @@ def export_prefix_frames(engine: "TPUEngine", token_ids: Sequence[int],
     executor): the gather reads live pool pages and the spill probe mutates
     LRU state.
     """
+    require_kv_pages(engine)
     import jax.numpy as jnp
 
     from distributed_gpu_inference_tpu.utils.data_structures import (
@@ -1033,6 +1050,7 @@ class HandoffReceiver:
     MAX_COMMIT_MEMO = 32
 
     def __init__(self, engine: "TPUEngine") -> None:
+        require_kv_pages(engine)
         self.engine = engine
         self._sessions: Dict[str, _AdoptSession] = {}
         # recently committed keys → the result dict their commit returned

@@ -672,6 +672,11 @@ class SpeculativeDecoder:
             get_model_config(model_cfg) if isinstance(model_cfg, str) else model_cfg
         )
         self.spec_cfg = spec_cfg or SpeculativeConfig()
+        if self.model_cfg.latent_kv:
+            raise ValueError(
+                f"{self.model_cfg.name}: the tree decoder drafts with a "
+                "Llama head and moves K/V rows; a latent-attention model's "
+                "multi-token-prediction layer is not loaded")
         if kv_cache_dtype not in (None, "int8"):
             raise ValueError(
                 f"SpeculativeDecoder kv_cache_dtype={kv_cache_dtype!r}: "

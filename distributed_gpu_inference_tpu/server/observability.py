@@ -346,6 +346,31 @@ class Metrics:
                  "tile padding included"),
                 ("active_experts", "Experts that received at least one "
                  "row, summed over layer calls"),
+                ("pairs_routed", "Every (token, expert) pair the router "
+                 "kept, on experts this worker holds or not (a worker that "
+                 "holds a share of the experts: assignments / pairs_routed "
+                 "is the share that fell on its own)"),
+            )
+        }
+        # a latent-attention (MLA) engine: what a cached token is, and what
+        # its decode scans attended (context_tokens / row_steps = the mean
+        # cache length a scan row read)
+        self.worker_kv_layout = Gauge(
+            "worker_kv_layout",
+            "1 for what the worker's cache holds a token: kv (per-head K "
+            "and V pages) or latent (one compressed latent and its rope "
+            "key)", ["worker", "layout"], registry=r)
+        self.worker_mla = {
+            name: Counter(f"worker_mla_{name}_total", help_, ["worker"],
+                          registry=r)
+            for name, help_ in (
+                ("context_tokens_scan", "Cached tokens the decode scans' "
+                 "rows attended, summed over row-steps"),
+                ("row_steps_scan", "Row-steps the decode scans took"),
+                ("pairs_ragged", "(query, cached token) pairs the plain "
+                 "ragged rounds' attention held, causal"),
+                ("context_tokens_ragged", "Cached tokens of the plain ragged "
+                 "rounds' rows, each row's once a round"),
             )
         }
         # cache-aware routing (round 7): hits = placements that landed on
@@ -741,6 +766,11 @@ class MetricsCollector:
             for name in ("in_place", "layer_copy"):
                 self.metrics.worker_ragged_kv_path.labels(worker, name).set(
                     1.0 if name == path else 0.0)
+        layout = stats.get("kv_layout")
+        if isinstance(layout, str):
+            for name in ("kv", "latent"):
+                self.metrics.worker_kv_layout.labels(worker, name).set(
+                    1.0 if name == layout else 0.0)
         prev = self._batcher_prev.setdefault(worker, {})
         for key, metric in (
             ("decode_rounds", self.metrics.batcher_decode_rounds),
@@ -787,6 +817,10 @@ class MetricsCollector:
                 if name not in self.metrics.worker_moe:
                     continue
                 metric = self.metrics.worker_moe[name].labels(worker, kind)
+            elif key.startswith("mla_"):
+                if key[4:] not in self.metrics.worker_mla:
+                    continue
+                metric = self.metrics.worker_mla[key[4:]].labels(worker)
             else:
                 continue
             try:

@@ -18,6 +18,7 @@ from distributed_gpu_inference_tpu.utils.data_structures import (  # noqa: F401
     WorkerInfo,
     WorkerRole,
     WorkerState,
+    block_prefix_hashes,
     compute_prefix_hash,
     estimate_kv_cache_bytes,
 )

@@ -22,6 +22,7 @@ and hermetically unit-testable on CPU.
 from __future__ import annotations
 
 import hashlib
+import struct
 import time
 import uuid
 from dataclasses import dataclass, field
@@ -534,6 +535,22 @@ def compute_prefix_hash(token_ids: Sequence[int], upto: Optional[int] = None) ->
     for t in ids:
         h.update(int(t).to_bytes(4, "little", signed=False))
     return h.hexdigest()
+
+
+def block_prefix_hashes(token_ids: Sequence[int], block_size: int,
+                        n_blocks: int) -> List[str]:
+    """``compute_prefix_hash(token_ids, (i + 1) * block_size)`` for every
+    ``i < n_blocks``, in one pass over the tokens: the hash of a prefix is
+    carried on to the next block instead of being started over (a chain of
+    n blocks otherwise hashes n**2 / 2 blocks of tokens)."""
+    ids = token_ids[: n_blocks * block_size]
+    raw = struct.pack(f"<{len(ids)}I", *ids)
+    h = hashlib.sha256()
+    out = []
+    for i in range(n_blocks):
+        h.update(raw[i * block_size * 4:(i + 1) * block_size * 4])
+        out.append(h.copy().hexdigest())
+    return out
 
 
 def estimate_kv_cache_bytes(

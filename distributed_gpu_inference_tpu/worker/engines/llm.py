@@ -444,7 +444,12 @@ class TPULLMEngine(LLMBaseEngine):
         sv = self._serving_config()
         eng_cfg = EngineConfig(
             max_batch_size=int(self.config.get("max_batch_size", 8)),
-            max_seq_len=int(self.config.get("max_seq_len", 2048)),
+            # EngineModelConfig names no context length: a worker's file
+            # carries it under ``extra``, as it carries ``tp_size``
+            max_seq_len=int(
+                self.config.get("max_seq_len")
+                or (self.config.get("extra") or {}).get("max_seq_len")
+                or 2048),
             multi_step=int(self.config.get("multi_step", 16)),
             enable_prefix_cache=bool(
                 self.config.get("enable_prefix_cache", True)
@@ -548,6 +553,9 @@ class TPULLMEngine(LLMBaseEngine):
             # invalid mesh/model combination must drop the task type, not
             # kill worker startup (load_engines catches EngineLoadError)
             raise EngineLoadError(str(exc)) from exc
+        if self.engine.model_cfg.latent_kv:
+            # peers pull K/V pages (runtime/kv_handoff.py): not this cache's
+            self.kv_migrate_enabled = False
         if eng_cfg.speculative is not None and \
                 int(self.config.get("spec_distill_steps", 0)) > 0:
             # optional on-load draft distillation against the engine's own

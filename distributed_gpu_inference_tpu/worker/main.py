@@ -325,6 +325,15 @@ class Worker:
                 cfg = self.config.engine_for(task_type)
                 eng = create_engine(task_type, cfg.model_dump())
                 eng.load_model()
+                core = getattr(eng, "engine", None)
+                if self.config.role != "hybrid" and core is not None and \
+                        getattr(core, "stats", {}).get("kv_layout") == "latent":
+                    # a prefill / decode role hands K/V pages to a peer
+                    # (runtime/kv_handoff.py require_kv_pages): refused
+                    # here, where the worker is configured
+                    raise EngineLoadError(
+                        f"role {self.config.role!r}: the PD handoff carries "
+                        f"K/V pages, {cfg.model} caches latent pages")
                 self.engines[task_type] = eng
                 loaded.append(task_type)
             except (EngineLoadError, KeyError) as exc:
@@ -486,8 +495,9 @@ class Worker:
             es = core.get_stats() if core is not None else {}
             for k in ("compiles", "compile_s"):
                 out[k] = max(out.get(k, 0), round(es.get(k, 0), 3))
-            if es.get("ragged_kv_path"):
-                out["ragged_kv_path"] = es["ragged_kv_path"]
+            for k in ("ragged_kv_path", "kv_layout"):
+                if es.get(k):
+                    out[k] = es[k]
             for k, src in (("between_rounds_s", s), ("admit_s", s),
                            ("deliver_s", s), ("round_build_s", es),
                            ("round_dispatch_s", es),
@@ -500,8 +510,9 @@ class Worker:
                         or k.startswith("scans_"):
                     out[k] = out.get(k, 0) + int(s[k] or 0)
             # the routed expert layers' counters (MoE engines only)
+            # and a latent-attention engine's scan counters (mla_*)
             for k in es:
-                if k.startswith("moe_"):
+                if k.startswith(("moe_", "mla_")):
                     out[k] = out.get(k, 0) + int(es[k] or 0)
             if s.get("avg_occupancy") is not None:
                 out["avg_occupancy"] = round(

@@ -1,0 +1,25 @@
+"""The benchmark's self-tests that hold its judged statistics, its knee and
+spread rules and its round readers (``benchmark/tests/test_metrics.py``,
+``test_sweep.py``, ``test_spread.py``, ``test_round_readers.py`` and, of
+PR 31, ``test_mla_readers.py``), inside
+tier-1: they need no chip and no JAX, and a later change to ``harness/`` or
+a reader should not wait for someone to run ``benchmark/tests`` by hand.
+Imported from their files, as ``tests/test_model_olmoe.py`` imports
+``harness``; each case keeps its name behind its module's."""
+
+import importlib.util
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1] / "benchmark"
+if str(BENCH) not in sys.path:      # as benchmark/tests/conftest.py does
+    sys.path.insert(0, str(BENCH))
+
+for _stem in ("metrics", "sweep", "spread", "round_readers", "mla_readers"):
+    _spec = importlib.util.spec_from_file_location(
+        f"benchmark_selftests_{_stem}", BENCH / "tests" / f"test_{_stem}.py")
+    _mod = importlib.util.module_from_spec(_spec)
+    _spec.loader.exec_module(_mod)
+    for _name, _obj in vars(_mod).items():
+        if _name.startswith("test_") and callable(_obj):
+            globals()[f"test_{_stem}__{_name[5:]}"] = _obj

@@ -289,3 +289,54 @@ class TestRadix:
         assert r.is_leaf(5)
         r.remove_block(5)
         assert r.match_prefix(toks(32)) == []
+
+
+class TestLongChains:
+    """A finished sequence of a few thousand tokens (a chain of ~200
+    blocks) costs the host a pass over its tokens and a pool under
+    pressure one index probe an eviction, not a pass over the chain a
+    block (measured on the v5e's host at 52 ms a finish and 40 ms an
+    admission for prompts of 1.5-3 k tokens: PERF.md, PR 31)."""
+
+    @pytest.mark.parametrize("n_blocks", [0, 1, 7])
+    def test_block_prefix_hashes_are_the_prefix_hashes(self, n_blocks):
+        from distributed_gpu_inference_tpu.utils.data_structures import (
+            block_prefix_hashes,
+            compute_prefix_hash,
+        )
+
+        ids = [int(t) for t in
+               np.random.default_rng(3).integers(0, 2 ** 32, 7 * BS + 5)]
+        assert block_prefix_hashes(ids, BS, n_blocks) == [
+            compute_prefix_hash(ids, (i + 1) * BS) for i in range(n_blocks)]
+
+    def test_a_freed_chain_keeps_its_hashes(self):
+        from distributed_gpu_inference_tpu.utils.data_structures import (
+            compute_prefix_hash,
+        )
+
+        m = PagedKVCacheManager(num_blocks=16, block_size=BS)
+        blocks, _ = m.allocate_sequence("a", toks(5 * BS + 3))
+        m.free_sequence("a")
+        assert [m.metas[b].prefix_hash for b in blocks[:5]] == [
+            compute_prefix_hash(toks(5 * BS + 3), (i + 1) * BS)
+            for i in range(5)]
+
+    @pytest.mark.parametrize("native", [True, False])
+    def test_eviction_probes_once_a_block_and_goes_leaf_to_root(self, native):
+        n = 200
+        m = PagedKVCacheManager(num_blocks=2 * n + 4, block_size=BS)
+        if not native:
+            m.radix = RadixPrefixIndex(BS)
+        old, _ = m.allocate_sequence("old", toks(n * BS + 1))
+        new, _ = m.allocate_sequence("new", toks(n * BS + 1, 10 ** 6))
+        m.free_sequence("old")
+        m.free_sequence("new")
+        probes, is_leaf = [], m.radix.is_leaf
+        m.radix.is_leaf = lambda b: probes.append(b) or is_leaf(b)
+        # the free list holds the two partial last blocks and one more
+        got, _ = m.allocate_sequence("x", toks((n + 3) * BS, 2 * 10 ** 6))
+        # the older chain goes first, its leaf before its ancestors
+        assert got[3:] == old[n - 1::-1]
+        assert len(probes) == n
+        assert m.radix.match_prefix(toks(n * BS, 10 ** 6)) == new[:n]

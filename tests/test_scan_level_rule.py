@@ -12,6 +12,7 @@ is kept per scan length, because a longer scan's host time grows with it.
 
 import asyncio
 import random
+import time
 import types
 
 import numpy as np
@@ -355,3 +356,29 @@ def test_the_rules_counters_count():
                  for t, _, out in scans for toks in out.values())
     assert st["scan_row_steps_masked"] == masked == 3
     assert st["horizon"] == 4.0
+
+
+def test_a_scan_ten_times_its_usual_length_is_counted_and_logged(caplog):
+    """``scans_stalled`` / ``scan_stall_s``: a scan that takes over ten
+    times what its steps take by the running mean, with the engine's
+    phases in the log; a usual scan counts nothing."""
+    b = _batcher([100, 100])
+    eng, delay = b.engine, [0.0]
+    eng.stats = {"rounds": 0, "round_readback_s": 0.0}
+
+    def decode_multi(steps):
+        time.sleep(delay[0])
+        eng.stats["round_readback_s"] += delay[0]
+        return {0: [1] * steps, 1: [1] * steps}
+
+    eng.decode_multi = decode_multi
+    b.stats["step_latency_ema_ms"] = 2.0
+    steps, _, _ = b._engine_round()
+    assert b.stats["scans_stalled"] == 0 and b.stats["scan_stall_s"] == 0.0
+    delay[0] = 10 * steps * 2e-3 + 0.02
+    with caplog.at_level("WARNING", logger=batcher_mod.log.name):
+        b._engine_round()
+    assert b.stats["scans_stalled"] == 1
+    assert b.stats["scan_stall_s"] == pytest.approx(
+        delay[0] - steps * 2e-3, abs=0.02)
+    assert "wait for the device" in caplog.text

@@ -404,3 +404,71 @@ def test_mesh_engine_traces_no_pallas_kernel(tpu_dispatch, cpu_devices):
     # slot state carried from the last round have the same (replicated)
     # sharding, so the round graph does not compile a second time
     assert eng._decode_multi_fn._cache_size() == 1
+
+
+# --------------------------------------------------------------------- #
+# (d) the latent-attention model: its kernels and its serving graphs
+# --------------------------------------------------------------------- #
+
+PANGU = "openpangu-ultra-moe-718b-ep16"
+PANGU_CTX = 4096        # the cell serves 4096 positions
+
+
+@pytest.mark.parametrize("s", [1, 16, 256])
+def test_latent_kernels_compile(v5e, s):
+    """The absorbed kernel (128 heads against 640-lane pool rows) and the
+    one-pool page write, at the served geometry: a scan step, a narrow and
+    a full rectangle."""
+    from distributed_gpu_inference_tpu.models import mla
+    from distributed_gpu_inference_tpu.ops import mla_attention_pallas as mk
+
+    cfg = get_model_config(PANGU)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    w, blocks = mla.pool_width(cfg), 1 + 12 * (PANGU_CTX // 16)
+    pool = sds((cfg.num_layers, blocks, 16, w), jnp.bfloat16)
+    tables = sds((BATCH, PANGU_CTX // 16), jnp.int32)
+
+    def attend(q, pool, layer, tables, pos, lens):
+        return mk.latent_paged_attention(
+            q, pool, layer, tables, pos, lens, 16, scale=cfg.head_dim ** -0.5,
+            latent=cfg.kv_lora_rank, decode=s == 1)
+
+    def write(rows, pool, layer, tables, pos):
+        plan = page_write_plan(tables, pos, 16, page_bytes=16 * w * 2)
+        return mk.write_latent_pages_in_place(rows, pool, layer, plan)
+
+    lowered = jax.jit(attend).lower(
+        sds((BATCH, s, cfg.num_heads, w), jnp.bfloat16), pool,
+        sds((), jnp.int32), tables, sds((BATCH, s), jnp.int32),
+        sds((BATCH,), jnp.int32))
+    assert _kernels(lowered) == {
+        "dgi_mla_decode" if s == 1 else "dgi_mla_ragged"}
+    lowered.compile()
+    jax.jit(write, donate_argnums=(1,)).lower(
+        sds((BATCH * s, w), jnp.bfloat16), pool, sds((), jnp.int32), tables,
+        sds((BATCH, s), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("tp,s", [(None, 1), (264, 256), (2048, 256)],
+                         ids=["scan-step", "Tp264", "Tp2048"])
+def test_latent_model_graphs_compile_and_move_no_pool_layer(
+        v5e, tpu_dispatch, tp, s):
+    """openPangu's share at its published widths: a decode step and the
+    packed round at a middle and the top rung. Latent pages are written
+    (``dgi_mla_write``) and read by the absorbed kernel in the stacked pool;
+    the held experts go through the grouped-matmul kernel; no array of a
+    pool layer's shape exists, and the temporaries stay inside what the
+    chip has left beside 9.3 GB of weights and the pool."""
+    cfg = get_model_config(PANGU)
+    lowered = _forward_chunk_lowered(cfg, s, None, v5e, tp=tp, ctx=PANGU_CTX)
+    found = _kernels(lowered)
+    want = {"dgi_mla_write", "dgi_mla_decode", "dgi_moe_gmm_step"} \
+        if tp is None else {"dgi_mla_write", "dgi_mla_ragged", "dgi_moe_gmm"}
+    assert want <= found and found <= want | {"dgi_qmm"}, found
+    compiled = lowered.compile()
+    blocks = 1 + BATCH * (PANGU_CTX // 16)
+    text = compiled.as_text()
+    assert f"[{cfg.num_layers},{blocks},16,640]" in text
+    assert f"[{blocks},16,640]" not in text.replace(
+        f"[{cfg.num_layers},{blocks},16,640]", "")
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
