@@ -79,6 +79,10 @@ _HOST_PHASES = ("round_build_s", "round_dispatch_s", "round_commit_s")
 _STALL_FACTOR = 10.0
 # why a scan got its length, counted as ``scans_<reason>``
 _SCAN_REASONS = ("amortise", "raised_waiting", "capped_by_budget")
+# why a scan was read back before the next round went out behind it, counted
+# as ``chain_breaks_<reason>`` (``ContinuousBatcher._chain_break``)
+_CHAIN_BREAKS = ("admission", "row_end_waiting", "row_end", "signal",
+                 "pressure", "idle")
 
 
 def _mean(mean: float, sample: float) -> float:
@@ -318,6 +322,8 @@ class ContinuousBatcher:
         # the horizon rule's ``h`` by scan length: what a round of that
         # many steps costs the host, in ms (``_retune``)
         self._host_ms: Dict[int, float] = {}
+        # and what it costs the host, hidden behind a scan or not
+        self._cost_ms: Dict[int, float] = {}
         self._rebuild_levels(float(self.cfg.multi_step))
         self._slot_items: Dict[int, _QueueItem] = {}
         # admission stamps for LIFO victim selection (slot indices recycle,
@@ -347,6 +353,18 @@ class ContinuousBatcher:
         # perf_counter at the last round's end, stamped on the engine
         # thread; None once the loop has parked (no work owned in between)
         self._round_end: Optional[float] = None
+        # steps of the scan the last round left unread on the device (the
+        # engine's ``decode_multi(..., ahead=True)``), None when every
+        # token dispatched has been read; and the host time the chip spent
+        # idle since the last scan that was read, for ``_retune``
+        self._unread_steps: Optional[int] = None
+        self._exposed_s = self._cost_s = 0.0
+        # callers waiting to run their own work on the engine thread
+        # (``BatcherServing.run_exclusive``): while there are any, every
+        # scan is read by the call that made it, so that what they run
+        # finds the host mirrors current
+        self._foreign = 0
+        self._foreign_lock = threading.Lock()
         self.stats: Dict[str, Any] = {
             "submitted": 0, "completed": 0, "rejected": 0, "timeouts": 0,
             "decode_rounds": 0, "admitted": 0, "queue_peak": 0,
@@ -361,6 +379,13 @@ class ContinuousBatcher:
             **{f"scans_{reason}": 0 for reason in _SCAN_REASONS},
             "scan_row_steps_masked": 0,
             "scans_stalled": 0, "scan_stall_s": 0.0,
+            # scans dispatched while the one before was unread, why the
+            # others were not, and the host's round time the chip idled
+            # through (the gaps between rounds and the engine's phases that
+            # ran with no scan on the device)
+            "scans_chained": 0,
+            **{f"chain_breaks_{why}": 0 for why in _CHAIN_BREAKS},
+            "round_host_exposed_s": 0.0,
             "ragged_admissions": 0, "ragged_rounds": 0,
             "budgeted_rounds": 0, "budget_skipped_admissions": 0,
             "spec_waves": 0, "spec_completed": 0, "spec_errors": 0,
@@ -1323,15 +1348,16 @@ class ContinuousBatcher:
                     req, list(resp.token_ids), resp.prompt_tokens))
                 self._count_abandon(req, now)
 
-    def _notify_observers(self) -> None:
+    def _notify_observers(self, finished: bool = False) -> None:
         """Push per-round progress to streaming observers (loop thread;
         observers must only enqueue). Finished slots are excluded — their
-        full token list rides the resolving response."""
+        full token list rides the resolving response — unless ``finished``
+        says that response has to wait."""
         for slot, item in list(self._slot_items.items()):
             if item.observer is None:
                 continue
             s = self.engine.slots[slot]
-            if s is None or s.finish_reason is not None:
+            if s is None or (s.finish_reason is not None and not finished):
                 continue
             try:
                 item.observer(list(s.generated))
@@ -1398,14 +1424,139 @@ class ContinuousBatcher:
     def _host_phases_s(engine_stats: Dict[str, Any]) -> float:
         return sum(engine_stats.get(k, 0.0) for k in _HOST_PHASES)
 
+    @staticmethod
+    def _host_exposed_s(engine_stats: Dict[str, Any]) -> float:
+        """The engine's host phases the chip idled through: its own count
+        where it keeps one, else all of them (an engine that reads every
+        scan back in the call that made it)."""
+        if "round_host_exposed_s" in engine_stats:
+            return engine_stats["round_host_exposed_s"]
+        return ContinuousBatcher._host_phases_s(engine_stats)
+
+    def _signal_pending(self) -> bool:
+        """A cancel, an interrupt or a hopeless deadline waits on a
+        decoding slot: what ``_scan_signals`` / ``_scan_deadlines`` would
+        take to the engine."""
+        for item in self._slot_items.values():
+            if (item.cancel is not None and item.cancel.is_set()) or \
+                    (item.interrupt is not None and item.interrupt.is_set()):
+                return True
+        if self.cfg.abandon_deadlines:
+            now = time.time()
+            for slot, item in self._slot_items.items():
+                s = self.engine.slots[slot]
+                if s is not None and s.finish_reason is None and \
+                        self._deadline_hopeless(
+                            item.request,
+                            int(item.request.sampling.max_new_tokens)
+                            - len(s.generated), now):
+                    return True
+        return False
+
+    def _chain_break(self) -> Optional[str]:
+        """Why the scan left unread must be read before the loop goes on,
+        or None: the next round is another scan over its rows, which goes
+        out behind it, and the loop's work of this round (deliver, admit,
+        both hops to the engine thread, the engine's build and upload) runs
+        while the device does. Read from what the batcher holds now:
+
+        ``signal``: a cancel, interrupt or deadline wants a slot, or an
+        out-of-band engine call read the scan or waits for the thread;
+        ``pressure``: the pool froze a row, or resumes are held;
+        ``admission``: a request waits and a slot is free (or the
+        speculative route may take it): the next round is not a scan;
+        ``row_end_waiting``: a request waits and a row's budget ends inside
+        the unread scan: a slot coming free is an admission, not a scan
+        (``_choose_steps`` never runs a raised scan past that step either);
+        ``idle``: no row has a step left after it.
+        (``row_end``, in ``_deliver``: a row was found finished.) An
+        arrival so waits for at most the scan that went out as it came."""
+        eng = self.engine
+        if not eng.scan_unread or self._foreign:
+            return "signal"
+        if self._resume_hold or eng.pressure_pending:
+            return "pressure"
+        if self._signal_pending():
+            return "signal"
+        if self._heap:
+            if self.spec is not None or eng.free_slots():
+                return "admission"
+            if eng.scan_ends_row():
+                return "row_end_waiting"
+        if not eng.decode_budgets().any():
+            return "idle"
+        return None
+
+    def _scan_measured(self, steps: Optional[int],
+                       emitted: Dict[int, List[int]], gap_s: float,
+                       call_s: float, engine_exposed_s: float,
+                       cost_s: float, behind: bool, note: str
+                       ) -> Optional[Tuple[int, float, float]]:
+        """What ``_retune`` gets for the scan of ``steps`` steps whose tokens
+        ``emitted`` a call of ``call_s`` seconds just brought back, ``gap_s``
+        after the round before ended: (steps, the scan's seconds on the
+        device, the host's seconds the chip idled through since the last
+        scan read). None when no scan came back (a call that left its own
+        unread behind nothing): its times fall to the next that does. What
+        the host worked meanwhile (``cost_s``: the gap and the engine's
+        phases, hidden or not) joins the scan length's cost (``_cost_ms``).
+
+        The chip's idle time, by what was on it when the round started.
+        Nothing (``behind`` false): the gap and what the engine counts of
+        its own phases (``engine_exposed_s``), and the rest of the time is
+        the scan's. A scan left there by the round before, still running
+        when the engine came to read it: the chip had work all the time,
+        so only what the engine counts (the commit of a scan nothing went
+        out behind), and the time since the read before is the scan's own.
+        A scan that had ended by then: the chip has idled since it ended,
+        which no clock of the host's saw, so the time less what the scan's
+        steps usually take; such a round says nothing new about ``s``
+        (taking the rest for the scan's time would feed ``s`` its own
+        error back: the figure drifts up to the host's round, and the idle
+        time with it to nothing)."""
+        st = self.stats
+        cycle_s = gap_s + call_s
+        usual = (steps or 0) * st["step_latency_ema_ms"] * 1e-3
+        timed = True
+        if not behind:
+            exposed_s = gap_s + engine_exposed_s
+        elif getattr(self.engine, "scan_read_running", True):
+            exposed_s = engine_exposed_s
+        else:
+            exposed_s = min(cycle_s, max(engine_exposed_s, cycle_s - usual))
+            timed = False
+        st["round_host_exposed_s"] += exposed_s
+        self._exposed_s += exposed_s
+        self._cost_s += cost_s
+        if steps is None:
+            return None
+        # a row that started the scan and emitted fewer tokens than it has
+        # steps had finished inside it, or before it where it was chained
+        # (a speculative step emits several: never counted negative)
+        st["scan_row_steps_masked"] += sum(
+            max(0, steps - len(toks)) for toks in emitted.values())
+        scan_s = max(cycle_s - exposed_s, 0.0) if timed else usual
+        if 0.0 < usual * _STALL_FACTOR < scan_s:
+            st["scans_stalled"] += 1
+            st["scan_stall_s"] += scan_s - usual
+            log.warning(
+                "round %d: a %d-step scan of %d rows took %.3f s where "
+                "%.3f is usual: %s", self._round, steps, len(emitted),
+                scan_s, usual, note)
+        host_s, self._exposed_s = self._exposed_s, 0.0
+        self._cost_ms[steps] = _mean(
+            self._cost_ms.get(steps, 0.0), self._cost_s * 1e3)
+        self._cost_s = 0.0
+        return steps, scan_s, host_s
+
     def _engine_round(self) -> Optional[Tuple[int, float, float]]:
-        """One blocking engine round on the worker thread. Returns what a
-        scan measured for ``_retune`` — (steps, the scan's seconds less the
-        engine's host phases, the host's seconds: the gap since the last
-        round plus those phases) — and None after a ragged round, which is
-        as long as the prompt tokens it admits, whatever the level, and so
-        says nothing about how long a scan should be. (The gap *before* a
-        scan, not the one after: a row that ends in a scan is followed by
+        """One engine round on the worker thread. Returns what a scan
+        measured for ``_retune`` (``_scan_measured``: the scan whose tokens
+        came back, which is the one before the scan this round dispatched
+        where the engine leaves scans unread) and None after a ragged
+        round, which is as long as the prompt tokens it admits, whatever the
+        level, and so says nothing about how long a scan should be. (The
+        gap *before* a scan, not the one after: a row that ends in a scan is followed by
         its slot's next admission, which is no cost of a round; that gap
         falls to the ragged round it precedes. On the v5e, charged to the
         scan, it carried two cells' level to T=16: PERF.md, PR 26.)
@@ -1416,7 +1567,11 @@ class ContinuousBatcher:
         dispatch → commit, no competing prefill dispatch. With no
         admission in flight a ragged round degenerates to pure decode, so
         the multi-step scan (horizon amortization of the host RTT) is the
-        better dispatch for the identical math and runs instead."""
+        better dispatch for the identical math and runs instead — left
+        unread on the device wherever the engine can do that
+        (``supports_scan_ahead``), for the next round to go out behind it:
+        whether it does is decided when the next round is known
+        (``_chain_break``), from what the loop holds then."""
         t0 = time.perf_counter()
         st = self.stats
         gap = 0.0
@@ -1427,42 +1582,94 @@ class ContinuousBatcher:
         engine_stats = getattr(self.engine, "stats", None) or {}
         n = self._round = int(engine_stats.get("rounds", self._round)) + 1
         ragged = bool(self._ragged)
+        can = not ragged and bool(
+            getattr(self.engine, "supports_scan_ahead", False))
+        ahead = can and self._spec_wave is None and not self._foreign
         steps, reason = (1, "ragged") if ragged else self._choose_steps()
+        # the scan this round's goes out behind; whatever the engine does
+        # with the call, it reads that one
+        behind, self._unread_steps = self._unread_steps, None
+        if behind is not None and not (
+                self.engine.scan_unread and (ahead or ragged)):
+            # an out-of-band engine call read it since the loop looked, or
+            # waits for the thread: this round's call reads it first
+            st["chain_breaks_signal"] += 1
+            behind = None
         try:
             with flight.span("dgi.batcher.round", st,
                              None if ragged else f"scan_s_t{steps}",
                              round=n, kind="ragged" if ragged else "scan",
                              steps=steps, level=self._levels[self._level],
-                             reason=reason, queue_depth=len(self._heap)):
+                             reason=reason, queue_depth=len(self._heap),
+                             chained=int(behind is not None and not ragged)):
                 if ragged:
                     adms = [adm for adm, _ in self._ragged]
                     self.engine.ragged_round(
                         adms, self._prefill_chunk_caps(adms))
                     st["ragged_rounds"] += 1
+                    # (the engine reads an unread scan first: none is left
+                    # where ``_chain_break`` saw the admission come)
+                    st["chain_breaks_admission"] += behind is not None
                     return None
                 st[f"scans_t{steps}"] = st.get(f"scans_t{steps}", 0) + 1
                 st[f"scans_{reason}"] += 1
-                host = -self._host_phases_s(engine_stats)
+                st["scans_chained"] += behind is not None
+                # a scan read by the call that made it although the engine
+                # could leave it: an out-of-band call waits for the thread,
+                # or a speculative wave shares the rounds
+                st["chain_breaks_signal"] += can and not ahead
+                exposed = -self._host_exposed_s(engine_stats)
+                cost = gap - self._host_phases_s(engine_stats)
                 wait = -engine_stats.get("round_readback_s", 0.0)
-                emitted = self.engine.decode_multi(steps)
-                host += self._host_phases_s(engine_stats)
+                if ahead:
+                    emitted, back = \
+                        self.engine.decode_multi(steps, ahead=True), behind
+                    if self.engine.scan_unread:
+                        self._unread_steps = steps
+                else:
+                    emitted, back = self.engine.decode_multi(steps), steps
+                exposed += self._host_exposed_s(engine_stats)
+                cost += self._host_phases_s(engine_stats)
                 wait += engine_stats.get("round_readback_s", 0.0)
-                # a row that started the scan and emitted fewer tokens than
-                # it has steps had finished inside it (a speculative step
-                # emits several: never counted negative)
-                st["scan_row_steps_masked"] += sum(
-                    max(0, steps - len(toks)) for toks in emitted.values())
-            scan_s = time.perf_counter() - t0 - host
-            usual = steps * st["step_latency_ema_ms"] * 1e-3
-            if 0.0 < usual * _STALL_FACTOR < scan_s:
-                st["scans_stalled"] += 1
-                st["scan_stall_s"] += scan_s - usual
-                log.warning(
-                    "round %d: a %d-step scan of %d rows took %.3f s where "
-                    "%.3f is usual: host phases %.3f s, wait for the device "
-                    "%.3f s, gap before it %.3f s", n, steps, len(emitted),
-                    scan_s + host, usual, host, wait, gap)
-            return steps, scan_s, gap + host
+            call = time.perf_counter() - t0
+            return self._scan_measured(
+                back, emitted, gap, call, exposed, cost, behind is not None,
+                f"the call {call:.3f} s, its wait for the device "
+                f"{wait:.3f} s, the gap before it {gap:.3f} s")
+        finally:
+            self._round_end = time.perf_counter()
+
+    def _collect_round(self, why: str
+                       ) -> Optional[Tuple[int, float, float]]:
+        """Read the unread scan back on the worker thread, because the next
+        round does not go out behind it (``why``: ``_chain_break``), and
+        return what it measured for ``_retune``."""
+        t0 = time.perf_counter()
+        st = self.stats
+        gap = 0.0
+        if self._round_end is not None:
+            # the loop's time before this read falls to the round that is
+            # dispatched next: one gap counted, both halves in its seconds
+            gap = t0 - self._round_end
+            st["between_rounds_s"] += gap
+        st[f"chain_breaks_{why}"] += 1
+        engine_stats = getattr(self.engine, "stats", None) or {}
+        steps, self._unread_steps = self._unread_steps, None
+        try:
+            if not self.engine.scan_unread:
+                return None     # the engine read it for another call
+            with flight.span("dgi.batcher.round", round=self._round,
+                             kind="collect", steps=steps, reason=why,
+                             queue_depth=len(self._heap), chained=0):
+                exposed = -self._host_exposed_s(engine_stats)
+                cost = gap - self._host_phases_s(engine_stats)
+                emitted = self.engine.collect_scan()
+                exposed += self._host_exposed_s(engine_stats)
+                cost += self._host_phases_s(engine_stats)
+            call = time.perf_counter() - t0
+            return self._scan_measured(
+                steps, emitted, gap, call, exposed, cost, True,
+                f"read back {call:.3f} s after a gap of {gap:.3f} s")
         finally:
             self._round_end = time.perf_counter()
 
@@ -1475,14 +1682,28 @@ class ContinuousBatcher:
         set of compiled decode graphs stays bounded; a fixed horizon
         (``adaptive: false``) has one level and keeps it.
 
+        ``h`` is the host's time the CHIP IDLED THROUGH (``host_s``): the
+        rule buys the host's share down with longer scans because that time
+        is the chip's, so what the host does while a scan runs (scans
+        dispatched behind an unread one) is no cost of a round to it, and a
+        round whose host work outlasts its scan costs the excess. What a
+        round costs the host, hidden or not, is kept beside it
+        (``_scan_measured``); where every scan is read by the call that
+        made it the two are one number.
+
         ``h`` is kept per scan length, and a level is judged by its own:
         the host's time around a longer scan also holds work that grows
         with its steps (block reservation, streaming its tokens), which no
         longer scan buys down — judged by a raised scan's cost the level
         would call for a longer scan still, and stay there. The level below
-        is judged by its own cost or the current level's, whichever is less
-        (a shorter scan costs the host no more, so what is measured now
-        corrects a figure from a busier or emptier moment), and without
+        is judged by its own ``h`` or by the most it can idle the chip
+        going by the current level's times, whichever is less: a shorter
+        scan costs the host no more, so it idles the chip for what the
+        current level does plus, at the most, the part of the current
+        round's cost that the shorter scan would not cover (nothing, where
+        every scan is read by its own call or the host keeps up: then that
+        is the current level's ``h``, and what is measured now corrects a
+        figure from a busier or emptier moment) — and without
         the slack while it has never run: one visit measures it. Until the
         current level has run, the level stays. (Nothing refreshes the cost
         of a level the batcher has left upward, so a level that failed in a
@@ -1500,7 +1721,9 @@ class ContinuousBatcher:
                 _HOST_AMORTISE * h(levels[level], 0.0) * (1.0 - _LEVEL_SLACK):
             level += 1
         while level > 0 and levels[level - 1] * s >= _HOST_AMORTISE * min(
-                h(levels[level - 1], inf), h(levels[level], inf)
+                h(levels[level - 1], inf),
+                h(levels[level], inf) + max(0.0, self._cost_ms.get(
+                    levels[level], 0.0) - levels[level - 1] * s)
         ) * (1.0 + _LEVEL_SLACK * (levels[level - 1] in self._host_ms)):
             level -= 1
         self._level = level
@@ -1512,6 +1735,21 @@ class ContinuousBatcher:
         loop = asyncio.get_running_loop()
         latch_until = 0.0
         while True:
+            if self._unread_steps is not None:
+                # a scan is unread on the device. While the next round is
+                # another scan over its rows the loop goes straight on to
+                # dispatch it behind that one (nothing below touches the
+                # engine then); if not, the scan is read and delivered
+                # first and the loop is the one it always was
+                why = self._chain_break()
+                if why is not None:
+                    try:
+                        await self._deliver(await loop.run_in_executor(
+                            self._exec, self._collect_round, why))
+                    except asyncio.CancelledError:
+                        raise
+                    except Exception as e:
+                        await self._fail_in_flight(e)
             # idle = no batcher-OWNED work. Deliberately not engine.num_active:
             # a foreign slot (PD sequence retained/adopted between stages,
             # awaiting its decode job) must neither keep this loop spinning
@@ -1565,109 +1803,140 @@ class ContinuousBatcher:
                 measured = await loop.run_in_executor(
                     self._exec, self._engine_round
                 )
-                with flight.span("dgi.batcher.deliver", self.stats,
-                                 "deliver_s") as delivered:
-                    finished = 0
-                    self.stats["decode_rounds"] += 1
-                    self.stats["occupancy_sum"] += self.engine.num_active
-                    # only what a scan measured steers the scan level
-                    if measured:
-                        self._retune(*measured)
-                    # admission-chunk rounds on the timeline: one bounded
-                    # note per in-flight traced admission per round
-                    # (saturates at the per-request event cap on
-                    # pathological prompts)
-                    for adm, item in self._ragged:
-                        if item.flight is not None:
-                            self._note(item, "batcher.chunk_round",
-                                       off=adm.off, round=self._round)
-                    # ragged admissions whose final chunk sampled its first
-                    # token this round join the batch (the finished-slot
-                    # sweep below then resolves any that immediately hit
-                    # stop/length)
-                    for adm, item in [p for p in self._ragged if p[0].done]:
-                        self._ragged.remove((adm, item))
-                        self._slot_items[adm.slot] = item
-                        self._admit_stamp[adm.slot] = next(self._stamp)
-                        self.stats["admitted"] += 1
-                        self._note_first_token(item, adm.slot,
-                                               round=self._round)
-                    for i, s in enumerate(list(self.engine.slots)):
-                        if s is not None and s.finish_reason is not None \
-                                and i in self._slot_items:
-                            # OWNED slots only: a foreign sequence that
-                            # finished while sharing our rounds (PD
-                            # retained/awaiting adoption) keeps its slot
-                            # until its owner collects it — finishing it
-                            # here would discard the response
-                            resp = await loop.run_in_executor(
-                                self._exec, self.engine.finish_slot, i
-                            )
-                            item = self._slot_items.pop(i, None)
-                            finished += 1
-                            if item and not item.future.done():
-                                self._note(item, "batcher.completed",
-                                           finish_reason=resp.finish_reason,
-                                           tokens=resp.completion_tokens)
-                                item.future.set_result(resp)
-                                self.stats["completed"] += 1
-                    # streaming observers see each surviving slot's
-                    # monotonic token list once per round (finished slots
-                    # resolved above)
-                    self._notify_observers()
-                    # decode-sourced KV pressure: slots froze this round —
-                    # preempt the policy victim so the next round progresses
-                    # (completions above may already have freed blocks; the
-                    # check skips if every frozen slot resolved). An
-                    # unpressured round releases the resume hold.
-                    await self._check_pressure(after_round=True)
-                    delivered.set(finished=finished)
+                self.stats["decode_rounds"] += 1
+                self.stats["occupancy_sum"] += self.engine.num_active
+                await self._deliver(measured)
             except asyncio.CancelledError:
                 raise
             except Exception as e:
-                # a failed round must not wedge the batcher: fail every
-                # in-flight request, abort its slot, keep serving the queue
-                self.stats["engine_errors"] = self.stats.get("engine_errors", 0) + 1
-                # mid-prefill admissions aren't in _slot_items yet —
-                # release their slots and resolve their futures here or
-                # the callers hang until timeout
-                for adm, rag_item in list(self._ragged):
-                    try:
-                        await loop.run_in_executor(
-                            self._exec, self.engine.abort_chunked, adm
-                        )
-                    except Exception:
-                        pass
-                    if not rag_item.future.done():
-                        rag_item.future.set_result(
-                            InferenceResponse(
-                                request_id=rag_item.request.request_id,
-                                error=f"engine error: {e}",
-                            )
-                        )
-                        self.stats["completed"] += 1
-                self._ragged.clear()
-                for i in list(self._slot_items):
-                    # fail OWNED slots only — a foreign slot's owner handles
-                    # its own engine-error cleanup (PD decode already does)
-                    if self.engine.slots[i] is not None:
-                        try:
-                            await loop.run_in_executor(
-                                self._exec,
-                                lambda i=i: self.engine.finish_slot(
-                                    i, cache=False),
-                            )
-                        except Exception:
-                            pass
+                await self._fail_in_flight(e)
+
+    async def _deliver(self, measured: Optional[Tuple[int, float, float]]
+                       ) -> None:
+        """What the loop does with what a round brought back: steer the
+        scan level, move admissions that sampled their first token into
+        the batch, resolve finished slots, stream the others' tokens."""
+        loop = asyncio.get_running_loop()
+        with flight.span("dgi.batcher.deliver", self.stats,
+                         "deliver_s") as delivered:
+            finished = 0
+            # only what a scan measured steers the scan level
+            if measured:
+                self._retune(*measured)
+            # admission-chunk rounds on the timeline: one bounded
+            # note per in-flight traced admission per round
+            # (saturates at the per-request event cap on
+            # pathological prompts)
+            for adm, item in self._ragged:
+                if item.flight is not None:
+                    self._note(item, "batcher.chunk_round",
+                               off=adm.off, round=self._round)
+            # ragged admissions whose final chunk sampled its first
+            # token this round join the batch (the finished-slot
+            # sweep below then resolves any that immediately hit
+            # stop/length)
+            for adm, item in [p for p in self._ragged if p[0].done]:
+                self._ragged.remove((adm, item))
+                self._slot_items[adm.slot] = item
+                self._admit_stamp[adm.slot] = next(self._stamp)
+                self.stats["admitted"] += 1
+                self._note_first_token(item, adm.slot,
+                                       round=self._round)
+            if self._unread_steps is not None and (
+                    self.engine.pressure_pending or any(
+                        s is not None and s.finish_reason is not None
+                        and i in self._slot_items
+                        for i, s in enumerate(self.engine.slots))):
+                # finishing a slot and preempting one take the engine,
+                # which reads the unread scan first: read it here, where
+                # its tokens are counted and its times steer the level —
+                # after the streams have what this round brought (the
+                # finished rows' too: their response waits for the read)
+                self._notify_observers(finished=True)
+                more = await loop.run_in_executor(
+                    self._exec, self._collect_round,
+                    "pressure" if self.engine.pressure_pending
+                    else "row_end")
+                if more:
+                    self._retune(*more)
+            for i, s in enumerate(list(self.engine.slots)):
+                if s is not None and s.finish_reason is not None \
+                        and i in self._slot_items:
+                    # OWNED slots only: a foreign sequence that
+                    # finished while sharing our rounds (PD
+                    # retained/awaiting adoption) keeps its slot
+                    # until its owner collects it — finishing it
+                    # here would discard the response
+                    resp = await loop.run_in_executor(
+                        self._exec, self.engine.finish_slot, i
+                    )
                     item = self._slot_items.pop(i, None)
+                    finished += 1
                     if item and not item.future.done():
-                        item.future.set_result(
-                            InferenceResponse(
-                                request_id=item.request.request_id,
-                                error=f"engine error: {e}",
-                            )
-                        )
+                        self._note(item, "batcher.completed",
+                                   finish_reason=resp.finish_reason,
+                                   tokens=resp.completion_tokens)
+                        item.future.set_result(resp)
                         self.stats["completed"] += 1
+            # streaming observers see each surviving slot's
+            # monotonic token list once per round (finished slots
+            # resolved above)
+            self._notify_observers()
+            # decode-sourced KV pressure: slots froze this round —
+            # preempt the policy victim so the next round progresses
+            # (completions above may already have freed blocks; the
+            # check skips if every frozen slot resolved). An
+            # unpressured round releases the resume hold.
+            await self._check_pressure(after_round=True)
+            delivered.set(finished=finished)
+
+    async def _fail_in_flight(self, e: Exception) -> None:
+        """A failed round must not wedge the batcher: fail every in-flight
+        request, abort its slot, keep serving the queue."""
+        loop = asyncio.get_running_loop()
+        self.stats["engine_errors"] = self.stats.get("engine_errors", 0) + 1
+        # whatever was unread went with the engine's device state
+        self._unread_steps = None
+        # mid-prefill admissions aren't in _slot_items yet —
+        # release their slots and resolve their futures here or
+        # the callers hang until timeout
+        for adm, rag_item in list(self._ragged):
+            try:
+                await loop.run_in_executor(
+                    self._exec, self.engine.abort_chunked, adm
+                )
+            except Exception:
+                pass
+            if not rag_item.future.done():
+                rag_item.future.set_result(
+                    InferenceResponse(
+                        request_id=rag_item.request.request_id,
+                        error=f"engine error: {e}",
+                    )
+                )
+                self.stats["completed"] += 1
+        self._ragged.clear()
+        for i in list(self._slot_items):
+            # fail OWNED slots only — a foreign slot's owner handles
+            # its own engine-error cleanup (PD decode already does)
+            if self.engine.slots[i] is not None:
+                try:
+                    await loop.run_in_executor(
+                        self._exec,
+                        lambda i=i: self.engine.finish_slot(
+                            i, cache=False),
+                    )
+                except Exception:
+                    pass
+            item = self._slot_items.pop(i, None)
+            if item and not item.future.done():
+                item.future.set_result(
+                    InferenceResponse(
+                        request_id=item.request.request_id,
+                        error=f"engine error: {e}",
+                    )
+                )
+                self.stats["completed"] += 1
 
     def get_stats(self) -> Dict[str, Any]:
         out = dict(self.stats)
@@ -1795,9 +2064,25 @@ class BatcherServing:
         call the batcher makes runs on that SAME single thread, so this is
         the serialization point for out-of-band engine work (PD prefill,
         handoff adoption): no lock ordering, no mid-round interleaving —
-        the work simply runs between rounds."""
-        assert self.batcher is not None
-        return self.batcher._exec.submit(fn, *args, **kw).result()
+        the work simply runs between rounds, on host mirrors that are
+        current: a scan the loop left unread is read first (the loop counts
+        it as a chain broken by a ``signal``), and while a caller waits
+        here the loop leaves none (``ContinuousBatcher._foreign``). The read
+        is an item of its own in front of ``fn``, not a wrapper around it:
+        on the v5e the warm-up's lowering of the round graphs
+        (``lower_serving_graphs``, 24 s of tracing) took 2 s longer inside
+        one more Python frame (PERF.md, PR 32)."""
+        b = self.batcher
+        assert b is not None
+        with b._foreign_lock:
+            b._foreign += 1
+        try:
+            if hasattr(b.engine, "collect_scan"):
+                b._exec.submit(b.engine.collect_scan)
+            return b._exec.submit(fn, *args, **kw).result()
+        finally:
+            with b._foreign_lock:
+                b._foreign -= 1
 
     def reconfigure(self, **updates: Any) -> None:
         """Thread-safe config push: applied on the loop thread between

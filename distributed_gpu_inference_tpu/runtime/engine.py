@@ -217,6 +217,21 @@ class _Slot:
 
 
 @dataclass
+class _UnreadScan:
+    """A decode scan that was dispatched and whose tokens the host has not
+    read back: the one the next scan may be dispatched behind
+    (``decode_multi(..., ahead=True)``). ``active_mask`` is the host's view
+    of its rows when it went out (a chained scan's rows are those of them
+    the device still found live), ``steps`` what each may take of it."""
+
+    num_steps: int
+    active_mask: np.ndarray
+    steps: np.ndarray
+    emitted: jax.Array
+    moe: List[jax.Array]
+
+
+@dataclass
 class ChunkedAdmission:
     """In-flight chunked admission (``submit_chunked_start``): a bound
     slot whose prompt is still to be prefilled.
@@ -541,6 +556,19 @@ class TPUEngine:
         # a dispatch of its own in front of the round's.
         self._dev_core: Optional[Dict[str, jax.Array]] = None
         self._core_dirty = True
+        # The one scan whose tokens are still on the device (``decode_multi``
+        # with ``ahead``): the host mirrors lag by it until ``collect_scan``,
+        # which every other entry that reads or writes slots, pool or
+        # mirrors calls first. The device orders its own work; only the
+        # host's view lags.
+        self._unread: Optional[_UnreadScan] = None
+        # whether the scan read last was still running when the host came
+        # for it: then the chip had work up to the read, and the time since
+        # the read before is that scan's own
+        self.scan_read_running = True
+        # the speculative rounds replay their bookkeeping on the host
+        # between dispatches: nothing of theirs can go out ahead
+        self.supports_scan_ahead = self.cfg.speculative is None
 
         # integrated speculative decoding: EAGLE-style draft head weights +
         # per-slot last-verified hidden state (device-resident between
@@ -589,6 +617,11 @@ class TPUEngine:
             "ragged_positions_dispatched": 0, "ragged_positions_live": 0,
             "round_build_s": 0.0, "round_dispatch_s": 0.0,
             "round_readback_s": 0.0, "round_commit_s": 0.0,
+            # what of a scan's build, dispatch and commit ran while no scan
+            # of the engine's was on the device: all of it where every scan
+            # is read back by the call that made it, little where the next
+            # scan goes out ahead of the readback
+            "round_host_exposed_s": 0.0,
             # which KV path the multi-token graphs were built with
             # (in_place / layer_copy): a trace-time fact, from the
             # predicate forward_chunk itself dispatches on
@@ -931,6 +964,23 @@ class TPUEngine:
 
         self._unpack_sched_fn = jax.jit(
             unpack_sched, out_shardings=replicated
+        )
+
+        def chain_sched(core, ends):
+            # The rows and budgets of a scan dispatched BEHIND one the host
+            # has not read, from where that one leaves the device core:
+            # ``ends`` is the committed length at which each of its rows
+            # runs out of budget (0: not a row of it), so a row that used
+            # its budget up or whose last token is a stop id is out, as the
+            # scan itself masked it, and the others have what is left. The
+            # shapes do not depend on the scan length: one program.
+            left = ends - core["lens"]
+            live = (left > 0) & ~jnp.any(
+                core["last"][:, None] == core["stops"], axis=1)
+            return live, jnp.where(live, left, 0)
+
+        self._chain_sched_fn = jax.jit(
+            chain_sched, out_shardings=replicated
         )
 
         # --- sampling fused into the serving graphs. ``mode`` is static:
@@ -1598,8 +1648,12 @@ class TPUEngine:
         graph will run is read from the lowered text (``kernel_name =
         "..."`` marks a Pallas call), and ``.compile()`` on an entry
         stores the program in the persistent compile cache, where the
-        first real round finds it. Plain (non-speculative) engines; call
-        while no round is in flight."""
+        first real round finds it. With a scan length comes
+        ``chain_sched``, the small program that schedules a scan
+        dispatched behind an unread one (one program for every length),
+        which is also run once here so that it is in memory. Plain
+        (non-speculative) engines; call while no round is in flight."""
+        self.collect_scan()
         b = len(self.slots)
         core = self._sync_core()
         tables, active, budgets = self._sched_arrays(
@@ -1611,6 +1665,9 @@ class TPUEngine:
                 self.params, self.kv, core, tables, active, budgets,
                 int(t), "greedy",
             )
+        if out:
+            out["chain_sched"] = self._chain_sched_fn.lower(core, budgets)
+            self._chain_sched_fn(core, budgets)
         most, below = b * max(map(int, ragged_widths), default=0), 0
         for tp in self._ragged_ladder():
             if most <= below:       # the rung below takes every such round
@@ -1777,6 +1834,12 @@ class TPUEngine:
         killing requests the pool could trivially hold a moment later."""
         return self._fits_empty_pool(pre.prompt_len + len(pre.generated) + 1)
 
+    @property
+    def pressure_pending(self) -> bool:
+        """A pressure signal waits for ``take_pressure`` (a look, for a
+        caller that must not consume it yet)."""
+        return self._pressure is not None
+
     def take_pressure(self) -> Optional[KVPressure]:
         """Consume the pending pressure signal (None when the last round
         ran unpressured). The scheduler calls this after every engine round
@@ -1795,7 +1858,12 @@ class TPUEngine:
         ``generated`` may include the pending token (sampled, KV unwritten);
         resume treats the whole list as prompt suffix and recomputes, so the
         distinction never leaks. Mid-prefill and finished slots have nothing
-        useful to checkpoint and are rejected."""
+        useful to checkpoint and are rejected.
+
+        The one reader that does NOT read an unread scan first: it only
+        copies host state, the worker calls it from its heartbeat thread
+        beside the engine thread, and a checkpoint that lags by a scan is
+        the checkpoint of a moment ago, as valid as any."""
         s = self.slots[slot]
         if s is None:
             raise ValueError(f"slot {slot} is empty")
@@ -1826,6 +1894,7 @@ class TPUEngine:
         is dropped from the manager's token log first, so only fully
         written blocks can be cached/spilled; it stays in ``generated`` and
         is recomputed by the resume prefill."""
+        self.collect_scan()
         s = self.slots[slot]
         if s is None:
             raise ValueError(f"slot {slot} is empty")
@@ -1878,6 +1947,7 @@ class TPUEngine:
 
         Raises OutOfBlocksError (state untouched) when the pool still
         cannot hold the sequence — the scheduler retries later."""
+        self.collect_scan()
         sp = pre.request.sampling
         remaining = sp.max_new_tokens - len(pre.generated)
         if remaining <= 0:
@@ -1924,6 +1994,7 @@ class TPUEngine:
     def submit(self, request: InferenceRequest, slot: Optional[int] = None) -> int:
         """Admit a request into a slot: allocate blocks (prefix-cache aware),
         run prefill, sample the first token. Returns the slot index."""
+        self.collect_scan()
         if slot is None:
             free = self.free_slots()
             if not free:
@@ -1967,6 +2038,7 @@ class TPUEngine:
         With ``partial=False`` (default) exhaustion rolls back the whole
         wave and raises ``OutOfBlocksError`` after signalling pressure;
         state is clean either way."""
+        self.collect_scan()
         if not requests:
             return []
         free = self.free_slots()
@@ -2101,6 +2173,7 @@ class TPUEngine:
         ids) for a sequence already allocated in the manager. Shared by the
         prefill submit path and the PD-handoff adopt path so the two can
         never drift."""
+        self.collect_scan()
         self.slots[slot] = s
         self._block_tables[slot] = self.manager.block_table_for(
             s.seq_id, self.cfg.max_blocks_per_seq
@@ -2258,6 +2331,7 @@ class TPUEngine:
         """Begin a chunk-interleaved admission: allocate + bind the slot but
         run NO prefill yet. The slot is marked ``prefilling`` so decode
         rounds skip it until ``submit_chunked_step`` finishes the prompt."""
+        self.collect_scan()
         if slot is None:
             free = self.free_slots()
             if not free:
@@ -2294,6 +2368,7 @@ class TPUEngine:
         admission completed (first token sampled). Work per call is bounded
         by the largest bucket, so a scheduler can interleave decode rounds
         between calls and no active slot stalls longer than one chunk."""
+        self.collect_scan()
         if adm.done:
             return True
         s = self.slots[adm.slot]
@@ -2340,6 +2415,7 @@ class TPUEngine:
 
     def abort_chunked(self, adm: ChunkedAdmission) -> None:
         """Release a failed/cancelled chunked admission's slot and blocks."""
+        self.collect_scan()
         s = self.slots[adm.slot]
         adm.done = True
         if s is None or s.seq_id != adm.seq_id:
@@ -2380,6 +2456,7 @@ class TPUEngine:
         no reservation — it retries next round). Chunked prefill is
         chunk-width-invariant, so any cap schedule yields byte-identical
         outputs; caps only shape WHEN prefill work lands."""
+        self.collect_scan()
         st = self.stats
         st["rounds"] += 1
         with flight.span("dgi.engine.ragged_round", round=st["rounds"],
@@ -2429,15 +2506,17 @@ class TPUEngine:
         st["ragged_positions_dispatched"] += positions
         st["ragged_positions_live"] += decode_tokens + live_prompt
 
-    def _count_moe(self, sp: flight.span, kind: str,
+    def _count_moe(self, sp: Optional[flight.span], kind: str,
                    moe: Sequence[np.ndarray]) -> None:
         """A round's routed-expert counters (``_MOE_COUNTERS``, summed over
-        its layer calls on the device) onto its span and into the counters
+        its layer calls on the device) onto its span (where the read has
+        one: a scan read for another entry has none) and into the counters
         of its ``kind``. Empty: the round ran no routed layer."""
         if not moe:
             return
         held = {name: int(v) for name, v in zip(self._moe_names, moe[0])}
-        sp.set(**{f"moe_{name}": v for name, v in held.items()})
+        if sp is not None:
+            sp.set(**{f"moe_{name}": v for name, v in held.items()})
         for name, v in held.items():
             self.stats[f"moe_{name}_{kind}"] += v
 
@@ -2945,6 +3024,7 @@ class TPUEngine:
         pending token (writing its KV at position ``_kv_lens``), samples the
         next. Returns {slot: sampled_token} (stop tokens included, then the
         slot finishes)."""
+        self.collect_scan()
         active = [
             i for i, s in enumerate(self.slots)
             if s is not None and s.finish_reason is None and not s.prefilling
@@ -3097,6 +3177,7 @@ class TPUEngine:
         decode). Rounds bucket to powers of two so at most log2 variants
         compile; per-round records replay on the host so cache-manager
         commits and emission bookkeeping exactly match the per-step path."""
+        self.collect_scan()
         spec = self.cfg.speculative
         assert spec is not None and self._spec_rounds_fn is not None
         active = [
@@ -3255,10 +3336,22 @@ class TPUEngine:
             steps=steps, **kw,
         )
 
-    def decode_multi(self, num_steps: Optional[int] = None) -> Dict[int, List[int]]:
+    def decode_multi(self, num_steps: Optional[int] = None,
+                     ahead: bool = False) -> Dict[int, List[int]]:
         """Run T decode steps in one device call (lax.scan) with on-device
         stop masking; host sees tokens only at the end. TPU-first throughput
         path — amortizes per-token host round-trips.
+
+        ``ahead`` is for the caller that owns the round loop and sees that
+        its next round is another scan: the scan is dispatched and LEFT
+        UNREAD, and what comes back is the tokens of the scan the last such
+        call left (nothing after a call that read its own). Where one is
+        unread, the new scan goes out behind it, its rows and budgets taken
+        on the device from what the unread one leaves there, so the host's
+        build, upload, commit and whatever the caller does between calls
+        run while the device does. At most one scan is unread at a time;
+        every other entry of the engine reads it first (``collect_scan``).
+        Without ``ahead`` the call returns its own tokens, as it always did.
 
         With ``EngineConfig.speculative`` set, the T steps are fused
         draft→verify→accept rounds instead — each commits 1..K+1 tokens per
@@ -3270,51 +3363,140 @@ class TPUEngine:
                          steps=num_steps) as sp:
             if self.cfg.speculative is not None:
                 return self._spec_decode_rounds(num_steps)
-            return self._plain_decode_multi(num_steps, sp)
+            return self._plain_decode_multi(num_steps, sp, ahead)
 
-    def _plain_decode_multi(self, num_steps: int, sp: flight.span
-                            ) -> Dict[int, List[int]]:
+    @property
+    def scan_unread(self) -> bool:
+        """A scan's tokens are still on the device: the host mirrors lag."""
+        return self._unread is not None
+
+    def scan_ends_row(self) -> bool:
+        """A row of the unread scan reaches its budget inside it: its slot
+        comes free when the scan is read (short of that, only a stop id
+        ends a row, which the host cannot know before it reads)."""
+        prev = self._unread
+        return prev is not None and bool(
+            (prev.active_mask
+             & (self._host_budgets() <= prev.num_steps)).any())
+
+    def collect_scan(self, sp: Optional[flight.span] = None
+                     ) -> Dict[int, List[int]]:
+        """THE collect point: read the unread scan back and commit it, so
+        that the host mirrors (``_last_tokens``, ``_kv_lens``, the slots'
+        ``generated`` and ``finish_reason``, the manager's token lists) are
+        current. ``{}`` when nothing is unread. Every entry that reads or
+        writes slots, pool or mirrors calls it first; the round loop calls
+        it when its next round is not a scan over the same rows."""
+        scan, self._unread = self._unread, None
+        return {} if scan is None else self._collect(scan, sp)
+
+    def _plain_decode_multi(self, num_steps: int, sp: flight.span,
+                            ahead: bool = False) -> Dict[int, List[int]]:
         """The plain scan of ``decode_multi``, in the four phases of a
         round (build, dispatch, readback, commit), each a span inside
-        ``sp`` and a time counter."""
-        with flight.span("dgi.engine.decode_multi.build", self.stats,
+        ``sp`` and a time counter; with ``ahead``, the readback and commit
+        are the previous scan's."""
+        st = self.stats
+        out: Dict[int, List[int]] = {}
+        if self._unread is not None and not (
+                ahead and not self._core_dirty
+                and self._dev_core is not None):
+            out = self.collect_scan(sp)
+        prev = self._unread
+        # the build and the dispatch cost the chip nothing while a scan of
+        # ours still runs on it (a poll)
+        hidden = prev is not None and not prev.emitted.is_ready()
+        t0 = time.perf_counter()
+        with flight.span("dgi.engine.decode_multi.build", st,
                          "round_build_s"):
-            built = self._build_decode_multi(num_steps)
+            try:
+                built = self._build_decode_multi(num_steps, prev)
+            except OutOfBlocksError:
+                # no room to reserve a scan AHEAD of the unread one: read it
+                # first and hand back what the attempt took, then the
+                # build is the one a call that reads its own scan makes
+                # (freeze the row, signal the pressure)
+                out = self.collect_scan(sp)
+                for i in np.flatnonzero(prev.active_mask):
+                    s = self.slots[i]
+                    if s is not None:
+                        self.manager.trim_reserved(s.seq_id)
+                prev, hidden = None, False
+                built = self._build_decode_multi(num_steps, None)
         if built is None:
-            return {}
-        active_mask, operands, mode = built
-        rows = int(active_mask.sum())
-        sp.set(decode_rows=rows, positions=len(self.slots) * num_steps)
-        with flight.span("dgi.engine.decode_multi.dispatch", self.stats,
+            # no row is left to run: nothing to leave unread either
+            return self._merge(out, self.collect_scan(sp))
+        scan, operands, mode = built
+        sp.set(decode_rows=int(scan.active_mask.sum()),
+               positions=len(self.slots) * num_steps,
+               chained=int(prev is not None))
+        with flight.span("dgi.engine.decode_multi.dispatch", st,
                          "round_dispatch_s"):
             try:
-                self.kv, self._dev_core, emitted, *moe = self._decode_multi_fn(
-                    self.params, self.kv, *operands, num_steps, mode,
-                )
+                self.kv, self._dev_core, scan.emitted, *scan.moe = \
+                    self._decode_multi_fn(
+                        self.params, self.kv, *operands, num_steps, mode,
+                    )
             except Exception:
+                self._unread = None
                 self._invalidate_device_state()
                 raise
-        self.stats["decode_calls"] += num_steps
-        with flight.span("dgi.engine.decode_multi.readback", self.stats,
+        st["decode_calls"] += num_steps
+        if not hidden:
+            st["round_host_exposed_s"] += time.perf_counter() - t0
+        self._unread = scan
+        if prev is not None:
+            self._merge(out, self._collect(prev, sp))
+        if not ahead:
+            self._merge(out, self.collect_scan(sp))
+        elif prev is None:
+            # a call that waits for no scan still returns only once the
+            # device has taken its uploads, the last thing in front of the
+            # scan: the scan then starts inside the call's span on a
+            # profiler's clock, as every other scan does (it starts when
+            # the scan before it ends, which its call waits for)
+            jax.block_until_ready(operands[1])
+        return out
+
+    @staticmethod
+    def _merge(out: Dict[int, List[int]], more: Dict[int, List[int]]
+               ) -> Dict[int, List[int]]:
+        for slot, toks in more.items():
+            out.setdefault(slot, []).extend(toks)
+        return out
+
+    def _collect(self, scan: _UnreadScan, sp: Optional[flight.span]
+                 ) -> Dict[int, List[int]]:
+        """Readback and commit of one dispatched scan."""
+        st = self.stats
+        with flight.span("dgi.engine.decode_multi.readback", st,
                          "round_readback_s"):
             # [B, T], -1 = masked-out step: the wait for the device; the
             # experts' counters come with it
-            emitted, moe = jax.device_get((emitted, moe))
+            self.scan_read_running = not scan.emitted.is_ready()
+            try:
+                emitted, moe = jax.device_get((scan.emitted, scan.moe))
+            except Exception:
+                # whatever else is outstanding went out behind this scan
+                self._unread = None
+                self._invalidate_device_state()
+                raise
+        t0 = time.perf_counter()
         self._count_moe(sp, "scan", moe)
-        with flight.span("dgi.engine.decode_multi.commit", self.stats,
+        with flight.span("dgi.engine.decode_multi.commit", st,
                          "round_commit_s"):
             out: Dict[int, List[int]] = {}
             for i, s in enumerate(self.slots):
-                if not active_mask[i] or s is None:
+                if not scan.active_mask[i] or s is None:
                     continue
                 toks = [int(t) for t in emitted[i] if t >= 0]
                 out[i] = toks
-                if "mla_row_steps_scan" in self.stats:
+                if "mla_row_steps_scan" in st:
                     # step t of the row attended its cache and the token
                     # the step wrote: len + 1 ... len + n
                     n = len(toks)
-                    self.stats["mla_row_steps_scan"] += n
-                    self.stats["mla_context_tokens_scan"] += (
+                    st["mla_row_steps_scan"] += n
+                    st["mla_context_tokens_scan"] += (
                         n * int(self._kv_lens[i]) + n * (n + 1) // 2)
                 # each emitted token corresponds to one scan step that fed
                 # (and thus committed) the previous pending token
@@ -3330,22 +3512,34 @@ class TPUEngine:
                 commit = toks if s.finish_reason is None else toks[:-1]
                 self.manager.commit_tokens(s.seq_id, commit)
                 self._maybe_release_window(i)
+        if self._unread is None:
+            # nothing went out behind it: the chip waited for this commit
+            st["round_host_exposed_s"] += time.perf_counter() - t0
         return out
 
-    def _build_decode_multi(self, num_steps: int
-                            ) -> Optional[Tuple[np.ndarray, Tuple[Any, ...],
+    def _build_decode_multi(self, num_steps: int,
+                            prev: Optional[_UnreadScan] = None
+                            ) -> Optional[Tuple[_UnreadScan, Tuple[Any, ...],
                                                 str]]:
         """The host's half of a scan before the dispatch: who decodes, the
         budgets, block reservation for the horizon, pending pool ops, the
-        uploads. None when no row is left to run."""
+        uploads. None when no row is left to run. Behind an unread scan
+        ``prev`` the host's budgets are what is left after ``prev`` at the
+        least, the reservation covers ``prev``'s steps and this scan's (the
+        manager counts from the committed length, which lags by ``prev``),
+        and the rows and budgets the device runs come from the device
+        (``chain_sched``); a reservation the pool cannot hold raises
+        ``OutOfBlocksError`` there, for the caller to read ``prev`` first."""
         # per-slot token budgets enforced ON DEVICE (scan masks a slot once
         # it emits its allowance) — num_steps stays the compiled constant
         # instead of shrinking to the shortest slot and recompiling per
         # distinct tail length
-        budgets = self.decode_budgets()
+        raw = self._host_budgets()
+        budgets = self._budgets_after(raw, prev)
         active_mask = budgets > 0
         if not active_mask.any():
             return None
+        steps = np.minimum(num_steps, budgets)
         # pre-reserve KV blocks for each slot's actual horizon (no host
         # alloc mid-scan). A slot whose reservation exhausts the pool is
         # FROZEN for this round (masked out, partial reservation trimmed
@@ -3359,13 +3553,17 @@ class TPUEngine:
                 # trigger token is never appended, so reserving past
                 # max_seq_len would only overflow the block-table width
                 cur = len(self.manager.seq_tokens[s.seq_id])
-                n_res = min(int(min(num_steps, budgets[i])),
-                            self.cfg.max_seq_len - cur)
+                n_res = int(steps[i])
+                if prev is not None:
+                    n_res += int(prev.steps[i])
+                n_res = min(n_res, self.cfg.max_seq_len - cur)
                 if n_res <= 0:
                     continue
                 try:
                     self.manager.reserve_tokens(s.seq_id, n_res)
                 except OutOfBlocksError:
+                    if prev is not None:
+                        raise
                     self.manager.trim_reserved(s.seq_id)
                     active_mask[i] = False
                     pressured.append(i)
@@ -3378,18 +3576,22 @@ class TPUEngine:
             return None
         self._apply_pending()
         core = self._sync_core()
-        tables, act_d, bud_d = self._sched_arrays(
-            active_mask, budgets.astype(np.int32)
-        )
-        return active_mask, (core, tables, act_d, bud_d), self._decode_mode()
+        if prev is None:
+            tables, act_d, bud_d = self._sched_arrays(
+                active_mask, budgets.astype(np.int32)
+            )
+        else:
+            ends = np.where(prev.active_mask, self._kv_lens + raw, 0)
+            tables, _, ends_d = self._sched_arrays(
+                active_mask, ends.astype(np.int32)
+            )
+            act_d, bud_d = self._chain_sched_fn(core, ends_d)
+        scan = _UnreadScan(num_steps, active_mask, steps, None, [])
+        return scan, (core, tables, act_d, bud_d), self._decode_mode()
 
-    def decode_budgets(self) -> np.ndarray:
-        """Tokens each slot may still emit, ``[max_batch_size]`` int32: what
-        is left of ``max_new_tokens`` and of the context, 0 for a slot that
-        does not decode (empty, finished, mid-prefill). The next scan masks
-        a row after that many steps; the smallest positive entry is the
-        first step at which a slot can come free short of a stop token
-        (the batcher's horizon rule reads it)."""
+    def _host_budgets(self) -> np.ndarray:
+        """``decode_budgets`` by the host mirrors as they stand: what they
+        say is left, which lags by the unread scan."""
         return np.array(
             [
                 max(0, min(
@@ -3402,7 +3604,28 @@ class TPUEngine:
             dtype=np.int32,
         )
 
+    @staticmethod
+    def _budgets_after(raw: np.ndarray, prev: Optional[_UnreadScan]
+                       ) -> np.ndarray:
+        if prev is None:
+            return raw
+        return np.where(prev.active_mask,
+                        np.maximum(raw - prev.num_steps, 0), 0)
+
+    def decode_budgets(self) -> np.ndarray:
+        """Tokens each slot may still emit, ``[max_batch_size]`` int32: what
+        is left of ``max_new_tokens`` and of the context, 0 for a slot that
+        does not decode (empty, finished, mid-prefill). The next scan masks
+        a row after that many steps; the smallest positive entry is the
+        first step at which a slot can come free short of a stop token
+        (the batcher's horizon rule reads it). While a scan is unread: what
+        is left after it, for its rows."""
+        return self._budgets_after(self._host_budgets(), self._unread)
+
     def finish_slot(self, slot: int, cache: bool = True) -> InferenceResponse:
+        # the unread scan may hold the slot's last tokens, and has its
+        # blocks in its tables
+        self.collect_scan()
         s = self.slots[slot]
         if s is None:
             raise ValueError(f"slot {slot} empty")
@@ -3449,6 +3672,7 @@ class TPUEngine:
         preempted more than ``max_preemptions`` times finishes with a
         ``preempted_too_often`` error instead of livelocking the wave.
         Clients never see an OutOfBlocksError."""
+        self.collect_scan()
         pending = []
         responses: Dict[str, InferenceResponse] = {}
         for r in requests:

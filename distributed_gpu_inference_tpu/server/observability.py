@@ -103,6 +103,7 @@ class Metrics:
                 "batcher_loop_seconds", "batcher_scans",
                 "batcher_scan_step_ms", "batcher_round_host_ms",
                 "batcher_scan_reasons", "batcher_scan_row_steps_masked",
+                "batcher_scans_chained", "batcher_chain_breaks",
                 "engine_round_seconds", "worker_compiles",
                 "worker_compile_seconds",
                 "prefix_route_hits", "prefix_route_spillover",
@@ -287,7 +288,10 @@ class Metrics:
             "Seconds of the batcher loop by part: between_rounds (one "
             "round's end to the next one's start), and the loop's admit "
             "and deliver steps, which split it (with a speculative wave "
-            "in flight admit includes its engine dispatches)",
+            "in flight admit includes its engine dispatches); "
+            "round_host_exposed: the part of the gaps and of the engine's "
+            "build, dispatch and commit that ran with no scan on the "
+            "device (the chip's idle time the host caused)",
             ["worker", "part"], registry=r)
         self.batcher_scans = Counter(
             "batcher_scans_total",
@@ -305,6 +309,22 @@ class Metrics:
             "Row-steps scans ran for rows that had already finished "
             "inside them (a slot held past its row's end)", ["worker"],
             registry=r)
+        # chained / batcher_scans_total is the share of scans whose round
+        # work the host did while the device ran the scan before
+        self.batcher_scans_chained = Counter(
+            "batcher_scans_chained_total",
+            "decode_multi rounds dispatched while the scan before was "
+            "still unread on the device (the host's work of that round ran "
+            "beside the device's)", ["worker"], registry=r)
+        self.batcher_chain_breaks = Counter(
+            "batcher_chain_breaks_total",
+            "Scans read back before the next round went out, by why: "
+            "admission (a request waits and a slot is free), "
+            "row_end_waiting (a request waits and a row's budget ends in "
+            "the scan), row_end (a row was found finished), signal "
+            "(cancel, interrupt, deadline, an out-of-band engine call), "
+            "pressure (KV pool), idle (no row has a step left)",
+            ["worker", "reason"], registry=r)
         # readback is the engine thread waiting for the device; its share
         # of the four says whether the host or the chip bounds the rounds
         self.engine_round_seconds = Counter(
@@ -791,7 +811,8 @@ class MetricsCollector:
         # round spans' counters: seconds are floats, scan counts carry their
         # level in the key (``scans_t<T>``); same delta anchoring
         for key, value in stats.items():
-            if key in ("between_rounds_s", "admit_s", "deliver_s"):
+            if key in ("between_rounds_s", "admit_s", "deliver_s",
+                       "round_host_exposed_s"):
                 metric = self.metrics.batcher_loop_seconds.labels(
                     worker, key[:-2])
             elif key in ("round_build_s", "round_dispatch_s",
@@ -806,6 +827,11 @@ class MetricsCollector:
                 metric = self.metrics.batcher_round_gaps.labels(worker)
             elif key.startswith("scans_t") and key[7:].isdigit():
                 metric = self.metrics.batcher_scans.labels(worker, key[7:])
+            elif key == "scans_chained":
+                metric = self.metrics.batcher_scans_chained.labels(worker)
+            elif key.startswith("chain_breaks_"):
+                metric = self.metrics.batcher_chain_breaks.labels(
+                    worker, key[13:])
             elif key.startswith("scans_"):
                 metric = self.metrics.batcher_scan_reasons.labels(
                     worker, key[6:])

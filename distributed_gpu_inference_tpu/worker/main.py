@@ -499,15 +499,18 @@ class Worker:
                 if es.get(k):
                     out[k] = es[k]
             for k, src in (("between_rounds_s", s), ("admit_s", s),
-                           ("deliver_s", s), ("round_build_s", es),
+                           ("deliver_s", s), ("round_host_exposed_s", s),
+                           ("round_build_s", es),
                            ("round_dispatch_s", es),
                            ("round_readback_s", es), ("round_commit_s", es)):
                 out[k] = round(out.get(k, 0.0) + float(src.get(k, 0) or 0), 6)
             # scans by level (scans_t<T>) and by why they got their length
-            # (scans_<reason>), and the row-steps they ran past a row's end
+            # (scans_<reason>), the row-steps they ran past a row's end,
+            # the scans dispatched behind an unread one (scans_chained)
+            # and why the others were read first (chain_breaks_<reason>)
             for k in s:
                 if k in ("between_rounds", "scan_row_steps_masked") \
-                        or k.startswith("scans_"):
+                        or k.startswith(("scans_", "chain_breaks_")):
                     out[k] = out.get(k, 0) + int(s[k] or 0)
             # the routed expert layers' counters (MoE engines only)
             # and a latent-attention engine's scan counters (mla_*)

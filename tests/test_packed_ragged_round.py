@@ -227,7 +227,10 @@ def test_ladder_is_no_longer_than_the_buckets_it_replaces():
     # narrow traffic reaches only the rungs eight pieces of it fill
     assert list(eng.lower_serving_graphs([], [16])) == [
         f"ragged_round[Tp={t}]" for t in ladder[:2]]
-    assert eng.lower_serving_graphs([4], []).keys() == {"decode_multi[T=4]"}
+    # with a scan length comes the one program that schedules a scan
+    # dispatched behind an unread one (PR 32)
+    assert eng.lower_serving_graphs([4], []).keys() == {
+        "decode_multi[T=4]", "chain_sched"}
 
 
 @pytest.mark.parametrize("seed", [0, 1])

@@ -1,0 +1,661 @@
+"""One scan kept in flight (PR 32): the batcher dispatches the next decode
+scan before the last one is read back (``TPUEngine.decode_multi(T,
+ahead=True)``), so its round work runs while the device does.
+
+The same work: a chain of length zero is the loop as it always was, and the
+token streams are the same bytes either way. Everything here runs the tiny
+models on the CPU; ``supports_scan_ahead = False`` on the engine instance is
+the unchained control (the batcher asks per call, from what it observes)."""
+
+import asyncio
+import inspect
+import threading
+import types
+
+import numpy as np
+import pytest
+
+from distributed_gpu_inference_tpu.models.configs import get_model_config
+from distributed_gpu_inference_tpu.runtime import batcher as batcher_mod
+from distributed_gpu_inference_tpu.runtime.batcher import (
+    _CHAIN_BREAKS,
+    BatcherConfig,
+    ContinuousBatcher,
+)
+from distributed_gpu_inference_tpu.runtime.engine import EngineConfig, TPUEngine
+from distributed_gpu_inference_tpu.utils.data_structures import (
+    InferenceRequest,
+    SamplingParams,
+)
+from tests.test_scan_level_rule import _batcher, _feed
+
+PROMPTS = [list(range(5, 17)), list(range(40, 59)), list(range(90, 97))]
+BUDGETS = [9, 23, 30]
+
+
+def _engine(model, **kw):
+    cfg = dict(max_batch_size=4, max_seq_len=128, dtype="float32",
+               prefill_buckets=(16, 32, 64), multi_step=4,
+               enable_prefix_cache=False)
+    cfg.update(kw)
+    return TPUEngine(get_model_config(model, dtype="float32"),
+                     EngineConfig(**cfg), seed=0)
+
+
+@pytest.fixture(scope="module")
+def engines():
+    return {"dense": _engine("llama3-tiny"), "olmoe": _engine("olmoe-tiny")}
+
+
+def _req(prompt, max_new, temp=0.0, seed=None, stop=(), **kw):
+    return InferenceRequest(
+        prompt_token_ids=list(prompt),
+        sampling=SamplingParams(max_new_tokens=max_new, temperature=temp,
+                                seed=seed, stop_token_ids=list(stop)),
+        **kw)
+
+
+def _requests(temp, stop=()):
+    return [_req(p, n, temp, seed=7 + i, stop=stop)
+            for i, (p, n) in enumerate(zip(PROMPTS, BUDGETS))]
+
+
+def _serve(engine, requests, steps, chained=True, during=None, **cfg):
+    """The requests through a fresh batcher at a fixed scan length;
+    returns (responses, stats). ``during(batcher)`` is a coroutine run
+    beside them."""
+    engine.supports_scan_ahead = chained
+
+    async def go():
+        b = ContinuousBatcher(engine, BatcherConfig(
+            max_wait_ms=1, adaptive=False, multi_step=steps,
+            max_multi_step=max(steps, 4), **cfg))
+        b.start()
+        work = [b.submit(r) for r in requests]
+        if during is not None:
+            work.append(during(b))
+        out = await asyncio.gather(*work)
+        stats = b.get_stats()
+        await b.stop()
+        return out[:len(requests)], stats
+
+    try:
+        return asyncio.run(go())
+    finally:
+        engine.supports_scan_ahead = True
+
+
+def _scans(stats):
+    return sum(v for k, v in stats.items()
+               if k.startswith("scans_t") and k[7:].isdigit())
+
+
+def _breaks(stats):
+    return sum(stats[f"chain_breaks_{why}"] for why in _CHAIN_BREAKS)
+
+
+# --------------------------------------------------------------------- #
+# (1) chained against unchained: the same bytes
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("end", ["budget", "stop"])
+@pytest.mark.parametrize("temp", [0.0, 0.8], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("steps", [1, 4])
+@pytest.mark.parametrize("model", ["dense", "olmoe"])
+def test_chained_and_unchained_streams_are_the_same_bytes(
+        engines, model, steps, temp, end):
+    eng = engines[model]
+    stop = ()
+    if end == "stop":
+        # a token the longest stream emits mid-way, as everyone's stop id:
+        # rows then end inside a scan at a step the host cannot foresee
+        plain, _ = _serve(eng, _requests(temp), steps, chained=False)
+        stop = (plain[2].token_ids[len(plain[2].token_ids) // 2],)
+    routed = [eng.stats.get("moe_assignments_scan", 0)]
+    want, base = _serve(eng, _requests(temp, stop), steps, chained=False)
+    routed.append(eng.stats.get("moe_assignments_scan", 0))
+    got, stats = _serve(eng, _requests(temp, stop), steps, chained=True)
+    routed.append(eng.stats.get("moe_assignments_scan", 0))
+    # the experts' counters of a scan read for another entry are counted
+    assert routed[2] - routed[1] == routed[1] - routed[0]
+    assert (routed[1] > routed[0]) == (model == "olmoe")
+    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+    assert [r.finish_reason for r in got] == [r.finish_reason for r in want]
+    if end == "stop":
+        assert "stop" in [r.finish_reason for r in got]
+    else:
+        assert [len(r.token_ids) for r in got] == BUDGETS
+    # the control never chained, the other did, and rows ended mid-chain
+    assert base["scans_chained"] == 0 and _breaks(base) == 0
+    assert stats["scans_chained"] > 0
+    assert stats["chain_breaks_row_end"] + stats["chain_breaks_idle"] > 0
+    assert eng.manager.get_stats()["free_blocks"] == eng.num_blocks - 1
+
+
+# --------------------------------------------------------------------- #
+# (2) a row that hits a stop id inside scan n: nothing in scan n+1
+# --------------------------------------------------------------------- #
+
+def test_a_row_stopped_inside_a_scan_emits_nothing_in_the_chained_one(
+        engines):
+    eng = engines["dense"]
+    free = eng.manager.get_stats()["free_blocks"]
+    ref = eng.generate([_req(PROMPTS[1], 24)], use_multi_step=True)[0]
+    stop = ref.token_ids[5]
+    assert stop not in ref.token_ids[:5]
+    slot = eng.submit(_req(PROMPTS[1], 24, stop=(stop,)))
+    other = eng.submit(_req(PROMPTS[0], 24))
+    # token 0 came with the prefill; scan 1 emits tokens 1-4, scan 2 hits
+    # the stop id at its first step, scan 3 goes out behind scan 2 unread
+    assert eng.decode_multi(4, ahead=True) == {}
+    first = eng.decode_multi(4, ahead=True)
+    assert first[slot] == ref.token_ids[1:5] and eng.scan_unread
+    second = eng.decode_multi(4, ahead=True)         # reads scan 2
+    assert second[slot] == [stop] and len(second[other]) == 4
+    assert eng.slots[slot].finish_reason == "stop"
+    third = eng.collect_scan()                       # scan 3: masked row
+    assert third[slot] == [] and len(third[other]) == 4
+    assert not eng.scan_unread and eng.collect_scan() == {}
+    resp = eng.finish_slot(slot)
+    assert resp.token_ids == ref.token_ids[:5]
+    eng.finish_slot(other)
+    assert eng.manager.get_stats()["free_blocks"] == free
+
+
+# --------------------------------------------------------------------- #
+# (3) an arrival, a cancel, a deadline mid-chain
+# --------------------------------------------------------------------- #
+
+async def _mid_chain(b):
+    while b.stats["scans_chained"] < 3:
+        await asyncio.sleep(0.0005)
+
+
+@pytest.mark.parametrize("what", ["arrival", "cancel", "deadline"])
+def test_mid_chain_events_break_the_chain_within_a_scan(engines, what):
+    eng = engines["dense"]
+    cancel = threading.Event()
+    seen = {}
+    long = _req(PROMPTS[0], 90)
+    cfg = {}
+    if what == "deadline":
+        long = _req(PROMPTS[0], 90, deadline_s=0.0)
+        cfg = dict(abandon_deadlines=True, deadline_grace_s=3600.0)
+    real_ragged = eng.ragged_round
+
+    def ragged_round(admissions, *a, **kw):
+        # scans dispatched by the time the arrival's prompt goes out
+        seen.setdefault("admitted_at", None)
+        if seen.get("sent_at") is not None and seen["admitted_at"] is None:
+            seen["admitted_at"] = _scans(seen["b"].stats)
+        return real_ragged(admissions, *a, **kw)
+
+    async def during(b):
+        seen["b"] = b
+        await _mid_chain(b)
+        seen["sent_at"] = _scans(b.stats)
+        if what == "arrival":
+            return await b.submit(_req(PROMPTS[2], 3))
+        if what == "cancel":
+            cancel.set()
+        else:
+            b.cfg.deadline_grace_s = 0.0        # hopeless from now on
+        while b._slot_items:
+            await asyncio.sleep(0.0005)
+        seen["gone_at"] = _scans(b.stats)
+
+    eng.ragged_round = ragged_round
+    try:
+        async def go():
+            b = ContinuousBatcher(eng, BatcherConfig(
+                max_wait_ms=1, adaptive=False, multi_step=1,
+                max_multi_step=4, **cfg))
+            b.start()
+            out = await asyncio.gather(
+                b.submit(long, cancel=cancel), during(b))
+            stats = b.get_stats()
+            await b.stop()
+            return out, stats
+        (resp, extra), stats = asyncio.run(go())
+    finally:
+        del eng.ragged_round
+    if what == "arrival":
+        assert extra.ok and len(extra.token_ids) == 3
+        assert len(resp.token_ids) == 90
+        assert stats["chain_breaks_admission"] >= 1
+        # the scan that was out when it came, and at most the one whose
+        # dispatch was under way
+        assert seen["admitted_at"] - seen["sent_at"] <= 2
+    else:
+        assert resp.finish_reason == "abort" and len(resp.token_ids) < 90
+        assert stats["chain_breaks_signal"] >= 1
+        assert seen["gone_at"] - seen["sent_at"] <= 2
+        assert stats["cancelled" if what == "cancel" else "abandoned"] == 1
+    assert not eng.scan_unread
+    assert _scans(stats) == stats["scans_chained"] + _breaks(stats)
+
+
+def test_out_of_band_engine_work_runs_on_current_mirrors(engines):
+    """``BatcherServing.run_exclusive`` (PD stages, handoff, export): the
+    callable finds no scan unread and mirrors that agree with each other,
+    mid-chain; the loop counts the chain as broken and serves on."""
+    import time
+
+    from distributed_gpu_inference_tpu.runtime.batcher import BatcherServing
+
+    eng = engines["dense"]
+    serving = BatcherServing(eng, BatcherConfig(
+        max_wait_ms=1, adaptive=False, multi_step=1, max_multi_step=4))
+    try:
+        fut = serving.submit_async(_req(PROMPTS[0], 100))
+        while serving.get_stats()["scans_chained"] < 3:
+            time.sleep(0.0005)
+
+        def look():
+            slot = next((i for i, s in enumerate(eng.slots)
+                         if s is not None), None)
+            if slot is None:
+                return None                 # the request is done
+            s = eng.slots[slot]
+            return (eng.scan_unread, len(s.generated),
+                    int(eng._kv_lens[slot]),
+                    len(eng.manager.seq_tokens[s.seq_id]))
+
+        for i in range(6):      # at whatever point of a round it lands
+            seen = serving.run_exclusive(look)
+            if seen is None and i:
+                break
+            unread, generated, kv_len, managed = seen
+            assert not unread
+            # every token but the pending one is committed, the manager's
+            # list holds the pending one too
+            assert kv_len == len(PROMPTS[0]) + generated - 1 == managed - 1
+            time.sleep(0.003)
+        resp = fut.result(timeout=60)
+        stats = serving.get_stats()
+    finally:
+        serving.stop()
+    assert len(resp.token_ids) == 100
+    assert stats["chain_breaks_signal"] >= 1
+    assert _scans(stats) == stats["scans_chained"] + _breaks(stats)
+
+
+# --------------------------------------------------------------------- #
+# (4) no room to reserve ahead: no chain, the pressure path as it was
+# --------------------------------------------------------------------- #
+
+def test_out_of_blocks_while_reserving_ahead_reads_first_and_leaks_nothing():
+    # 16 tokens a block; two rows of 14-token prompts hold a block each,
+    # scan 1 (16 steps) takes each into its second, and the pool (pad +
+    # 5) has one block left where the scan behind it needs two more
+    eng = _engine("llama3-tiny", max_batch_size=2, num_blocks=6,
+                  max_seq_len=64, multi_step=16)
+    free = eng.manager.get_stats()["free_blocks"]
+    ref = _engine("llama3-tiny", max_batch_size=2, max_seq_len=64,
+                  multi_step=16)
+    want = ref.generate([_req(range(3, 17), 40), _req(range(50, 64), 40)],
+                        use_multi_step=True)
+    a = eng.submit(_req(range(3, 17), 40))
+    b = eng.submit(_req(range(50, 64), 40))
+    assert eng.decode_multi(16, ahead=True) == {}
+    assert eng.take_pressure() is None
+    got = eng.decode_multi(16, ahead=True)      # cannot reserve ahead
+    assert [len(got[a]), len(got[b])] == [16, 16]
+    # the first scan was read before anything else; the second went out by
+    # the path a call that reads its own scan takes: one row frozen, the
+    # pressure signalled, nothing raised
+    pressure = eng.take_pressure()
+    assert pressure is not None and pressure.source == "decode"
+    assert len(pressure.slots) == 1
+    more = eng.collect_scan()
+    frozen = pressure.slots[0]
+    assert more.get(frozen, []) == [] and len(more[a + b - frozen]) == 16
+    for slot, resp in ((a, want[0]), (b, want[1])):
+        n = len(eng.slots[slot].generated)
+        assert eng.slots[slot].generated == resp.token_ids[:n]
+        eng.finish_slot(slot)
+    assert eng.manager.get_stats()["free_blocks"] == free
+
+
+def test_reserve_ahead_raises_nothing_through_the_batcher():
+    """The same pool through the loop: both requests complete (the frozen
+    row by preemption and resume), with the control's tokens."""
+    reqs = lambda: [_req(range(3, 17), 40), _req(range(50, 64), 40)]  # noqa
+    kw = dict(max_batch_size=2, num_blocks=6, max_seq_len=64, multi_step=16)
+    want, base = _serve(_engine("llama3-tiny", **kw), reqs(), 16,
+                        chained=False)
+    eng = _engine("llama3-tiny", **kw)
+    got, stats = _serve(eng, reqs(), 16, chained=True)
+    assert all(r.ok for r in got)
+    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+    assert stats["preemption_block_pressure"] >= 1
+    assert stats["chain_breaks_pressure"] >= 1
+    assert base["preemption_block_pressure"] >= 1
+    assert eng.manager.get_stats()["free_blocks"] == eng.num_blocks - 1
+
+
+# --------------------------------------------------------------------- #
+# (5) the counters account for every scan; (7) the harness's contract
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("steps", [1, 4])
+def test_every_scan_is_chained_or_broken_and_wrapped_once(engines, steps):
+    """``benchmark/harness/session.py annotate()`` replaces
+    ``decode_multi`` on the instance with a wrapper that reads
+    ``num_steps``: one wrapper call a dispatched scan, each carrying that
+    scan's length, and no scan dispatched outside one."""
+    eng = engines["dense"]
+    inner = eng.decode_multi
+    assert list(inspect.signature(inner).parameters)[:1] == ["num_steps"]
+    calls = []
+
+    def outer(*a, **kw):
+        before = eng.stats["decode_calls"]
+        out = inner(*a, **kw)
+        calls.append((a[0], eng.stats["decode_calls"] - before))
+        return out
+
+    eng.decode_multi = outer
+    try:
+        got, stats = _serve(eng, _requests(0.0), steps)
+    finally:
+        del eng.decode_multi
+    assert [len(r.token_ids) for r in got] == BUDGETS
+    # each call dispatched exactly the steps it was asked for
+    assert calls and all(asked == ran == steps for asked, ran in calls)
+    assert len(calls) == _scans(stats) == stats[f"scans_t{steps}"]
+    assert _scans(stats) == stats["scans_chained"] + _breaks(stats)
+    assert stats["scans_chained"] >= _scans(stats) // 2
+    assert 0.0 < stats["round_host_exposed_s"]
+    assert eng.stats["round_host_exposed_s"] <= sum(
+        eng.stats[f"round_{p}_s"] for p in ("build", "dispatch", "commit"))
+
+
+# --------------------------------------------------------------------- #
+# (6) the horizon rule on the exposed host time
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("s_ms,h_ms", [(11.4, 8.0), (5.7, 5.0), (30.6, 6.0)],
+                         ids=["mistral", "olmoe", "mixtral-tp4"])
+def test_hidden_host_time_settles_at_the_lowest_level(s_ms, h_ms):
+    b = _batcher()
+    for _ in range(40):                 # every round's host time exposed
+        _feed(b, s_ms, h_ms)
+    start = b._levels[b._level]
+    for _ in range(200):                # hidden behind the scan before
+        _feed(b, s_ms, 0.05)
+    assert b._levels[b._level] == 1 <= start
+    assert b.stats["round_host_ema_ms"] < s_ms / 4
+
+
+@pytest.mark.parametrize("s_ms", [2.0, 5.7])
+def test_exposed_host_time_over_a_quarter_of_the_scan_climbs(s_ms):
+    """A chained T=1 round whose host work exceeds a step leaves the chip
+    idle by the excess: that is exposed ``h``, and the rule climbs."""
+    b = _batcher()
+    # what a round costs the host, hidden or not (``_scan_measured`` keeps
+    # it): under a step, so a T=1 scan hides it and the rule goes down
+    b._cost_ms = {4: 0.8 * s_ms}
+    for _ in range(200):
+        _feed(b, s_ms, 0.05)
+    assert b._levels[b._level] == 1
+    # the host slows to a step and a half a round: over half a step of
+    # every T=1 round is exposed, and a round at T=4 costs as much
+    b._cost_ms = {1: 1.5 * s_ms, 4: 1.5 * s_ms}
+    seen = {_feed(b, s_ms, s_ms * 0.6) for _ in range(200)}
+    # it climbs, and the small figure of the level above does not fetch it
+    # straight back: a T=1 scan would not cover what a round costs now
+    assert b._levels[b._level] == 4 and seen == {1, 4}
+    # the host recovers: what the level above measures says so
+    b._cost_ms[4] = 0.8 * s_ms
+    for _ in range(50):
+        _feed(b, s_ms, 0.05)
+    assert b._levels[b._level] == 1
+
+
+class _Chip:
+    """A device on a scripted clock behind the engine's chaining surface:
+    a scan of T steps takes ``T * step_s`` from when it is dispatched or
+    the scan before it ends, whichever is later; the host pays ``build_s``
+    before a dispatch and ``commit_s`` after a readback."""
+
+    supports_scan_ahead = True
+    step_s, build_s, commit_s = 0.010, 0.003, 0.001
+
+    def __init__(self, clock):
+        self.clock, self.free_at, self.unread = clock, 0.0, None
+        self.slots = [None] * 2
+        self.cfg = types.SimpleNamespace()
+        self.pressure_pending = False
+        self.stats = {"rounds": 0, "round_build_s": 0.0,
+                      "round_dispatch_s": 0.0, "round_commit_s": 0.0,
+                      "round_readback_s": 0.0, "round_host_exposed_s": 0.0}
+
+    scan_unread = property(lambda self: self.unread is not None)
+    scan_in_flight = property(
+        lambda self: self.unread is not None and self.clock[0] < self.unread)
+
+    def decode_budgets(self):
+        return np.array([1000, 1000], dtype=np.int32)
+
+    def _read(self, end):
+        wait = max(0.0, end - self.clock[0])
+        self.scan_read_running = wait > 0.0
+        self.clock[0] += wait + self.commit_s
+        self.stats["round_readback_s"] += wait
+        self.stats["round_commit_s"] += self.commit_s
+        if self.unread is None:
+            self.stats["round_host_exposed_s"] += self.commit_s
+
+    def decode_multi(self, steps, ahead=False):
+        self.stats["rounds"] += 1
+        hidden = self.scan_in_flight
+        self.clock[0] += self.build_s
+        self.stats["round_build_s"] += self.build_s
+        if not hidden:
+            self.stats["round_host_exposed_s"] += self.build_s
+        prev = self.unread
+        self.unread = self.free_at = \
+            max(self.clock[0], self.free_at) + steps * self.step_s
+        if prev is not None:
+            self._read(prev)
+        if not ahead:
+            end, self.unread = self.unread, None
+            self._read(end)
+        return {0: [1] * steps, 1: [1] * steps} \
+            if prev is not None or not ahead else {}
+
+    def collect_scan(self):
+        end, self.unread = self.unread, None
+        self._read(end)
+        return {0: [1], 1: [1]}
+
+
+@pytest.mark.parametrize("gap_s,want_ms,within", [
+    (0.002, 0.0, 0.05), (0.012, 6.0, 0.05),
+], ids=["host-keeps-up", "host-outlasts-the-scan"])
+def test_the_loop_hands_retune_the_exposed_host_time(
+        monkeypatch, gap_s, want_ms, within):
+    """On a scripted clock: read by its own call, a T=1 scan's gap, build
+    and commit are all the chip's idle time; chained, none of it is while
+    the host keeps up (2 + 3 + 1 ms under a 10 ms step), and what the
+    host's round has over a step where it does not (12 + 3 + 1 - 10: no
+    clock of the host's sees a scan end that it did not wait for, so that
+    is the round less the step as rounds that did wait measured it, and
+    such rounds leave the step's figure alone)."""
+    clock = [100.0]
+    monkeypatch.setattr(batcher_mod, "time", types.SimpleNamespace(
+        perf_counter=lambda: clock[0], time=lambda: clock[0]))
+    eng = _Chip(clock)
+    b = ContinuousBatcher(eng, BatcherConfig(adaptive=False, multi_step=1))
+
+    def rounds(n):
+        out = []
+        for _ in range(n):
+            clock[0] += gap_s
+            out.append(b._engine_round())
+            if out[-1]:
+                b._retune(*out[-1])         # as the loop does: keeps ``s``
+        return out
+
+    eng.supports_scan_ahead = False
+    for steps, scan_s, host_s in rounds(4)[1:]:
+        assert (steps, scan_s) == (1, pytest.approx(eng.step_s))
+        assert host_s == pytest.approx(gap_s + eng.build_s + eng.commit_s)
+    eng.supports_scan_ahead = True
+    first, *chained = rounds(12)
+    assert first is None and b.stats["scans_chained"] == 11
+    for steps, scan_s, host_s in chained[2:]:
+        assert steps == 1 and scan_s == pytest.approx(eng.step_s, abs=1e-4)
+        assert host_s * 1e3 == pytest.approx(want_ms, abs=within)
+    assert b.stats["step_latency_ema_ms"] == pytest.approx(10.0, abs=0.1)
+    # what the rounds cost the host, hidden or not, is kept beside it
+    assert b._cost_ms[1] == pytest.approx(
+        (gap_s + eng.build_s + eng.commit_s) * 1e3, rel=0.3)
+    clock[0] += gap_s
+    steps, scan_s, host_s = b._collect_round("idle")
+    assert steps == 1 and not eng.scan_unread
+    assert b.stats["chain_breaks_idle"] == 1
+
+
+# --------------------------------------------------------------------- #
+# (8) the engine's own callers see no change
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("steps", [1, 4])
+def test_direct_decode_multi_returns_its_own_tokens(engines, steps):
+    eng = engines["dense"]
+    want = eng.generate([_req(PROMPTS[0], 12)])[0].token_ids
+    slot = eng.submit(_req(PROMPTS[0], 12))
+    got = list(eng.slots[slot].generated)
+    while eng.slots[slot].finish_reason is None:
+        out = eng.decode_multi(steps)
+        assert not eng.scan_unread and 1 <= len(out[slot]) <= steps
+        got += out[slot]
+    assert got == want == eng.finish_slot(slot).token_ids
+
+
+def test_generate_and_a_call_after_a_chain_see_current_mirrors(engines):
+    eng = engines["dense"]
+    want = eng.generate(_requests(0.0), use_multi_step=True)
+    assert [len(r.token_ids) for r in want] == BUDGETS
+    # a scan left unread by one caller is read by whoever comes next
+    slot = eng.submit(_req(PROMPTS[0], 9))
+    assert eng.decode_multi(4, ahead=True) == {} and eng.scan_unread
+    assert eng.decode_budgets()[slot] == 4       # 8 left, less the scan
+    own = eng.decode_multi(4)                    # reads both
+    assert len(own[slot]) == 8 and not eng.scan_unread
+    assert eng.finish_slot(slot).token_ids == want[0].token_ids
+
+
+def test_lowered_graphs_hold_the_chained_schedule(engines):
+    eng = engines["dense"]
+    graphs = eng.lower_serving_graphs([1, 4], [])
+    assert list(graphs) == ["decode_multi[T=1]", "decode_multi[T=4]",
+                            "chain_sched"]
+    # one program whatever the scan length, in memory once lowered
+    assert eng._chain_sched_fn._cache_size() == 1
+
+
+# --------------------------------------------------------------------- #
+# the counters' route: batcher.stats -> heartbeat -> /metrics
+# --------------------------------------------------------------------- #
+
+def test_chain_counters_reach_the_planes_metrics():
+    from distributed_gpu_inference_tpu.server.observability import (
+        MetricsCollector,
+    )
+    from distributed_gpu_inference_tpu.worker.main import Worker
+
+    class Eng:
+        engine = None
+
+        def serving_stats(self):
+            return {"decode_rounds": 12, "scans_t1": 9, "scans_chained": 6,
+                    "chain_breaks_admission": 2, "chain_breaks_idle": 1,
+                    "chain_breaks_signal": 0, "round_host_exposed_s": 0.25,
+                    "between_rounds_s": 0.5, "between_rounds": 11}
+
+    worker = Worker.__new__(Worker)
+    worker.engines = {"a": Eng(), "b": Eng()}
+    worker.serving_capacity = lambda: 8
+    sent = worker._batcher_stats()
+    assert sent["scans_chained"] == 12 and sent["chain_breaks_admission"] == 4
+    assert sent["round_host_exposed_s"] == 0.5
+    mc = MetricsCollector()
+    mc.record_batcher_engine("w1", sent)
+    mc.record_batcher_engine("w1", dict(sent, scans_chained=20,
+                                        chain_breaks_idle=5,
+                                        round_host_exposed_s=0.75))
+    text = mc.metrics.render().decode()
+    if "batcher_scans_chained_total" not in text:
+        pytest.skip("prometheus_client is absent: the metrics are no-ops")
+    assert 'batcher_scans_chained_total{worker="w1"} 20.0' in text
+    assert ('batcher_chain_breaks_total{reason="admission",worker="w1"} 4.0'
+            in text)
+    assert 'batcher_chain_breaks_total{reason="idle",worker="w1"} 5.0' in text
+    assert ('batcher_loop_seconds_total{part="round_host_exposed",'
+            'worker="w1"} 0.75') in text
+    # a chained scan is no "reason a scan got its length"
+    assert 'reason="chained"' not in text
+
+
+def test_under_a_mesh_a_chained_scan_compiles_nothing_anew(cpu_devices):
+    """Rows and budgets taken on the device (``chain_sched``) come placed
+    as the host's upload is (replicated): the scan graph and the uploads
+    compile no more often than in an engine that chains nothing, the
+    schedule's own program once, and the tokens are the same."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    def make():
+        return TPUEngine(
+            get_model_config("llama3-tiny", dtype="float32"),
+            EngineConfig(max_batch_size=2, max_seq_len=128, dtype="float32",
+                         prefill_buckets=(16, 32), multi_step=4,
+                         enable_prefix_cache=False),
+            mesh=Mesh(np.array(cpu_devices[:2]), ("model",)), seed=0)
+
+    def serve(eng, ahead):
+        slot = eng.submit(_req(PROMPTS[0], 18))
+        got = list(eng.slots[slot].generated)
+        while eng.slots[slot].finish_reason is None or eng.scan_unread:
+            out = eng.decode_multi(4, ahead=ahead) \
+                if eng.decode_budgets().any() else eng.collect_scan()
+            got += out.get(slot, [])
+        return got
+
+    plain, eng = make(), make()
+    assert serve(eng, True) == serve(plain, False)
+    assert eng._chain_sched_fn._cache_size() == 1
+    assert plain._chain_sched_fn._cache_size() == 0
+    for fn in ("_decode_multi_fn", "_unpack_sched_fn", "_unpack_core_fn"):
+        assert getattr(eng, fn)._cache_size() == \
+            getattr(plain, fn)._cache_size(), fn
+
+
+@pytest.mark.parametrize("c0,c1,want", [
+    ({"scans_t1": 10, "scans_t4": 2, "scans_chained": 4, "scans_amortise": 9},
+     {"scans_t1": 40, "scans_t4": 12, "scans_chained": 34,
+      "scans_amortise": 30}, 75.0),
+    # the parent's program has no such counter: 0 %, which is the truth
+    ({"scans_t4": 5}, {"scans_t4": 25}, 0.0),
+    # no scan in the window: nothing to read
+    ({"scans_t1": 7, "scans_chained": 3}, {"scans_t1": 7, "scans_chained": 3},
+     None),
+], ids=["window-delta", "no-counter", "no-scan"])
+def test_the_benchmarks_reader_of_the_chained_share(c0, c1, want):
+    import importlib.util
+    import sys
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.insert(0, str(bench))
+    spec = importlib.util.spec_from_file_location(
+        "batcher_chained_scan_share",
+        bench / "layer_metrics" / "batcher_chained_scan_share.py")
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    assert reader.read({"win": {"c0": {"batcher": c0},
+                                "c1": {"batcher": c1}}}) == want

@@ -311,12 +311,19 @@ def test_xplane_holds_engine_spans_inside_their_batcher_round(
         else:
             assert 1 <= s["decode_rows"] <= 4
             assert s["positions"] == 4 * s["steps"]
-        # the four phases, in order, inside the engine span
+        # the four phases, in order, inside the engine span; a scan that
+        # is left unread (PR 32) has its readback and commit in the call
+        # that reads it: the next scan's, or the batcher's collect round
         kids = sorted((c for c in spans
                        if c["name"].startswith(engine_span + ".")
                        and s["a"] <= c["a"] and c["b"] <= s["b"]),
                       key=lambda c: c["a"])
-        assert [c["name"].rsplit(".", 1)[1] for c in kids] == list(PHASES)
+        names = [c["name"].rsplit(".", 1)[1] for c in kids]
+        if kind == "ragged":
+            assert names == list(PHASES)
+        else:
+            assert names in (list(PHASES), list(PHASES[:2]))
+            assert s["chained"] == o["chained"] == (len(names) == 4)
         assert all(x["b"] <= y["a"] for x, y in zip(kids, kids[1:]))
 
 
@@ -325,7 +332,12 @@ def test_xplane_holds_the_loop_spans_on_another_thread(served):
     admit = [s for s in spans if s["name"] == "dgi.batcher.admit"]
     deliver = [s for s in spans if s["name"] == "dgi.batcher.deliver"]
     rounds = [s for s in spans if s["name"] == "dgi.batcher.round"]
-    assert len(deliver) == len(rounds) and len(admit) >= len(rounds)
+    # a deliver follows every round that dispatched; a scan read back
+    # because the next round is not a scan behind it (kind "collect") has
+    # one of its own or is read inside the deliver that found a row ended
+    dispatched = [s for s in rounds if s["kind"] != "collect"]
+    assert len(dispatched) <= len(deliver) <= len(rounds)
+    assert len(admit) >= len(dispatched)
     assert {s["thread"] for s in admit + deliver}.isdisjoint(
         {s["thread"] for s in rounds})
     assert all("queue_depth" in s for s in admit)

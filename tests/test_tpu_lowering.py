@@ -390,7 +390,8 @@ def test_mesh_engine_traces_no_pallas_kernel(tpu_dispatch, cpu_devices):
     )
     # what chip_smoke.py reads the implementations from
     graphs = eng.lower_serving_graphs([4], [16])
-    assert set(graphs) == {"decode_multi[T=4]", "ragged_round[Tp=64]"}
+    assert set(graphs) == {"decode_multi[T=4]", "ragged_round[Tp=64]",
+                           "chain_sched"}
     for lowered in graphs.values():
         assert _kernels(lowered) == set()
     out = eng.generate([
