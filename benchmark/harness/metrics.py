@@ -18,7 +18,7 @@ MIN_TOKENS_FOR_TPOT = 8
 # the manifest's business
 END_TO_END = ("ttft_p50_ms", "ttft_mean_ms", "ttft_p90_ms", "tpot_p50_ms",
               "tpot_p90_ms", "itl_p50_ms", "itl_p98_ms", "itl_p99_ms",
-              "gap_p90_ms", "out_tok_s")
+              "gap_p50_ms", "gap_p90_ms", "out_tok_s")
 
 
 def percentile(values: Sequence[float], q: float) -> Optional[float]:
@@ -102,6 +102,10 @@ def summarize(rows: List[Dict[str, Any]], w0: float, w1: float,
     # every wait between two events of every stream, pooled: thousands of
     # readings where the requests are a hundred, so its tail holds still
     waits = sorted(g for r in ok for g in gaps_ms(r))
+    # each stream's longest wait, one reading a stream however many slow
+    # rounds it sat through: the pooled tail counts each of them once a row
+    # and slides with their number, the median over the streams does not
+    longest = [x for x in map(longest_gap_ms, ok) if x is not None]
     out: Dict[str, Any] = {
         "attempted": len(sample),
         "failed": len(sample) - len(ok),
@@ -116,8 +120,12 @@ def summarize(rows: List[Dict[str, Any]], w0: float, w1: float,
         "itl_p98_ms": percentile(waits, 98),
         "itl_p99_ms": percentile(waits, 99),
         "n_waits": len(waits),
-        "gap_p90_ms": percentile(
-            [x for x in map(longest_gap_ms, ok) if x is not None], 90),
+        "gap_p50_ms": percentile(longest, 50),
+        "gap_p90_ms": percentile(longest, 90),
+        # how near the median lies to the edge of its cluster (spread.py)
+        "gap_p40_ms": percentile(longest, 40),
+        "gap_p60_ms": percentile(longest, 60),
+        "n_gaps": len(longest),
         "gen_late_p90_ms": percentile(late, 90),
         "gen_late_max_ms": max(late) if late else None,
         "n_tpot": len(tpot),

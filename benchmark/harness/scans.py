@@ -37,12 +37,17 @@ def slice_rounds(run: Dict[str, Any]) -> List[Dict[str, Any]]:
             if m.get("widest_piece") is not None]
 
 
+def scans_by_level(run: Dict[str, Any]) -> Dict[int, float]:
+    """The window's scans by their length ``T`` (the batcher's
+    ``scans_t<T>``); empty for a program that does not count its levels."""
+    win = run["win"]
+    return {int(key[7:]): delta(win, "batcher", key)
+            for key in win["c1"]["batcher"]
+            if key.startswith("scans_t") and key[7:].isdigit()}
+
+
 def window_steps(run: Dict[str, Any]) -> float:
     """Steps the window's scans took: ``T x scans_t<T>`` over the levels
     the batcher counts (``decode_calls`` also counts a ragged round that
     held a decode row)."""
-    win = run["win"]
-    return sum(
-        int(key[7:]) * delta(win, "batcher", key)
-        for key in win["c1"]["batcher"]
-        if key.startswith("scans_t") and key[7:].isdigit())
+    return sum(t * n for t, n in scans_by_level(run).items())

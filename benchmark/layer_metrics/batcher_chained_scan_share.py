@@ -5,12 +5,11 @@ them was still unread on the device, so that the host's work of the round
 delta. A program that reads every scan back in the call that made it has
 no such counter and reads 0; no scan in the window reads nothing."""
 
+from harness.scans import scans_by_level
 from harness.window import delta
 
 
 def read(run):
-    win = run["win"]
-    scans = sum(delta(win, "batcher", key) for key in win["c1"]["batcher"]
-                if key.startswith("scans_t") and key[7:].isdigit())
-    return 100.0 * delta(win, "batcher", "scans_chained") / scans \
+    scans = sum(scans_by_level(run).values())
+    return 100.0 * delta(run["win"], "batcher", "scans_chained") / scans \
         if scans else None

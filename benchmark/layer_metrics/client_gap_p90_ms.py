@@ -1,9 +1,10 @@
 """The longest wait between two consecutive events of a stream after its first
-token, 90th percentile over the requests due in the window: the stall a long
-decode scan or a co-scheduled prefill round imposes, which TPOT averages away.
-On one chip it reads a T=64 scan (~710 ms) when over a tenth of the requests
-met one and a T=16 scan (~206 ms) when fewer did, so it is recorded and the
-pooled `itl_p99_ms` is judged."""
+token, 90th percentile over the requests due in the window: the stall the
+unluckier tenth of the streams saw (a round with two pieces, a scan raised
+while a request waited), which TPOT averages away. The median of the same
+population, `gap_p50_ms`, is what the one-chip chat cells are judged by
+(PERF.md, section 2); the 90th percentile spreads 0.06-0.10 over ten seeds
+there, which the admission rule refuses, and is recorded."""
 
 
 def read(run):

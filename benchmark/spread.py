@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """How closely a cell's runs repeat: the admission rule, computed.
 
-    python3 benchmark/spread.py <dir or detail file> ... [--json out]
+    python3 benchmark/spread.py <dir or detail file> ... [--bound b] [--json out]
 
 Reads the detail files runs have left (``run.py``'s
 ``<cell>.seed<n>.trace0.json`` and the windows ``sweep.py --keep-rows``
@@ -14,7 +14,9 @@ left out, over the median. A cell is admitted only if every metric it is
 judged by spreads by at most half its bound and ranges by at most the
 bound, over ten seeds or more (``README.md``). No chip is needed: the rows
 hold every event's instant, so a statistic that is only a candidate is
-computed from runs that were made before it was thought of.
+computed from runs that were made before it was thought of: ``--bound b``
+says ``admitted`` or ``NOT ADMITTED`` at ``b`` beside every statistic of
+``END_TO_END`` the cell is not judged by, marked ``candidate``.
 
 ``setup_s`` and the phases of ``timing`` are reported over the runs whose
 every compile request hit the cache (warm runs).
@@ -89,7 +91,17 @@ def warm(detail: Dict[str, Any]) -> bool:
     return all(c.get("cache") == "hit" for c in detail.get("compiles", []))
 
 
-def report(by_cell: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
+def admitted(t: Dict[str, float], bound: float) -> bool:
+    """The admission rule: spread at most half the bound, range without
+    the farthest run at most the bound."""
+    return t["spread"] <= bound / 2 and t["range_without_farthest"] <= bound
+
+
+def report(by_cell: Dict[str, List[Dict[str, Any]]],
+           candidate_bound: Optional[float] = None) -> Dict[str, Any]:
+    """Every cell's table. A statistic the cell is judged by carries its
+    ``bound`` and whether it is ``admitted``; with ``candidate_bound`` every
+    other statistic of ``END_TO_END`` does too, marked ``candidate``."""
     result: Dict[str, Any] = {}
     for name, runs in sorted(by_cell.items()):
         try:
@@ -101,8 +113,9 @@ def report(by_cell: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
             vocab, limits, judged = 1 << 31, None, {}
         sums = [statistics_of(d, vocab, limits) for d in runs]
         table: Dict[str, Any] = {}
-        for m in metrics.END_TO_END + ("gen_late_p90_ms", "n_waits",
-                                       "attempted", "failed"):
+        for m in metrics.END_TO_END + ("gap_p40_ms", "gap_p60_ms",
+                                       "gen_late_p90_ms", "n_waits",
+                                       "n_gaps", "attempted", "failed"):
             table[m] = spread_of([s.get(m) for s in sums])
         warm_runs = [d for d in runs if warm(d) and "end_to_end" in d]
         table["setup_s"] = spread_of(
@@ -111,6 +124,11 @@ def report(by_cell: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
         for k in phases:
             table[f"timing.{k}"] = spread_of(
                 [d["timing"].get(k) for d in warm_runs])
+        for m in metrics.END_TO_END:
+            bound = judged.get(m, candidate_bound)
+            if table[m] is not None and bound is not None:
+                table[m].update(bound=bound, admitted=admitted(table[m], bound),
+                                candidate=m not in judged)
         result[name] = {
             "runs": len(runs),
             "seeds": [d["seed"] for d in runs],
@@ -128,9 +146,12 @@ def report(by_cell: Dict[str, List[Dict[str, Any]]]) -> Dict[str, Any]:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("paths", nargs="+")
+    ap.add_argument("--bound", type=float, default=None,
+                    help="judge every unjudged statistic of END_TO_END at "
+                         "this bound too, as a candidate")
     ap.add_argument("--json", default=None, help="also write the tables here")
     args = ap.parse_args()
-    result = report(load_runs(args.paths))
+    result = report(load_runs(args.paths), args.bound)
     for name, r in result.items():
         print(f"{name}: {r['runs']} runs, rate "
               f"{r['rate_rps']}, every check true: {r['all_correct']}")
@@ -139,12 +160,11 @@ def main() -> int:
         for m, t in r["table"].items():
             if t is None:
                 continue
-            bound = r["judged"].get(m)
             verdict = ""
-            if bound is not None and m != "setup_s":
-                ok = t["spread"] <= bound / 2 \
-                    and t["range_without_farthest"] <= bound
-                verdict = f"{bound} {'admitted' if ok else 'NOT ADMITTED'}"
+            if "admitted" in t:
+                verdict = f"{t['bound']} " \
+                    f"{'admitted' if t['admitted'] else 'NOT ADMITTED'}" \
+                    f"{' candidate' if t['candidate'] else ''}"
             print(f"  {m:24s} {t['n']:3d} {t['median']:10.4f} {t['q1']:10.4f} "
                   f"{t['q3']:10.4f} {t['spread']:7.4f} "
                   f"{t['range_without_farthest']:7.4f}  {verdict}")

@@ -92,8 +92,10 @@ def test_mean_decode_context():
 
 
 # --------------------------------------------------------------------- #
-# the statistics of the pooled waits that cells are judged by (PR 30): the
-# 98th percentile where a tail repeats, the median where none does
+# the statistics of the waits that cells are judged by: a stall statistic
+# where one repeats (PR 36: the median over streams of a stream's longest
+# wait; the pooled 98th percentile of PR 30 is recorded beside it), the
+# typical wait where none does
 # --------------------------------------------------------------------- #
 
 def judged_in(cell):
@@ -101,6 +103,12 @@ def judged_in(cell):
     manifest = json.loads((spec.CHECKOUT / "BENCHMARK.json").read_text())
     return {m["name"]: m["bound"] for m in manifest["end_to_end"]
             if cell in m.get("workloads", ()) and m["name"] != "setup_s"}
+
+
+def stall_bound(cell):
+    """The bound of the one latency metric ``cell`` is judged by."""
+    (bound,) = judged_in(cell).values()
+    return bound
 
 
 def window_of(waits_ms):
@@ -152,7 +160,7 @@ def test_the_judged_statistics_on_hand_made_rows():
 
 
 def test_the_99th_percentile_jumps_where_a_cluster_crosses_it_and_the_98th_holds():
-    bound = judged_in("mistral-7b-int8.chat")["itl_p98_ms"]
+    bound = stall_bound("mistral-7b-int8.chat")
     # the waits of T=16 scans are 0.8 % of all, then 1.2 %: seeds of one
     # program read both (PERF.md, section 2)
     fewer, more = summary_of(chat_like(128)), summary_of(chat_like(192))
@@ -184,12 +192,88 @@ def test_the_98th_percentile_does_not_follow_one_long_stall():
     ({"stalls": 256}, False),
 ], ids=["stall-share-past-2pc", "rounds-24ms-longer", "stall-share-inside-top-2pc"])
 def test_what_moves_the_98th_percentile_by_more_than_its_bound(change, moves):
-    bound = judged_in("mistral-7b-int8.chat")["itl_p98_ms"]
+    bound = stall_bound("mistral-7b-int8.chat")
     base, changed = summary_of(chat_like(128)), summary_of(chat_like(**change))
     moved = changed["itl_p98_ms"] / base["itl_p98_ms"] - 1.0
     assert (moved > bound) is moves
     if not moves:
         assert changed["itl_p99_ms"] > (1.0 + bound) * base["itl_p99_ms"]
+
+
+def stream(due, waits_ms):
+    times, t = [due + 0.05], due + 0.05
+    for w in waits_ms:
+        t += w / 1e3
+        times.append(t)
+    return row(due, due, times)
+
+
+def test_gap_p50_is_the_median_over_streams_of_the_longest_wait():
+    rows = [stream(0.1, [10.0, 30.0, 10.0]), stream(0.2, [10.0] * 9),
+            stream(0.3, [50.0, 10.0]), stream(0.4, [10.0, 90.0]),
+            stream(0.5, [])]       # one event: no wait, so no longest one
+    s = metrics.summarize(rows, 0.0, 10.0, VOCAB)
+    longest = [metrics.longest_gap_ms(r) for r in rows]
+    assert longest[-1] is None and s["attempted"] == 5 and s["n_gaps"] == 4
+    assert s["gap_p50_ms"] == pytest.approx(40.0)       # 10 30 50 90
+    # the population gap_p90_ms has had since PR 22, at another percentile
+    assert s["gap_p90_ms"] == pytest.approx(
+        metrics.percentile(longest[:4], 90))
+    assert s["gap_p40_ms"] < s["gap_p50_ms"] < s["gap_p60_ms"]
+    # a failed stream is out of it, as it is out of the pooled waits
+    rows[3]["finish"] = "error"
+    assert metrics.summarize(rows, 0.0, 10.0, VOCAB)["gap_p50_ms"] \
+        == pytest.approx(30.0)
+    assert metrics.summarize([rows[4]], 0.0, 10.0, VOCAB)["gap_p50_ms"] is None
+
+
+def chat_streams(slow_rounds, piece=0.0):
+    """100 streams of 160 waits as the Mistral chat cell's since PR 32: a
+    wait is a T=1 step (11.2 ms) but for four rounds with one piece a
+    stream (37-46 ms, ``piece`` longer: 2.5 % of the waits, so the pooled
+    98th percentile lies inside that cluster), and ``slow_rounds`` rounds
+    with two pieces (57 ms), each of which six decode rows sat through: the
+    same six, the streams in flight while a burst of arrivals came."""
+    rows = []
+    for k in range(100):
+        waits = [11.2] * 160
+        for j in range(4):          # 400 waits spread evenly over 37-46 ms
+            waits[20 + 30 * j] = 37.0 + piece + 9.0 * (4 * k + j) / 400
+        if 40 <= k < 46:
+            for j in range(slow_rounds):
+                waits[5 + 5 * j] = 57.0 + piece
+        rows.append(stream(0.01 * k, waits))
+    return rows
+
+
+def test_a_slow_round_counts_once_a_row_in_the_pooled_tail_and_once_a_stream():
+    bound = stall_bound("mistral-7b-int8.chat")
+    few, many = (metrics.summarize(chat_streams(n), 0.0, 10.0, VOCAB)
+                 for n in (10, 30))
+    assert few["n_waits"] == many["n_waits"] == 16000
+    # 60 against 180 of 16,000 waits (0.4 and 1.1 %: what seeds of one
+    # program read, PERF.md section 2) slide the 98th percentile along the
+    # cluster it lies in by more than the rule allows a spread
+    assert many["itl_p98_ms"] / few["itl_p98_ms"] - 1.0 > bound / 2
+    # the same six streams had a slow round as their longest wait in both
+    assert many["gap_p50_ms"] == few["gap_p50_ms"]
+    assert many["gap_p90_ms"] == few["gap_p90_ms"]
+    # and without any slow round it stands three ranks of a hundred lower
+    none = metrics.summarize(chat_streams(0), 0.0, 10.0, VOCAB)
+    assert 0.0 < few["gap_p50_ms"] / none["gap_p50_ms"] - 1.0 < bound / 4
+
+
+def test_the_median_longest_wait_still_reads_the_round_with_a_piece():
+    bound = stall_bound("mistral-7b-int8.chat")
+    base = metrics.summarize(chat_streams(10), 0.0, 10.0, VOCAB)
+    assert 37.0 < base["gap_p50_ms"] < 46.0
+    # every round with a piece 8 ms longer (the rectangle's copies S9 would
+    # take are 3.3 of 35.4 ms; PR 28 took 24): seen at 1.5 x the bound
+    slower = metrics.summarize(chat_streams(10, piece=8.0), 0.0, 10.0, VOCAB)
+    assert slower["gap_p50_ms"] / base["gap_p50_ms"] - 1.0 > 1.5 * bound
+    # which the typical wait, a step, does not see
+    assert slower["itl_p50_ms"] == pytest.approx(base["itl_p50_ms"])
+    assert base["itl_p50_ms"] == pytest.approx(11.2)
 
 
 def test_the_median_wait_reads_the_decode_step_whatever_the_tail_does():

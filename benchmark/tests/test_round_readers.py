@@ -53,20 +53,25 @@ def test_prefill_padding_share_is_what_the_rectangle_did_not_hold():
                                {"ragged_rounds": 7})}) is None
 
 
-def test_top_scan_time_share_reads_the_highest_configured_level():
-    read = reader("batcher.top_scan_time_share")
-    geometry = {"horizon_levels": [1, 4, 16, 64]}
-    counted = {"scan_s_t1": 0.0, "scan_s_t4": 3.0, "scan_s_t16": 9.0,
-               "scan_s_t64": 1.0}
-    run = {"geometry": geometry,
-           "win": window({}, {}, counted, dict(counted, scan_s_t64=8.5))}
-    assert read(run) == pytest.approx(15.0)             # 7.5 s of 50
-    # the level was never reached: a reading of zero, not nothing
-    assert read({"geometry": geometry,
-                 "win": window({}, {}, counted, counted)}) == 0.0
-    # a program that does not count its levels
-    assert read({"geometry": geometry,
-                 "win": window({}, {}, {"horizon": 4.0},
+def test_raised_scan_time_share_reads_the_levels_above_the_settled_one():
+    read = reader("batcher.raised_scan_time_share")
+    before = {"scans_t1": 300, "scans_t4": 8, "scans_t16": 0, "scans_t64": 0,
+              "scan_s_t1": 3.0, "scan_s_t4": 0.5, "scan_s_t16": 0.0,
+              "scan_s_t64": 0.0}
+    after = {"scans_t1": 3800, "scans_t4": 70, "scans_t16": 2, "scans_t64": 0,
+             "scan_s_t1": 38.0, "scan_s_t4": 3.0, "scan_s_t16": 0.5,
+             "scan_s_t64": 0.0}
+    # settled at T=1 (3,500 of the window's scans): 2.5 + 0.5 s of 50 above it
+    assert read({"win": window({}, {}, before, after)}) == pytest.approx(6.0)
+    # settled at T=4: the T=1 scans below it are not raised ones
+    at4 = dict(after, scans_t1=400, scans_t4=4000)
+    assert read({"win": window({}, {}, before, at4)}) == pytest.approx(1.0)
+    # no scan was raised: a reading of zero, not nothing
+    flat = dict(before, scans_t1=900, scan_s_t1=9.0)
+    assert read({"win": window({}, {}, before, flat)}) == 0.0
+    # a window without a scan, and a program that does not count its levels
+    assert read({"win": window({}, {}, before, before)}) is None
+    assert read({"win": window({}, {}, {"horizon": 4.0},
                                {"horizon": 4.0})}) is None
 
 

@@ -32,11 +32,16 @@ def test_too_few_runs_or_a_zero_median_give_nothing():
 
 
 def run_file(tmp_path, seed, times, name="tiny-mistral.chat", trace=0):
+    """Four streams with the events ``times`` after they are due (or each
+    with its own, where ``times`` is a list of four) and one past the
+    window."""
+    each = times if isinstance(times[0], list) else [times] * 4
     rows = [{"id": f"r{k}", "due": 0.1 * k, "sent": 0.1 * k, "status": 200,
-             "t": [0.1 * k + t for t in times], "n": [1] * len(times),
-             "asked": len(times), "prompt_tokens": 10,
-             "done_at": 100.0, "finish": "length", "usage_out": len(times),
-             "id_min": 4, "id_max": 99, "error": None} for k in range(4)]
+             "t": [0.1 * k + t for t in ts], "n": [1] * len(ts),
+             "asked": len(ts), "prompt_tokens": 10,
+             "done_at": 100.0, "finish": "length", "usage_out": len(ts),
+             "id_min": 4, "id_max": 99, "error": None}
+            for k, ts in enumerate(each)]
     rows.append(dict(rows[0], id="late", due=6.5, sent=6.5))  # past the window
     d = {"cell": name, "seed": seed, "seconds": 6.0, "trace": trace,
          "rate_rps": 5.0, "checks": {"probes": True},
@@ -64,3 +69,37 @@ def test_detail_files_are_read_and_their_statistics_recomputed(tmp_path):
     assert one["table"]["timing.graphs_s"]["n"] == 2
     assert "itl_p99_ms" in one["judged"]
     assert one["values"]["tpot_p50_ms"] == [None, None]  # under 8 tokens
+
+
+def test_the_longest_wait_median_is_tabled_and_judged(tmp_path, capsys,
+                                                      monkeypatch):
+    # ten runs of a cell the root manifest judges by ``gap_p50_ms``: every
+    # stream's longest wait is 38 ms and a little more with the seed, but
+    # for the one stream whose 60 ms waits come and go (the pooled tail)
+    cell = "mistral-7b-int8.chat"
+
+    def events(waits):
+        times, t = [1.0], 1.0
+        for w in waits:
+            t += w
+            times.append(t)
+        return times
+
+    for seed in range(1, 11):
+        calm = [0.011] * 20 + [0.038 + 0.0001 * seed] + [0.011] * 20
+        run_file(tmp_path, seed, [events(calm)] * 3
+                 + [events(calm + [0.06] * (seed % 4))], name=cell)
+    one = spread.report(spread.load_runs([str(tmp_path)]))[cell]
+    assert one["judged"].get("gap_p50_ms") == 0.1
+    assert "itl_p98_ms" not in one["judged"]
+    assert one["values"]["gap_p50_ms"][0] == pytest.approx(38.1)
+    assert one["table"]["gap_p40_ms"]["n"] == 10
+    monkeypatch.setattr("sys.argv", ["spread.py", str(tmp_path),
+                                     "--bound", "0.08"])
+    assert spread.main() == 0
+    lines = {ln.split()[0]: ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("  ")}
+    assert lines["gap_p50_ms"].endswith("0.1 admitted")
+    assert lines["itl_p50_ms"].endswith("0.08 admitted candidate")
+    assert lines["itl_p98_ms"].endswith("0.08 NOT ADMITTED candidate")
+    assert "0.08" not in lines["n_waits"]
