@@ -40,7 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_gpu_inference_tpu.ops import attention as _attention
-from distributed_gpu_inference_tpu.ops.qmm_pallas import pick_tiles
+from distributed_gpu_inference_tpu.ops.qmm_pallas import block_tiles
 
 # fixed: the Mosaic kernel's name and, as the innermost scope, the custom
 # call's name on a device trace's XLA Ops line (``dgi_moe_gmm.<n>``).
@@ -52,9 +52,11 @@ KERNEL_NAME_STEP = "dgi_moe_gmm_step"
 _MAX_TILE_ROWS = 128
 # an expert matrix this small is one weight block: one DMA and one grid
 # step a row tile (OLMoE's [2048, 1024] int8 is 2 MiB; two buffers and the
-# bf16 copy of one stay well inside VMEM). Larger ones tile as
-# ops/qmm_pallas.py does.
+# bf16 copy of one stay well inside VMEM). Larger ones tile by
+# ops/qmm_pallas.py's rule under this kernel's own constants: the chip
+# has been asked about column tiles past 512 for ``dgi_qmm`` only.
 _WHOLE_BLOCK_BYTES = 2 * 1024 * 1024
+_MAX_TILE_COLS = 512
 
 
 def weight_tiles(k: int, n: int):
@@ -67,13 +69,7 @@ def weight_tiles(k: int, n: int):
     tile where 512 x 512 took fifteen)."""
     if k % 128 == 0 and n % 128 == 0 and k * n <= _WHOLE_BLOCK_BYTES:
         return k, n
-    tiles = pick_tiles(k, n)
-    if tiles is None:
-        return None
-    bn = tiles[1]
-    bk = next((d for d in range(_WHOLE_BLOCK_BYTES // bn // 128 * 128, 0,
-                                -128) if k % d == 0), tiles[0])
-    return max(bk, tiles[0]), bn
+    return block_tiles(k, n, _WHOLE_BLOCK_BYTES, _MAX_TILE_COLS)
 
 
 class RoutePlan(NamedTuple):

@@ -10,7 +10,11 @@ the gap by doing the convert AFTER the HBM read, in VMEM:
 
 - **Blocked operands**: weight tiles ``[BK, BN]`` are DMA'd HBM→VMEM as int8
   (half the bytes on the wire), converted to the activation dtype in VMEM,
-  and contracted on the MXU with f32 accumulation.
+  and contracted on the MXU with f32 accumulation. The block is a function
+  of the weight's shape and item size (``pick_tiles``): the widest column
+  tile that divides N, then the longest contraction tile that divides K
+  inside a byte budget — 0.9-2 MB a grid step on every width the
+  benchmark's cells run (PERF.md section 6, PR 38: the chip's table).
 - **Stacked weights + scalar-prefetch layer index**: like the paged-attention
   kernel (``ops/paged_attention_pallas.py``), the kernel takes the whole
   stacked ``[L, K, N]`` weight and a scalar ``layer_idx`` — a custom-call
@@ -37,11 +41,22 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Tile menu. BN/BK must divide N/K exactly (no ragged K/N tiles: an
-# out-of-bounds K read would contract garbage into real outputs). The lane
-# dim of every block must be a multiple of 128.
-_BN_CHOICES = (512, 256, 128)
-_BK_CHOICES = (2048, 1024, 512, 256, 128)
+# Block rule. BN/BK must divide N/K exactly (no ragged K/N tiles: an
+# out-of-bounds K read would contract garbage into real outputs) and both
+# are multiples of 128 (the lane dim of the weight block and of the
+# activation block). The column tile comes first: the WIDEST multiple of
+# 128 that divides N, up to ``_BN_MAX`` — a block's rows are contiguous in
+# HBM over its width only, and every column tile re-reads the activation
+# block. Then the LONGEST contraction tile that divides K and keeps the
+# block inside ``_BLOCK_BYTES``. Both constants are the chip's (TPU v5e;
+# PERF.md section 6, PR 38 has the table, GB/s by block for every (K, N)
+# the cells run): a 512-wide tile streams at 450-490 GB/s in 256 KB
+# blocks and 650-720 in 1-4 MB ones, a 1792-2048-wide tile at 720-740
+# from 1 MB on; past 2 MB nothing gains and a small matrix loses (the
+# first block's DMA is not overlapped). Not a menu of powers of two: a
+# width that is not one (3584, 18944, 7680) falls to its smallest entry.
+_BN_MAX = 2048
+_BLOCK_BYTES = 2 * 1024 * 1024
 
 # Bandwidth-bound regime bound: above this many activation rows the matmul
 # is MXU-bound and XLA's native path (with its better K-parallel scheduling)
@@ -50,12 +65,25 @@ _BK_CHOICES = (2048, 1024, 512, 256, 128)
 _MAX_ROWS = 256
 
 
-def pick_tiles(k: int, n: int) -> Optional[tuple]:
-    bn = next((t for t in _BN_CHOICES if n % t == 0), None)
-    bk = next((t for t in _BK_CHOICES if k % t == 0), None)
-    if bn is None or bk is None:
-        return None
-    return bk, bn
+def _longest_tile(dim: int, cap: int) -> Optional[int]:
+    """The longest multiple of 128 that divides ``dim``, at most ``cap``."""
+    top = min(cap, dim) // 128 * 128
+    return next((d for d in range(top, 0, -128) if dim % d == 0), None)
+
+
+def block_tiles(k: int, n: int, block_bytes: int, bn_max: int,
+                itemsize: int = 1) -> Optional[tuple]:
+    """(bk, bn) of a ``[K, N]`` weight's blocks — the widest column tile up
+    to ``bn_max``, then the longest contraction tile inside ``block_bytes``
+    — or None if K x N does not tile. ``ops/moe_gmm_pallas.py`` tiles its
+    expert matrices by the same rule under constants of its own."""
+    bn = _longest_tile(n, bn_max)
+    bk = bn and _longest_tile(k, block_bytes // (bn * itemsize))
+    return (bk, bn) if bk else None
+
+
+def pick_tiles(k: int, n: int, itemsize: int = 1) -> Optional[tuple]:
+    return block_tiles(k, n, _BLOCK_BYTES, _BN_MAX, itemsize)
 
 
 # fixed: the Mosaic kernel's name (``kernel_name`` in the lowered text,
@@ -101,7 +129,7 @@ def qmm_stacked_pallas(
     l, k2, n = qw.shape
     if k != k2:
         raise ValueError(f"x K {k} != weight K {k2}")
-    tiles = pick_tiles(k, n)
+    tiles = pick_tiles(k, n, qw.dtype.itemsize)
     if tiles is None:
         raise ValueError(f"untileable qmm shape K={k} N={n}")
     bk, bn = tiles

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_gpu_inference_tpu.ops import qmm_pallas
 from distributed_gpu_inference_tpu.ops.qmm_pallas import (
     pick_tiles,
     qmm_stacked_pallas,
@@ -64,15 +65,81 @@ def test_qmm_layer_index_selects_layer():
         )
 
 
+# every (K, N) the one-chip cells send through the kernel (ISSUE 38)
+CELL_SHAPES = {
+    "qwen2.5-7b": [(3584, 3584), (3584, 512), (3584, 18944), (18944, 3584)],
+    "mistral-7b": [(4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)],
+    "olmoe-1b-7b": [(2048, 2048)],
+    "openpangu-ultra-moe-718b-ep16": [
+        (7680, 1536), (1536, 24576), (16384, 7680), (7680, 2048),
+        (2048, 7680), (7680, 18432), (18432, 7680),
+    ],
+}
+_MB = 1024 * 1024
+
+
+def _tiles_128(dim):
+    return [d for d in range(128, dim + 1, 128) if dim % d == 0]
+
+
+@pytest.mark.parametrize(
+    "k,n", [kn for shapes in CELL_SHAPES.values() for kn in shapes]
+)
+def test_block_rule_on_cell_shapes(k, n):
+    """The block divides K and N, both of its dimensions are multiples of
+    128 (K is the activation block's lane dimension, N the weight's), it is
+    inside the byte budget, its column tile is the widest the shape has up
+    to the cap, and it is under 1 MB only where no longer block of that
+    column tile fits the budget (18944 x 3584: 512 x 1792, that shape's
+    best reading on the chip, where the next contraction tile is 4736)."""
+    bk, bn = pick_tiles(k, n)
+    assert k % bk == 0 and n % bn == 0
+    assert bk % 128 == 0 and bn % 128 == 0
+    assert bk * bn <= qmm_pallas._BLOCK_BYTES
+    assert bn == max(b for b in _tiles_128(n) if b <= qmm_pallas._BN_MAX)
+    if bk * bn < _MB:
+        longer = [a for a in _tiles_128(k)
+                  if bk < a and a * bn <= qmm_pallas._BLOCK_BYTES]
+        assert not longer, (bk, bn, longer[-1])
+    # a two-byte weight takes half the rows of the same budget
+    bk2, bn2 = pick_tiles(k, n, 2)
+    assert k % bk2 == 0 and bk2 * bn2 * 2 <= qmm_pallas._BLOCK_BYTES
+
+
+# K that is not a power of two: 7 x 128 and 37 x 128 stand in for Qwen's
+# 3584 and 18944, as one block (num_k 1) and, long enough to pass the
+# budget at the narrowest column tile, as several
+@pytest.mark.parametrize("k,n,one_block", [
+    (7 * 128, 512, True), (37 * 128, 256, True),
+    (7 * 128 * 32, 128, False), (37 * 128 * 8, 128, False),
+])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_qmm_parity_odd_k(k, n, one_block, dtype):
+    assert (k == pick_tiles(k, n)[0]) == one_block
+    qw, _ = _stacked_quant(jax.random.PRNGKey(4), 1, k, n)
+    x = (jax.random.normal(jax.random.PRNGKey(5), (8, k)) * 0.05).astype(dtype)
+    got = qmm_stacked_pallas(
+        x, qw["qw"], qw["scale"], jnp.int32(0), interpret=True
+    )
+    want = matmul(
+        x, {"qw": qw["qw"][0], "scale": qw["scale"][0]}, pallas=False
+    )
+    assert got.dtype == x.dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        rtol=3e-2, atol=3e-2,
+    )
+
+
 def test_qmm_multi_k_tiles_accumulate():
-    # K = 512 with BK=512 single tile vs K=2048 (BK=2048): exercise the
-    # accumulator by using a K that forces multiple tiles relative to the
-    # menu — 2048+256 isn't tileable, so use K=2560 (BK=512, 5 tiles)
+    # the accumulator across K tiles: 40960 x 128 is past the block budget
+    # at the narrowest column tile
     key = jax.random.PRNGKey(4)
-    qw, _ = _stacked_quant(key, 1, 2560, 128)
-    assert pick_tiles(2560, 128) == (512, 128)
+    k = 40960
+    qw, _ = _stacked_quant(key, 1, k, 128)
+    assert k // pick_tiles(k, 128)[0] > 1
     x = jnp.asarray(
-        jax.random.normal(jax.random.PRNGKey(5), (8, 2560)) * 0.05,
+        jax.random.normal(jax.random.PRNGKey(5), (8, k)) * 0.05,
         jnp.bfloat16,
     )
     got = qmm_stacked_pallas(
@@ -104,7 +171,8 @@ def test_qmm_fp8_storage():
 def test_pick_tiles_untileable():
     assert pick_tiles(100, 256) is None
     assert pick_tiles(256, 100) is None
-    assert pick_tiles(14336, 4096) == (2048, 512)
+    assert pick_tiles(7680, 576) is None     # openPangu's wkv_a: XLA's path
+    assert pick_tiles(14336, 4096) == (1024, 2048)
 
 
 def test_split_stacked_quant_partition():

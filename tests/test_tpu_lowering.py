@@ -25,16 +25,19 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from distributed_gpu_inference_tpu.models import llama
+from distributed_gpu_inference_tpu.models import llama, mla
 from distributed_gpu_inference_tpu.models.configs import get_model_config
-from distributed_gpu_inference_tpu.ops import attention
+from distributed_gpu_inference_tpu.ops import attention, moe_gmm_pallas
 from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
     page_write_plan,
     paged_decode_attention_fused,
     ragged_paged_attention,
     write_kv_pages_in_place,
 )
-from distributed_gpu_inference_tpu.ops.qmm_pallas import qmm_stacked_pallas
+from distributed_gpu_inference_tpu.ops.qmm_pallas import (
+    pick_tiles,
+    qmm_stacked_pallas,
+)
 from distributed_gpu_inference_tpu.ops.quantization import quantize_params
 from distributed_gpu_inference_tpu.parallel import sharding as sh
 
@@ -161,15 +164,41 @@ def test_page_write_kernel_compiles(v5e, model, block):
         lowered.compile()
 
 
-@pytest.mark.parametrize("model", MODELS)
-def test_qmm_kernel_compiles(v5e, model):
-    cfg = get_model_config(model)
+# the four one-chip configurations of the benchmark's cells
+ONE_CHIP = MODELS + ("olmoe-1b-7b", "openpangu-ultra-moe-718b-ep16")
+
+
+def _qmm_shapes(cfg):
+    """Every (K, N) a layer of ``cfg`` sends through ``dgi_qmm``: the int8
+    projections whose widths tile (openPangu's 7680 x 576 ``wkv_a`` does
+    not and stays on XLA's path; routed experts take ``dgi_moe_gmm``)."""
+    if cfg.latent_kv:
+        shapes = {
+            shape
+            for group, _ in mla.layer_groups(cfg)
+            for shape, _, kind in mla.leaf_specs(cfg, group).values()
+            if kind == "q" and len(shape) == 2
+        }
+    else:
+        h, i = cfg.hidden_size, cfg.intermediate_size
+        q_out, kv_out = (cfg.num_heads * cfg.head_dim,
+                         cfg.num_kv_heads * cfg.head_dim)
+        # wq, wk/wv, wo and, in a dense layer, w_gate/w_up, w_down
+        shapes = {(h, q_out), (h, kv_out), (q_out, h)}
+        if not cfg.num_experts:
+            shapes |= {(h, i), (i, h)}
+    return sorted(kn for kn in shapes if pick_tiles(*kn) is not None)
+
+
+@pytest.mark.parametrize("m", [16, 144, 256])   # 256: the widest row count
+@pytest.mark.parametrize("model", ONE_CHIP)
+def test_qmm_kernel_compiles(v5e, model, m):
+    """Mosaic accepts the block ``pick_tiles`` gives every projection of
+    the configuration, and its VMEM, at a decode step's rows, a ragged
+    round's and the row gate's bound."""
     sds = _on(SingleDeviceSharding(v5e[0]))
-    h, i = cfg.hidden_size, cfg.intermediate_size
-    q_out, kv_out = (cfg.num_heads * cfg.head_dim,
-                     cfg.num_kv_heads * cfg.head_dim)
-    # every projection of the layer: wq, wk/wv, wo, w_gate/w_up, w_down
-    shapes = {(h, q_out), (h, kv_out), (q_out, h), (h, i), (i, h)}
+    shapes = _qmm_shapes(get_model_config(model))
+    assert shapes
 
     def all_projections(xs, ws, idx):
         return [
@@ -177,11 +206,21 @@ def test_qmm_kernel_compiles(v5e, model):
             for x, w in zip(xs, ws)
         ]
 
-    for m in (BATCH, 256):      # a decode step; the widest row count served
-        xs = [sds((m, k), jnp.bfloat16) for k, _ in shapes]
-        ws = [{"qw": sds((2, k, n), jnp.int8),
-               "scale": sds((2, 1, n), jnp.float32)} for k, n in shapes]
-        jax.jit(all_projections).lower(xs, ws, sds((), jnp.int32)).compile()
+    xs = [sds((m, k), jnp.bfloat16) for k, _ in shapes]
+    ws = [{"qw": sds((2, k, n), jnp.int8),
+           "scale": sds((2, 1, n), jnp.float32)} for k, n in shapes]
+    jax.jit(all_projections).lower(xs, ws, sds((), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("k,n,want", [
+    (2048, 1024, (2048, 1024)), (1024, 2048, (1024, 2048)),     # OLMoE
+    (7680, 2048, (3840, 512)), (2048, 7680, (2048, 512)),       # openPangu
+])
+def test_expert_kernel_keeps_its_blocks(k, n, want):
+    """``dgi_moe_gmm`` shares ``block_tiles`` with ``dgi_qmm`` under a
+    budget of its own: the cells' expert shapes tile as they did before
+    ``dgi_qmm``'s rule changed (``kernels.moe_*`` are read against them)."""
+    assert moe_gmm_pallas.weight_tiles(k, n) == want
 
 
 # --------------------------------------------------------------------- #
