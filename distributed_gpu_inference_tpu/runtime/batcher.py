@@ -239,6 +239,39 @@ def split_prefill_budget(needs: List[int], budget: int,
     return grants
 
 
+class _StreamWait:
+    """What the batcher keeps of a streamed request's waits (one per item
+    with an observer, O(1)): the tokens and the round of the last
+    notification that brought it one, and the longest stretch between two
+    such rounds' ``ready`` with the round that ended it."""
+
+    __slots__ = ("tokens", "ready", "preempts", "gap_s", "round", "cause")
+
+    def __init__(self) -> None:
+        self.tokens = 0
+        self.ready: Optional[float] = None
+        self.preempts = 0
+        self.gap_s = 0.0
+        self.round: Optional[int] = None
+        self.cause = ""
+
+    def progress(self, tokens: int, stamp: flight.RoundStamp,
+                 preempts: int) -> None:
+        """A notification holds ``tokens`` tokens of the stream after the
+        round ``stamp``: where that is more than the last one held, the
+        stretch since then ends here."""
+        if tokens <= self.tokens:
+            return
+        if self.ready is not None and stamp.ready - self.ready > self.gap_s:
+            self.gap_s = stamp.ready - self.ready
+            self.round = stamp.round
+            # the first tokens after a preemption waited for the resume,
+            # whatever round brought them
+            self.cause = stamp.cause if preempts == self.preempts \
+                else "other"
+        self.tokens, self.ready, self.preempts = tokens, stamp.ready, preempts
+
+
 @dataclass(order=True)
 class _QueueItem:
     # (-priority, deadline_at, arrival_time, seq): EDF *within* a priority
@@ -273,6 +306,8 @@ class _QueueItem:
     # None for untraced requests: the recorder-off path costs one None
     # check per boundary, nothing per token.
     flight: Optional[Any] = field(compare=False, default=None)
+    # a streamed request's waits (``_StreamWait``); None without an observer
+    stream: Optional[_StreamWait] = field(compare=False, default=None)
 
 
 class ContinuousBatcher:
@@ -358,7 +393,11 @@ class ContinuousBatcher:
         # token dispatched has been read; and the host time the chip spent
         # idle since the last scan that was read, for ``_retune``
         self._unread_steps: Optional[int] = None
+        self._unread_reason = ""
         self._exposed_s = self._cost_s = 0.0
+        # the last round's stamp, made where it returned on the engine
+        # thread: what the streams' snapshots of that round carry
+        self._ready: Optional[flight.RoundStamp] = None
         # callers waiting to run their own work on the engine thread
         # (``BatcherServing.run_exclusive``): while there are any, every
         # scan is read by the call that made it, so that what they run
@@ -398,6 +437,10 @@ class ContinuousBatcher:
             # owned throughout, and the loop's two halves that split it
             "between_rounds_s": 0.0, "between_rounds": 0,
             "admit_s": 0.0, "deliver_s": 0.0,
+            # streams completed, by the round that ended their longest
+            # wait for a token, and those waits' seconds (``_StreamWait``)
+            **{f"longest_wait_{c}": 0 for c in flight.WAIT_CAUSES},
+            **{f"longest_wait_s_{c}": 0.0 for c in flight.WAIT_CAUSES},
         }
         self._level_counters()
 
@@ -635,6 +678,7 @@ class ContinuousBatcher:
             interrupt=interrupt,
             preempted=resume_from,
             flight=flight,
+            stream=_StreamWait() if observer is not None else None,
         )
         self._note(item, "batcher.enqueued",
                    queue_depth=len(self._heap))
@@ -1352,17 +1396,42 @@ class ContinuousBatcher:
         """Push per-round progress to streaming observers (loop thread;
         observers must only enqueue). Finished slots are excluded — their
         full token list rides the resolving response — unless ``finished``
-        says that response has to wait."""
+        says that response has to wait. A snapshot carries the stamp of
+        the round that just returned and the instant of this call."""
+        stamp = self._ready
         for slot, item in list(self._slot_items.items()):
             if item.observer is None:
                 continue
             s = self.engine.slots[slot]
             if s is None or (s.finish_reason is not None and not finished):
                 continue
+            snap = flight.Snapshot(s.generated, stamp, time.monotonic())
+            if stamp is not None:
+                item.stream.progress(len(snap), stamp, item.preempt_count)
             try:
-                item.observer(list(s.generated))
+                item.observer(snap)
             except Exception:  # noqa: BLE001 — an observer must never wedge serving
                 pass
+
+    def _stream_completed(self, item: "_QueueItem",
+                          resp: InferenceResponse) -> Dict[str, Any]:
+        """A streamed request resolves: its last tokens ride the response,
+        so the response carries their round's stamp (``extra["egress"]``,
+        for the pump) and the stream's last stretch ends here. Counts the
+        stream under the round that ended its longest wait and returns
+        that wait as ``batcher.completed``'s attributes."""
+        stamp, w = self._ready, item.stream
+        if stamp is None:
+            return {}
+        w.progress(len(resp.token_ids), stamp, item.preempt_count)
+        resp.extra["egress"] = (stamp, time.monotonic())
+        if w.round is None:
+            return {}       # every token came with one round: no wait
+        self.stats[f"longest_wait_{w.cause}"] += 1
+        self.stats[f"longest_wait_s_{w.cause}"] += w.gap_s
+        return {"longest_wait_ms": round(w.gap_s * 1e3, 3),
+                "longest_wait_round": w.round,
+                "longest_wait_cause": w.cause}
 
     def _prefill_chunk_caps(
         self, adms: List[ChunkedAdmission],
@@ -1589,6 +1658,9 @@ class ContinuousBatcher:
         # the scan this round's goes out behind; whatever the engine does
         # with the call, it reads that one
         behind, self._unread_steps = self._unread_steps, None
+        # what the stamp says of the tokens this call brings back: its own
+        # round's, or those of the scan it went out behind
+        read, pieces, cause = steps, 0, "scan"
         if behind is not None and not (
                 self.engine.scan_unread and (ahead or ragged)):
             # an out-of-band engine call read it since the loop looked, or
@@ -1604,8 +1676,11 @@ class ContinuousBatcher:
                              chained=int(behind is not None and not ragged)):
                 if ragged:
                     adms = [adm for adm, _ in self._ragged]
-                    self.engine.ragged_round(
-                        adms, self._prefill_chunk_caps(adms))
+                    caps = self._prefill_chunk_caps(adms)
+                    pieces = len(adms) if caps is None else sum(
+                        caps.get(adm.slot, 0) > 0 for adm in adms)
+                    cause = "ragged_2plus" if pieces > 1 else "ragged_1"
+                    self.engine.ragged_round(adms, caps)
                     st["ragged_rounds"] += 1
                     # (the engine reads an unread scan first: none is left
                     # where ``_chain_break`` saw the admission come)
@@ -1624,10 +1699,15 @@ class ContinuousBatcher:
                 if ahead:
                     emitted, back = \
                         self.engine.decode_multi(steps, ahead=True), behind
+                    read, came = back or 0, self._unread_reason
                     if self.engine.scan_unread:
                         self._unread_steps = steps
+                        self._unread_reason = reason
                 else:
                     emitted, back = self.engine.decode_multi(steps), steps
+                    came = reason
+                if came == "raised_waiting":
+                    cause = "scan_raised"
                 exposed += self._host_exposed_s(engine_stats)
                 cost += self._host_phases_s(engine_stats)
                 wait += engine_stats.get("round_readback_s", 0.0)
@@ -1638,6 +1718,9 @@ class ContinuousBatcher:
                 f"{wait:.3f} s, the gap before it {gap:.3f} s")
         finally:
             self._round_end = time.perf_counter()
+            self._ready = flight.RoundStamp(
+                time.monotonic(), n, "ragged" if ragged else "scan", read,
+                pieces, cause)
 
     def _collect_round(self, why: str
                        ) -> Optional[Tuple[int, float, float]]:
@@ -1672,6 +1755,9 @@ class ContinuousBatcher:
                 f"read back {call:.3f} s after a gap of {gap:.3f} s")
         finally:
             self._round_end = time.perf_counter()
+            self._ready = flight.RoundStamp(
+                time.monotonic(), self._round, "collect", steps or 0, 0,
+                "other")
 
     def _retune(self, steps: int, scan_s: float, host_s: float) -> None:
         """Keep the rule's measured times — ``s``, a scan's time per step,
@@ -1873,9 +1959,11 @@ class ContinuousBatcher:
                     item = self._slot_items.pop(i, None)
                     finished += 1
                     if item and not item.future.done():
+                        waited = self._stream_completed(item, resp) \
+                            if item.stream is not None else {}
                         self._note(item, "batcher.completed",
                                    finish_reason=resp.finish_reason,
-                                   tokens=resp.completion_tokens)
+                                   tokens=resp.completion_tokens, **waited)
                         item.future.set_result(resp)
                         self.stats["completed"] += 1
             # streaming observers see each surviving slot's

@@ -27,13 +27,20 @@ says when it was admitted and by which round (``round=<n>``); the round's
 ``dgi.*`` spans say what the batcher and the engine did in it, on the
 profiler's clock, beside the device's ops. docs/observability.md has the
 table of both.
+
+A TOKEN's way out is the third: the round that brought it is stamped once
+as it returns (:class:`RoundStamp`, engine thread), the stamp rides the
+stream's snapshot (:class:`Snapshot`) and its chunk through the loop, the
+pump thread and the direct server, each of which adds its own instant
+(:data:`EGRESS_KEY`), and the direct server sums the stages where it writes
+the event.
 """
 
 from __future__ import annotations
 
 import os
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 # per-request event cap: a runaway event source (e.g. one chunk-round event
 # per ragged round on a 100k-token prompt) saturates at the cap and counts
@@ -91,6 +98,61 @@ class _NullTimeline:
 
 
 NULL_TIMELINE = _NullTimeline()
+
+
+# ---------------------------------------------------------------------------
+# a token's way out: one stamp a round, carried with the snapshot
+# ---------------------------------------------------------------------------
+
+# the causes a stream's longest wait is counted under (``batcher.stats``
+# ``longest_wait_<cause>``): the round that ended it carried one prompt
+# piece, or several; was a scan raised while a request waited for a slot, or
+# any other scan; or was none of these (a scan read back on its own, the
+# first round after a preemption)
+WAIT_CAUSES = ("ragged_1", "ragged_2plus", "scan_raised", "scan", "other")
+
+# the chunk key an :class:`Egress` rides under from the pump thread to the
+# direct server, which takes it off before the event is serialised
+EGRESS_KEY = "_egress"
+
+
+class RoundStamp(NamedTuple):
+    """One per round, made where the round returns on the engine thread and
+    shared by every row it served: ``ready`` is ``time.monotonic()`` with
+    the round's tokens on the host and committed."""
+
+    ready: float
+    round: int
+    kind: str       # ragged | scan | collect
+    steps: int
+    pieces: int     # prompt pieces a ragged round carried
+    cause: str      # one of WAIT_CAUSES
+
+
+class Egress(NamedTuple):
+    """What a chunk carries of its way out when the pump thread yields it:
+    the stamp of the round that brought its token, the instant the
+    batcher's loop called the observer, the instant of the yield
+    (``time.monotonic()`` both) and the batcher's request id."""
+
+    stamp: RoundStamp
+    notified: float
+    pumped: float
+    req: str
+
+
+class Snapshot(list):
+    """A stream's generated tokens after a round, as its observer gets
+    them: a list, with the round's stamp and the instant the observer was
+    called (``time.monotonic()``, loop thread)."""
+
+    __slots__ = ("round", "notified")
+
+    def __init__(self, tokens: Any, round: Optional[RoundStamp],
+                 notified: float) -> None:
+        super().__init__(tokens)
+        self.round = round
+        self.notified = notified
 
 
 # ---------------------------------------------------------------------------
@@ -230,6 +292,11 @@ class Timeline:
         except (TypeError, ValueError):
             pass
 
+    def at(self, mono: float) -> float:
+        """The wall-clock timestamp this timeline gives the instant
+        ``mono`` of ``time.monotonic()`` (for ``note_at``)."""
+        return self._wall0 + (float(mono) - self._mono0)
+
     def extend_at(self, events: Any) -> None:
         """Adopt ``[(name, wall_ts), ...]`` pairs recorded by a component
         that has no timeline of its own (e.g. the HandoffReceiver, which
@@ -247,11 +314,15 @@ class Timeline:
         as the FULL list each time — the server-side merge unions events
         per source keyed by (name, timestamp), so duplicate delivery (a
         heartbeat retried, a result replayed) is idempotent by
-        construction."""
+        construction. ``mono0`` / ``wall0`` are the clock anchor: an
+        event's instant on ``time.monotonic()`` — one clock for every
+        process of the machine — is ``ts - wall0 + mono0``."""
         if not self.events:
             return None
         out: Dict[str, Any] = {
             "trace_id": self.trace_id,
+            "mono0": round(self._mono0, 6),
+            "wall0": round(self._wall0, 6),
             "events": [
                 [name, round(ts, 6), _safe_attrs(attrs) if attrs else None]
                 for name, ts, attrs in self.events

@@ -41,7 +41,7 @@ from .calibration import CostCalibration, MigrateHintTracker
 from .flight_recorder import FlightRecorder
 from .geo import GeoService
 from .health import HealthService
-from .observability import MetricsCollector, StructuredLogger, TracingManager
+from .observability import MetricsCollector, StructuredLogger
 from .prefix_routing import (
     PrefixRegistry,
     RoutingConfig,
@@ -158,16 +158,12 @@ class ServerState:
         # flipped/retuned live via GET/PUT /api/v1/admin/admission.
         self.admission = AdmissionController(metrics=self.metrics)
         self.privacy = EnterprisePrivacyService(self.store)
-        # console export is env-driven (DGI_OTEL_CONSOLE) — the knob was
-        # previously unreachable (no caller could ever enable it)
-        self.tracing = TracingManager()
         # request flight recorder (round 14): merged per-request timelines
         # — server admission/route/claim/complete events plus worker-side
         # events shipped through results and heartbeats. Always-on and
         # advisory: every recorder call is wrapped so it can never fail or
         # reorder a request.
         self.flight = FlightRecorder(metrics=self.metrics,
-                                     tracing=self.tracing,
                                      calibration=self.calibration)
         self.scheduler.attach_flight(self.flight)
         # gray-failure defense (round 18): windowed per-worker health
@@ -893,15 +889,13 @@ async def next_job(request: web.Request) -> web.Response:
             worker_id, current_job_id=None, status=WorkerState.IDLE.value
         )
         return web.Response(status=204)
-    # the claim lands on the request's timeline (+ an OTel span): queue
-    # wait on the queued path is submitted → claimed
+    # the claim lands on the request's timeline: queue wait on the queued
+    # path is submitted → claimed
     trace_id = (job.get("params") or {}).get("trace_id") \
         if isinstance(job.get("params"), dict) else None
     if trace_id:
-        with st.tracing.span("job.claim", trace_id=trace_id,
-                             worker=worker_id):
-            _flight_note(st, trace_id, "server.claimed",
-                         job_id=job["id"], worker=worker_id)
+        _flight_note(st, trace_id, "server.claimed",
+                     job_id=job["id"], worker=worker_id)
     st.metrics.record_queue("queued", (await st.store.queue_stats())["queued"])
     return web.json_response({"job": job})
 
@@ -1059,10 +1053,8 @@ async def _flight_complete(st: ServerState, job: Dict[str, Any],
             return
         if flight_wire is not None:
             st.flight.ingest_wire(worker_id, flight_wire)
-        with st.tracing.span("job.complete", trace_id=trace_id,
-                             worker=worker_id, success=success):
-            _flight_note(st, trace_id, "server.completed", job_id=job_id,
-                         worker=worker_id, success=success)
+        _flight_note(st, trace_id, "server.completed", job_id=job_id,
+                     worker=worker_id, success=success)
         # a PD prefill child's completion is NOT the end of the request:
         # defer e2e/decode/handoff observation to the decode child's
         # finalize (observe-once would otherwise lock in a prefill-only
@@ -1383,8 +1375,7 @@ async def create_job(request: web.Request) -> web.Response:
         row["status"] = JobStatus.RUNNING.value
         row["started_at"] = time.time()
         try:
-            with st.tracing.span("job.submit", trace_id=trace_id, pd=True):
-                job_id = await st.store.create_job(row)
+            job_id = await st.store.create_job(row)
         except sqlite3.OperationalError as exc:
             return _store_unavailable(st, exc)
         st.metrics.record_store_degraded(False)
@@ -1415,8 +1406,7 @@ async def create_job(request: web.Request) -> web.Response:
             {"job_id": job_id, "status": "running", "pd": True}, status=201
         )
     try:
-        with st.tracing.span("job.submit", trace_id=trace_id):
-            job_id = await st.store.create_job(row)
+        job_id = await st.store.create_job(row)
     except sqlite3.OperationalError as exc:
         return _store_unavailable(st, exc)
     st.metrics.record_store_degraded(False)
@@ -1459,8 +1449,7 @@ async def create_job_sync(request: web.Request) -> web.Response:
     row = await _make_job_row(request, body)
     row["priority"] = row["priority"] + 10
     try:
-        with st.tracing.span("job.submit", trace_id=trace_id, sync=True):
-            job_id = await st.store.create_job(row)
+        job_id = await st.store.create_job(row)
     except sqlite3.OperationalError as exc:
         return _store_unavailable(st, exc)
     st.metrics.record_store_degraded(False)

@@ -17,9 +17,8 @@ It accumulates:
 ``finalize`` derives the canonical phase durations from the merged
 timeline, feeds the ``request_phase_latency_seconds{phase}`` histograms
 (each phase observed at most ONCE per trace, no matter how many times a
-completion/heartbeat re-delivers), retains the N slowest traces per phase
-in bounded exemplar rings, and emits one retroactive OTel span per phase
-when the ``TracingManager`` is live.
+completion/heartbeat re-delivers) and retains the N slowest traces per
+phase in bounded exemplar rings.
 
 Everything here is advisory: a malformed payload is a counted, skipped
 sample; the per-trace store is a bounded LRU; no recorder failure can
@@ -100,16 +99,14 @@ class _Trace:
 
 
 class FlightRecorder:
-    """Bounded per-trace event store + the /metrics·OTel·exemplar fan-out."""
+    """Bounded per-trace event store + the /metrics·exemplar fan-out."""
 
     def __init__(self, metrics: Optional[Any] = None,
-                 tracing: Optional[Any] = None,
                  trace_cap: int = TRACE_CAP,
                  event_cap: int = FLIGHT_EVENT_CAP,
                  exemplars_per_phase: int = EXEMPLARS_PER_PHASE,
                  calibration: Optional[Any] = None) -> None:
         self._metrics = metrics
-        self._tracing = tracing
         # cost-model self-calibration sink (server/calibration.py): done
         # wires carry the full per-source event list, whose queue-wait /
         # prefill spans are the calibration samples. Optional and
@@ -340,9 +337,8 @@ class FlightRecorder:
         """Derive phases from the merged timeline and fan out: histogram
         observation (once per phase per trace — re-finalizing after more
         events arrive observes only phases not yet seen, so PD child
-        completions and duplicate deliveries compose), exemplar retention,
-        and retroactive OTel phase spans. Returns the durations observed
-        THIS call.
+        completions and duplicate deliveries compose) and exemplar
+        retention. Returns the durations observed THIS call.
 
         ``partial=True`` (a PD prefill child's completion) defers the
         phases whose right edge is the END of the request — e2e, decode,
@@ -392,28 +388,4 @@ class FlightRecorder:
                 ring = self.exemplars.get(phase)
                 if ring is not None:
                     ring.push(dur, trace_id)
-        tracing = self._tracing
-        if tracing is not None and getattr(tracing, "enabled", False):
-            self._emit_spans(trace_id, merged, fresh)
         return fresh
-
-    def _emit_spans(self, trace_id: str, merged: List[Dict[str, Any]],
-                    fresh: Dict[str, float]) -> None:
-        """One retroactive OTel span per freshly-observed phase, anchored
-        at the merged timeline's start. Best-effort by contract."""
-        if not merged:
-            return
-        start = float(merged[0]["ts"])
-        end = float(merged[-1]["ts"])
-        for phase, dur in fresh.items():
-            # anchor: e2e/ttft/queue_wait start at the trace start; the
-            # rest end where their closing event landed — close enough
-            # for a span waterfall, exact durations ride the histogram
-            t1 = end if phase == "e2e" else min(start + dur, end)
-            try:
-                self._tracing.emit_span(
-                    f"request.{phase}", t1 - dur, t1,
-                    trace_id=trace_id, duration_s=round(dur, 6),
-                )
-            except Exception:  # noqa: BLE001
-                pass

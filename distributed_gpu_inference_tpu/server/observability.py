@@ -1,12 +1,12 @@
-"""Metrics, tracing, structured logging — the observability surface.
+"""Metrics and structured logging — the observability surface.
 
 Behavioral parity with the reference's ``server/app/services/observability.py``:
 - Prometheus metric set (:30-141): inference requests/latency, tokens and
   tokens/s, KV-cache hit rate / size / evictions per tier, worker status,
   accelerator memory, distributed hop latency histogram, KV migration latency,
   batch size, per-phase queue size, speculative accept rate + speedup.
-- Optional imports (:22-27, :146-154): everything degrades to no-op stubs when
-  prometheus_client / opentelemetry are absent.
+- Optional import (:22-27): everything degrades to no-op stubs when
+  prometheus_client is absent.
 - ``MetricsCollector`` facade (:255-405), ``/metrics`` text endpoint factory
   (:410-450), ``StructuredLogger`` with bound context (:455-488).
 
@@ -19,12 +19,10 @@ counters", says how to capture a trace on a live worker).
 
 from __future__ import annotations
 
-import contextlib
 import json
 import logging
-import os
 import time
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Optional
 
 try:
     from prometheus_client import (
@@ -38,18 +36,6 @@ try:
     HAVE_PROMETHEUS = True
 except Exception:  # pragma: no cover
     HAVE_PROMETHEUS = False
-
-try:
-    from opentelemetry import trace as _otel_trace
-    from opentelemetry.sdk.trace import TracerProvider
-    from opentelemetry.sdk.trace.export import (
-        BatchSpanProcessor,
-        ConsoleSpanExporter,
-    )
-
-    HAVE_OTEL = True
-except Exception:  # pragma: no cover
-    HAVE_OTEL = False
 
 
 # ---------------------------------------------------------------------------
@@ -104,6 +90,10 @@ class Metrics:
                 "batcher_scan_step_ms", "batcher_round_host_ms",
                 "batcher_scan_reasons", "batcher_scan_row_steps_masked",
                 "batcher_scans_chained", "batcher_chain_breaks",
+                "batcher_stream_longest_wait",
+                "batcher_stream_longest_wait_seconds",
+                "direct_sse_events", "direct_token_egress_seconds",
+                "direct_egress_stalls", "direct_admit_seconds",
                 "engine_round_seconds", "worker_compiles",
                 "worker_compile_seconds",
                 "prefix_route_hits", "prefix_route_spillover",
@@ -325,6 +315,43 @@ class Metrics:
             "(cancel, interrupt, deadline, an out-of-band engine call), "
             "pressure (KV pool), idle (no row has a step left)",
             ["worker", "reason"], registry=r)
+        # seconds / count by cause is the mean longest wait a cause leaves
+        # a stream: what a round with a prompt piece costs a user
+        self.batcher_stream_longest_wait = Counter(
+            "batcher_stream_longest_wait_total",
+            "Streams completed, by the round that ended their longest "
+            "wait for a token: ragged_1 (a round with one prompt piece), "
+            "ragged_2plus, scan_raised (a scan raised while a request "
+            "waited for a slot), scan, other (a scan read back on its "
+            "own, the first round after a preemption)",
+            ["worker", "cause"], registry=r)
+        self.batcher_stream_longest_wait_seconds = Counter(
+            "batcher_stream_longest_wait_seconds_total",
+            "Seconds of those longest waits, one a stream (between the "
+            "returns of two rounds, engine thread)",
+            ["worker", "cause"], registry=r)
+        # a token's way out of the worker (DirectServer.stats): seconds by
+        # stage over events is the mean time a token takes from its round's
+        # return to the socket write
+        self.direct_sse_events = Counter(
+            "direct_sse_events_total",
+            "Token-bearing SSE events the direct server wrote",
+            ["worker"], registry=r)
+        self.direct_token_egress_seconds = Counter(
+            "direct_token_egress_seconds_total",
+            "Seconds from a round's return to its events' socket writes, "
+            "by stage: notify (engine thread to the batcher loop's observer "
+            "call), pump (to the stream's pump thread yielding the chunk), "
+            "write (to the write's return on the direct server's loop)",
+            ["worker", "stage"], registry=r)
+        self.direct_egress_stalls = Counter(
+            "direct_egress_stalls_total",
+            "Events written over 50 ms after their round returned",
+            ["worker"], registry=r)
+        self.direct_admit_seconds = Counter(
+            "direct_admit_seconds_total",
+            "Direct-server loop seconds parsing and admitting requests",
+            ["worker"], registry=r)
         # readback is the engine thread waiting for the device; its share
         # of the four says whether the host or the chip bounds the rounds
         self.engine_round_seconds = Counter(
@@ -832,6 +859,12 @@ class MetricsCollector:
             elif key.startswith("chain_breaks_"):
                 metric = self.metrics.batcher_chain_breaks.labels(
                     worker, key[13:])
+            elif key.startswith("longest_wait_s_"):
+                metric = self.metrics.batcher_stream_longest_wait_seconds \
+                    .labels(worker, key[15:])
+            elif key.startswith("longest_wait_"):
+                metric = self.metrics.batcher_stream_longest_wait.labels(
+                    worker, key[13:])
             elif key.startswith("scans_"):
                 metric = self.metrics.batcher_scan_reasons.labels(
                     worker, key[6:])
@@ -1186,20 +1219,35 @@ class MetricsCollector:
                              stats: Dict[str, Any]) -> None:
         """Ingest one worker's direct-serving channel (heartbeat
         ``engine_stats["direct"]`` — ``DirectServer.wire_stats()``):
-        cancelled hedge losers into ``hedges_total{outcome=cancelled}``.
-        Same delta anchoring as every other engine payload; the latency
-        samples riding the same channel feed the HealthService, not a
-        metric."""
+        cancelled hedge losers into ``hedges_total{outcome=cancelled}``,
+        a token's way out into ``direct_sse_events_total``,
+        ``direct_token_egress_seconds_total{stage}``,
+        ``direct_egress_stalls_total`` and ``direct_admit_seconds_total``.
+        Same delta anchoring as every other engine payload (totals
+        re-anchor on restart, a malformed field skips its sample); the
+        latency samples riding the same channel feed the HealthService,
+        not a metric."""
         prev = self._direct_prev.setdefault(worker, {})
-        if "hedge_cancels" in stats:
+        m = self.metrics
+        for key, metric in (
+            ("hedge_cancels", m.hedges.labels("cancelled")),
+            ("sse_events", m.direct_sse_events.labels(worker)),
+            ("egress_stalled", m.direct_egress_stalls.labels(worker)),
+            ("admit_s", m.direct_admit_seconds.labels(worker)),
+            *((f"egress_{stage}_s",
+               m.direct_token_egress_seconds.labels(worker, stage))
+              for stage in ("notify", "pump", "write")),
+        ):
+            if key not in stats:
+                continue
             try:
-                cur = int(stats.get("hedge_cancels", 0) or 0)
+                cur = float(stats.get(key) or 0.0)
             except (TypeError, ValueError):
-                return
-            delta = cur - prev.get("hedge_cancels", 0)
+                continue
+            delta = cur - prev.get(key, 0)
             if delta > 0:
-                self.metrics.hedges.labels("cancelled").inc(delta)
-            prev["hedge_cancels"] = cur
+                metric.inc(delta)
+            prev[key] = cur
 
     def record_chaos_event(self, kind: str) -> None:
         """Harness-facing seam: the fleet chaos driver reports each event
@@ -1222,76 +1270,6 @@ class MetricsCollector:
 
     def render(self) -> bytes:
         return self.metrics.render()
-
-
-# ---------------------------------------------------------------------------
-# Tracing (reference :157-246)
-# ---------------------------------------------------------------------------
-
-
-def otel_console_from_env() -> bool:
-    """``DGI_OTEL_CONSOLE=1`` turns on the console span exporter — the
-    previously-unreachable ``TracingManager(console_export=...)`` knob
-    (no caller could ever enable it) is now operator-settable without a
-    code change. Off by default."""
-    return os.environ.get("DGI_OTEL_CONSOLE", "").strip().lower() in (
-        "1", "true", "yes", "on",
-    )
-
-
-class TracingManager:
-    def __init__(self, service_name: str = "dgi-tpu",
-                 console_export: Optional[bool] = None) -> None:
-        if console_export is None:
-            console_export = otel_console_from_env()
-        self.enabled = HAVE_OTEL
-        if not self.enabled:
-            self._tracer = None
-            return
-        provider = TracerProvider()
-        if console_export:  # deployments swap in OTLP/Jaeger exporters
-            provider.add_span_processor(
-                BatchSpanProcessor(ConsoleSpanExporter())
-            )
-        self._tracer = provider.get_tracer(service_name)
-
-    @contextlib.contextmanager
-    def span(self, name: str, **attributes: Any) -> Iterator[Any]:
-        if not self.enabled or self._tracer is None:
-            yield None
-            return
-        with self._tracer.start_as_current_span(name) as sp:
-            for k, v in attributes.items():
-                try:
-                    sp.set_attribute(k, v)
-                except Exception:  # noqa: BLE001
-                    pass
-            try:
-                yield sp
-            except Exception as exc:
-                sp.record_exception(exc)
-                raise
-
-    def emit_span(self, name: str, start_s: float, end_s: float,
-                  **attributes: Any) -> None:
-        """One RETROACTIVE span (explicit wall-clock start/end): the
-        flight recorder derives phase boundaries after the fact and maps
-        each onto an OTel span. No-op without opentelemetry; best-effort
-        with it (a tracing failure must never fail a request)."""
-        if not self.enabled or self._tracer is None:
-            return
-        try:
-            sp = self._tracer.start_span(
-                name, start_time=int(float(start_s) * 1e9)
-            )
-            for k, v in attributes.items():
-                try:
-                    sp.set_attribute(k, v)
-                except Exception:  # noqa: BLE001
-                    pass
-            sp.end(end_time=int(float(end_s) * 1e9))
-        except Exception:  # noqa: BLE001 — advisory by contract
-            pass
 
 
 # ---------------------------------------------------------------------------

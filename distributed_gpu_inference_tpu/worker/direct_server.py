@@ -15,14 +15,85 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import json
+import logging
 import threading
 import time
 import uuid
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 from aiohttp import web
 
+from ..runtime.flight import EGRESS_KEY, Egress, span
 from ..testing import faults as _faults
+
+log = logging.getLogger(__name__)
+
+# an event written this long after its round returned is a stalled one:
+# counted, and logged with its stamps
+EGRESS_STALL_S = 0.050
+
+
+class _Written(NamedTuple):
+    """One event of a traced stream as the direct server keeps it: its four
+    stamps (``time.monotonic()`` seconds) and the round that brought it."""
+
+    ready: float
+    notified: float
+    pumped: float
+    written: float
+    round: int
+    cause: str
+
+
+class _StreamWatch:
+    """What the direct server keeps of a TRACED stream's writes (O(1)):
+    its first token-bearing event, its last one, and the two either side of
+    the longest stretch between two consecutive writes. They become the
+    timeline events ``direct.first_write`` and ``direct.longest_wait``."""
+
+    __slots__ = ("first", "last", "gap_s", "ended", "before")
+
+    def __init__(self) -> None:
+        self.first: Optional[_Written] = None
+        self.last: Optional[_Written] = None
+        self.gap_s = 0.0
+        self.ended: Optional[_Written] = None
+        self.before: Optional[_Written] = None
+
+    def wrote(self, ev: _Written) -> None:
+        if self.last is None:
+            self.first = ev
+        elif ev.written - self.last.written > self.gap_s:
+            self.gap_s = ev.written - self.last.written
+            self.ended, self.before = ev, self.last
+        self.last = ev
+
+    def events(self) -> List[Tuple[str, float, Dict[str, Any]]]:
+        """``(name, time.monotonic() instant, attributes)`` of each."""
+        out = []
+        if self.first is not None:
+            out.append(("direct.first_write", self.first.written,
+                        self.first._asdict()))
+        if self.ended is not None and self.before is not None:
+            out.append(("direct.longest_wait", self.ended.written, {
+                "wait_ms": round(self.gap_s * 1e3, 3), **self.ended._asdict(),
+                **{f"prev_{k}": v
+                   for k, v in self.before._asdict().items()}}))
+        return out
+
+
+def add_timeline_events(wire: Any, watch: _StreamWatch) -> None:
+    """Add the watch's events to a serialised timeline (``Timeline.wire``),
+    whose clock anchor puts their monotonic instants on its axis. The list
+    is replaced, not grown: the heartbeat ring holds the same dict and may
+    be serialising it on another thread."""
+    if not isinstance(wire, dict) or "mono0" not in wire:
+        return
+    shift = float(wire["wall0"]) - float(wire["mono0"])
+    wire["events"] = list(wire.get("events") or []) + [
+        [name, round(mono + shift, 6), attrs]
+        for name, mono, attrs in watch.events()]
 
 
 class DirectServer:
@@ -37,8 +108,20 @@ class DirectServer:
         self._thread: Optional[threading.Thread] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._started = threading.Event()
-        self.stats: Dict[str, Any] = {"requests": 0, "rejected": 0,
-                                      "hedge_cancels": 0}
+        self.stats: Dict[str, Any] = {
+            "requests": 0, "rejected": 0, "hedge_cancels": 0,
+            # seconds inside _parse_and_admit (span dgi.direct.admit)
+            "admit_s": 0.0,
+            # a token's way out, summed on this server's loop as each
+            # event is written: events that carried a round's stamp, the
+            # seconds from that round's return to the write and their
+            # three stages (engine thread -> batcher loop -> pump thread
+            # -> socket), and the events over EGRESS_STALL_S with their
+            # seconds above it (docs/observability.md)
+            "sse_events": 0, "egress_s": 0.0, "egress_notify_s": 0.0,
+            "egress_pump_s": 0.0, "egress_write_s": 0.0,
+            "egress_stalled": 0, "egress_stall_s": 0.0,
+        }
         # health-telemetry accumulators, drained into each heartbeat by
         # wire_stats(): per-request wall latencies (ms) and served-5xx
         # counts since the last beat. Handlers run on the direct-server
@@ -62,6 +145,19 @@ class DirectServer:
 
     async def _parse_and_admit(self, request: web.Request,
                                require_stream: bool = False):
+        """``_admit`` under the span ``dgi.direct.admit`` (its seconds:
+        ``admit_s``); a traced request takes the instant it was accepted,
+        before its body was parsed, to its timeline."""
+        accepted = time.monotonic()
+        with span("dgi.direct.admit", self.stats, "admit_s") as sp:
+            out = await self._admit(request, require_stream)
+            params = (out[1] or {}).get("params")
+            if isinstance(params, dict) and params.get("trace_id"):
+                params["_flight_accepted"] = accepted
+                sp.set(trace_id=str(params["trace_id"])[:128])
+        return out
+
+    async def _admit(self, request: web.Request, require_stream: bool):
         """ONE admission pipeline for both inference endpoints (load-control
         caps must hold no matter which path the job takes): returns
         ``(engine, body, release, None)`` with the worker CLAIMED, or
@@ -107,6 +203,7 @@ class DirectServer:
             # flight recorder: the arrival stamps are worker-minted too —
             # a client-forged pickup time would skew phase attribution
             params.pop("_flight_picked_up_ts", None)
+            params.pop("_flight_accepted", None)
             params.pop("_flight_tl", None)
             if params.get("trace_id"):
                 # direct requests skip the queue: the "pickup" is the
@@ -180,15 +277,49 @@ class DirectServer:
     def wire_stats(self) -> Dict[str, Any]:
         """Heartbeat ``engine_stats["direct"]`` channel: drains the
         since-last-beat latency samples / served-5xx count (deltas), plus
-        the CUMULATIVE hedge-cancel counter the plane delta-anchors into
-        ``hedges_total{outcome="cancelled"}``."""
+        the CUMULATIVE counters the plane delta-anchors: hedge cancels
+        into ``hedges_total{outcome="cancelled"}``, and a token's way out
+        (``direct_sse_events_total``, ``direct_token_egress_seconds_total
+        {stage}``, ``direct_egress_stalls_total``,
+        ``direct_admit_seconds_total``)."""
         with self._stats_lock:
             recent = self._recent_ms
             self._recent_ms = []
             errors = self._new_errors
             self._new_errors = 0
+        st = self.stats
         return {"recent_ms": recent, "new_errors": errors,
-                "hedge_cancels": int(self.stats["hedge_cancels"])}
+                "hedge_cancels": int(st["hedge_cancels"]),
+                "sse_events": int(st["sse_events"]),
+                "egress_stalled": int(st["egress_stalled"]),
+                **{k: round(float(st[k]), 6) for k in (
+                    "egress_notify_s", "egress_pump_s", "egress_write_s",
+                    "admit_s")}}
+
+    def _wrote(self, egress: Egress, written: float,
+               watch: Optional[_StreamWatch]) -> None:
+        """Count one event whose chunk carried ``egress``, written at
+        ``written`` (``time.monotonic()``)."""
+        rstamp, notified, pumped, req = egress
+        st = self.stats
+        total = written - rstamp.ready
+        st["sse_events"] += 1
+        st["egress_s"] += total
+        st["egress_notify_s"] += notified - rstamp.ready
+        st["egress_pump_s"] += pumped - notified
+        st["egress_write_s"] += written - pumped
+        if total > EGRESS_STALL_S:
+            st["egress_stalled"] += 1
+            st["egress_stall_s"] += total - EGRESS_STALL_S
+            log.warning(
+                "request %s: an event left %.3f s after round %d (%s, %d "
+                "steps) returned: ready %.6f, notified +%.3f, pumped +%.3f, "
+                "written +%.3f", req, total, rstamp.round, rstamp.kind,
+                rstamp.steps, rstamp.ready, notified - rstamp.ready,
+                pumped - rstamp.ready, total)
+        if watch is not None:
+            watch.wrote(_Written(rstamp.ready, notified, pumped, written,
+                                 rstamp.round, rstamp.cause))
 
     async def _inference(self, request: web.Request) -> web.Response:
         t0 = time.time()   # BEFORE the fault seam: injected gray delay is
@@ -274,9 +405,15 @@ class DirectServer:
         (``{"stream_id", "offset"}``) adopts the stream's control-plane
         checkpoint — possibly left by a DIFFERENT, now-dead worker — and
         splices the continuation at the client's offset: no token re-sent,
-        none skipped."""
-        import json
+        none skipped.
 
+        A token-bearing chunk comes with the stamps of its way here under
+        ``EGRESS_KEY``; the key is taken off before the event is
+        serialised (the bytes on the wire do not know of it), and the
+        stages are summed once the write returns (``_wrote``). A traced
+        stream's first write and longest wait between writes join its
+        timeline in the closing event, which this server is the last to
+        touch."""
         engine, body, release, err = await self._parse_and_admit(
             request, require_stream=True
         )
@@ -342,6 +479,7 @@ class DirectServer:
             }
         )
         await resp.prepare(request)
+        watch = _StreamWatch() if params.get("trace_id") else None
         agen = engine.stream_inference(params)
         try:
             async for chunk in agen:
@@ -354,11 +492,19 @@ class DirectServer:
                     with contextlib.suppress(Exception):
                         request.transport.close()
                     raise ConnectionResetError("fault injected: stream cut")
-                evt = b""
-                if chunk.get("offset") is not None:
-                    evt += f"id: {chunk['offset']}\n".encode()
-                evt += f"data: {json.dumps(chunk)}\n\n".encode()
-                await resp.write(evt)
+                egress = chunk.pop(EGRESS_KEY, None)
+                if watch is not None and chunk.get("done"):
+                    add_timeline_events(chunk.get("timeline"), watch)
+                with span("dgi.direct.write",
+                          req=egress.req if egress else "",
+                          round=egress.stamp.round if egress else -1):
+                    evt = b""
+                    if chunk.get("offset") is not None:
+                        evt += f"id: {chunk['offset']}\n".encode()
+                    evt += f"data: {json.dumps(chunk)}\n\n".encode()
+                    await resp.write(evt)
+                if egress is not None:
+                    self._wrote(egress, time.monotonic(), watch)
         except ConnectionResetError:
             pass  # client went away mid-stream; aclose() below aborts the run
         finally:

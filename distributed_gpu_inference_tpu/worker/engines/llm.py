@@ -35,7 +35,13 @@ from ...runtime.batcher import (
 from ...testing import faults as _faults
 from ...utils.backoff import full_jitter_delay
 from ...runtime.engine import EngineConfig, PreemptedSequence, TPUEngine
-from ...runtime.flight import NULL_TIMELINE, timeline_for
+from ...runtime.flight import (
+    EGRESS_KEY,
+    NULL_TIMELINE,
+    Egress,
+    span,
+    timeline_for,
+)
 from ...runtime.prefix_summary import TIER_HOST, TIER_SPILL, PrefixHotSet
 from ...utils.config import ServingConfig, warn_deprecated_serving_key
 from ...utils.data_structures import InferenceRequest, SamplingParams
@@ -1924,8 +1930,14 @@ class TPULLMEngine(LLMBaseEngine):
             params, source=str(getattr(self, "fault_tag", "") or "")
         )
         ts = params.pop("_flight_picked_up_ts", None)
-        if ts is not None and tl.enabled:
-            tl.note_at("worker.picked_up", ts)
+        accepted = params.pop("_flight_accepted", None)
+        if tl.enabled:
+            if accepted is not None:
+                # the direct server's first sight of the request, before
+                # its body was parsed (time.monotonic())
+                tl.note_at("direct.accepted", tl.at(accepted))
+            if ts is not None:
+                tl.note_at("worker.picked_up", ts)
         return tl
 
     def _flight_finish(self, tl: Any,
@@ -2334,16 +2346,22 @@ class TPULLMEngine(LLMBaseEngine):
         (``serving.mode: direct``). Both emit the same chunk contract:
         ``{"text_delta", "token_ids", "offset"}...`` then a final
         ``{"done": True, "finish_reason", "usage", "offset"}``."""
+        if self.serving is not None and self.serving.active:
+            return self._stream_serving(params, cancel=cancel)
+        tl = self._flight_migrate(params)
+        if tl.enabled:
+            return self._stream_direct_traced(tl, params, cancel)
+        return self._stream_direct(params, cancel=cancel)
+
+    def _flight_migrate(self, params: Dict[str, Any]) -> Any:
+        """A stream's first two steps: its timeline, and the KV-migration
+        probe, which notes on it."""
         tl = self._flight_timeline(params)
         if tl.enabled:
             params["_flight_tl"] = tl
         self._maybe_migrate_kv(params)
-        if self.serving is not None and self.serving.active:
-            return self._stream_serving(params, cancel=cancel)
-        if tl.enabled:
-            params.pop("_flight_tl", None)
-            return self._stream_direct_traced(tl, params, cancel)
-        return self._stream_direct(params, cancel=cancel)
+        params.pop("_flight_tl", None)
+        return tl
 
     def _stream_direct_traced(self, tl: Any, params: Dict[str, Any],
                               cancel: Optional[Any] = None):
@@ -2422,59 +2440,75 @@ class TPULLMEngine(LLMBaseEngine):
         from the observer's monotonic token snapshots with the exact
         stop-string/holdback/splice machinery of the legacy per-step
         driver, so exactly-once token offsets and checkpoint/resume hold
-        while the sequence shares decode rounds with other slots."""
-        cfg = GenerationConfig.from_params(params)
-        tl = params.pop("_flight_tl", NULL_TIMELINE)
-        tl.note("worker.stream.start")
-        ctx = params.get("_failover_ctx")
-        ctx = ctx if isinstance(ctx, dict) else {}
-        key = str(ctx.get("key") or params.get("stream_id") or "") or None
-        epoch = int(ctx.get("epoch") or 0)
-        ckpt = ctx.get("checkpoint")
-        resume_from = int(ctx.get("offset") or 0)
-        resume_text = int(ctx.get("text_offset") or 0)
+        while the sequence shares decode rounds with other slots.
 
-        def stamp(chunk: Dict[str, Any], offset: int) -> Dict[str, Any]:
-            if key is not None:
-                chunk["stream_id"] = key
-                chunk["offset"] = offset
-            return chunk
+        A token's way out is stamped as it passes (runtime/flight.py,
+        ``EGRESS_KEY``): a chunk carries the stamp of the round that brought
+        its token, the instant the batcher's loop handed the snapshot over
+        and the instant it is yielded here, for the direct server to sum
+        where it writes the event; ``dgi.worker.stream.open`` spans the
+        way in, ``dgi.worker.stream.pump`` each snapshot."""
+        with span("dgi.worker.stream.open") as opened:
+            tl = self._flight_migrate(params)
+            cfg = GenerationConfig.from_params(params)
+            tl.note("worker.stream.start")
+            ctx = params.get("_failover_ctx")
+            ctx = ctx if isinstance(ctx, dict) else {}
+            key = str(ctx.get("key") or params.get("stream_id") or "") \
+                or None
+            epoch = int(ctx.get("epoch") or 0)
+            resume_from = int(ctx.get("offset") or 0)
+            resume_text = int(ctx.get("text_offset") or 0)
 
-        holdback = max((len(s) for s in cfg.stop), default=0)
-        holdback = max(holdback - 1, 0)
-        pre = self._ckpt_from_wire(ckpt)
-        if pre is not None:
-            remaining = (pre.request.sampling.max_new_tokens
-                         - len(pre.generated))
-            if remaining <= 0:
-                yield from self._stream_checkpoint_tail(
-                    pre, cfg, stamp, holdback, resume_from, resume_text
+            def stamp(chunk: Dict[str, Any], offset: int) -> Dict[str, Any]:
+                if key is not None:
+                    chunk["stream_id"] = key
+                    chunk["offset"] = offset
+                return chunk
+
+            holdback = max((len(s) for s in cfg.stop), default=0)
+            holdback = max(holdback - 1, 0)
+            pre = self._ckpt_from_wire(ctx.get("checkpoint"))
+            # a checkpoint that already holds the whole generation is
+            # served from itself, below: nothing is submitted
+            whole = pre is not None and len(pre.generated) >= \
+                pre.request.sampling.max_new_tokens
+            if not whole:
+                if pre is not None:
+                    req = pre.request
+                else:
+                    req = self._build_request(
+                        params.get("messages") or params.get("prompt")
+                        or "", cfg,
+                        token_ids=params.pop("_kvmig_token_ids", None),
+                    )
+                    if params.get("priority") is not None:
+                        req.priority = int(params.get("priority") or 0)
+                # spec waves buffer whole generations — a stream needs
+                # per-round progress, so it always decodes through the
+                # paged slots
+                req.params["speculative"] = False
+                request_id = req.request_id
+                snaps: "_queue_mod.Queue" = _queue_mod.Queue()
+                _DONE = object()
+                # batcher-side abort (cancel / stop cut)
+                stop_evt = threading.Event()
+                fut = self.serving.submit_async(
+                    req, observer=snaps.put,
+                    cancel=stop_evt, resume_from=pre,
+                    flight=tl if tl.enabled else None,
                 )
-                return
-            req = pre.request
-        else:
-            req = self._build_request(
-                params.get("messages") or params.get("prompt") or "", cfg,
-                token_ids=params.pop("_kvmig_token_ids", None),
+                fut.add_done_callback(lambda f: snaps.put(_DONE))
+                opened.set(req=request_id)
+                if tl.enabled:
+                    opened.set(trace_id=tl.trace_id)
+        if whole:
+            yield from self._stream_checkpoint_tail(
+                pre, cfg, stamp, holdback, resume_from, resume_text
             )
-            if params.get("priority") is not None:
-                req.priority = int(params.get("priority") or 0)
-        # spec waves buffer whole generations — a stream needs per-round
-        # progress, so it always decodes through the paged slots
-        req.params["speculative"] = False
-        request_id = req.request_id
+            return
         live_info = {"kind": "stream", "epoch": epoch,
                      "request_id": request_id}
-
-        snaps: "_queue_mod.Queue" = _queue_mod.Queue()
-        _DONE = object()
-        stop_evt = threading.Event()   # batcher-side abort (cancel / stop cut)
-        fut = self.serving.submit_async(
-            req, observer=lambda toks: snaps.put(toks),
-            cancel=stop_evt, resume_from=pre,
-            flight=tl if tl.enabled else None,
-        )
-        fut.add_done_callback(lambda f: snaps.put(_DONE))
 
         last_ckpt = len(pre.generated) if pre is not None else 0
         if key is not None:
@@ -2509,9 +2543,13 @@ class TPULLMEngine(LLMBaseEngine):
                         _raise_serving(final)
                     gen = list(final.token_ids)
                     finished = True
+                    rstamp, notified = final.extra.get("egress") \
+                        or (None, 0.0)
                 else:
                     gen = list(item)
                     finished = False
+                    rstamp = getattr(item, "round", None)
+                    notified = getattr(item, "notified", 0.0)
                 # a round snapshot may carry SEVERAL new tokens — process
                 # them one at a time so the SSE cadence (one event per
                 # token, each stamped with its offset) is identical to the
@@ -2520,18 +2558,24 @@ class TPULLMEngine(LLMBaseEngine):
                 ks = list(range(sp.sent_tokens + 1, len(gen) + 1))
                 if not ks and finished:
                     ks = [len(gen)]       # flush held-back chars at EOS
-                for k in ks:
-                    if stopping:
-                        break
-                    fin_k = finished and k == len(gen)
-                    chunk, stop_cut = sp.advance(gen[:k], fin_k)
-                    if chunk is not None:
-                        yield stamp(chunk, sp.sent_tokens)
-                    if stop_cut and not fin_k:
-                        # release the slot; the final (abort) response
-                        # still carries the full usage accounting
-                        stopping = True
-                        stop_evt.set()
+                with span("dgi.worker.stream.pump", req=request_id,
+                          round=rstamp.round if rstamp else -1):
+                    for k in ks:
+                        if stopping:
+                            break
+                        fin_k = finished and k == len(gen)
+                        chunk, stop_cut = sp.advance(gen[:k], fin_k)
+                        if chunk is not None:
+                            if rstamp is not None:
+                                chunk[EGRESS_KEY] = Egress(
+                                    rstamp, notified, time.monotonic(),
+                                    request_id)
+                            yield stamp(chunk, sp.sent_tokens)
+                        if stop_cut and not fin_k:
+                            # release the slot; the final (abort) response
+                            # still carries the full usage accounting
+                            stopping = True
+                            stop_evt.set()
                 if finished:
                     break
                 if cancel is not None and cancel.is_set():

@@ -512,6 +512,12 @@ class Worker:
                 if k in ("between_rounds", "scan_row_steps_masked") \
                         or k.startswith(("scans_", "chain_breaks_")):
                     out[k] = out.get(k, 0) + int(s[k] or 0)
+                elif k.startswith("longest_wait_s_"):
+                    # streams by the round that ended their longest wait
+                    # (longest_wait_<cause>), and those waits' seconds
+                    out[k] = round(out.get(k, 0.0) + float(s[k] or 0), 6)
+                elif k.startswith("longest_wait_"):
+                    out[k] = out.get(k, 0) + int(s[k] or 0)
             # the routed expert layers' counters (MoE engines only)
             # and a latent-attention engine's scan counters (mla_*)
             for k in es:
@@ -645,7 +651,8 @@ class Worker:
                 except Exception:  # noqa: BLE001 — never break the beat
                     ds = None
                 if ds and (ds.get("recent_ms") or ds.get("new_errors")
-                           or ds.get("hedge_cancels")):
+                           or ds.get("hedge_cancels")
+                           or ds.get("sse_events")):
                     engine_stats["direct"] = ds
             summary = self._prefix_summary_payload()
             if summary is not None:

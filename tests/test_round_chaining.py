@@ -485,7 +485,8 @@ def test_the_loop_hands_retune_the_exposed_host_time(
     such rounds leave the step's figure alone)."""
     clock = [100.0]
     monkeypatch.setattr(batcher_mod, "time", types.SimpleNamespace(
-        perf_counter=lambda: clock[0], time=lambda: clock[0]))
+        perf_counter=lambda: clock[0], time=lambda: clock[0],
+        monotonic=lambda: clock[0]))
     eng = _Chip(clock)
     b = ContinuousBatcher(eng, BatcherConfig(adaptive=False, multi_step=1))
 
