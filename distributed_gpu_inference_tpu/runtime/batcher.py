@@ -425,7 +425,10 @@ class ContinuousBatcher:
             "scans_chained": 0,
             **{f"chain_breaks_{why}": 0 for why in _CHAIN_BREAKS},
             "round_host_exposed_s": 0.0,
-            "ragged_admissions": 0, "ragged_rounds": 0,
+            # fresh admissions, and those of them bound while the scan
+            # before their round was still unread on the device
+            "ragged_admissions": 0, "admissions_ahead": 0,
+            "ragged_rounds": 0,
             "budgeted_rounds": 0, "budget_skipped_admissions": 0,
             "spec_waves": 0, "spec_completed": 0, "spec_errors": 0,
             "preemptions": 0, "resumes": 0, "preemption_block_pressure": 0,
@@ -883,7 +886,7 @@ class ContinuousBatcher:
             ordered.extend(sorted(members, key=lambda it: it.sort_key))
         return resumes + ordered
 
-    async def _admit(self) -> int:
+    async def _admit(self, ahead: bool = False) -> int:
         """Admit queued requests into free slots. Heap mutation and future
         resolution happen HERE on the event-loop thread (asyncio futures and
         the heap are not thread-safe); only the engine call itself runs on the
@@ -893,7 +896,14 @@ class ContinuousBatcher:
         (``engine.submit_chunked_start``) and runs no prefill: its prompt
         rides the next ragged round(s) as chunk rows co-dispatched with the
         active decodes — admission IS "append rows to the next round";
-        several may be in flight at once, short or long alike."""
+        several may be in flight at once, short or long alike.
+
+        ``ahead``: a scan is unread on the device and this pass runs before
+        its read (``_admission_pass``). The engine binds a free slot beside
+        that scan; what needs the scan read — a resume, or a prompt the pool
+        cannot hold until the read gives blocks back — stops the pass with
+        the item back in the queue, and the pass after the read takes it up
+        in the order and with the pool it would have had."""
         admitted = 0
         if self._resume_hold:
             # the round after a preemption belongs to the FROZEN slots:
@@ -942,6 +952,9 @@ class ContinuousBatcher:
                 continue  # already handled
             if item.future.cancelled():
                 continue
+            if ahead and item.preempted is not None:
+                requeue.append(item)
+                break
             if item.preempted is not None:
                 # resume a preempted sequence: head-of-line, restores
                 # cached/spilled pages through the normal allocate+prefill
@@ -1012,6 +1025,9 @@ class ContinuousBatcher:
                     item.request,
                 )
             except OutOfBlocksError:
+                if ahead:
+                    requeue.append(item)
+                    break
                 _defer(item)
                 continue
             except Exception as e:
@@ -1033,6 +1049,10 @@ class ContinuousBatcher:
             free.pop(0)
             self._ragged.append((adm, item))
             self.stats["ragged_admissions"] += 1
+            # (the engine reads the scan first where its start cannot run
+            # beside it: that admission did not run ahead)
+            self.stats["admissions_ahead"] += \
+                ahead and self.engine.scan_unread
             self._note(item, "batcher.admitted", slot=adm.slot,
                        mode="ragged", round=self._round + 1,
                        tokens=len(item.request.prompt_token_ids or []))
@@ -1828,6 +1848,11 @@ class ContinuousBatcher:
                 # engine then); if not, the scan is read and delivered
                 # first and the loop is the one it always was
                 why = self._chain_break()
+                if why == "admission" and self.spec is None:
+                    # a request waits, a slot is free and nothing else wants
+                    # the engine: the pass that would follow the read runs
+                    # now, while that scan still runs on the device
+                    await self._admission_pass(ahead=True)
                 if why is not None:
                     try:
                         await self._deliver(await loop.run_in_executor(
@@ -1854,27 +1879,7 @@ class ContinuousBatcher:
             while time.time() < latch_until and \
                     len(self._heap) < len(self.engine.slots):
                 await asyncio.sleep(0.001)
-            with flight.span("dgi.batcher.admit", self.stats, "admit_s",
-                             queue_depth=len(self._heap)):
-                # cancel/interrupt events land at this quiescent boundary:
-                # aborted requests release their slots BEFORE admission so
-                # the freed capacity admits waiting work this very pass
-                await self._scan_signals()
-                # hopeless deadline work drops at the same boundary, so its
-                # freed blocks admit waiting on-time work this very pass
-                await self._scan_deadlines()
-                # low-depth all-greedy load routes through the spec tree
-                # BEFORE paged admission claims it; requests arriving
-                # mid-wave admit to paged slots below and the two interleave
-                # round for round
-                await self._maybe_start_spec_wave()
-                await self._admit()
-                # admission-sourced KV pressure: deferred requests wait, or
-                # a higher-priority arrival preempts the lowest-priority
-                # victim
-                await self._check_pressure()
-                # one bounded fused dispatch of the in-flight spec wave
-                await self._step_spec_wave()
+            await self._admission_pass()
             if not self._slot_items and not self._ragged:
                 # no batcher-owned slot decodes: no frozen slot of OURS is
                 # waiting on freed blocks, so resumes may flow immediately
@@ -1897,6 +1902,39 @@ class ContinuousBatcher:
             except Exception as e:
                 await self._fail_in_flight(e)
 
+    async def _admission_pass(self, ahead: bool = False) -> None:
+        """The loop's work at a round's boundary before the next round goes
+        out. ``ahead``: ``_chain_break`` said ``admission`` of an unread
+        scan, and the pass runs before that scan's read instead of after it
+        — the same pass in the same order, and the one after the read stays
+        for what this one left (``_admit``). Its seconds are no cost of the
+        scan it ran beside (``_engine_round``), whose read counts them in
+        its gap: they are taken out here."""
+        t0 = time.perf_counter()
+        with flight.span("dgi.batcher.admit", self.stats, "admit_s",
+                         queue_depth=len(self._heap), ahead=int(ahead)):
+            # cancel/interrupt events land at this quiescent boundary:
+            # aborted requests release their slots BEFORE admission so
+            # the freed capacity admits waiting work this very pass
+            await self._scan_signals()
+            # hopeless deadline work drops at the same boundary, so its
+            # freed blocks admit waiting on-time work this very pass
+            await self._scan_deadlines()
+            # low-depth all-greedy load routes through the spec tree
+            # BEFORE paged admission claims it; requests arriving
+            # mid-wave admit to paged slots below and the two interleave
+            # round for round
+            await self._maybe_start_spec_wave()
+            await self._admit(ahead)
+            # admission-sourced KV pressure: deferred requests wait, or
+            # a higher-priority arrival preempts the lowest-priority
+            # victim
+            await self._check_pressure()
+            # one bounded fused dispatch of the in-flight spec wave
+            await self._step_spec_wave()
+        if ahead:
+            self._cost_s -= time.perf_counter() - t0
+
     async def _deliver(self, measured: Optional[Tuple[int, float, float]]
                        ) -> None:
         """What the loop does with what a round brought back: steer the
@@ -1912,11 +1950,13 @@ class ContinuousBatcher:
             # admission-chunk rounds on the timeline: one bounded
             # note per in-flight traced admission per round
             # (saturates at the per-request event cap on
-            # pathological prompts)
-            for adm, item in self._ragged:
-                if item.flight is not None:
-                    self._note(item, "batcher.chunk_round",
-                               off=adm.off, round=self._round)
+            # pathological prompts); a scan read back behind an admission
+            # that ran ahead of it carried no chunk
+            if self._ready.kind == "ragged":
+                for adm, item in self._ragged:
+                    if item.flight is not None:
+                        self._note(item, "batcher.chunk_round",
+                                   off=adm.off, round=self._round)
             # ragged admissions whose final chunk sampled its first
             # token this round join the batch (the finished-slot
             # sweep below then resolves any that immediately hit

@@ -2172,8 +2172,10 @@ class TPUEngine:
         """Install slot state (block table, committed length, sampling, stop
         ids) for a sequence already allocated in the manager. Shared by the
         prefill submit path and the PD-handoff adopt path so the two can
-        never drift."""
-        self.collect_scan()
+        never drift. Writes this slot's rows of the host mirrors and makes
+        no device call: beside an unread scan that is safe for a slot the
+        scan does not hold, and only then is the scan left unread."""
+        self._collect_unless_free(slot)
         self.slots[slot] = s
         self._block_tables[slot] = self.manager.block_table_for(
             s.seq_id, self.cfg.max_blocks_per_seq
@@ -2330,13 +2332,23 @@ class TPUEngine:
     ) -> ChunkedAdmission:
         """Begin a chunk-interleaved admission: allocate + bind the slot but
         run NO prefill yet. The slot is marked ``prefilling`` so decode
-        rounds skip it until ``submit_chunked_step`` finishes the prompt."""
-        self.collect_scan()
+        rounds skip it until ``submit_chunked_step`` finishes the prompt.
+
+        The one entry that may run beside an unread scan (``collect_scan``):
+        a slot that is free now is no row of that scan, the blocks the scan
+        writes were reserved when it was built, the prefix lookup and the
+        allocation (which evicts only cached blocks nobody holds) read
+        nothing its commit changes, pool operations queue on ``self.kv``
+        behind it, and the mirrors written are this slot's rows. The pool
+        the allocation finds then lacks what the read gives back, so beside
+        an unread scan ``OutOfBlocksError`` signals no pressure: the caller
+        tries again once the scan is read."""
         if slot is None:
             free = self.free_slots()
             if not free:
                 raise RuntimeError("no free slots")
             slot = free[0]
+        self._collect_unless_free(slot)
         if self.slots[slot] is not None:
             raise RuntimeError(f"slot {slot} busy")
         token_ids = self._validate_request(request)
@@ -2344,9 +2356,14 @@ class TPUEngine:
         try:
             _, cached = self.manager.allocate_sequence(seq_id, token_ids)
         except OutOfBlocksError:
-            self._signal_pressure("admission", requests=1)
+            if self._unread is None:
+                self._signal_pressure("admission", requests=1)
             raise
         try:
+            if self.manager.pending.downloads:
+                # an evicted page's download waits for the device: read the
+                # scan where that wait is counted
+                self.collect_scan()
             self._apply_pending()
             s = _Slot(request=request, seq_id=seq_id,
                       prompt_len=len(token_ids), cached_tokens=cached,
@@ -3386,9 +3403,24 @@ class TPUEngine:
         ``generated`` and ``finish_reason``, the manager's token lists) are
         current. ``{}`` when nothing is unread. Every entry that reads or
         writes slots, pool or mirrors calls it first; the round loop calls
-        it when its next round is not a scan over the same rows."""
+        it when its next round is not a scan over the same rows.
+
+        One exception: ``submit_chunked_start`` binds a slot that is free
+        and outside the unread scan's rows without reading it
+        (``_collect_unless_free``; its docstring says why nothing it
+        touches is stale), so an arrival is admitted while the scan before
+        its round still runs. The engine decides that from what it holds;
+        a slot inside the scan's rows is read first like everything else."""
         scan, self._unread = self._unread, None
         return {} if scan is None else self._collect(scan, sp)
+
+    def _collect_unless_free(self, slot: int) -> None:
+        """``collect_scan`` unless ``slot`` is free and no row of the unread
+        scan: the exception of ``collect_scan``'s rule."""
+        prev = self._unread
+        if prev is not None and (
+                self.slots[slot] is not None or prev.active_mask[slot]):
+            self.collect_scan()
 
     def _plain_decode_multi(self, num_steps: int, sp: flight.span,
                             ahead: bool = False) -> Dict[int, List[int]]:

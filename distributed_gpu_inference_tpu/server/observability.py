@@ -90,6 +90,7 @@ class Metrics:
                 "batcher_scan_step_ms", "batcher_round_host_ms",
                 "batcher_scan_reasons", "batcher_scan_row_steps_masked",
                 "batcher_scans_chained", "batcher_chain_breaks",
+                "batcher_admissions", "batcher_admissions_ahead",
                 "batcher_stream_longest_wait",
                 "batcher_stream_longest_wait_seconds",
                 "direct_sse_events", "direct_token_egress_seconds",
@@ -315,6 +316,17 @@ class Metrics:
             "(cancel, interrupt, deadline, an out-of-band engine call), "
             "pressure (KV pool), idle (no row has a step left)",
             ["worker", "reason"], registry=r)
+        # ahead / admissions is the share of arrivals whose admission cost
+        # the decoding rows nothing
+        self.batcher_admissions = Counter(
+            "batcher_admissions_total",
+            "Fresh requests bound to a slot (their prompts ride the ragged "
+            "rounds that follow)", ["worker"], registry=r)
+        self.batcher_admissions_ahead = Counter(
+            "batcher_admissions_ahead_total",
+            "Those of them bound while the scan before their round was "
+            "still unread on the device (the admission ran beside it)",
+            ["worker"], registry=r)
         # seconds / count by cause is the mean longest wait a cause leaves
         # a stream: what a round with a prompt piece costs a user
         self.batcher_stream_longest_wait = Counter(
@@ -856,6 +868,10 @@ class MetricsCollector:
                 metric = self.metrics.batcher_scans.labels(worker, key[7:])
             elif key == "scans_chained":
                 metric = self.metrics.batcher_scans_chained.labels(worker)
+            elif key == "ragged_admissions":
+                metric = self.metrics.batcher_admissions.labels(worker)
+            elif key == "admissions_ahead":
+                metric = self.metrics.batcher_admissions_ahead.labels(worker)
             elif key.startswith("chain_breaks_"):
                 metric = self.metrics.batcher_chain_breaks.labels(
                     worker, key[13:])

@@ -507,9 +507,11 @@ class Worker:
             # scans by level (scans_t<T>) and by why they got their length
             # (scans_<reason>), the row-steps they ran past a row's end,
             # the scans dispatched behind an unread one (scans_chained)
-            # and why the others were read first (chain_breaks_<reason>)
+            # and why the others were read first (chain_breaks_<reason>);
+            # fresh admissions and those that ran beside an unread scan
             for k in s:
-                if k in ("between_rounds", "scan_row_steps_masked") \
+                if k in ("between_rounds", "scan_row_steps_masked",
+                         "ragged_admissions", "admissions_ahead") \
                         or k.startswith(("scans_", "chain_breaks_")):
                     out[k] = out.get(k, 0) + int(s[k] or 0)
                 elif k.startswith("longest_wait_s_"):

@@ -660,3 +660,374 @@ def test_the_benchmarks_reader_of_the_chained_share(c0, c1, want):
     spec.loader.exec_module(reader)
     assert reader.read({"win": {"c0": {"batcher": c0},
                                 "c1": {"batcher": c1}}}) == want
+
+
+# --------------------------------------------------------------------- #
+# (9) admission ahead of the read (PR 40): where a request waits, a slot
+#     is free and nothing else wants the engine, the admission pass runs
+#     BEFORE the unread scan is read, while that scan runs on the device
+# --------------------------------------------------------------------- #
+
+def _serve_arriving(engine, first, later, steps=1, ahead=True, spec=None,
+                    before=None, **cfg):
+    """``first`` at once, then ``later`` one at a time, each two chained
+    scans after the one before it was admitted, so that it meets a scan
+    in flight; returns (responses, stats, batcher). ``ahead=False`` is the
+    parent's order: the pass before the read does not run, everything is
+    admitted after it. ``before(b, i)`` runs in the loop step that sends
+    arrival ``i``."""
+    async def go():
+        b = ContinuousBatcher(engine, BatcherConfig(
+            max_wait_ms=1, adaptive=False, multi_step=steps,
+            max_multi_step=max(steps, 4), **cfg), spec=spec)
+        if not ahead:
+            after_the_read = b._admission_pass
+
+            async def only_after_the_read(ahead=False):
+                if not ahead:
+                    await after_the_read()
+            b._admission_pass = only_after_the_read
+        b.start()
+        work = [asyncio.ensure_future(b.submit(r)) for r in first]
+        await asyncio.sleep(0)          # they are in the queue now
+
+        async def arrive():
+            for i, r in enumerate(later):
+                admitted = b.stats["ragged_admissions"]
+                chained = b.stats["scans_chained"]
+                while b.stats["scans_chained"] < chained + 2 \
+                        and (b._slot_items or b._ragged or b._heap):
+                    await asyncio.sleep(0.0005)
+                if before is not None:
+                    before(b, i)
+                work.append(asyncio.ensure_future(b.submit(r)))
+                while b.stats["ragged_admissions"] == admitted \
+                        and not work[-1].done():
+                    await asyncio.sleep(0.0005)
+        await arrive()
+        out = await asyncio.gather(*work, return_exceptions=True)
+        stats = b.get_stats()
+        await b.stop()
+        return out, stats, b
+
+    return asyncio.run(go())
+
+
+def _long(temp=0.0):
+    return [_req(PROMPTS[0], 100, temp, seed=3),
+            _req(PROMPTS[1], 80, temp, seed=4)]
+
+
+def _arrivals(temp=0.0):
+    return [_req(PROMPTS[2], 7, temp, seed=11),
+            _req(list(range(60, 95)), 5, temp, seed=12),
+            _req(list(range(20, 31)), 9, temp, seed=13)]
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("steps", [1, 4])
+@pytest.mark.parametrize("model", ["dense", "olmoe"])
+def test_streams_with_admissions_ahead_are_the_same_bytes(
+        engines, model, steps, temp):
+    eng = engines[model]
+    want, base, _ = _serve_arriving(eng, _long(temp), _arrivals(temp), steps,
+                                    ahead=False)
+    got, stats, _ = _serve_arriving(eng, _long(temp), _arrivals(temp), steps)
+    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+    assert [r.finish_reason for r in got] == [r.finish_reason for r in want]
+    assert [len(r.token_ids) for r in got] == [100, 80, 7, 5, 9]
+    # the same admissions either way; only their place moved
+    assert stats["ragged_admissions"] == base["ragged_admissions"] == 5
+    assert base["admissions_ahead"] == 0
+    assert 1 <= stats["admissions_ahead"] <= 3
+    assert stats["chain_breaks_admission"] >= stats["admissions_ahead"]
+    assert _scans(stats) == stats["scans_chained"] + _breaks(stats)
+    assert eng.manager.get_stats()["free_blocks"] == eng.num_blocks - 1
+    assert eng.free_slots() == list(range(len(eng.slots)))
+
+
+def test_an_admission_ahead_binds_no_slot_of_the_unread_scan(engines):
+    eng = engines["dense"]
+    real, seen = eng.submit_chunked_start, []
+
+    def start(request, slot=None):
+        prev = eng._unread
+        adm = real(request, slot)
+        # left unread: the start ran beside the scan
+        if prev is not None and eng._unread is prev:
+            seen.append((adm.slot, prev.active_mask.copy(),
+                         eng._core_dirty, eng.slots[adm.slot].prefilling))
+        return adm
+
+    eng.submit_chunked_start = start
+    try:
+        got, stats, _ = _serve_arriving(eng, _long(), _arrivals())
+    finally:
+        del eng.submit_chunked_start
+    assert all(r.ok for r in got)
+    assert len(seen) == stats["admissions_ahead"] >= 1
+    for slot, mask, dirty, prefilling in seen:
+        assert not mask[slot] and mask.any()
+        # no scan goes out on the stale core, and no scan runs the new row
+        assert dirty and prefilling
+
+
+def test_a_slot_that_comes_free_inside_the_unread_scan_is_admitted_after(
+        engines):
+    """Every slot is taken: the fifth request's slot comes free only when
+    the scan that ends a row is read (``row_end_waiting`` / ``row_end``),
+    so it is admitted after that read and ``admissions_ahead`` stands."""
+    eng = engines["dense"]
+    reqs = [_req(range(5 + 9 * i, 17 + 9 * i), 12 + 4 * i) for i in range(4)]
+    got, stats = _serve(eng, reqs + [_req(PROMPTS[2], 6)], 1)
+    assert [len(r.token_ids) for r in got] == [12, 16, 20, 24, 6]
+    assert stats["ragged_admissions"] == 5 and stats["admissions_ahead"] == 0
+    assert stats["chain_breaks_row_end_waiting"] \
+        + stats["chain_breaks_row_end"] >= 1
+    assert stats["chain_breaks_admission"] == 0
+
+
+def _tight_pool():
+    # 16 tokens a block, pad + 7: two rows of 14-token prompts hold two
+    # blocks each once they decode, and a 60-token prompt needs four
+    return _engine("llama3-tiny", max_batch_size=3, num_blocks=8,
+                   max_seq_len=64, multi_step=1)
+
+
+def test_out_of_blocks_ahead_defers_and_the_parents_victim_is_preempted():
+    def reqs():
+        return ([_req(range(3, 17), 40, priority=0),
+                 _req(range(50, 64), 40, priority=1)],
+                [_req(range(100, 160), 3, priority=5)])
+
+    def run(ahead):
+        eng = _tight_pool()
+        real_start, real_preempt = eng.submit_chunked_start, eng.preempt_slot
+        refused, victims = [], []
+
+        def start(request, slot=None):
+            prev = eng._unread
+            try:
+                return real_start(request, slot)
+            except Exception as e:
+                refused.append((type(e).__name__, prev is not None
+                                and eng._unread is prev,
+                                eng.pressure_pending))
+                raise
+
+        def preempt(slot):
+            victims.append(list(eng.slots[slot].request.prompt_token_ids))
+            return real_preempt(slot)
+
+        eng.submit_chunked_start, eng.preempt_slot = start, preempt
+        # (the policy resumes its victim ahead of the arrival it was
+        # preempted for, round after round, until a row ends: on the
+        # parent too; the cap is raised so that nobody is dropped for it)
+        got, stats, b = _serve_arriving(eng, *reqs(), ahead=ahead,
+                                        max_preemptions=100)
+        assert all(r.ok for r in got), [r.error for r in got]
+        # no block and no slot leaked, nothing left in the queue
+        assert eng.manager.get_stats()["free_blocks"] == eng.num_blocks - 1
+        assert eng.free_slots() == [0, 1, 2] and not b._heap
+        return got, stats, refused, victims
+
+    want, base, base_refused, base_victims = run(ahead=False)
+    got, stats, refused, victims = run(ahead=True)
+    # the pool could not hold the prompt beside the unread scan: no
+    # pressure signalled there, the item back in the queue, and the pass
+    # after the read met the pool the parent's met
+    assert ("OutOfBlocksError", True, False) in refused
+    assert ("OutOfBlocksError", False, True) in refused
+    assert all(not beside for _, beside, _ in base_refused)
+    assert stats["admissions_ahead"] == 0 == base["admissions_ahead"]
+    # (how often the policy repeats itself hangs on the step at which the
+    # arrival came, which no two runs share: the victim does not)
+    assert victims[0] == base_victims[0] == list(range(3, 17))
+    assert stats["preemptions"] >= 1 and base["preemptions"] >= 1
+    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+    assert [len(r.token_ids) for r in got] == [40, 40, 3]
+
+
+def test_a_request_cancelled_in_the_queue_is_not_admitted_ahead(engines):
+    eng = engines["dense"]
+
+    async def go():
+        b = ContinuousBatcher(eng, BatcherConfig(
+            max_wait_ms=1, adaptive=False, multi_step=1, max_multi_step=4))
+        b.start()
+        long = asyncio.ensure_future(b.submit(_req(PROMPTS[0], 60)))
+        await _mid_chain(b)
+        gone = asyncio.ensure_future(b.submit(_req(PROMPTS[2], 5)))
+        await asyncio.sleep(0)          # it is in the queue now
+        assert len(b._heap) == 1 and eng.scan_unread
+        gone.cancel()
+        kept = await b.submit(_req(PROMPTS[1], 4))
+        resp = await long
+        stats = b.get_stats()
+        await b.stop()
+        return gone, kept, resp, stats
+
+    gone, kept, resp, stats = asyncio.run(go())
+    assert gone.cancelled() and len(kept.token_ids) == 4
+    assert len(resp.token_ids) == 60
+    # the long one and the one that stayed: the cancelled one never bound
+    assert stats["ragged_admissions"] == 2 and eng.stats["requests"] >= 2
+    assert stats["admissions_ahead"] <= 1
+    assert eng.free_slots() == list(range(len(eng.slots)))
+
+
+@pytest.mark.parametrize("what", ["signal", "resume_hold", "foreign",
+                                  "speculative_engine", "speculative_route"])
+def test_anything_else_pending_keeps_the_old_order(engines, what):
+    """``_chain_break`` answers a cancel, a resume hold or a foreign call
+    before it looks at the queue, a speculative engine leaves no scan
+    unread and a speculative route may take the request: the arrival is
+    admitted after the read, as it always was."""
+    eng = engines["dense"]
+    cancel = threading.Event()
+    first = [_req(PROMPTS[0], 60), _req(PROMPTS[1], 50)]
+    spec, tasks = None, []
+
+    def before(b, i):
+        if what == "signal":
+            # the second row's cancel lands with the arrival
+            next(it for it in b._slot_items.values()
+                 if it.request is first[1]).cancel = cancel
+            cancel.set()
+        elif what == "resume_hold":
+            # held over the round in flight, as after a preemption, until
+            # the loop has answered it
+            real = b._check_pressure
+
+            async def held(after_round=False):
+                await real(after_round)
+                b._resume_hold = b.stats["chain_breaks_pressure"] == 0
+            b._resume_hold, b._check_pressure = True, held
+        elif what == "foreign":
+            # a caller waits for the engine thread until the arrival is in
+            async def release():
+                while b.stats["ragged_admissions"] < 3:
+                    await asyncio.sleep(0.0005)
+                b._foreign -= 1
+            b._foreign += 1
+            tasks.append(asyncio.ensure_future(release()))
+
+    if what == "speculative_engine":
+        eng.supports_scan_ahead = False     # what cfg.speculative sets
+    elif what == "speculative_route":
+        spec = types.SimpleNamespace(max_batch_size=0, get_stats=dict)
+    try:
+        got, stats, _ = _serve_arriving(
+            eng, first, [_req(PROMPTS[2], 6)], before=before, spec=spec)
+    finally:
+        eng.supports_scan_ahead = True
+    assert len(got[0].token_ids) == 60 and len(got[2].token_ids) == 6
+    assert (got[1].finish_reason == "abort") == (what == "signal")
+    assert stats["ragged_admissions"] == 3 and stats["admissions_ahead"] == 0
+    if what == "speculative_engine":
+        assert stats["scans_chained"] == 0
+    else:
+        assert stats["scans_chained"] > 0
+    assert eng.free_slots() == list(range(len(eng.slots)))
+
+
+# the engine's side: the rule's one exception, decided from what it holds
+
+def test_a_start_on_a_free_slot_leaves_the_scan_unread(engines):
+    eng = engines["dense"]
+    free = eng.manager.get_stats()["free_blocks"]
+    want = eng.generate([_req(PROMPTS[0], 12), _req(PROMPTS[2], 5)])
+    row = eng.submit(_req(PROMPTS[0], 12))
+    assert eng.decode_multi(4, ahead=True) == {} and eng.scan_unread
+    adm = eng.submit_chunked_start(_req(PROMPTS[2], 5))
+    assert eng.scan_unread and adm.slot != row
+    assert eng.slots[adm.slot].prefilling and eng._core_dirty
+    # the new slot is no row of the scan, of its read or of the next one
+    assert eng.decode_budgets()[adm.slot] == 0
+    assert list(eng.collect_scan()) == [row]
+    eng.ragged_round([adm])
+    while eng.slots[row].finish_reason is None \
+            or eng.slots[adm.slot].finish_reason is None:
+        eng.decode_multi(4)
+    assert eng.finish_slot(row).token_ids == want[0].token_ids
+    assert eng.finish_slot(adm.slot).token_ids == want[1].token_ids
+    assert eng.manager.get_stats()["free_blocks"] == free
+
+
+def test_a_start_on_a_slot_of_the_unread_scan_reads_first(engines):
+    eng = engines["dense"]
+    row = eng.submit(_req(PROMPTS[0], 12))
+    before = len(eng.slots[row].generated)
+    assert eng.decode_multi(4, ahead=True) == {} and eng.scan_unread
+    with pytest.raises(RuntimeError, match="busy"):
+        eng.submit_chunked_start(_req(PROMPTS[2], 5), slot=row)
+    # read before anything else was looked at: the mirrors are current
+    assert not eng.scan_unread
+    assert len(eng.slots[row].generated) == before + 4
+    # ... and every other entry reads first, a free slot or not
+    assert eng.decode_multi(4, ahead=True) == {} and eng.scan_unread
+    other = eng.submit(_req(PROMPTS[2], 5))
+    assert not eng.scan_unread and other != row
+    eng.finish_slot(other)
+    eng.finish_slot(row)
+
+
+def test_out_of_blocks_beside_an_unread_scan_signals_no_pressure():
+    eng = _tight_pool()
+    free = eng.manager.get_stats()["free_blocks"]
+    a = eng.submit(_req(range(3, 17), 40))
+    b = eng.submit(_req(range(50, 64), 40))
+    eng.decode_multi(4)                      # both rows in their second block
+    assert eng.decode_multi(1, ahead=True) == {} and eng.scan_unread
+    held = eng.manager.get_stats()["free_blocks"]
+    with pytest.raises(batcher_mod.OutOfBlocksError):
+        eng.submit_chunked_start(_req(range(100, 160), 3))
+    # nothing signalled, nothing bound, nothing taken, the scan still out
+    assert eng.scan_unread and not eng.pressure_pending
+    assert eng.free_slots() == [2]
+    assert eng.manager.get_stats()["free_blocks"] == held
+    eng.collect_scan()
+    # with the scan read the same refusal is the pool's word: pressure
+    with pytest.raises(batcher_mod.OutOfBlocksError):
+        eng.submit_chunked_start(_req(range(100, 160), 3))
+    assert eng.take_pressure().source == "admission"
+    eng.finish_slot(a)
+    eng.finish_slot(b)
+    assert eng.manager.get_stats()["free_blocks"] == free
+
+
+def test_admissions_ahead_reach_get_stats_and_the_planes_metrics(engines):
+    from distributed_gpu_inference_tpu.runtime.batcher import BatcherServing
+    from distributed_gpu_inference_tpu.server.observability import (
+        MetricsCollector,
+    )
+    from distributed_gpu_inference_tpu.worker.main import Worker
+
+    serving = BatcherServing(engines["dense"], BatcherConfig())
+    try:
+        stats = serving.get_stats()
+    finally:
+        serving.stop()
+    assert stats["admissions_ahead"] == 0 == stats["ragged_admissions"]
+
+    class Eng:
+        engine = None
+
+        def serving_stats(self):
+            return {"decode_rounds": 12, "ragged_admissions": 5,
+                    "admissions_ahead": 3}
+
+    worker = Worker.__new__(Worker)
+    worker.engines = {"a": Eng(), "b": Eng()}
+    worker.serving_capacity = lambda: 8
+    sent = worker._batcher_stats()
+    assert sent["ragged_admissions"] == 10 and sent["admissions_ahead"] == 6
+    mc = MetricsCollector()
+    mc.record_batcher_engine("w1", sent)
+    mc.record_batcher_engine("w1", dict(sent, ragged_admissions=14,
+                                        admissions_ahead=9))
+    text = mc.metrics.render().decode()
+    if "batcher_admissions_total" not in text:
+        pytest.skip("prometheus_client is absent: the metrics are no-ops")
+    assert 'batcher_admissions_total{worker="w1"} 14.0' in text
+    assert 'batcher_admissions_ahead_total{worker="w1"} 9.0' in text

@@ -345,6 +345,29 @@ def test_xplane_holds_the_loop_spans_on_another_thread(served):
     assert sum(s["finished"] for s in deliver) == 6
 
 
+def test_xplane_admit_span_says_whether_it_ran_ahead_of_the_read(served):
+    """PR 40: the pass that admits an arrival while the scan before its
+    round is still unread carries ``ahead`` 1 and lies before that scan's
+    read (the ``collect`` round, reason ``admission``) on the clock."""
+    spans, b = served["spans"], served["batcher"]
+    admit = sorted((s for s in spans if s["name"] == "dgi.batcher.admit"),
+                   key=lambda s: s["a"])
+    assert all(s["ahead"] in (0, 1) for s in admit)
+    ahead = [s for s in admit if s["ahead"]]
+    # the sixth request came while two rows decoded in chained scans
+    assert 1 <= b["admissions_ahead"] <= len(ahead)
+    assert b["admissions_ahead"] <= b["ragged_admissions"] == 6
+    reads = sorted((s for s in spans if s["name"] == "dgi.batcher.round"
+                    and s["kind"] == "collect"
+                    and s["reason"] == "admission"), key=lambda s: s["a"])
+    assert len(reads) == len(ahead) == b["chain_breaks_admission"]
+    for s, read in zip(ahead, reads):
+        assert s["b"] <= read["a"]
+        # ... and the pass after the read is the next one, not ahead
+        after = next(x for x in admit if x["a"] >= read["b"])
+        assert not after["ahead"]
+
+
 # --------------------------------------------------------------------- #
 # the operator's route: heartbeat payload -> /metrics
 # --------------------------------------------------------------------- #
