@@ -43,8 +43,10 @@ class ModelConfig:
     qk_norm: bool = False
     # Latent attention (MLA): the cache holds one ``kv_lora_rank`` latent and
     # ``qk_rope_head_dim`` rope values a token a layer instead of per-head K
-    # and V (models/mla.py). 0 = the K/V layout. ``head_dim`` is then the
-    # width of a query/key head, nope + rope.
+    # and V (models/mla.py). 0 = the K/V layout. No layer of such a model
+    # reads ``head_dim`` (a query/key head is ``qk_head_dim``, nope + rope,
+    # its default here): it may carry that, or the ``hidden_size //
+    # num_heads`` a published config states, and nothing else.
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -66,6 +68,22 @@ class ModelConfig:
     held_experts: Optional[Tuple[int, int]] = None
     # RMSNorm after each sub-block as well as before it (four a layer)
     sandwich_norm: bool = False
+    # a hybrid of linear and latent attention: the 1-based layers whose
+    # attention is latent (MLA), as published; every other layer is a gated
+    # delta-rule (KDA) layer of ``kda_num_heads`` heads of ``kda_head_dim``
+    # behind a causal depthwise convolution of ``kda_conv_kernel`` taps,
+    # whose past is a fixed-size state a sequence (models/kda.py). Empty:
+    # every layer is latent.
+    full_attn_layers: Tuple[int, ...] = ()
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 0
+    # the latent layers rotate nothing: the "rope" dims of q and the shared
+    # key are used as they are
+    mla_use_nope: bool = False
+    # the router picks the top-k of ``score + bias`` (one learned value an
+    # expert); the weights are the scores', the bias is not in them
+    router_selection_bias: bool = False
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
@@ -90,13 +108,48 @@ class ModelConfig:
             only_latent = [name for name, default in (
                 ("first_k_dense", 0), ("n_shared_experts", 0),
                 ("routed_scaling_factor", 1.0), ("held_experts", None),
-                ("sandwich_norm", False),
+                ("sandwich_norm", False), ("full_attn_layers", ()),
+                ("kda_num_heads", 0), ("kda_head_dim", 0),
+                ("kda_conv_kernel", 0), ("mla_use_nope", False),
+                ("router_selection_bias", False),
             ) if getattr(self, name) != default]
             if only_latent:
                 raise ValueError(
                     f"{self.name}: {', '.join(only_latent)} without "
                     "kv_lora_rank: only the latent-attention model "
                     "(models/mla.py) reads them")
+        elif self.head_dim not in (self.qk_head_dim,
+                                   self.hidden_size // self.num_heads):
+            raise ValueError(
+                f"{self.name}: head_dim {self.head_dim} on a latent-attention "
+                f"model, whose query/key head is {self.qk_head_dim} wide "
+                "(qk_nope_head_dim + qk_rope_head_dim): no layer reads it, "
+                "so it carries that or the published hidden_size // "
+                "num_heads")
+        kda = (self.kda_num_heads, self.kda_head_dim, self.kda_conv_kernel)
+        if self.full_attn_layers:
+            if not all(kda) or self.kda_conv_kernel < 2:
+                raise ValueError(
+                    f"{self.name}: full_attn_layers leaves linear-attention "
+                    "layers, which need kda_num_heads, kda_head_dim and "
+                    "kda_conv_kernel (>= 2)")
+            at = tuple(self.full_attn_layers)
+            if list(at) != sorted(set(at)) or at[0] < 1 \
+                    or at[-1] > self.num_layers:
+                raise ValueError(
+                    f"{self.name}: full_attn_layers {at} is not a rising "
+                    f"list of layers 1..{self.num_layers}")
+        elif any(kda):
+            raise ValueError(
+                f"{self.name}: kda_* sizes without full_attn_layers: no "
+                "layer would read them")
+        if self.router_selection_bias and not self.num_experts:
+            raise ValueError(
+                f"{self.name}: router_selection_bias without experts")
+        if self.sandwich_norm and self.full_attn_layers:
+            raise ValueError(
+                f"{self.name}: sandwich norms around a linear-attention "
+                "layer are not built")
         if self.held_experts is not None:
             first, count = self.held_experts
             if first < 0 or count < 1 or first + count > self.num_experts:
@@ -108,6 +161,40 @@ class ModelConfig:
     @property
     def latent_kv(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """``"kda"`` or ``"mla"`` for each layer of a latent-attention
+        model, in layer order."""
+        if not self.full_attn_layers:
+            return ("mla",) * self.num_layers
+        full = set(self.full_attn_layers)
+        return tuple("mla" if li + 1 in full else "kda"
+                     for li in range(self.num_layers))
+
+    @property
+    def num_kda_layers(self) -> int:
+        """Layers whose past is a state row, not pages."""
+        return self.layer_kinds.count("kda") if self.full_attn_layers else 0
+
+    @property
+    def num_cache_layers(self) -> int:
+        """Layers that write pages: the paged pool's layer axis."""
+        return self.num_layers - self.num_kda_layers
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a latent layer's query / key head, nope + rope (where
+        ``head_dim`` carries a published value no layer reads)."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def state_bytes_per_row(self, conv_bytes: int = 2) -> int:
+        """Bytes of one sequence's state over all KDA layers: a float32
+        ``head_dim x head_dim`` matrix a head and the convolution's tail."""
+        p = self.kda_num_heads * self.kda_head_dim
+        return self.num_kda_layers * (
+            4 * p * self.kda_head_dim
+            + max(self.kda_conv_kernel - 1, 0) * 3 * p * conv_bytes)
 
     @property
     def num_held_experts(self) -> int:
@@ -148,7 +235,7 @@ class ModelConfig:
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
         if self.latent_kv:
-            return self.num_layers * (
+            return self.num_cache_layers * (
                 self.kv_lora_rank + self.qk_rope_head_dim) * dtype_bytes
         return 2 * self.num_layers * self.num_kv_heads * self.head_dim * dtype_bytes
 
@@ -156,20 +243,32 @@ class ModelConfig:
         """Parameters layer ``layer`` of a latent-attention model stores
         here (the held experts only)."""
         h, nh = self.hidden_size, self.num_heads
-        attn = (
-            h * self.q_lora_rank + self.q_lora_rank * nh * self.head_dim
-            + h * (self.kv_lora_rank + self.qk_rope_head_dim)
-            + self.kv_lora_rank * nh
-            * (self.qk_nope_head_dim + self.v_head_dim)
-            + nh * self.v_head_dim * h
-        )
-        norms = (4 if self.sandwich_norm else 2) * h \
-            + self.q_lora_rank + self.kv_lora_rank
+        if self.layer_kinds[layer] == "kda":
+            kh, kd = self.kda_num_heads, self.kda_head_dim
+            p = kh * kd
+            # q, k, v and o; the two low-rank gates; the write strength;
+            # the convolution; A_log and dt_bias
+            attn = 4 * h * p + 2 * (h * kd + kd * p) + h * kh \
+                + 3 * p * self.kda_conv_kernel + kh + p
+            norms = 2 * h + kd
+        else:
+            q = (h * self.q_lora_rank
+                 + self.q_lora_rank * nh * self.qk_head_dim
+                 if self.q_lora_rank else h * nh * self.qk_head_dim)
+            attn = (
+                q + h * (self.kv_lora_rank + self.qk_rope_head_dim)
+                + self.kv_lora_rank * nh
+                * (self.qk_nope_head_dim + self.v_head_dim)
+                + nh * self.v_head_dim * h
+            )
+            norms = (4 if self.sandwich_norm else 2) * h \
+                + self.q_lora_rank + self.kv_lora_rank
         if layer < self.first_k_dense or not self.num_experts:
             mlp = 3 * h * self.intermediate_size
         else:
             mlp = h * self.num_experts + 3 * h * self.moe_intermediate_size * (
-                self.num_held_experts + self.n_shared_experts)
+                self.num_held_experts + self.n_shared_experts) + (
+                self.num_experts if self.router_selection_bias else 0)
         return attn + norms + mlp
 
     def layer_param_bytes(self, dtype_bytes: int = 2) -> int:
@@ -356,6 +455,41 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
         num_experts_per_tok=8, norm_topk_prob=True,
         routed_scaling_factor=2.5,
         held_experts=(0, 16), sandwich_norm=True,
+    ),
+    # Kimi-Linear — three gated delta-rule (KDA) layers to one latent (MLA)
+    # layer that rotates nothing, no query low-rank, a dense first layer,
+    # then routed experts picked by sigmoid score plus a selection bias
+    # beside a shared expert (models/mla.py, models/kda.py). ``head_dim``
+    # carries the published 72 (hidden / heads), which no layer reads: a
+    # latent head is 192 / 128 wide, a KDA head 128.
+    "kimi-linear-tiny": _llama(  # test-scale: a repeated period and both odd ends
+        "kimi-linear-tiny", vocab_size=512, hidden_size=64, num_layers=15,
+        num_heads=4, num_kv_heads=4, intermediate_size=96, head_dim=16,
+        max_position_embeddings=1024, rope_theta=10000.0,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, first_k_dense=1, moe_intermediate_size=32,
+        n_shared_experts=1, num_experts=8, num_experts_per_tok=3,
+        norm_topk_prob=True, routed_scaling_factor=2.446,
+        held_experts=(0, 2), full_attn_layers=(4, 8, 12, 15), kda_num_heads=4,
+        kda_head_dim=16, kda_conv_kernel=4, mla_use_nope=True,
+        router_selection_bias=True,
+    ),
+    # one chip's share of the published model where 8 chips share each
+    # layer: 32 of the 256 routed experts, an eighth of the vocabulary, all
+    # 27 layers; every width as published
+    # (benchmark/configs/kimi-linear-48b-a3b-ep8-int8.json)
+    "kimi-linear-48b-a3b-ep8": _llama(
+        "kimi-linear-48b-a3b-ep8", vocab_size=20480, hidden_size=2304,
+        num_layers=27, num_heads=32, num_kv_heads=32,
+        intermediate_size=9216, head_dim=72, max_position_embeddings=4096,
+        rope_theta=10000.0, rms_norm_eps=1e-5,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, first_k_dense=1, moe_intermediate_size=1024,
+        n_shared_experts=1, num_experts=256, num_experts_per_tok=8,
+        norm_topk_prob=True, routed_scaling_factor=2.446,
+        held_experts=(0, 32), full_attn_layers=(4, 8, 12, 16, 20, 24, 27),
+        kda_num_heads=32, kda_head_dim=128, kda_conv_kernel=4,
+        mla_use_nope=True, router_selection_bias=True,
     ),
 }
 

@@ -112,6 +112,7 @@ def init_kv_pools(
     num_blocks: int,
     block_size: int = 16,
     dtype: Optional[jnp.dtype] = None,
+    state_rows: Optional[int] = None,
 ) -> KVPools:
     """Device-resident paged KV pools. Block 0 is reserved as the garbage/pad
     block — writes for padded tokens land there and reads mask it out.
@@ -127,11 +128,14 @@ def init_kv_pools(
     contract: ``ops.paged_attention_pallas._quantize_token_rows``).
 
     A latent-attention model (``cfg.latent_kv``) has one pool and no head
-    axis instead: ``{"ckv": [L, N, Bk, latent + rope]}`` (models/mla.py)."""
+    axis instead: ``{"ckv": [L, N, Bk, latent + rope]}`` (models/mla.py),
+    and beside it, where some layers are linear attention, the state pool
+    of ``state_rows`` sequences (models/kda.py)."""
     if cfg.latent_kv:
         from distributed_gpu_inference_tpu.models import mla
 
-        return mla.init_kv_pools(cfg, num_blocks, block_size, dtype)
+        return mla.init_kv_pools(cfg, num_blocks, block_size, dtype,
+                                 state_rows)
     dtype = jnp.dtype(dtype or cfg.dtype)
     shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size, cfg.head_dim)
     pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}

@@ -26,6 +26,13 @@ batcher and the cache manager serve this model as they serve the others):
   ``cfg.first_k_dense`` leading layers (dense SwiGLU), ``params["layers"]``
   the rest (router over all ``num_experts``, the ``held_experts`` this chip
   stores, a shared expert). Two scans, one layer body.
+- **A hybrid model interleaves layers that differ in their cache**
+  (``cfg.full_attn_layers``): latent layers as above, without rotation
+  where ``cfg.mla_use_nope``, and gated delta-rule layers
+  (``models/kda.py``) whose past is a row of a state pool that rides the
+  same ``kv`` dict. The latent pool then has a layer for each latent layer
+  only, and the scans run over ``layer_units``: one period of the pattern,
+  repeated, and the odd ends.
 - **The expert layer computes the chip's share**: sigmoid scores over all
   published experts, top-k kept, normalised and scaled as published; pairs that fall on experts held elsewhere are routed nowhere,
   and nothing stands in for them.
@@ -34,6 +41,7 @@ batcher and the cache manager serve this model as they serve the others):
 from __future__ import annotations
 
 import functools
+import math
 import zlib
 from typing import Any, Dict, Optional, Tuple
 
@@ -57,6 +65,8 @@ _NORM_SPREAD = 0.25
 
 
 _LANES = 128
+# leaves kept in float32 whatever the model's dtype (``leaf_specs`` kinds)
+_F32_KINDS = "atb"
 
 
 def latent_width(cfg: ModelConfig) -> int:
@@ -72,38 +82,109 @@ def pool_width(cfg: ModelConfig) -> int:
     return -(-latent_width(cfg) // _LANES) * _LANES
 
 
-def layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, int], ...]:
-    """(params key, layers) of the homogeneous stacks, in layer order."""
+_KDA = "kda_"
+
+
+def group_of(cfg: ModelConfig, layer: int) -> str:
+    """The parameter stack layer ``layer`` (0-based) lies in: by its MLP
+    (``dense_layers`` / ``layers``) and, for a gated delta-rule layer, the
+    prefix ``kda_``."""
     lead = cfg.first_k_dense if cfg.num_experts else 0
-    groups = (("dense_layers", lead), ("layers", cfg.num_layers - lead))
-    return tuple(g for g in groups if g[1])
+    name = "dense_layers" if layer < lead else "layers"
+    return _KDA + name if cfg.layer_kinds[layer] == "kda" else name
+
+
+def layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, int], ...]:
+    """(params key, layers) of the homogeneous stacks: the latent layers'
+    in layer order, then the gated delta-rule layers'."""
+    names = [group_of(cfg, li) for li in range(cfg.num_layers)]
+    order = ("dense_layers", "layers", _KDA + "dense_layers",
+             _KDA + "layers")
+    return tuple((g, names.count(g)) for g in order if g in names)
+
+
+def layer_units(cfg: ModelConfig
+                ) -> Tuple[Tuple[int, Tuple[Tuple[str, int], ...]], ...]:
+    """The forward pass as ``(repeat, runs)`` units in layer order, ``runs``
+    the ``(params key, layers)`` of a unit's homogeneous stretches. A model
+    of one cache is one unit; a hybrid is cut after every latent layer and
+    equal neighbours merge, so that a repeated period is traced once."""
+    names = [group_of(cfg, li) for li in range(cfg.num_layers)]
+    cuts = [li for li in cfg.full_attn_layers if li < cfg.num_layers]
+    units: list = []
+    for lo, hi in zip([0] + cuts, cuts + [cfg.num_layers]):
+        runs: list = []
+        for name in names[lo:hi]:
+            if runs and runs[-1][0] == name:
+                runs[-1] = (name, runs[-1][1] + 1)
+            else:
+                runs.append((name, 1))
+        if units and units[-1][1] == tuple(runs):
+            units[-1] = (units[-1][0] + 1, units[-1][1])
+        else:
+            units.append((1, tuple(runs)))
+    return tuple(units)
 
 
 def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]]:
     """name → (shape of ONE layer, fan-in, kind) for a group's leaves.
     kind: ``q`` a matmul weight (quantized where the engine quantizes),
-    ``d`` a dense bf16 weight, ``n`` a norm vector."""
+    ``d`` a dense bf16 weight, ``n`` a norm vector; float32 vectors drawn
+    as the family draws them: ``a`` (``A_log``), ``t`` (``dt_bias``), ``b``
+    (the router's selection bias)."""
     h, nh = cfg.hidden_size, cfg.num_heads
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
-    spec = {
-        "attn_norm": ((h,), 0, "n"),
-        "wq_a": ((h, rq), h, "q"),
-        "q_a_norm": ((rq,), 0, "n"),
-        "wq_b": ((rq, nh * (dn + dr)), rq, "q"),
-        "wkv_a": ((h, rkv + dr), h, "q"),
-        "kv_a_norm": ((rkv,), 0, "n"),
-        # W_kvb split a head into W_UK and W_UV; bf16: the absorbed products
-        # are batched over heads, not the int8 kernel's shape
-        "w_uk": ((nh, rkv, dn), rkv, "d"),
-        "w_uv": ((nh, rkv, dv), rkv, "d"),
-        "wo": ((nh * dv, h), nh * dv, "q"),
-        "mlp_norm": ((h,), 0, "n"),
-    }
+    if group.startswith(_KDA):
+        kh, kd, taps = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_conv_kernel
+        p = kh * kd
+        spec = {
+            "attn_norm": ((h,), 0, "n"),
+            # q, k and v side by side: one matmul, one tail
+            "wqkv": ((h, 3 * p), h, "q"),
+            "conv": ((taps, 3 * p), taps, "d"),
+            "w_fa": ((h, kd), h, "q"),
+            "w_fb": ((kd, p), kd, "q"),
+            "dt_bias": ((p,), 0, "t"),
+            "a_log": ((kh,), 0, "a"),
+            "w_b": ((h, kh), h, "d"),
+            "w_ga": ((h, kd), h, "q"),
+            "w_gb": ((kd, p), kd, "q"),
+            "o_norm": ((kd,), 0, "n"),
+            "wo": ((p, h), p, "q"),
+            "mlp_norm": ((h,), 0, "n"),
+        }
+    else:
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        spec = {"attn_norm": ((h,), 0, "n")}
+        if rq:
+            spec.update({
+                "wq_a": ((h, rq), h, "q"),
+                "q_a_norm": ((rq,), 0, "n"),
+                "wq_b": ((rq, nh * (dn + dr)), rq, "q"),
+            })
+        else:       # no query low-rank: one projection, no norm
+            spec["wq"] = ((h, nh * (dn + dr)), h, "q")
+        spec.update({
+            "wkv_a": ((h, rkv + dr), h, "q"),
+            "kv_a_norm": ((rkv,), 0, "n"),
+            # W_kvb split a head into W_UK and W_UV; bf16: the absorbed
+            # products are batched over heads, not the int8 kernel's shape
+            "w_uk": ((nh, rkv, dn), rkv, "d"),
+            "w_uv": ((nh, rkv, dv), rkv, "d"),
+            "wo": ((nh * dv, h), nh * dv, "q"),
+            "mlp_norm": ((h,), 0, "n"),
+        })
     if cfg.sandwich_norm:
         spec["post_attn_norm"] = ((h,), 0, "n")
         spec["post_mlp_norm"] = ((h,), 0, "n")
-    if group == "layers" and cfg.num_experts:
+    if group.endswith("dense_layers") or not cfg.num_experts:
+        i = cfg.intermediate_size
+        spec.update({
+            "w_gate": ((h, i), h, "q"),
+            "w_up": ((h, i), h, "q"),
+            "w_down": ((i, h), i, "q"),
+        })
+    else:
         mi, held = cfg.moe_intermediate_size, cfg.num_held_experts
         spec.update({
             "w_router": ((h, cfg.num_experts), h, "d"),
@@ -111,6 +192,8 @@ def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]
             "we_up": ((held, h, mi), h, "q"),
             "we_down": ((held, mi, h), mi, "q"),
         })
+        if cfg.router_selection_bias:
+            spec["router_bias"] = ((cfg.num_experts,), 0, "b")
         if cfg.n_shared_experts:
             ms = mi * cfg.n_shared_experts
             spec.update({
@@ -118,13 +201,6 @@ def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]
                 "ws_up": ((h, ms), h, "q"),
                 "ws_down": ((ms, h), ms, "q"),
             })
-    else:
-        i = cfg.intermediate_size
-        spec.update({
-            "w_gate": ((h, i), h, "q"),
-            "w_up": ((h, i), h, "q"),
-            "w_down": ((i, h), i, "q"),
-        })
     return spec
 
 
@@ -137,7 +213,17 @@ def draw_leaf(key: jax.Array, shape: tuple, fan_in: int, kind: str
               ) -> jax.Array:
     """ONE layer's float32 draw of a leaf: what both the program's init and
     the benchmark's reference (``harness/reference_mla_moe.py``) round."""
+    if kind == "a":     # A_log = log U(1, 16), a head
+        return jnp.log(jax.random.uniform(
+            key, shape, jnp.float32, minval=1.0, maxval=16.0))
+    if kind == "t":     # dt_bias: the inverse softplus of a log-uniform dt
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, minval=math.log(1e-3),
+            maxval=math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
     x = jax.random.normal(key, shape, jnp.float32)
+    if kind == "b":     # moves some selections, so a dropped bias shows
+        return 0.1 * x
     if kind == "n":
         return 1.0 + _NORM_SPREAD * x
     return x * (fan_in ** -0.5)
@@ -164,7 +250,7 @@ def init_params(
             if kind == "q" and mode is not None:
                 q = quantize_weight(w, mode)
                 return carry, (q["qw"], q["scale"])
-            return carry, w.astype(dtype)
+            return carry, w if kind in _F32_KINDS else w.astype(dtype)
 
         return jax.jit(lambda keys: lax.scan(body, 0, keys)[1])
 
@@ -189,15 +275,26 @@ def init_params(
 
 
 def init_kv_pools(cfg: ModelConfig, num_blocks: int, block_size: int = 16,
-                  dtype: Optional[jnp.dtype] = None) -> Dict[str, jax.Array]:
-    """The latent paged pool ``[L, N, Bk, W]``; block 0 is the pad block.
-    A one-byte float dtype (fp8) stores narrower rows; int8 with scales is
-    not built."""
+                  dtype: Optional[jnp.dtype] = None,
+                  state_rows: Optional[int] = None) -> Dict[str, jax.Array]:
+    """The latent paged pool ``[L, N, Bk, W]``, ``L`` the latent layers;
+    block 0 is the pad block. A one-byte float dtype (fp8) stores narrower
+    rows; int8 with scales is not built. A hybrid model's dict also holds
+    the state pool of ``state_rows`` sequences (``models/kda.py``)."""
     dtype = jnp.dtype(dtype or cfg.dtype)
     if dtype == jnp.int8:
         raise NotImplementedError("int8 latent pools (scaled) are not built")
-    return {POOL: jnp.zeros(
-        (cfg.num_layers, num_blocks, block_size, pool_width(cfg)), dtype)}
+    pools = {POOL: jnp.zeros(
+        (cfg.num_cache_layers, num_blocks, block_size, pool_width(cfg)),
+        dtype)}
+    if cfg.num_kda_layers:
+        from distributed_gpu_inference_tpu.models import kda
+
+        if state_rows is None:
+            raise ValueError(
+                f"{cfg.name}: a state pool needs its number of rows")
+        pools.update(kda.init_state_pools(cfg, state_rows, conv_dtype=dtype))
+    return pools
 
 
 # ---------------------------------------------------------------------------
@@ -240,7 +337,7 @@ def latent_attention_xla(
     k_r = ctx[..., rkv:rkv + cfg.qk_rope_head_dim].astype(f32)
     q_n, q_r = q_n.astype(f32), q_r.astype(f32)
     w_uk, w_uv = w_uk.astype(f32), w_uv.astype(f32)
-    scale = cfg.head_dim ** -0.5
+    scale = cfg.qk_head_dim ** -0.5
     visible = _visible(positions, kv_lens, ctx.shape[1])
     rope_scores = jnp.einsum("bshr,bjr->bhsj", q_r, k_r)
     if form == "expanded":
@@ -277,14 +374,20 @@ def kernels_on(cfg: ModelConfig, padded_ctx: int, pool_dtype,
 # ---------------------------------------------------------------------------
 
 
-def route(cfg: ModelConfig, x: jax.Array, w_router: jax.Array
-          ) -> Tuple[jax.Array, jax.Array]:
+def route(cfg: ModelConfig, x: jax.Array, w_router: jax.Array,
+          bias: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid scores over ALL published experts in float32, the top-k
-    kept (no groups, no selection bias), normalised and scaled → (weights
-    [T, k], experts [T, k])."""
+    kept (no groups), normalised and scaled → (weights [T, k], experts
+    [T, k]). ``bias`` (``cfg.router_selection_bias``) moves which experts
+    are kept and is not in their weights."""
     scores = jax.nn.sigmoid(
         x.astype(jnp.float32) @ w_router.astype(jnp.float32))
-    topv, topi = lax.top_k(scores, cfg.num_experts_per_tok)
+    if bias is None:
+        topv, topi = lax.top_k(scores, cfg.num_experts_per_tok)
+    else:
+        _, topi = lax.top_k(scores + bias.astype(jnp.float32),
+                            cfg.num_experts_per_tok)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
     if cfg.norm_topk_prob:
         topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
     return topv * cfg.routed_scaling_factor, topi
@@ -308,7 +411,9 @@ def _experts(
     t, k = b * s, cfg.num_experts_per_tok
     act = _mlp_act(cfg.activation)
     xf = x.reshape(t, h)
-    topv, topi = route(cfg, xf, lp["w_router"])
+    topv, topi = route(
+        cfg, xf, lp["w_router"],
+        lp["router_bias"] if cfg.router_selection_bias else None)
     first, count = cfg.held_experts or (0, cfg.num_experts)
     local = topi - first
     live = jnp.ones((t,), bool) if live is None else live.reshape(t)
@@ -330,21 +435,108 @@ def _experts(
 # ---------------------------------------------------------------------------
 
 
-def _layer_step(
-    cfg: ModelConfig, block_size: int, carry, lp: Dict[str, Any], *,
-    block_tables, write_positions, kv_lens, cos, sin, stacked, pallas,
-    kernels, unpack, tiles, moe_live, emit_routing, write_plan, pool_offset,
-):
+def _latent_attention(
+    cfg: ModelConfig, block_size: int, x: jax.Array, lp: Dict[str, Any],
+    proj, pool: jax.Array, pool_layer, *, block_tables, write_positions,
+    kv_lens, cos, sin, kernels, unpack, tiles, write_plan,
+) -> Tuple[jax.Array, jax.Array]:
+    """A latent layer's attention over ``x`` (normed) → (``concat(o)``
+    before ``W_o``, the pool with the layer's rows written)."""
     from distributed_gpu_inference_tpu.models.llama import (
-        _mlp, apply_rope, rms_norm,
+        apply_rope, rms_norm,
     )
 
-    hidden, pool, layer_idx = carry
-    b, s, _ = hidden.shape
+    b, s, _ = x.shape
     nh, rkv = cfg.num_heads, cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     eps = cfg.rms_norm_eps
-    pool_layer = layer_idx + pool_offset
+    if cfg.q_lora_rank:
+        c_q = rms_norm(proj(x, "wq_a"), lp["q_a_norm"], eps)
+        q = proj(c_q, "wq_b").reshape(b, s, nh, dn + dr)
+    else:
+        q = proj(x, "wq").reshape(b, s, nh, dn + dr)
+    ckr = proj(x, "wkv_a")
+    c = rms_norm(ckr[..., :rkv], lp["kv_a_norm"], eps)
+    if cfg.mla_use_nope:        # the "rope" dims as they are
+        q_n, q_r, k_r = q[..., :dn], q[..., dn:], ckr[..., rkv:]
+    else:
+        q_n, q_r = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+        k_r = apply_rope(ckr[..., None, rkv:], cos, sin)[..., 0, :]
+    pad = jnp.zeros((b, s, pool.shape[-1] - rkv - dr), c.dtype)
+    new_rows = jnp.concatenate([c, k_r, pad], axis=-1).astype(pool.dtype)
+    positions = write_positions
+    if unpack is not None and not kernels:
+        # the XLA path attends over the [B, S] rectangle; the kernels
+        # take the packed axis as it is (page write) or as query tiles
+        to_rect, tok_row, tok_col = unpack
+
+        def rectangle(t_):
+            return jnp.take(t_[0], to_rect, axis=0, mode="fill",
+                            fill_value=0)
+
+        q_n, q_r, new_rows = (rectangle(q_n), rectangle(q_r),
+                              rectangle(new_rows))
+    if kernels:
+        from distributed_gpu_inference_tpu.ops import (
+            mla_attention_pallas as mla_k,
+        )
+
+        pool = mla_k.write_latent_pages_in_place(
+            new_rows.reshape(-1, new_rows.shape[-1]), pool, pool_layer,
+            write_plan)
+        q_abs = jnp.einsum("bshd,hcd->bshc", q_n, lp["w_uk"],
+                           preferred_element_type=jnp.float32)
+        q_cat = jnp.concatenate(
+            [q_abs.astype(pool.dtype), q_r.astype(pool.dtype),
+             jnp.zeros((*q_r.shape[:3], pool.shape[-1] - rkv - dr),
+                       pool.dtype)], axis=-1)
+        common = dict(scale=cfg.qk_head_dim ** -0.5, latent=rkv)
+        if tiles is not None:
+            u = mla_k.latent_paged_attention_packed(
+                q_cat[0], tiles, pool, pool_layer, block_tables,
+                kv_lens, block_size, **common)[None]
+        else:
+            u = mla_k.latent_paged_attention(
+                q_cat, pool, pool_layer, block_tables, positions,
+                kv_lens, block_size, decode=s == 1, **common)
+        attn = jnp.einsum("bshc,hcd->bshd", u, lp["w_uv"],
+                          preferred_element_type=jnp.float32)
+    else:
+        from distributed_gpu_inference_tpu.models.llama import (
+            _page_scatter_indices,
+        )
+
+        n_blocks = pool.shape[1]
+        phys, slot = _page_scatter_indices(
+            n_blocks, block_tables, positions, block_size)
+        # straight into the stacked pool: no layer slice, no write-back
+        pool = pool.at[pool_layer, phys, slot].set(
+            new_rows.reshape(-1, new_rows.shape[-1]), mode="drop")
+        ctx = pool[pool_layer, block_tables].reshape(
+            block_tables.shape[0], -1, pool.shape[-1])
+        attn = latent_attention_xla(
+            cfg, q_n, q_r, lp["w_uk"], lp["w_uv"], ctx, positions,
+            kv_lens, "absorbed" if positions.shape[1] == 1
+            else "expanded",
+        )
+        if unpack is not None:
+            attn = attn.at[unpack[1], unpack[2]].get(mode="fill",
+                                                     fill_value=0)
+    return attn.astype(x.dtype).reshape(b, s, nh * cfg.v_head_dim), pool
+
+
+def _layer_step(
+    cfg: ModelConfig, block_size: int, hidden: jax.Array,
+    kv: Dict[str, jax.Array], lp: Dict[str, Any], *, linear: bool,
+    layer_idx, cache_layer, stacked, pallas, kernels, kda_kernels,
+    kda_plan, rope_positions, moe_live, emit_routing, **latent,
+):
+    """One layer: ``layer_idx`` its place in its parameter stack,
+    ``cache_layer`` its place in its cache (the latent pool, or for a
+    ``linear`` layer the state pool)."""
+    from distributed_gpu_inference_tpu.models.llama import _mlp, rms_norm
+
+    eps = cfg.rms_norm_eps
 
     def proj(x_, name):
         if stacked is not None and name in stacked:
@@ -353,74 +545,18 @@ def _layer_step(
 
     with jax.named_scope("dgi_attention"):
         x = rms_norm(hidden, lp["attn_norm"], eps)
-        c_q = rms_norm(proj(x, "wq_a"), lp["q_a_norm"], eps)
-        q = proj(c_q, "wq_b").reshape(b, s, nh, dn + dr)
-        q_n, q_r = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
-        ckr = proj(x, "wkv_a")
-        c = rms_norm(ckr[..., :rkv], lp["kv_a_norm"], eps)
-        k_r = apply_rope(ckr[..., None, rkv:], cos, sin)[..., 0, :]
-        pad = jnp.zeros((b, s, pool.shape[-1] - rkv - dr), c.dtype)
-        new_rows = jnp.concatenate([c, k_r, pad], axis=-1).astype(pool.dtype)
-        positions = write_positions
-        if unpack is not None and not kernels:
-            # the XLA path attends over the [B, S] rectangle; the kernels
-            # take the packed axis as it is (page write) or as query tiles
-            to_rect, tok_row, tok_col = unpack
+        if linear:
+            from distributed_gpu_inference_tpu.models import kda
 
-            def rectangle(t_):
-                return jnp.take(t_[0], to_rect, axis=0, mode="fill",
-                                fill_value=0)
-
-            q_n, q_r, new_rows = (rectangle(q_n), rectangle(q_r),
-                                  rectangle(new_rows))
-        if kernels:
-            from distributed_gpu_inference_tpu.ops import (
-                mla_attention_pallas as mla_k,
-            )
-
-            pool = mla_k.write_latent_pages_in_place(
-                new_rows.reshape(-1, new_rows.shape[-1]), pool, pool_layer,
-                write_plan)
-            q_abs = jnp.einsum("bshd,hcd->bshc", q_n, lp["w_uk"],
-                               preferred_element_type=jnp.float32)
-            q_cat = jnp.concatenate(
-                [q_abs.astype(pool.dtype), q_r.astype(pool.dtype),
-                 jnp.zeros((*q_r.shape[:3], pool.shape[-1] - rkv - dr),
-                           pool.dtype)], axis=-1)
-            common = dict(scale=cfg.head_dim ** -0.5, latent=rkv)
-            if tiles is not None:
-                u = mla_k.latent_paged_attention_packed(
-                    q_cat[0], tiles, pool, pool_layer, block_tables,
-                    kv_lens, block_size, **common)[None]
-            else:
-                u = mla_k.latent_paged_attention(
-                    q_cat, pool, pool_layer, block_tables, positions,
-                    kv_lens, block_size, decode=s == 1, **common)
-            attn = jnp.einsum("bshc,hcd->bshd", u, lp["w_uv"],
-                              preferred_element_type=jnp.float32)
+            attn, kv = kda.attention(
+                cfg, x, lp, proj, kv, cache_layer, plan=kda_plan,
+                positions=rope_positions, kernels=kda_kernels)
         else:
-            from distributed_gpu_inference_tpu.models.llama import (
-                _page_scatter_indices,
-            )
-
-            n_blocks = pool.shape[1]
-            phys, slot = _page_scatter_indices(
-                n_blocks, block_tables, positions, block_size)
-            # straight into the stacked pool: no layer slice, no write-back
-            pool = pool.at[pool_layer, phys, slot].set(
-                new_rows.reshape(-1, new_rows.shape[-1]), mode="drop")
-            ctx = pool[pool_layer, block_tables].reshape(
-                block_tables.shape[0], -1, pool.shape[-1])
-            attn = latent_attention_xla(
-                cfg, q_n, q_r, lp["w_uk"], lp["w_uv"], ctx, positions,
-                kv_lens, "absorbed" if positions.shape[1] == 1
-                else "expanded",
-            )
-            if unpack is not None:
-                attn = attn.at[tok_row, tok_col].get(mode="fill",
-                                                     fill_value=0)
-        attn = attn.astype(hidden.dtype)
-        attn = proj(attn.reshape(b, s, nh * dv), "wo").astype(hidden.dtype)
+            attn, pool = _latent_attention(
+                cfg, block_size, x, lp, proj, kv[POOL], cache_layer,
+                kernels=kernels, **latent)
+            kv = {**kv, POOL: pool}
+            attn = proj(attn, "wo").astype(hidden.dtype)
         if "post_attn_norm" in lp:
             attn = rms_norm(attn, lp["post_attn_norm"], eps)
         hidden = hidden + attn
@@ -437,8 +573,7 @@ def _layer_step(
         if "post_mlp_norm" in lp:
             out = rms_norm(out, lp["post_mlp_norm"], eps)
         hidden = hidden + out
-    return (hidden, pool, layer_idx + 1), (
-        stats, routing if emit_routing else None)
+    return hidden, kv, stats, routing if emit_routing else None
 
 
 def forward_chunk(
@@ -450,7 +585,8 @@ def forward_chunk(
     """``models/llama.forward_chunk`` for a latent-attention model (its
     docstring holds the contract of every argument; sequence-parallel
     attention, attention overrides and feature collection are not this
-    model's)."""
+    model's). A hybrid model's state rows are the batch rows: row ``b`` of
+    ``block_tables`` is row ``b`` of the state pool."""
     from distributed_gpu_inference_tpu.models import llama
 
     pool = kv[POOL]
@@ -469,6 +605,25 @@ def forward_chunk(
         rope_positions = positions
     kernels = kernels_on(
         cfg, block_tables.shape[1] * block_size, pool.dtype, pallas)
+    kda_plan, kda_kernels = None, False
+    if cfg.num_kda_layers:
+        from distributed_gpu_inference_tpu.models import kda
+
+        rows = kv[kda.STATE].shape[1]
+        if block_tables.shape[0] != rows:
+            raise ValueError(
+                f"{cfg.name}: {block_tables.shape[0]} batch rows over a "
+                f"state pool of {rows}: a batch row is a state row")
+        kda_kernels = kda.kernels_on(cfg, kv[kda.STATE].dtype, pallas)
+        if packing is not None:
+            kda_plan = kda.make_plan(packing.row, packing.col,
+                                     rope_positions[0], rows)
+        elif positions.shape[1] > 1:
+            b_, s_ = positions.shape
+            kda_plan = kda.make_plan(
+                jnp.repeat(jnp.arange(b_, dtype=jnp.int32), s_),
+                jnp.tile(jnp.arange(s_, dtype=jnp.int32), b_),
+                positions.reshape(-1), rows)
     write_plan = tiles = None
     if kernels:
         from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
@@ -490,30 +645,109 @@ def forward_chunk(
             token_index=to_rect, num_tokens=tp,
         )
     hidden = llama.embed_tokens(params, token_ids, cfg)
-    cos, sin = llama._rope_angles(
-        jnp.maximum(rope_positions, 0), cfg.qk_rope_head_dim, cfg.rope_theta)
+    cos = sin = None
+    if not cfg.mla_use_nope:
+        cos, sin = llama._rope_angles(
+            jnp.maximum(rope_positions, 0), cfg.qk_rope_head_dim,
+            cfg.rope_theta)
 
+    split = {group: _split_group(params[group], pallas)
+             for group, _ in layer_groups(cfg)}
+    step = functools.partial(
+        _layer_step, cfg, block_size,
+        block_tables=block_tables, write_positions=positions,
+        kv_lens=kv_lens, cos=cos, sin=sin, pallas=pallas, kernels=kernels,
+        kda_kernels=kda_kernels, kda_plan=kda_plan,
+        rope_positions=rope_positions, unpack=unpack, tiles=tiles,
+        moe_live=rope_positions >= 0, emit_routing=collect_routing,
+        write_plan=write_plan,
+    )
+    # where each stack and each cache stands, in layers
+    at_w = {group: 0 for group in split}
+    at_c = {False: 0, True: 0}
     moe = None
     routes = []
-    offset = 0
-    for group, n in layer_groups(cfg):
-        scanned, stacked = _split_group(params[group], pallas)
-        step = functools.partial(
-            _layer_step, cfg, block_size,
-            block_tables=block_tables, write_positions=positions,
-            kv_lens=kv_lens, cos=cos, sin=sin, stacked=stacked,
-            pallas=pallas, kernels=kernels, unpack=unpack, tiles=tiles,
-            moe_live=rope_positions >= 0, emit_routing=collect_routing,
-            write_plan=write_plan, pool_offset=offset,
-        )
-        (hidden, pool, _), (stats, routing) = lax.scan(
-            lambda c, lp: step(c, lp), (hidden, pool, jnp.int32(0)), scanned)
+
+    def run(carry, group, scanned, n, w0, c0):
+        """``n`` layers of one stack (``scanned``: their leaves that ride
+        the scan): weights from ``w0``, cache layers from ``c0`` (scalars,
+        traced inside a repeated unit)."""
+        stacked = split[group][1]
+        linear = group.startswith(_KDA)
+
+        def body(c, xs):
+            j, lp = xs
+            hidden_, kv_, stats, routing = step(
+                c[0], c[1], lp, linear=linear, layer_idx=w0 + j,
+                cache_layer=c0 + j, stacked=stacked)
+            return (hidden_, kv_), (stats, routing)
+
+        return lax.scan(body, carry, (jnp.arange(n, dtype=jnp.int32), scanned))
+
+    def leaves(group, lo, n, repeat=None):
+        """Layers ``lo .. lo + n`` of a stack's scanned leaves; with
+        ``repeat`` as ``[repeat, n / repeat, ...]``."""
+        def cut(a):
+            a = a[lo:lo + n]
+            return a if repeat is None else a.reshape(
+                repeat, n // repeat, *a.shape[1:])
+        return jax.tree.map(cut, split[group][0])
+
+    def add_stats(stats):
+        """A run's routed-expert counters, summed over its layers, onto
+        the pass's."""
+        nonlocal moe
         if stats is not None:
-            moe = {name: jnp.sum(v) for name, v in stats.items()}
-        if routing is not None:
-            routes.append(routing)
-        offset += n
-    new_kv = {POOL: pool}
+            sums = {name: jnp.sum(v) for name, v in stats.items()}
+            moe = sums if moe is None else {
+                name: moe[name] + v for name, v in sums.items()}
+
+    carry = (hidden, kv)
+    for repeat, runs in layer_units(cfg):
+        if repeat == 1:
+            for group, n in runs:
+                linear = group.startswith(_KDA)
+                carry, (stats, routing) = run(
+                    carry, group, leaves(group, at_w[group], n), n,
+                    jnp.int32(at_w[group]), jnp.int32(at_c[linear]))
+                at_w[group] += n
+                at_c[linear] += n
+                add_stats(stats)
+                if routing is not None:
+                    routes.append(routing)
+            continue
+        # a repeated period: one scan over its repeats, its runs inside
+        w_lo = {g: at_w[g] for g, _ in runs}
+        c_lo = dict(at_c)
+        per_c = {lin: sum(n for g, n in runs if g.startswith(_KDA) == lin)
+                 for lin in (False, True)}
+        xs = {g: leaves(g, w_lo[g], repeat * n, repeat) for g, n in runs}
+
+        def period(c, px):
+            p_, lp_ = px
+            outs = []
+            seen = {False: 0, True: 0}
+            for g, n in runs:
+                lin = g.startswith(_KDA)
+                c, out = run(c, g, lp_[g], n, w_lo[g] + p_ * n,
+                             c_lo[lin] + p_ * per_c[lin] + seen[lin])
+                seen[lin] += n
+                outs.append(out)
+            return c, outs
+
+        carry, outs = lax.scan(
+            period, carry, (jnp.arange(repeat, dtype=jnp.int32), xs))
+        period_routes = []
+        for (g, n), (stats, routing) in zip(runs, outs):
+            at_w[g] += repeat * n
+            at_c[g.startswith(_KDA)] += repeat * n
+            add_stats(stats)
+            if routing is not None:
+                period_routes.append(routing)       # [repeat, n, T, k]
+        if period_routes:
+            r_ = jnp.concatenate(period_routes, axis=1)
+            routes.append(r_.reshape(-1, *r_.shape[2:]))
+    hidden, new_kv = carry
     routing = jnp.concatenate(routes, axis=0) if routes else None
     if not with_logits:
         return llama.ChunkOutput(hidden=hidden, kv=new_kv, logits=None,

@@ -46,7 +46,9 @@ QUANT_KEYS = frozenset(
      "we_gate", "we_up", "we_down",
      # latent attention's low-rank projections and the shared expert
      # (models/mla.py); W_UK / W_UV stay bf16 like the router
-     "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down"}
+     "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down",
+     # gated delta-rule layers (models/kda.py): q|k|v and the low-rank gates
+     "wqkv", "w_fa", "w_fb", "w_ga", "w_gb"}
 )
 
 _FP8_MAX = 448.0  # float8_e4m3 largest finite value
@@ -126,7 +128,8 @@ def matmul(x: jax.Array, w: Any, pallas: bool = True) -> jax.Array:
 # weight keys large enough to be worth the stacked-scan treatment
 STACKED_KEYS = frozenset(
     {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-     "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down"}
+     "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down",
+     "wqkv", "w_fa", "w_fb", "w_ga", "w_gb"}
 )
 # the MoE expert weights: kept whole for the routed layer's grouped-matmul
 # kernel (ops/moe_gmm_pallas.py), scanned where the layer runs in XLA
@@ -207,7 +210,9 @@ def quantize_params(
     out = dict(params)
     # a model whose layers are of two kinds keeps a second stack
     # (models/mla.py: the leading dense layers)
-    for group in ("layers", "dense_layers"):
+    # and a hybrid one a pair more (the gated delta-rule layers)
+    for group in ("layers", "dense_layers", "kda_layers",
+                  "kda_dense_layers"):
         if group in params:
             out[group] = _quantize_group(params[group], mode, consume)
     return out

@@ -512,6 +512,10 @@ class TPUEngine:
         else:
             self.params = self._load_params(checkpoint_path, seed)
         self.num_blocks = self.cfg.resolved_num_blocks()
+        # a hybrid model's linear-attention layers keep one state row a
+        # slot beside the pages: its size follows from max_batch_size
+        self._state_rows = (
+            self.cfg.max_batch_size if self.model_cfg.num_kda_layers else 0)
         self.kv = self._init_kv()
         host_store = (
             HostKVStore(self.cfg.spill_host_blocks)
@@ -526,6 +530,7 @@ class TPUEngine:
             remote_store=self.cfg.spill_remote_store,
             spill_on_evict=spill,
             kv_dtype=np.dtype(self.kv_dtype),
+            state_rows=self._state_rows,
         )
         self.eos_token_id = eos_token_id
 
@@ -631,8 +636,10 @@ class TPUEngine:
                 quantized_kv=self.kv_dtype == jnp.int8,
                 pallas=self.mesh is None,
             ),
-            # what a cached token is: per-head K and V, or one latent
-            "kv_layout": "latent" if self.model_cfg.latent_kv else "kv",
+            # what a cached token is: per-head K and V, or one latent;
+            # hybrid: latent pages beside a state row a sequence
+            "kv_layout": "hybrid" if self._state_rows
+            else "latent" if self.model_cfg.latent_kv else "kv",
         }
         self._moe_names = (
             _MOE_SHARE_COUNTERS if self.model_cfg.latent_kv
@@ -648,6 +655,22 @@ class TPUEngine:
                                "mla_row_steps_scan": 0,
                                "mla_pairs_ragged": 0,
                                "mla_context_tokens_ragged": 0})
+        if self._state_rows:
+            from distributed_gpu_inference_tpu.models import kda
+
+            # the state pool; live row x step x KDA layer of the scans (host
+            # arithmetic at a scan's commit); what the ragged rounds handed
+            # the chunk form (at a round's build; every KDA layer takes it
+            # once): live tokens, segments (a row's tokens in a round) and
+            # the 64-token chunks they are cut into
+            self.stats.update({
+                "state_pool_bytes": sum(
+                    int(self.kv[name].nbytes)
+                    for name in (kda.STATE, kda.CONV)),
+                "state_rows": self._state_rows,
+                "kda_row_steps_scan": 0, "kda_tokens_ragged": 0,
+                "kda_segments_ragged": 0, "kda_chunks_ragged": 0,
+            })
         if self.model_cfg.num_experts:
             # what the routed expert layers did (models/llama.py
             # _moe_mlp), summed on the device and read back beside a
@@ -686,6 +709,10 @@ class TPUEngine:
             raise ValueError(
                 f"{name}: the latent pool is served in the activation "
                 f"dtype, not kv_cache_dtype={self.cfg.kv_cache_dtype!r}")
+        if self.model_cfg.num_kda_layers and self.cfg.kv_seq_sharded:
+            raise ValueError(
+                f"{name}: a state row is whole on one chip (no sequence "
+                "sharding of the linear-attention layers)")
 
     # -------------------------------------------------- sharded weight init
 
@@ -847,7 +874,7 @@ class TPUEngine:
         if self.mesh is None:
             return llama.init_kv_pools(
                 self.model_cfg, self.num_blocks, self.cfg.block_size,
-                self.kv_dtype,
+                self.kv_dtype, state_rows=self._state_rows or None,
             )
         # zeros created directly with the sharded layout (no single-device
         # staging allocation)
@@ -1589,12 +1616,20 @@ class TPUEngine:
 
             self._unpack_spec_sched_fn = jax.jit(unpack_spec_sched)
 
+        state_pools = ()
+        if self._state_rows:
+            from distributed_gpu_inference_tpu.models import kda
+
+            state_pools = (kda.STATE, kda.CONV)
+
         def apply_ops(kv, srcs, dsts):
             # page copies (CoW): dst = -1 entries are dropped. Scale pools
             # (int8 KV) copy with their pages — a page without its scale is
             # garbage
+            # (a state pool has rows, not pages: it is no operand of a copy)
             return {
-                name: pool.at[:, dsts].set(pool[:, srcs], mode="drop")
+                name: pool if name in state_pools
+                else pool.at[:, dsts].set(pool[:, srcs], mode="drop")
                 for name, pool in kv.items()
             }
 
@@ -2181,6 +2216,8 @@ class TPUEngine:
             s.seq_id, self.cfg.max_blocks_per_seq
         )
         self._kv_lens[slot] = kv_len
+        if self._state_rows:
+            self.manager.bind_state(slot)
         sp = s.request.sampling
         self._temps[slot] = sp.temperature
         self._top_ks[slot] = sp.top_k
@@ -2296,6 +2333,8 @@ class TPUEngine:
         its own); intermediate chunks skip
         the LM head entirely."""
         n = len(piece)
+        if self._state_rows:
+            return self._prefill_piece_packed(slot, piece, off, is_last, mode)
         bucket = (
             self._bucket_len(max(n, 1)) if is_last
             else self.cfg.prefill_buckets[-1]
@@ -2323,6 +2362,32 @@ class TPUEngine:
         )
         self.stats["prefill_tokens"] += n
         self.stats["prefill_calls"] += 1
+        return first
+
+    def _prefill_piece_packed(self, slot: int, piece: List[int], off: int,
+                              is_last: bool, mode: str):
+        """``_prefill_one_chunk`` where a batch row is a state row (a
+        hybrid model): the piece goes out as the one segment of a packed
+        round, in its own slot's row, not as row 0 of a one-row batch."""
+        cap = self._ragged_chunk_cap()
+        first = None
+        for lo in range(0, len(piece), cap):
+            part = piece[lo:lo + cap]
+            last = is_last and lo + len(part) == len(piece)
+            self._count_kda_ragged(None, [len(part)])
+            _tp, operands, round_mode, s_w = self._pack_ragged(
+                [], [(slot, off + lo, part, last, mode)])
+            try:
+                self.kv, self._dev_core, toks, *_ = self._ragged_round_fn(
+                    self.params, self.kv, *operands, round_mode, s_w)
+            except Exception:
+                self._invalidate_device_state()
+                raise
+            first = toks[slot:slot + 1]
+            self.stats["prefill_tokens"] += len(part)
+            self.stats["prefill_calls"] += 1
+        # the caller records the token as not yet on the device's core
+        self._core_dirty = True
         return first
 
     # ------------------------------------------- chunk-interleaved admission
@@ -2522,6 +2587,20 @@ class TPUEngine:
         st = self.stats
         st["ragged_positions_dispatched"] += positions
         st["ragged_positions_live"] += decode_tokens + live_prompt
+
+    def _count_kda_ragged(self, sp: Optional[flight.span],
+                          segments: Sequence[int]) -> None:
+        """What a packed round hands the chunk form of the linear-attention
+        layers (each of them, once): its segments' tokens, the segments, and
+        the 64-token chunks they are cut into."""
+        from distributed_gpu_inference_tpu.models.kda import CHUNK
+
+        held = {"kda_tokens": sum(segments), "kda_segments": len(segments),
+                "kda_chunks": sum(-(-n // CHUNK) for n in segments)}
+        if sp is not None:
+            sp.set(**held)
+        for name, v in held.items():
+            self.stats[f"{name}_ragged"] += v
 
     def _count_moe(self, sp: Optional[flight.span], kind: str,
                    moe: Sequence[np.ndarray]) -> None:
@@ -2729,11 +2808,9 @@ class TPUEngine:
             return None
 
         self._apply_pending()
-        # the round's live tokens on one axis, row after row: token id,
-        # position, row and column in the rectangle attention sees
-        # (padding: row b, which every scatter drops, at position -1)
-        live = len(kept) + sum(len(piece) for _, piece, _ in ready)
-        tp, s_w = self._ragged_shape(live)
+        tp, operands, mode, s_w = self._pack_ragged(
+            kept, [(adm.slot, adm.off, piece, is_last, adm.mode)
+                   for adm, piece, is_last in ready])
         self._count_ragged(sp, tp, tp, len(kept), len(kept), ready)
         if "mla_pairs_ragged" in self.stats:
             # a decode row's token sees its cache and itself; query j of a
@@ -2745,6 +2822,26 @@ class TPUEngine:
                 ctx += adm.off + m
             self.stats["mla_pairs_ragged"] += pairs
             self.stats["mla_context_tokens_ragged"] += ctx
+        if self._state_rows:
+            self._count_kda_ragged(
+                sp, [1] * len(kept) + [len(piece) for _, piece, _ in ready])
+        return kept, ready, operands, mode, s_w
+
+    def _pack_ragged(
+        self, kept: Sequence[int],
+        pieces: Sequence[Tuple[int, int, Sequence[int], bool, str]],
+    ) -> Tuple[int, Tuple[Any, ...], str, int]:
+        """The operands of a plain ragged round that holds the decode rows
+        ``kept`` (each its pending token) and ``pieces`` (slot, offset,
+        tokens, whether the piece samples, its sampling mode): the round's
+        live tokens on one axis, row after row, as token id, position, row
+        and column in the rectangle attention sees (padding: row b, which
+        every scatter drops, at position -1). Returns the packed length,
+        the operands behind ``params`` and ``kv``, the round's mode and the
+        rectangle's width."""
+        b = len(self.slots)
+        tp, s_w = self._ragged_shape(
+            len(kept) + sum(len(piece) for _, _, piece, _, _ in pieces))
         tok_at = np.zeros((4, tp), np.int32)
         tok_at[1], tok_at[2] = -1, b
         lens_last = np.zeros((2, b), np.int32)
@@ -2760,22 +2857,22 @@ class TPUEngine:
             sample_flag[i] = 1
             if self._temps[i] > 0:
                 mode = "mixed"
-        for adm, piece, is_last in ready:
-            sl, m = adm.slot, len(piece)
+        for sl, off, piece, samples, piece_mode in pieces:
+            m = len(piece)
             tok_at[0, n:n + m] = piece
-            tok_at[1, n:n + m] = np.arange(adm.off, adm.off + m)
+            tok_at[1, n:n + m] = np.arange(off, off + m)
             tok_at[2, n:n + m] = sl
             tok_at[3, n:n + m] = np.arange(m)
             n += m
-            lens_last[:, sl] = (adm.off + m, n - 1)
+            lens_last[:, sl] = (off + m, n - 1)
             row_mask[sl] = True
-            sample_flag[sl] = 1 if is_last else 0
-            if adm.mode != "greedy":
+            sample_flag[sl] = 1 if samples else 0
+            if piece_mode != "greedy":
                 mode = "mixed"
         core = self._sync_core()
         tables, _act, flag_d = self._sched_arrays(row_mask, sample_flag)
-        return kept, ready, (tok_at, tables, jnp.asarray(lens_last),
-                             core, flag_d), mode, s_w
+        return tp, (tok_at, tables, jnp.asarray(lens_last), core,
+                    flag_d), mode, s_w
 
     def _spec_ragged_round(
         self, admissions: Sequence[ChunkedAdmission],
@@ -3515,6 +3612,12 @@ class TPUEngine:
                 raise
         t0 = time.perf_counter()
         self._count_moe(sp, "scan", moe)
+        if self._state_rows:
+            # a live row's step went through every linear-attention layer
+            steps = int((emitted >= 0).sum()) * self.model_cfg.num_kda_layers
+            st["kda_row_steps_scan"] += steps
+            if sp is not None:
+                sp.set(kda_row_steps=steps)
         with flight.span("dgi.engine.decode_multi.commit", st,
                          "round_commit_s"):
             out: Dict[int, List[int]] = {}
@@ -3833,6 +3936,9 @@ class TPUEngine:
     def get_stats(self) -> Dict[str, Any]:
         out = dict(self.stats)
         out["kv_cache"] = self.manager.get_stats()
+        if self._state_rows:
+            for name in ("state_binds", "prefix_hits_without_state"):
+                out[name] = out["kv_cache"][name]
         out["active_slots"] = self.num_active
         # XLA compile requests of the PROCESS (one log for all engines): a
         # worker with no warm-up compiles inside requests, and this shows it

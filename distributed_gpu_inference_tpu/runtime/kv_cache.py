@@ -273,6 +273,10 @@ class KVCacheStats:
     cached_blocks: int = 0
     free_blocks: int = 0
     window_released_blocks: int = 0
+    # a manager with a state pool (``state_rows``): rows bound to a new
+    # sequence, and lookups cut to zero because pages alone back them
+    state_binds: int = 0
+    prefix_hits_without_state: int = 0
 
     def as_dict(self) -> Dict[str, Any]:
         d = dict(self.__dict__)
@@ -393,8 +397,15 @@ class PagedKVCacheManager:
         remote_store: Optional[RemoteKVStore] = None,
         spill_on_evict: bool = False,
         kv_dtype: Optional[Any] = None,
+        state_rows: int = 0,
     ) -> None:
-        """``kv_dtype``: the engine's pool dtype — a probe hit must match
+        """``state_rows``: rows of the state pool that lies beside the paged
+        one (a hybrid model's linear-attention layers: one row a sequence,
+        fixed in size, ``bind_state`` / ``free_state``). A sequence's pages
+        then hold only part of its past, so a prefix found in the radix
+        index is no hit: nothing snapshots the state at a block boundary.
+
+        ``kv_dtype``: the engine's pool dtype — a probe hit must match
         it exactly (a token-keyed store shared across engines must never
         hand a bf16 engine int8 codes, f32 pages to a bf16 engine, etc.),
         and int8 hits must carry their scale page (spilled as one atomic
@@ -410,6 +421,7 @@ class PagedKVCacheManager:
         self.spill_on_evict = spill_on_evict
         self.kv_dtype = np.dtype(kv_dtype) if kv_dtype is not None else None
         self.quantized_kv = self.kv_dtype == np.int8
+        self.state_rows = int(state_rows)
 
         # durable-tier immunity (round 19): per-tier circuit breakers +
         # cumulative error/quarantine counters. A tier put/get that raises
@@ -681,6 +693,14 @@ class PagedKVCacheManager:
 
     # -- sequence lifecycle -------------------------------------------------
 
+    def bind_state(self, row: int) -> None:
+        """A state row goes to a new sequence. Its first piece starts at
+        position 0 (no lookup gives this model cached tokens), which is what
+        zeroes the row (``models/kda.py``): counted here, no dispatch."""
+        if not 0 <= row < self.state_rows:
+            raise ValueError(f"state row {row} outside {self.state_rows}")
+        self.stats.state_binds += 1
+
     def allocate_sequence(self, seq_id: str, token_ids: Sequence[int]) -> Tuple[List[int], int]:
         """Allocate the block chain for a prompt. Returns (block_ids,
         num_cached_tokens) — the first ``num_cached_tokens`` positions already
@@ -707,6 +727,10 @@ class PagedKVCacheManager:
             self.stats.prefix_queries += 1
             self.stats.prefix_total_tokens += n_tokens
             cached = self.radix.match_prefix(probe)
+            if self.state_rows and cached:
+                # latent pages without the state that belongs to them
+                self.stats.prefix_hits_without_state += 1
+                cached = []
             # never reuse the *entire* prompt from cache: the last token's
             # logits must be recomputed, so keep at least one token fresh
             while cached and len(cached) * self.block_size >= n_tokens:

@@ -417,8 +417,10 @@ class Metrics:
         self.worker_kv_layout = Gauge(
             "worker_kv_layout",
             "1 for what the worker's cache holds a token: kv (per-head K "
-            "and V pages) or latent (one compressed latent and its rope "
-            "key)", ["worker", "layout"], registry=r)
+            "and V pages), latent (one compressed latent and its rope "
+            "key) or hybrid (latent pages in some layers, a fixed-size "
+            "state row a sequence in the others)", ["worker", "layout"],
+            registry=r)
         self.worker_mla = {
             name: Counter(f"worker_mla_{name}_total", help_, ["worker"],
                           registry=r)
@@ -430,6 +432,35 @@ class Metrics:
                  "ragged rounds' attention held, causal"),
                 ("context_tokens_ragged", "Cached tokens of the plain ragged "
                  "rounds' rows, each row's once a round"),
+            )
+        }
+        # a hybrid engine's state pool (linear-attention layers): its size,
+        # and what its rows and its two kernels were handed
+        self.worker_state_pool_bytes = Gauge(
+            "worker_state_pool_bytes",
+            "Bytes of the state pool: a float32 matrix a head a layer and "
+            "the convolution's tail, a row a sequence", ["worker"],
+            registry=r)
+        self.worker_state_rows = Gauge(
+            "worker_state_rows", "Rows of the state pool (one a slot)",
+            ["worker"], registry=r)
+        self.worker_state = {
+            name: Counter(f"worker_{name}_total", help_, ["worker"],
+                          registry=r)
+            for name, help_ in (
+                ("state_binds", "State rows bound to a new sequence (its "
+                 "first piece starts from a zero state)"),
+                ("prefix_hits_without_state", "Prefix lookups cut to no "
+                 "cached tokens because pages alone back them, no state"),
+                ("kda_row_steps_scan", "Live row x step x linear-attention "
+                 "layer of the decode scans (calls of the step kernel's "
+                 "row)"),
+                ("kda_tokens_ragged", "Live tokens the plain ragged rounds "
+                 "handed the chunk form, a round's once"),
+                ("kda_segments_ragged", "Segments (a row's tokens in a "
+                 "round) the plain ragged rounds handed the chunk form"),
+                ("kda_chunks_ragged", "64-token chunks those segments were "
+                 "cut into"),
             )
         }
         # cache-aware routing (round 7): hits = placements that landed on
@@ -827,9 +858,14 @@ class MetricsCollector:
                     1.0 if name == path else 0.0)
         layout = stats.get("kv_layout")
         if isinstance(layout, str):
-            for name in ("kv", "latent"):
+            for name in ("kv", "latent", "hybrid"):
                 self.metrics.worker_kv_layout.labels(worker, name).set(
                     1.0 if name == layout else 0.0)
+        for key, gauge in (
+                ("state_pool_bytes", self.metrics.worker_state_pool_bytes),
+                ("state_rows", self.metrics.worker_state_rows)):
+            if key in stats:
+                gauge.labels(worker).set(float(stats[key] or 0.0))
         prev = self._batcher_prev.setdefault(worker, {})
         for key, metric in (
             ("decode_rounds", self.metrics.batcher_decode_rounds),
@@ -896,6 +932,8 @@ class MetricsCollector:
                 if key[4:] not in self.metrics.worker_mla:
                     continue
                 metric = self.metrics.worker_mla[key[4:]].labels(worker)
+            elif key in self.metrics.worker_state:
+                metric = self.metrics.worker_state[key].labels(worker)
             else:
                 continue
             try:

@@ -327,13 +327,15 @@ class Worker:
                 eng.load_model()
                 core = getattr(eng, "engine", None)
                 if self.config.role != "hybrid" and core is not None and \
-                        getattr(core, "stats", {}).get("kv_layout") == "latent":
+                        getattr(core, "stats", {}).get("kv_layout") in (
+                            "latent", "hybrid"):
                     # a prefill / decode role hands K/V pages to a peer
                     # (runtime/kv_handoff.py require_kv_pages): refused
                     # here, where the worker is configured
                     raise EngineLoadError(
                         f"role {self.config.role!r}: the PD handoff carries "
-                        f"K/V pages, {cfg.model} caches latent pages")
+                        f"K/V pages, {cfg.model} caches latent pages (and, "
+                        "a hybrid model, state rows)")
                 self.engines[task_type] = eng
                 loaded.append(task_type)
             except (EngineLoadError, KeyError) as exc:
@@ -521,10 +523,14 @@ class Worker:
                 elif k.startswith("longest_wait_"):
                     out[k] = out.get(k, 0) + int(s[k] or 0)
             # the routed expert layers' counters (MoE engines only)
-            # and a latent-attention engine's scan counters (mla_*)
+            # and a latent-attention engine's scan counters (mla_*); a
+            # hybrid engine's state pool and what its kernels were handed
             for k in es:
-                if k.startswith(("moe_", "mla_")):
+                if k.startswith(("moe_", "mla_", "kda_")) or k in (
+                        "state_binds", "prefix_hits_without_state"):
                     out[k] = out.get(k, 0) + int(es[k] or 0)
+                elif k in ("state_pool_bytes", "state_rows"):
+                    out[k] = int(es[k])
             if s.get("avg_occupancy") is not None:
                 out["avg_occupancy"] = round(
                     float(s.get("avg_occupancy") or 0.0), 3

@@ -240,7 +240,9 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
         )
     )
     kv = jax.eval_shape(
-        lambda: llama.init_kv_pools(cfg, 1 + BATCH * (ctx // 16), 16)
+        lambda: llama.init_kv_pools(
+            cfg, 1 + BATCH * (ctx // 16), 16,
+            state_rows=BATCH if cfg.num_kda_layers else None)
     )
     if mesh is None:
         one = SingleDeviceSharding(devices[0])
@@ -511,4 +513,77 @@ def test_latent_model_graphs_compile_and_move_no_pool_layer(
     assert f"[{cfg.num_layers},{blocks},16,640]" in text
     assert f"[{blocks},16,640]" not in text.replace(
         f"[{cfg.num_layers},{blocks},16,640]", "")
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
+
+
+# --------------------------------------------------------------------- #
+# (e) the hybrid model: the state pool's kernels and its serving graphs
+# --------------------------------------------------------------------- #
+
+KIMI = "kimi-linear-48b-a3b-ep8"
+
+
+def test_state_pool_kernels_compile(v5e):
+    """``dgi_kda_step`` (eight rows, 32 heads of 128 x 128 float32 in place
+    in the stacked pool) and ``dgi_kda_chunk`` (the 12 chunks a one-piece
+    round of 264 packed tokens is cut into), at the published widths."""
+    from distributed_gpu_inference_tpu.models import kda
+    from distributed_gpu_inference_tpu.ops import kda_pallas
+
+    cfg = get_model_config(KIMI)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    h, d = cfg.kda_num_heads, cfg.kda_head_dim
+    pool = sds((cfg.num_kda_layers, BATCH, h, d, d), jnp.float32)
+    row = sds((BATCH, h, d), jnp.float32)
+    lowered = jax.jit(kda_pallas.kda_step, donate_argnums=(5,)).lower(
+        row, row, row, row, sds((BATCH, h), jnp.float32), pool,
+        sds((), jnp.int32), sds((BATCH,), bool), sds((BATCH,), bool))
+    assert _kernels(lowered) == {"dgi_kda_step"}
+    lowered.compile()
+    c = BATCH + 264 // kda.CHUNK
+    tile = lambda *t: sds((c, h, *t), jnp.float32)        # noqa: E731
+    ops = kda.ChunkOperands(
+        w=tile(64, d), u=tile(64, d), qd=tile(64, d), kd=tile(64, d),
+        b=tile(64, 64), dlast=tile(d))
+    flags = sds((c,), bool)
+    lowered = jax.jit(kda_pallas.kda_chunk_pass, donate_argnums=(1,)).lower(
+        ops, pool, sds((), jnp.int32), sds((c,), jnp.int32), flags, flags,
+        flags)
+    assert _kernels(lowered) == {"dgi_kda_chunk"}
+    lowered.compile()
+
+
+@pytest.mark.parametrize("tp,s", [(None, 1), (264, 256), (2048, 256)],
+                         ids=["scan-step", "Tp264", "Tp2048"])
+def test_hybrid_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch, tp,
+                                                      s):
+    """The hybrid share at its published widths, all 27 layers: a decode
+    step and the packed round at a middle and the top rung. The state is
+    read and written in place in the stacked pool by the two KDA kernels,
+    the latent pages of the seven latent layers by theirs, the held experts
+    go through the grouped-matmul kernel (no dequantised expert copy), no
+    ``[T, 256, ...]`` temporary of the router's width exists, and no array
+    of a pool layer's shape."""
+    cfg = get_model_config(KIMI)
+    lowered = _forward_chunk_lowered(cfg, s, None, v5e, tp=tp, ctx=PANGU_CTX)
+    found = _kernels(lowered)
+    want = {"dgi_mla_write", "dgi_mla_decode", "dgi_moe_gmm_step",
+            "dgi_kda_step"} if tp is None else {
+        "dgi_mla_write", "dgi_mla_ragged", "dgi_moe_gmm", "dgi_kda_chunk"}
+    assert want <= found and found <= want | {"dgi_qmm"}, found
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    blocks = 1 + BATCH * (PANGU_CTX // 16)
+    h, d = cfg.kda_num_heads, cfg.kda_head_dim
+    for whole, layer in (
+            (f"[{cfg.num_cache_layers},{blocks},16,640]",
+             f"[{blocks},16,640]"),
+            (f"[{cfg.num_kda_layers},{BATCH},{h},{d},{d}]",
+             f"[{BATCH},{h},{d},{d}]")):
+        assert whole in text
+        assert layer not in text.replace(whole, "")
+    tokens = BATCH if tp is None else tp
+    mi, hid = cfg.moe_intermediate_size, cfg.hidden_size
+    assert f"[{tokens},{cfg.num_experts}," not in text
+    assert f"bf16[{cfg.num_held_experts},{hid},{mi}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
