@@ -273,20 +273,21 @@ def _moe_mlp(
     kept experts. Two forms of that mathematics, chosen as the other
     kernels are (``pallas`` and the backend, no option):
 
-    - **routed** (``pallas=True``: every one-device path). The ``T x k``
-      (token, expert) pairs are sorted by expert into row tiles that each
-      belong to one expert (``ops/moe_gmm_pallas.route_plan``), gate / up /
-      down run as grouped matmuls over those tiles and the ``k`` rows of a
-      token are combined in float32. Compute follows ``T x k`` rows plus
-      tile padding, no ``[T, E, ...]`` tensor exists, and tokens that are
-      not ``live`` are routed nowhere. On a TPU with quantized expert
-      weights (``stacked``: kept whole, addressed by ``layer_idx``) the
-      grouped matmul is the ``dgi_moe_gmm`` kernel, which reads the int8
-      weights as stored and only the experts that received a row; on the
-      CPU the same plan runs through an XLA gather of each tile's weight.
-      Returns the layer's counters beside the output
-      (``moe_gmm.expert_stats``), and the experts each token was routed to
-      (``[T, k]``) last.
+    - **routed** (``pallas=True``: every one-device path). Compute follows
+      the ``T x k`` (token, expert) pairs, no ``[T, E, ...]`` tensor
+      exists, tokens that are not ``live`` are routed nowhere, and an
+      expert that received no pair is never read
+      (``ops/moe_gmm_pallas.py``, ``_routed_sum``). A round with a piece
+      (``s > 1``) sorts the pairs by expert into row tiles that each belong
+      to one expert and runs gate / up / down as grouped matmuls over them
+      (``dgi_moe_gmm``); a scan step (``s == 1``, at most 128 padded rows)
+      keeps its rows in ONE resident tile and walks the experts that
+      received a pair in one call a layer (``dgi_moe_gmm_step``). On a TPU
+      with quantized expert weights (``stacked``: kept whole, addressed by
+      ``layer_idx``) both are Pallas kernels that read the int8 weights as
+      stored; on the CPU the same plans run through XLA gathers. Returns
+      the layer's counters beside the output (``moe_gmm.expert_stats``),
+      and the experts each token was routed to (``[T, k]``) last.
     - **dense over the expert axis** (``pallas=False``: a GSPMD mesh, which
       refuses a ``pallas_call``). The combine is an einsum over ``E`` with
       top-k-masked weights: where ``we_*`` shard their E axis over
@@ -346,18 +347,28 @@ def _routed_sum(
     *,
     stacked: Optional[Dict[str, Any]],
     layer_idx: Any,
-    decode: bool,               # one token a row: names the kernel
+    decode: bool,               # one token a row: a scan step
 ) -> Tuple[jax.Array, Any]:
     """``sum_e w_e * down_e(act(gate_e(x)) * up_e(x))`` over a token's live
-    pairs as grouped matmuls (``ops/moe_gmm_pallas``) → (``[T, H]`` float32,
-    the plan). The routed form of ``_moe_mlp`` and the held share of
+    pairs (``ops/moe_gmm_pallas``) → (``[T, H]`` float32, the plan). The
+    routed form of ``_moe_mlp`` and the held share of
     ``models/mla._experts`` (whose ``live`` is per pair: a pair on an expert
-    held elsewhere is routed nowhere and reads nothing)."""
+    held elsewhere is routed nowhere and reads nothing). A scan step whose
+    rows fit one tile (``moe_gmm.takes_step_form``: ``decode``, the row
+    count and, for the kernel, a hidden width whose thinnest blocks fit its
+    budget; nothing else) takes the step form, one call over the resident
+    rows; every other call lays the pairs out in sorted tiles and runs
+    three grouped matmuls."""
     # imported where a sparse model needs it: a dense model's start does
     # not pay for it
     from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
 
     (t, h), k = xf.shape, topv.shape[1]
+    if decode and moe_gmm.takes_step_form(t, xf.dtype, stacked):
+        plan = moe_gmm.step_plan(experts, topv, live, num_stored,
+                                 moe_gmm.step_rows(t, xf.dtype))
+        return moe_gmm.routed_step(xf, lp, stacked, layer_idx, plan,
+                                   act), plan
     plan = moe_gmm.route_plan(
         experts, live, num_stored,
         moe_gmm.tile_rows(pairs_hint, num_stored, moe_gmm.sublane(xf.dtype)),

@@ -3,7 +3,13 @@
 A sparse-MoE layer with many small experts (OLMoE: top-8 of 64) cannot run
 as a dense einsum over the expert axis — that computes and reads every
 expert for every token, E/k = 8x the work and a ``[T, E, H]`` combine
-tensor. Here the layer is *routed*:
+tensor. Here the layer is *routed*, in one of two forms of the same sum
+``sum_e w_e * down_e(act(gate_e(x)) * up_e(x))``. Which one runs is decided
+at trace time from the call's shape alone (``takes_step_form``), never from
+a model's name, a config key or the environment:
+
+**The tiled form** — a round with a prompt piece, tree-verify, anything
+with more than one token a row or more rows than one MXU tile (128).
 
 - **Plan** (:func:`route_plan`, plain ``jax.numpy``): the ``T x k`` (token,
   expert) pairs are sorted by expert and laid out in row tiles of ``tm``
@@ -11,27 +17,48 @@ tensor. Here the layer is *routed*:
   Shapes are static: at most ``ceil(T k / tm) + min(E, T k)`` tiles. Dead
   tokens (padding of a packed round, finished rows of a scan step) are
   routed nowhere.
-- **Grouped matmul** (:func:`grouped_matmul_pallas`): ``y[r] = x[r] @
-  dequant(w[layer, expert_of_tile(r)])`` over the stacked int8 expert
-  weights ``[L, E, K, N]`` as stored. The Pallas kernel takes the layer
-  index, the tile → expert map and the number of used tiles as scalar
-  prefetch, like ``ops/qmm_pallas.py`` takes its layer index: weight
+- **Grouped matmul** (:func:`grouped_matmul_pallas`, ``dgi_moe_gmm``):
+  ``y[r] = x[r] @ dequant(w[layer, expert_of_tile(r)])`` over the stacked
+  int8 expert weights ``[L, E, K, N]`` as stored. The Pallas kernel takes
+  the layer index, the tile → expert map and the number of used tiles as
+  scalar prefetch, like ``ops/qmm_pallas.py`` takes its layer index: weight
   blocks are DMA'd int8 and converted in VMEM, consecutive tiles of one
   expert reuse the resident block, and an expert that received no token is
   never named by a block index, so it costs no HBM read. Tiles past the
   used count repeat the last block indices and skip the compute.
 - Elsewhere (CPU, shapes that do not tile) the same plan runs through an
   XLA gather of each tile's expert weight (:func:`grouped_matmul_layer`).
+- The caller (``models/llama.py _routed_sum``) gathers the rows in, applies
+  the activation between the two matmul stages, and combines the ``k`` rows
+  of a token in float32.
 
-The caller (``models/llama.py _moe_mlp``) gathers the rows in, applies the
-activation between the two matmul stages, and combines the ``k`` rows of a
-token in float32.
+**The step form** — a scan step (``s == 1``) of at most 128 padded rows: 8
+rows fit ONE row tile, so no row needs gathering and no pair a rank; at 8
+rows the tiled form above spent nine tenths of its grid steps on tiles that
+moved nothing (PERF.md section 6, PR 43).
+
+- **Plan** (:func:`step_plan`): compares and one cumsum give the dense
+  weight of each (row, stored expert) — zero where the pair is dead — and
+  the packed list of experts that received a live pair. No sort, no
+  ``searchsorted``, no scatter, no gather of rows.
+- **One call a layer** (:func:`routed_step_pallas`, ``dgi_moe_gmm_step``):
+  the rows stay resident in VMEM; for each listed expert and each tile of
+  the intermediate axis the kernel DMAs the gate and up columns and the
+  down rows int8 as stored, converts in VMEM, and adds the expert's weighted
+  share to a float32 ``[T_pad, H]`` accumulator that is written once. Every
+  rounding point of the tiled form stays or moves to float32 (gate, up,
+  their product and the down output are never rounded to the activation
+  dtype; the down matmul's input is, as there); a row's experts are summed
+  in stored-expert order, not top-k order. The grid's first axis is the
+  number of experts listed, read at run time: nothing is stepped over.
+- Elsewhere the same plan runs through :func:`routed_step_layer` (a gather
+  of the listed experts' weights and three einsums).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +67,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_gpu_inference_tpu.ops import attention as _attention
-from distributed_gpu_inference_tpu.ops.qmm_pallas import block_tiles
+from distributed_gpu_inference_tpu.ops.qmm_pallas import (
+    _longest_tile, block_tiles,
+)
 
 # fixed: the Mosaic kernel's name and, as the innermost scope, the custom
 # call's name on a device trace's XLA Ops line (``dgi_moe_gmm.<n>``).
@@ -57,6 +86,15 @@ _MAX_TILE_ROWS = 128
 # has been asked about column tiles past 512 for ``dgi_qmm`` only.
 _WHOLE_BLOCK_BYTES = 2 * 1024 * 1024
 _MAX_TILE_COLS = 512
+# the step form's three weight blocks of one grid step, together (bytes as
+# stored): 512 columns at every width the cells run (3 MB a step at 2048,
+# 3.5 at 2304, 11.8 at 7680). With the grid as long as the experts used,
+# 256-1024 columns read within 3.4 % of one another on the chip; whole
+# experts (1024) lose where few experts are used, because the first
+# block's DMA is not overlapped (PERF.md section 6, PR 43 has the table).
+_STEP_BLOCK_BYTES = 12 * 1024 * 1024
+_STEP_VMEM_SLACK = 4 * 1024 * 1024
+_STEP_VMEM_FLOOR = 16 * 1024 * 1024
 
 
 def weight_tiles(k: int, n: int):
@@ -70,6 +108,18 @@ def weight_tiles(k: int, n: int):
     if k % 128 == 0 and n % 128 == 0 and k * n <= _WHOLE_BLOCK_BYTES:
         return k, n
     return block_tiles(k, n, _WHOLE_BLOCK_BYTES, _MAX_TILE_COLS)
+
+
+def step_tile(h: int, i: int, itemsize: int = 1):
+    """Columns of the intermediate axis one grid step of the STEP form
+    takes (``bi``), or None if ``H x I`` does not tile: the longest
+    multiple of 128 that divides ``I`` and keeps the step's three weight
+    blocks (gate and up ``[H, bi]``, down ``[bi, H]``) inside
+    ``_STEP_BLOCK_BYTES`` together, ``_MAX_TILE_COLS`` at most —
+    ``ops/qmm_pallas.block_tiles``' rule with the whole hidden axis as the
+    other side of every block."""
+    return _longest_tile(
+        i, min(_MAX_TILE_COLS, _STEP_BLOCK_BYTES // (3 * h * itemsize)))
 
 
 class RoutePlan(NamedTuple):
@@ -270,6 +320,211 @@ def grouped_matmul_layer(x: jax.Array, w, plan: RoutePlan) -> jax.Array:
     return jnp.where(keep[:, None, None], out, 0).reshape(-1, out.shape[-1])
 
 
+# ---------------------------------------------------------------------------
+# the step form: a scan step's few rows as ONE resident tile
+# ---------------------------------------------------------------------------
+
+
+class StepPlan(NamedTuple):
+    """A scan step's routing with no row layout: every row is in the one
+    tile, so all the kernel needs is which experts to walk and what each
+    weighs in each row."""
+
+    weight: jax.Array       # [T_pad, E_pad] float32; 0 = pair dead / absent
+    slot_expert: jax.Array  # [A_max] int32 experts with a live pair, packed
+    used_slots: jax.Array   # [] int32 how many of them
+    assignments: jax.Array  # [] int32 live (token, expert) pairs
+    rows: int               # T_pad, static
+
+
+def step_rows(t: int, dtype) -> int:
+    """``T`` rows padded to the dtype's sublane tile."""
+    sub = sublane(dtype)
+    return -(-t // sub) * sub
+
+
+def takes_step_form(t: int, dtype, stacked: Optional[Dict[str, Any]]) -> bool:
+    """Whether ``T`` one-token rows run as the step form: they fit its
+    single row tile (one MXU tile at most) and, where the kernel takes the
+    weights, a step's three blocks fit its budget (``step_tile``)."""
+    if step_rows(t, dtype) > _MAX_TILE_ROWS:
+        return False
+    if stacked is None or "we_gate" not in stacked:
+        return True
+    # the thinnest step, 128 columns (that the widths tile at all is
+    # ``kernel_ok``'s to say, before this)
+    qw = stacked["we_gate"]["qw"]
+    return step_tile(qw.shape[2], 128, qw.dtype.itemsize) is not None
+
+
+def step_plan(experts: jax.Array, topv: jax.Array, live: jax.Array,
+              num_experts: int, rows: int) -> StepPlan:
+    """The plan of ``experts [T, k]`` (distinct within a row) weighing
+    ``topv [T, k]``, ``live`` as in :func:`route_plan`: compares and one
+    cumsum over the experts — no sort, no scatter, no gather of rows."""
+    t, k = experts.shape
+    a_max = min(num_experts, t * k)
+    e_pad = -(-num_experts // 128) * 128
+    pair_live = live if live.ndim == 2 else live[:, None]
+    hit = pair_live[..., None] & (
+        experts[..., None] == jnp.arange(e_pad, dtype=jnp.int32))
+    weight = jnp.sum(
+        jnp.where(hit, topv[..., None].astype(jnp.float32), 0.0), axis=1)
+    active = jnp.any(hit, axis=(0, 1))[:num_experts]              # [E]
+    slot_of = jnp.cumsum(active, dtype=jnp.int32) - 1
+    # slots past the count name expert 0 and are never walked
+    slot_expert = jnp.sum(
+        jnp.where(active & (slot_of == jnp.arange(
+            a_max, dtype=jnp.int32)[:, None]),
+                  jnp.arange(num_experts, dtype=jnp.int32), 0),
+        axis=1, dtype=jnp.int32)
+    return StepPlan(
+        weight=jnp.pad(weight, ((0, rows - t), (0, 0))),
+        slot_expert=slot_expert, used_slots=slot_of[-1] + 1,
+        assignments=jnp.sum(hit, dtype=jnp.int32), rows=rows,
+    )
+
+
+def _step_kernel(idx_ref, used_ref, se_ref, x_ref, w_ref, g_ref, gs_ref,
+                 u_ref, us_ref, d_ref, ds_ref, o_ref, *, act):
+    del idx_ref                     # consumed by the index maps
+    j, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((j == 0) & (i == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(j < used_ref[0])       # false only where no expert is listed
+    def _():
+        x = x_ref[...]
+
+        def mm(rows, q_ref, scale_ref):
+            return lax.dot(
+                rows, q_ref[0, 0].astype(x.dtype),
+                preferred_element_type=jnp.float32) * scale_ref[0, 0]
+
+        mid = act(mm(x, g_ref, gs_ref)) * mm(x, u_ref, us_ref)
+        y = mm(mid.astype(x.dtype), d_ref, ds_ref)              # [T_pad, H]
+        # this expert's column of the dense weights: a masked lane sum
+        lane = lax.broadcasted_iota(jnp.int32, w_ref.shape, 1)
+        col = jnp.sum(jnp.where(lane == se_ref[j], w_ref[...], 0.0),
+                      axis=1, keepdims=True)
+        # a row the expert does not serve adds nothing, whatever it holds
+        o_ref[...] += jnp.where(col != 0.0, col * y, 0.0)
+
+
+def routed_step_pallas(
+    x: jax.Array,               # [T_pad, H] the step's rows
+    stacked: Dict[str, Any],    # we_gate / we_up / we_down: qw [L, E, K, N]
+    layer_idx: jax.Array,       # scalar int32
+    plan: StepPlan,
+    act,
+    *,
+    bi: Optional[int] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """``sum_e weight[:, e] * down_e(act(gate_e(x)) * up_e(x))`` over the
+    plan's experts in ONE call → ``[T_pad, H]`` float32. Grid (slot,
+    intermediate tile), the slots as many as the plan lists (a bound read
+    at run time; one where it lists none, to write the zeros): a step DMAs
+    the expert's gate and up columns and down rows int8 as stored,
+    converts in VMEM and adds its weighted share to the resident
+    accumulator, written once."""
+    gate, up, down = (stacked[n] for n in ("we_gate", "we_up", "we_down"))
+    t_pad, h = x.shape
+    _, _, _, inter = gate["qw"].shape
+    if bi is None:
+        bi = inter if interpret else step_tile(
+            h, inter, gate["qw"].dtype.itemsize)
+    if t_pad != plan.rows or not bi or inter % bi:
+        raise ValueError(f"step form shapes: x {x.shape}, gate "
+                         f"{gate['qw'].shape}, tile {bi}")
+
+    def cols(rows, width):      # a block of gate / up, or of their scales
+        return pl.BlockSpec(
+            (1, 1, rows, width), lambda j, i, idx, used, se:
+            (idx[0], se[j], 0, i))
+
+    whole = lambda *shape: pl.BlockSpec(shape, lambda j, i, *_: (0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(jnp.maximum(plan.used_slots, 1), inter // bi),
+        in_specs=[
+            whole(t_pad, h), whole(*plan.weight.shape),
+            cols(h, bi), cols(1, bi), cols(h, bi), cols(1, bi),
+            pl.BlockSpec((1, 1, bi, h), lambda j, i, idx, used, se:
+                         (idx[0], se[j], i, 0)),
+            pl.BlockSpec((1, 1, 1, h), lambda j, i, idx, used, se:
+                         (idx[0], se[j], 0, 0)),
+        ],
+        out_specs=whole(t_pad, h),
+    )
+    # two buffers of the three int8 blocks, their copies in x's dtype, the
+    # rows, the weights and the accumulator twice, and room for the rest
+    block = 3 * h * bi
+    need = block * (2 * gate["qw"].dtype.itemsize + x.dtype.itemsize) \
+        + 4 * t_pad * (h + plan.weight.shape[1]) * 4
+    f32 = lambda w: w["scale"].astype(jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_step_kernel, act=act),
+        out_shape=jax.ShapeDtypeStruct((t_pad, h), jnp.float32),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            # one accumulator over both axes
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(need + _STEP_VMEM_SLACK, _STEP_VMEM_FLOOR),
+        ),
+        interpret=interpret,
+        name=KERNEL_NAME_STEP,
+    )(
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
+        jnp.asarray(plan.used_slots, jnp.int32).reshape(1),
+        plan.slot_expert,
+        x, plan.weight,
+        gate["qw"], f32(gate), up["qw"], f32(up), down["qw"], f32(down),
+    )
+
+
+def routed_step_layer(x: jax.Array, lp: Dict[str, Any], plan: StepPlan,
+                      act) -> jax.Array:
+    """The same sum without the kernel, over ONE layer's expert weights
+    ``[E, K, N]`` (plain or quantized sub-dicts): the plan's experts are
+    gathered and contracted batched, float32 where the kernel is."""
+    from distributed_gpu_inference_tpu.ops.quantization import is_quantized
+
+    se = plan.slot_expert
+
+    def mm(spec, rows, w):
+        if not is_quantized(w):
+            return jnp.einsum(spec, rows, w[se],
+                              preferred_element_type=jnp.float32)
+        return jnp.einsum(
+            spec, rows, w["qw"][se].astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        ) * w["scale"][se].astype(jnp.float32)
+
+    mid = act(mm("tk,akn->atn", x, lp["we_gate"])) \
+        * mm("tk,akn->atn", x, lp["we_up"])
+    y = mm("atk,akn->atn", mid.astype(x.dtype), lp["we_down"])  # [A, T, H]
+    used = jnp.arange(se.shape[0]) < plan.used_slots
+    weight = jnp.where(
+        used, jnp.take(plan.weight, se, axis=1), 0.0).T[..., None]  # [A, T, 1]
+    return jnp.sum(jnp.where(weight != 0.0, weight * y, 0.0), axis=0)
+
+
+def routed_step(x: jax.Array, lp: Dict[str, Any],
+                stacked: Optional[Dict[str, Any]], layer_idx, plan: StepPlan,
+                act) -> jax.Array:
+    """``[T, H]`` rows → their routed sum ``[T, H]`` float32 by the step
+    form: the kernel over the stacked quantized weights where
+    ``kernel_ok`` kept them whole, else the XLA twin over the layer's."""
+    t = x.shape[0]
+    rows = jnp.pad(x, ((0, plan.rows - t), (0, 0)))
+    if stacked is not None and "we_gate" in stacked:
+        return routed_step_pallas(rows, stacked, layer_idx, plan, act)[:t]
+    return routed_step_layer(rows, lp, plan, act)[:t]
+
+
 def kernel_ok(layers: Dict[str, Any]) -> bool:
     """Trace-time gate for the Pallas kernel, from a stacked layer tree:
     TPU backend, quantized expert weights, K and N that tile."""
@@ -303,14 +558,23 @@ def sublane(dtype) -> int:
     return 16 if jnp.dtype(dtype) == jnp.bfloat16 else 8
 
 
-def expert_stats(plan: RoutePlan) -> Dict[str, jax.Array]:
-    """What one call of the layer did, as int32 scalars the caller sums:
-    whether it held a live token, its live (token, expert) pairs, the rows
-    the grouped matmul ran (tile padding included), the experts that
-    received at least one row."""
+def expert_stats(plan) -> Dict[str, jax.Array]:
+    """What one call of the layer did (either plan), as int32 scalars the
+    caller sums: whether it held a live token, its live (token, expert)
+    pairs, the rows the MXU ran (tile padding included: the used tiles'
+    rows, or the step form's one tile once an expert walked), the experts
+    that received at least one row, and whether the call took the step
+    form."""
+    calls = (plan.assignments > 0).astype(jnp.int32)
+    if isinstance(plan, StepPlan):
+        rows, active, step = plan.used_slots * plan.rows, plan.used_slots, calls
+    else:
+        rows, active = plan.used_tiles * plan.tile_rows, plan.active_experts
+        step = jnp.zeros_like(calls)
     return {
-        "layer_calls": (plan.assignments > 0).astype(jnp.int32),
+        "layer_calls": calls,
         "assignments": plan.assignments,
-        "rows_dispatched": plan.used_tiles * plan.tile_rows,
-        "active_experts": plan.active_experts,
+        "rows_dispatched": rows,
+        "active_experts": active,
+        "step_form_calls": step,
     }

@@ -78,11 +78,12 @@ _QUANT_DEVICE_BUILD_LIMIT = 8 * 1024**3
 # the routed expert layers' counters, in the order a round returns them:
 # layer calls that held a live token, live (token, expert) pairs, rows the
 # grouped matmul ran (tile padding included), experts with at least one row
-# summed over layer calls
+# summed over layer calls, layer calls that took the step form (a scan
+# step's rows as one resident tile: ops/moe_gmm_pallas.py)
 # (the keys of ops/moe_gmm_pallas.expert_stats, spelled here so that a dense
 # model's engine does not import that module and Pallas with it at start)
 _MOE_COUNTERS = ("layer_calls", "assignments", "rows_dispatched",
-                 "active_experts")
+                 "active_experts", "step_form_calls")
 
 
 # one more where the chip holds a share of the experts: every (token, expert)

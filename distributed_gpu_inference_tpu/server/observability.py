@@ -405,6 +405,9 @@ class Metrics:
                  "tile padding included"),
                 ("active_experts", "Experts that received at least one "
                  "row, summed over layer calls"),
+                ("step_form_calls", "Layer calls that took the step form "
+                 "(a scan step's rows as one resident tile, one kernel "
+                 "call a layer): all of a scan's, none of a round's"),
                 ("pairs_routed", "Every (token, expert) pair the router "
                  "kept, on experts this worker holds or not (a worker that "
                  "holds a share of the experts: assignments / pairs_routed "

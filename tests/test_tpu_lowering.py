@@ -223,6 +223,41 @@ def test_expert_kernel_keeps_its_blocks(k, n, want):
     assert moe_gmm_pallas.weight_tiles(k, n) == want
 
 
+@pytest.mark.parametrize("rows", [BATCH, 128])
+@pytest.mark.parametrize("model", [
+    "olmoe-1b-7b", "openpangu-ultra-moe-718b-ep16",
+    "kimi-linear-48b-a3b-ep8"])
+def test_expert_step_kernel_compiles(v5e, model, rows):
+    """``dgi_moe_gmm_step`` at the three sparse models' published widths
+    (64 experts of 2048 x 1024, 32 held of 2304 x 1024, 16 held of 7680 x
+    2048): the engine's 8 rows and the most the step form takes (128), the
+    three weight blocks of a grid step with their bf16 copies inside the
+    VMEM the call asks for, the grid's first bound read at run time."""
+    cfg = get_model_config(model)
+    e = cfg.num_held_experts or cfg.num_experts
+    h = cfg.hidden_size
+    i = cfg.moe_intermediate_size or cfg.intermediate_size
+    k = cfg.num_experts_per_tok
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    w = lambda kk, n: {"qw": sds((2, e, kk, n), jnp.int8),  # noqa: E731
+                       "scale": sds((2, e, 1, n), jnp.float32)}
+
+    def layer(x, stacked, idx, experts, topv, live):
+        plan = moe_gmm_pallas.step_plan(
+            experts, topv, live, e, moe_gmm_pallas.step_rows(rows, x.dtype))
+        return moe_gmm_pallas.routed_step(
+            x, {}, stacked, idx, plan, jax.nn.silu)
+
+    assert moe_gmm_pallas.takes_step_form(rows, jnp.bfloat16,
+                                          {"we_gate": w(h, i)})
+    jax.jit(layer).lower(
+        sds((rows, h), jnp.bfloat16),
+        {"we_gate": w(h, i), "we_up": w(h, i), "we_down": w(i, h)},
+        sds((), jnp.int32), sds((rows, k), jnp.int32),
+        sds((rows, k), jnp.float32), sds((rows, k), jnp.bool_),
+    ).compile()
+
+
 # --------------------------------------------------------------------- #
 # (b) the whole serving graph, one chip and a model=4 mesh
 # --------------------------------------------------------------------- #
