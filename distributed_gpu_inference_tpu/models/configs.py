@@ -41,6 +41,18 @@ class ModelConfig:
     # OLMoE-style QK-norm: RMSNorm over the whole projected q / k width
     # (one learned vector per layer each), before the head split and RoPE
     qk_norm: bool = False
+    # Qwen3-style QK-norm: RMSNorm over each head's ``head_dim`` values of q
+    # and k (one learned ``head_dim`` vector a layer each), before RoPE
+    qk_norm_per_head: bool = False
+    # learned sparse attention (a lightning indexer on a K/V layer): the
+    # indexer's ``index_num_heads`` query heads of ``index_head_dim`` score
+    # every cached token against ONE index key a token (kept in a pool
+    # beside the K/V pages), and a query attends the ``index_topk`` cached
+    # tokens of largest score (every one while it has no more than that).
+    # 0 = dense attention
+    index_topk: int = 0
+    index_num_heads: int = 0
+    index_head_dim: int = 0
     # Latent attention (MLA): the cache holds one ``kv_lora_rank`` latent and
     # ``qk_rope_head_dim`` rope values a token a layer instead of per-head K
     # and V (models/mla.py). 0 = the K/V layout. No layer of such a model
@@ -101,6 +113,26 @@ class ModelConfig:
             )
         if self.num_experts and self.num_experts_per_tok > self.num_experts:
             raise ValueError("num_experts_per_tok exceeds num_experts")
+        if self.qk_norm and self.qk_norm_per_head:
+            raise ValueError(
+                f"{self.name}: qk_norm (whole width) and qk_norm_per_head "
+                "are two conventions; a layer has one")
+        index = (self.index_topk, self.index_num_heads, self.index_head_dim)
+        if any(index):
+            if not all(index) or self.index_head_dim % 2:
+                raise ValueError(
+                    f"{self.name}: an indexer needs index_topk, "
+                    "index_num_heads and an even index_head_dim")
+            if self.kv_lora_rank:
+                raise ValueError(
+                    f"{self.name}: an indexer over latent pages "
+                    "(kv_lora_rank) is not built: the index-key pool lies "
+                    "beside K/V pages (models/llama.py)")
+            if self.sliding_window is not None:
+                raise ValueError(
+                    f"{self.name}: an indexer with sliding_window is not "
+                    "built: pages that left the window are released, the "
+                    "selection reads every cached token")
         if not self.kv_lora_rank:
             # read by models/mla.py alone: models/llama.py would drop them
             # without a word (a softmax router, every expert held, two
@@ -202,6 +234,16 @@ class ModelConfig:
         return self.held_experts[1] if self.held_experts else self.num_experts
 
     @property
+    def mlp_width(self) -> int:
+        """Width of the K/V recipe's MLP, or of ONE of its experts: a
+        published config that names the experts' width apart
+        (``moe_intermediate_size`` beside an ``intermediate_size`` no layer
+        of a wholly routed model reads) is taken at its word."""
+        if self.num_experts and self.moe_intermediate_size:
+            return self.moe_intermediate_size
+        return self.intermediate_size
+
+    @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
@@ -213,7 +255,7 @@ class ModelConfig:
             return (self.vocab_size + head) * self.hidden_size + sum(
                 self.layer_params(li) for li in range(self.num_layers)
             ) + self.hidden_size
-        h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        h, i, v = self.hidden_size, self.mlp_width, self.vocab_size
         d = self.head_dim
         attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) + (
             self.num_heads * d
@@ -225,10 +267,22 @@ class ModelConfig:
         norms = 2 * h
         if self.qk_norm:
             norms += (self.num_heads + self.num_kv_heads) * d
-        per_layer = attn + mlp + norms
+        if self.qk_norm_per_head:
+            norms += 2 * d
+        per_layer = attn + mlp + norms + self.index_params
         emb = v * h
         head = 0 if self.tie_word_embeddings else v * h
         return emb + self.num_layers * per_layer + head + h
+
+    @property
+    def index_params(self) -> int:
+        """A layer's indexer: query, key and head-weight projections and the
+        key's LayerNorm (weight and bias)."""
+        if not self.index_topk:
+            return 0
+        di = self.index_head_dim
+        return self.hidden_size * (
+            self.index_num_heads * di + di + self.index_num_heads) + 2 * di
 
     def param_bytes(self, dtype_bytes: int = 2) -> int:
         return self.num_params * dtype_bytes
@@ -237,7 +291,9 @@ class ModelConfig:
         if self.latent_kv:
             return self.num_cache_layers * (
                 self.kv_lora_rank + self.qk_rope_head_dim) * dtype_bytes
-        return 2 * self.num_layers * self.num_kv_heads * self.head_dim * dtype_bytes
+        return self.num_layers * (
+            2 * self.num_kv_heads * self.head_dim
+            + (self.index_head_dim if self.index_topk else 0)) * dtype_bytes
 
     def layer_params(self, layer: int) -> int:
         """Parameters layer ``layer`` of a latent-attention model stores
@@ -275,7 +331,7 @@ class ModelConfig:
         """Per-layer weight bytes — the shard planner's unit of placement."""
         if self.latent_kv:
             return self.layer_params(self.num_layers - 1) * dtype_bytes
-        h, i, d = self.hidden_size, self.intermediate_size, self.head_dim
+        h, i, d = self.hidden_size, self.mlp_width, self.head_dim
         attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) + (
             self.num_heads * d
         ) * h
@@ -283,7 +339,7 @@ class ModelConfig:
             mlp = self.num_experts * 3 * h * i + h * self.num_experts
         else:
             mlp = 3 * h * i
-        return (attn + mlp + 2 * h) * dtype_bytes
+        return (attn + mlp + 2 * h + self.index_params) * dtype_bytes
 
 
 def _llama(name: str, **kw) -> ModelConfig:
@@ -428,6 +484,25 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
     # routed experts (sigmoid scores, top-8 normalised and scaled) beside a
     # shared expert, four norms a layer (models/mla.py). One multi-token-
     # prediction layer is published and not loaded.
+    "keye-vl-tiny": _llama(  # test-scale: contexts of 9+ tokens select
+        "keye-vl-tiny", vocab_size=512, hidden_size=64, num_layers=3,
+        num_heads=4, num_kv_heads=2, intermediate_size=96,
+        moe_intermediate_size=32, head_dim=16,
+        max_position_embeddings=1024, rope_theta=10000.0, rms_norm_eps=1e-6,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
+        qk_norm_per_head=True, index_topk=8, index_num_heads=2,
+        index_head_dim=16,
+    ),
+    # Keye-VL-2.0-30B-A3B's language model (the tower is not loaded): 8 of
+    # its 48 layers, one stage of a six-stage pipeline, each layer whole
+    "keye-vl-2.0-30b-a3b-8l": _llama(
+        "keye-vl-2.0-30b-a3b-8l", vocab_size=151936, hidden_size=2048,
+        num_layers=8, num_heads=32, num_kv_heads=4, intermediate_size=6144,
+        moe_intermediate_size=768, head_dim=128, max_position_embeddings=24576, rope_theta=10000000.0,
+        rms_norm_eps=1e-6, num_experts=128, num_experts_per_tok=8,
+        norm_topk_prob=True, qk_norm_per_head=True, index_topk=2048,
+        index_num_heads=16, index_head_dim=64,
+    ),
     "openpangu-ultra-moe-tiny": _llama(  # test-scale, every mechanism
         "openpangu-ultra-moe-tiny", vocab_size=512, hidden_size=64,
         num_layers=3, num_heads=4, num_kv_heads=4, intermediate_size=96,

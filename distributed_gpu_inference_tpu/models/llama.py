@@ -56,7 +56,7 @@ def init_params(
         return mla.init_params(cfg, key, dtype)
     dtype = dtype or jnp.dtype(cfg.dtype)
     h, d = cfg.hidden_size, cfg.head_dim
-    nh, nkv, i = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
+    nh, nkv, i = cfg.num_heads, cfg.num_kv_heads, cfg.mlp_width
     L, v = cfg.num_layers, cfg.vocab_size
     keys = jax.random.split(key, 9)
 
@@ -95,6 +95,8 @@ def init_params(
     if cfg.qk_norm:  # OLMoE: one norm vector over the whole q / k width
         layers["q_norm"] = norm_init((L, nh * d), dtype)
         layers["k_norm"] = norm_init((L, nkv * d), dtype)
+    if cfg.qk_norm_per_head or cfg.index_topk:
+        layers.update(init_index_leaves(cfg, key, dtype))
     if cfg.attention_bias:  # Qwen2-style QKV biases (random init ~ small)
         bkeys = jax.random.split(keys[1], 3)
         params["layers"]["bq"] = _w(bkeys[0], (L, nh * d), nh * d)
@@ -105,6 +107,47 @@ def init_params(
         # embedding, or head/embedding swap bugs become invisible to tests
         params["lm_head"] = _w(keys[8], (v, h), h)
     return params
+
+
+INDEX_KEYS = "ki"      # the index-key pool's name in ``KVPools``
+_NORM_SPREAD = 0.25
+
+
+def init_index_leaves(
+    cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype
+) -> Dict[str, jax.Array]:
+    """The leaves a per-head QK-norm and an indexer add to a layer stack,
+    shared by both inits (``init_params`` and the streamed one in
+    ``models/loader.py``): one ``head_dim`` norm vector a layer for q and
+    for k; the indexer's query projection ``wqi`` (quantized like the other
+    matmul weights), its key projection ``wki`` and head weights ``ww``
+    (64 and 16 columns: under the int8 kernel's 128-column tiles, kept in
+    the activation dtype), and the key's LayerNorm. Norm vectors are drawn
+    ``1 + 0.25 x normal`` and the bias ``0.25 x normal`` so that dropping
+    one shows."""
+    L, h, d = cfg.num_layers, cfg.hidden_size, cfg.head_dim
+    keys = jax.random.split(jax.random.fold_in(key, 0x1D8), 7)
+
+    def vec(k, width, centre):
+        return (centre + _NORM_SPREAD * jax.random.normal(
+            k, (L, width), jnp.float32)).astype(dtype)
+
+    def mat(k, width):
+        return (jax.random.normal(k, (L, h, width), jnp.float32)
+                * h ** -0.5).astype(dtype)
+
+    out: Dict[str, jax.Array] = {}
+    if cfg.qk_norm_per_head:
+        out["q_norm"] = vec(keys[0], d, 1.0)
+        out["k_norm"] = vec(keys[1], d, 1.0)
+    if cfg.index_topk:
+        hi, di = cfg.index_num_heads, cfg.index_head_dim
+        out.update({
+            "wqi": mat(keys[2], hi * di), "wki": mat(keys[3], di),
+            "ww": mat(keys[4], hi),
+            "ki_norm": vec(keys[5], di, 1.0), "ki_bias": vec(keys[6], di, 0.0),
+        })
+    return out
 
 
 def init_kv_pools(
@@ -127,6 +170,11 @@ def init_kv_pools(
     scale per (page, token) shared across KV heads (real = int * scale;
     contract: ``ops.paged_attention_pallas._quantize_token_rows``).
 
+    A model with an indexer (``cfg.index_topk``: learned sparse attention)
+    carries a third pool, ``"ki"`` ``[L, N, Bk, lanes]``: the index key of
+    every cached token in a row's first ``index_head_dim`` lanes
+    (``ops/index_select.py``).
+
     A latent-attention model (``cfg.latent_kv``) has one pool and no head
     axis instead: ``{"ckv": [L, N, Bk, latent + rope]}`` (models/mla.py),
     and beside it, where some layers are linear attention, the state pool
@@ -139,6 +187,14 @@ def init_kv_pools(
     dtype = jnp.dtype(dtype or cfg.dtype)
     shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size, cfg.head_dim)
     pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if cfg.index_topk:
+        # one index key a token a layer, addressed by the same block table
+        # as K/V: a page copy, a prefix hit and a resume bring it
+        from distributed_gpu_inference_tpu.ops.index_select import pool_lanes
+
+        pools[INDEX_KEYS] = jnp.zeros(
+            (cfg.num_layers, num_blocks, block_size,
+             pool_lanes(cfg.index_head_dim)), dtype)
     if dtype == jnp.int8:
         sshape = (cfg.num_layers, num_blocks, block_size, cfg.head_dim)
         pools["k_scale"] = jnp.zeros(sshape, jnp.bfloat16)
@@ -161,6 +217,16 @@ def rms_norm(
     if offset:  # Gemma stores zero-centered norm weights; scale is (1 + w)
         w = 1.0 + w
     return (x * w).astype(dt)
+
+
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float) -> jax.Array:
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    x = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (x * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dt)
 
 
 def _rope_angles(positions: jax.Array, head_dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
@@ -494,13 +560,64 @@ def _in_place_kv(
         token_index=token_index, num_tokens=num_tokens,
     )
 
-    def attn_stacked(q, k_pool, v_pool, layer_idx):
+    def attn_stacked(q, k_pool, v_pool, layer_idx, keep=None):
         return ragged_paged_attention(
             q, k_pool, v_pool, block_tables, positions, kv_lens, block_size,
-            window=cfg.sliding_window, layer_idx=layer_idx,
+            window=cfg.sliding_window, layer_idx=layer_idx, keep=keep,
         )
 
     return plan, attn_stacked
+
+
+class _IndexPlan(NamedTuple):
+    """An indexer's view of a chunk, built once a forward pass: the
+    rotation of its ``index_head_dim``-wide heads and where each token's
+    index key lands in the pool, on the chunk's flat token axis."""
+
+    cos: jax.Array
+    sin: jax.Array
+    scatter: Tuple[jax.Array, jax.Array]    # (page, slot) a token
+
+
+def _index_plan(
+    cfg: ModelConfig, num_blocks: int, block_tables: jax.Array,
+    positions: jax.Array,        # [B, S] the rectangle's (-1 = nothing)
+    rope_positions: jax.Array,   # [B, S], or [1, Tp] of a packed chunk
+    packing: Optional["Packing"], block_size: int,
+) -> _IndexPlan:
+    cos, sin = _rope_angles(jnp.maximum(rope_positions, 0),
+                            cfg.index_head_dim, cfg.rope_theta)
+    if packing is None:
+        scatter = _page_scatter_indices(
+            num_blocks, block_tables, positions, block_size)
+    else:
+        pos, b = rope_positions[0], block_tables.shape[0]
+        valid = (pos >= 0) & (packing.row < b)
+        safe = jnp.where(valid, pos, 0)
+        phys = block_tables[jnp.minimum(packing.row, b - 1),
+                            safe // block_size]
+        scatter = (jnp.where(valid, phys, num_blocks), safe % block_size)
+    return _IndexPlan(cos, sin, scatter)
+
+
+def index_inputs(
+    cfg: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array, proj,
+    index: _IndexPlan,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """What a layer's indexer makes of the layer's normed input ``x [B, S,
+    H]`` (the input q / k / v are projected from): its rotated queries
+    ``[B, S, Hi, Di]``, the chunk's rotated index keys ``[B, S, 1, Di]``
+    (LayerNorm with bias, then all ``Di`` values rotated) and the heads'
+    weights ``[B, S, Hi]`` float32, scaled by ``(Hi * Di) ** -0.5``."""
+    b, s, _ = x.shape
+    hi, di = cfg.index_num_heads, cfg.index_head_dim
+    qi = apply_rope(proj(x, "wqi").reshape(b, s, hi, di), index.cos,
+                    index.sin)
+    kin = layer_norm(proj(x, "wki"), lp["ki_norm"], lp["ki_bias"],
+                     cfg.rms_norm_eps)
+    kin = apply_rope(kin[:, :, None, :], index.cos, index.sin)
+    wts = proj(x, "ww").astype(jnp.float32) * (hi * di) ** -0.5
+    return qi, kin, wts
 
 
 class ChunkOutput(NamedTuple):
@@ -566,6 +683,8 @@ def _layer_step(
                                   # layer_idx) → attn): a multi-token chunk
                                   # writes and reads the STACKED pools
                                   # (``_in_place_kv``)
+    index=None,                   # an indexer's view of the chunk, the same
+                                  # for every layer (``_index_plan``)
 ) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array],
            Tuple[Optional[jax.Array], Optional[Dict[str, jax.Array]],
                  Optional[jax.Array]]]:
@@ -600,6 +719,12 @@ def _layer_step(
     spreads over the ``seq`` mesh axis while KV pages still land in the
     same paged pools decode reads (SURVEY §5.7).
 
+    ``index`` (a model with an indexer): the carry holds the index-key
+    pool as a fifth entry. The chunk's index keys are scattered into it
+    whatever the K/V path, the selection is computed from it
+    (``ops/index_select.select``) and handed to the attention call as
+    ``keep``.
+
     ``unpack``: ``hidden`` is ``[1, Tp, H]``, a round's live tokens packed
     on one axis. q/k/v are gathered into the ``[B, S]`` rectangle (empty
     positions zero, their ``write_positions`` -1) for the page write and
@@ -607,7 +732,8 @@ def _layer_step(
     output is gathered back; everything else runs over ``Tp`` rows. With
     ``in_place`` only q takes the rectangle: the page write gathers K and V
     from the packed axis straight into page-shaped updates."""
-    hidden, k_ent, v_ent, layer_idx = carry
+    hidden, k_ent, v_ent, layer_idx, *more = carry
+    ki_pool = more[0] if more else None
     # int8-KV pools travel as (pool, scale_pool) tuples through the scan
     # carry; bf16 pools stay bare arrays (static structure, zero overhead)
     quant_kv = isinstance(k_ent, tuple)
@@ -636,14 +762,20 @@ def _layer_step(
             q = q + lp["bq"]
             k = k + lp["bk"]
             v = v + lp["bv"]
-        if "q_norm" in lp:  # OLMoE QK-norm: over the whole width, pre-RoPE
+        if cfg.qk_norm:  # OLMoE QK-norm: over the whole width, pre-RoPE
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
             k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         q = q.reshape(b, s, nh, d)
         k = k.reshape(b, s, nkv, d)
         v = v.reshape(b, s, nkv, d)
+        if cfg.qk_norm_per_head:  # Qwen3: over each head's values, pre-RoPE
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
+        keep = None
+        if index is not None:
+            qi, kin, wts = index_inputs(cfg, lp, x, proj, index)
         if unpack is not None:
             to_rect, tok_row, tok_col = unpack
 
@@ -654,6 +786,22 @@ def _layer_step(
             q = rectangle(q)
             if in_place is None:    # the in-place write reads the packed axis
                 k, v = rectangle(k), rectangle(v)
+            if index is not None:
+                qi, wts = rectangle(qi), rectangle(wts)
+
+        if index is not None:
+            from distributed_gpu_inference_tpu.ops import index_select
+
+            with jax.named_scope("dgi_index"):
+                ki_pool = index_select.write_index_keys(
+                    ki_pool, kin.reshape(-1, cfg.index_head_dim), layer_idx,
+                    *index.scatter)
+                keep = index_select.select(
+                    qi, wts, ki_pool, layer_idx, block_tables,
+                    write_positions, kv_lens, cfg.index_topk,
+                    kernels=fused_decode or in_place is not None,
+                )
+        sel = {} if keep is None else {"keep": keep}
 
         if fused_decode:
             from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
@@ -675,7 +823,7 @@ def _layer_step(
                     q, k.astype(k_pool.dtype), v.astype(v_pool.dtype),
                     k_pool, v_pool, layer_idx, block_tables,
                     write_positions, kv_lens, block_size,
-                    window=cfg.sliding_window,
+                    window=cfg.sliding_window, **sel,
                 )
         elif in_place is not None:
             from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
@@ -687,7 +835,7 @@ def _layer_step(
                 k.reshape(-1, nkv, d), v.reshape(-1, nkv, d),
                 k_pool, v_pool, layer_idx, plan,
             )
-            attn = attn_stacked(q, k_pool, v_pool, layer_idx)
+            attn = attn_stacked(q, k_pool, v_pool, layer_idx, **sel)
         else:
             layer_k = lax.dynamic_index_in_dim(k_pool, layer_idx, 0, keepdims=False)
             layer_v = lax.dynamic_index_in_dim(v_pool, layer_idx, 0, keepdims=False)
@@ -743,7 +891,7 @@ def _layer_step(
             elif quant_kv:
                 attn = attn_fn(q, layer_k, layer_v, layer_ks, layer_vs)
             else:
-                attn = attn_fn(q, layer_k, layer_v)
+                attn = attn_fn(q, layer_k, layer_v, **sel)
 
         if unpack is not None:
             attn = attn.at[tok_row, tok_col].get(mode="fill", fill_value=0)
@@ -761,7 +909,8 @@ def _layer_step(
             hidden = hidden + _mlp(mlp_in, proj, cfg.activation)
     k_out = (k_pool, k_scale_pool) if quant_kv else k_pool
     v_out = (v_pool, v_scale_pool) if quant_kv else v_pool
-    return (hidden, k_out, v_out, layer_idx + 1), (
+    return (hidden, k_out, v_out, layer_idx + 1,
+            *(() if ki_pool is None else (ki_pool,))), (
         hidden if emit_hidden else None, moe_stats,
         routing if emit_routing else None,
     )
@@ -863,6 +1012,16 @@ def forward_chunk(
             cfg, kv, block_tables, positions, kv_lens, block_size,
             token_index=to_rect, num_tokens=tp,
         )
+    index = None
+    if cfg.index_topk:
+        if (dense_attn_fn is not None or attn_override is not None
+                or "k_scale" in kv):
+            raise NotImplementedError(
+                "a model with an indexer has no sequence-parallel or "
+                "overridden attention and no int8 pools: the selection is "
+                "computed from its own index-key pool")
+        index = _index_plan(cfg, kv["k"].shape[1], block_tables, positions,
+                            rope_positions, packing, block_size)
     b, s = token_ids.shape
     hidden = embed_tokens(params, token_ids, cfg)
 
@@ -880,12 +1039,13 @@ def forward_chunk(
                 layer_ks, layer_vs,
             )
     else:
-        def attn_fn(q, layer_k, layer_v, layer_ks=None, layer_vs=None):
+        def attn_fn(q, layer_k, layer_v, layer_ks=None, layer_vs=None,
+                    **sel):
             return paged_attention(
                 q, layer_k, layer_v, block_tables, positions, kv_lens,
                 block_size, impl="auto" if pallas else "xla",
                 window=cfg.sliding_window,
-                k_scale=layer_ks, v_scale=layer_vs,
+                k_scale=layer_ks, v_scale=layer_vs, **sel,
             )
 
     scanned, stacked = _split_layers(params["layers"], pallas)
@@ -914,12 +1074,14 @@ def forward_chunk(
         moe_live=rope_positions >= 0,
         emit_routing=collect_routing,
         in_place=in_place,
+        index=index,
     )
     k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
     v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
-    (hidden, k_out, v_out, _), (layer_hs, moe, routing) = lax.scan(
+    (hidden, k_out, v_out, _, *ki_out), (layer_hs, moe, routing) = lax.scan(
         lambda c, lp: step(c, lp),
-        (hidden, k0, v0, jnp.int32(0)),
+        (hidden, k0, v0, jnp.int32(0),
+         *(() if index is None else (kv[INDEX_KEYS],))),
         scanned,
     )
     if moe is not None:
@@ -929,6 +1091,8 @@ def forward_chunk(
          "k_scale": k_out[1], "v_scale": v_out[1]}
         if quant_kv else {"k": k_out, "v": v_out}
     )
+    if ki_out:
+        new_kv[INDEX_KEYS] = ki_out[0]
     features = (
         jnp.concatenate([layer_hs[i] for i in collect_layers], axis=-1)
         if collect_layers is not None else None
@@ -991,6 +1155,9 @@ def forward_tree_chunk(
     """
     from distributed_gpu_inference_tpu.ops.attention import paged_tree_attention
 
+    if cfg.index_topk:
+        raise NotImplementedError(
+            "tree verification over a model with an indexer is not built")
     hidden = embed_tokens(params, token_ids, cfg)
     cos, sin = _rope_angles(
         jnp.maximum(rope_positions, 0), cfg.head_dim, cfg.rope_theta
@@ -1059,9 +1226,10 @@ def forward_hidden_chunk(
     int8 KV pools are fenced (stage pools are bf16/f32 today; a bare-array
     scan carry would silently truncate rows into the int8 pool).
     """
-    if "k_scale" in kv:
+    if "k_scale" in kv or cfg.index_topk:
         raise NotImplementedError(
-            "forward_hidden_chunk over int8 KV pools is not wired"
+            "forward_hidden_chunk over int8 KV pools or an index-key pool "
+            "is not wired"
         )
     safe_pos = jnp.maximum(positions, 0)
     cos, sin = _rope_angles(safe_pos, cfg.head_dim, cfg.rope_theta)

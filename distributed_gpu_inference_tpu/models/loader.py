@@ -221,7 +221,7 @@ def init_quantized_streamed(
         return mla.init_params(cfg, jax.random.PRNGKey(seed), dtype, mode)
     dtype = jnp.dtype(dtype or cfg.dtype)
     h, d = cfg.hidden_size, cfg.head_dim
-    nh, nkv, i = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
+    nh, nkv, i = cfg.num_heads, cfg.num_kv_heads, cfg.mlp_width
     L, v = cfg.num_layers, cfg.vocab_size
 
     root = jax.random.PRNGKey(seed)
@@ -307,6 +307,15 @@ def init_quantized_streamed(
     if cfg.qk_norm:
         layers["q_norm"] = norm_init((L, nh * d), "q_norm")
         layers["k_norm"] = norm_init((L, nkv * d), "k_norm")
+    if cfg.qk_norm_per_head or cfg.index_topk:
+        if mesh is not None:
+            raise ValueError(
+                f"{cfg.name}: a per-head QK-norm or an indexer has no "
+                "sharding rule; the model is one-chip")
+        extra = llama.init_index_leaves(cfg, root, dtype)
+        if "wqi" in extra:
+            extra["wqi"] = quantize_weight(extra["wqi"], mode)
+        layers.update(extra)
     if cfg.attention_bias:
         layers["bq"] = _dense_leaf("bq", (L, nh * d), nh * d)
         layers["bk"] = _dense_leaf("bk", (L, nkv * d), nkv * d)

@@ -138,6 +138,9 @@ def paged_attention(
     window: Optional[int] = None,  # Mistral sliding window (None = full causal)
     k_scale: Optional[jax.Array] = None,   # [N, Bk, D] bf16 — int8 pools
     v_scale: Optional[jax.Array] = None,
+    keep: Optional[jax.Array] = None,      # [B, S, M * Bk] float32 > 0: the
+                                           # context positions a query
+                                           # attends (ops/index_select.py)
 ) -> jax.Array:
     """Attention of a chunk of queries against paged context. → [B, S, Nh, D].
 
@@ -147,6 +150,9 @@ def paged_attention(
     ``window``: query at position p sees context positions (p-window, p].
     ``k_scale``/``v_scale``: int8 pools' per-(page, token) scales — both
     impls dequantize context-sized (Pallas in VMEM, XLA at the gather).
+    ``keep``: a per-query selection of the context (learned sparse
+    attention), applied on top of the causal / in-length mask by every
+    implementation; None: a query attends all it sees.
     """
     if impl == "auto":
         # the Pallas decode kernel needs lane-aligned pages: XLA:TPU stores
@@ -172,7 +178,7 @@ def paged_attention(
 
         return paged_attention_pallas(
             q, k_pool, v_pool, block_tables, positions, kv_lens, block_size,
-            window=window, k_scale=k_scale, v_scale=v_scale,
+            window=window, k_scale=k_scale, v_scale=v_scale, keep=keep,
         )
     if impl in ("ragged", "pallas_mq"):
         # "pallas_mq" is the pre-round-6 name of the small-q path, kept as
@@ -184,11 +190,11 @@ def paged_attention(
 
         return ragged_paged_attention(
             q, k_pool, v_pool, block_tables, positions, kv_lens, block_size,
-            window=window, k_scale=k_scale, v_scale=v_scale,
+            window=window, k_scale=k_scale, v_scale=v_scale, keep=keep,
         )
     return paged_attention_xla(
         q, k_pool, v_pool, block_tables, positions, kv_lens, block_size,
-        window=window, k_scale=k_scale, v_scale=v_scale,
+        window=window, k_scale=k_scale, v_scale=v_scale, keep=keep,
     )
 
 
@@ -237,6 +243,7 @@ def paged_attention_xla(
     window: Optional[int] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    keep: Optional[jax.Array] = None,
 ) -> jax.Array:
     b, s, nh, d = q.shape
     hkv = k_pool.shape[1]
@@ -258,6 +265,8 @@ def paged_attention_xla(
     visible = causal & in_len
     if window is not None:  # Mistral SWA: key must be within (p-window, p]
         visible &= key_pos[:, None, :] > positions[:, :, None] - window
+    if keep is not None:    # a learned selection of what the query sees
+        visible &= keep > 0
     mask = visible[:, None, None, :, :]                         # [B,1,1,S,J]
     scores = jnp.where(mask, scores, _NEG_INF)
 

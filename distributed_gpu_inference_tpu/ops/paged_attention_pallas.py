@@ -125,7 +125,8 @@ def _decode_kernel(
     newv_ref,      # [B, Hkv, D]
     k_hbm,         # [L, N, Hkv, Bk, D] full stacked pool (HBM, aliased)
     v_hbm,         # [L, N, Hkv, Bk, D]
-    *rest,         # [ks_hbm, vs_hbm,] out_ref, ko_hbm, vo_hbm, scratch...
+    *rest,         # [keep_ref,] [ks_hbm, vs_hbm,] out_ref, ko_hbm, vo_hbm,
+                   # scratch...
     batch: int,
     block_size: int,
     pages_per_group: int,
@@ -134,7 +135,13 @@ def _decode_kernel(
     scale: float,
     fused_write: bool,
     quantized: bool,
+    selected: bool = False,
 ):
+    # a learned selection (ops/index_select.py): [1, 1, group] float32 > 0
+    # at the context positions of this group the row's query attends
+    keep_ref = None
+    if selected:
+        keep_ref, *rest = rest
     # int8 pools carry per-(page, token) scale pages ([L, N, Bk, D] bf16,
     # lane-replicated): staged tiles dequantize IN PAGE LAYOUT during the
     # upcast — int8→bf16 is a native VPU convert (unlike fp8, which v5e
@@ -440,6 +447,8 @@ def _decode_kernel(
         valid = (col < kv_len) & (col <= pos)
         if window is not None:
             valid &= col > pos - window
+        if selected:
+            valid &= keep_ref[...] > 0
         scores = jnp.where(valid, scores, _NEG_INF)
 
         m_prev, l_prev = m_scr[...], l_scr[...]
@@ -485,6 +494,7 @@ def _call_decode_kernel(
     interpret: bool,
     k_scale: Optional[jax.Array] = None,   # [L, N, Bk, D] bf16 lane-replicated
     v_scale: Optional[jax.Array] = None,   # (int8 pools; see paged_attention_pallas)
+    keep: Optional[jax.Array] = None,      # [B, 1, M * Bk] float32 selection
 ) -> Tuple[jax.Array, ...]:
     # → (out, k_pool, v_pool) — plus (k_scale, v_scale) when quantized
     b, s, nh, d = q.shape
@@ -550,6 +560,16 @@ def _call_decode_kernel(
         pl.BlockSpec(memory_space=pltpu.HBM),
         pl.BlockSpec(memory_space=pltpu.HBM),
     ]
+    selected = keep is not None
+    if selected:
+        # a group's slice of the row's selection rides the grid like q
+        gsz = gp * block_size
+        keep = jnp.pad(keep.astype(jnp.float32), (
+            (0, 0), (0, 0), (0, max_groups * gsz - keep.shape[2])))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, gsz), lambda i, j, *_refs: (i, 0, j),
+            memory_space=pltpu.VMEM,
+        ))
     if quantized:
         in_specs += [
             pl.BlockSpec(memory_space=pltpu.HBM),   # k_scale
@@ -599,6 +619,7 @@ def _call_decode_kernel(
         scale=d**-0.5,
         fused_write=fused_write,
         quantized=quantized,
+        selected=selected,
     )
     operands = [
         block_tables.astype(jnp.int32),
@@ -610,6 +631,8 @@ def _call_decode_kernel(
         jnp.ones((1,), jnp.int32),    # init_flag
         q, new_k, new_v, k_pool, v_pool,
     ]
+    if selected:
+        operands.append(keep)
     out_shape = [
         jax.ShapeDtypeStruct((b, 1, nh, d), q.dtype),
         jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
@@ -621,13 +644,14 @@ def _call_decode_kernel(
     # the fused write's quantization scales land in place
     aliases = {10: 1, 11: 2}
     if quantized:
+        first = len(operands)       # after the selection, where there is one
         operands += [k_scale.astype(jnp.bfloat16),
                      v_scale.astype(jnp.bfloat16)]
         out_shape += [
             jax.ShapeDtypeStruct(k_scale.shape, jnp.bfloat16),
             jax.ShapeDtypeStruct(v_scale.shape, jnp.bfloat16),
         ]
-        aliases.update({12: 3, 13: 4})
+        aliases.update({first: 3, first + 1: 4})
     results = pl.pallas_call(
         kernel,
         out_shape=out_shape,
@@ -658,6 +682,8 @@ def paged_decode_attention_fused(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,   # [L, N, Bk, D] bf16 (int8 pools)
     v_scale: Optional[jax.Array] = None,
+    keep: Optional[jax.Array] = None,      # [B, 1, M * Bk] float32 > 0: the
+                                           # context the row's query attends
 ):
     """The per-layer decode step: write this step's K/V rows into their page
     slots AND attend over the updated paged context, in one kernel with the
@@ -670,7 +696,7 @@ def paged_decode_attention_fused(
         q, new_k[:, 0], new_v[:, 0], k_pool, v_pool, layer_idx,
         block_tables, pos, pos, kv_lens, block_size, window,
         fused_write=True, interpret=interpret,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, keep=keep,
     )
 
 
@@ -1009,8 +1035,8 @@ def _ragged_kernel(
                    # tiled over the GQA slots in q_ref's row order
     k_hbm,         # [L, N, Hkv, Bk, D] full stacked pool (HBM, read in
     v_hbm,         # place: a page DMA is k_hbm.at[layer, page])
-    *rest,         # [ks_hbm, vs_hbm,] out_ref, kbuf, vbuf, [ksbuf, vsbuf,]
-                   # sems, [ssems,] m_scr, l_scr, acc_scr
+    *rest,         # [keep_ref,] [ks_hbm, vs_hbm,] out_ref, kbuf, vbuf,
+                   # [ksbuf, vsbuf,] sems, [ssems,] m_scr, l_scr, acc_scr
     rows: int,
     q_tiles: int,
     q_tile: int,
@@ -1020,7 +1046,13 @@ def _ragged_kernel(
     window: Optional[int],
     scale: float,
     quantized: bool,
+    selected: bool = False,
 ):
+    # a learned selection (ops/index_select.py): [1, T, group] float32 > 0
+    # at the context positions of this group each query of the tile attends
+    keep_ref = None
+    if selected:
+        keep_ref, *rest = rest
     if quantized:
         (_ks_in, _vs_in, out_ref, kbuf, vbuf, ksbuf, vsbuf,
          sems, ssems, m_scr, l_scr, acc_scr) = rest
@@ -1177,6 +1209,12 @@ def _ragged_kernel(
         valid = (col < kv_len) & (col <= pos_b)
         if window is not None:
             valid &= col > pos_b - window
+        if selected:
+            # the tile's T rows repeat once a GQA slot, as its positions do
+            kept = keep_ref[0] > 0                  # [T, gsz]
+            if qpk > 1:
+                kept = jnp.concatenate([kept] * qpk, axis=0)
+            valid &= kept[None]
         scores = jnp.where(valid, scores, _NEG_INF)
 
         # softmax state keeps a size-1 minor dim ([Hkv, qpk*T, 1]) so every
@@ -1223,6 +1261,8 @@ def ragged_paged_attention(
     v_scale: Optional[jax.Array] = None,   # ([L, N, Bk, D] with layer_idx)
     layer_idx: Optional[jax.Array] = None,  # scalar int32: the pools are
                               # the stacked ones and this is the layer
+    keep: Optional[jax.Array] = None,       # [B, S, M * Bk] float32 > 0:
+                              # the context each query attends
 ) -> jax.Array:
     """Ragged paged attention: ONE kernel invocation over a flattened token
     batch in which each row carries its own (block table, query-span
@@ -1274,6 +1314,8 @@ def ragged_paged_attention(
         positions = jnp.pad(
             positions, ((0, 0), (0, s_pad - s)), constant_values=-1
         )
+        if keep is not None:
+            keep = jnp.pad(keep, ((0, 0), (0, s_pad - s), (0, 0)))
     qt = s_pad // t
     rows = b * qt
     # [B, S, Nh, D] → [R, Hkv, qpk*T, D] with the query index t fastest
@@ -1312,6 +1354,22 @@ def ragged_paged_attention(
         pl.BlockSpec(memory_space=pltpu.HBM),   # k_pool
         pl.BlockSpec(memory_space=pltpu.HBM),   # v_pool
     ]
+    selected = keep is not None
+    if selected:
+        gsz = gp * block_size
+        keep_r = jnp.pad(keep.astype(jnp.float32), (
+            (0, 0), (0, 0), (0, max_groups * gsz - keep.shape[2]))
+        ).reshape(rows, t, max_groups * gsz)
+
+        def keep_block(i, j, _bt, lens, qmax, *_refs):
+            # a cell past the tile's last live group names that group's
+            # block again, so nothing of a dead cell is fetched
+            needed = jnp.minimum(qmax[i] + 1, lens[i // qt])
+            live = jnp.minimum(pl.cdiv(needed, gsz), max_groups)
+            return i, 0, jnp.clip(j, 0, jnp.maximum(live - 1, 0))
+
+        in_specs.append(pl.BlockSpec(
+            (1, t, gsz), keep_block, memory_space=pltpu.VMEM))
     if quantized:
         in_specs += [
             pl.BlockSpec(memory_space=pltpu.HBM),   # k_scale
@@ -1357,6 +1415,7 @@ def ragged_paged_attention(
         window=window,
         scale=d**-0.5,
         quantized=quantized,
+        selected=selected,
     )
     # block tables and kv lens stay per-SEQUENCE ([B, M] / [B]): q-tile
     # rows index them via row // q_tiles inside the kernel. Repeating them
@@ -1372,6 +1431,8 @@ def ragged_paged_attention(
         jnp.ones((1,), jnp.int32),    # init_flag
         q_r, pos_q, k_pool, v_pool,
     ]
+    if selected:
+        operands.append(keep_r)
     if quantized:
         operands += [k_scale.astype(jnp.bfloat16),
                      v_scale.astype(jnp.bfloat16)]
@@ -1436,6 +1497,7 @@ def paged_attention_pallas(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,   # [N, Bk, D] bf16 lane-replicated
     v_scale: Optional[jax.Array] = None,
+    keep: Optional[jax.Array] = None,      # [B, 1, M * Bk] float32 selection
 ) -> jax.Array:
     """Read-only single-layer variant (micro-benchmarks, parity tests, and
     callers that manage KV writes themselves).
@@ -1457,5 +1519,6 @@ def paged_attention_pallas(
         fused_write=False, interpret=interpret,
         k_scale=None if k_scale is None else k_scale[None],
         v_scale=None if v_scale is None else v_scale[None],
+        keep=keep,
     )
     return results[0]

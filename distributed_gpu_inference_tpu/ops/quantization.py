@@ -48,7 +48,11 @@ QUANT_KEYS = frozenset(
      # (models/mla.py); W_UK / W_UV stay bf16 like the router
      "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down",
      # gated delta-rule layers (models/kda.py): q|k|v and the low-rank gates
-     "wqkv", "w_fa", "w_fb", "w_ga", "w_gb"}
+     "wqkv", "w_fa", "w_fb", "w_ga", "w_gb",
+     # an indexer's query projection (models/llama.py); its key and head-
+     # weight projections are 64 and 16 columns wide, under the int8
+     # kernel's 128-column tiles, and stay in the activation dtype
+     "wqi"}
 )
 
 _FP8_MAX = 448.0  # float8_e4m3 largest finite value
@@ -129,7 +133,7 @@ def matmul(x: jax.Array, w: Any, pallas: bool = True) -> jax.Array:
 STACKED_KEYS = frozenset(
     {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
      "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down",
-     "wqkv", "w_fa", "w_fb", "w_ga", "w_gb"}
+     "wqkv", "w_fa", "w_fb", "w_ga", "w_gb", "wqi"}
 )
 # the MoE expert weights: kept whole for the routed layer's grouped-matmul
 # kernel (ops/moe_gmm_pallas.py), scanned where the layer runs in XLA

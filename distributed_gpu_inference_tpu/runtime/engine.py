@@ -75,6 +75,18 @@ _BIG_BUDGET = 1 << 30
 _QUANT_DEVICE_BUILD_LIMIT = 8 * 1024**3
 
 
+def _index_work(before: int, n: int, topk: int) -> Tuple[int, int, int]:
+    """``n`` consecutive queries of a row that holds ``before`` tokens ahead
+    of them: query ``j`` (1-based) sees ``before + j`` tokens and its
+    selection keeps at most ``topk`` of them (ties beyond that are not
+    counted) → (pairs seen, pairs kept, queries that see at most ``topk``
+    and so select nothing)."""
+    dense = min(max(topk - before, 0), n)
+    return (n * before + n * (n + 1) // 2,
+            dense * before + dense * (dense + 1) // 2 + (n - dense) * topk,
+            dense)
+
+
 # the routed expert layers' counters, in the order a round returns them:
 # layer calls that held a live token, live (token, expert) pairs, rows the
 # grouped matmul ran (tile padding included), experts with at least one row
@@ -455,6 +467,8 @@ class TPUEngine:
         self._seq_axis = 1
         if self.model_cfg.latent_kv:
             self._refuse_latent(mesh)
+        if self.model_cfg.index_topk:
+            self._refuse_indexed(mesh)
         if mesh is not None:
             sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
             tp = sizes.get("model", 1)
@@ -639,9 +653,23 @@ class TPUEngine:
             ),
             # what a cached token is: per-head K and V, or one latent;
             # hybrid: latent pages beside a state row a sequence
+            # kv+index: K/V pages and an index key a token beside them
             "kv_layout": "hybrid" if self._state_rows
-            else "latent" if self.model_cfg.latent_kv else "kv",
+            else "latent" if self.model_cfg.latent_kv
+            else "kv+index" if self.model_cfg.index_topk else "kv",
         }
+        if self.model_cfg.index_topk:
+            # the index-key pool; what the scans' rows selected from (host
+            # arithmetic at a scan's commit: a row-step with ``c`` cached
+            # tokens attends min(c, topk) of them, and one with at most
+            # topk selects nothing); the (query, cached token) pairs the
+            # plain ragged rounds scored and kept (at a round's build)
+            self.stats.update({
+                "index_pool_bytes": int(self.kv[llama.INDEX_KEYS].nbytes),
+                "index_row_steps_scan": 0, "index_context_tokens_scan": 0,
+                "index_selected_tokens_scan": 0, "index_dense_rows_scan": 0,
+                "index_pairs_ragged": 0, "index_selected_pairs_ragged": 0,
+            })
         self._moe_names = (
             _MOE_SHARE_COUNTERS if self.model_cfg.latent_kv
             else _MOE_COUNTERS
@@ -714,6 +742,37 @@ class TPUEngine:
             raise ValueError(
                 f"{name}: a state row is whole on one chip (no sequence "
                 "sharding of the linear-attention layers)")
+
+    def _refuse_indexed(self, mesh: Optional[Any]) -> None:
+        """A model with an indexer (learned sparse attention) keeps an
+        index-key pool beside its K/V pages and computes each query's
+        selection from it (ops/index_select.py). What would serve it
+        without that pool, or without the selection, refuses it here, when
+        the engine is configured."""
+        name = self.model_cfg.name
+        if mesh is not None:
+            raise ValueError(
+                f"{name}: a model with an indexer is served on one chip "
+                "(no sharding rule for the index-key pool, and the "
+                "selection runs in kernels a mesh refuses)")
+        if self.cfg.speculative is not None:
+            raise ValueError(
+                f"{name}: speculative decoding verifies a token tree; "
+                "tree attention takes no per-query selection")
+        if self.cfg.spill_host_blocks > 0 or \
+                self.cfg.spill_remote_store is not None:
+            raise ValueError(
+                f"{name}: the spill tiers carry K/V pages, not the index "
+                "keys that belong to them")
+        if self.kv_dtype.itemsize != jnp.dtype(self.dtype).itemsize:
+            raise ValueError(
+                f"{name}: the index-key pool is served in the activation "
+                f"dtype, not kv_cache_dtype={self.cfg.kv_cache_dtype!r} "
+                "(an int8 / fp8 index key is not built)")
+        if self.cfg.kv_seq_sharded:
+            raise ValueError(
+                f"{name}: a query's selection reads every cached index "
+                "key; the pool is not sharded over a sequence axis")
 
     # -------------------------------------------------- sharded weight init
 
@@ -2603,6 +2662,18 @@ class TPUEngine:
         for name, v in held.items():
             self.stats[f"{name}_ragged"] += v
 
+    def _count_index_ragged(self, sp: flight.span,
+                            segments: Sequence[Tuple[int, int]]) -> None:
+        """What a packed round's indexer scored and kept, from ``(cached
+        tokens before it, tokens)`` of each row."""
+        pairs = kept = ctx = 0
+        for off, m in segments:
+            seen, selected, _ = _index_work(off, m, self.model_cfg.index_topk)
+            pairs, kept, ctx = pairs + seen, kept + selected, ctx + off + m
+        sp.set(index_context_tokens=ctx)
+        self.stats["index_pairs_ragged"] += pairs
+        self.stats["index_selected_pairs_ragged"] += kept
+
     def _count_moe(self, sp: Optional[flight.span], kind: str,
                    moe: Sequence[np.ndarray]) -> None:
         """A round's routed-expert counters (``_MOE_COUNTERS``, summed over
@@ -2823,6 +2894,10 @@ class TPUEngine:
                 ctx += adm.off + m
             self.stats["mla_pairs_ragged"] += pairs
             self.stats["mla_context_tokens_ragged"] += ctx
+        if "index_pairs_ragged" in self.stats:
+            self._count_index_ragged(
+                sp, [(int(self._kv_lens[i]), 1) for i in kept]
+                + [(adm.off, len(piece)) for adm, piece, _ in ready])
         if self._state_rows:
             self._count_kda_ragged(
                 sp, [1] * len(kept) + [len(piece) for _, piece, _ in ready])
@@ -3622,6 +3697,7 @@ class TPUEngine:
         with flight.span("dgi.engine.decode_multi.commit", st,
                          "round_commit_s"):
             out: Dict[int, List[int]] = {}
+            index_ctx = 0
             for i, s in enumerate(self.slots):
                 if not scan.active_mask[i] or s is None:
                     continue
@@ -3634,6 +3710,9 @@ class TPUEngine:
                     st["mla_row_steps_scan"] += n
                     st["mla_context_tokens_scan"] += (
                         n * int(self._kv_lens[i]) + n * (n + 1) // 2)
+                if "index_row_steps_scan" in st:
+                    index_ctx += self._count_index_scan(
+                        int(self._kv_lens[i]), len(toks))
                 # each emitted token corresponds to one scan step that fed
                 # (and thus committed) the previous pending token
                 self._kv_lens[i] += len(toks)
@@ -3648,10 +3727,25 @@ class TPUEngine:
                 commit = toks if s.finish_reason is None else toks[:-1]
                 self.manager.commit_tokens(s.seq_id, commit)
                 self._maybe_release_window(i)
+        if index_ctx and sp is not None:
+            sp.set(index_context_tokens=index_ctx)
         if self._unread is None:
             # nothing went out behind it: the chip waited for this commit
             st["round_host_exposed_s"] += time.perf_counter() - t0
         return out
+
+    def _count_index_scan(self, cached: int, n: int) -> int:
+        """A row's ``n`` scan steps over ``cached`` tokens: step ``t``
+        attended its cache and the token it wrote. Returns the context
+        tokens counted."""
+        st = self.stats
+        seen, selected, dense = _index_work(cached, n,
+                                            self.model_cfg.index_topk)
+        st["index_row_steps_scan"] += n
+        st["index_dense_rows_scan"] += dense
+        st["index_context_tokens_scan"] += seen
+        st["index_selected_tokens_scan"] += selected
+        return seen
 
     def _build_decode_multi(self, num_steps: int,
                             prev: Optional[_UnreadScan] = None

@@ -105,6 +105,11 @@ def require_kv_pages(engine: "TPUEngine") -> None:
         raise ValueError(
             f"{engine.model_cfg.name}: the KV handoff and migration wire "
             "carries K/V pages; this engine caches latent pages")
+    if getattr(getattr(engine, "model_cfg", None), "index_topk", 0):
+        raise ValueError(
+            f"{engine.model_cfg.name}: the KV handoff and migration wire "
+            "carries K/V pages, not the index keys this engine caches "
+            "beside them")
 
 
 def export_slot_kv(engine: "TPUEngine", slot: int) -> KVHandoff:

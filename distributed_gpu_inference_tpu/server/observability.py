@@ -420,10 +420,35 @@ class Metrics:
         self.worker_kv_layout = Gauge(
             "worker_kv_layout",
             "1 for what the worker's cache holds a token: kv (per-head K "
-            "and V pages), latent (one compressed latent and its rope "
-            "key) or hybrid (latent pages in some layers, a fixed-size "
-            "state row a sequence in the others)", ["worker", "layout"],
+            "and V pages), kv+index (those and an indexer's key beside "
+            "them), latent (one compressed latent and its rope key) or "
+            "hybrid (latent pages in some layers, a fixed-size state row "
+            "a sequence in the others)", ["worker", "layout"],
             registry=r)
+        # a model with an indexer (learned sparse attention): its index-key
+        # pool, what its scans selected from and what its rounds scored
+        self.worker_index_pool_bytes = Gauge(
+            "worker_index_pool_bytes",
+            "Bytes of the index-key pool: one key a cached token a layer, "
+            "addressed by the K/V pages' block table", ["worker"],
+            registry=r)
+        self.worker_index = {
+            name: Counter(f"worker_index_{name}_total", help_, ["worker"],
+                          registry=r)
+            for name, help_ in (
+                ("row_steps_scan", "Row-steps the decode scans took"),
+                ("context_tokens_scan", "Cached tokens the scans' row-steps "
+                 "could attend (selected / context = the share kept)"),
+                ("selected_tokens_scan", "Cached tokens the scans' "
+                 "row-steps attended: at most topk a row-step"),
+                ("dense_rows_scan", "Row-steps with at most topk cached "
+                 "tokens, which select nothing"),
+                ("pairs_ragged", "(query, cached token) pairs the plain "
+                 "ragged rounds' indexer scored, causal"),
+                ("selected_pairs_ragged", "Pairs of those the selection "
+                 "kept: at most topk a query"),
+            )
+        }
         self.worker_mla = {
             name: Counter(f"worker_mla_{name}_total", help_, ["worker"],
                           registry=r)
@@ -861,12 +886,13 @@ class MetricsCollector:
                     1.0 if name == path else 0.0)
         layout = stats.get("kv_layout")
         if isinstance(layout, str):
-            for name in ("kv", "latent", "hybrid"):
+            for name in ("kv", "kv+index", "latent", "hybrid"):
                 self.metrics.worker_kv_layout.labels(worker, name).set(
                     1.0 if name == layout else 0.0)
         for key, gauge in (
                 ("state_pool_bytes", self.metrics.worker_state_pool_bytes),
-                ("state_rows", self.metrics.worker_state_rows)):
+                ("state_rows", self.metrics.worker_state_rows),
+                ("index_pool_bytes", self.metrics.worker_index_pool_bytes)):
             if key in stats:
                 gauge.labels(worker).set(float(stats[key] or 0.0))
         prev = self._batcher_prev.setdefault(worker, {})
@@ -935,6 +961,10 @@ class MetricsCollector:
                 if key[4:] not in self.metrics.worker_mla:
                     continue
                 metric = self.metrics.worker_mla[key[4:]].labels(worker)
+            elif key.startswith("index_"):
+                if key[6:] not in self.metrics.worker_index:
+                    continue
+                metric = self.metrics.worker_index[key[6:]].labels(worker)
             elif key in self.metrics.worker_state:
                 metric = self.metrics.worker_state[key].labels(worker)
             else:

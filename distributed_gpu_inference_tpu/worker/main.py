@@ -328,14 +328,15 @@ class Worker:
                 core = getattr(eng, "engine", None)
                 if self.config.role != "hybrid" and core is not None and \
                         getattr(core, "stats", {}).get("kv_layout") in (
-                            "latent", "hybrid"):
+                            "latent", "hybrid", "kv+index"):
                     # a prefill / decode role hands K/V pages to a peer
                     # (runtime/kv_handoff.py require_kv_pages): refused
                     # here, where the worker is configured
                     raise EngineLoadError(
                         f"role {self.config.role!r}: the PD handoff carries "
                         f"K/V pages, {cfg.model} caches latent pages (and, "
-                        "a hybrid model, state rows)")
+                        "a hybrid model, state rows) or index keys beside "
+                        "its K/V pages")
                 self.engines[task_type] = eng
                 loaded.append(task_type)
             except (EngineLoadError, KeyError) as exc:
@@ -527,9 +528,11 @@ class Worker:
             # hybrid engine's state pool and what its kernels were handed
             for k in es:
                 if k.startswith(("moe_", "mla_", "kda_")) or k in (
-                        "state_binds", "prefix_hits_without_state"):
+                        "state_binds", "prefix_hits_without_state") or (
+                        k.startswith("index_") and k != "index_pool_bytes"):
                     out[k] = out.get(k, 0) + int(es[k] or 0)
-                elif k in ("state_pool_bytes", "state_rows"):
+                elif k in ("state_pool_bytes", "state_rows",
+                           "index_pool_bytes"):
                     out[k] = int(es[k])
             if s.get("avg_occupancy") is not None:
                 out["avg_occupancy"] = round(

@@ -622,3 +622,80 @@ def test_hybrid_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch, tp,
     assert f"[{tokens},{cfg.num_experts}," not in text
     assert f"bf16[{cfg.num_held_experts},{hid},{mi}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
+
+
+# --------------------------------------------------------------------- #
+# (e) a model with an indexer (learned sparse attention): Keye-VL-2.0's
+# language model at its published widths and the served 24,576 positions
+# --------------------------------------------------------------------- #
+
+KEYE = "keye-vl-2.0-30b-a3b-8l"
+KEYE_CTX = 24576
+
+
+@pytest.mark.parametrize("s", [1, 256])
+def test_selection_kernels_compile(v5e, s):
+    """The selection's two kernels at the served context: 8 rows of a scan
+    step under their ``_step`` names, a rectangle of 8 x 256 queries under
+    the round's."""
+    from distributed_gpu_inference_tpu.ops import index_select
+
+    cfg = get_model_config(KEYE)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    hi, di = cfg.index_num_heads, cfg.index_head_dim
+
+    def run(qi, wts, pool, tables, pos, lens):
+        return index_select.select(qi, wts, pool, jnp.int32(3), tables, pos,
+                                   lens, cfg.index_topk, kernels=True)
+
+    lowered = jax.jit(run).lower(
+        sds((BATCH, s, hi, di), jnp.bfloat16), sds((BATCH, s, hi), jnp.float32),
+        sds((cfg.num_layers, 1 + BATCH * (KEYE_CTX // 16), 16,
+             index_select.pool_lanes(di)), jnp.bfloat16),
+        sds((BATCH, KEYE_CTX // 16), jnp.int32), sds((BATCH, s), jnp.int32),
+        sds((BATCH,), jnp.int32))
+    tail = "_step" if s == 1 else ""
+    assert _kernels(lowered) == {"dgi_index_score" + tail,
+                                 "dgi_index_threshold" + tail}
+    lowered.compile()
+
+
+@pytest.mark.parametrize("tp,s", [(None, 1), (128, 128), (264, 256),
+                                  (2048, 256)],
+                         ids=["scan-step", "Tp128", "Tp264", "Tp2048"])
+def test_indexed_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch, tp,
+                                                       s):
+    """A decode step and the packed round at three rungs, 24,576 positions a
+    row. The selection runs in its own kernels and the attention kernels
+    take it; K, V and the index keys are written and read in place in the
+    stacked pools (no array of a pool layer's shape); nothing sorts a row
+    of scores (the router's top-8 of 128 is the one sort a layer has); the
+    128 experts go through the grouped-matmul kernel (no dequantised
+    copy)."""
+    cfg = get_model_config(KEYE)
+    lowered = _forward_chunk_lowered(cfg, s, None, v5e, tp=tp, ctx=KEYE_CTX)
+    found = _kernels(lowered)
+    want = {"dgi_paged_decode", "dgi_moe_gmm_step", "dgi_index_score_step",
+            "dgi_index_threshold_step"} if tp is None else {
+        "dgi_paged_write", "dgi_ragged_attention", "dgi_moe_gmm",
+        "dgi_index_score", "dgi_index_threshold"}
+    assert want <= found and found <= want | {"dgi_qmm"}, found
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    blocks = 1 + BATCH * (KEYE_CTX // 16)
+    L, nkv, d = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    for whole, layer in ((f"[{L},{blocks},{nkv},16,{d}]",
+                          f"[{blocks},{nkv},16,{d}]"),
+                         (f"[{L},{blocks},16,128]", f"[{blocks},16,128]")):
+        assert whole in text
+        assert layer not in text.replace(whole, "")
+    for line in text.splitlines():
+        if " sort(" in line:
+            assert str(KEYE_CTX) not in line, line
+    e, hid, mi = cfg.num_experts, cfg.hidden_size, cfg.mlp_width
+    for dt in ("bf16", "f32"):
+        assert f"{dt}[{e},{hid},{mi}]" not in text
+        assert f"{dt}[{e},{mi},{hid}]" not in text
+    # a round's largest temporaries are the rectangle's scores and mask
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (3 if tp == 2048 else 1) * 1024 ** 3
