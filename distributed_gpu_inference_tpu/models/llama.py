@@ -637,6 +637,11 @@ class ChunkOutput(NamedTuple):
     # [L, T, k] the experts every token was routed to in every layer
     # (collect_routing; the benchmark's comparison with its reference)
     routing: Optional[jax.Array] = None
+    # a one-token chunk of a model with an indexer: the cached tokens the
+    # decode kernel fetches for the rows' selections, summed over layers
+    # (int32 scalar: ops/paged_attention_pallas.fetched_tokens; the kernel's
+    # rule, whichever form of attention the chunk ran) — None otherwise
+    index_fetched: Optional[jax.Array] = None
 
 
 class Packing(NamedTuple):
@@ -802,6 +807,13 @@ def _layer_step(
                     kernels=fused_decode or in_place is not None,
                 )
         sel = {} if keep is None else {"keep": keep}
+        fetched = None
+        if keep is not None and unpack is None and s == 1:
+            from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+                fetched_tokens,
+            )
+
+            fetched = fetched_tokens(keep, block_size)
 
         if fused_decode:
             from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
@@ -912,7 +924,7 @@ def _layer_step(
     return (hidden, k_out, v_out, layer_idx + 1,
             *(() if ki_pool is None else (ki_pool,))), (
         hidden if emit_hidden else None, moe_stats,
-        routing if emit_routing else None,
+        routing if emit_routing else None, fetched,
     )
 
 
@@ -1078,14 +1090,17 @@ def forward_chunk(
     )
     k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
     v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
-    (hidden, k_out, v_out, _, *ki_out), (layer_hs, moe, routing) = lax.scan(
-        lambda c, lp: step(c, lp),
-        (hidden, k0, v0, jnp.int32(0),
-         *(() if index is None else (kv[INDEX_KEYS],))),
-        scanned,
-    )
+    (hidden, k_out, v_out, _, *ki_out), (layer_hs, moe, routing, fetched) = \
+        lax.scan(
+            lambda c, lp: step(c, lp),
+            (hidden, k0, v0, jnp.int32(0),
+             *(() if index is None else (kv[INDEX_KEYS],))),
+            scanned,
+        )
     if moe is not None:
         moe = {name: jnp.sum(v) for name, v in moe.items()}
+    if fetched is not None:
+        fetched = jnp.sum(fetched)
     new_kv = (
         {"k": k_out[0], "v": v_out[0],
          "k_scale": k_out[1], "v_scale": v_out[1]}
@@ -1102,6 +1117,7 @@ def forward_chunk(
         return ChunkOutput(
             hidden=hidden, kv=new_kv, logits=None,
             features=features, moe=moe, routing=routing,
+            index_fetched=fetched,
         )
     if last_only and packing is not None:
         logits_in = jnp.take(hidden[0], packing.last, axis=0)[:, None]
@@ -1119,7 +1135,8 @@ def forward_chunk(
     with jax.named_scope("dgi_head"):
         logits = project_logits(cfg, params, logits_in)
     return ChunkOutput(hidden=hidden, kv=new_kv, logits=logits,
-                       features=features, moe=moe, routing=routing)
+                       features=features, moe=moe, routing=routing,
+                       index_fetched=fetched)
 
 
 def forward_tree_chunk(
@@ -1187,7 +1204,7 @@ def forward_tree_chunk(
     )
     k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
     v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
-    (hidden, k_out, v_out, _), (layer_hs, _, _) = lax.scan(
+    (hidden, k_out, v_out, _), (layer_hs, *_) = lax.scan(
         lambda c, lp: step(c, lp), (hidden, k0, v0, jnp.int32(0)),
         scanned,
     )

@@ -16,7 +16,11 @@ decode KV path:
   single-layer API made XLA copy the layer slice (pool_bytes/L per layer per
   pool per step) just to pass it in.
 - Walks only the **live** page groups of each sequence — the grid is
-  ``(B, max_groups)`` and dead cells skip in a few cycles,
+  ``(B, max_groups)`` and dead cells skip in a few cycles; under a learned
+  selection (``keep``) only the pages that hold a token the query attends,
+  which the wrapper sorts to the front of a table of their own
+  (``_selected_pages``): the kernel is bound by its DMA descriptors, not by
+  bytes, so a page is the unit and nothing is tested a page in the kernel,
 - DMAs each KV page HBM→VMEM exactly once (whole ``[Hkv, Bk, D]`` pages stay
   contiguous) and runs flash-style online softmax per page group,
 - **Pipelines DMA across the whole (sequence, group) walk** — while group g
@@ -52,6 +56,7 @@ puts the chunk's rows into the stacked pools by layer index, and
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -70,11 +75,20 @@ WRITE_KERNEL_NAME = "dgi_paged_write"
 # VMEM budget for the four KV staging buffers (2 pools x 2 slots); the rest
 # of VMEM stays free for q/out blocks and compute temporaries.
 _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+# tokens of a page group: of a dense walk, and of a walk under a learned
+# selection, which is over the pages that hold a selected token and takes
+# the width the kernel alone ran fastest at (PERF.md section 6, PR 46)
+_GROUP_TOKENS = 512
+_SELECTED_GROUP_TOKENS = 2048
+# pages of a group's DMA loop unrolled together under a selection (a wide
+# group's loop unrolled whole would be three times 120 descriptor pairs)
+_SELECTED_UNROLL = 8
 
 
 def _pages_per_group(
     block_size: int, hkv: int, head_dim: int, itemsize: int, max_pages: int,
     staging_pages: int = 0, scale_page_bytes: int = 0,
+    selected: bool = False,
 ) -> int:
     """Pages DMA'd per loop iteration.
 
@@ -85,12 +99,68 @@ def _pages_per_group(
     static table width. ``scale_page_bytes``: per-page bytes of the int8
     path's bf16 scale buffers ([Bk, D] per page, staged AND double-buffered
     alongside the data pages) — at MQA-ish hkv they rival the int8 data
-    pages, so they must count against the same budget."""
+    pages, so they must count against the same budget. ``selected``: the
+    walk is over the pages a selection kept a token of, in wider groups."""
     page_bytes = hkv * block_size * head_dim * itemsize + scale_page_bytes
     budget = _VMEM_BUDGET_BYTES - staging_pages * page_bytes
     g = max(1, budget // (4 * page_bytes))
-    g = min(g, max(512 // block_size, 1), max_pages)
+    tokens = _SELECTED_GROUP_TOKENS if selected else _GROUP_TOKENS
+    g = min(g, max(tokens // block_size, 1), max_pages)
+    if selected:
+        # a group's slice of the selection is a block of the grid: whole
+        # 128-lane tiles where the budget, not the target, set the width
+        lanes = 128 // math.gcd(128, block_size)
+        if g > lanes:
+            g -= g % lanes
     return max(g, 1)
+
+
+def fetched_tokens(keep: jax.Array, block_size: int) -> jax.Array:
+    """Tokens the decode kernel fetches of one layer's pool for ``keep [B,
+    1, J]`` (int32 scalar): the pages that hold a token a row's query
+    attends, whole."""
+    b, _, j = keep.shape
+    hit = jnp.max(keep.reshape(b, j // block_size, block_size), axis=-1) > 0
+    return block_size * jnp.sum(hit, dtype=jnp.int32)
+
+
+def _selected_pages(
+    keep: jax.Array,          # [B, 1, J] float32 > 0: what the query attends
+    block_tables: jax.Array,  # [B, M]
+    positions: jax.Array,     # [B]
+    kv_lens: jax.Array,       # [B]
+    block_size: int,
+    window: Optional[int],
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A row's walk under a selection, laid out for the decode kernel: the
+    pages that hold a token the row's query attends, in context order, the
+    others after them. → (``pages [B, M]`` int32 physical ids; ``keep [B, 1,
+    M x Bk]`` float32 in that order, with what ``positions``, ``kv_lens``
+    and ``window`` hide already taken out; ``count [B]``: the pages to
+    fetch). One stable sort of ``[B, M]`` words a call (a page's ``keep``
+    rides it as a bit mask): the kernel then walks ``count`` pages with no
+    test of its own a page — a page DMA costs the scalar core what a branch
+    does — and masks a token's score by ``keep`` alone."""
+    b, m = block_tables.shape
+    if block_size > 32:
+        raise ValueError(
+            f"block_size {block_size}: a page's selection is one 32-bit word")
+    col = jnp.arange(m * block_size, dtype=jnp.int32)[None, :]
+    seen = (col <= positions[:, None]) & (col < kv_lens[:, None])
+    if window is not None:
+        seen &= col > positions[:, None] - window
+    keep = jnp.pad(keep[:, 0], ((0, 0), (0, m * block_size - keep.shape[2])))
+    kept = ((keep > 0) & seen).reshape(b, m, block_size)
+    slots = jnp.arange(block_size, dtype=jnp.uint32)
+    bits = jnp.sum(kept.astype(jnp.uint32) << slots, axis=-1,
+                   dtype=jnp.uint32)
+    _, pages, bits = lax.sort(
+        ((bits == 0).astype(jnp.int32), block_tables.astype(jnp.int32), bits),
+        dimension=1, is_stable=True, num_keys=1,
+    )
+    keep = ((bits[:, :, None] >> slots) & 1).astype(jnp.float32)
+    return (pages, keep.reshape(b, 1, m * block_size),
+            jnp.sum(bits != 0, axis=1, dtype=jnp.int32))
 
 
 def _quantize_token_rows(x: jax.Array, axes) -> Tuple[jax.Array, jax.Array]:
@@ -119,13 +189,13 @@ def _decode_kernel(
     layer_ref,     # [1] int32 layer index into the stacked pools
     bidx_ref,      # [1] int32 current double-buffer slot
     init_ref,      # [1] int32 1 until the first live chunk issues its DMA
-    # blocked operands
-    q_ref,         # [1, 1, Nh, D] — this sequence's query heads
-    newk_ref,      # [B, Hkv, D] new K rows (VMEM; whole-batch block)
-    newv_ref,      # [B, Hkv, D]
-    k_hbm,         # [L, N, Hkv, Bk, D] full stacked pool (HBM, aliased)
-    v_hbm,         # [L, N, Hkv, Bk, D]
-    *rest,         # [keep_ref,] [ks_hbm, vs_hbm,] out_ref, ko_hbm, vo_hbm,
+    *rest,         # [pages_ref,] then the blocked operands:
+                   # q_ref [1, 1, Nh, D] — this sequence's query heads
+                   # newk_ref, newv_ref [B, Hkv, D] new rows (VMEM; whole
+                   #   batch)
+                   # k_hbm, v_hbm [L, N, Hkv, Bk, D] full stacked pools (HBM,
+                   #   aliased)
+                   # [keep_ref,] [ks_hbm, vs_hbm,] out_ref, ko_hbm, vo_hbm,
                    # scratch...
     batch: int,
     block_size: int,
@@ -137,9 +207,14 @@ def _decode_kernel(
     quantized: bool,
     selected: bool = False,
 ):
-    # a learned selection (ops/index_select.py): [1, 1, group] float32 > 0
-    # at the context positions of this group the row's query attends
-    keep_ref = None
+    # a learned selection (ops/index_select.py), as ``_selected_pages`` lays
+    # it out: scalar prefetch [B, M] int32, the row's pages that hold a
+    # token its query attends (``lens_ref`` counts their tokens), and [1,
+    # 1, group] float32 > 0 at the tokens of this group's pages it attends
+    pages_ref = keep_ref = None
+    if selected:
+        pages_ref, *rest = rest
+    q_ref, newk_ref, newv_ref, k_hbm, v_hbm, *rest = rest
     if selected:
         keep_ref, *rest = rest
     # int8 pools carry per-(page, token) scale pages ([L, N, Bk, D] bf16,
@@ -312,51 +387,69 @@ def _decode_kernel(
                         for c in copies:
                             c.wait()
 
-    def start_dma(s, j, slot):
-        """Issue the page DMAs of group j of sequence s into buffer slot.
-        Reads go through the ALIASED output refs so they observe the token
-        writes above."""
-        for p in range(gp):  # static unroll: G paired page DMAs
-            idx = jnp.minimum(j * gp + p, max_pages - 1)  # clamp, mask later
-            page = bt_ref[jnp.clip(s, 0, batch - 1), idx]
-            # whole-page slice [Hkv, Bk, D]: contiguous, tiling-safe
-            pltpu.make_async_copy(
-                ko_hbm.at[layer, page], kbuf.at[slot, p], sems.at[0, slot, p]
-            ).start()
-            pltpu.make_async_copy(
-                vo_hbm.at[layer, page], vbuf.at[slot, p], sems.at[1, slot, p]
-            ).start()
-            if quantized:
-                # via the ALIASED outputs: this step's written scales must
-                # be visible to its own attention, like the data pages
-                pltpu.make_async_copy(
-                    kso_hbm.at[layer, page], ksbuf.at[slot, p],
-                    ssems.at[0, slot, p],
-                ).start()
-                pltpu.make_async_copy(
-                    vso_hbm.at[layer, page], vsbuf.at[slot, p],
-                    ssems.at[1, slot, p],
-                ).start()
+    def group_dma(s, j, slot, wait):
+        """Start, or wait for, the page DMAs of group j of sequence s into
+        buffer slot: the next ``gp`` pages of its table, or under a selection
+        of the pages it attends a token of. Reads go through the ALIASED
+        output refs so they observe the token writes above (the written
+        scales of an int8 pool like its data pages)."""
+        if selected:
+            row = jnp.clip(s, 0, batch - 1)
+            # past the row's last page: that page again (``keep`` holds
+            # zeros there), never one the selection dropped
+            last = jnp.maximum(lax.div(lens_ref[row], block_size) - 1, 0)
 
-    def wait_dma(s, j, slot):
-        for p in range(gp):
-            idx = jnp.minimum(j * gp + p, max_pages - 1)
-            page = bt_ref[jnp.clip(s, 0, batch - 1), idx]
-            pltpu.make_async_copy(
-                ko_hbm.at[layer, page], kbuf.at[slot, p], sems.at[0, slot, p]
-            ).wait()
-            pltpu.make_async_copy(
-                vo_hbm.at[layer, page], vbuf.at[slot, p], sems.at[1, slot, p]
-            ).wait()
+        def copies(p):
+            at = j * gp + p
+            if selected:
+                page = pages_ref[row, jnp.minimum(at, last)]
+            else:
+                idx = jnp.minimum(at, max_pages - 1)   # clamp, mask later
+                page = bt_ref[jnp.clip(s, 0, batch - 1), idx]
+            # a semaphore a page; under a selection one a pool and slot,
+            # which every copy of the group signals and every wait draws
+            # its own bytes from (a group of 2,048 tokens would hold more
+            # semaphores than the core has): the slot is whole once all
+            # are drawn
+            sem = 0 if selected else p
+            # whole-page slice [Hkv, Bk, D]: contiguous, tiling-safe
+            out = [
+                pltpu.make_async_copy(
+                    ko_hbm.at[layer, page], kbuf.at[slot, p],
+                    sems.at[0, slot, sem]),
+                pltpu.make_async_copy(
+                    vo_hbm.at[layer, page], vbuf.at[slot, p],
+                    sems.at[1, slot, sem]),
+            ]
             if quantized:
-                pltpu.make_async_copy(
-                    kso_hbm.at[layer, page], ksbuf.at[slot, p],
-                    ssems.at[0, slot, p],
-                ).wait()
-                pltpu.make_async_copy(
-                    vso_hbm.at[layer, page], vsbuf.at[slot, p],
-                    ssems.at[1, slot, p],
-                ).wait()
+                out += [
+                    pltpu.make_async_copy(
+                        kso_hbm.at[layer, page], ksbuf.at[slot, p],
+                        ssems.at[0, slot, sem]),
+                    pltpu.make_async_copy(
+                        vso_hbm.at[layer, page], vsbuf.at[slot, p],
+                        ssems.at[1, slot, sem]),
+                ]
+            return out
+
+        # static unroll: G paired page DMAs. Under a selection, whose group
+        # is up to 128 pages wide, a rolled loop over runs of a few
+        step = _SELECTED_UNROLL if selected and gp % _SELECTED_UNROLL == 0 \
+            else gp
+
+        def run(c, carry):
+            for p in range(step):
+                for dma in copies(c * step + p):
+                    if wait:
+                        dma.wait()
+                    else:
+                        dma.start()
+            return carry
+
+        if step == gp:
+            run(0, 0)
+        else:
+            lax.fori_loop(0, gp // step, run, 0)
 
     def next_chunk(s, j):
         """Grid-order successor of live chunk (s, j): (s, j+1) within the
@@ -388,7 +481,7 @@ def _decode_kernel(
         # very first live chunk of the whole walk: nothing prefetched it
         @pl.when(init_ref[0] == 1)
         def _():
-            start_dma(b, i, slot)
+            group_dma(b, i, slot, wait=False)
 
         init_ref[0] = 0
 
@@ -398,11 +491,11 @@ def _decode_kernel(
 
         @pl.when(nb < batch)
         def _():
-            start_dma(nb, ni, 1 - slot)
+            group_dma(nb, ni, 1 - slot, wait=False)
 
         bidx_ref[0] = 1 - slot
 
-        wait_dma(b, i, slot)
+        group_dma(b, i, slot, wait=True)
 
         @pl.when(i == start_b)
         def _():
@@ -443,12 +536,16 @@ def _decode_kernel(
             qf, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * scale                                         # [Hkv, qpk, gsz]
-        col = i * gsz + lax.broadcasted_iota(jnp.int32, (hkv, qpk, gsz), 2)
-        valid = (col < kv_len) & (col <= pos)
-        if window is not None:
-            valid &= col > pos - window
         if selected:
-            valid &= keep_ref[...] > 0
+            # the group's pages lie in the order of ``keep``, which has the
+            # position, the length and the window in it already
+            valid = jnp.broadcast_to(keep_ref[...] > 0, (hkv, qpk, gsz))
+        else:
+            col = i * gsz + lax.broadcasted_iota(
+                jnp.int32, (hkv, qpk, gsz), 2)
+            valid = (col < kv_len) & (col <= pos)
+            if window is not None:
+                valid &= col > pos - window
         scores = jnp.where(valid, scores, _NEG_INF)
 
         m_prev, l_prev = m_scr[...], l_scr[...]
@@ -528,9 +625,17 @@ def _call_decode_kernel(
         n_stage = max(1, min(b, _VMEM_BUDGET_BYTES // 2 // (2 * page_bytes)))
     else:
         n_stage = 1
+    selected = keep is not None
+    lens = kv_lens
+    if selected:
+        # the walk is over the pages the selection kept a token of
+        pages, keep, count = _selected_pages(
+            keep, block_tables, positions, kv_lens, block_size, window)
+        lens = count * block_size
     gp = _pages_per_group(
         block_size, hkv, d, k_pool.dtype.itemsize, m,
         staging_pages=2 * n_stage, scale_page_bytes=scale_page_bytes,
+        selected=selected,
     )
     max_groups = -(-m // gp)
 
@@ -560,11 +665,22 @@ def _call_decode_kernel(
         pl.BlockSpec(memory_space=pltpu.HBM),
         pl.BlockSpec(memory_space=pltpu.HBM),
     ]
-    selected = keep is not None
+    scalars = [
+        block_tables.astype(jnp.int32),
+        lens.astype(jnp.int32),
+        positions.astype(jnp.int32),
+        write_positions.astype(jnp.int32),
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
+        jnp.zeros((1,), jnp.int32),   # buffer_index
+        jnp.ones((1,), jnp.int32),    # init_flag
+    ]
     if selected:
+        # the pages in the order of the walk: a second table in SMEM beside
+        # the block table, which the fused write still reads
+        scalars.append(pages)
         # a group's slice of the row's selection rides the grid like q
         gsz = gp * block_size
-        keep = jnp.pad(keep.astype(jnp.float32), (
+        keep = jnp.pad(keep, (
             (0, 0), (0, 0), (0, max_groups * gsz - keep.shape[2])))
         in_specs.append(pl.BlockSpec(
             (1, 1, gsz), lambda i, j, *_refs: (i, 0, j),
@@ -583,9 +699,10 @@ def _call_decode_kernel(
             pltpu.VMEM((2, gp, block_size, d), jnp.bfloat16),    # ksbuf
             pltpu.VMEM((2, gp, block_size, d), jnp.bfloat16),    # vsbuf
         ]
-    scratch += [pltpu.SemaphoreType.DMA((2, 2, gp))]             # sems
+    n_sems = 1 if selected else gp
+    scratch += [pltpu.SemaphoreType.DMA((2, 2, n_sems))]         # sems
     if quantized:
-        scratch += [pltpu.SemaphoreType.DMA((2, 2, gp))]         # ssems
+        scratch += [pltpu.SemaphoreType.DMA((2, 2, n_sems))]     # ssems
     scratch += [
         pltpu.SemaphoreType.DMA((4 if quantized else 2, b)),     # wsems
         pltpu.VMEM((n_stage, hkv, block_size, d), k_pool.dtype),
@@ -603,7 +720,7 @@ def _call_decode_kernel(
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=len(scalars),
         grid=(b, max_groups),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -621,16 +738,7 @@ def _call_decode_kernel(
         quantized=quantized,
         selected=selected,
     )
-    operands = [
-        block_tables.astype(jnp.int32),
-        kv_lens.astype(jnp.int32),
-        positions.astype(jnp.int32),
-        write_positions.astype(jnp.int32),
-        jnp.asarray(layer_idx, jnp.int32).reshape(1),
-        jnp.zeros((1,), jnp.int32),   # buffer_index
-        jnp.ones((1,), jnp.int32),    # init_flag
-        q, new_k, new_v, k_pool, v_pool,
-    ]
+    operands = [*scalars, q, new_k, new_v, k_pool, v_pool]
     if selected:
         operands.append(keep)
     out_shape = [
@@ -638,11 +746,12 @@ def _call_decode_kernel(
         jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
         jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
     ]
-    # operand order: 7 scalar-prefetch args, then q, new_k, new_v,
-    # k_pool (idx 10), v_pool (idx 11) → aliased to outputs 1, 2;
-    # quantized adds scale pools (idx 12, 13) aliased to outputs 3, 4 so
-    # the fused write's quantization scales land in place
-    aliases = {10: 1, 11: 2}
+    # operand order: the scalar-prefetch args (7, and the selection's
+    # pages), then q, new_k, new_v, k_pool, v_pool → the pools aliased to
+    # outputs 1, 2; quantized adds scale pools (after the selection, where
+    # there is one) aliased to outputs 3, 4 so the fused write's
+    # quantization scales land in place
+    aliases = {len(scalars) + 3: 1, len(scalars) + 4: 2}
     if quantized:
         first = len(operands)       # after the selection, where there is one
         operands += [k_scale.astype(jnp.bfloat16),

@@ -103,11 +103,16 @@ _MOE_COUNTERS = ("layer_calls", "assignments", "rows_dispatched",
 _MOE_SHARE_COUNTERS = _MOE_COUNTERS + ("pairs_routed",)
 
 
-def _moe_vector(moe: Dict[str, Any]) -> Any:
-    """``ChunkOutput.moe`` as one int32 vector, so that a round brings its
-    counters back in one transfer."""
+def _moe_vector(out: Any) -> Any:
+    """A ``ChunkOutput``'s ``moe`` as one int32 vector, so that a round
+    brings its counters back in one transfer; after them, where a scan step
+    of a model with an indexer has one, its ``index_fetched``."""
+    moe = out.moe
     names = _MOE_SHARE_COUNTERS if "pairs_routed" in moe else _MOE_COUNTERS
-    return jnp.stack([moe[name] for name in names]).astype(jnp.int32)
+    held = [moe[name] for name in names]
+    if out.index_fetched is not None:
+        held.append(out.index_fetched)
+    return jnp.stack(held).astype(jnp.int32)
 
 
 def _resolve_kv_dtype(kv_cache_dtype: Optional[str], activation_dtype) -> Any:
@@ -670,6 +675,12 @@ class TPUEngine:
                 "index_selected_tokens_scan": 0, "index_dense_rows_scan": 0,
                 "index_pairs_ragged": 0, "index_selected_pairs_ragged": 0,
             })
+            if self.model_cfg.num_experts and self.mesh is None:
+                # what the decode kernel fetched for those selections: the
+                # pages that hold a selected token, whole, a row-step, mean
+                # over the layers (each layer selects its own; counted on
+                # the device, read with the scan's tokens)
+                self.stats["index_fetched_tokens_scan"] = 0
         self._moe_names = (
             _MOE_SHARE_COUNTERS if self.model_cfg.latent_kv
             else _MOE_COUNTERS
@@ -1321,9 +1332,10 @@ class TPUEngine:
                     new_lens = jnp.where(done, lens, lens + 1)
                     new_last = jnp.where(done, last, toks)
                     return (out.kv, new_last, new_lens, new_done, new_emit,
-                            moe + _moe_vector(out.moe)), emitted
+                            moe + _moe_vector(out)), emitted
 
-                moe0 = jnp.zeros((len(self._moe_names),), jnp.int32)
+                moe0 = jnp.zeros(
+                    (len(self._moe_names) + bool(cfg.index_topk),), jnp.int32)
                 (kv, last, lens, _done, _, moe), emitted = jax.lax.scan(
                     step, (kv, core["last"], core["lens"], ~active,
                            jnp.zeros_like(core["lens"]), moe0),
@@ -1350,7 +1362,7 @@ class TPUEngine:
                 core = dict(core)
                 core["last"] = jnp.where(sampled, toks, core["last"])
                 core["lens"] = jnp.where(sampled, lens_after, core["lens"])
-                return out.kv, core, toks, _moe_vector(out.moe)
+                return out.kv, core, toks, _moe_vector(out)
 
             self._decode_multi_fn = jax.jit(
                 decode_multi_counted, static_argnames=("num_steps", "mode"),
@@ -3688,6 +3700,12 @@ class TPUEngine:
                 raise
         t0 = time.perf_counter()
         self._count_moe(sp, "scan", moe)
+        if "index_fetched_tokens_scan" in st and moe:
+            # the vector's last entry, summed over the layers
+            fetched = int(moe[0][-1]) // self.model_cfg.num_layers
+            st["index_fetched_tokens_scan"] += fetched
+            if sp is not None:
+                sp.set(index_fetched_tokens=fetched)
         if self._state_rows:
             # a live row's step went through every linear-attention layer
             steps = int((emitted >= 0).sum()) * self.model_cfg.num_kda_layers

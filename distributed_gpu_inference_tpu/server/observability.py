@@ -443,6 +443,9 @@ class Metrics:
                  "row-steps attended: at most topk a row-step"),
                 ("dense_rows_scan", "Row-steps with at most topk cached "
                  "tokens, which select nothing"),
+                ("fetched_tokens_scan", "Cached tokens the decode kernel "
+                 "fetched for the scans' selections: the pages that hold "
+                 "a selected token, mean over the layers"),
                 ("pairs_ragged", "(query, cached token) pairs the plain "
                  "ragged rounds' indexer scored, causal"),
                 ("selected_pairs_ragged", "Pairs of those the selection "

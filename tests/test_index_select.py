@@ -1,8 +1,10 @@
 """The selection of learned sparse attention (``ops/index_select.py``) and
 the attention kernels that take it: ``S_t`` equal to the benchmark
 reference's on float32 inputs (a planted tie included), the kernels in
-interpret mode against their XLA forms, and the served layer on the kernel
-path (interpret mode) against the reference's full forward pass."""
+interpret mode against their XLA forms, the served layer on the kernel
+path (interpret mode) against the reference's full forward pass, and the
+decode kernel's walk under a selection: the pages that hold a selected
+token and no other, a dense caller's program as it was."""
 
 import sys
 from pathlib import Path
@@ -217,3 +219,211 @@ def test_the_references_set_on_float32_inputs():
     got = np.asarray(ix.keep_from_scores(scores, 8))[0] > 0
     assert np.array_equal(got, np.asarray(want))
     assert (got.sum(-1)[8:] >= 8).all() and got.sum(-1)[3] == 4
+
+
+# --------------------------------------------------------------------------
+# the decode kernel under a selection: of a row's table it fetches the pages
+# that hold a token the query attends, and no other
+# --------------------------------------------------------------------------
+
+BK, HKV, NH, HD, PAGES, ROWS = 16, 2, 4, 16, 6, 3
+
+
+def _rows(lens, chosen):
+    """``keep [ROWS, 1, PAGES * BK]`` with 1 at each row's ``chosen``."""
+    keep = np.zeros((ROWS, 1, PAGES * BK), np.float32)
+    for r, at in enumerate(chosen):
+        keep[r, 0, list(at)] = 1.0
+    return np.asarray(lens, np.int32), keep
+
+
+def _empty_pages():
+    # row 0: pages 1, 3 and 4 hold nothing selected; row 1: pages 0 and 2
+    # (of its four); row 2: the first and the last token it sees, pages 1
+    # to 3 between them empty
+    return _rows([90, 50, 70], [(3, 5, 40, 89), (17, 30, 49), (0, 69)])
+
+
+def _ties_at_the_threshold():
+    # eleven scores equal to the 8th largest: all are kept, across pages
+    rng = np.random.default_rng(3)
+    lens = np.asarray([90, 64, 33], np.int32)
+    scores = rng.normal(size=(ROWS, 1, PAGES * BK)).astype(np.float32)
+    col = np.arange(PAGES * BK)
+    for r in range(ROWS):
+        kth = np.sort(scores[r, 0, :lens[r]])[-TOPK]
+        scores[r, 0, rng.choice(lens[r], 11, replace=False)] = kth
+    scores = np.where(col < lens[:, None, None], scores, -np.inf)
+    keep = np.asarray(ix.keep_from_scores(jnp.asarray(scores), TOPK))
+    assert (keep.sum(-1) > TOPK).all()
+    return lens, keep
+
+
+def _short_long_inactive():
+    # at most topk tokens: the row keeps all it sees; a row past topk; a
+    # row that is not decoding (position -1: nothing kept, nothing fetched)
+    lens, keep = _rows([7, 90, 0], [range(7), (1, 20, 21, 22, 50, 70, 88, 89),
+                                    ()])
+    return lens, keep
+
+
+def _written_token_alone(selected):
+    # the step's token lands at position 64, the first slot of page 4,
+    # which holds nothing else the query attends
+    def case():
+        return _rows([65, 41, 9], [
+            (2, 30, 60) + ((64,) if selected else ()),
+            (0, 40), range(9)])
+    return case
+
+
+DECODE_CASES = {
+    # name: (lens and keep, fused write, tokens of a page group or None for
+    # the rule's own: the whole table here)
+    "empty_pages": (_empty_pages, False, None),
+    "ties_at_the_threshold": (_ties_at_the_threshold, False, None),
+    "short_long_inactive": (_short_long_inactive, False, None),
+    "written_token_alone_selected": (_written_token_alone(True), True, None),
+    "written_token_alone_dropped": (_written_token_alone(False), True, None),
+    # a table wider than one group, the last group partly past kv_lens
+    "three_groups": (_empty_pages, False, 32),
+    "two_groups": (_ties_at_the_threshold, True, 64),
+}
+
+
+def _poisoned(pool, tables, keep, fill):
+    """``pool [L, N, ...]`` with ``fill`` in every page (of every layer) but
+    layer 1's pages that a row's selection keeps a token of."""
+    out = np.full_like(pool, fill)
+    hit = keep[:, 0].reshape(ROWS, PAGES, BK).any(-1)
+    for r in range(ROWS):
+        out[1, tables[r, hit[r]]] = pool[1, tables[r, hit[r]]]
+    return out
+
+
+@pytest.mark.parametrize("name", DECODE_CASES)
+def test_the_decode_kernel_fetches_only_the_pages_that_hold_a_selected_token(
+        name, monkeypatch):
+    build, fused, group = DECODE_CASES[name]
+    if group is not None:
+        monkeypatch.setattr(pp, "_SELECTED_GROUP_TOKENS", group)
+    lens, keep = build()
+    rng = np.random.default_rng(7)
+    n = ROWS * PAGES + 1
+    tables = (1 + rng.permutation(ROWS * PAGES)).reshape(ROWS, PAGES) \
+        .astype(np.int32)
+    kp = rng.normal(size=(2, n, HKV, BK, HD)).astype(np.float32)
+    vp = rng.normal(size=(2, n, HKV, BK, HD)).astype(np.float32)
+    q = rng.normal(size=(ROWS, 1, NH, HD)).astype(np.float32)
+    new_k = rng.normal(size=(ROWS, 1, HKV, HD)).astype(np.float32)
+    new_v = rng.normal(size=(ROWS, 1, HKV, HD)).astype(np.float32)
+    pos = (lens - 1)[:, None].astype(np.int32)
+
+    def written(pool, new):
+        """The pool after the step's tokens are in their slots (layer 1)."""
+        out = pool.copy()
+        for r in np.flatnonzero(lens):
+            page, slot = tables[r, pos[r, 0] // BK], pos[r, 0] % BK
+            out[1, page, :, slot] = new[r, 0]
+        return out
+
+    def run(kp, vp):
+        args = [jnp.asarray(x) for x in (tables, pos, lens)]
+        if not fused:
+            out = pp.paged_attention_pallas(
+                jnp.asarray(q), jnp.asarray(kp[1]), jnp.asarray(vp[1]), *args,
+                BK, interpret=True, keep=jnp.asarray(keep))
+            return np.asarray(out), kp, vp
+        out, k_out, v_out = pp.paged_decode_attention_fused(
+            jnp.asarray(q), jnp.asarray(new_k), jnp.asarray(new_v),
+            jnp.asarray(kp), jnp.asarray(vp), jnp.int32(1), *args, BK,
+            interpret=True, keep=jnp.asarray(keep))
+        return np.asarray(out), np.asarray(k_out), np.asarray(v_out)
+
+    got, k_out, v_out = run(kp, vp)
+    k_want, v_want = (written(kp, new_k), written(vp, new_v)) if fused \
+        else (kp, vp)
+    want = paged_attention_xla(
+        jnp.asarray(q), jnp.asarray(k_want[1]), jnp.asarray(v_want[1]),
+        jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(lens), BK,
+        keep=jnp.asarray(keep))
+    assert np.abs(got - np.asarray(want)).max() < 1e-5
+    np.testing.assert_array_equal(k_out, k_want)
+    np.testing.assert_array_equal(v_out, v_want)
+    # no page the selection dropped is read: NaN, or any other value, in
+    # every page without a selected token leaves the output as it was, bit
+    # for bit, and the pool as it was but for the written token
+    for fill in (np.nan, -3.0e4):
+        k_bad = _poisoned(kp, tables, keep, fill)
+        v_bad = _poisoned(vp, tables, keep, fill)
+        again, k_out, v_out = run(k_bad, v_bad)
+        np.testing.assert_array_equal(again, got)
+        if fused:
+            np.testing.assert_array_equal(k_out, written(k_bad, new_k))
+            np.testing.assert_array_equal(v_out, written(v_bad, new_v))
+
+
+def _primitives(jaxpr, out=None):
+    """Every equation's primitive, sub-jaxprs (the kernel's body, its
+    branches) walked in place."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, out)
+    return out
+
+
+
+# (operands of the kernel's call, equations, digest of their primitives)
+DENSE_DIGEST = (12, 1778, "43a53e548f11f967")
+
+
+def test_a_call_without_a_selection_keeps_its_group_width_and_its_jaxpr():
+    """Everything the selection added to ``dgi_paged_decode`` sits behind
+    ``keep``: a dense caller's group is the 512 tokens it was (less where
+    the staging budget or the table is smaller), and its program is the one
+    it was: the count and a digest of its primitives in order, taken from
+    the tree before the change (the printed jaxprs of the two trees were
+    compared whole, bf16 and int8 pools, read-only and fused, blocks of 16
+    and 32: identical)."""
+    import hashlib
+
+    assert pp._pages_per_group(16, 8, 128, 2, 2048) == 32
+    assert pp._pages_per_group(16, 4, 128, 2, 1536, staging_pages=16) == 32
+    assert pp._pages_per_group(32, 8, 128, 2, 1024) == 16
+    assert pp._pages_per_group(16, 8, 128, 2, 20) == 20
+    assert pp._pages_per_group(16, 16, 256, 2, 2048, staging_pages=32) == 8
+    assert pp._pages_per_group(16, 1, 128, 1, 2048, staging_pages=16,
+                               scale_page_bytes=4096) == 32
+    # under a selection: 2,048 tokens, cut to whole 128-lane tiles of the
+    # selection's block where the budget sets the width
+    assert pp._pages_per_group(16, 8, 128, 2, 2048, selected=True) == 64
+    assert pp._pages_per_group(16, 4, 128, 2, 1536, staging_pages=16,
+                               selected=True) == 120
+    assert pp._pages_per_group(16, 2, 16, 4, 6, selected=True) == 6
+
+    b, m, hkv, nh, d, bk = 3, 40, 2, 4, 128, 16
+    pool = jnp.zeros((2, b * m + 1, hkv, bk, d), jnp.bfloat16)
+    q = jnp.zeros((b, 1, nh, d), jnp.bfloat16)
+    new = jnp.zeros((b, 1, hkv, d), jnp.bfloat16)
+    tables = jnp.zeros((b, m), jnp.int32)
+    pos, lens = jnp.zeros((b, 1), jnp.int32), jnp.ones((b,), jnp.int32)
+
+    def digest(**kw):
+        jaxpr = jax.make_jaxpr(lambda: pp.paged_decode_attention_fused(
+            q, new, new, pool, pool, jnp.int32(1), tables, pos, lens, bk,
+            **kw))().jaxpr
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        names = _primitives(jaxpr)
+        return (len(call.invars), len(names),
+                hashlib.sha256("\n".join(names).encode()).hexdigest()[:16])
+
+    assert digest() == DENSE_DIGEST
+    keep = jnp.ones((b, 1, m * bk), jnp.float32)
+    operands, count, _ = digest(keep=keep)
+    assert operands == DENSE_DIGEST[0] + 2 and count != DENSE_DIGEST[1]
+

@@ -360,6 +360,51 @@ def test_engine_rounds_follow_the_reference_and_count():
     assert st["index_context_tokens_scan"] == (41 + 42 + 43 + 44) + (7 + 8 + 9)
 
 
+def test_a_scan_counts_the_pages_its_selections_keep():
+    """``index_fetched_tokens_scan``: what the decode kernel fetches for the
+    scans' selections, the pages that hold a selected token, whole, mean
+    over the layers: counted on the device from the served ``keep``, here
+    from the reference's ``S_t`` of the same queries."""
+    eng = _engine()
+    mc = eng.model_cfg
+    cfg = published(mc)
+    prompts = [_prompt(150), _prompt(5)]
+    slots, first = _admit(eng, prompts, [6, 16])
+    scan = eng.decode_multi(4)
+    # step t of a row's scan: the query at the row's last cached position
+    queries = [(len(p) + len(first[s]) - 1, len(scan[s]))
+               for p, s in zip(prompts, slots)]
+    seqs = [(list(p) + first[s] + scan[s])[:-1]
+            for p, s in zip(prompts, slots)]
+    dims, pages = reference.dims(cfg), []
+
+    def tap(l, n, w, x):
+        lo, steps = queries[n]
+        _, keep = reference.attend(
+            dims, reference.project(dims, w, x), jnp.int32(lo), steps)
+        keep = np.asarray(keep)
+        keep = np.pad(keep, ((0, 0), (0, -keep.shape[1] % BLOCK)))
+        pages.append(int(keep.reshape(steps, -1, BLOCK).any(-1).sum()))
+
+    reference.forward(cfg, reference.FromTree(eng.params), seqs, tap=tap)
+    st = eng.get_stats()
+    assert st["index_row_steps_scan"] == sum(n for _, n in queries) == 8
+    assert st["index_fetched_tokens_scan"] \
+        == BLOCK * sum(pages) // mc.num_layers
+    rounded = sum(-(-(lo + 1 + t) // BLOCK) * BLOCK
+                  for lo, n in queries for t in range(n))
+    assert st["index_selected_tokens_scan"] \
+        <= st["index_fetched_tokens_scan"] <= rounded
+    assert rounded - st["index_context_tokens_scan"] < 8 * BLOCK
+    # the long row's 8 tokens of ~150 leave pages out
+    assert st["index_fetched_tokens_scan"] < rounded - 8 * BLOCK
+    # a routed model without an indexer has no such counter
+    assert "index_fetched_tokens_scan" not in TPUEngine(
+        get_model_config("mixtral-tiny"),
+        EngineConfig(max_batch_size=2, max_seq_len=64, block_size=BLOCK),
+    ).stats
+
+
 def test_a_prefix_hit_and_a_page_copy_bring_the_index_keys(chain):
     """The second request's prompt is a hit on the first one's pages: its
     index keys come with them (the model keeps its prefix cache), and a
@@ -466,6 +511,7 @@ def test_the_index_counters_reach_the_metrics_endpoint():
         "kv_layout": "kv+index", "index_pool_bytes": 603979776,
         "index_row_steps_scan": 640, "index_context_tokens_scan": 12800000,
         "index_selected_tokens_scan": 1310720, "index_dense_rows_scan": 3,
+        "index_fetched_tokens_scan": 7475200,
         "index_pairs_ragged": 5000000, "index_selected_pairs_ragged": 524288})
     text = mc.metrics.render().decode()
     if "worker_kv_layout" not in text:
@@ -476,5 +522,7 @@ def test_the_index_counters_reach_the_metrics_endpoint():
     assert 'worker_index_selected_tokens_scan_total{worker="w1"} ' \
         '1.31072e+06' in text
     assert 'worker_index_dense_rows_scan_total{worker="w1"} 3.0' in text
+    assert 'worker_index_fetched_tokens_scan_total{worker="w1"} ' \
+        '7.4752e+06' in text
     assert 'worker_index_selected_pairs_ragged_total{worker="w1"} ' \
         '524288.0' in text
