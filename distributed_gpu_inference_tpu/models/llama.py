@@ -110,6 +110,10 @@ def init_params(
 
 
 INDEX_KEYS = "ki"      # the index-key pool's name in ``KVPools``
+# a scan's index keys in context order, every layer's: beside the pools
+# inside a ``decode_multi`` call of several steps and nowhere else
+# (``scan_index_keys``)
+INDEX_SCAN_KEYS = "ki_scan"
 _NORM_SPREAD = 0.25
 
 
@@ -600,6 +604,33 @@ def _index_plan(
     return _IndexPlan(cos, sin, scatter)
 
 
+def scan_index_keys(
+    cfg: ModelConfig, kv: KVPools, block_tables: jax.Array,
+    lens: jax.Array,          # [B] cached tokens before the scan
+    active: jax.Array,        # [B] bool rows the scan runs
+    num_steps: int,
+) -> KVPools:
+    """``kv`` as a scan of ``num_steps`` decode steps carries it. Where the
+    caller handed storage for them (``INDEX_SCAN_KEYS``: a model with an
+    indexer, a scan of several steps): with every layer's index keys of the
+    rows laid out in context order ONCE (``ops/index_select.
+    gather_scan_keys``), for ``forward_chunk`` to append to and score from
+    in place of a gather a layer a step. What the entry holds is derived
+    from the pool at every call; only its storage outlives the call, in the
+    caller's hands and not among the pools. Without the entry (any other
+    model, a single step, a table no wider than ``topk``): ``kv`` itself."""
+    if INDEX_SCAN_KEYS not in kv:
+        return kv
+    from distributed_gpu_inference_tpu.ops import index_select
+
+    keys = index_select.gather_scan_keys(
+        kv[INDEX_KEYS], block_tables,
+        jnp.max(jnp.where(active, lens, 0)) + num_steps, cfg.index_topk,
+        into=kv[INDEX_SCAN_KEYS],
+    )
+    return {**kv, INDEX_SCAN_KEYS: keys}
+
+
 def index_inputs(
     cfg: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array, proj,
     index: _IndexPlan,
@@ -728,7 +759,9 @@ def _layer_step(
     pool as a fifth entry. The chunk's index keys are scattered into it
     whatever the K/V path, the selection is computed from it
     (``ops/index_select.select``) and handed to the attention call as
-    ``keep``.
+    ``keep``. Inside a scan of several steps a sixth entry holds the scan's
+    keys in context order (``scan_index_keys``): the step's keys are
+    appended there too and the selection reads them there, not the pool.
 
     ``unpack``: ``hidden`` is ``[1, Tp, H]``, a round's live tokens packed
     on one axis. q/k/v are gathered into the ``[B, S]`` rectangle (empty
@@ -738,7 +771,7 @@ def _layer_step(
     ``in_place`` only q takes the rectangle: the page write gathers K and V
     from the packed axis straight into page-shaped updates."""
     hidden, k_ent, v_ent, layer_idx, *more = carry
-    ki_pool = more[0] if more else None
+    ki_pool, scan_keys = (*more, None, None)[:2]
     # int8-KV pools travel as (pool, scale_pool) tuples through the scan
     # carry; bf16 pools stay bare arrays (static structure, zero overhead)
     quant_kv = isinstance(k_ent, tuple)
@@ -801,10 +834,15 @@ def _layer_step(
                 ki_pool = index_select.write_index_keys(
                     ki_pool, kin.reshape(-1, cfg.index_head_dim), layer_idx,
                     *index.scatter)
+                if scan_keys is not None:
+                    scan_keys = index_select.append_scan_keys(
+                        scan_keys, kin[:, 0, 0], layer_idx,
+                        write_positions[:, 0])
                 keep = index_select.select(
                     qi, wts, ki_pool, layer_idx, block_tables,
                     write_positions, kv_lens, cfg.index_topk,
                     kernels=fused_decode or in_place is not None,
+                    scan_keys=scan_keys,
                 )
         sel = {} if keep is None else {"keep": keep}
         fetched = None
@@ -922,7 +960,7 @@ def _layer_step(
     k_out = (k_pool, k_scale_pool) if quant_kv else k_pool
     v_out = (v_pool, v_scale_pool) if quant_kv else v_pool
     return (hidden, k_out, v_out, layer_idx + 1,
-            *(() if ki_pool is None else (ki_pool,))), (
+            *(a for a in (ki_pool, scan_keys) if a is not None)), (
         hidden if emit_hidden else None, moe_stats,
         routing if emit_routing else None, fetched,
     )
@@ -1094,7 +1132,9 @@ def forward_chunk(
         lax.scan(
             lambda c, lp: step(c, lp),
             (hidden, k0, v0, jnp.int32(0),
-             *(() if index is None else (kv[INDEX_KEYS],))),
+             *(() if index is None else
+               (kv[name] for name in (INDEX_KEYS, INDEX_SCAN_KEYS)
+                if name in kv))),
             scanned,
         )
     if moe is not None:
@@ -1106,8 +1146,7 @@ def forward_chunk(
          "k_scale": k_out[1], "v_scale": v_out[1]}
         if quant_kv else {"k": k_out, "v": v_out}
     )
-    if ki_out:
-        new_kv[INDEX_KEYS] = ki_out[0]
+    new_kv.update(zip((INDEX_KEYS, INDEX_SCAN_KEYS), ki_out))
     features = (
         jnp.concatenate([layer_hs[i] for i in collect_layers], axis=-1)
         if collect_layers is not None else None

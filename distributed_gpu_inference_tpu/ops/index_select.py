@@ -27,6 +27,21 @@ of the same arithmetic (the CPU, ``pallas=False``):
 
 A scan step's two calls (``S == 1``) carry the names with ``_step`` at the
 end, so that a device trace tells a scan's selection from a round's.
+
+The score kernels read index keys **in context order**. A round lays its
+batch's out of the pool, one layer at a time (``gather_index_keys``). A scan
+of several steps lays out every layer's ONCE, before its first step
+(``gather_scan_keys`` → ``[L, B, J, lanes]``, "the scan's keys"), carries them
+through its steps beside the pools, appends each step's key to them
+(``append_scan_keys``; the pool is written as well: the pages stay the
+truth) and has ``dgi_index_score_step`` read a layer's out of the carried
+array by layer index. What the array holds lives for the scan's call only:
+it is derived from the pool at every call, and nothing that owns pages (the
+cache manager, the prefix cache, preemption, the handoff) learns of it; its
+storage is the engine's, handed from call to call (``runtime/engine.py``
+``decode_multi``; ``engine.stats`` ``index_key_gathers_scan`` counts the
+layer-gathers the scans issued). A scan no row of which can pass ``topk``
+before its last step gathers nothing.
 """
 
 from __future__ import annotations
@@ -86,6 +101,74 @@ def write_index_keys(
     new = jnp.pad(new.astype(ki_pool.dtype),
                   ((0, 0), (0, ki_pool.shape[3] - new.shape[1])))
     return ki_pool.at[layer_idx, flat_phys, flat_slot].set(new, mode="drop")
+
+
+def scan_keys_shape(pool_shape: tuple, rows: int, table_width: int,
+                    topk: int) -> tuple | None:
+    """Shape of a scan's keys, ``[L, B, Jp, lanes]``, for a pool ``[L, N,
+    Bk, lanes]`` and ``rows`` block tables ``table_width`` wide (``Jp``: the
+    table's positions, padded to whole tiles of the step's score kernel).
+    None where the table cannot hold more than ``topk`` (``select`` never
+    scores)."""
+    l, _, bk, lanes = pool_shape
+    j = table_width * bk
+    return None if j <= topk else (l, rows, _step_tile(j)[1], lanes)
+
+
+def gather_scan_keys(
+    ki_pool: jax.Array,       # [L, N, Bk, lanes]
+    block_tables: jax.Array,  # [B, M]
+    most: jax.Array,          # the longest context a row can reach in the scan
+    topk: int,
+    into: jax.Array,          # [L, B, Jp, lanes] the array's storage
+) -> jax.Array:
+    """Every layer's index keys of a batch in context order, ``[L, B, Jp,
+    lanes]`` (``scan_keys_shape``; the pool's whole rows, the key in their
+    first lanes, so that the scatter that appends and the kernel that reads
+    agree on one layout: ``pool_lanes``): what a scan carries through its
+    steps. ``into`` is storage the caller owns and hands from call to call
+    (its contents are never trusted: an array of this size allocated anew
+    by every call stalled the device for seconds every few hundred calls).
+    Where no row can hold more than ``topk`` tokens inside the scan
+    (``most``) no step will score: nothing is gathered, and ``into`` comes
+    back as it is, never read."""
+    l, n, bk, lanes = ki_pool.shape
+    b, m = block_tables.shape
+    j = m * bk
+    assert into.shape == scan_keys_shape(ki_pool.shape, b, m, topk), (
+        into.shape, ki_pool.shape, block_tables.shape)
+
+    def gathered():
+        # ONE gather whose result is layer-major as it comes: the layers'
+        # pages as rows of one pool, addressed by layer and page at once
+        # (indexing the layer axis with a slice gathers page-major and
+        # then transposes the whole result)
+        pages = block_tables[None] + n * jnp.arange(l, dtype=jnp.int32)[
+            :, None, None]
+        keys = ki_pool.reshape(l * n, bk, lanes)[pages].reshape(
+            l, b, j, lanes)
+        if into.shape[2] != j:
+            keys = jnp.pad(
+                keys, ((0, 0), (0, 0), (0, into.shape[2] - j), (0, 0)))
+        return keys
+
+    with jax.named_scope("dgi_index_scan_keys"):
+        return lax.cond(most > topk, gathered, lambda: into)
+
+
+def append_scan_keys(
+    scan_keys: jax.Array,     # [L, B, Jp, lanes]
+    new: jax.Array,           # [B, Di] a step's index keys, one a row
+    layer_idx: jax.Array,
+    positions: jax.Array,     # [B] where each lands (-1 = nothing to write)
+) -> jax.Array:
+    """A step's keys into layer ``layer_idx`` of the scan's keys, in place in
+    the scan's carry."""
+    new = jnp.pad(new.astype(scan_keys.dtype),
+                  ((0, 0), (0, scan_keys.shape[3] - new.shape[1])))
+    rows = jnp.arange(positions.shape[0], dtype=jnp.int32)
+    at = jnp.where(positions >= 0, positions, scan_keys.shape[2])
+    return scan_keys.at[layer_idx, rows, at].set(new, mode="drop")
 
 
 def _sortable(x: jax.Array) -> jax.Array:
@@ -196,16 +279,25 @@ def _col_tile(j: int, most: int) -> int:
                      if groups % g == 0)
 
 
+def _step_tile(j: int) -> tuple[int, int]:
+    """Columns of a tile of a scan step's score kernel over a context of
+    ``j`` positions, and the context padded to whole tiles."""
+    tj = _col_tile(j, _STEP_COLS)
+    return tj, -(-j // tj) * tj
+
+
 def _step_score_kernel(
     lens_ref,      # [B] int32 (SMEM)
     pos_ref,       # [B] int32 the row's query position (-1: none)
+    layer_ref,     # [1] int32 the layer of ``ctx`` the call reads
     q_ref,         # [1, Hi, Di]
     w_ref,         # [1, Hi, 1] float32
-    ctx_ref,       # [1, tj, Di]
+    ctx_ref,       # [1, 1, tj, Di or more lanes: the key is the first Di]
     out_ref,       # [1, 1, tj] float32
 ):
+    del layer_ref       # the index maps read it
     b, j = pl.program_id(0), pl.program_id(1)
-    tj = ctx_ref.shape[1]
+    tj = ctx_ref.shape[2]
     kv_len, pos = lens_ref[b], pos_ref[b]
     live = (j * tj <= pos) & (j * tj < kv_len)
 
@@ -213,7 +305,8 @@ def _step_score_kernel(
     def _():
         # the heads are the rows of ONE matmul against the tile's keys
         dots = lax.dot_general(
-            q_ref[0], ctx_ref[0], (((1,), (1,)), ((), ())),
+            q_ref[0], ctx_ref[0, 0][:, :q_ref.shape[2]],
+            (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # [Hi, tj]
         score = jnp.sum(w_ref[0] * jnp.maximum(dots, 0.0), axis=0,
                         keepdims=True)
@@ -226,24 +319,29 @@ def _step_score_kernel(
         out_ref[0] = jnp.full((1, tj), -jnp.inf, jnp.float32)
 
 
-def _step_scores_pallas(qi, wts, ctx, positions, kv_lens, interpret):
-    """A scan step's scores: one query a row, ``[B, 1, J]``."""
+def _step_scores_pallas(qi, wts, ctx, layer_idx, positions, kv_lens, j,
+                        interpret):
+    """A scan step's scores: one query a row, ``[B, 1, j]``. ``ctx [L, B,
+    Jp, W]`` holds whole tiles (``_step_tile``), a key in the first ``Di``
+    of a row's ``W`` values, and the call reads its layer ``layer_idx`` in
+    place: the index maps take the layer from a prefetched scalar, as the
+    attention kernels address the stacked pools."""
     b, _, heads, di = qi.shape
-    j = ctx.shape[1]
-    tj = _col_tile(j, _STEP_COLS)
-    j_pad = -(-j // tj) * tj
-    if j_pad != j:
-        ctx = jnp.pad(ctx, ((0, 0), (0, j_pad - j), (0, 0)))
+    tj, j_pad = _step_tile(j)
+    width = ctx.shape[3]
+    assert ctx.shape[2] == j_pad, (ctx.shape, j_pad)
     out = pl.pallas_call(
         _step_score_kernel,
         out_shape=jax.ShapeDtypeStruct((b, 1, j_pad), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, j_pad // tj),
             in_specs=[
                 pl.BlockSpec((1, heads, di), lambda b_, j_, *_: (b_, 0, 0)),
                 pl.BlockSpec((1, heads, 1), lambda b_, j_, *_: (b_, 0, 0)),
-                pl.BlockSpec((1, tj, di), lambda b_, j_, *_: (b_, j_, 0)),
+                pl.BlockSpec((1, 1, tj, width),
+                             lambda b_, j_, lens, pos, layer:
+                             (layer[0], b_, j_, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, tj), lambda b_, j_, *_: (b_, 0, j_)),
         ),
@@ -254,6 +352,7 @@ def _step_scores_pallas(qi, wts, ctx, positions, kv_lens, interpret):
         name=_name(SCORE_KERNEL_NAME, True),
     )(
         kv_lens.astype(jnp.int32), positions[:, 0].astype(jnp.int32),
+        jnp.reshape(layer_idx, (1,)).astype(jnp.int32),
         qi[:, 0], wts[:, 0, :, None], ctx,
     )
     return out[:, :, :j]
@@ -269,10 +368,13 @@ def index_scores_pallas(
     row's context) is filled and not computed. A scan step (one query a
     row) takes a form of its own, the heads as the rows of one matmul."""
     b, s, heads, di = qi.shape
-    if s == 1:
-        return _step_scores_pallas(qi, wts, ctx, positions, kv_lens,
-                                   interpret)
     j = ctx.shape[1]
+    if s == 1:
+        j_pad = _step_tile(j)[1]
+        if j_pad != j:
+            ctx = jnp.pad(ctx, ((0, 0), (0, j_pad - j), (0, 0)))
+        return _step_scores_pallas(qi, wts, ctx[None], jnp.int32(0),
+                                   positions, kv_lens, j, interpret)
     ts = min(_SCORE_ROWS, -(-s // 8) * 8)
     tj = _col_tile(j, _SCORE_COLS)
     s_pad, j_pad = -(-s // ts) * ts, -(-j // tj) * tj
@@ -380,28 +482,42 @@ def select(
     topk: int,
     kernels: bool,            # the Pallas forms (a TPU, no mesh)
     interpret: bool = False,
+    scan_keys: jax.Array | None = None,
+                              # [L, B, Jp, lanes] a scan's keys in context
+                              # order, this step's appended (S == 1)
 ) -> jax.Array:
     """``keep [B, S, J]`` float32: what each query of the chunk attends.
     While no row holds more than ``topk`` tokens nothing is gathered or
-    scored: every query keeps what it sees."""
+    scored: every query keeps what it sees. With ``scan_keys`` nothing is
+    gathered at all: the scores are taken from the layer's keys there."""
     b, s = positions.shape
     j = block_tables.shape[1] * ki_pool.shape[2]
 
     def dense():
         return _visible(positions, kv_lens, j).astype(jnp.float32)
 
-    def sparse():
-        ctx = gather_index_keys(ki_pool, layer_idx, block_tables,
-                                qi.shape[3])
-        if not kernels:
-            return keep_from_scores(
-                index_scores_xla(qi, wts, ctx, positions, kv_lens), topk)
-        scores = index_scores_pallas(qi, wts, ctx, positions, kv_lens,
-                                     interpret=interpret)
+    def threshold(scores):
         return keep_from_scores_pallas(
             scores.reshape(b * s, j), (positions >= 0).reshape(-1), topk,
             interpret=interpret, step=s == 1,
         ).reshape(b, s, j)
+
+    def sparse():
+        if scan_keys is None:
+            ctx = gather_index_keys(ki_pool, layer_idx, block_tables,
+                                    qi.shape[3])
+        elif kernels:       # the layer's keys read where they lie
+            return threshold(_step_scores_pallas(
+                qi, wts, scan_keys, layer_idx, positions, kv_lens, j,
+                interpret))
+        else:
+            ctx = lax.dynamic_index_in_dim(
+                scan_keys, layer_idx, 0, keepdims=False)[:, :j, :qi.shape[3]]
+        if not kernels:
+            return keep_from_scores(
+                index_scores_xla(qi, wts, ctx, positions, kv_lens), topk)
+        return threshold(index_scores_pallas(
+            qi, wts, ctx, positions, kv_lens, interpret=interpret))
 
     if j <= topk:       # static: the table cannot hold more than topk
         return dense()

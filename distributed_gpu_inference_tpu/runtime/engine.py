@@ -537,6 +537,25 @@ class TPUEngine:
         self._state_rows = (
             self.cfg.max_batch_size if self.model_cfg.num_kda_layers else 0)
         self.kv = self._init_kv()
+        # storage for a scan's index keys in context order (a model with an
+        # indexer whose tables can pass topk): a scan of several steps takes
+        # it beside the pools, fills it from the pool and hands it back
+        # (``_scan_kv``). It is never among ``self.kv``: what it holds is
+        # one call's, derived from the pool at every call, and no owner of
+        # pages learns of it. It is kept from call to call because an array
+        # of its size (403 MB at 8 layers x 8 rows x 24,576 positions)
+        # allocated anew inside every scan stalled the device for 1.7-5 s
+        # every few hundred scans (PERF.md section 6, PR 47).
+        self._scan_keys: Optional[jax.Array] = None
+        if self.model_cfg.index_topk:
+            from distributed_gpu_inference_tpu.ops import index_select
+
+            shape = index_select.scan_keys_shape(
+                self.kv[llama.INDEX_KEYS].shape, self.cfg.max_batch_size,
+                self.cfg.max_blocks_per_seq, self.model_cfg.index_topk)
+            if shape is not None:
+                self._scan_keys = jnp.zeros(
+                    shape, self.kv[llama.INDEX_KEYS].dtype)
         host_store = (
             HostKVStore(self.cfg.spill_host_blocks)
             if self.cfg.spill_host_blocks > 0 else None
@@ -674,6 +693,12 @@ class TPUEngine:
                 "index_row_steps_scan": 0, "index_context_tokens_scan": 0,
                 "index_selected_tokens_scan": 0, "index_dense_rows_scan": 0,
                 "index_pairs_ragged": 0, "index_selected_pairs_ragged": 0,
+                # layer-gathers of index keys the scans issued: a scan of
+                # several steps lays every layer's keys out once (L), a
+                # single step once a layer (L), and one no row of which
+                # passes topk inside it not at all (host arithmetic at a
+                # scan's commit, the device's own condition)
+                "index_key_gathers_scan": 0,
             })
             if self.model_cfg.num_experts and self.mesh is None:
                 # what the decode kernel fetched for those selections: the
@@ -986,6 +1011,9 @@ class TPUEngine:
         fwd = functools.partial(
             llama.forward_chunk, pallas=self.mesh is None
         )
+        # what a scan carries beside the pools (a model with an indexer: its
+        # rows' index keys in context order, laid out once a scan)
+        scan_keys = functools.partial(llama.scan_index_keys, cfg)
 
         # seq-sharded pools: decode reads go through the shard_map
         # partial-softmax op (a GSPMD gather from an N-sharded pool would
@@ -1239,7 +1267,9 @@ class TPUEngine:
             done0 = ~active
             n0 = jnp.zeros_like(core["lens"])
             (kv, last, lens, _done, _), emitted = jax.lax.scan(
-                step, (kv, core["last"], core["lens"], done0, n0), None,
+                step, (scan_keys(kv, tables, core["lens"], active,
+                                 num_steps),
+                       core["last"], core["lens"], done0, n0), None,
                 length=num_steps,
             )
             core = dict(core)
@@ -1337,7 +1367,9 @@ class TPUEngine:
                 moe0 = jnp.zeros(
                     (len(self._moe_names) + bool(cfg.index_topk),), jnp.int32)
                 (kv, last, lens, _done, _, moe), emitted = jax.lax.scan(
-                    step, (kv, core["last"], core["lens"], ~active,
+                    step, (scan_keys(kv, tables, core["lens"], active,
+                                     num_steps),
+                           core["last"], core["lens"], ~active,
                            jnp.zeros_like(core["lens"]), moe0),
                     None, length=num_steps,
                 )
@@ -1769,8 +1801,8 @@ class TPUEngine:
         out: Dict[str, Any] = {}
         for t in decode_steps:
             out[f"decode_multi[T={t}]"] = self._decode_multi_fn.lower(
-                self.params, self.kv, core, tables, active, budgets,
-                int(t), "greedy",
+                self.params, self._scan_kv(int(t)), core, tables, active,
+                budgets, int(t), "greedy",
             )
         if out:
             out["chain_sched"] = self._chain_sched_fn.lower(core, budgets)
@@ -1786,6 +1818,16 @@ class TPUEngine:
             )
             below = tp
         return out
+
+    def _scan_kv(self, num_steps: int) -> llama.KVPools:
+        """The pools as a scan of ``num_steps`` steps takes them: a scan of
+        several steps of a model with an indexer also takes the storage of
+        its index keys in context order (donated with the pools; the caller
+        takes it back out of what the scan returns). A single step lays
+        its keys out a layer at a time, as a round does."""
+        if self._scan_keys is None or num_steps == 1:
+            return self.kv
+        return {**self.kv, llama.INDEX_SCAN_KEYS: self._scan_keys}
 
     def _decode_mode(self) -> str:
         for i, s in enumerate(self.slots):
@@ -3652,8 +3694,11 @@ class TPUEngine:
             try:
                 self.kv, self._dev_core, scan.emitted, *scan.moe = \
                     self._decode_multi_fn(
-                        self.params, self.kv, *operands, num_steps, mode,
+                        self.params, self._scan_kv(num_steps), *operands,
+                        num_steps, mode,
                     )
+                self._scan_keys = self.kv.pop(
+                    llama.INDEX_SCAN_KEYS, self._scan_keys)
             except Exception:
                 self._unread = None
                 self._invalidate_device_state()
@@ -3715,7 +3760,7 @@ class TPUEngine:
         with flight.span("dgi.engine.decode_multi.commit", st,
                          "round_commit_s"):
             out: Dict[int, List[int]] = {}
-            index_ctx = 0
+            index_ctx, index_most = 0, -1
             for i, s in enumerate(self.slots):
                 if not scan.active_mask[i] or s is None:
                     continue
@@ -3731,6 +3776,8 @@ class TPUEngine:
                 if "index_row_steps_scan" in st:
                     index_ctx += self._count_index_scan(
                         int(self._kv_lens[i]), len(toks))
+                    if toks:    # a row the device found live
+                        index_most = max(index_most, int(self._kv_lens[i]))
                 # each emitted token corresponds to one scan step that fed
                 # (and thus committed) the previous pending token
                 self._kv_lens[i] += len(toks)
@@ -3747,6 +3794,10 @@ class TPUEngine:
                 self._maybe_release_window(i)
         if index_ctx and sp is not None:
             sp.set(index_context_tokens=index_ctx)
+        # (its storage exists where a table can pass topk at all)
+        if self._scan_keys is not None and index_most >= 0 and \
+                index_most + scan.num_steps > self.model_cfg.index_topk:
+            st["index_key_gathers_scan"] += self.model_cfg.num_layers
         if self._unread is None:
             # nothing went out behind it: the chip waited for this commit
             st["round_host_exposed_s"] += time.perf_counter() - t0

@@ -450,6 +450,9 @@ class Metrics:
                  "ragged rounds' indexer scored, causal"),
                 ("selected_pairs_ragged", "Pairs of those the selection "
                  "kept: at most topk a query"),
+                ("key_gathers_scan", "Layer-gathers of index keys into "
+                 "context order the scans issued: the layers once a scan "
+                 "that scores, none for a scan under topk"),
             )
         }
         self.worker_mla = {

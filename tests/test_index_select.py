@@ -427,3 +427,114 @@ def test_a_call_without_a_selection_keeps_its_group_width_and_its_jaxpr():
     operands, count, _ = digest(keep=keep)
     assert operands == DENSE_DIGEST[0] + 2 and count != DENSE_DIGEST[1]
 
+
+
+# --------------------------------------------------------------------- #
+# a scan's keys: every layer's laid out once, appended to, read by layer
+# --------------------------------------------------------------------- #
+
+def _scan_pool(layers=3, bs=4, seed=5):
+    """A pool of ``layers`` layers whose two rows' tables interleave, every
+    position of both rows written with its own key."""
+    rng = np.random.default_rng(seed)
+    m = J // bs
+    tables = jnp.asarray(rng.permutation(np.arange(1, 2 * m + 1))
+                         .reshape(B, m), jnp.int32)
+    pool = jnp.zeros((layers, 2 * m + 1, bs, ix.pool_lanes(DI)), jnp.float32)
+    keys = jnp.asarray(rng.normal(size=(layers, B, J, DI)), jnp.float32)
+    tok = jnp.arange(J)
+    for l in range(layers):
+        for b in range(B):
+            pool = ix.write_index_keys(
+                pool, keys[l, b], jnp.int32(l), tables[b, tok // bs],
+                tok % bs)
+    return pool, tables, keys
+
+
+def _storage(pool, tables, fill=0.0):
+    return jnp.full(ix.scan_keys_shape(pool.shape, *tables.shape, TOPK),
+                    fill, pool.dtype)
+
+
+def test_a_scans_keys_are_every_layers_gather_laid_out_once():
+    pool, tables, keys = _scan_pool()
+    jp = ix._step_tile(J)[1]
+    assert jp == 128 and ix._step_tile(24576) == (12288, 24576)
+    assert ix.scan_keys_shape(pool.shape, B, J // 4, TOPK) == (3, B, jp, 128)
+    assert ix.scan_keys_shape((8, 12289, 16, 128), 8, 1536, 2048) \
+        == (8, 8, 24576, 128)
+    # whatever the storage held, what comes back is the gather
+    got = ix.gather_scan_keys(pool, tables, jnp.int32(TOPK + 1), TOPK,
+                              into=_storage(pool, tables, jnp.nan))
+    assert got.shape == (3, B, jp, 128) and got.dtype == pool.dtype
+    for l in range(3):
+        assert np.array_equal(
+            np.asarray(got[l, :, :J, :DI]),
+            np.asarray(ix.gather_index_keys(pool, jnp.int32(l), tables, DI)))
+    assert np.array_equal(np.asarray(got[:, :, :J, :DI]), np.asarray(keys))
+    assert not np.asarray(got[:, :, J:]).any()
+    assert not np.asarray(got[..., DI:]).any()
+    # no row can pass topk inside the scan: nothing is gathered, the
+    # storage comes back as it went in
+    got = ix.gather_scan_keys(pool * jnp.nan, tables, jnp.int32(TOPK), TOPK,
+                              into=_storage(pool, tables, 7.0))
+    assert got.shape == (3, B, jp, 128) and (np.asarray(got) == 7.0).all()
+    # a table that cannot hold more than topk: no array at all
+    assert ix.scan_keys_shape(pool.shape, B, J // 4, J) is None
+
+
+def test_an_append_lands_at_its_rows_position_and_a_finished_row_writes_nothing():
+    pool, tables, _ = _scan_pool()
+    carried = ix.gather_scan_keys(pool, tables, jnp.int32(J), TOPK,
+                                  into=_storage(pool, tables))
+    new = jnp.asarray(np.random.default_rng(1).normal(size=(B, DI)),
+                      jnp.float32)
+    got = np.asarray(ix.append_scan_keys(
+        carried, new, jnp.int32(1), jnp.asarray([37, -1], jnp.int32)))
+    want = np.asarray(carried).copy()
+    want[1, 0, 37, :DI] = np.asarray(new[0])
+    want[1, 0, 37, DI:] = 0
+    assert np.array_equal(got, want)
+    # the pool's own write of the same key, gathered again, is the append
+    page, slot = tables[:, 37 // 4], jnp.asarray([37 % 4] * B)
+    page = jnp.where(jnp.asarray([True, False]), page, pool.shape[1])
+    pool2 = ix.write_index_keys(pool, new, jnp.int32(1), page, slot)
+    again = ix.gather_scan_keys(pool2, tables, jnp.int32(J), TOPK,
+                                into=_storage(pool, tables))
+    assert np.array_equal(np.asarray(again), got)
+
+
+@pytest.mark.parametrize("lens,pos", [
+    ([42, 51], [41, 50]),       # both rows past topk
+    ([42, 6], [41, 5]),         # a row under topk beside one past it
+    ([42, 0], [41, -1]),        # a finished row: position -1, nothing seen
+    ([64, 33], [63, 32]),       # the table's last position; a page's first
+], ids=["past-topk", "one-under", "finished-row", "edges"])
+def test_the_step_kernel_reads_its_layer_of_the_scans_keys_in_place(lens, pos):
+    """``keep`` from the carried array, by layer index (the kernel in
+    interpret mode and the XLA form), is bit for bit the ``keep`` of the
+    gather a layer a step, in every layer."""
+    pool, tables, _ = _scan_pool()
+    rng = np.random.default_rng(7)
+    qi = jnp.asarray(rng.normal(size=(B, 1, HI, DI)), jnp.float32)
+    wts = jnp.asarray(rng.normal(size=(B, 1, HI)), jnp.float32)
+    lens, pos = jnp.asarray(lens, jnp.int32), jnp.asarray(pos, jnp.int32)
+    carried = ix.gather_scan_keys(pool, tables, jnp.max(lens), TOPK,
+                                  into=_storage(pool, tables))
+    for l in range(3):
+        layer = jnp.int32(l)
+        for kernels in (False, True):
+            args = (qi, wts, pool, layer, tables, pos[:, None], lens, TOPK)
+            want = ix.select(*args, kernels=kernels, interpret=True)
+            got = ix.select(*args, kernels=kernels, interpret=True,
+                            scan_keys=carried)
+            assert got.shape == (B, 1, J)
+            assert np.array_equal(np.asarray(got), np.asarray(want)), (
+                l, kernels)
+            assert np.asarray(got).sum() > 0
+    # the carried array is what is scored: another key there, another keep
+    moved = carried.at[2, 0, :40, :DI].multiply(-1.0)
+    other = ix.select(qi, wts, pool, jnp.int32(2), tables, pos[:, None],
+                      lens, TOPK, kernels=True, interpret=True,
+                      scan_keys=moved)
+    assert not np.array_equal(np.asarray(other), np.asarray(want))

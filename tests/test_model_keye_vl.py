@@ -511,7 +511,7 @@ def test_the_index_counters_reach_the_metrics_endpoint():
         "kv_layout": "kv+index", "index_pool_bytes": 603979776,
         "index_row_steps_scan": 640, "index_context_tokens_scan": 12800000,
         "index_selected_tokens_scan": 1310720, "index_dense_rows_scan": 3,
-        "index_fetched_tokens_scan": 7475200,
+        "index_fetched_tokens_scan": 7475200, "index_key_gathers_scan": 1280,
         "index_pairs_ragged": 5000000, "index_selected_pairs_ragged": 524288})
     text = mc.metrics.render().decode()
     if "worker_kv_layout" not in text:
@@ -526,3 +526,170 @@ def test_the_index_counters_reach_the_metrics_endpoint():
         '7.4752e+06' in text
     assert 'worker_index_selected_pairs_ragged_total{worker="w1"} ' \
         '524288.0' in text
+    assert 'worker_index_key_gathers_scan_total{worker="w1"} 1280.0' in text
+
+
+# --------------------------------------------------------------------- #
+# a scan of several steps lays its rows' index keys out once
+# --------------------------------------------------------------------- #
+
+DOC = _prompt(40, seed=11)
+# (what the row is for, its prompt, its new tokens: the first comes from its
+# last piece, the rest from the scan)
+SCAN_ROWS = [
+    ("shares the document's pages", DOC + _prompt(5, seed=12), 6),
+    ("crosses a page boundary at 32", _prompt(29, seed=13), 6),
+    ("crosses topk at 8", _prompt(5, seed=14), 6),
+    ("shares them too, finishes at step 2", DOC + _prompt(7, seed=15), 3),
+]
+
+
+def _tapped_scan(monkeypatch, calls, carried=True):
+    """A fresh engine whose every selection hands its layer, its rows'
+    positions, its ``keep`` and the scan's keys it read to the host; the
+    document cached, SCAN_ROWS admitted to their first token, then
+    ``calls`` as (steps, times). ``carried=False``: the scan as it was
+    before it carried its keys (a gather a layer a step)."""
+    from distributed_gpu_inference_tpu.ops import index_select
+
+    seen, orig = [], index_select.select
+
+    def select(qi, wts, pool, layer_idx, tables, positions, kv_lens, topk,
+               kernels, interpret=False, scan_keys=None):
+        keep = orig(qi, wts, pool, layer_idx, tables, positions, kv_lens,
+                    topk, kernels, interpret, scan_keys)
+        if positions.shape[1] == 1:     # a scan's step
+            jax.debug.callback(
+                lambda *a: seen.append([np.asarray(x) for x in a]),
+                layer_idx, positions[:, 0], keep,
+                jnp.zeros(()) if scan_keys is None else scan_keys)
+        return keep
+
+    monkeypatch.setattr(index_select, "select", select)
+    eng = _engine()
+    assert eng._scan_keys.shape == (eng.model_cfg.num_layers, 4, 256, 128)
+    if not carried:
+        eng._scan_keys = None       # no storage: no scan lays its keys out
+    eng.generate([_req(DOC, 2)], use_multi_step=True)
+    seen.clear()
+    slots, first = _admit(eng, [p for _, p, _ in SCAN_ROWS],
+                          [n for _, _, n in SCAN_ROWS])
+    assert eng.manager.stats.prefix_hit_tokens == 2 * 32
+    toks = {s: list(first[s]) for s in slots}
+    gathers = eng.stats["index_key_gathers_scan"]
+    for steps, times in calls:
+        for _ in range(times):
+            for slot, more in eng.decode_multi(steps).items():
+                toks[slot] += more
+    jax.effects_barrier()
+    keeps = {}
+    for layer, positions, keep, _ in seen:
+        for row, p in enumerate(positions):
+            if p >= 0:
+                keeps[int(layer), row, int(p)] = keep[row, 0]
+    return (eng, [toks[s] for s in slots], keeps,
+            eng.stats["index_key_gathers_scan"] - gathers, seen)
+
+
+def test_a_scan_that_carries_its_keys_selects_what_a_gather_a_step_selects(
+        monkeypatch):
+    """One T=4 scan against four single steps (today's path: a gather a
+    layer) and against the T=4 scan as it was (a gather a layer a step):
+    the same tokens and, layer by layer and row by row, the same ``keep``
+    bit for bit, over rows that cross ``topk``, cross a page boundary,
+    finish at step 2 and share their document's pages."""
+    layers = get_model_config(MODEL).num_layers
+    eng, toks, keeps, gathers, seen = _tapped_scan(monkeypatch, [(4, 1)])
+    assert [len(t) for t in toks] == [5, 5, 5, 3]
+    assert gathers == layers            # every layer's, once
+    # the rows' contexts over the scan: 46-49, 30-33, 6-9, 48-49
+    starts, steps = [45, 29, 5, 47], [4, 4, 4, 2]
+    assert sorted(keeps) == sorted(
+        (l, row, starts[row] + t) for l in range(layers)
+        for row in range(4) for t in range(steps[row]))
+    assert keeps[0, 2, 7].sum() == 8 and keeps[0, 2, 8].sum() == 8 \
+        and keeps[0, 2, 6].sum() == 7
+    one, toks1, keeps1, gathers1, _ = _tapped_scan(monkeypatch, [(1, 4)])
+    # a single step gathers a layer, where a row is past topk: all four do
+    assert gathers1 == 4 * layers
+    _, toks0, keeps0, _, seen0 = _tapped_scan(monkeypatch, [(4, 1)],
+                                              carried=False)
+    assert all(entry[3].ndim == 0 for entry in seen0)
+    assert toks == toks1 == toks0
+    for other in (keeps1, keeps0):
+        assert sorted(other) == sorted(keeps)
+        for at, keep in keeps.items():
+            assert np.array_equal(keep, other[at]), at
+    for name in ("index_row_steps_scan", "index_context_tokens_scan",
+                 "index_selected_tokens_scan", "index_dense_rows_scan"):
+        assert eng.stats[name] == one.stats[name], name
+    # after the scan: every layer's carried keys are the gather of the pool
+    # the scan returned (the last layer's call of the last step read them
+    # with every append made; the engine holds them as storage for the next
+    # scan and not among its pools)
+    from distributed_gpu_inference_tpu.ops import index_select
+
+    assert llama.INDEX_SCAN_KEYS not in eng.kv
+    assert llama.INDEX_SCAN_KEYS not in one.kv
+    last = max((e for e in seen if e[0] == layers - 1),
+               key=lambda e: e[1][0])      # row 0 runs all four steps
+    carried = last[3]
+    assert np.array_equal(carried, np.asarray(eng._scan_keys))
+    tables = jnp.asarray(eng._block_tables)
+    j = tables.shape[1] * BLOCK
+    assert carried.shape[:2] == (layers, 4) and carried.shape[2] >= j
+    for l in range(layers):
+        want = index_select.gather_index_keys(
+            eng.kv[llama.INDEX_KEYS], jnp.int32(l), tables,
+            eng.model_cfg.index_head_dim)
+        assert np.array_equal(
+            carried[l, :, :j, :eng.model_cfg.index_head_dim],
+            np.asarray(want)), l
+
+
+def test_a_scan_whose_rows_stay_under_topk_gathers_nothing(monkeypatch):
+    from distributed_gpu_inference_tpu.ops import index_select
+
+    seen, orig = [], index_select.select
+
+    def select(*a, **kw):
+        if a[5].shape[1] == 1:
+            jax.debug.callback(
+                lambda p, k: seen.append((np.asarray(p), np.asarray(k))),
+                a[5][:, 0], kw["scan_keys"])
+        return orig(*a, **kw)
+
+    monkeypatch.setattr(index_select, "select", select)
+    eng = _engine()
+    prompts = [_prompt(2, seed=21), _prompt(3, seed=22)]
+    slots, first = _admit(eng, prompts, [9, 9])
+    scan = eng.decode_multi(4)      # contexts 3-6 and 4-7: at most topk 8
+    jax.effects_barrier()
+    assert [len(scan[s]) for s in slots] == [4, 4]
+    assert eng.stats["index_key_gathers_scan"] == 0
+    assert eng.stats["index_dense_rows_scan"] == 8
+    # what the steps were handed holds their own appends and nothing else:
+    # no page was gathered
+    assert seen
+    for positions, carried in seen:
+        held = np.abs(carried).sum(axis=(0, 3)) > 0      # [B, Jp]
+        for row, p in enumerate(positions[:2]):
+            assert not held[row, :len(prompts[row])].any()
+            assert not held[row, p + 1:].any()
+    held = np.abs(np.asarray(eng._scan_keys)).sum(axis=(0, 3)) > 0
+    assert held.sum() == 8          # two rows' four appends
+    # the next scan crosses topk in its third step and gathers once; the
+    # tokens are those of single steps throughout
+    more = eng.decode_multi(4)
+    assert eng.stats["index_key_gathers_scan"] == eng.model_cfg.num_layers
+    ref = _engine()
+    slots_r, first_r = _admit(ref, prompts, [9, 9])
+    steps = {s: [] for s in slots_r}
+    for _ in range(8):
+        for s, t in ref.decode_multi(1).items():
+            steps[s] += t
+    for s, r in zip(slots, slots_r):
+        assert first[s] + scan[s] + more[s] == first_r[r] + steps[r]
+    # single steps gather where a row is past topk: contexts 8 and 9 on
+    assert ref.stats["index_key_gathers_scan"] \
+        == 3 * ref.model_cfg.num_layers

@@ -699,3 +699,128 @@ def test_indexed_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch, tp,
     # a round's largest temporaries are the rectangle's scores and mask
     assert compiled.memory_analysis().temp_size_in_bytes \
         < (3 if tp == 2048 else 1) * 1024 ** 3
+
+
+def _indexed_scan_lowered(cfg, steps, devices, ctx=KEYE_CTX):
+    """``steps`` decode steps of int8 ``cfg`` over [BATCH, 1] in one
+    ``lax.scan``, the way ``TPUEngine``'s ``decode_multi`` carries a model
+    with an indexer: the storage of the scan's keys beside the pools (donated
+    with them), ``llama.scan_index_keys`` ahead of the steps."""
+    one = SingleDeviceSharding(devices[0])
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
+    params = jax.eval_shape(lambda: quantize_params(
+        llama.init_params(cfg, jax.random.PRNGKey(0)), "int8"))
+    from distributed_gpu_inference_tpu.ops import index_select
+
+    kv = jax.eval_shape(
+        lambda: llama.init_kv_pools(cfg, 1 + BATCH * (ctx // 16), 16))
+    kv[llama.INDEX_SCAN_KEYS] = jax.ShapeDtypeStruct(
+        index_select.scan_keys_shape(
+            kv[llama.INDEX_KEYS].shape, BATCH, ctx // 16, cfg.index_topk),
+        kv[llama.INDEX_KEYS].dtype)
+    sds = _on(one)
+
+    def scan(params, kv, last, lens, tables, active):
+        kv = llama.scan_index_keys(cfg, kv, tables, lens, active, steps)
+
+        def step(carry, _):
+            kv, last, lens = carry
+            pos = jnp.where(active, lens, -1)[:, None]
+            out = llama.forward_chunk(
+                cfg, params, last[:, None], pos, kv, tables,
+                jnp.where(active, lens + 1, 0), block_size=16)
+            toks = jnp.argmax(out.logits[:, 0], axis=-1).astype(jnp.int32)
+            return (out.kv, toks, lens + 1), toks
+
+        (kv, _, _), toks = jax.lax.scan(
+            step, (kv, last, lens), None, length=steps)
+        return kv, toks
+
+    return jax.jit(scan, donate_argnums=(1,)).lower(
+        place(params), place(kv), sds((BATCH,), jnp.int32),
+        sds((BATCH,), jnp.int32), sds((BATCH, ctx // 16), jnp.int32),
+        sds((BATCH,), jnp.bool_))
+
+
+def _computations(text):
+    """The compiled module's computations by name → their lines."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def test_indexed_scan_gathers_its_index_keys_once_outside_the_step_loop(
+        v5e, tpu_dispatch):
+    """The T=4 scan of the Keye configuration at 24,576 positions a row: the
+    index-key pool is gathered in the branch of ONE conditional ahead of the
+    step loop (every layer's keys at once, ``[L, B, J, 128]``) and by
+    nothing inside the loop; the score kernel's operand is that array as
+    the loop carries it (no slice of a layer, no copy, one layout from the
+    scatter that appends to the kernel that reads); the array is the
+    caller's storage, written in place (the program allocates none of its
+    size)."""
+    cfg = get_model_config(KEYE)
+    lowered = _indexed_scan_lowered(cfg, 4, v5e)
+    assert _kernels(lowered) == {
+        "dgi_index_score_step", "dgi_index_threshold_step",
+        "dgi_paged_decode", "dgi_moe_gmm_step", "dgi_qmm"}
+    compiled = lowered.compile()
+    comps = _computations(compiled.as_text())
+    L, blocks = cfg.num_layers, 1 + BATCH * (KEYE_CTX // 16)
+    keys = f"bf16[{L},{BATCH},{KEYE_CTX},128]"
+    pool = f"bf16[{L},{blocks},16,128]"
+    # (1) who gathers from the pool: one computation, a conditional's branch
+    gathering = {
+        name for name, lines in comps.items()
+        if any("gather" in ln and "dgi_index_scan_keys" in ln
+               for ln in lines)}
+    branches = {
+        b for lines in comps.values() for ln in lines
+        if " conditional(" in ln and "dgi_index_scan_keys" in ln
+        for b in re.findall(r"%([\w.\-]+)", ln.split("branch_computations")[1]
+                            .split("}")[0])}
+    assert gathering and gathering <= branches | {
+        c for b in branches for ln in comps[b]
+        for c in re.findall(r"calls=%([\w.\-]+)", ln)}, (gathering, branches)
+    # the step's own selection gathers nothing: no gather under its scope
+    for lines in comps.values():
+        for ln in lines:
+            if "dgi_index_select" in ln or "dgi_index/" in ln:
+                assert " gather(" not in ln, ln
+    # (2) loop bodies: the conditional is in none of them
+    bodies = {m for lines in comps.values() for ln in lines
+              if " while(" in ln
+              for m in re.findall(r"body=%([\w.\-]+)", ln)}
+    assert bodies
+    for body in bodies:
+        assert not any(" conditional(" in ln and "dgi_index_scan_keys" in ln
+                       for ln in comps[body]), body
+    # (3) the score kernel reads the carried array itself
+    calls = [ln for lines in comps.values() for ln in lines
+             if "dgi_index_score_step" in ln and "custom-call(" in ln]
+    assert calls
+    for ln in calls:
+        assert keys in ln.split("operand_layout_constraints")[1], ln
+    # (4) nothing copies or slices it, and it has one layout
+    layouts = set()
+    for lines in comps.values():
+        for ln in lines:
+            layouts |= set(re.findall(re.escape(keys) + r"\{([\d,]*)", ln))
+            if keys in ln.split(" = ")[0] if " = " in ln else False:
+                op = ln.split(" = ")[1]
+                assert not re.match(r"\S+ (copy|dynamic-slice|transpose)\(",
+                                    op), ln
+    assert layouts == {"3,2,1,0"}, layouts
+    # (5) the storage comes back with the pools, aliased to what went in:
+    # the program holds no array of its size (403 MB) among its temporaries
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 64 * 1024 ** 2
+    size = L * BATCH * KEYE_CTX * 128 * 2
+    assert stats.alias_size_in_bytes >= size + 3 * L * blocks * 16 * 128 * 2
