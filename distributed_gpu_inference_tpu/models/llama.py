@@ -516,9 +516,9 @@ def ragged_kv_path(
       (``pallas``: no mesh).
     - ``layer_copy``: the layer is sliced out of the stack, scattered into
       and written back — pool-sized copies in every layer. Everything
-      else: a mesh, the CPU, head widths the kernels refuse, int8 pools
-      (their scale pools have no in-place write; no worker can serve
-      them at block 16)."""
+      else (a mesh, the CPU, a head width the kernels refuse, int8 pools
+      with no in-place scale write), and by these facts alone: both chunk
+      callers, ``forward_chunk`` and ``forward_hidden_chunk``, ask here."""
     from distributed_gpu_inference_tpu.ops.attention import resolve_impl
 
     if cfg.latent_kv:
@@ -724,9 +724,9 @@ def _layer_step(
 ) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array],
            Tuple[Optional[jax.Array], Optional[Dict[str, jax.Array]],
                  Optional[jax.Array]]]:
-    """One transformer layer over paged KV — shared by the causal decode path
-    and the speculative tree-verify path (they differ only in the attention
-    mask and in where KV rows are written).
+    """One transformer layer over paged KV, for every caller: ``attn_fn``
+    carries the attention mask and ``write_positions`` where the chunk's
+    K/V rows are written.
 
     The KV path has three forms, chosen at trace time by shape and backend.
     ``fused_decode`` (S = 1 on the kernel path) routes it through the Pallas
@@ -1176,89 +1176,6 @@ def forward_chunk(
     return ChunkOutput(hidden=hidden, kv=new_kv, logits=logits,
                        features=features, moe=moe, routing=routing,
                        index_fetched=fetched)
-
-
-def forward_tree_chunk(
-    cfg: ModelConfig,
-    params: Params,
-    token_ids: jax.Array,       # [B, N] tree-node tokens
-    rope_positions: jax.Array,  # [B, N] semantic positions (prefix + depth)
-    cache_positions: jax.Array, # [B, N] KV slot positions (prefix + node idx)
-    kv: KVPools,
-    block_tables: jax.Array,    # [B, M]
-    prefix_lens: jax.Array,     # [B] committed context before the tree
-    tree_mask: jax.Array,       # [N, N] ancestor-visibility mask
-    *,
-    block_size: int = 16,
-    collect_layers: Optional[Tuple[int, ...]] = None,
-) -> ChunkOutput:
-    """Target forward over a speculative token tree (the verify pass).
-
-    RoPE uses semantic depth positions; KV pages are written at distinct
-    node-indexed slots so sibling nodes don't collide. After acceptance the
-    engine compacts the winning path's pages (see
-    ``runtime/speculative.py``). Reference analogue:
-    ``worker/engines/speculative.py:419-453`` _verify_candidates.
-
-    Composes with sliding-window models (the tree-attention mask windows
-    prefix AND within-chunk keys by semantic node position — round 8
-    deleted the depth-vs-window guard) and with int8 KV pools: node KV
-    quantizes through the shared per-token contract on write and the
-    verify read dequantizes context-sized via ``ops.attention
-    .dequantize_kv`` — the same arithmetic every other int8 reader uses,
-    so tree verification over int8 pools is bit-identical to a
-    dequantized oracle.
-    """
-    from distributed_gpu_inference_tpu.ops.attention import paged_tree_attention
-
-    if cfg.index_topk:
-        raise NotImplementedError(
-            "tree verification over a model with an indexer is not built")
-    hidden = embed_tokens(params, token_ids, cfg)
-    cos, sin = _rope_angles(
-        jnp.maximum(rope_positions, 0), cfg.head_dim, cfg.rope_theta
-    )
-
-    def attn_fn(q, layer_k, layer_v, layer_ks=None, layer_vs=None):
-        return paged_tree_attention(
-            q, layer_k, layer_v, block_tables, prefix_lens, tree_mask,
-            block_size, node_positions=rope_positions,
-            window=cfg.sliding_window,
-            k_scale=layer_ks, v_scale=layer_vs,
-        )
-
-    quant_kv = "k_scale" in kv
-    scanned, stacked = _split_layers(params["layers"], True)
-    step = functools.partial(
-        _layer_step,
-        cfg,
-        block_size,
-        block_tables=block_tables,
-        write_positions=cache_positions,
-        cos=cos,
-        sin=sin,
-        attn_fn=attn_fn,
-        stacked=stacked,
-        emit_hidden=collect_layers is not None,
-    )
-    k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
-    v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
-    (hidden, k_out, v_out, _), (layer_hs, *_) = lax.scan(
-        lambda c, lp: step(c, lp), (hidden, k0, v0, jnp.int32(0)),
-        scanned,
-    )
-    new_kv = (
-        {"k": k_out[0], "v": v_out[0],
-         "k_scale": k_out[1], "v_scale": v_out[1]}
-        if quant_kv else {"k": k_out, "v": v_out}
-    )
-    features = (
-        jnp.concatenate([layer_hs[i] for i in collect_layers], axis=-1)
-        if collect_layers is not None else None
-    )
-    logits = project_logits(cfg, params, hidden)
-    return ChunkOutput(hidden=hidden, kv=new_kv,
-                       logits=logits, features=features)
 
 
 def forward_hidden_chunk(

@@ -280,78 +280,6 @@ def paged_attention_xla(
     return out.reshape(b, s, nh, d).astype(q.dtype)
 
 
-def paged_tree_attention(
-    q: jax.Array,             # [B, N, Nh, D] — one query per tree node
-    k_pool: jax.Array,        # [Nb, Hkv, Bk, D]
-    v_pool: jax.Array,
-    block_tables: jax.Array,  # [B, M]
-    prefix_lens: jax.Array,   # [B] committed context BEFORE the tree chunk
-    tree_mask: jax.Array,     # [N, N] bool — node i may attend node j (ancestors)
-    block_size: int = 16,
-    node_positions: Optional[jax.Array] = None,  # [B, N] semantic positions
-    window: Optional[int] = None,                # Mistral SWA over the prefix
-    k_scale: Optional[jax.Array] = None,         # [Nb, Bk, D] — int8 pools
-    v_scale: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Attention for speculative tree verification.
-
-    The N tree-node KVs are written at *cache positions* ``prefix_len + i``
-    (node index, NOT semantic depth — siblings share a depth but need distinct
-    slots). Masking: every node sees the committed prefix; within the chunk,
-    node i sees node j iff ``tree_mask[i, j]`` (ancestor chain, reference
-    ``worker/engines/speculative.py:184-213`` get_tree_attention_mask).
-    """
-    b, n, nh, d = q.shape
-    hkv = k_pool.shape[1]
-    qpk = nh // hkv
-    m = block_tables.shape[1]
-    j = m * block_size
-
-    k_ctx = _gather_ctx(k_pool, block_tables, block_size, k_scale)
-    v_ctx = _gather_ctx(v_pool, block_tables, block_size, v_scale)
-
-    qg = q.reshape(b, n, hkv, qpk, d).astype(jnp.float32)
-    scores = jnp.einsum("bsgqd,bjgd->bgqsj", qg, k_ctx.astype(jnp.float32)) * (
-        d**-0.5
-    )
-
-    key_pos = jnp.arange(j, dtype=jnp.int32)[None, :]                # [1, J]
-    is_prefix = key_pos[:, None, :] < prefix_lens[:, None, None]     # [B, 1, J]
-    chunk_idx = key_pos[:, None, :] - prefix_lens[:, None, None]     # [B, 1, J]
-    in_chunk = (chunk_idx >= 0) & (chunk_idx < n)
-    safe_idx = jnp.clip(chunk_idx, 0, n - 1)                         # [B, 1, J]
-    # tree_mask lookup per (query node, chunk key)
-    tm = jnp.take_along_axis(
-        jnp.broadcast_to(tree_mask[None, :, :], (b, n, n)),
-        jnp.broadcast_to(safe_idx, (b, n, j)).astype(jnp.int32),
-        axis=2,
-    )                                                                # [B, N, J]
-    if window is not None and node_positions is not None:
-        # prefix keys beyond the node's window drop out by ABSOLUTE
-        # position; within-chunk keys window by SEMANTIC node position
-        # (prefix + depth — cache slots are node-indexed, so the raw
-        # key_pos of a chunk key says nothing about its distance). Deep
-        # trees on tiny windows (Mistral-class SWA, VERDICT r5 #5) thus
-        # mask exactly like the sequential engine would: an ancestor more
-        # than ``window`` semantic steps up is invisible.
-        is_prefix &= (
-            key_pos[:, None, :] > node_positions[:, :, None] - window
-        )
-        key_node_pos = jnp.take_along_axis(
-            jnp.broadcast_to(node_positions[:, None, :], (b, n, n)),
-            jnp.broadcast_to(safe_idx, (b, n, j)).astype(jnp.int32),
-            axis=2,
-        )                                                            # [B, N, J]
-        tm &= key_node_pos > node_positions[:, :, None] - window
-    mask = is_prefix | (in_chunk & tm)
-    scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    any_valid = jnp.any(mask[:, None, None, :, :], axis=-1, keepdims=True)
-    probs = jnp.where(any_valid, probs, 0.0)
-    out = jnp.einsum("bgqsj,bjgd->bsgqd", probs, v_ctx.astype(jnp.float32))
-    return out.reshape(b, n, nh, d).astype(q.dtype)
-
-
 def dense_causal_attention(
     q: jax.Array,   # [B, S, Nh, D]
     k: jax.Array,   # [B, S, Hkv, D]

@@ -146,19 +146,6 @@ class BatcherConfig:
     # with their full generated context, so resume restores spilled/cached
     # pages instead of recomputing.
     max_preemptions: int = 3
-    # adaptive speculation (VERDICT r3 #7): when a SpeculativeDecoder is
-    # attached and the ENTIRE waiting load is <= this many greedy requests,
-    # they decode through the spec tree — the low-depth regime where
-    # drafting wins; deeper load decodes vanilla (batched weight streaming
-    # already amortizes better there). 0 = never.
-    spec_max_batch: int = 2
-    # a wave may START while up to this many paged slots are still active
-    # (spec dispatches and paged rounds interleave in the serving loop, so
-    # a busy slot only bounds, not blocks, the other path). 0 = round-4
-    # behavior: require a fully idle engine — which made routing STICKY at
-    # steady low rates (the first paged request kept the engine active
-    # when each next one arrived, so no wave ever started again).
-    spec_max_active: int = 2
     # per-ROUND prefill token budget for ragged rounds (PR 17, long-context
     # serving): the total prefill-chunk tokens all in-flight admissions may
     # land in one ragged round, split fairly across them (water-fill, with
@@ -313,23 +300,10 @@ class _QueueItem:
 class ContinuousBatcher:
     """Admission queue + decode loop over a :class:`TPUEngine`."""
 
-    def __init__(self, engine: TPUEngine, cfg: Optional[BatcherConfig] = None,
-                 spec: Optional[Any] = None) -> None:
-        """``spec``: a ``runtime.speculative.SpeculativeDecoder`` sharing the
-        engine's target weights (its own KV pool). When set, low-depth
-        all-greedy load routes through the incremental spec-wave API
-        (one bounded fused dispatch per loop iteration, interleaved with
-        paged decode rounds — never a blocking whole-generation call)."""
+    def __init__(self, engine: TPUEngine,
+                 cfg: Optional[BatcherConfig] = None) -> None:
         self.engine = engine
         self.cfg = cfg or BatcherConfig()
-        self.spec = spec
-        if spec is not None and \
-                getattr(engine.cfg, "speculative", None) is not None:
-            raise ValueError(
-                "engine already speculates in-engine "
-                "(EngineConfig.speculative); attaching a standalone "
-                "SpeculativeDecoder would draft twice — pick one"
-            )
         if getattr(engine, "supports_ragged", True) is False:
             # the one admission path is the ragged round; an engine that
             # says it has none cannot be served (a stub without the
@@ -340,11 +314,6 @@ class ContinuousBatcher:
                 "engines are fenced — their decode rows read through a "
                 "dedicated shard_map op with no ragged variant"
             )
-        # (wave, items) while a speculative wave is in flight
-        self._spec_wave: Optional[Tuple[Any, List["_QueueItem"]]] = None
-        # True while start_wave runs on the executor: the requests are off
-        # the heap but the wave isn't registered yet — drain must wait
-        self._spec_starting = False
         self._heap: List[_QueueItem] = []
         self._seq = itertools.count()
         self._wake = asyncio.Event()
@@ -430,7 +399,6 @@ class ContinuousBatcher:
             "ragged_admissions": 0, "admissions_ahead": 0,
             "ragged_rounds": 0,
             "budgeted_rounds": 0, "budget_skipped_admissions": 0,
-            "spec_waves": 0, "spec_completed": 0, "spec_errors": 0,
             "preemptions": 0, "resumes": 0, "preemption_block_pressure": 0,
             "preempted_too_often": 0,
             "cancelled": 0, "migrated": 0, "adopted": 0,
@@ -476,134 +444,6 @@ class ContinuousBatcher:
         for t in self._levels:
             self.stats.setdefault(f"scans_t{t}", 0)
             self.stats.setdefault(f"scan_s_t{t}", 0.0)
-
-    # ---------------------------------------------------- speculative routing
-
-    def _spec_eligible(self, item: "_QueueItem") -> bool:
-        """A request may decode through the spec tree iff it is greedy
-        (verify is an argmax match), its prompt fits one spec prefill
-        bucket, the generation fits the spec pool, and it did not opt out
-        (``request.params['speculative'] = False``)."""
-        r = item.request
-        ids = r.prompt_token_ids or []
-        if not ids or r.sampling.temperature > 0.0:
-            return False
-        if r.params.get("speculative") is False:
-            return False
-        if item.observer is not None or item.cancel is not None \
-                or item.interrupt is not None:
-            # serving hooks need round-granular slot access (streaming
-            # deltas, step-boundary abort/migrate) — a whole-wave spec
-            # dispatch offers none of that
-            return False
-        s = self.spec
-        max_bucket = s.prefill_buckets[-1]
-        eng_buckets = getattr(self.engine.cfg, "prefill_buckets", None)
-        if eng_buckets:
-            # prompts beyond the PAGED engine's largest bucket prefill over
-            # several rounds; spec routing keeps them off the wave so the
-            # long-prompt path is one contract across serving modes
-            max_bucket = min(max_bucket, eng_buckets[-1])
-        if len(ids) > max_bucket:
-            return False
-        # headroom must cover the WORST verify tree (incl. adaptive depth
-        # growth): the spec fits-freeze ends a row early at
-        # prefix + nodes + 1 > ctx, which would return fewer tokens than
-        # the paged engine serves for the same request
-        margin = s.worst_case_tree_nodes() + 1
-        return len(ids) + r.sampling.max_new_tokens + margin <= s.max_seq_len
-
-    async def _maybe_start_spec_wave(self) -> bool:
-        """Route the ENTIRE waiting queue through the spec decoder when it
-        is a low-depth all-greedy moment: queue depth <= spec_max_batch,
-        every request eligible, at most spec_max_active paged slots still
-        decoding (waves and paged rounds interleave in the serving loop),
-        no wave in flight. Mixed/deep load never waits on drafting."""
-        spec_cap = (
-            min(self.cfg.spec_max_batch, self.spec.max_batch_size)
-            if self.spec is not None else 0
-        )
-        if (
-            self.spec is None
-            or spec_cap <= 0
-            or self._spec_wave is not None
-            or self._ragged
-            or not self._heap
-            or len(self._heap) > spec_cap
-            or self.engine.num_active > self.cfg.spec_max_active
-        ):
-            return False
-        items = [it for it in list(self._heap) if not it.future.cancelled()]
-        if not items or not all(self._spec_eligible(it) for it in items):
-            return False
-        if any(it.preempted is not None for it in items):
-            # a preempted sequence must RESUME (restoring its generated
-            # context, TTFT origin, and warm pages) — a spec wave would
-            # silently regenerate it from token 0
-            return False
-        loop = asyncio.get_running_loop()
-        self._heap.clear()
-        self._spec_starting = True
-        try:
-            wave = await loop.run_in_executor(
-                self._exec, self.spec.start_wave,
-                [it.request for it in items],
-            )
-        except Exception:
-            # fall back to the paged engine, which can serve these requests
-            # (a transient spec failure must not error a servable request);
-            # mark them so a persistent spec fault can't retry-loop
-            for it in items:
-                it.request.params["speculative"] = False
-                heapq.heappush(self._heap, it)
-            return False
-        finally:
-            self._spec_starting = False
-        self._spec_wave = (wave, items)
-        self.stats["spec_waves"] += 1
-        self.stats["admitted"] += len(items)
-        return True
-
-    async def _step_spec_wave(self) -> None:
-        """Advance the in-flight spec wave by ONE fused dispatch; finish and
-        resolve futures when every row is done (or a caller gave up)."""
-        if self._spec_wave is None:
-            return
-        wave, items = self._spec_wave
-        loop = asyncio.get_running_loop()
-        if all(it.future.done() for it in items):
-            self._spec_wave = None          # every caller timed out/cancelled
-            await loop.run_in_executor(self._exec, self.spec.abort_wave, wave)
-            return
-        try:
-            done = await loop.run_in_executor(
-                self._exec, self.spec.advance_wave, wave
-            )
-        except Exception as e:
-            self._spec_wave = None
-            await loop.run_in_executor(self._exec, self.spec.abort_wave, wave)
-            for it in items:
-                if not it.future.done():
-                    it.future.set_result(InferenceResponse(
-                        request_id=it.request.request_id,
-                        error=f"speculative engine error: {e}",
-                    ))
-                    self.stats["completed"] += 1
-                    self.stats["spec_errors"] += 1
-            return
-        if done:
-            self._spec_wave = None
-            resps = await loop.run_in_executor(
-                self._exec, self.spec.finish_wave, wave
-            )
-            # completed counts responses actually DELIVERED — a row whose
-            # caller already timed out was counted by submit()'s timeout
-            # path, not here, so stats stay reconcilable per-request
-            for it, resp in zip(items, resps):
-                if not it.future.done():
-                    it.future.set_result(resp)
-                    self.stats["completed"] += 1
-                    self.stats["spec_completed"] += 1
 
     # ---------------------------------------------------------------- API
 
@@ -765,8 +605,7 @@ class ContinuousBatcher:
         if drain:
             # drain batcher-OWNED work only: a foreign engine slot (e.g. a
             # PD sequence retained between stages) is not ours to wait on
-            while self._heap or self._slot_items or self._ragged \
-                    or self._spec_wave is not None or self._spec_starting:
+            while self._heap or self._slot_items or self._ragged:
                 await asyncio.sleep(0.01)
         if self._run_task:
             self._run_task.cancel()
@@ -791,16 +630,6 @@ class ContinuousBatcher:
                 pass
             pending.append(rag_item)
         self._ragged = []
-        if self._spec_wave is not None:
-            wave, items = self._spec_wave
-            self._spec_wave = None
-            try:
-                await loop.run_in_executor(
-                    self._exec, self.spec.abort_wave, wave
-                )
-            except Exception:  # noqa: BLE001
-                pass
-            pending.extend(items)
         for item in pending:
             if item.future.done():
                 continue
@@ -1552,8 +1381,8 @@ class ContinuousBatcher:
         ``signal``: a cancel, interrupt or deadline wants a slot, or an
         out-of-band engine call read the scan or waits for the thread;
         ``pressure``: the pool froze a row, or resumes are held;
-        ``admission``: a request waits and a slot is free (or the
-        speculative route may take it): the next round is not a scan;
+        ``admission``: a request waits and a slot is free: the next round
+        is not a scan;
         ``row_end_waiting``: a request waits and a row's budget ends inside
         the unread scan: a slot coming free is an admission, not a scan
         (``_choose_steps`` never runs a raised scan past that step either);
@@ -1568,7 +1397,7 @@ class ContinuousBatcher:
         if self._signal_pending():
             return "signal"
         if self._heap:
-            if self.spec is not None or eng.free_slots():
+            if eng.free_slots():
                 return "admission"
             if eng.scan_ends_row():
                 return "row_end_waiting"
@@ -1673,7 +1502,7 @@ class ContinuousBatcher:
         ragged = bool(self._ragged)
         can = not ragged and bool(
             getattr(self.engine, "supports_scan_ahead", False))
-        ahead = can and self._spec_wave is None and not self._foreign
+        ahead = can and not self._foreign
         steps, reason = (1, "ragged") if ragged else self._choose_steps()
         # the scan this round's goes out behind; whatever the engine does
         # with the call, it reads that one
@@ -1710,8 +1539,7 @@ class ContinuousBatcher:
                 st[f"scans_{reason}"] += 1
                 st["scans_chained"] += behind is not None
                 # a scan read by the call that made it although the engine
-                # could leave it: an out-of-band call waits for the thread,
-                # or a speculative wave shares the rounds
+                # could leave it: an out-of-band call waits for the thread
                 st["chain_breaks_signal"] += can and not ahead
                 exposed = -self._host_exposed_s(engine_stats)
                 cost = gap - self._host_phases_s(engine_stats)
@@ -1848,7 +1676,7 @@ class ContinuousBatcher:
                 # engine then); if not, the scan is read and delivered
                 # first and the loop is the one it always was
                 why = self._chain_break()
-                if why == "admission" and self.spec is None:
+                if why == "admission":
                     # a request waits, a slot is free and nothing else wants
                     # the engine: the pass that would follow the read runs
                     # now, while that scan still runs on the device
@@ -1867,7 +1695,7 @@ class ContinuousBatcher:
             # nor be decoded/finished behind its owner's back — it joins the
             # batch only through adopt_slot().
             if not self._heap and not self._slot_items \
-                    and not self._ragged and self._spec_wave is None:
+                    and not self._ragged:
                 self._wake.clear()
                 if self._stopping:
                     return
@@ -1920,18 +1748,11 @@ class ContinuousBatcher:
             # hopeless deadline work drops at the same boundary, so its
             # freed blocks admit waiting on-time work this very pass
             await self._scan_deadlines()
-            # low-depth all-greedy load routes through the spec tree
-            # BEFORE paged admission claims it; requests arriving
-            # mid-wave admit to paged slots below and the two interleave
-            # round for round
-            await self._maybe_start_spec_wave()
             await self._admit(ahead)
             # admission-sourced KV pressure: deferred requests wait, or
             # a higher-priority arrival preempts the lowest-priority
             # victim
             await self._check_pressure()
-            # one bounded fused dispatch of the in-flight spec wave
-            await self._step_spec_wave()
         if ahead:
             self._cost_s -= time.perf_counter() - t0
 
@@ -2071,9 +1892,6 @@ class ContinuousBatcher:
         out["queue_depth"] = len(self._heap)
         out["active_slots"] = self.engine.num_active
         out["ragged_in_flight"] = len(self._ragged)
-        out["spec_wave_active"] = self._spec_wave is not None
-        if self.spec is not None:
-            out["spec"] = self.spec.get_stats()
         if getattr(self.engine.cfg, "speculative", None) is not None:
             # engine-integrated speculation: every decode round commits
             # 1..K+1 tokens per slot, so these are THE serving-efficiency
@@ -2113,11 +1931,9 @@ class BatcherServing:
     """
 
     def __init__(self, engine: TPUEngine,
-                 cfg: Optional[BatcherConfig] = None,
-                 spec: Optional[Any] = None) -> None:
+                 cfg: Optional[BatcherConfig] = None) -> None:
         self.engine = engine
         self._cfg = cfg
-        self._spec = spec
         self.batcher: Optional[ContinuousBatcher] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._ready = threading.Event()
@@ -2141,9 +1957,7 @@ class BatcherServing:
 
         async def boot() -> None:
             try:
-                self.batcher = ContinuousBatcher(
-                    self.engine, self._cfg, spec=self._spec
-                )
+                self.batcher = ContinuousBatcher(self.engine, self._cfg)
                 self.batcher.start()
             except BaseException as exc:  # noqa: BLE001 — surfaced to ctor
                 self._boot_error = exc

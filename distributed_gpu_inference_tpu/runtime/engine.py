@@ -793,8 +793,8 @@ class TPUEngine:
                 "selection runs in kernels a mesh refuses)")
         if self.cfg.speculative is not None:
             raise ValueError(
-                f"{name}: speculative decoding verifies a token tree; "
-                "tree attention takes no per-query selection")
+                f"{name}: speculative decoding verifies a drafted chain in "
+                "one window; the verify read takes no per-query selection")
         if self.cfg.spill_host_blocks > 0 or \
                 self.cfg.spill_remote_store is not None:
             raise ValueError(

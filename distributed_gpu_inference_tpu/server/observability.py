@@ -74,7 +74,7 @@ class Metrics:
                 "tokens_per_second", "kv_cache_hit_rate", "kv_cache_size",
                 "kv_cache_evictions", "worker_status", "hbm_used_bytes",
                 "hop_latency", "kv_migration_latency", "batch_size",
-                "queue_size", "spec_accept_rate", "spec_speedup",
+                "queue_size",
                 "spec_accepted_tokens", "spec_drafted_tokens",
                 "spec_decode_steps", "spec_worker_accept_rate",
                 "spec_worker_tokens_per_step",
@@ -156,10 +156,6 @@ class Metrics:
             "batch_size", "Current decode batch size", registry=r)
         self.queue_size = Gauge(
             "queue_size", "Queued requests per phase", ["phase"], registry=r)
-        self.spec_accept_rate = Gauge(
-            "speculative_accept_rate", "Draft token accept rate", registry=r)
-        self.spec_speedup = Gauge(
-            "speculative_speedup", "Tokens per verify step", registry=r)
         # per-worker speculation efficiency (engine-integrated decode mode):
         # counters scrape-delta cleanly into fleet accept-rate / tokens-per-
         # step panels; the gauges mirror the engine's own derived numbers
@@ -278,8 +274,7 @@ class Metrics:
             "batcher_loop_seconds_total",
             "Seconds of the batcher loop by part: between_rounds (one "
             "round's end to the next one's start), and the loop's admit "
-            "and deliver steps, which split it (with a speculative wave "
-            "in flight admit includes its engine dispatches); "
+            "and deliver steps, which split it; "
             "round_host_exposed: the part of the gaps and of the engine's "
             "build, dispatch and commit that ran with no scan on the "
             "device (the chip's idle time the host caused)",
@@ -793,11 +788,6 @@ class MetricsCollector:
 
     def record_queue(self, phase: str, size: int) -> None:
         self.metrics.queue_size.labels(phase).set(size)
-
-    def record_speculative(self, accept_rate: float,
-                           tokens_per_step: float) -> None:
-        self.metrics.spec_accept_rate.set(accept_rate)
-        self.metrics.spec_speedup.set(tokens_per_step)
 
     def record_spec_engine(self, worker: str,
                            engine_stats: Dict[str, Any]) -> None:

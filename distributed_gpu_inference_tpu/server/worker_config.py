@@ -92,12 +92,12 @@ class WorkerRemoteConfig:
     # utils.config.ServingConfig that retune a RUNNING batcher between
     # decode rounds: max_horizon, min_horizon, multi_step, adaptive,
     # max_wait_ms, queue_limit, default_timeout_s, max_preemptions,
-    # spec_max_batch, spec_max_active, prefill_budget, ...:
-    # worker/engines/llm.py SERVING_REMOTE_KEYS).
+    # prefill_budget, ...: worker/engines/llm.py SERVING_REMOTE_KEYS).
     # `mode` is load-time-only worker YAML and silently ignored by the
     # worker if pushed. The keys of the admission path that is gone
-    # (ragged, subwave, interleave) and target_step_ms are read by nothing
-    # and max_horizon is degenerate: still accepted (saved SLO configs
+    # (ragged, subwave, interleave), the two wave knobs of the tree
+    # decoder that is gone and target_step_ms are read by nothing and
+    # max_horizon is degenerate: still accepted (saved SLO configs
     # keep deploying) but deprecation-warned once on ingest — see
     # utils.config.DEPRECATED_SERVING_KEYS. Empty dict = no override (the
     # worker keeps its local config).

@@ -35,7 +35,10 @@ ENV_PREFIX = "TPU_WORKER_"
 # the batcher's horizon rule. They stay ACCEPTED in worker YAML, plain-dict
 # engine configs and remote pushes (rolling fleets, saved SLO configs) but
 # are warned once per process; nothing reads ``ragged``, ``subwave``,
-# ``interleave`` or ``target_step_ms``.
+# ``interleave`` or ``target_step_ms``. The last two entries shaped the
+# waves of the standalone tree decoder, which is gone (the engine's chain,
+# ``speculative_decode: true``, is the one speculative decoder): they are
+# no field of ``ServingConfig`` any more and are dropped on load.
 DEPRECATED_SERVING_KEYS: Dict[str, str] = {
     "ragged": (
         "ignored: ragged rounds are the one admission path — the legacy "
@@ -59,6 +62,16 @@ DEPRECATED_SERVING_KEYS: Dict[str, str] = {
         "and the host's cost per round that it measures (runtime/batcher.py "
         "_choose_steps), not from a latency target"
     ),
+    "spec_max_batch": (
+        "ignored: the standalone tree decoder whose waves this key sized "
+        "is gone — speculative_decode: true (the engine's chain) is the "
+        "speculative decoder"
+    ),
+    "spec_max_active": (
+        "ignored: the standalone tree decoder whose waves this key gated "
+        "is gone — speculative_decode: true (the engine's chain) is the "
+        "speculative decoder"
+    ),
 }
 _deprecated_serving_warned: Set[str] = set()
 
@@ -76,6 +89,37 @@ def warn_deprecated_serving_key(key: str, source: str) -> None:
         "serving.%s (%s) is deprecated: %s",
         key, source, DEPRECATED_SERVING_KEYS[key],
     )
+
+
+# Engine keys of the standalone tree decoder, which is gone: the ``engine``
+# values that built it and the ``spec_widths`` that shaped its tree. A saved
+# worker YAML or engine dict that names them keeps loading — as the plain
+# ``jax`` engine — and says so once per process and key.
+_TREE_DECODER_ENGINES = ("jax-speculative", "speculative")
+_retired_engine_warned: Set[str] = set()
+
+
+def retire_tree_decoder_keys(cfg: Dict[str, Any], source: str
+                             ) -> Dict[str, Any]:
+    """``cfg`` (an engine's config as written) without what selected or
+    shaped the tree decoder: ``engine: jax-speculative`` / ``speculative``
+    reads ``jax`` and ``spec_widths`` is dropped, each warned once."""
+    out = dict(cfg)
+    retired = {}
+    if out.get("engine") in _TREE_DECODER_ENGINES:
+        retired["engine"] = out["engine"]
+        out["engine"] = "jax"
+    if out.pop("spec_widths", None) is not None:
+        retired["spec_widths"] = cfg["spec_widths"]
+    for key in retired.keys() - _retired_engine_warned:
+        _retired_engine_warned.add(key)
+        log.warning(
+            "%s: %r (%s) is ignored — the standalone tree decoder is gone "
+            "and the plain jax engine loads; speculative_decode: true (the "
+            "chain inside the engine's rounds) is the speculative decoder",
+            key, retired[key], source,
+        )
+    return out
 
 
 class ServerConfig(BaseModel):
@@ -153,8 +197,6 @@ class ServingConfig(BaseModel):
     max_preemptions: int = 3
     subwave: int = 0                    # DEPRECATED: read by nothing
     interleave: int = 0                 # DEPRECATED: read by nothing
-    spec_max_batch: int = 2
-    spec_max_active: int = 2
     ragged: Optional[bool] = None       # DEPRECATED: read by nothing
     # per-ROUND prefill token budget for ragged rounds: caps how many fresh
     # prompt tokens all concurrent admissions may prefill in one round
@@ -179,22 +221,33 @@ class ServingConfig(BaseModel):
     # ``abandoned_predictive``). Requires abandon_deadlines. Remote-pushable.
     predictive_abandon: bool = False
 
-    @model_validator(mode="after")
-    def _warn_deprecated(self) -> "ServingConfig":
-        for key in self.model_fields_set & DEPRECATED_SERVING_KEYS.keys():
-            warn_deprecated_serving_key(key, "worker YAML")
-        return self
+    @model_validator(mode="before")
+    @classmethod
+    def _warn_deprecated(cls, data: Any) -> Any:
+        # on the keys as written: the ones that are no field any more are
+        # dropped by the model (extra keys are ignored) and warned here
+        if isinstance(data, dict):
+            for key in data.keys() & DEPRECATED_SERVING_KEYS.keys():
+                warn_deprecated_serving_key(key, "worker YAML")
+        return data
 
 
 class EngineModelConfig(BaseModel):
     """Per-task-type engine/model selection (reference :173-188)."""
 
-    engine: str = "jax"                 # jax | jax-speculative | echo (tests)
+    engine: str = "jax"                 # jax | echo (tests)
     model: str = "llama3-tiny"
     dtype: str = "bfloat16"
     quantization: Optional[str] = None  # int8 | fp8 | None
     serving: Optional[ServingConfig] = None   # None → engine defaults
     extra: Dict[str, Any] = Field(default_factory=dict)
+
+    @model_validator(mode="before")
+    @classmethod
+    def _retire_tree_decoder(cls, data: Any) -> Any:
+        if isinstance(data, dict):
+            return retire_tree_decoder_keys(data, "worker YAML")
+        return data
 
 
 DEFAULT_ENGINE_CONFIGS: Dict[str, EngineModelConfig] = {

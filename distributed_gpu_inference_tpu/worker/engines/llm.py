@@ -43,7 +43,11 @@ from ...runtime.flight import (
     timeline_for,
 )
 from ...runtime.prefix_summary import TIER_HOST, TIER_SPILL, PrefixHotSet
-from ...utils.config import ServingConfig, warn_deprecated_serving_key
+from ...utils.config import (
+    ServingConfig,
+    retire_tree_decoder_keys,
+    warn_deprecated_serving_key,
+)
 from ...utils.data_structures import InferenceRequest, SamplingParams
 from .base import (
     EngineLoadError,
@@ -82,8 +86,6 @@ SERVING_REMOTE_KEYS: Dict[str, str] = {
     "queue_limit": "queue_limit",
     "default_timeout_s": "default_timeout_s",
     "max_preemptions": "max_preemptions",
-    "spec_max_batch": "spec_max_batch",
-    "spec_max_active": "spec_max_active",
     # long-context round shaping: the per-round prefill token budget and
     # the per-admission chunk width are both read per-round (widths bucket
     # through compiled prefill_buckets), so they retune live without a
@@ -292,14 +294,14 @@ class TPULLMEngine(LLMBaseEngine):
     supports_failover = True
 
     def __init__(self, config: Optional[Dict[str, Any]] = None) -> None:
-        super().__init__(config)
+        super().__init__(
+            retire_tree_decoder_keys(config or {}, "engine config"))
         self.engine: Optional[TPUEngine] = None
         # batcher-backed serving front-end (the DEFAULT worker path since
         # round 6): all queued jobs and direct/SSE requests share decode
         # rounds through one ContinuousBatcher; ``serving.mode: direct``
         # restores the legacy per-request engine driving
         self.serving: Optional[BatcherServing] = None
-        self._spec = None            # EAGLE-style decoder (engine=jax-speculative)
         self.tokenizer = self.config.get("tokenizer")
         # PD disaggregation: kv_cache_key → (slot, seq, adopted_at) — an
         # adopted (or locally retained) sequence awaiting its decode-stage
@@ -487,12 +489,10 @@ class TPULLMEngine(LLMBaseEngine):
         # just the initial value (remote pushes can retune it live)
         if sv.get("ragged_chunk"):
             eng_cfg.ragged_chunk = int(sv["ragged_chunk"])
-        # engine-INTEGRATED speculative decoding (EngineConfig.speculative):
-        # every decode round runs fused draft→verify→accept steps committing
-        # 1..K+1 tokens per slot — unlike engine=jax-speculative below,
-        # which routes a SUBSET of requests to a standalone tree decoder.
-        # Greedy outputs stay byte-identical; sampled requests ride the same
-        # graph at one token per step.
+        # speculative decoding (EngineConfig.speculative): every decode
+        # round runs fused draft→verify→accept steps committing 1..K+1
+        # tokens per slot. Greedy outputs stay byte-identical; sampled
+        # requests ride the same graph at one token per step.
         if self.config.get("speculative_decode"):
             from ...runtime.speculative import SpecDecodeConfig
 
@@ -520,15 +520,6 @@ class TPULLMEngine(LLMBaseEngine):
                 raise EngineLoadError(
                     f"speculative_decode config invalid: {exc}"
                 ) from exc
-            if self.config.get("engine") in ("jax-speculative",
-                                             "speculative"):
-                # config-only conflict: fail BEFORE weights load / the
-                # draft head distills, not after minutes of work
-                raise EngineLoadError(
-                    "speculative_decode (engine-integrated) and "
-                    "engine=jax-speculative (standalone tree decoder) are "
-                    "mutually exclusive — pick one"
-                )
         # first-class TP: tp_size > 1 builds a model-axis mesh over local
         # devices (the reference forwarded tensor_parallel_size to vLLM;
         # here the engine itself shards, llm_vllm.py:56 / SURVEY §2.2)
@@ -575,44 +566,10 @@ class TPULLMEngine(LLMBaseEngine):
                 raise EngineLoadError(
                     f"speculative draft distillation failed: {exc}"
                 ) from exc
-        # engine=jax-speculative: short-prompt greedy requests route through
-        # the EAGLE-style tree decoder (shares the TARGET weights with the
-        # paged engine but owns its own KV pool — sized to exactly one
-        # batch's worst case to bound the extra HBM); sampled, streaming,
-        # and beyond-bucket-length requests keep using the paged TPUEngine.
-        if self.config.get("engine") in ("jax-speculative", "speculative"):
-            try:
-                from ...runtime.speculative import (
-                    SpeculativeConfig,
-                    SpeculativeDecoder,
-                )
-
-                raw_w = self.config.get("spec_widths") or (4, 2, 2)
-                if isinstance(raw_w, str):          # CLI/env: "4,2,2"
-                    raw_w = [p for p in raw_w.split(",") if p.strip()]
-                widths = tuple(int(w) for w in raw_w)
-                if not widths or any(w < 1 for w in widths):
-                    raise ValueError(f"invalid spec_widths {widths}")
-                blocks_per_seq = -(-eng_cfg.max_seq_len // eng_cfg.block_size)
-                self._spec = SpeculativeDecoder(
-                    model_name,
-                    params=self.engine.params,
-                    spec_cfg=SpeculativeConfig(widths=widths),
-                    max_batch_size=eng_cfg.max_batch_size,
-                    max_seq_len=eng_cfg.max_seq_len,
-                    num_blocks=eng_cfg.max_batch_size * blocks_per_seq + 2,
-                    prefill_buckets=eng_cfg.prefill_buckets,
-                )
-            except (ValueError, TypeError) as exc:
-                # a bad speculative config drops the task type, never kills
-                # worker startup
-                raise EngineLoadError(
-                    f"speculative engine config invalid: {exc}"
-                ) from exc
         if str(sv["mode"]) == "batcher":
             try:
                 self.serving = BatcherServing(
-                    self.engine, self._batcher_config(sv), spec=self._spec
+                    self.engine, self._batcher_config(sv)
                 )
             except (ValueError, RuntimeError) as exc:
                 raise EngineLoadError(
@@ -651,8 +608,6 @@ class TPULLMEngine(LLMBaseEngine):
             queue_limit=int(sv["queue_limit"]),
             default_timeout_s=float(sv["default_timeout_s"]),
             max_preemptions=int(sv["max_preemptions"]),
-            spec_max_batch=int(sv["spec_max_batch"]),
-            spec_max_active=int(sv["spec_max_active"]),
             prefill_budget=int(sv.get("prefill_budget") or 0),
             abandon_deadlines=bool(sv.get("abandon_deadlines") or False),
             deadline_grace_s=float(sv.get("deadline_grace_s") or 0.5),
@@ -748,7 +703,6 @@ class TPULLMEngine(LLMBaseEngine):
             self.serving.stop(drain=False)
             self.serving = None
         self.engine = None
-        self._spec = None
         super().unload()
 
     # -- core generate ---------------------------------------------------------
@@ -2114,15 +2068,6 @@ class TPULLMEngine(LLMBaseEngine):
             return self._job_inference_serving(params, cfg, key, epoch, ckpt)
         tl = params.pop("_flight_tl", NULL_TIMELINE)
         tl.note("worker.start", path="job")
-        if not isinstance(ckpt, dict) and self._spec is not None \
-                and cfg.temperature <= 0.0:
-            # standalone tree-speculative decoder (engine=jax-speculative):
-            # its fused tree rounds are neither interruptible nor
-            # checkpointable, but the multi-x decode speedup should not be
-            # lost on every queued job. Fresh spec-eligible jobs take the
-            # legacy fast path — a drain finishes them and a crash replays
-            # from scratch, exactly the pre-failover contract.
-            return super().inference(params)
         t0 = time.perf_counter()
         pre = self._ckpt_from_wire(ckpt)
         if pre is not None:
@@ -2214,29 +2159,17 @@ class TPULLMEngine(LLMBaseEngine):
             )
             if params.get("priority") is not None:
                 req.priority = int(params.get("priority") or 0)
-        # parity with the legacy driver: a FRESH spec-eligible greedy job
-        # keeps the standalone tree decoder's multi-x speedup by waiving
-        # failover hooks (the wave is neither interruptible nor
-        # checkpointable — a drain finishes it, a crash replays it)
-        spec_fast = (
-            pre is None and self._spec is not None
-            and cfg.temperature <= 0.0
-            and params.get("speculative") is not False
-        )
-        interrupt = None if spec_fast else self._interrupt
-        if not spec_fast:
-            self._register_live(key, "job", epoch, req.request_id)
+        self._register_live(key, "job", epoch, req.request_id)
         try:
             resp = self.serving.submit(
-                req, resume_from=pre, interrupt=interrupt,
+                req, resume_from=pre, interrupt=self._interrupt,
                 flight=tl if tl.enabled else None,
             )
         except RequestMigrated as mig:
             raise JobMigrated(mig.pre.to_wire(),
                               tokens=len(mig.pre.generated)) from None
         finally:
-            if not spec_fast:
-                self._unregister_live(key)
+            self._unregister_live(key)
         if resp.error is not None:
             _raise_serving(resp)
         tl.note("worker.done")
@@ -2304,20 +2237,7 @@ class TPULLMEngine(LLMBaseEngine):
                   cfg: GenerationConfig) -> GenerationResult:
         req = self._build_request(prompt_or_messages, cfg)
         t0 = time.perf_counter()
-        # speculative path only for greedy prompts within one prefill
-        # bucket: the tree decoder's prefill is single-shot, so longer
-        # prompts take the paged engine's CHUNKED prefill instead of
-        # compiling per prompt length
-        use_spec = (
-            self._spec is not None
-            and cfg.temperature <= 0.0
-            and len(req.prompt_token_ids or [])
-            <= self.engine.cfg.prefill_buckets[-1]
-        )
-        if use_spec:
-            resp = self._spec.generate([req])[0]
-        else:
-            resp = self.engine.generate([req], use_multi_step=True)[0]
+        resp = self.engine.generate([req], use_multi_step=True)[0]
         e2e_ms = (time.perf_counter() - t0) * 1000.0
         out_text = self.tokenizer.decode(resp.token_ids)
         finish = resp.finish_reason or "stop"
@@ -2484,10 +2404,6 @@ class TPULLMEngine(LLMBaseEngine):
                     )
                     if params.get("priority") is not None:
                         req.priority = int(params.get("priority") or 0)
-                # spec waves buffer whole generations — a stream needs
-                # per-round progress, so it always decodes through the
-                # paged slots
-                req.params["speculative"] = False
                 request_id = req.request_id
                 snaps: "_queue_mod.Queue" = _queue_mod.Queue()
                 _DONE = object()

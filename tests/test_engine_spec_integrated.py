@@ -7,9 +7,6 @@ engine, incl. int8 KV), and the batcher wiring."""
 import numpy as np
 import pytest
 
-# compile-heavy (jit/scan graphs): excluded from the fast CI gate
-pytestmark = pytest.mark.slow
-
 from distributed_gpu_inference_tpu.runtime.engine import EngineConfig, TPUEngine
 from distributed_gpu_inference_tpu.runtime.speculative import SpecDecodeConfig
 from distributed_gpu_inference_tpu.utils.data_structures import (
@@ -22,7 +19,7 @@ MODEL = "llama3-tiny"
 
 def _cfg(**kw):
     # f32 numerics: bit-exact greedy equality across the two decode paths
-    # needs identical arithmetic (same stance as tests/test_batcher_spec.py)
+    # needs identical arithmetic
     base = dict(max_batch_size=4, max_seq_len=128, block_size=16,
                 prefill_buckets=(16, 32), multi_step=8, dtype="float32")
     base.update(kw)
@@ -232,25 +229,6 @@ def test_batcher_serves_spec_engine_bit_exact():
     assert [g.token_ids for g in got] == want
     assert "spec_integrated" in stats
     assert stats["spec_integrated"]["steps"] > 0
-
-
-def test_batcher_rejects_double_speculation():
-    from distributed_gpu_inference_tpu.runtime.batcher import (
-        ContinuousBatcher,
-    )
-    from distributed_gpu_inference_tpu.runtime.speculative import (
-        SpeculativeConfig,
-        SpeculativeDecoder,
-    )
-
-    _, e2 = _pair(seed=0, max_batch_size=2)
-    spec = SpeculativeDecoder(
-        MODEL, params=e2.params,
-        spec_cfg=SpeculativeConfig(widths=(2,), adaptive=False),
-        max_batch_size=2, max_seq_len=128,
-    )
-    with pytest.raises(ValueError, match="draft twice"):
-        ContinuousBatcher(e2, spec=spec)
 
 
 def test_worker_stream_routes_through_speculation():

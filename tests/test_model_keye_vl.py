@@ -468,7 +468,7 @@ def test_what_cannot_carry_the_index_keys_refuses_the_model():
         TPUEngine(mc, EngineConfig(**base), mesh=mesh)
     with pytest.raises(ValueError, match="spill"):
         TPUEngine(mc, EngineConfig(**base, spill_host_blocks=8))
-    with pytest.raises(ValueError, match="token tree"):
+    with pytest.raises(ValueError, match="drafted chain"):
         TPUEngine(mc, EngineConfig(
             **base, speculative=SpecDecodeConfig(num_draft_tokens=2)))
     with pytest.raises(ValueError, match="kv_cache_dtype"):
@@ -478,12 +478,6 @@ def test_what_cannot_carry_the_index_keys_refuses_the_model():
         kv_handoff.HandoffReceiver(eng)
     with pytest.raises(ValueError, match="index keys"):
         kv_handoff.export_slot_kv(eng, 0)
-    with pytest.raises(NotImplementedError, match="indexer"):
-        llama.forward_tree_chunk(
-            mc, eng.params, jnp.zeros((1, 2), jnp.int32),
-            jnp.zeros((1, 2), jnp.int32), jnp.zeros((1, 2), jnp.int32),
-            eng.kv, jnp.ones((1, 4), jnp.int32), jnp.asarray([0]),
-            jnp.ones((2, 2), bool), block_size=16)
 
 
 def test_a_worker_with_a_handoff_role_drops_the_model():

@@ -216,45 +216,6 @@ def test_window_released_chain_not_prefix_cached():
     assert len(m.radix) == 0  # broken chain must not enter the radix
 
 
-def test_speculative_decoder_deep_tree_on_window_allowed():
-    """Round 8 deleted the depth-vs-window construction guard: the
-    tree-attention mask now windows within-chunk nodes by semantic
-    position, so a tree deeper than the window constructs AND emits the
-    vanilla engine's greedy stream (the full equivalence run lives in
-    tests/test_spec_serving.py::test_tree_decoder_swa_greedy_equivalence)."""
-    from distributed_gpu_inference_tpu.runtime.speculative import (
-        SpeculativeConfig,
-        SpeculativeDecoder,
-    )
-
-    dec = SpeculativeDecoder(
-        get_model_config(MODEL, dtype="float32"),  # window 8
-        spec_cfg=SpeculativeConfig(widths=(4, 2, 1, 1)),  # 21 nodes >= 8
-        max_batch_size=1, max_seq_len=64,
-    )
-    assert dec.worst_case_tree_nodes() >= 8
-
-
-def test_tree_verify_deep_window_runs():
-    """forward_tree_chunk with nodes >= sliding_window no longer raises
-    (round 8): within-chunk keys window by semantic node position inside
-    paged_tree_attention."""
-    import jax
-
-    cfg = get_model_config(MODEL, dtype="float32")  # window 8
-    params = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-    kv = llama.init_kv_pools(cfg, 8, 16, jnp.float32)
-    n = 8  # nodes >= window
-    out = llama.forward_tree_chunk(
-        cfg, params,
-        jnp.zeros((1, n), jnp.int32), jnp.zeros((1, n), jnp.int32),
-        jnp.full((1, n), -1, jnp.int32), kv,
-        jnp.asarray([[1, 2]], jnp.int32), jnp.zeros((1,), jnp.int32),
-        jnp.tril(jnp.ones((n, n), bool)),
-    )
-    assert out.logits.shape == (1, n, cfg.vocab_size)
-
-
 def test_mistral_tp_matches_single(cpu_devices):
     from distributed_gpu_inference_tpu.parallel.mesh import MeshPlan, make_mesh
 

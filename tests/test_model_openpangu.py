@@ -368,14 +368,13 @@ def test_d_a_prefix_hit_on_latent_pages_serves_what_a_cold_run_serves():
 
 
 def test_e_what_carries_kv_pages_refuses_the_model_when_configured():
-    """(e) a mesh, the spill tiers, speculative decoding (both decoders),
-    quantized pools and the KV handoff: refused at configuration."""
+    """(e) a mesh, the spill tiers, speculative decoding, quantized pools
+    and the KV handoff: refused at configuration."""
     from jax.sharding import Mesh
 
     from distributed_gpu_inference_tpu.runtime import kv_handoff
     from distributed_gpu_inference_tpu.runtime.speculative import (
         SpecDecodeConfig,
-        SpeculativeDecoder,
     )
 
     mc = get_model_config(MODEL)
@@ -391,8 +390,6 @@ def test_e_what_carries_kv_pages_refuses_the_model_when_configured():
             **base, speculative=SpecDecodeConfig(num_draft_tokens=2)))
     with pytest.raises(ValueError, match="kv_cache_dtype"):
         TPUEngine(mc, EngineConfig(**base, kv_cache_dtype="int8"))
-    with pytest.raises(ValueError, match="multi-token-prediction"):
-        SpeculativeDecoder(mc)
     eng = TPUEngine(mc, EngineConfig(**base))
     with pytest.raises(ValueError, match="latent pages"):
         kv_handoff.HandoffReceiver(eng)

@@ -668,7 +668,7 @@ def test_the_benchmarks_reader_of_the_chained_share(c0, c1, want):
 #     BEFORE the unread scan is read, while that scan runs on the device
 # --------------------------------------------------------------------- #
 
-def _serve_arriving(engine, first, later, steps=1, ahead=True, spec=None,
+def _serve_arriving(engine, first, later, steps=1, ahead=True,
                     before=None, **cfg):
     """``first`` at once, then ``later`` one at a time, each two chained
     scans after the one before it was admitted, so that it meets a scan
@@ -679,7 +679,7 @@ def _serve_arriving(engine, first, later, steps=1, ahead=True, spec=None,
     async def go():
         b = ContinuousBatcher(engine, BatcherConfig(
             max_wait_ms=1, adaptive=False, multi_step=steps,
-            max_multi_step=max(steps, 4), **cfg), spec=spec)
+            max_multi_step=max(steps, 4), **cfg))
         if not ahead:
             after_the_read = b._admission_pass
 
@@ -877,16 +877,15 @@ def test_a_request_cancelled_in_the_queue_is_not_admitted_ahead(engines):
 
 
 @pytest.mark.parametrize("what", ["signal", "resume_hold", "foreign",
-                                  "speculative_engine", "speculative_route"])
+                                  "speculative_engine"])
 def test_anything_else_pending_keeps_the_old_order(engines, what):
     """``_chain_break`` answers a cancel, a resume hold or a foreign call
-    before it looks at the queue, a speculative engine leaves no scan
-    unread and a speculative route may take the request: the arrival is
-    admitted after the read, as it always was."""
+    before it looks at the queue, and a speculative engine leaves no scan
+    unread: the arrival is admitted after the read, as it always was."""
     eng = engines["dense"]
     cancel = threading.Event()
     first = [_req(PROMPTS[0], 60), _req(PROMPTS[1], 50)]
-    spec, tasks = None, []
+    tasks = []
 
     def before(b, i):
         if what == "signal":
@@ -914,11 +913,9 @@ def test_anything_else_pending_keeps_the_old_order(engines, what):
 
     if what == "speculative_engine":
         eng.supports_scan_ahead = False     # what cfg.speculative sets
-    elif what == "speculative_route":
-        spec = types.SimpleNamespace(max_batch_size=0, get_stats=dict)
     try:
         got, stats, _ = _serve_arriving(
-            eng, first, [_req(PROMPTS[2], 6)], before=before, spec=spec)
+            eng, first, [_req(PROMPTS[2], 6)], before=before)
     finally:
         eng.supports_scan_ahead = True
     assert len(got[0].token_ids) == 60 and len(got[2].token_ids) == 6

@@ -3,7 +3,8 @@
 - SpecDecodeConfig (engine-integrated chain mode) rejects draft depths
   whose worst-case per-step block growth exceeds max_blocks_per_seq, with
   the limiting field named.
-- The tree SpeculativeConfig gets the same screen per verify round.
+- What selected or shaped the standalone tree decoder (gone) still loads,
+  as the plain engine, and nothing can attach a second decoder.
 - MetricsCollector.record_spec_engine exports per-worker accept-rate and
   tokens-per-step counters for /metrics.
 """
@@ -13,7 +14,6 @@ import pytest
 from distributed_gpu_inference_tpu.runtime.engine import EngineConfig
 from distributed_gpu_inference_tpu.runtime.speculative import (
     SpecDecodeConfig,
-    SpeculativeConfig,
 )
 
 
@@ -67,39 +67,65 @@ def test_engine_ctor_validates_spec_config():
         )
 
 
-def test_tree_config_rejects_block_growth_overflow():
-    spec = SpeculativeConfig(widths=(8, 8, 8), adaptive=False)
-    with pytest.raises(ValueError) as ei:
-        spec.validate_blocks(max_blocks_per_seq=2, block_size=16)
-    msg = str(ei.value)
-    assert "widths" in msg
-    assert "max_blocks_per_seq" in msg
+@pytest.mark.parametrize("surface", ["yaml", "engine dict"])
+@pytest.mark.parametrize("key, value", [
+    ("engine", "jax-speculative"), ("engine", "speculative"),
+    ("spec_widths", "4,2,2"),
+])
+def test_tree_decoder_engine_keys_load_the_plain_engine(
+        surface, key, value, tmp_path, monkeypatch, caplog):
+    """The standalone tree decoder is gone; a saved worker YAML or engine
+    dict that selected it (``engine: jax-speculative`` / ``speculative``)
+    or shaped its tree (``spec_widths``) keeps loading — as the plain
+    ``jax`` engine — and says once a process which decoder exists. On the
+    configuration layer: nothing is loaded."""
+    import logging
+
+    from distributed_gpu_inference_tpu.utils import config as config_mod
+    from distributed_gpu_inference_tpu.worker.engines.llm import TPULLMEngine
+
+    monkeypatch.setattr(config_mod, "_retired_engine_warned", set())
+    caplog.set_level(logging.WARNING)
+
+    def load():
+        if surface == "yaml":
+            yml = tmp_path / "config.yaml"
+            yml.write_text(
+                "engines:\n  llm:\n    model: llama3-tiny\n"
+                f"    {key}: {value!r}\n")
+            cfg = config_mod.load_worker_config(yml, environ={})
+            return cfg.engines["llm"].model_dump()
+        return TPULLMEngine({"model": "llama3-tiny", key: value}).config
+
+    for _ in range(3):
+        got = load()
+    assert got.get("engine", "jax") == "jax" and "spec_widths" not in got
+    assert got["model"] == "llama3-tiny"
+    said = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith(f"{key}: ")]
+    assert len(said) == 1 and "speculative_decode: true" in said[0]
 
 
-def test_tree_config_counts_adaptive_growth():
-    # widths fit as configured but adaptive depth growth overflows
-    spec = SpeculativeConfig(widths=(8, 8), adaptive=True, max_depth=4)
-    spec.validate_blocks(max_blocks_per_seq=32, block_size=16)
-    with pytest.raises(ValueError, match="max_depth"):
-        spec.validate_blocks(max_blocks_per_seq=5, block_size=16)
+def test_no_second_decoder_can_be_attached_to_the_batcher():
+    """One admission path: the batcher takes an engine and a config and
+    nothing that decodes beside the engine's rounds."""
+    import dataclasses
 
-
-def test_tree_config_rejects_zero_width():
-    with pytest.raises(ValueError, match="widths"):
-        SpeculativeConfig(widths=(4, 0)).validate_blocks(8, 16)
-
-
-def test_decoder_ctor_validates_widths():
-    from distributed_gpu_inference_tpu.runtime.speculative import (
-        SpeculativeDecoder,
+    from distributed_gpu_inference_tpu.runtime.batcher import (
+        BatcherConfig,
+        BatcherServing,
+        ContinuousBatcher,
     )
 
-    with pytest.raises(ValueError, match="widths"):
-        SpeculativeDecoder(
-            "llama3-tiny",
-            spec_cfg=SpeculativeConfig(widths=(8, 8, 8), adaptive=False),
-            max_batch_size=1, max_seq_len=32, block_size=16,
-        )
+    class Engine:
+        cfg = EngineConfig()
+
+    with pytest.raises(TypeError, match="spec"):
+        ContinuousBatcher(Engine(), BatcherConfig(), spec=object())
+    with pytest.raises(TypeError, match="spec"):
+        BatcherServing(Engine(), BatcherConfig(), spec=object())
+    assert not [f.name for f in dataclasses.fields(BatcherConfig)
+                if f.name.startswith("spec")]
 
 
 def test_record_spec_engine_exports_per_worker():

@@ -7,9 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# compile-heavy (jit/interpret kernels): excluded from the fast CI gate
-pytestmark = pytest.mark.slow
-
 from distributed_gpu_inference_tpu.ops.attention import (
     paged_attention_xla,
     resolve_impl,

@@ -1,10 +1,10 @@
 """Spec decoding as a first-class serving path (round 8): spec ragged
-rounds (verify rows + prefill chunk rows in ONE dispatch), the deleted
-int8/sliding-window verify fences, acceptance-adaptive draft depth, and
-the oracle draft a ``*.spec`` benchmark cell will drive (ROADMAP R5).
+rounds (verify rows + prefill chunk rows in ONE dispatch) over int8 and
+sliding-window pools, acceptance-adaptive draft depth, and the oracle
+draft a ``*.spec`` benchmark cell will drive (ROADMAP R5).
 
 Tier-1 keeps the cheap contracts (config validation, oracle dither,
-depth selection, op-level tree-mask/int8 identities, one tiny smoke);
+depth selection, one tiny smoke and the overload stamp on its engines);
 the compile-heavy byte-identity matrices ride the ``slow`` marker.
 """
 
@@ -19,8 +19,6 @@ from distributed_gpu_inference_tpu.runtime.engine import (
 )
 from distributed_gpu_inference_tpu.runtime.speculative import (
     SpecDecodeConfig,
-    SpeculativeConfig,
-    SpeculativeDecoder,
 )
 from distributed_gpu_inference_tpu.utils.data_structures import (
     InferenceRequest,
@@ -171,116 +169,49 @@ def test_adaptive_k_selection_tracks_ema():
     assert list(ks) == [1, 2, 4, 4]
 
 
-def test_tree_attention_int8_matches_dequant_oracle():
-    """Op-level byte identity: paged_tree_attention over int8 pools must
-    equal the same call over pre-dequantized bf16 pools (the shared
-    dequantize_kv arithmetic — the fence was deleted, not relaxed)."""
-    import jax.numpy as jnp
-
-    from distributed_gpu_inference_tpu.ops.attention import (
-        dequantize_kv,
-        paged_tree_attention,
-    )
-
-    rng = np.random.default_rng(0)
-    b, n, nh, hkv, d, bk, m = 2, 7, 4, 2, 16, 8, 4
-    nb = b * m + 1
-    q = jnp.asarray(rng.normal(size=(b, n, nh, d)), jnp.float32)
-    codes_k = jnp.asarray(rng.integers(-127, 128, (nb, hkv, bk, d)), jnp.int8)
-    codes_v = jnp.asarray(rng.integers(-127, 128, (nb, hkv, bk, d)), jnp.int8)
-    scale_k = jnp.asarray(rng.uniform(0.01, 0.1, (nb, bk, d)), jnp.bfloat16)
-    scale_v = jnp.asarray(rng.uniform(0.01, 0.1, (nb, bk, d)), jnp.bfloat16)
-    tables = jnp.asarray(
-        np.arange(1, 1 + b * m).reshape(b, m), jnp.int32
-    )
-    prefix = jnp.asarray([9, 13], jnp.int32)
-    parents = np.array([-1, 0, 0, 1, 1, 2, 2], np.int32)
-    mask = np.zeros((n, n), bool)
-    for i in range(n):
-        cur = i
-        while cur >= 0:
-            mask[i, cur] = True
-            cur = int(parents[cur])
-    depths = np.zeros((n,), np.int32)
-    for i, p in enumerate(parents):
-        if p >= 0:
-            depths[i] = depths[p] + 1
-    node_pos = prefix[:, None] + jnp.asarray(depths)[None, :]
-
-    got = paged_tree_attention(
-        q, codes_k, codes_v, tables, prefix, jnp.asarray(mask), bk,
-        node_positions=node_pos, k_scale=scale_k, v_scale=scale_v,
-    )
-    want = paged_tree_attention(
-        q, dequantize_kv(codes_k, scale_k[:, None]),
-        dequantize_kv(codes_v, scale_v[:, None]),
-        tables, prefix, jnp.asarray(mask), bk, node_positions=node_pos,
-    )
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_tree_attention_window_masks_within_chunk():
-    """A tree deeper than the sliding window must mask within-chunk
-    ancestors beyond the window by SEMANTIC position — the mask a
-    sequential engine would apply (the old guard just refused)."""
-    import jax.numpy as jnp
-
-    from distributed_gpu_inference_tpu.ops.attention import (
-        paged_tree_attention,
-    )
-
-    rng = np.random.default_rng(1)
-    b, nh, hkv, d, bk, m = 1, 2, 1, 8, 8, 3
-    # a pure chain of depth 6 (chain tree): node i's parent is i-1
-    n = 6
-    parents = np.arange(-1, n - 1)
-    mask = np.tril(np.ones((n, n), bool))
-    depths = np.arange(n, dtype=np.int32)
-    prefix = jnp.asarray([0], jnp.int32)     # no prefix: chunk-only
-    node_pos = jnp.asarray(depths)[None, :]
-    window = 3
-    q = jnp.asarray(rng.normal(size=(b, n, nh, d)), jnp.float32)
-    pools = jnp.asarray(rng.normal(size=(b * m + 1, hkv, bk, d)),
-                        jnp.float32)
-    tables = jnp.asarray(np.arange(1, 1 + m).reshape(1, m), jnp.int32)
-
-    got = paged_tree_attention(
-        q, pools, pools, tables, prefix, jnp.asarray(mask), bk,
-        node_positions=node_pos, window=window,
-    )
-    # reference: windowed mask applied by semantic distance
-    wmask = mask & (
-        depths[None, :] > depths[:, None] - window
-    )
-    want = paged_tree_attention(
-        q, pools, pools, tables, prefix, jnp.asarray(wmask), bk,
-        node_positions=node_pos,
-    )
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # and the window genuinely bites: unwindowed differs
-    free = paged_tree_attention(
-        q, pools, pools, tables, prefix, jnp.asarray(mask), bk,
-        node_positions=node_pos,
-    )
-    assert not np.array_equal(np.asarray(got), np.asarray(free))
-
-
-def test_spec_ragged_smoke():
-    """Cheap tier-1 smoke of the tentpole: one spec engine serves a
-    request through ragged rounds (chunk row → verify rows) and the
-    greedy stream matches the vanilla engine."""
+@pytest.fixture(scope="module")
+def smoke_engines():
+    """A vanilla engine and a chain engine over the same weights."""
     e1 = TPUEngine(MODEL, _cfg(max_batch_size=2), seed=0)
-    want = e1.generate([_req(PROMPTS[0], max_new=5)], use_multi_step=True)
     e2 = TPUEngine(
         MODEL,
         _cfg(max_batch_size=2,
              speculative=SpecDecodeConfig(num_draft_tokens=2)),
         params=e1.params, seed=0,
     )
+    return e1, e2
+
+
+def test_spec_ragged_smoke(smoke_engines):
+    """Cheap tier-1 smoke of the tentpole: one spec engine serves a
+    request through ragged rounds (chunk row → verify rows) and the
+    greedy stream matches the vanilla engine."""
+    e1, e2 = smoke_engines
+    want = e1.generate([_req(PROMPTS[0], max_new=5)], use_multi_step=True)
     assert e2.supports_ragged
     got = _serve_ragged(e2, [_req(PROMPTS[0], max_new=5)])
     assert got[0].token_ids == want[0].token_ids
     assert e2.stats["spec_steps"] > 0 and e2.stats["ragged_rounds"] > 0
+
+
+def test_the_overload_stamp_is_read_by_no_decoder(smoke_engines):
+    """The plane's overload ladder stamps ``params["speculative"] = False``
+    on a degraded job (``server/admission.py`` ``disable_spec``) and the
+    worker copies the stamp onto the request. The chain does not read it:
+    the stamped request is drafted for and served byte for byte as the
+    unstamped one. ROADMAP D22 changes that knowingly or drops the rung."""
+    _, e2 = smoke_engines
+    outs, drafted = [], []
+    for stamp in (False, True):
+        req = _req(PROMPTS[1], max_new=5)
+        if stamp:
+            req.params["speculative"] = False
+        before = e2.stats["spec_drafted"]
+        outs.append(_serve_ragged(e2, [req])[0])
+        drafted.append(e2.stats["spec_drafted"] - before)
+    assert outs[0].token_ids == outs[1].token_ids
+    assert outs[0].finish_reason == outs[1].finish_reason
+    assert drafted[0] == drafted[1] > 0
 
 
 # ------------------------------------------------------------------ slow
@@ -424,50 +355,6 @@ def test_spec_ragged_sliding_window():
         params=e1.params, seed=0,
     )
     got = _serve_ragged(e2, [_req(p) for p in PROMPTS])
-    for a, b in zip(want, got):
-        assert a.token_ids == b.token_ids
-
-
-@pytest.mark.slow
-def test_tree_decoder_swa_greedy_equivalence():
-    """VERDICT r5 #5 done-bar: the guard is deleted and a tree DEEPER
-    than the window (mistral-tiny: window=8, tree 4x2x2 = 15 nodes)
-    emits the vanilla engine's exact greedy stream."""
-    from distributed_gpu_inference_tpu.models.configs import (
-        get_model_config,
-    )
-
-    cfg = get_model_config("mistral-tiny", dtype="float32")
-    eng = TPUEngine(cfg, _cfg(), seed=0)
-    want = eng.generate([_req(p) for p in PROMPTS[:2]],
-                        use_multi_step=True)
-    dec = SpeculativeDecoder(
-        cfg, params=eng.params,
-        spec_cfg=SpeculativeConfig(widths=(4, 2, 2), adaptive=False),
-        max_seq_len=128, block_size=32,
-    )
-    got = dec.generate([_req(p) for p in PROMPTS[:2]])
-    for a, b in zip(want, got):
-        assert a.token_ids == b.token_ids
-
-
-@pytest.mark.slow
-def test_tree_decoder_int8_greedy_equivalence():
-    """Tree verification over int8 pools (fence deleted): the decoder's
-    greedy stream matches an int8-pool TPUEngine token for token — node
-    KV quantizes through the shared per-token contract and compaction
-    moves code + scale rows as a pair."""
-    from distributed_gpu_inference_tpu.models.configs import (
-        get_model_config,
-    )
-
-    cfg = get_model_config(MODEL, dtype="float32")
-    eng = TPUEngine(cfg, _cfg(kv_cache_dtype="int8"), seed=3)
-    want = eng.generate([_req(p) for p in PROMPTS[:2]],
-                        use_multi_step=True)
-    dec = SpeculativeDecoder(cfg, params=eng.params, max_seq_len=128,
-                             block_size=32, kv_cache_dtype="int8")
-    got = dec.generate([_req(p) for p in PROMPTS[:2]])
     for a, b in zip(want, got):
         assert a.token_ids == b.token_ids
 

@@ -63,49 +63,25 @@ def test_tp_size_wiring():
     assert isinstance(out["text"], str)
 
 
-def test_speculative_engine_backend():
-    """engine=jax-speculative serves greedy via the tree decoder and routes
-    sampled requests to the paged engine."""
+def test_a_saved_tree_decoder_config_loads_and_serves_the_plain_engine():
+    """What selected and shaped the standalone tree decoder (gone) is
+    accepted and ignored: the worker loads the plain engine behind the
+    batcher and serves greedy and sampled requests through it."""
     e = TPULLMEngine({
         "model": "llama3-tiny", "engine": "jax-speculative",
-        "max_batch_size": 2, "max_seq_len": 96, "spec_widths": "2,2",
+        "max_batch_size": 2, "max_seq_len": 96, "spec_widths": "banana",
+        "serving": {"spec_max_batch": 4, "spec_max_active": 0},
     })
     e.load_model()
-    assert e._spec.spec_cfg.widths == (2, 2)     # string config parsed
-    assert e._spec is not None
+    assert e.config["engine"] == "jax" and "spec_widths" not in e.config
+    assert e.engine.cfg.speculative is None and e.serving.active
     greedy = e.inference({"prompt": "abcdef", "max_new_tokens": 6})
-    assert greedy["usage"]["completion_tokens"] <= 6
-    st = e._spec.get_stats()
-    assert st["steps"] > 0                       # tree decoder actually ran
+    assert 0 < greedy["usage"]["completion_tokens"] <= 6
     sampled = e.inference({"prompt": "abcdef", "max_new_tokens": 6,
                            "temperature": 0.8})
-    assert isinstance(sampled["text"], str)      # routed to TPUEngine
-
-
-def test_speculative_long_prompt_routes_to_chunked_engine():
-    e = TPULLMEngine({
-        "model": "llama3-tiny", "engine": "jax-speculative",
-        "max_batch_size": 1, "max_seq_len": 96, "spec_widths": "2,2",
-    })
-    e.load_model()
-    # shrink the largest bucket so a 40-token prompt exceeds it
-    e.engine.cfg.prefill_buckets = (16,)
-    steps_before = e._spec.get_stats()["steps"]
-    out = e.inference({"prompt": "x" * 40, "max_new_tokens": 4})
-    assert isinstance(out["text"], str)
-    # prompt (40 tokens) exceeds the largest bucket → paged engine served it
-    assert e._spec.get_stats()["steps"] == steps_before
-
-
-def test_bad_spec_widths_is_load_error():
-    from distributed_gpu_inference_tpu.worker.engines.base import (
-        EngineLoadError,
-    )
-
-    e = TPULLMEngine({"model": "llama3-tiny", "engine": "jax-speculative",
-                      "spec_widths": "banana"})
-    with pytest.raises(EngineLoadError, match="speculative engine config"):
-        e.load_model()
+    assert isinstance(sampled["text"], str)
+    assert e.serving.get_stats()["completed"] == 2
+    e.unload()
 
 
 def test_tp_size_too_large_is_load_error():

@@ -98,7 +98,7 @@ def test_serving_yaml_and_env_keys(tmp_path):
 
 @pytest.mark.parametrize("key, value", [
     ("target_step_ms", 50.0), ("ragged", False), ("subwave", 2),
-    ("interleave", 2),
+    ("interleave", 2), ("spec_max_batch", 4), ("spec_max_active", 0),
 ])
 @pytest.mark.parametrize("surface", ["yaml", "engine dict", "remote push"])
 def test_target_step_ms_is_accepted_warned_once_and_ignored(
@@ -109,7 +109,10 @@ def test_target_step_ms_is_accepted_warned_once_and_ignored(
     ``target_step_ms: 50``) and remote pushes that still name
     ``target_step_ms``, ``ragged``, ``subwave`` or ``interleave`` keep
     loading, say so once a process, and change nothing — the batcher they
-    build is the default one, which serves ragged rounds."""
+    build is the default one, which serves ragged rounds. So do
+    ``spec_max_batch`` and ``spec_max_active``, which sized the waves of
+    the standalone tree decoder that is gone: they are no field of
+    ``ServingConfig`` any more, so a YAML's value is dropped on load."""
     import logging
 
     from distributed_gpu_inference_tpu.runtime.batcher import BatcherConfig
@@ -149,7 +152,10 @@ def test_target_step_ms_is_accepted_warned_once_and_ignored(
         sv = load()
     said = [r for r in caplog.records if f"serving.{key} " in r.getMessage()]
     assert len(said) == 1 and "deprecated" in said[0].getMessage()
-    assert sv[key] == value                     # accepted as written
+    if key in ServingConfig.model_fields or surface != "yaml":
+        assert sv[key] == value                 # accepted as written
+    else:
+        assert key not in sv                    # no field: dropped on load
     assert key not in SERVING_REMOTE_KEYS
     assert TPULLMEngine._batcher_config(sv) == BatcherConfig()
     assert not hasattr(BatcherConfig(), key)
@@ -220,8 +226,53 @@ def test_remote_pushable_keys_match_serving_config():
     assert set(SERVING_REMOTE_KEYS) <= fields
     assert set(SERVING_DEFAULTS) == fields
     for unpushable in ("ragged", "subwave", "interleave", "target_step_ms",
-                       "mode"):
+                       "spec_max_batch", "spec_max_active", "mode"):
         assert unpushable not in SERVING_REMOTE_KEYS
+
+
+def test_a_greedy_job_keeps_the_failover_hooks():
+    """Every queued job — a fresh greedy one too, under a configuration
+    that used to select the standalone tree decoder — is registered for
+    heartbeat checkpointing while it runs and is submitted with the drain
+    interrupt: no decoder waives crash-safe generation for the jobs it
+    takes. On a stub serving front-end: no engine is built."""
+    from types import SimpleNamespace
+
+    from distributed_gpu_inference_tpu.runtime.engine import EngineConfig
+    from distributed_gpu_inference_tpu.utils.data_structures import (
+        InferenceResponse,
+    )
+    from distributed_gpu_inference_tpu.worker.engines.llm import (
+        ByteTokenizer,
+        TPULLMEngine,
+    )
+
+    eng = TPULLMEngine({"model": "llama3-tiny", "engine": "jax-speculative"})
+    eng.tokenizer = ByteTokenizer()
+    eng.engine = SimpleNamespace(cfg=EngineConfig(enable_prefix_cache=False))
+    eng.loaded = True
+    seen: Dict[str, Any] = {}
+
+    class Serving:
+        active = True
+
+        def submit(self, req, resume_from=None, interrupt=None, flight=None):
+            seen.update(interrupt=interrupt, live=dict(eng._live),
+                        request_id=req.request_id)
+            return InferenceResponse(
+                request_id=req.request_id, token_ids=[70, 71],
+                prompt_tokens=len(req.prompt_token_ids),
+                finish_reason="length")
+
+    eng.serving = Serving()
+    out = eng._job_inference(
+        {"prompt": "hello", "temperature": 0.0, "max_tokens": 2},
+        {"key": "job-7", "epoch": 3})
+    assert out["usage"]["completion_tokens"] == 2
+    assert seen["interrupt"] is eng._interrupt
+    assert seen["live"] == {"job-7": {
+        "kind": "job", "epoch": 3, "request_id": seen["request_id"]}}
+    assert eng._live == {}                      # unregistered at the end
 
 
 # ---------------------------------------------------------------------------
