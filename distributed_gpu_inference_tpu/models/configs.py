@@ -96,6 +96,30 @@ class ModelConfig:
     # the router picks the top-k of ``score + bias`` (one learned value an
     # expert); the weights are the scores', the bias is not in them
     router_selection_bias: bool = False
+    # how the router scores its logits: ``softmax`` over all experts (the
+    # K/V recipe's default) or ``sigmoid`` an expert (the latent recipe's)
+    router_scoring: Optional[str] = None
+    # attention described per layer (the K/V recipe): ``"full"`` or
+    # ``"sliding"`` for each layer. A sliding layer attends the last
+    # ``sliding_window`` keys; where the two kinds are mixed it has
+    # ``sliding_num_heads`` query heads (0: ``num_heads``) over the same
+    # ``num_kv_heads`` and rotates a whole head at ``sliding_rope_theta``
+    # (0: ``rope_theta``), and its K/V pages lie in a pool of their own
+    # (``cache_kinds``). Empty: every layer alike, sliding where
+    # ``sliding_window`` is set -- the one-kind case of the same code.
+    layer_types: Tuple[str, ...] = ()
+    sliding_num_heads: int = 0
+    sliding_rope_theta: float = 0.0
+    # a full layer (every layer of a model that is not mixed) rotates the
+    # first ``partial_rotary_factor`` of a head's values, at frequencies
+    # blended by YaRN where ``rope_yarn`` = (factor, original positions,
+    # beta_fast, beta_slow, attention_factor) and cos / sin scaled by the
+    # last
+    partial_rotary_factor: float = 1.0
+    rope_yarn: Optional[Tuple[float, int, float, float, float]] = None
+    # one learned scalar a head a token, ``sigmoid(x W_g)``, multiplied
+    # into the head's attention output before ``W_o``
+    head_gate: bool = False
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
@@ -133,13 +157,20 @@ class ModelConfig:
                     f"{self.name}: an indexer with sliding_window is not "
                     "built: pages that left the window are released, the "
                     "selection reads every cached token")
+        if self.router_scoring is None:
+            object.__setattr__(
+                self, "router_scoring",
+                "sigmoid" if self.kv_lora_rank else "softmax")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"{self.name}: router_scoring {self.router_scoring!r}; use "
+                "'softmax' or 'sigmoid'")
+        self._check_layer_types()
         if not self.kv_lora_rank:
             # read by models/mla.py alone: models/llama.py would drop them
-            # without a word (a softmax router, every expert held, two
-            # norms a layer, every layer alike)
+            # without a word (two norms a layer, pages in every layer, a
+            # router with no bias)
             only_latent = [name for name, default in (
-                ("first_k_dense", 0), ("n_shared_experts", 0),
-                ("routed_scaling_factor", 1.0), ("held_experts", None),
                 ("sandwich_norm", False), ("full_attn_layers", ()),
                 ("kda_num_heads", 0), ("kda_head_dim", 0),
                 ("kda_conv_kernel", 0), ("mla_use_nope", False),
@@ -150,6 +181,26 @@ class ModelConfig:
                     f"{self.name}: {', '.join(only_latent)} without "
                     "kv_lora_rank: only the latent-attention model "
                     "(models/mla.py) reads them")
+            if self.described_per_layer:
+                # the per-layer description draws and reads its leaves by
+                # ``models/llama.leaf_specs``, which has none of these
+                unread = [name for name in (
+                    "attention_bias", "qk_norm", "norm_offset",
+                    "index_topk") if getattr(self, name)]
+                if unread:
+                    raise ValueError(
+                        f"{self.name}: {', '.join(unread)} on a model "
+                        "described per layer (layer_types, first_k_dense, "
+                        "n_shared_experts, held_experts, head_gate): its "
+                        "leaf specs have no such leaf")
+            if (self.first_k_dense or self.n_shared_experts
+                    or self.held_experts is not None
+                    or self.routed_scaling_factor != 1.0) \
+                    and not self.num_experts:
+                raise ValueError(
+                    f"{self.name}: first_k_dense, n_shared_experts, "
+                    "held_experts or routed_scaling_factor without "
+                    "num_experts: no expert layer would read them")
         elif self.head_dim not in (self.qk_head_dim,
                                    self.hidden_size // self.num_heads):
             raise ValueError(
@@ -190,9 +241,118 @@ class ModelConfig:
                     f"{self.num_experts} routed experts"
                 )
 
+    def _check_layer_types(self) -> None:
+        """What a per-layer description of attention must state, and what a
+        model of mixed kinds cannot do yet: refused here, when the
+        configuration is made, each with its reason."""
+        kinds = self.layer_types
+        sliding_only = [name for name, default in (
+            ("sliding_num_heads", 0), ("sliding_rope_theta", 0.0),
+        ) if getattr(self, name) != default]
+        if not kinds:
+            if sliding_only:
+                raise ValueError(
+                    f"{self.name}: {', '.join(sliding_only)} without "
+                    "layer_types of two kinds: no layer would read them")
+        else:
+            if len(kinds) != self.num_layers or \
+                    set(kinds) - {"full", "sliding"}:
+                raise ValueError(
+                    f"{self.name}: layer_types names 'full' or 'sliding' "
+                    f"for each of the {self.num_layers} layers")
+            if "sliding" in kinds and self.sliding_window is None:
+                raise ValueError(
+                    f"{self.name}: a sliding layer needs sliding_window")
+            if self.kv_lora_rank:
+                raise ValueError(
+                    f"{self.name}: layer_types over latent pages "
+                    "(kv_lora_rank) is not built: a window is a rule over "
+                    "K/V pages (models/llama.py)")
+            if len(set(kinds)) < 2 and sliding_only:
+                raise ValueError(
+                    f"{self.name}: {', '.join(sliding_only)} on a model of "
+                    "one attention kind: no layer would read them")
+        if self.sliding_num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"{self.name}: sliding_num_heads must be divisible by "
+                "num_kv_heads (K/V heads that differ by kind are not "
+                "built: both pools have num_kv_heads)")
+        if self.mixed_attention and self.index_topk:
+            raise ValueError(
+                f"{self.name}: an indexer on a model of mixed attention "
+                "kinds is not built: the index-key pool follows one block "
+                "table, and pages that left a window are released")
+        if not 0.0 < self.partial_rotary_factor <= 1.0 or \
+                int(self.head_dim * self.partial_rotary_factor) % 2:
+            raise ValueError(
+                f"{self.name}: partial_rotary_factor "
+                f"{self.partial_rotary_factor} must leave an even, "
+                "non-empty rotated width")
+        if self.rope_yarn is not None and (
+                len(self.rope_yarn) != 5 or self.rope_yarn[0] < 1.0):
+            raise ValueError(
+                f"{self.name}: rope_yarn is (factor >= 1, original "
+                "positions, beta_fast, beta_slow, attention_factor)")
+        if self.kv_lora_rank and (
+                self.partial_rotary_factor != 1.0 or self.rope_yarn
+                or self.head_gate):
+            raise ValueError(
+                f"{self.name}: partial_rotary_factor, rope_yarn and "
+                "head_gate are the K/V recipe's (models/llama.py); the "
+                "latent layers would not read them")
+
     @property
     def latent_kv(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def attn_kinds(self) -> Tuple[str, ...]:
+        """``"full"`` or ``"sliding"`` for each layer of a K/V model."""
+        if self.layer_types:
+            return tuple(self.layer_types)
+        kind = "full" if self.sliding_window is None else "sliding"
+        return (kind,) * self.num_layers
+
+    @property
+    def mixed_attention(self) -> bool:
+        """Layers of both attention kinds: pages per kind."""
+        return len(set(self.layer_types)) > 1
+
+    @property
+    def cache_kinds(self) -> Tuple[Tuple[str, int, Optional[int]], ...]:
+        """(kind, layers, window) of each K/V pool, the full kind first. A
+        model of one kind has one pool, with its window if it has one."""
+        kinds = self.attn_kinds
+        return tuple(
+            (kind, kinds.count(kind),
+             self.sliding_window if kind == "sliding" else None)
+            for kind in ("full", "sliding") if kind in kinds)
+
+    def heads_of(self, kind: str) -> int:
+        """Query heads of a layer of ``kind``."""
+        if kind == "sliding" and self.mixed_attention \
+                and self.sliding_num_heads:
+            return self.sliding_num_heads
+        return self.num_heads
+
+    def rope_of(self, kind: str) -> Tuple[
+            float, int, Optional[Tuple[float, int, float, float, float]]]:
+        """(theta, rotated width, YaRN or None) of a layer of ``kind``."""
+        if kind == "sliding" and self.mixed_attention:
+            return (self.sliding_rope_theta or self.rope_theta,
+                    self.head_dim, None)
+        return (self.rope_theta,
+                int(self.head_dim * self.partial_rotary_factor),
+                self.rope_yarn)
+
+    @property
+    def described_per_layer(self) -> bool:
+        """A K/V model whose layers are described one by one
+        (``models/llama.group_of`` / ``leaf_specs``): parameter stacks a
+        group, leaves drawn by name."""
+        return not self.kv_lora_rank and bool(
+            self.layer_types or self.first_k_dense or self.n_shared_experts
+            or self.held_experts is not None or self.head_gate)
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -255,6 +415,11 @@ class ModelConfig:
             return (self.vocab_size + head) * self.hidden_size + sum(
                 self.layer_params(li) for li in range(self.num_layers)
             ) + self.hidden_size
+        if self.described_per_layer:
+            head = 0 if self.tie_word_embeddings else self.vocab_size
+            return (self.vocab_size + head) * self.hidden_size + sum(
+                self.kv_layer_params(li) for li in range(self.num_layers)
+            ) + self.hidden_size
         h, i, v = self.hidden_size, self.mlp_width, self.vocab_size
         d = self.head_dim
         attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) + (
@@ -295,6 +460,21 @@ class ModelConfig:
             2 * self.num_kv_heads * self.head_dim
             + (self.index_head_dim if self.index_topk else 0)) * dtype_bytes
 
+    def kv_layer_params(self, layer: int) -> int:
+        """Parameters layer ``layer`` of a K/V model described per layer
+        stores here (the held experts only)."""
+        h, d = self.hidden_size, self.head_dim
+        nh = self.heads_of(self.attn_kinds[layer])
+        attn = 2 * h * nh * d + 2 * h * self.num_kv_heads * d \
+            + (h * nh if self.head_gate else 0)
+        norms = 2 * h + (2 * d if self.qk_norm_per_head else 0)
+        if layer < self.first_k_dense or not self.num_experts:
+            mlp = 3 * h * self.intermediate_size
+        else:
+            mlp = h * self.num_experts + 3 * h * self.mlp_width * (
+                self.num_held_experts + self.n_shared_experts)
+        return attn + norms + mlp
+
     def layer_params(self, layer: int) -> int:
         """Parameters layer ``layer`` of a latent-attention model stores
         here (the held experts only)."""
@@ -331,6 +511,8 @@ class ModelConfig:
         """Per-layer weight bytes — the shard planner's unit of placement."""
         if self.latent_kv:
             return self.layer_params(self.num_layers - 1) * dtype_bytes
+        if self.described_per_layer:
+            return self.kv_layer_params(self.num_layers - 1) * dtype_bytes
         h, i, d = self.hidden_size, self.mlp_width, self.head_dim
         attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) + (
             self.num_heads * d
@@ -502,6 +684,46 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
         rms_norm_eps=1e-6, num_experts=128, num_experts_per_tok=8,
         norm_topk_prob=True, qk_norm_per_head=True, index_topk=2048,
         index_num_heads=16, index_head_dim=64,
+    ),
+    # Laguna-S-2.1 -- window and full attention mixed layer by layer (period
+    # F,S,S,S) with a head count and a rotation per kind (full: the first
+    # half of a head under YaRN; sliding: the whole head, plain), a per-head
+    # output gate, a dense first layer, then softmax-routed experts scaled
+    # by 2.5 beside a shared one; K/V pages per layer kind
+    # (models/llama.py, runtime/kv_cache.py)
+    "laguna-tiny": _llama(  # test-scale: two periods and an odd end; a
+        # window several times shorter than every test context
+        "laguna-tiny", vocab_size=512, hidden_size=64, num_layers=10,
+        num_heads=4, num_kv_heads=2, intermediate_size=96,
+        moe_intermediate_size=32, head_dim=16,
+        max_position_embeddings=1024, rope_theta=500000.0,
+        rms_norm_eps=1e-6, sliding_window=16,
+        layer_types=("full", "sliding", "sliding") * 3 + ("full",),
+        sliding_num_heads=6, sliding_rope_theta=10000.0,
+        partial_rotary_factor=0.5,
+        rope_yarn=(8.0, 64, 32.0, 1.0, 1.2079441541679836),
+        head_gate=True, first_k_dense=1, n_shared_experts=1, num_experts=8,
+        num_experts_per_tok=3, norm_topk_prob=True,
+        routed_scaling_factor=2.5, held_experts=(0, 2),
+    ),
+    # one chip's share of the published model as 16 chips serve it: four
+    # pipeline stages of 12 layers, each stage's four chips sharing every
+    # layer. The first stage: layers 0-11 (three whole periods), 64 of the
+    # 256 routed experts, a quarter of the vocabulary; every width as
+    # published (benchmark/configs/laguna-s-2.1-ep4-12l-int8.json)
+    "laguna-s-2.1-ep4-12l": _llama(
+        "laguna-s-2.1-ep4-12l", vocab_size=25088, hidden_size=3072,
+        num_layers=12, num_heads=48, num_kv_heads=8,
+        intermediate_size=12288, moe_intermediate_size=1024, head_dim=128,
+        max_position_embeddings=24576, rope_theta=500000.0,
+        rms_norm_eps=1e-6, sliding_window=512,
+        layer_types=("full", "sliding", "sliding", "sliding") * 3,
+        sliding_num_heads=72, sliding_rope_theta=10000.0,
+        partial_rotary_factor=0.5,
+        rope_yarn=(128.0, 8192, 32.0, 1.0, 1.4852030263919618),
+        head_gate=True, first_k_dense=1, n_shared_experts=1,
+        num_experts=256, num_experts_per_tok=10, norm_topk_prob=True,
+        routed_scaling_factor=2.5, held_experts=(0, 64),
     ),
     "openpangu-ultra-moe-tiny": _llama(  # test-scale, every mechanism
         "openpangu-ultra-moe-tiny", vocab_size=512, hidden_size=64,

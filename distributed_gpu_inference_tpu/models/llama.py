@@ -46,14 +46,139 @@ KVPools = Dict[str, jax.Array]  # {"k": [L,N,Hkv,Bk,D], "v": [L,N,Hkv,Bk,D]}
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# Layers described one by one: attention kind and MLP kind a layer
+# ---------------------------------------------------------------------------
+
+_FULL = "full_"         # a full-attention layer's stack in a mixed model
+WINDOW_POOLS = "_win"           # the sliding kind's pools in a mixed model's ``kv``
+
+
+def group_of(cfg: ModelConfig, layer: int) -> str:
+    """The parameter stack layer ``layer`` (0-based) lies in: by its MLP
+    (``dense_layers`` / ``layers``) and, for a full-attention layer of a
+    model of mixed kinds, the prefix ``full_``. A model whose layers are
+    all alike has the one stack ``layers``."""
+    lead = cfg.first_k_dense if cfg.num_experts else 0
+    name = "dense_layers" if layer < lead else "layers"
+    if cfg.mixed_attention and cfg.attn_kinds[layer] == "full":
+        return _FULL + name
+    return name
+
+
+def group_kind(cfg: ModelConfig, group: str) -> str:
+    """The attention kind of a stack's layers."""
+    if cfg.mixed_attention:
+        return "full" if group.startswith(_FULL) else "sliding"
+    return cfg.attn_kinds[0]
+
+
+def layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, int], ...]:
+    """(params key, layers) of the homogeneous stacks, in a fixed order."""
+    names = [group_of(cfg, li) for li in range(cfg.num_layers)]
+    order = (_FULL + "dense_layers", _FULL + "layers", "dense_layers",
+             "layers")
+    return tuple((g, names.count(g)) for g in order if g in names)
+
+
+def layer_units(cfg: ModelConfig
+                ) -> Tuple[Tuple[int, Tuple[Tuple[str, int], ...]], ...]:
+    """The forward pass as ``(repeat, runs)`` units in layer order, ``runs``
+    the ``(params key, layers)`` of a unit's homogeneous stretches. A model
+    whose layers are all alike is one unit of one run; a model of mixed
+    kinds is cut before every full layer and equal neighbours merge, so
+    that a repeated period is traced once and scanned over its repeats, the
+    odd ends once."""
+    names = [group_of(cfg, li) for li in range(cfg.num_layers)]
+    kinds = cfg.attn_kinds
+    cuts = [li for li in range(1, cfg.num_layers)
+            if cfg.mixed_attention and kinds[li] == "full"]
+    units: list = []
+    for lo, hi in zip([0] + cuts, cuts + [cfg.num_layers]):
+        runs: list = []
+        for name in names[lo:hi]:
+            if runs and runs[-1][0] == name:
+                runs[-1] = (name, runs[-1][1] + 1)
+            else:
+                runs.append((name, 1))
+        if units and units[-1][1] == tuple(runs):
+            units[-1] = (units[-1][0] + 1, units[-1][1])
+        else:
+            units.append((1, tuple(runs)))
+    return tuple(units)
+
+
+def leaf_specs(cfg: ModelConfig, group: str
+               ) -> Dict[str, Tuple[tuple, int, str]]:
+    """name -> (shape of ONE layer, fan-in, kind) for the leaves of a stack
+    of a model described per layer, kinds as ``models/mla.leaf_specs`` has
+    them (``q`` a matmul weight, ``d`` a dense bf16 weight, ``n`` a norm
+    vector). ``W_q`` / ``W_o`` and the gate follow the layer kind's head
+    count; the gate is one column a head (under the int8 kernel's
+    128-column tiles: kept in the activation dtype, like the router)."""
+    h, d, nkv = cfg.hidden_size, cfg.head_dim, cfg.num_kv_heads
+    nh = cfg.heads_of(group_kind(cfg, group))
+    spec: Dict[str, Tuple[tuple, int, str]] = {
+        "attn_norm": ((h,), 0, "n"),
+        "wq": ((h, nh * d), h, "q"),
+        "wk": ((h, nkv * d), h, "q"),
+        "wv": ((h, nkv * d), h, "q"),
+        "wo": ((nh * d, h), nh * d, "q"),
+        "mlp_norm": ((h,), 0, "n"),
+    }
+    if cfg.head_gate:
+        spec["w_hgate"] = ((h, nh), h, "d")
+    if cfg.qk_norm_per_head:
+        spec["q_norm"] = ((d,), 0, "n")
+        spec["k_norm"] = ((d,), 0, "n")
+    if group.endswith("dense_layers") or not cfg.num_experts:
+        i = cfg.intermediate_size
+        spec.update({
+            "w_gate": ((h, i), h, "q"),
+            "w_up": ((h, i), h, "q"),
+            "w_down": ((i, h), i, "q"),
+        })
+    else:
+        mi, held = cfg.mlp_width, cfg.num_held_experts
+        spec.update({
+            "w_router": ((h, cfg.num_experts), h, "d"),
+            "we_gate": ((held, h, mi), h, "q"),
+            "we_up": ((held, h, mi), h, "q"),
+            "we_down": ((held, mi, h), mi, "q"),
+        })
+        if cfg.n_shared_experts:
+            ms = mi * cfg.n_shared_experts
+            spec.update({
+                "ws_gate": ((h, ms), h, "q"),
+                "ws_up": ((h, ms), h, "q"),
+                "ws_down": ((ms, h), ms, "q"),
+            })
+    return spec
+
+
 def init_params(
-    cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = None
+    cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = None,
+    mode: Optional[str] = None,
 ) -> Params:
-    """Random-init params with the exact pytree layout the engine shards."""
+    """Random-init params with the exact pytree layout the engine shards.
+    ``mode``: quantized as drawn, a layer at a time (a model described per
+    layer or of latent attention; the others quantize a drawn tree)."""
     if cfg.latent_kv:
         from distributed_gpu_inference_tpu.models import mla
 
-        return mla.init_params(cfg, key, dtype)
+        return mla.init_params(cfg, key, dtype, mode)
+    if cfg.described_per_layer:
+        from distributed_gpu_inference_tpu.models import mla
+
+        return mla.init_params(cfg, key, dtype, mode,
+                               groups=layer_groups(cfg),
+                               specs=functools.partial(leaf_specs, cfg))
+    if mode is not None:
+        from distributed_gpu_inference_tpu.ops.quantization import (
+            quantize_params,
+        )
+
+        return quantize_params(init_params(cfg, key, dtype), mode)
     dtype = dtype or jnp.dtype(cfg.dtype)
     h, d = cfg.hidden_size, cfg.head_dim
     nh, nkv, i = cfg.num_heads, cfg.num_kv_heads, cfg.mlp_width
@@ -160,9 +285,15 @@ def init_kv_pools(
     block_size: int = 16,
     dtype: Optional[jnp.dtype] = None,
     state_rows: Optional[int] = None,
+    window_blocks: Optional[int] = None,
 ) -> KVPools:
     """Device-resident paged KV pools. Block 0 is reserved as the garbage/pad
     block — writes for padded tokens land there and reads mask it out.
+
+    A model of mixed attention kinds (``cfg.mixed_attention``) has a pool a
+    kind: ``"k"`` / ``"v"`` ``[L_full, N, ...]`` for its full layers and
+    ``"k_win"`` / ``"v_win"`` ``[L_sliding, window_blocks, ...]`` for its
+    sliding ones, block 0 of each the pad block.
 
     Layout ``[L, N, Hkv, Bk, D]`` (head-major pages, like vLLM's pools and
     the reference's CacheBlock [max_blocks, heads, block, head_dim],
@@ -189,6 +320,24 @@ def init_kv_pools(
         return mla.init_kv_pools(cfg, num_blocks, block_size, dtype,
                                  state_rows)
     dtype = jnp.dtype(dtype or cfg.dtype)
+    if cfg.mixed_attention:
+        # pages per layer kind: a pool for the full layers and one for the
+        # sliding ones, each under a block table of its own
+        if dtype.itemsize == 1:
+            raise NotImplementedError(
+                f"{cfg.name}: int8 / fp8 pools of a model of mixed "
+                "attention kinds are not built")
+        if not window_blocks or window_blocks < 2:
+            raise ValueError(
+                f"{cfg.name}: the sliding kind's pool needs its number of "
+                "blocks")
+        pools = {}
+        for (kind, layers, _), n in zip(cfg.cache_kinds,
+                                        (num_blocks, window_blocks)):
+            shape = (layers, n, cfg.num_kv_heads, block_size, cfg.head_dim)
+            for name in kind_pools(cfg, kind):
+                pools[name] = jnp.zeros(shape, dtype)
+        return pools
     shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size, cfg.head_dim)
     pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if cfg.index_topk:
@@ -204,6 +353,13 @@ def init_kv_pools(
         pools["k_scale"] = jnp.zeros(sshape, jnp.bfloat16)
         pools["v_scale"] = jnp.zeros(sshape, jnp.bfloat16)
     return pools
+
+
+def kind_pools(cfg: ModelConfig, kind: str) -> Tuple[str, str]:
+    """The names of a layer kind's K and V pools in ``KVPools``."""
+    if cfg.mixed_attention and kind == "sliding":
+        return "k" + WINDOW_POOLS, "v" + WINDOW_POOLS
+    return "k", "v"
 
 
 # ---------------------------------------------------------------------------
@@ -241,11 +397,62 @@ def _rope_angles(positions: jax.Array, head_dim: int, theta: float) -> Tuple[jax
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def rope_inv_freq(cfg: ModelConfig, kind: str) -> Tuple[jax.Array, float]:
+    """(inverse frequencies ``[rotated / 2]``, cos / sin scale) of a layer
+    kind's rotation. Under YaRN the frequencies blend interpolation
+    (``1 / (factor x f)``) and extrapolation (``1 / f``) by a linear ramp
+    over the rotated pairs between the two correction dims, as Hugging
+    Face's ``_compute_yarn_parameters`` (truncated) computes them, and cos /
+    sin are scaled by the attention factor."""
+    theta, rot, yarn = cfg.rope_of(kind)
+    half = rot // 2
+    freqs = theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        return 1.0 / freqs, 1.0
+    import math
+
+    factor, original, beta_fast, beta_slow, attention_factor = yarn
+
+    def correction_dim(rotations: float) -> float:
+        return rot * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    extrapolation = 1.0 - ramp
+    inv = (1.0 / (factor * freqs)) * (1.0 - extrapolation) \
+        + (1.0 / freqs) * extrapolation
+    return inv, float(attention_factor)
+
+
+def rope_tables(cfg: ModelConfig, kind: str, positions: jax.Array
+                ) -> Tuple[jax.Array, jax.Array]:
+    """(cos, sin) ``[..., S, rotated / 2]`` float32 of a layer kind, computed
+    once a graph: ``apply_rope`` rotates as many of a head's leading values
+    as they cover."""
+    theta, rot, yarn = cfg.rope_of(kind)
+    if yarn is None and rot == cfg.head_dim:
+        return _rope_angles(positions, cfg.head_dim, theta)
+    inv_freq, scale = rope_inv_freq(cfg, kind)
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """Half-split RoPE (HF Llama ``rotate_half`` convention).
 
-    x: [B, S, H, D]; cos/sin: [B, S, D/2] broadcast over heads.
+    x: [B, S, H, D]; cos/sin: [B, S, R/2] broadcast over heads, ``R`` the
+    rotated width: the head's first ``R`` values are rotated, the others
+    pass as they are (``R = D``: the whole head).
     """
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     c = cos[..., None, :]  # [B, S, 1, D/2]
@@ -332,16 +539,19 @@ def _moe_mlp(
     lp: Dict[str, jax.Array],
     cfg: ModelConfig,
     *,
+    proj=None,                          # the layer's projections (a shared
+                                        # expert's matmuls go through it)
     live: Optional[jax.Array] = None,   # [B, S] bool: tokens that are routed
     pallas: bool = True,
     stacked: Optional[Dict[str, Any]] = None,
     layer_idx: Any = 0,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]], jax.Array]:
-    """Sparse MoE MLP: softmax over all router logits in float32, keep the
-    top-k, renormalise them if ``cfg.norm_topk_prob`` (Mixtral does, OLMoE
-    does not), ``sum_e p_e * down_e(act(gate_e(x)) * up_e(x))`` over the
-    kept experts. Two forms of that mathematics, chosen as the other
-    kernels are (``pallas`` and the backend, no option):
+    """Sparse MoE MLP: the router (``route_experts``: softmax over all
+    logits in float32, the top-k kept, renormalised if
+    ``cfg.norm_topk_prob`` -- Mixtral does, OLMoE does not), then
+    ``sum_e p_e * down_e(act(gate_e(x)) * up_e(x))`` over the kept experts.
+    Two forms of that mathematics, chosen as the other kernels are
+    (``pallas`` and the backend, no option):
 
     - **routed** (``pallas=True``: every one-device path). Compute follows
       the ``T x k`` (token, expert) pairs, no ``[T, E, ...]`` tensor
@@ -368,41 +578,104 @@ def _moe_mlp(
       No counters. The routed form under a mesh (``shard_map`` around the
       kernel) is the open upgrade.
     """
+    if pallas or cfg.held_experts is not None or cfg.n_shared_experts:
+        # (a share of the experts and a shared expert have the routed form
+        # alone: nothing shards them over a mesh yet)
+        return expert_layer(x, lp, cfg, proj, live=live, stacked=stacked,
+                            layer_idx=layer_idx)
     act = _mlp_act(cfg.activation)
     b, s, h = x.shape
-    t, k, num_e = b * s, cfg.num_experts_per_tok, cfg.num_experts
+    t = b * s
     xf = x.reshape(t, h)                                       # [T, H]
-    # router math in float32: top-k selection is precision-sensitive
-    logits = (xf.astype(jnp.float32) @ lp["w_router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                    # [T, E]
-    topv, topi = lax.top_k(probs, k)                           # [T, k]
-    if cfg.norm_topk_prob:
-        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    topv, topi = route_experts(cfg, xf, lp["w_router"])        # [T, k]
+    # scatter the kept top-k back to a dense [T, E] combine weight
+    weights = jnp.zeros((t, cfg.num_experts), jnp.float32).at[
+        jnp.arange(t)[:, None], topi
+    ].set(topv)                                                # [T, E]
+    gate = act(jnp.einsum("th,ehi->tei", xf, _deq(lp["we_gate"], x.dtype)))
+    up = jnp.einsum("th,ehi->tei", xf, _deq(lp["we_up"], x.dtype))
+    per_expert = jnp.einsum(
+        "tei,eih->teh", gate * up, _deq(lp["we_down"], x.dtype)
+    )                                                          # [T, E, H]
+    out = jnp.einsum(
+        "te,teh->th", weights.astype(jnp.float32),
+        per_expert.astype(jnp.float32),
+    )
+    return out.reshape(b, s, h).astype(x.dtype), None, topi
 
-    if not pallas:
-        # scatter the kept top-k back to a dense [T, E] combine weight
-        weights = jnp.zeros_like(probs).at[
-            jnp.arange(t)[:, None], topi
-        ].set(topv)                                            # [T, E]
-        gate = act(jnp.einsum("th,ehi->tei", xf, _deq(lp["we_gate"], x.dtype)))
-        up = jnp.einsum("th,ehi->tei", xf, _deq(lp["we_up"], x.dtype))
-        per_expert = jnp.einsum(
-            "tei,eih->teh", gate * up, _deq(lp["we_down"], x.dtype)
-        )                                                      # [T, E, H]
-        out = jnp.einsum(
-            "te,teh->th", weights.astype(jnp.float32),
-            per_expert.astype(jnp.float32),
-        )
-        return out.reshape(b, s, h).astype(x.dtype), None, topi
 
+def route_experts(cfg: ModelConfig, x: jax.Array, w_router: jax.Array,
+                  bias: Optional[jax.Array] = None
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """The router of every expert layer: scores over ALL published experts
+    in float32 (``cfg.router_scoring``: softmax over the logits, or a
+    sigmoid an expert), the top-k kept (no groups), renormalised where
+    ``cfg.norm_topk_prob`` and scaled by ``cfg.routed_scaling_factor`` ->
+    (weights [T, k], experts [T, k]). ``bias``
+    (``cfg.router_selection_bias``) moves which experts are kept and is not
+    in their weights. Top-k selection is precision-sensitive: float32."""
+    logits = x.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    k = cfg.num_experts_per_tok
+    if cfg.router_scoring == "softmax":
+        topv, topi = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        if cfg.norm_topk_prob:
+            topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        if bias is None:
+            topv, topi = lax.top_k(scores, k)
+        else:
+            _, topi = lax.top_k(scores + bias.astype(jnp.float32), k)
+            topv = jnp.take_along_axis(scores, topi, axis=-1)
+        if cfg.norm_topk_prob:
+            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+    if cfg.routed_scaling_factor != 1.0:
+        topv = topv * cfg.routed_scaling_factor
+    return topv, topi
+
+
+def expert_layer(
+    x: jax.Array, lp: Dict[str, Any], cfg: ModelConfig, proj, *,
+    live: Optional[jax.Array], stacked: Optional[Dict[str, Any]],
+    layer_idx: Any,
+) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """THE routed expert layer, of both recipes (``_moe_mlp`` here,
+    ``models/mla._experts``): ``E_shared(m) + sum over the kept experts
+    held HERE of w_e E_e(m)``, the counters of the routed part, and every
+    token's experts ``[T, k]``. The router scores all published experts
+    (``route_experts``); a pair that falls on an expert held elsewhere
+    (``cfg.held_experts``: the chip's share of an expert-parallel layer) is
+    routed nowhere and reads nothing, and nothing stands in for it; tokens
+    that are not ``live`` are routed nowhere either. The routed sum is
+    ``_routed_sum`` over the live pairs."""
     from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
 
+    b, s, h = x.shape
+    t, k = b * s, cfg.num_experts_per_tok
+    act = _mlp_act(cfg.activation)
+    xf = x.reshape(t, h)
+    topv, topi = route_experts(
+        cfg, xf, lp["w_router"],
+        lp["router_bias"] if cfg.router_selection_bias else None)
     live = jnp.ones((t,), bool) if live is None else live.reshape(t)
-    out, plan = _routed_sum(xf, lp, topv, topi, live, num_e, t * k, act,
-                            stacked=stacked, layer_idx=layer_idx,
-                            decode=s == 1)
-    return (out.reshape(b, s, h).astype(x.dtype),
-            moe_gmm.expert_stats(plan), topi)
+    if cfg.held_experts is None:
+        local, held, count = topi, live, cfg.num_experts
+    else:
+        first, count = cfg.held_experts
+        local = topi - first
+        held = live[:, None] & (local >= 0) & (local < count)     # [T, k]
+        local = jnp.clip(local, 0, count - 1)
+    out, plan = _routed_sum(
+        xf, lp, topv, local, held, count,
+        max(t * k * count // cfg.num_experts, 1), act,
+        stacked=stacked, layer_idx=layer_idx, decode=s == 1)
+    if "ws_gate" in lp or (stacked is not None and "ws_gate" in stacked):
+        shared = proj(act(proj(x, "ws_gate")) * proj(x, "ws_up"), "ws_down")
+        out = out + shared.reshape(t, h).astype(jnp.float32)
+    stats = moe_gmm.expert_stats(plan)
+    if cfg.held_experts is not None or cfg.latent_kv:
+        stats["pairs_routed"] = jnp.sum(live, dtype=jnp.int32) * k
+    return out.reshape(b, s, h).astype(x.dtype), stats, topi
 
 
 def _routed_sum(
@@ -543,11 +816,13 @@ def _in_place_kv(
     block_size: int,
     token_index: Optional[jax.Array] = None,
     num_tokens: Optional[int] = None,
+    kind: Optional[str] = None,
 ):
     """``_layer_step``'s ``in_place`` for a multi-token chunk on the kernel
     path, or None where ``ragged_kv_path`` says ``layer_copy``: the page
-    write plan (the same for every layer, so built here, outside the
-    scan) and attention over the stacked pools."""
+    write plan (the same for every layer of a kind -- ``block_tables`` is
+    the kind's -- so built here, outside the scan) and attention over the
+    kind's stacked pools."""
     if positions.shape[1] == 1 or ragged_kv_path(
         cfg, block_tables.shape[1] * block_size, "k_scale" in kv
     ) != "in_place":
@@ -556,7 +831,9 @@ def _in_place_kv(
         page_write_plan, ragged_paged_attention,
     )
 
-    pool = kv["k"]
+    kind = kind or cfg.attn_kinds[0]
+    window = cfg.sliding_window if kind == "sliding" else None
+    pool = kv[kind_pools(cfg, kind)[0]]
     plan = page_write_plan(
         block_tables, positions, block_size,
         page_bytes=pool.shape[2] * pool.shape[3] * pool.shape[4]
@@ -567,7 +844,7 @@ def _in_place_kv(
     def attn_stacked(q, k_pool, v_pool, layer_idx, keep=None):
         return ragged_paged_attention(
             q, k_pool, v_pool, block_tables, positions, kv_lens, block_size,
-            window=cfg.sliding_window, layer_idx=layer_idx, keep=keep,
+            window=window, layer_idx=layer_idx, keep=keep,
         )
 
     return plan, attn_stacked
@@ -721,6 +998,10 @@ def _layer_step(
                                   # (``_in_place_kv``)
     index=None,                   # an indexer's view of the chunk, the same
                                   # for every layer (``_index_plan``)
+    kind: Optional[str] = None,   # the layer's attention kind: its head
+                                  # count and window (None: the model's one)
+    cache_delta: Any = 0,         # the layer's place in its kind's pools
+                                  # less its place in its parameter stack
 ) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array],
            Tuple[Optional[jax.Array], Optional[Dict[str, jax.Array]],
                  Optional[jax.Array]]]:
@@ -772,6 +1053,12 @@ def _layer_step(
     from the packed axis straight into page-shaped updates."""
     hidden, k_ent, v_ent, layer_idx, *more = carry
     ki_pool, scan_keys = (*more, None, None)[:2]
+    kind = kind or cfg.attn_kinds[0]
+    window = cfg.sliding_window if kind == "sliding" else None
+    # weights are addressed by ``layer_idx``, pages by ``cache_layer``: the
+    # same number where every layer is of one kind
+    cache_layer = layer_idx if isinstance(cache_delta, int) \
+        and cache_delta == 0 else layer_idx + cache_delta
     # int8-KV pools travel as (pool, scale_pool) tuples through the scan
     # carry; bf16 pools stay bare arrays (static structure, zero overhead)
     quant_kv = isinstance(k_ent, tuple)
@@ -782,7 +1069,7 @@ def _layer_step(
         k_pool, v_pool = k_ent, v_ent
         k_scale_pool = v_scale_pool = None
     b, s, _ = hidden.shape
-    nh, nkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    nh, nkv, d = cfg.heads_of(kind), cfg.num_kv_heads, cfg.head_dim
 
     def proj(x_, name):
         if stacked is not None and name in stacked:
@@ -832,14 +1119,14 @@ def _layer_step(
 
             with jax.named_scope("dgi_index"):
                 ki_pool = index_select.write_index_keys(
-                    ki_pool, kin.reshape(-1, cfg.index_head_dim), layer_idx,
+                    ki_pool, kin.reshape(-1, cfg.index_head_dim), cache_layer,
                     *index.scatter)
                 if scan_keys is not None:
                     scan_keys = index_select.append_scan_keys(
-                        scan_keys, kin[:, 0, 0], layer_idx,
+                        scan_keys, kin[:, 0, 0], cache_layer,
                         write_positions[:, 0])
                 keep = index_select.select(
-                    qi, wts, ki_pool, layer_idx, block_tables,
+                    qi, wts, ki_pool, cache_layer, block_tables,
                     write_positions, kv_lens, cfg.index_topk,
                     kernels=fused_decode or in_place is not None,
                     scan_keys=scan_keys,
@@ -863,17 +1150,17 @@ def _layer_step(
                 attn, k_pool, v_pool, k_scale_pool, v_scale_pool = \
                     paged_decode_attention_fused(
                         q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
-                        k_pool, v_pool, layer_idx, block_tables,
+                        k_pool, v_pool, cache_layer, block_tables,
                         write_positions, kv_lens, block_size,
-                        window=cfg.sliding_window,
+                        window=window,
                         k_scale=k_scale_pool, v_scale=v_scale_pool,
                     )
             else:
                 attn, k_pool, v_pool = paged_decode_attention_fused(
                     q, k.astype(k_pool.dtype), v.astype(v_pool.dtype),
-                    k_pool, v_pool, layer_idx, block_tables,
+                    k_pool, v_pool, cache_layer, block_tables,
                     write_positions, kv_lens, block_size,
-                    window=cfg.sliding_window, **sel,
+                    window=window, **sel,
                 )
         elif in_place is not None:
             from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
@@ -883,12 +1170,12 @@ def _layer_step(
             plan, attn_stacked = in_place
             k_pool, v_pool = write_kv_pages_in_place(
                 k.reshape(-1, nkv, d), v.reshape(-1, nkv, d),
-                k_pool, v_pool, layer_idx, plan,
+                k_pool, v_pool, cache_layer, plan,
             )
-            attn = attn_stacked(q, k_pool, v_pool, layer_idx, **sel)
+            attn = attn_stacked(q, k_pool, v_pool, cache_layer, **sel)
         else:
-            layer_k = lax.dynamic_index_in_dim(k_pool, layer_idx, 0, keepdims=False)
-            layer_v = lax.dynamic_index_in_dim(v_pool, layer_idx, 0, keepdims=False)
+            layer_k = lax.dynamic_index_in_dim(k_pool, cache_layer, 0, keepdims=False)
+            layer_v = lax.dynamic_index_in_dim(v_pool, cache_layer, 0, keepdims=False)
             layer_ks = layer_vs = None
             if quant_kv:
                 from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
@@ -899,9 +1186,9 @@ def _layer_step(
                 k_q, k_s = _quantize_token_rows(k.astype(jnp.float32), (2, 3))
                 v_q, v_s = _quantize_token_rows(v.astype(jnp.float32), (2, 3))
                 layer_ks = lax.dynamic_index_in_dim(
-                    k_scale_pool, layer_idx, 0, keepdims=False)
+                    k_scale_pool, cache_layer, 0, keepdims=False)
                 layer_vs = lax.dynamic_index_in_dim(
-                    v_scale_pool, layer_idx, 0, keepdims=False)
+                    v_scale_pool, cache_layer, 0, keepdims=False)
                 layer_k = _write_kv_pages(
                     layer_k, k_q, block_tables, write_positions, block_size)
                 layer_v = _write_kv_pages(
@@ -913,14 +1200,14 @@ def _layer_step(
                     layer_vs, jnp.broadcast_to(v_s[:, :, 0, :], (*v.shape[:2], d)),
                     block_tables, write_positions, block_size)
                 k_scale_pool = lax.dynamic_update_index_in_dim(
-                    k_scale_pool, layer_ks, layer_idx, 0)
+                    k_scale_pool, layer_ks, cache_layer, 0)
                 v_scale_pool = lax.dynamic_update_index_in_dim(
-                    v_scale_pool, layer_vs, layer_idx, 0)
+                    v_scale_pool, layer_vs, cache_layer, 0)
             else:
                 layer_k = _write_kv_pages(layer_k, k, block_tables, write_positions, block_size)
                 layer_v = _write_kv_pages(layer_v, v, block_tables, write_positions, block_size)
-            k_pool = lax.dynamic_update_index_in_dim(k_pool, layer_k, layer_idx, 0)
-            v_pool = lax.dynamic_update_index_in_dim(v_pool, layer_v, layer_idx, 0)
+            k_pool = lax.dynamic_update_index_in_dim(k_pool, layer_k, cache_layer, 0)
+            v_pool = lax.dynamic_update_index_in_dim(v_pool, layer_v, cache_layer, 0)
             if dense_attn_fn is not None:
                 # pages written above for decode; attention itself runs over the
                 # chunk's dense K/V (== whole context for a from-scratch prefill)
@@ -945,13 +1232,19 @@ def _layer_step(
 
         if unpack is not None:
             attn = attn.at[tok_row, tok_col].get(mode="fill", fill_value=0)
+        if "w_hgate" in lp:
+            # one scalar a head a token, from the layer's normed input
+            gate = jax.nn.sigmoid(
+                proj(x, "w_hgate").astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate[..., None]).astype(
+                attn.dtype)
         hidden = hidden + proj(attn.reshape(b, s, nh * d), "wo").astype(hidden.dtype)
     with jax.named_scope("dgi_experts" if "w_router" in lp else "dgi_mlp"):
         mlp_in = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         moe_stats = routing = None
         if "w_router" in lp:
             moe_out, moe_stats, routing = _moe_mlp(
-                mlp_in, lp, cfg, live=moe_live, pallas=pallas,
+                mlp_in, lp, cfg, proj=proj, live=moe_live, pallas=pallas,
                 stacked=stacked, layer_idx=layer_idx,
             )
             hidden = hidden + moe_out
@@ -1056,12 +1349,17 @@ def forward_chunk(
             positions, mode="drop")
     else:
         rope_positions = positions
-    in_place = None
-    if pallas and dense_attn_fn is None and attn_override is None:
-        in_place = _in_place_kv(
-            cfg, kv, block_tables, positions, kv_lens, block_size,
-            token_index=to_rect, num_tokens=tp,
-        )
+    mixed = cfg.mixed_attention
+    if mixed and (dense_attn_fn is not None or attn_override is not None
+                  or collect_layers is not None or "k_scale" in kv):
+        raise NotImplementedError(
+            "a model of mixed attention kinds has no sequence-parallel, "
+            "overridden or feature-collecting forward and no int8 pools")
+    # a block table a cache kind, side by side in ``block_tables``
+    kinds = [kind for kind, _, _ in cfg.cache_kinds]
+    m_cols = block_tables.shape[1] // len(kinds)
+    tables = {kind: block_tables[:, i * m_cols:(i + 1) * m_cols] if mixed
+              else block_tables for i, kind in enumerate(kinds)}
     index = None
     if cfg.index_topk:
         if (dense_attn_fn is not None or attn_override is not None
@@ -1076,76 +1374,180 @@ def forward_chunk(
     hidden = embed_tokens(params, token_ids, cfg)
 
     safe_pos = jnp.maximum(rope_positions, 0)
-    cos, sin = _rope_angles(safe_pos, cfg.head_dim, cfg.rope_theta)
-
     quant_kv = "k_scale" in kv
-    if attn_override is not None:
-        # int8 pools: the override receives the layer's scale pools too —
-        # the seq-sharded shard_map ops dequantize their local page shards
-        # (scales ride the same block axis; parallel/ring_attention.py)
-        def attn_fn(q, layer_k, layer_v, layer_ks=None, layer_vs=None):
-            return attn_override(
-                q, layer_k, layer_v, block_tables, positions, kv_lens,
-                layer_ks, layer_vs,
-            )
-    else:
-        def attn_fn(q, layer_k, layer_v, layer_ks=None, layer_vs=None,
-                    **sel):
-            return paged_attention(
-                q, layer_k, layer_v, block_tables, positions, kv_lens,
-                block_size, impl="auto" if pallas else "xla",
-                window=cfg.sliding_window,
-                k_scale=layer_ks, v_scale=layer_vs, **sel,
-            )
 
-    scanned, stacked = _split_layers(params["layers"], pallas)
-    step = functools.partial(
-        _layer_step,
-        cfg,
-        block_size,
-        block_tables=block_tables,
-        write_positions=positions,
-        cos=cos,
-        sin=sin,
-        attn_fn=attn_fn,
-        fused_decode=(
-            pallas
-            and _use_fused_decode(cfg, s, block_tables, block_size)
-            and dense_attn_fn is None
-            and attn_override is None
-            and packing is None
-        ),
-        kv_lens=kv_lens,
-        stacked=stacked,
-        dense_attn_fn=dense_attn_fn,
-        emit_hidden=collect_layers is not None,
-        pallas=pallas,
-        unpack=unpack,
-        moe_live=rope_positions >= 0,
-        emit_routing=collect_routing,
-        in_place=in_place,
-        index=index,
-    )
-    k0 = (kv["k"], kv["k_scale"]) if quant_kv else kv["k"]
-    v0 = (kv["v"], kv["v_scale"]) if quant_kv else kv["v"]
-    (hidden, k_out, v_out, _, *ki_out), (layer_hs, moe, routing, fetched) = \
-        lax.scan(
-            lambda c, lp: step(c, lp),
-            (hidden, k0, v0, jnp.int32(0),
-             *(() if index is None else
-               (kv[name] for name in (INDEX_KEYS, INDEX_SCAN_KEYS)
-                if name in kv))),
-            scanned,
+    def kind_step(kind):
+        """``_layer_step`` with what a layer kind fixes, built once a
+        graph: its block table, rotation, window and attention calls."""
+        kind_tables = tables[kind]
+        window = cfg.sliding_window if kind == "sliding" else None
+        cos, sin = rope_tables(cfg, kind, safe_pos)
+        in_place = None
+        if pallas and dense_attn_fn is None and attn_override is None:
+            in_place = _in_place_kv(
+                cfg, kv, kind_tables, positions, kv_lens, block_size,
+                token_index=to_rect, num_tokens=tp, kind=kind,
+            )
+        if attn_override is not None:
+            # int8 pools: the override receives the layer's scale pools too
+            # -- the seq-sharded shard_map ops dequantize their local page
+            # shards (scales ride the same block axis;
+            # parallel/ring_attention.py)
+            def attn_fn(q, layer_k, layer_v, layer_ks=None, layer_vs=None):
+                return attn_override(
+                    q, layer_k, layer_v, kind_tables, positions, kv_lens,
+                    layer_ks, layer_vs,
+                )
+        else:
+            def attn_fn(q, layer_k, layer_v, layer_ks=None, layer_vs=None,
+                        **sel):
+                return paged_attention(
+                    q, layer_k, layer_v, kind_tables, positions, kv_lens,
+                    block_size, impl="auto" if pallas else "xla",
+                    window=window,
+                    k_scale=layer_ks, v_scale=layer_vs, **sel,
+                )
+
+        return functools.partial(
+            _layer_step,
+            cfg,
+            block_size,
+            block_tables=kind_tables,
+            write_positions=positions,
+            cos=cos,
+            sin=sin,
+            attn_fn=attn_fn,
+            fused_decode=(
+                pallas
+                and _use_fused_decode(cfg, s, kind_tables, block_size)
+                and dense_attn_fn is None
+                and attn_override is None
+                and packing is None
+            ),
+            kv_lens=kv_lens,
+            dense_attn_fn=dense_attn_fn,
+            emit_hidden=collect_layers is not None,
+            pallas=pallas,
+            unpack=unpack,
+            moe_live=rope_positions >= 0,
+            emit_routing=collect_routing,
+            in_place=in_place,
+            index=index,
+            kind=kind,
         )
-    if moe is not None:
-        moe = {name: jnp.sum(v) for name, v in moe.items()}
+
+    steps = {kind: kind_step(kind) for kind in kinds}
+    groups = layer_groups(cfg)
+    sizes = dict(groups)
+    split = {group: _split_layers(params[group], pallas)
+             for group, _ in groups}
+    pools = {
+        kind: tuple((kv[name], kv[name + "_scale"]) if quant_kv else kv[name]
+                    for name in kind_pools(cfg, kind))
+        for kind in kinds}
+    extra = () if index is None else tuple(
+        kv[name] for name in (INDEX_KEYS, INDEX_SCAN_KEYS) if name in kv)
+    # where each stack and each kind's pools stand, in layers
+    at_w = {group: 0 for group, _ in groups}
+    at_c = {kind: 0 for kind in kinds}
+    moe = None
+    emitted: Dict[str, list] = {"hs": [], "routing": [], "fetched": []}
+
+    def run(state, group, scanned, n, w0, delta):
+        """``n`` layers of one stack (``scanned``: their leaves that ride
+        the scan) from layer ``w0`` of the stack, their pages ``delta``
+        layers further on in their kind's pools (both scalars, traced
+        inside a repeated unit)."""
+        hidden_, pools_, extra_ = state
+        kind = group_kind(cfg, group)
+        step = functools.partial(steps[kind], stacked=split[group][1],
+                                 cache_delta=delta)
+        (hidden_, k_out, v_out, _, *extra_), outs = lax.scan(
+            lambda c, lp: step(c, lp),
+            (hidden_, *pools_[kind], w0, *extra_), scanned)
+        return (hidden_, {**pools_, kind: (k_out, v_out)},
+                tuple(extra_)), outs
+
+    def leaves(group, lo, n, repeat=None):
+        """Layers ``lo .. lo + n`` of a stack's scanned leaves (the whole
+        stack as it is); with ``repeat`` as ``[repeat, n / repeat, ...]``."""
+        if lo == 0 and n == sizes[group] and repeat is None:
+            return split[group][0]
+
+        def cut(a):
+            a = a[lo:lo + n]
+            return a if repeat is None else a.reshape(
+                repeat, n // repeat, *a.shape[1:])
+        return jax.tree.map(cut, split[group][0])
+
+    def take(outs, lead=0):
+        """A run's emissions onto the pass's: the routed experts' counters
+        summed over its layers, the rest a layer each (``lead`` leading
+        axes of a repeated unit folded into the layer axis)."""
+        nonlocal moe
+        layer_hs, stats, routing, fetched = outs
+        if stats is not None:
+            sums = {name: jnp.sum(v) for name, v in stats.items()}
+            moe = sums if moe is None else {
+                name: moe[name] + v for name, v in sums.items()}
+        for name, v in (("hs", layer_hs), ("routing", routing),
+                        ("fetched", fetched)):
+            if v is not None:
+                emitted[name].append(
+                    v.reshape(-1, *v.shape[1 + lead:]) if lead else v)
+
+    state = (hidden, pools, extra)
+    for repeat, runs in layer_units(cfg):
+        if repeat == 1:
+            for group, n in runs:
+                kind = group_kind(cfg, group)
+                state, outs = run(
+                    state, group, leaves(group, at_w[group], n), n,
+                    jnp.int32(at_w[group]), at_c[kind] - at_w[group])
+                at_w[group] += n
+                at_c[kind] += n
+                take(outs)
+            continue
+        # a repeated period: one scan over its repeats, its runs inside
+        w_lo = {g: at_w[g] for g, _ in runs}
+        c_lo = dict(at_c)
+        per_c = {kind: sum(n for g, n in runs if group_kind(cfg, g) == kind)
+                 for kind in kinds}
+        xs = {g: leaves(g, w_lo[g], repeat * n, repeat) for g, n in runs}
+
+        def period(st, px):
+            p_, lp_ = px
+            outs_ = []
+            seen = {kind: 0 for kind in kinds}
+            for g, n in runs:
+                kind = group_kind(cfg, g)
+                w0 = w_lo[g] + p_ * n
+                st, out = run(
+                    st, g, lp_[g], n, w0,
+                    c_lo[kind] + p_ * per_c[kind] + seen[kind] - w0)
+                seen[kind] += n
+                outs_.append(out)
+            return st, outs_
+
+        state, outs = lax.scan(
+            period, state, (jnp.arange(repeat, dtype=jnp.int32), xs))
+        for (g, n), out in zip(runs, outs):
+            at_w[g] += repeat * n
+            at_c[group_kind(cfg, g)] += repeat * n
+            take(out, lead=1)
+    hidden, pools, ki_out = state
+    layer_hs, routing, fetched = (
+        (v[0] if len(v) == 1 else jnp.concatenate(v, axis=0)) if v else None
+        for v in (emitted["hs"], emitted["routing"], emitted["fetched"]))
     if fetched is not None:
         fetched = jnp.sum(fetched)
-    new_kv = (
-        {"k": k_out[0], "v": v_out[0],
-         "k_scale": k_out[1], "v_scale": v_out[1]}
-        if quant_kv else {"k": k_out, "v": v_out}
-    )
+    new_kv = {}
+    for kind in kinds:
+        for name, ent in zip(kind_pools(cfg, kind), pools[kind]):
+            if quant_kv:
+                new_kv[name], new_kv[name + "_scale"] = ent
+            else:
+                new_kv[name] = ent
     new_kv.update(zip((INDEX_KEYS, INDEX_SCAN_KEYS), ki_out))
     features = (
         jnp.concatenate([layer_hs[i] for i in collect_layers], axis=-1)
@@ -1199,10 +1601,10 @@ def forward_hidden_chunk(
     int8 KV pools are fenced (stage pools are bf16/f32 today; a bare-array
     scan carry would silently truncate rows into the int8 pool).
     """
-    if "k_scale" in kv or cfg.index_topk:
+    if "k_scale" in kv or cfg.index_topk or cfg.described_per_layer:
         raise NotImplementedError(
-            "forward_hidden_chunk over int8 KV pools or an index-key pool "
-            "is not wired"
+            "forward_hidden_chunk over int8 KV pools, an index-key pool or "
+            "layers described one by one is not wired"
         )
     safe_pos = jnp.maximum(positions, 0)
     cos, sin = _rope_angles(safe_pos, cfg.head_dim, cfg.rope_theta)

@@ -219,6 +219,12 @@ def init_quantized_streamed(
         if mesh is not None:
             raise ValueError("a latent-attention model is one-chip")
         return mla.init_params(cfg, jax.random.PRNGKey(seed), dtype, mode)
+    if cfg.described_per_layer:
+        # likewise, over its own stacks and leaves (models/llama.py)
+        if mesh is not None:
+            raise ValueError(
+                f"{cfg.name}: a model described per layer is one-chip")
+        return llama.init_params(cfg, jax.random.PRNGKey(seed), dtype, mode)
     dtype = jnp.dtype(dtype or cfg.dtype)
     h, d = cfg.hidden_size, cfg.head_dim
     nh, nkv, i = cfg.num_heads, cfg.num_kv_heads, cfg.mlp_width

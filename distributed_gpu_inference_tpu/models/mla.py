@@ -231,13 +231,15 @@ def draw_leaf(key: jax.Array, shape: tuple, fan_in: int, kind: str
 
 def init_params(
     cfg: ModelConfig, key: jax.Array, dtype: Optional[jnp.dtype] = None,
-    mode: Optional[str] = None,
+    mode: Optional[str] = None, groups=None, specs=None,
 ) -> Params:
     """Random weights, a leaf a layer at a time (one float32 layer slice
     live): the same draws whether kept in ``dtype`` or, with ``mode``,
     quantized as the engine quantizes (``ops.quantization.quantize_weight``)
     — so a model whose full-precision tree outgrows the chip starts the way
-    a small one does."""
+    a small one does. ``groups`` / ``specs``: the stacks and their leaves
+    where they are not this recipe's (``models/llama.py``: a K/V model
+    described per layer)."""
     from distributed_gpu_inference_tpu.ops.quantization import quantize_weight
 
     dtype = jnp.dtype(dtype or cfg.dtype)
@@ -255,9 +257,10 @@ def init_params(
         return jax.jit(lambda keys: lax.scan(body, 0, keys)[1])
 
     params: Params = {}
-    for group, n in layer_groups(cfg):
+    specs = specs or functools.partial(leaf_specs, cfg)
+    for group, n in groups or layer_groups(cfg):
         leaves: Dict[str, Any] = {}
-        for name, (shape, fan_in, kind) in leaf_specs(cfg, group).items():
+        for name, (shape, fan_in, kind) in specs(group).items():
             keys = jax.random.split(name_key(key, f"{group}.{name}"), n)
             out = gen(shape, fan_in, kind)(keys)
             if isinstance(out, tuple):
@@ -376,21 +379,11 @@ def kernels_on(cfg: ModelConfig, padded_ctx: int, pool_dtype,
 
 def route(cfg: ModelConfig, x: jax.Array, w_router: jax.Array,
           bias: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid scores over ALL published experts in float32, the top-k
-    kept (no groups), normalised and scaled → (weights [T, k], experts
-    [T, k]). ``bias`` (``cfg.router_selection_bias``) moves which experts
-    are kept and is not in their weights."""
-    scores = jax.nn.sigmoid(
-        x.astype(jnp.float32) @ w_router.astype(jnp.float32))
-    if bias is None:
-        topv, topi = lax.top_k(scores, cfg.num_experts_per_tok)
-    else:
-        _, topi = lax.top_k(scores + bias.astype(jnp.float32),
-                            cfg.num_experts_per_tok)
-        topv = jnp.take_along_axis(scores, topi, axis=-1)
-    if cfg.norm_topk_prob:
-        topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
-    return topv * cfg.routed_scaling_factor, topi
+    """``models/llama.route_experts``: the one router of both recipes (this
+    recipe's ``cfg.router_scoring`` is the sigmoid)."""
+    from distributed_gpu_inference_tpu.models.llama import route_experts
+
+    return route_experts(cfg, x, w_router, bias)
 
 
 def _experts(
@@ -398,36 +391,13 @@ def _experts(
     live: Optional[jax.Array], stacked: Optional[Dict[str, Any]],
     layer_idx: Any,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
-    """``E_shared(m) + sum over the kept experts held HERE of w_e E_e(m)``,
-    the counters of the routed part, and every token's experts ``[T, k]``.
-    The routed part is ``models/llama._routed_sum`` over the pairs that
-    fell on held experts."""
-    from distributed_gpu_inference_tpu.models.llama import (
-        _mlp_act, _routed_sum,
-    )
-    from distributed_gpu_inference_tpu.ops import moe_gmm_pallas as moe_gmm
+    """``models/llama.expert_layer``: the one expert layer of both recipes
+    (router, the held share with its per-pair live mask, the shared
+    expert)."""
+    from distributed_gpu_inference_tpu.models.llama import expert_layer
 
-    b, s, h = x.shape
-    t, k = b * s, cfg.num_experts_per_tok
-    act = _mlp_act(cfg.activation)
-    xf = x.reshape(t, h)
-    topv, topi = route(
-        cfg, xf, lp["w_router"],
-        lp["router_bias"] if cfg.router_selection_bias else None)
-    first, count = cfg.held_experts or (0, cfg.num_experts)
-    local = topi - first
-    live = jnp.ones((t,), bool) if live is None else live.reshape(t)
-    held = live[:, None] & (local >= 0) & (local < count)         # [T, k]
-    out, plan = _routed_sum(
-        xf, lp, topv, jnp.clip(local, 0, count - 1), held, count,
-        max(t * k * count // cfg.num_experts, 1), act,
-        stacked=stacked, layer_idx=layer_idx, decode=s == 1)
-    if "ws_gate" in lp or (stacked is not None and "ws_gate" in stacked):
-        shared = proj(act(proj(x, "ws_gate")) * proj(x, "ws_up"), "ws_down")
-        out = out + shared.reshape(t, h).astype(jnp.float32)
-    stats = moe_gmm.expert_stats(plan)
-    stats["pairs_routed"] = jnp.sum(live, dtype=jnp.int32) * k
-    return out.reshape(b, s, h).astype(x.dtype), stats, topi
+    return expert_layer(x, lp, cfg, proj, live=live, stacked=stacked,
+                        layer_idx=layer_idx)
 
 
 # ---------------------------------------------------------------------------

@@ -215,8 +215,10 @@ def quantize_params(
     # a model whose layers are of two kinds keeps a second stack
     # (models/mla.py: the leading dense layers)
     # and a hybrid one a pair more (the gated delta-rule layers)
+    # and a K/V model of mixed attention kinds its full layers' (models/
+    # llama.py group_of)
     for group in ("layers", "dense_layers", "kda_layers",
-                  "kda_dense_layers"):
+                  "kda_dense_layers", "full_layers", "full_dense_layers"):
         if group in params:
             out[group] = _quantize_group(params[group], mode, consume)
     return out

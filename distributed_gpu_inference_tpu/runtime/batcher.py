@@ -1739,8 +1739,11 @@ class ContinuousBatcher:
         scan it ran beside (``_engine_round``), whose read counts them in
         its gap: they are taken out here."""
         t0 = time.perf_counter()
+        engine_stats = getattr(self.engine, "stats", None) or {}
+        cut = engine_stats.get("prefix_hit_tokens_cut_by_window", 0)
         with flight.span("dgi.batcher.admit", self.stats, "admit_s",
-                         queue_depth=len(self._heap), ahead=int(ahead)):
+                         queue_depth=len(self._heap),
+                         ahead=int(ahead)) as admitted:
             # cancel/interrupt events land at this quiescent boundary:
             # aborted requests release their slots BEFORE admission so
             # the freed capacity admits waiting work this very pass
@@ -1749,6 +1752,11 @@ class ContinuousBatcher:
             # freed blocks admit waiting on-time work this very pass
             await self._scan_deadlines()
             await self._admit(ahead)
+            now = engine_stats.get("prefix_hit_tokens_cut_by_window", 0)
+            if now != cut:
+                # pages per layer kind: tokens of a prefix hit the window
+                # kind could not back, prefilled again
+                admitted.set(window_cut_tokens=now - cut)
             # admission-sourced KV pressure: deferred requests wait, or
             # a higher-priority arrival preempts the lowest-priority
             # victim

@@ -103,6 +103,15 @@ _MOE_COUNTERS = ("layer_calls", "assignments", "rows_dispatched",
 _MOE_SHARE_COUNTERS = _MOE_COUNTERS + ("pairs_routed",)
 
 
+def _window_pairs(before: int, n: int, window: int) -> int:
+    """``sum(min(before + j, window) for j in 1..n)``: the keys ``n``
+    consecutive queries see in a sliding layer, the first of them with
+    ``before`` tokens cached."""
+    rising = max(min(n, window - before), 0)    # queries still under it
+    return rising * before + rising * (rising + 1) // 2 \
+        + (n - rising) * window
+
+
 def _moe_vector(out: Any) -> Any:
     """A ``ChunkOutput``'s ``moe`` as one int32 vector, so that a round
     brings its counters back in one transfer; after them, where a scan step
@@ -474,6 +483,8 @@ class TPUEngine:
             self._refuse_latent(mesh)
         if self.model_cfg.index_topk:
             self._refuse_indexed(mesh)
+        if self.model_cfg.described_per_layer:
+            self._refuse_per_layer(mesh)
         if mesh is not None:
             sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
             tp = sizes.get("model", 1)
@@ -536,6 +547,17 @@ class TPUEngine:
         # slot beside the pages: its size follows from max_batch_size
         self._state_rows = (
             self.cfg.max_batch_size if self.model_cfg.num_kda_layers else 0)
+        # pages per layer kind: the sliding layers' pool follows from the
+        # slots and the window, eight windows a slot (a live window with
+        # the piece or the scan horizon being written, a retained prefix
+        # end, what a reply releases before the next hit touches it, and a
+        # third to spare), never more than the full kind's
+        self._window_blocks = 0
+        if self.model_cfg.mixed_attention:
+            self._window_blocks = min(
+                self.num_blocks,
+                1 + self.cfg.max_batch_size * 8 * -(
+                    -self.model_cfg.sliding_window // self.cfg.block_size))
         self.kv = self._init_kv()
         # storage for a scan's index keys in context order (a model with an
         # indexer whose tables can pass topk): a scan of several steps takes
@@ -570,12 +592,19 @@ class TPUEngine:
             spill_on_evict=spill,
             kv_dtype=np.dtype(self.kv_dtype),
             state_rows=self._state_rows,
+            window_blocks=self._window_blocks,
+            window=self.model_cfg.sliding_window
+            if self._window_blocks else None,
         )
         self.eos_token_id = eos_token_id
 
         b, m = self.cfg.max_batch_size, self.cfg.max_blocks_per_seq
         self.slots: List[Optional[_Slot]] = [None] * b
-        self._block_tables = np.zeros((b, m), dtype=np.int32)
+        # a block table a layer kind, side by side in a row: the window
+        # kind's columns start at ``_window_col``
+        self._window_col = m if self._window_blocks else 0
+        self._table_cols = m + self._window_col
+        self._block_tables = np.zeros((b, self._table_cols), dtype=np.int32)
         self._kv_lens = np.zeros((b,), dtype=np.int32)
         self._last_tokens = np.zeros((b,), dtype=np.int32)
         self._temps = np.zeros((b,), dtype=np.float32)
@@ -706,8 +735,28 @@ class TPUEngine:
                 # over the layers (each layer selects its own; counted on
                 # the device, read with the scan's tokens)
                 self.stats["index_fetched_tokens_scan"] = 0
+        if self._window_blocks:
+            # pages per layer kind. Cached tokens the scans' row-steps
+            # attended in a full layer and in a sliding one (at most the
+            # window) and the window-kind tokens their rows held (host
+            # arithmetic at a scan's commit); the (query, key) pairs inside
+            # causal reach, and inside the window, of the plain ragged
+            # rounds (at a round's build)
+            self.stats.update({
+                "kv_layout": "kv+window",
+                "window_pool_blocks": self._window_blocks,
+                "attn_row_steps_scan": 0,
+                "attn_full_context_tokens_scan": 0,
+                "attn_window_context_tokens_scan": 0,
+                "kv_window_resident_tokens_scan": 0,
+                "attn_pairs_ragged_full": 0, "attn_pairs_ragged_window": 0,
+                # the manager's, as they stood at the last admission
+                "prefix_hits_cut_by_window": 0,
+                "prefix_hit_tokens_cut_by_window": 0,
+            })
         self._moe_names = (
             _MOE_SHARE_COUNTERS if self.model_cfg.latent_kv
+            or self.model_cfg.held_experts is not None
             else _MOE_COUNTERS
         )
         if self.model_cfg.latent_kv:
@@ -778,6 +827,41 @@ class TPUEngine:
             raise ValueError(
                 f"{name}: a state row is whole on one chip (no sequence "
                 "sharding of the linear-attention layers)")
+
+    def _refuse_per_layer(self, mesh: Optional[Any]) -> None:
+        """A K/V model described per layer (attention kinds mixed layer by
+        layer with pages per kind, a dense lead, a shared expert, a held
+        share of the experts: models/llama.py) is served on one chip, from
+        pools in the activation dtype. What would serve it otherwise
+        refuses it here, when the engine is configured."""
+        name = self.model_cfg.name
+        if mesh is not None:
+            raise ValueError(
+                f"{name}: a model described per layer is served on one chip "
+                "(no sharding rule for its parameter stacks, the held share "
+                "of its experts or a pool a layer kind)")
+        if self.cfg.kv_seq_sharded:
+            raise ValueError(
+                f"{name}: pages per layer kind are not sharded over a "
+                "sequence axis (the shard_map read takes one block table)")
+        if self.cfg.speculative is not None:
+            raise ValueError(
+                f"{name}: speculative decoding drafts with a Llama head "
+                "over one parameter stack and verifies a chain in one "
+                "window of one block table")
+        if not self.model_cfg.mixed_attention:
+            return
+        if self.cfg.spill_host_blocks > 0 or \
+                self.cfg.spill_remote_store is not None:
+            raise ValueError(
+                f"{name}: the spill tiers carry one kind's K/V pages, not "
+                "the window kind's that belong to them")
+        if self.kv_dtype.itemsize != jnp.dtype(self.dtype).itemsize:
+            raise ValueError(
+                f"{name}: pages per layer kind are served in the "
+                f"activation dtype, not kv_cache_dtype="
+                f"{self.cfg.kv_cache_dtype!r} (int8 / fp8 pools of two "
+                "kinds are not built)")
 
     def _refuse_indexed(self, mesh: Optional[Any]) -> None:
         """A model with an indexer (learned sparse attention) keeps an
@@ -971,6 +1055,7 @@ class TPUEngine:
             return llama.init_kv_pools(
                 self.model_cfg, self.num_blocks, self.cfg.block_size,
                 self.kv_dtype, state_rows=self._state_rows or None,
+                window_blocks=self._window_blocks or None,
             )
         # zeros created directly with the sharded layout (no single-device
         # staging allocation)
@@ -1001,7 +1086,7 @@ class TPUEngine:
 
     def _build_jit_fns(self) -> None:
         cfg, bs = self.model_cfg, self.cfg.block_size
-        m = self.cfg.max_blocks_per_seq
+        m = self._table_cols
         # every Pallas kernel in the serving graphs (fused decode, ragged
         # attention, int8 matmul) is a custom call with no GSPMD
         # partitioning rule — XLA refuses a sharded graph that holds one —
@@ -1731,8 +1816,11 @@ class TPUEngine:
             # (int8 KV) copy with their pages — a page without its scale is
             # garbage
             # (a state pool has rows, not pages: it is no operand of a copy)
+            # (nor is the window kind's pool of a model of mixed kinds: a
+            # copy-on-write is the full kind's, whose ids these are)
             return {
                 name: pool if name in state_pools
+                or name.endswith(llama.WINDOW_POOLS)
                 else pool.at[:, dsts].set(pool[:, srcs], mode="drop")
                 for name, pool in kv.items()
             }
@@ -1768,7 +1856,7 @@ class TPUEngine:
     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Per-round scheduling state (block tables, active mask, budgets)
         as ONE packed upload — tables grow most rounds, so these always ship."""
-        mm = self.cfg.max_blocks_per_seq
+        mm = self._table_cols
         si = np.zeros((len(self.slots), mm + 2), np.int32)
         si[:, :mm] = self._block_tables
         si[:, mm] = active_mask
@@ -2244,6 +2332,16 @@ class TPUEngine:
                     _, cached = self.manager.allocate_sequence(
                         seq_id, token_ids
                     )
+                    if self._window_blocks and \
+                            len(token_ids) - cached <= max_bucket:
+                        # a wave writes the whole prompt in one call: the
+                        # window kind's blocks for it, or nothing of it
+                        try:
+                            self.manager.extend_window(
+                                seq_id, len(token_ids))
+                        except OutOfBlocksError:
+                            self.manager.free_sequence(seq_id, cache=False)
+                            raise
                 except OutOfBlocksError:
                     # step-boundary pressure: allocate_sequence scrubbed its
                     # own staging, nothing of THIS request is admitted
@@ -2332,6 +2430,10 @@ class TPUEngine:
         self._kv_lens[slot] = kv_len
         if self._state_rows:
             self.manager.bind_state(slot)
+        if self._window_blocks:
+            for name in ("prefix_hits_cut_by_window",
+                         "prefix_hit_tokens_cut_by_window"):
+                self.stats[name] = getattr(self.manager.stats, name)
         sp = s.request.sampling
         self._temps[slot] = sp.temperature
         self._top_ks[slot] = sp.top_k
@@ -2405,6 +2507,7 @@ class TPUEngine:
             off += len(piece)
             if is_last:
                 break
+            self._release_prefill_window(slot, off)
 
         tok = int(np.asarray(first)[0])
         self._record_token(slot, tok)
@@ -2447,6 +2550,9 @@ class TPUEngine:
         its own); intermediate chunks skip
         the LM head entirely."""
         n = len(piece)
+        if not self._extend_window(slot, off + n):
+            raise OutOfBlocksError(
+                "the window kind's pool cannot hold the next piece")
         if self._state_rows:
             return self._prefill_piece_packed(slot, piece, off, is_last, mode)
         bucket = (
@@ -2466,7 +2572,10 @@ class TPUEngine:
             prefill_fn = self._prefill_chunk_paged_fn
         first, self.kv = prefill_fn(
             self.params, self.kv, toks_pos,
-            self._block_tables[slot : slot + 1],
+            # a copy: the window release that follows the dispatch writes
+            # this row of the mirror, and a CPU backend may read the
+            # operand where it lies
+            self._block_tables[slot : slot + 1].copy(),
             np.asarray([off + n], np.int32),
             self._slot_keys[slot : slot + 1],
             self._temps[slot : slot + 1],
@@ -2588,6 +2697,9 @@ class TPUEngine:
                 self.manager.trim_reserved(s.seq_id)
                 self._signal_pressure("admission", requests=1)
                 return False
+        if not self._extend_window(
+                adm.slot, adm.off + min(len(adm.fresh), max_bucket)):
+            return False
         self._apply_pending()
         piece = adm.fresh[: max_bucket]
         adm.fresh = adm.fresh[max_bucket:]
@@ -2606,7 +2718,7 @@ class TPUEngine:
             self._record_token(adm.slot, tok)
             adm.done = True
         else:
-            self._release_prefill_window(adm)
+            self._release_prefill_window(adm.slot, adm.off)
         return adm.done
 
     def abort_chunked(self, adm: ChunkedAdmission) -> None:
@@ -2778,9 +2890,31 @@ class TPUEngine:
                     self.manager.trim_reserved(s.seq_id)
                     self._signal_pressure("admission", requests=1)
                     continue
+            if not self._extend_window(adm.slot, adm.off + len(piece)):
+                continue    # the window kind is dry: the piece waits
             ready.append((adm, piece, is_last))
             width = max(width, len(piece))
         return ready, width
+
+    def _extend_window(self, slot: int, upto: int) -> bool:
+        """Pages per layer kind: the window kind's blocks for the positions
+        below ``upto`` that a forward pass is about to write for ``slot``
+        (``PagedKVCacheManager.extend_window``; the full kind's came with
+        the prompt). False where the window pool cannot give them: pressure
+        is signalled and nothing was taken, the step-boundary rule of
+        every other reservation. One kind of pages: True, nothing done."""
+        if not self._window_blocks:
+            return True
+        s = self.slots[slot]
+        assert s is not None
+        try:
+            if self.manager.extend_window(s.seq_id, upto):
+                self._block_tables[slot] = self.manager.block_table_for(
+                    s.seq_id, self.cfg.max_blocks_per_seq)
+        except OutOfBlocksError:
+            self._signal_pressure("admission", requests=1)
+            return False
+        return True
 
     def _fill_ragged_admission_rows(
         self, ready, toks_pos: np.ndarray, lens_after: np.ndarray,
@@ -2821,7 +2955,7 @@ class TPUEngine:
                 self._record_token(adm.slot, tok, device_synced=True)
                 adm.done = True
             else:
-                self._release_prefill_window(adm)
+                self._release_prefill_window(adm.slot, adm.off)
 
     def _plain_ragged_round(
         self, admissions: Sequence[ChunkedAdmission],
@@ -2948,6 +3082,19 @@ class TPUEngine:
                 ctx += adm.off + m
             self.stats["mla_pairs_ragged"] += pairs
             self.stats["mla_context_tokens_ragged"] += ctx
+        if self._window_blocks:
+            # a decode row's token sees its cache and itself; query j of a
+            # piece written at ``off`` sees ``off + j + 1``, a sliding
+            # layer at most the window of them
+            w = self.model_cfg.sliding_window
+            spans = [(int(self._kv_lens[i]), 1) for i in kept] + [
+                (adm.off, len(piece)) for adm, piece, _ in ready]
+            full = sum(m * off + m * (m + 1) // 2 for off, m in spans)
+            windowed = sum(_window_pairs(off, m, w) for off, m in spans)
+            self.stats["attn_pairs_ragged_full"] += full
+            self.stats["attn_pairs_ragged_window"] += windowed
+            sp.set(attn_full_context_tokens=full,
+                   attn_window_context_tokens=windowed)
         if "index_pairs_ragged" in self.stats:
             self._count_index_ragged(
                 sp, [(int(self._kv_lens[i]), 1) for i in kept]
@@ -3223,31 +3370,31 @@ class TPUEngine:
             self._apply_pending()
             self._maybe_release_window(slot)
 
-    def _release_prefill_window(self, adm: ChunkedAdmission) -> None:
+    def _release_prefill_window(self, slot: int, off: int) -> None:
         """Sliding-window models, MID-prefill: hand back blocks that every
         REMAINING chunk query is already past, between chunks. Without
         this a 32k prompt on a windowed model holds its entire prompt KV
         until the first decode step (``_maybe_release_window`` only runs
         on token commits) — worst-case pool pressure exactly when a long
         admission is streaming in. The earliest remaining query sits at
-        position ``adm.off``, not ``cur - 1`` (``seq_tokens`` already
+        position ``off``, not ``cur - 1`` (``seq_tokens`` already
         holds the WHOLE prompt during prefill), so the window passed to
         the manager widens by the not-yet-queried tail: only keys
-        <= adm.off - window release. The attention window mask already
+        <= off - window release. The attention window mask already
         excludes those positions for every remaining chunk row, so
         pad-block reads are never visible — byte-identical outputs."""
         w = self.model_cfg.sliding_window
         if w is None:
             return
-        s = self.slots[adm.slot]
+        s = self.slots[slot]
         if s is None:
             return
         cur = len(self.manager.seq_tokens[s.seq_id])
         released = self.manager.release_out_of_window(
-            s.seq_id, w + max(cur - adm.off, 0)
+            s.seq_id, w + max(cur - off, 0)
         )
         for lb in released:
-            self._block_tables[adm.slot, lb] = 0
+            self._block_tables[slot, self._window_col + lb] = 0
 
     def _maybe_release_window(self, slot: int) -> None:
         """Sliding-window models: hand blocks every future query is past back
@@ -3261,7 +3408,7 @@ class TPUEngine:
         assert s is not None
         released = self.manager.release_out_of_window(s.seq_id, w)
         for lb in released:
-            self._block_tables[slot, lb] = 0
+            self._block_tables[slot, self._window_col + lb] = 0
 
     def decode_step(self) -> Dict[int, int]:
         """One decode step for all active unfinished slots: feeds each slot's
@@ -3761,11 +3908,17 @@ class TPUEngine:
                          "round_commit_s"):
             out: Dict[int, List[int]] = {}
             index_ctx, index_most = 0, -1
+            attn_ctx = [0, 0]
             for i, s in enumerate(self.slots):
                 if not scan.active_mask[i] or s is None:
                     continue
                 toks = [int(t) for t in emitted[i] if t >= 0]
                 out[i] = toks
+                if self._window_blocks and toks:
+                    full, windowed = self._count_window_scan(
+                        i, s.seq_id, len(toks))
+                    attn_ctx[0] += full
+                    attn_ctx[1] += windowed
                 if "mla_row_steps_scan" in st:
                     # step t of the row attended its cache and the token
                     # the step wrote: len + 1 ... len + n
@@ -3794,6 +3947,9 @@ class TPUEngine:
                 self._maybe_release_window(i)
         if index_ctx and sp is not None:
             sp.set(index_context_tokens=index_ctx)
+        if attn_ctx[0] and sp is not None:
+            sp.set(attn_full_context_tokens=attn_ctx[0],
+                   attn_window_context_tokens=attn_ctx[1])
         # (its storage exists where a table can pass topk at all)
         if self._scan_keys is not None and index_most >= 0 and \
                 index_most + scan.num_steps > self.model_cfg.index_topk:
@@ -3802,6 +3958,25 @@ class TPUEngine:
             # nothing went out behind it: the chip waited for this commit
             st["round_host_exposed_s"] += time.perf_counter() - t0
         return out
+
+    def _count_window_scan(self, slot: int, seq_id: str, n: int
+                           ) -> Tuple[int, int]:
+        """A row's ``n`` scan steps into the counters of a model of mixed
+        attention kinds -> the cached tokens they attended in a full layer
+        and in a sliding one. Step ``j`` attends the row's cache and the
+        token it wrote, ``len + j``; a sliding layer at most the window of
+        them. The window-kind tokens the row held are taken as the scan
+        ends, a step each."""
+        before, w = int(self._kv_lens[slot]), self.model_cfg.sliding_window
+        full = n * before + n * (n + 1) // 2
+        windowed = _window_pairs(before, n, w)
+        st = self.stats
+        st["attn_row_steps_scan"] += n
+        st["attn_full_context_tokens_scan"] += full
+        st["attn_window_context_tokens_scan"] += windowed
+        st["kv_window_resident_tokens_scan"] += n * self.cfg.block_size \
+            * self.manager.window_resident_blocks(seq_id)
+        return full, windowed
 
     def _count_index_scan(self, cached: int, n: int) -> int:
         """A row's ``n`` scan steps over ``cached`` tokens: step ``t``

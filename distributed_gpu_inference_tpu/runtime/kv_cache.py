@@ -273,6 +273,18 @@ class KVCacheStats:
     cached_blocks: int = 0
     free_blocks: int = 0
     window_released_blocks: int = 0
+    # a manager with a pool a layer kind (``window_blocks``): blocks the
+    # live rows hold, a kind; window-kind blocks a live row released that
+    # stayed findable by their prefix, those the window pool took back from
+    # that cache, and the lookups the full kind matched of which the window
+    # kind cut some back (or to nothing) for want of the last window's pages
+    blocks_in_use: int = 0
+    window_blocks_in_use: int = 0
+    window_blocks_retained: int = 0
+    window_blocks_evicted: int = 0
+    prefix_lookups_matched: int = 0
+    prefix_hits_cut_by_window: int = 0
+    prefix_hit_tokens_cut_by_window: int = 0
     # a manager with a state pool (``state_rows``): rows bound to a new
     # sequence, and lookups cut to zero because pages alone back them
     state_binds: int = 0
@@ -376,6 +388,187 @@ class RemoteKVStore:
         return len(dead)
 
 
+class _WindowPages:
+    """The sliding kind's pages of a model of mixed attention kinds: a pool
+    and a chain a sequence of their own, inside the one manager.
+
+    A chain is indexed by logical block like the full kind's, 0 where the
+    row holds nothing: before its window (released, or never held after a
+    prefix hit) and past what it has written. A block is tied to the
+    full-kind block that caches the same tokens (``partner`` /
+    ``by_full``): that is how a prefix finds it, since the radix index
+    holds the full kind's chain. A block no row holds stays **parked**
+    while its partner lives, under the sequence that let go of it, and is
+    taken back when the pool runs dry: the oldest block of the sequence
+    that parks the most (ties: the sequence that parked first). A long cold
+    prompt releases a pool's worth of blocks in a row; taken back that way
+    it eats its own trail and leaves alone the few blocks the other rows
+    parked, which lie where a next turn's prefix ends. A block a prefix hit
+    has used once is **proven**: it is parked apart, oldest first, and taken
+    back only when no other parked block is left or the proven ones pass
+    half the pool (a session's shared prefix ends where it ended before;
+    the blocks a reply released after it are the oldest of their sequence
+    and would go first otherwise). That never touches the full kind."""
+
+    def __init__(self, num_blocks: int, block_size: int, window: int) -> None:
+        if num_blocks < 2:
+            raise ValueError("need at least 2 window blocks (0 is reserved)")
+        self.num_blocks = num_blocks
+        self.block_size = block_size
+        self.window = int(window)
+        self.free_list: List[int] = list(range(num_blocks - 1, 0, -1))
+        self.ref: Dict[int, int] = {}
+        self.partner: Dict[int, int] = {}
+        self.by_full: Dict[int, int] = {}
+        # sequence -> the blocks it parked, oldest first; sequences in the
+        # order of their first parked block
+        self.parked: "OrderedDict[str, OrderedDict[int, None]]" = \
+            OrderedDict()
+        self.parked_by: Dict[int, str] = {}
+        # blocks a hit has used, and those of them that are parked
+        self.proven: set = set()
+        self.parked_proven: "OrderedDict[int, None]" = OrderedDict()
+        self.seq_blocks: Dict[str, List[int]] = {}
+
+    def first_needed(self, tokens: int) -> int:
+        """The first logical block a query at position ``tokens`` (the next
+        one after ``tokens`` cached) still sees: keys past ``tokens -
+        window``."""
+        return max(tokens - self.window + 1, 0) // self.block_size
+
+    def deepest_hit(self, cached: Sequence[int]) -> int:
+        """The most leading blocks of a full-kind match that the window
+        kind can back: the deepest ``d`` whose last window's blocks are all
+        findable."""
+        have = [0]
+        for bid in cached:
+            have.append(have[-1] + (bid in self.by_full))
+        for d in range(len(cached), 0, -1):
+            first = self.first_needed(d * self.block_size)
+            if have[d] - have[first] == d - first:
+                return d
+        return 0
+
+    def _unpark(self, wid: int) -> bool:
+        if self.parked_proven.pop(wid, False) is None:
+            return True
+        owner = self.parked_by.pop(wid, None)
+        if owner is None:
+            return False
+        mine = self.parked[owner]
+        del mine[wid]
+        if not mine:
+            del self.parked[owner]
+        return True
+
+    def adopt(self, seq_id: str, cached: Sequence[int]) -> None:
+        """A new sequence's chain: the last window of its hit, shared."""
+        first = self.first_needed(len(cached) * self.block_size)
+        chain = [0] * min(first, len(cached))
+        for bid in cached[first:]:
+            wid = self.by_full[bid]
+            if self._unpark(wid):
+                self.ref[wid] = 1
+            else:
+                self.ref[wid] += 1
+            self.proven.add(wid)
+            chain.append(wid)
+        self.seq_blocks[seq_id] = chain
+
+    def evict(self, wid: int, stats: "KVCacheStats") -> int:
+        """Take a parked block back. Its full-kind partner stays as it is:
+        the next lookup that needs this block's window finds it gone and is
+        cut back."""
+        self._unpark(wid)
+        self.proven.discard(wid)
+        self.by_full.pop(self.partner.pop(wid), None)
+        stats.window_blocks_evicted += 1
+        return wid
+
+    def evict_one(self, stats: "KVCacheStats") -> int:
+        if self.parked_proven and (
+                not self.parked
+                or len(self.parked_proven) > self.num_blocks // 2):
+            return self.evict(next(iter(self.parked_proven)), stats)
+        if not self.parked:
+            raise OutOfBlocksError(
+                "window-kind KV pool exhausted: 0 free, 0 parked, all "
+                "others held by active sequences")
+        # (``max`` keeps the first of equals: the sequence that parked first)
+        most = max(self.parked.values(), key=len)
+        return self.evict(next(iter(most)), stats)
+
+    def pop_block(self, stats: "KVCacheStats") -> int:
+        return self.free_list.pop() if self.free_list \
+            else self.evict_one(stats)
+
+    def extend(self, seq_id: str, full_chain: Sequence[int], upto: int,
+               stats: "KVCacheStats") -> List[int]:
+        """Blocks for every position below ``upto`` -> the new ones. All or
+        nothing: exhaustion gives back what this call took."""
+        chain = self.seq_blocks[seq_id]
+        need = -(-upto // self.block_size)
+        added: List[int] = []
+        try:
+            while len(chain) + len(added) < need:
+                added.append(self.pop_block(stats))
+        except OutOfBlocksError:
+            self.free_list.extend(added)
+            raise
+        for wid in added:
+            full = full_chain[len(chain)]
+            self.ref[wid] = 1
+            if full and full not in self.by_full:
+                self.by_full[full], self.partner[wid] = wid, full
+            chain.append(wid)
+        return added
+
+    def drop(self, wid: int, seq_id: str, retained: bool = True) -> bool:
+        """``seq_id`` lets go of a block -> whether it stays findable."""
+        self.ref[wid] -= 1
+        if self.ref[wid]:
+            return True
+        del self.ref[wid]
+        if retained and wid in self.partner:
+            if wid in self.proven:
+                self.parked_proven[wid] = None
+            else:
+                self.parked.setdefault(seq_id, OrderedDict())[wid] = None
+                self.parked_by[wid] = seq_id
+            return True
+        self.proven.discard(wid)
+        self.by_full.pop(self.partner.pop(wid, None), None)
+        self.free_list.append(wid)
+        return False
+
+    def forget_partner(self, full: int) -> None:
+        """The full-kind block is gone: what was findable under it is not."""
+        wid = self.by_full.pop(full, None)
+        if wid is None:
+            return
+        del self.partner[wid]
+        self.proven.discard(wid)
+        if self._unpark(wid):
+            self.free_list.append(wid)
+
+    def repartner(self, old_full: int, new_full: int) -> None:
+        """The chain was indexed under another block of the same tokens:
+        what is findable under ``old_full`` moves there, if there is room."""
+        wid = self.by_full.get(old_full)
+        if wid is None or new_full in self.by_full:
+            return
+        del self.by_full[old_full]
+        self.by_full[new_full], self.partner[wid] = wid, new_full
+
+    @property
+    def in_use(self) -> int:
+        return len(self.ref)
+
+    @property
+    def num_parked(self) -> int:
+        return len(self.parked_by) + len(self.parked_proven)
+
+
 class PagedKVCacheManager:
     """Metadata brain for the device KV pools.
 
@@ -398,8 +591,19 @@ class PagedKVCacheManager:
         spill_on_evict: bool = False,
         kv_dtype: Optional[Any] = None,
         state_rows: int = 0,
+        window_blocks: int = 0,
+        window: Optional[int] = None,
     ) -> None:
-        """``state_rows``: rows of the state pool that lies beside the paged
+        """``window_blocks`` / ``window``: a model of mixed attention kinds
+        keeps its sliding layers' pages in a pool of ``window_blocks`` of
+        their own (``_WindowPages``); ``num_blocks`` is then the full
+        kind's. A live row holds every block of its context in the full
+        kind and, in the window kind, the blocks its next queries can still
+        see plus what is being written (``extend_window`` before a write,
+        ``release_out_of_window`` after); a prefix hit needs both kinds'
+        pages (``allocate_sequence``).
+
+        ``state_rows``: rows of the state pool that lies beside the paged
         one (a hybrid model's linear-attention layers: one row a sequence,
         fixed in size, ``bind_state`` / ``free_state``). A sequence's pages
         then hold only part of its past, so a prefix found in the radix
@@ -422,6 +626,14 @@ class PagedKVCacheManager:
         self.kv_dtype = np.dtype(kv_dtype) if kv_dtype is not None else None
         self.quantized_kv = self.kv_dtype == np.int8
         self.state_rows = int(state_rows)
+        self.win: Optional[_WindowPages] = None
+        if window_blocks:
+            if not window or host_store is not None \
+                    or remote_store is not None or state_rows:
+                raise ValueError(
+                    "pages per layer kind need the window and have no "
+                    "spill tiers or state rows")
+            self.win = _WindowPages(window_blocks, block_size, window)
 
         # durable-tier immunity (round 19): per-tier circuit breakers +
         # cumulative error/quarantine counters. A tier put/get that raises
@@ -537,6 +749,8 @@ class PagedKVCacheManager:
             self.pending.downloads.append((bid, meta.prefix_hash))
             self.stats.spills += 1
         self.radix.remove_block(bid)
+        if self.win is not None:
+            self.win.forget_partner(bid)
         self.stats.evictions += 1
 
     # -- spill tiers (reference get_or_compute chain, kv_cache.py:389-462) ---
@@ -735,6 +949,17 @@ class PagedKVCacheManager:
             # logits must be recomputed, so keep at least one token fresh
             while cached and len(cached) * self.block_size >= n_tokens:
                 cached.pop()
+            if self.win is not None and cached:
+                # the hit the window kind can back: its last window's pages
+                # must still be findable, or the hit is cut back to the
+                # deepest prefix whose are (or to nothing)
+                self.stats.prefix_lookups_matched += 1
+                keep = self.win.deepest_hit(cached)
+                if keep < len(cached):
+                    self.stats.prefix_hits_cut_by_window += 1
+                    self.stats.prefix_hit_tokens_cut_by_window += (
+                        len(cached) - keep) * self.block_size
+                    del cached[keep:]
             # L1 miss past this point: probe the spill tiers block-by-block
             # (reference get_or_compute chain) — restored pages re-upload
             # into freshly allocated blocks, same fresh-token rule applies
@@ -802,7 +1027,30 @@ class PagedKVCacheManager:
         self.seq_blocks[seq_id] = blocks
         self.seq_tokens[seq_id] = token_ids
         self.seq_shared_count[seq_id] = len(cached) + len(spill_pages)
+        if self.win is not None:
+            self.win.adopt(seq_id, cached)
+            # the hit's chain starts at its last window: nothing before it
+            # is held, as if released
+            self.seq_window_front[seq_id] = min(
+                self.win.first_needed(num_cached_tokens), len(cached))
         return blocks, num_cached_tokens
+
+    def window_resident_blocks(self, seq_id: str) -> int:
+        """Window-kind blocks the sequence holds (pages per layer kind)."""
+        if self.win is None:
+            return 0
+        return len(self.win.seq_blocks[seq_id]) \
+            - self.seq_window_front.get(seq_id, 0)
+
+    def extend_window(self, seq_id: str, upto: int) -> List[int]:
+        """Pages per layer kind: window-kind blocks for every position below
+        ``upto``, before a forward pass writes them (the full kind's were
+        allocated with the prompt) -> the new ones. ``OutOfBlocksError``
+        leaves the chain as it was. One kind of pages: nothing to do."""
+        if self.win is None:
+            return []
+        return self.win.extend(seq_id, self.seq_blocks[seq_id], upto,
+                               self.stats)
 
     def append_token(self, seq_id: str, token_id: int) -> Optional[int]:
         """Account one generated token; returns a newly allocated block id if
@@ -816,7 +1064,10 @@ class PagedKVCacheManager:
         if logical >= len(blocks):
             bid = self._pop_free_block()
             blocks.append(bid)
+            self.extend_window(seq_id, pos + 1)
             return bid
+        if self.win is not None and self.extend_window(seq_id, pos + 1):
+            return blocks[logical]
         tail = blocks[logical]
         meta = self.metas[tail]
         if meta.is_shared:
@@ -854,6 +1105,9 @@ class PagedKVCacheManager:
                 bid = self._pop_free_block()
                 blocks.append(bid)
                 added.append(bid)
+            # both kinds together: the caller's ``trim_reserved`` gives back
+            # what either took
+            added.extend(self.extend_window(seq_id, cur + n))
         except OutOfBlocksError:
             raise
         return added
@@ -879,6 +1133,11 @@ class PagedKVCacheManager:
             if meta is not None and meta.decref() == 0:
                 self._deactivate_block(bid)
             freed.append(bid)
+        if self.win is not None:
+            chain = self.win.seq_blocks[seq_id]
+            while len(chain) > needed:
+                freed.append(chain.pop())
+                self.win.drop(freed[-1], seq_id, retained=False)
         return freed
 
     def commit_tokens(self, seq_id: str, token_ids: Sequence[int]) -> None:
@@ -905,7 +1164,8 @@ class PagedKVCacheManager:
 
         This converts mask-only SWA into window-bounded KV memory — the
         rolling-buffer benefit vLLM gets for Mistral, without re-indexing."""
-        blocks = self.seq_blocks[seq_id]
+        blocks = self.seq_blocks[seq_id] if self.win is None \
+            else self.win.seq_blocks[seq_id]
         cur = len(self.seq_tokens[seq_id])
         released: List[int] = []
         lb = self.seq_window_front.get(seq_id, 0)
@@ -915,9 +1175,19 @@ class PagedKVCacheManager:
             if (lb + 1) * self.block_size > cur - window:
                 break
             bid = blocks[lb]
-            meta = self.metas.get(bid)
-            if meta is not None and meta.decref() == 0:
-                self._deactivate_block(bid)
+            if self.win is not None:
+                # pages per layer kind: the block stays findable by its
+                # prefix (parked as evictable cache) while its full-kind
+                # partner lives and the window pool does not need it
+                if not bid:     # before the hit's last window: never held
+                    lb += 1
+                    continue
+                self.stats.window_blocks_retained += self.win.drop(
+                    bid, seq_id)
+            else:
+                meta = self.metas.get(bid)
+                if meta is not None and meta.decref() == 0:
+                    self._deactivate_block(bid)
             blocks[lb] = 0
             released.append(lb)
             lb += 1
@@ -956,9 +1226,12 @@ class PagedKVCacheManager:
         tokens = self.seq_tokens.pop(seq_id, [])
         self.seq_shared_count.pop(seq_id, None)
         n_full = len(tokens) // self.block_size
-        if self.seq_window_front.pop(seq_id, 0) > 0 or 0 in blocks[:n_full]:
+        front = self.seq_window_front.pop(seq_id, 0)
+        if self.win is None and (front > 0 or 0 in blocks[:n_full]):
             # window-released leading blocks: the chain is no longer a valid
-            # prefix, so it cannot enter the radix index
+            # prefix, so it cannot enter the radix index. (Pages per layer
+            # kind: the full kind's chain is whole, it is the window kind's
+            # that has holes, and a hit asks for its last window alone.)
             cache = False
         if cache and self.enable_prefix_cache and n_full > 0:
             idx_tokens: Sequence[int] = tokens
@@ -966,6 +1239,13 @@ class PagedKVCacheManager:
                 # one bulk conversion → zero-copy across the native ABI
                 idx_tokens = np.asarray(tokens, np.int32)
             self.radix.insert(idx_tokens, blocks[:n_full])
+            if self.win is not None:
+                # where an equal chain was indexed before this one, what is
+                # findable under this row's blocks moves to the indexed ones
+                indexed = self.radix.match_prefix(idx_tokens)
+                for mine, theirs in zip(blocks, indexed):
+                    if mine != theirs:
+                        self.win.repartner(mine, theirs)
         hashes: Optional[List[str]] = None
         # leaf first: of one chain only its deepest cached block can be
         # evicted, so with the leaf ahead of its ancestors in the LRU
@@ -985,6 +1265,10 @@ class PagedKVCacheManager:
                             tokens, self.block_size, n_full)
                     meta.prefix_hash = hashes[i]
                 self._deactivate_block(bid)
+        if self.win is not None:
+            for wid in self.win.seq_blocks.pop(seq_id):
+                if wid:
+                    self.win.drop(wid, seq_id)
 
     def _scrub_pending_for(self, bid: int) -> None:
         """Withdraw staged device ops that reference a block returning to
@@ -1017,6 +1301,8 @@ class PagedKVCacheManager:
             self.metas.pop(bid, None)
             self._scrub_pending_for(bid)
             self.free_list.append(bid)
+            if self.win is not None:
+                self.win.forget_partner(bid)
 
     def _release_block(self, bid: int) -> None:
         """Force-free a block KNOWN to be unreferenced and unindexed."""
@@ -1030,6 +1316,8 @@ class PagedKVCacheManager:
             self.radix.remove_block(bid)
         self._scrub_pending_for(bid)
         self.free_list.append(bid)
+        if self.win is not None:
+            self.win.forget_partner(bid)
 
     # -- engine handshake ---------------------------------------------------
 
@@ -1043,10 +1331,21 @@ class PagedKVCacheManager:
             raise ValueError(
                 f"sequence {seq_id} uses {len(blocks)} blocks > table width {max_blocks}"
             )
+        if self.win is not None:
+            # a block table a layer kind, side by side in one row
+            table = np.full((2 * max_blocks,), pad, dtype=np.int32)
+            table[: len(blocks)] = blocks
+            chain = self.win.seq_blocks[seq_id]
+            table[max_blocks: max_blocks + len(chain)] = chain
+            return table
         table = np.full((max_blocks,), pad, dtype=np.int32)
         table[: len(blocks)] = blocks
         return table
 
     def get_stats(self) -> Dict[str, Any]:
         self.stats.free_blocks = len(self.free_list)
+        self.stats.blocks_in_use = \
+            self.num_blocks - 1 - len(self.free_list) - len(self.cached_lru)
+        if self.win is not None:
+            self.stats.window_blocks_in_use = self.win.in_use
         return self.stats.as_dict()

@@ -110,6 +110,11 @@ def require_kv_pages(engine: "TPUEngine") -> None:
             f"{engine.model_cfg.name}: the KV handoff and migration wire "
             "carries K/V pages, not the index keys this engine caches "
             "beside them")
+    if getattr(getattr(engine, "model_cfg", None), "mixed_attention", False):
+        raise ValueError(
+            f"{engine.model_cfg.name}: the KV handoff and migration wire "
+            "carries one kind's K/V pages under one block table; this "
+            "engine keeps pages per layer kind")
 
 
 def export_slot_kv(engine: "TPUEngine", slot: int) -> KVHandoff:

@@ -328,15 +328,15 @@ class Worker:
                 core = getattr(eng, "engine", None)
                 if self.config.role != "hybrid" and core is not None and \
                         getattr(core, "stats", {}).get("kv_layout") in (
-                            "latent", "hybrid", "kv+index"):
+                            "latent", "hybrid", "kv+index", "kv+window"):
                     # a prefill / decode role hands K/V pages to a peer
                     # (runtime/kv_handoff.py require_kv_pages): refused
                     # here, where the worker is configured
                     raise EngineLoadError(
                         f"role {self.config.role!r}: the PD handoff carries "
                         f"K/V pages, {cfg.model} caches latent pages (and, "
-                        "a hybrid model, state rows) or index keys beside "
-                        "its K/V pages")
+                        "a hybrid model, state rows), index keys beside "
+                        "its K/V pages or pages per layer kind")
                 self.engines[task_type] = eng
                 loaded.append(task_type)
             except (EngineLoadError, KeyError) as exc:

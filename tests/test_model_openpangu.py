@@ -124,16 +124,27 @@ def test_registry_and_the_cut():
         get_model_config(MODEL, held_experts=(6, 4))
 
 
-@pytest.mark.parametrize("field, value", [
-    ("sandwich_norm", True), ("n_shared_experts", 1), ("first_k_dense", 1),
-    ("routed_scaling_factor", 2.5), ("held_experts", (0, 4)),
+@pytest.mark.parametrize("field, value, refused", [
+    ("sandwich_norm", True, True), ("mla_use_nope", True, True),
+    ("router_selection_bias", True, True),
+    ("n_shared_experts", 1, False), ("first_k_dense", 1, False),
+    ("routed_scaling_factor", 2.5, False), ("held_experts", (0, 4), False),
 ])
 def test_a_kv_model_refuses_the_fields_only_the_latent_model_reads(
-        field, value):
+        field, value, refused):
     """``models/llama.py`` would drop them without a word: a K/V model that
-    asks for one is refused when its configuration is made."""
-    with pytest.raises(ValueError, match=field):
-        get_model_config("olmoe-tiny", **{field: value})
+    asks for one is refused when its configuration is made. The expert
+    layer's per-layer description (a dense lead, a shared expert, the scale,
+    a held share) is both recipes' since PR 49: the K/V recipe takes it, and
+    refuses it only where no expert layer would read it."""
+    if refused:
+        with pytest.raises(ValueError, match=field):
+            get_model_config("olmoe-tiny", **{field: value})
+    else:
+        assert getattr(get_model_config("mixtral-tiny", **{field: value}),
+                       field) == value
+        with pytest.raises(ValueError, match=field):
+            get_model_config("llama3-tiny", **{field: value})
     assert getattr(get_model_config(MODEL, **{field: value}), field) == value
 
 

@@ -552,3 +552,56 @@ def test_supports_ragged_engine_facts(params):
     finally:
         eng.cfg = orig
     assert eng.supports_ragged
+
+
+# --------------------------------------------------------------------- #
+# PR 49: a window SHORTER than the context (no configuration before it
+# served one: Mistral's 4096 at 2048) at GQA groups that are no multiples
+# of 8, contexts over several page groups: both kernels, interpreted,
+# against the XLA oracle
+# --------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("nh,window", [(12, 512), (18, 512), (18, 100),
+                                       (12, None)],
+                         ids=["group6-w512", "group9-w512", "group9-w100",
+                              "group6-full"])
+def test_a_window_shorter_than_the_context_at_groups_of_6_and_9(nh, window):
+    """Laguna-S-2.1's two kinds over two KV heads: decode rows and pieces
+    whose contexts pass one, two and three 512-token page groups, the
+    window's first key inside a group, on a group's first token and on its
+    last."""
+    rows = [(1, 700), (64, 1100), (1, 513), (48, 1024), (1, 1536), (0, 0),
+            (1, 1023)]
+    _compare(_ragged_setup(rows, nh=nh, hkv=2, d=32, block=16, m=96),
+             16, window=window)
+
+
+@pytest.mark.parametrize("nh,window", [(12, None), (18, 512), (18, 100)],
+                         ids=["group6-full", "group9-w512", "group9-w100"])
+def test_the_fused_decode_kernel_under_a_window_shorter_than_the_context(
+        nh, window):
+    from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention_fused,
+    )
+
+    lens = [700, 513, 1536, 1023, 1024, 40]
+    q, k_pool, v_pool, tables, positions, kv_lens = _ragged_setup(
+        [(1, n) for n in lens], nh=nh, hkv=2, d=32, block=16, m=96)
+    rng = np.random.default_rng(1)
+    new_k = jnp.asarray(rng.standard_normal((len(lens), 1, 2, 32)),
+                        jnp.float32)
+    new_v = jnp.asarray(rng.standard_normal((len(lens), 1, 2, 32)),
+                        jnp.float32)
+    got, got_k, got_v = paged_decode_attention_fused(
+        q, new_k, new_v, k_pool[None], v_pool[None], jnp.int32(0), tables,
+        positions, kv_lens, 16, window=window, interpret=True)
+    from distributed_gpu_inference_tpu.models.llama import _write_kv_pages
+
+    want_k = _write_kv_pages(k_pool, new_k, tables, positions, 16)
+    want_v = _write_kv_pages(v_pool, new_v, tables, positions, 16)
+    want = paged_attention_xla(q, want_k, want_v, tables, positions, kv_lens,
+                               16, window=window)
+    np.testing.assert_array_equal(np.asarray(got_k[0]), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(got_v[0]), np.asarray(want_v))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)

@@ -340,3 +340,244 @@ class TestLongChains:
         assert got[3:] == old[n - 1::-1]
         assert len(probes) == n
         assert m.radix.match_prefix(toks(n * BS, 10 ** 6)) == new[:n]
+
+
+# --------------------------------------------------------------------- #
+# pages per layer kind: a model of mixed attention kinds keeps its sliding
+# layers' pages in a pool and a chain of their own inside the one manager
+# --------------------------------------------------------------------- #
+
+WINDOW = 64          # four 16-token blocks
+
+
+def _mixed(full=64, window_blocks=24, **kw):
+    return PagedKVCacheManager(full, 16, window_blocks=window_blocks,
+                               window=WINDOW, **kw)
+
+
+def _serve(m, seq, tokens, piece=48, new=0):
+    """A sequence as the engine serves it: the window kind's blocks before
+    every piece, the release after it, then ``new`` decode tokens."""
+    _, cached = m.allocate_sequence(seq, tokens)
+    off = cached
+    while off < len(tokens):
+        upto = min(off + piece, len(tokens))
+        m.extend_window(seq, upto)
+        off = upto
+        # the earliest coming query sits at ``off``
+        m.release_out_of_window(seq, WINDOW + len(tokens) - off)
+    for t in range(new):
+        m.append_token(seq, 1000 + t)
+        m.release_out_of_window(seq, WINDOW)
+    return cached
+
+
+@pytest.mark.parametrize("prompt,new,full,held", [
+    (40, 0, 3, 3),        # shorter than the window: every block in both
+    (200, 0, 13, 5),      # the prompt's last window (and its partial block)
+    (200, 30, 15, 5),     # the window follows the decoded tokens
+    (64, 1, 5, 5),        # a boundary: nothing is past any query yet
+])
+def test_pages_held_per_kind_as_a_row_advances(prompt, new, full, held):
+    m = _mixed()
+    _serve(m, "a", list(range(prompt)), new=new)
+    chain = m.win.seq_blocks["a"]
+    assert len(m.seq_blocks["a"]) == full == len(chain)
+    assert all(m.seq_blocks["a"])                       # whole
+    assert sum(1 for b in chain if b) == held == m.window_resident_blocks("a")
+    assert chain[:full - held] == [0] * (full - held)
+    st = m.get_stats()
+    assert st["blocks_in_use"] == full and st["window_blocks_in_use"] == held
+    assert st["window_released_blocks"] == full - held
+    table = m.block_table_for("a", 16)
+    assert list(table[:full]) == m.seq_blocks["a"]
+    assert list(table[16:16 + full]) == chain and table.shape == (32,)
+
+
+def test_a_released_window_block_is_findable_until_the_pool_needs_it():
+    m = _mixed(window_blocks=12)
+    doc = list(range(160))                              # 10 blocks
+    _serve(m, "a", doc + [7] * 20, new=70)
+    # released while the row lived: parked, tied to the row's full blocks
+    assert m.get_stats()["window_blocks_retained"] == 11
+    assert m.win.num_parked + m.win.in_use <= 11
+    m.free_sequence("a")
+    assert m.win.in_use == 0 and m.get_stats()["blocks_in_use"] == 0
+    # the document's last window is among what the pool still holds ...
+    assert m.allocate_sequence("b", doc + [8] * 30)[1] == 160
+    assert m.stats.prefix_lookups_matched == 1
+    assert m.stats.prefix_hits_cut_by_window == 0
+    # ... as shared blocks of the hit's chain, nothing held before them
+    chain = m.win.seq_blocks["b"]
+    assert chain[:6] == [0] * 6 and all(chain[6:10]) and len(chain) == 10
+    assert m.window_resident_blocks("b") == 4
+    m.free_sequence("b")
+    # until other rows' pages take the pool: a block no hit has used goes
+    # when its sequence parks the most (here: many short prompts, whose own
+    # trails stay shorter than the finished document's)
+    m = _mixed(window_blocks=12)
+    _serve(m, "a", doc + [7] * 20, new=70)
+    m.free_sequence("a")
+    for n in range(12):
+        _serve(m, f"c{n}", [900 + n] * 100)
+        m.free_sequence(f"c{n}", cache=False)
+    assert m.stats.window_blocks_evicted >= 8
+    assert _serve(m, "d", doc + [8] * 30) == 0
+    assert m.stats.prefix_hits_cut_by_window == 1
+    assert m.stats.prefix_hit_tokens_cut_by_window == 160
+
+
+@pytest.mark.parametrize("gone,hit", [
+    ((), 160),            # all there: the whole document
+    ((9,), 144),          # the last block gone: cut to the window before it
+    ((6,), 96),           # a block inside the last window: back to where a
+                          # whole window ends before it
+    ((3, 9), 144),        # a block no remaining depth's window needs
+    ((5, 9), 80),         # two windows broken: the one that ends before both
+    ((0, 1, 5, 9), 0),    # no depth has its whole window: nothing
+])
+def test_a_hit_is_cut_back_exactly_where_the_window_kind_ends(gone, hit):
+    m = _mixed()
+    doc = list(range(160))
+    _serve(m, "a", doc + [7] * 80)
+    m.free_sequence("a")
+    full = m.radix.match_prefix(doc)
+    for i in gone:                  # what eviction does to one block
+        m.win.free_list.append(
+            m.win.evict(m.win.by_full[full[i]], m.stats))
+    _, cached = m.allocate_sequence("b", doc + [8] * 30)
+    assert cached == hit
+    assert m.stats.prefix_hits_cut_by_window == (hit < 160)
+    assert m.stats.prefix_hit_tokens_cut_by_window == 160 - hit
+    chain = m.win.seq_blocks["b"]
+    first = max(hit - WINDOW + 1, 0) // 16
+    assert chain == [0] * first + [m.win.by_full[b]
+                                   for b in full[first:hit // 16]]
+    # the full kind holds the prompt whole, the hit's blocks shared
+    assert m.seq_blocks["b"][:hit // 16] == full[:hit // 16]
+    assert len(m.seq_blocks["b"]) == 12
+
+
+def test_window_eviction_never_frees_or_dangles_the_full_chain():
+    m = _mixed(window_blocks=8)
+    doc = list(range(320))
+    _serve(m, "a", doc)
+    m.free_sequence("a")
+    full = m.radix.match_prefix(doc)
+    assert len(full) == 20 and m.stats.window_blocks_evicted > 0
+    # every full block is still indexed and cached, whatever the window
+    # kind kept of them
+    assert all(m.radix.contains_block(b) for b in full)
+    assert all(b in m.cached_lru for b in full)
+    assert set(m.win.by_full) <= set(full)
+    assert all(m.win.partner[w] == f for f, w in m.win.by_full.items())
+    # and the other way: a full block that goes takes its partner along
+    before = len(m.win.free_list)
+    held = len(m.win.by_full)
+    m.clear_cached()
+    assert not m.win.by_full and not m.win.partner and not m.win.num_parked
+    assert len(m.win.free_list) == before + held == 7
+
+
+@pytest.mark.parametrize("dry", ["full", "window"])
+def test_exhaustion_rolls_back_across_both_kinds(dry):
+    m = _mixed(full=8 if dry == "full" else 64,
+               window_blocks=5 if dry == "window" else 24)
+    _serve(m, "a", list(range(40)))                     # 3 blocks a kind
+    free = (len(m.free_list), len(m.win.free_list))
+    if dry == "full":
+        with pytest.raises(OutOfBlocksError):
+            m.allocate_sequence("b", list(range(500, 600)))
+        assert "b" not in m.seq_blocks and "b" not in m.win.seq_blocks
+    else:
+        m.allocate_sequence("b", list(range(500, 600)))
+        with pytest.raises(OutOfBlocksError):
+            m.extend_window("b", 48)        # three blocks, one is left
+        assert m.win.seq_blocks["b"] == []
+        m.free_sequence("b", cache=False)
+    assert (len(m.free_list), len(m.win.free_list)) == free
+    # a scan's horizon: both kinds or neither, the trim gives both back
+    grown = (len(m.seq_blocks["a"]), len(m.win.seq_blocks["a"]))
+    with pytest.raises(OutOfBlocksError):
+        m.reserve_tokens("a", 200)
+    m.trim_reserved("a")
+    assert (len(m.seq_blocks["a"]), len(m.win.seq_blocks["a"])) == grown
+    assert (len(m.free_list), len(m.win.free_list)) == free
+
+
+def test_preempt_and_resume_find_both_kinds_pages():
+    """Preemption frees a row with its pages cached; the resume is a hit
+    that needs the last window, and a resume after the window pool was
+    taken back recomputes instead."""
+    m = _mixed()
+    tokens = list(range(150))
+    _serve(m, "a", tokens, new=20)
+    seq = m.seq_tokens["a"][:160]
+    m.trim_reserved("a")
+    m.free_sequence("a")
+    assert _serve(m, "a2", seq + [5]) == 160
+    m.free_sequence("a2")
+    while m.win.num_parked:
+        m.win.free_list.append(m.win.evict_one(m.stats))
+    assert _serve(m, "a3", seq + [5]) == 0
+
+
+def test_a_model_wide_window_is_the_one_kind_case_of_the_same_release():
+    """Mistral: one pool, one chain, ``release_out_of_window`` on it, and a
+    chain with released blocks stays out of the radix index."""
+    m = PagedKVCacheManager(64, 16)
+    assert m.win is None and m.extend_window("x", 10 ** 6) == []
+    tokens = list(range(200))
+    m.allocate_sequence("a", tokens)
+    assert m.release_out_of_window("a", WINDOW) == list(range(8))
+    assert m.seq_blocks["a"][:8] == [0] * 8 and m.window_resident_blocks("a") == 0
+    assert m.block_table_for("a", 16).shape == (16,)
+    m.free_sequence("a")
+    assert m.radix.match_prefix(tokens) == []
+    assert m.get_stats()["window_released_blocks"] == 8
+
+
+def test_a_long_cold_prompt_eats_its_own_trail_not_the_other_rows_blocks():
+    """The window pool takes back the oldest block of the sequence that
+    parks the most: a cold 1,600-token prompt releases a pool's worth of
+    blocks in a row and evicts its own, while the document end another row
+    parked stays findable; under plain LRU it would be gone."""
+    m = _mixed(full=400, window_blocks=40)
+    doc = list(range(160))
+    _serve(m, "a", doc + [7] * 20, new=70)
+    m.free_sequence("a")
+    parked_by_a = dict(m.win.parked["a"])
+    assert len(parked_by_a) >= 8
+    _serve(m, "b", [9] * 1600)                  # 100 blocks through a pool of 39
+    assert m.stats.window_blocks_evicted >= 60
+    assert dict(m.win.parked["a"]) == parked_by_a       # untouched
+    assert len(m.win.parked["b"]) > len(parked_by_a)
+    m.free_sequence("b", cache=False)
+    assert m.allocate_sequence("c", doc + [8] * 30)[1] == 160
+    assert m.stats.prefix_hits_cut_by_window == 0
+
+
+def test_blocks_a_hit_has_used_outlive_what_the_replies_released_after_them():
+    """A session: the same document before every request. Its last window's
+    blocks are the oldest a reply's sequence parks; once a hit has used them
+    they are proven and the replies' own trails go first."""
+    m = _mixed(full=400, window_blocks=30)
+    doc = list(range(160))
+    _serve(m, "r1", doc + [1] * 20, new=70)
+    m.free_sequence("r1")
+    for turn in range(2, 8):        # six more requests on the document
+        assert _serve(m, f"r{turn}", doc + [turn] * 20, new=70) == 160, turn
+        m.free_sequence(f"r{turn}")
+    assert m.stats.prefix_hits_cut_by_window == 0
+    assert m.stats.window_blocks_evicted >= 10
+    assert len(m.win.parked_proven) == 4 and m.win.proven == set(
+        m.win.by_full[b] for b in m.radix.match_prefix(doc)[6:10])
+    # proven blocks are no more than half the pool: past that the oldest go
+    for n in range(8):
+        other = [1000 * (n + 1) + t for t in range(160)]
+        _serve(m, f"o{n}a", other + [1] * 20, new=10)
+        m.free_sequence(f"o{n}a")
+        assert _serve(m, f"o{n}b", other + [2] * 20, new=10) == 160
+        m.free_sequence(f"o{n}b")
+    assert len(m.win.parked_proven) <= 22      # half the pool at an eviction
+    assert m.allocate_sequence("late", doc + [9] * 20)[1] < 160

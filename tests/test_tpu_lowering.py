@@ -42,6 +42,9 @@ from distributed_gpu_inference_tpu.ops.quantization import quantize_params
 from distributed_gpu_inference_tpu.parallel import sharding as sh
 
 MODELS = ("mistral-7b", "qwen2.5-7b")
+# the window kind's pool of a model of mixed attention kinds, as the engine
+# sizes it for 8 slots and a window of 512
+WINDOW_BLOCKS = 1 + 8 * 8 * 32
 # what the worker path produces: max_batch_size 8 rows, max_seq_len 2048
 BATCH, CTX = 8, 2048
 
@@ -274,10 +277,12 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
             llama.init_params(cfg, jax.random.PRNGKey(0)), "int8"
         )
     )
+    kinds = len(cfg.cache_kinds) if not cfg.latent_kv else 1
     kv = jax.eval_shape(
         lambda: llama.init_kv_pools(
             cfg, 1 + BATCH * (ctx // 16), 16,
-            state_rows=BATCH if cfg.num_kda_layers else None)
+            state_rows=BATCH if cfg.num_kda_layers else None,
+            window_blocks=WINDOW_BLOCKS if kinds > 1 else None)
     )
     if mesh is None:
         one = SingleDeviceSharding(devices[0])
@@ -310,8 +315,8 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
     return jax.jit(step, donate_argnums=(1,)).lower(
         place(params, p_sh), place(kv, kv_sh),
         sds(tokens, jnp.int32), sds(tokens, jnp.int32),
-        sds((BATCH, ctx // 16), jnp.int32), sds((BATCH,), jnp.int32),
-        *where,
+        sds((BATCH, kinds * (ctx // 16)), jnp.int32),
+        sds((BATCH,), jnp.int32), *where,
     )
 
 
@@ -824,3 +829,48 @@ def test_indexed_scan_gathers_its_index_keys_once_outside_the_step_loop(
     assert stats.temp_size_in_bytes < 64 * 1024 ** 2
     size = L * BATCH * KEYE_CTX * 128 * 2
     assert stats.alias_size_in_bytes >= size + 3 * L * blocks * 16 * 128 * 2
+
+
+# --------------------------------------------------------------------- #
+# (f) window and full attention mixed layer by layer, pages per kind:
+# Laguna-S-2.1's first stage at its published widths and the served 24,576
+# positions. GQA groups of 6 (full) and 9 (sliding) are no multiples of 8.
+# --------------------------------------------------------------------- #
+
+LAGUNA = "laguna-s-2.1-ep4-12l"
+LAGUNA_CTX = 24576
+
+
+@pytest.mark.parametrize("tp,s", [(None, 1), (264, 256), (2048, 256)],
+                         ids=["scan-step", "Tp264", "Tp2048"])
+def test_mixed_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch, tp,
+                                                     s):
+    """A decode step and the packed round at two rungs, 24,576 positions a
+    row, GQA groups 6 and 9, window 512. The attention kernels as they are,
+    called per kind over that kind's pools and block table; both kinds'
+    pages are written and read in place in their stacked pools (no array of
+    a pool layer's shape); the 64 held experts go through the grouped-matmul
+    kernel (no dequantised copy) and no ``[T, 256, ...]`` temporary of the
+    router's width exists."""
+    cfg = get_model_config(LAGUNA)
+    lowered = _forward_chunk_lowered(cfg, s, None, v5e, tp=tp,
+                                     ctx=LAGUNA_CTX)
+    found = _kernels(lowered)
+    want = {"dgi_paged_decode", "dgi_moe_gmm_step"} if tp is None else {
+        "dgi_paged_write", "dgi_ragged_attention", "dgi_moe_gmm"}
+    assert want <= found and found <= want | {"dgi_qmm"}, found
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    nkv, d = cfg.num_kv_heads, cfg.head_dim
+    for (_, layers, _), blocks in zip(
+            cfg.cache_kinds, (1 + BATCH * (LAGUNA_CTX // 16), WINDOW_BLOCKS)):
+        whole = f"[{layers},{blocks},{nkv},16,{d}]"
+        assert whole in text
+        assert f"[{blocks},{nkv},16,{d}]" not in text.replace(whole, "")
+    tokens = BATCH if tp is None else tp
+    held, hid, mi = cfg.num_held_experts, cfg.hidden_size, cfg.mlp_width
+    assert f"[{tokens},{cfg.num_experts}," not in text
+    for dt in ("bf16", "f32"):
+        assert f"{dt}[{held},{hid},{mi}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (3 if tp == 2048 else 1) * 1024 ** 3
