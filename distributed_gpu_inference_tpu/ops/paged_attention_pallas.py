@@ -19,8 +19,13 @@ decode KV path:
   ``(B, max_groups)`` and dead cells skip in a few cycles; under a learned
   selection (``keep``) only the pages that hold a token the query attends,
   which the wrapper sorts to the front of a table of their own
-  (``_selected_pages``): the kernel is bound by its DMA descriptors, not by
-  bytes, so a page is the unit and nothing is tested a page in the kernel,
+  (``_selected_pages``). The scalar core issues the DMA descriptors and the
+  vector work from ONE instruction stream, so what it does a page is time
+  the compute does not get: a page is the unit, and a page costs the kernel
+  one SMEM read and a descriptor a pool. Nothing is tested or clamped a
+  page (the wrapper pads the walk's table to whole groups), and a group's
+  pages are waited for ONCE a pool: every copy of a buffer slot signals one
+  semaphore, and one wait draws the slot's byte count,
 - DMAs each KV page HBM→VMEM exactly once (whole ``[Hkv, Bk, D]`` pages stay
   contiguous) and runs flash-style online softmax per page group,
 - **Pipelines DMA across the whole (sequence, group) walk** — while group g
@@ -80,8 +85,11 @@ _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 # the width the kernel alone ran fastest at (PERF.md section 6, PR 46)
 _GROUP_TOKENS = 512
 _SELECTED_GROUP_TOKENS = 2048
-# pages of a group's DMA loop unrolled together under a selection (a wide
-# group's loop unrolled whole would be three times 120 descriptor pairs)
+# page starts of a group's DMA loop unrolled together under a selection. A
+# wide group's loop unrolled whole (twice 120 descriptor pairs) runs the
+# kernel 4 % faster, its starts' offsets being constants, and costs every
+# start of the worker a quarter more set-up: the lowering is Python's
+# (PERF.md section 6, PR 50)
 _SELECTED_UNROLL = 8
 
 
@@ -131,16 +139,20 @@ def _selected_pages(
     kv_lens: jax.Array,       # [B]
     block_size: int,
     window: Optional[int],
+    columns: int,             # >= M: the walk's whole groups, in pages
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """A row's walk under a selection, laid out for the decode kernel: the
-    pages that hold a token the row's query attends, in context order, the
-    others after them. → (``pages [B, M]`` int32 physical ids; ``keep [B, 1,
-    M x Bk]`` float32 in that order, with what ``positions``, ``kv_lens``
-    and ``window`` hide already taken out; ``count [B]``: the pages to
-    fetch). One stable sort of ``[B, M]`` words a call (a page's ``keep``
-    rides it as a bit mask): the kernel then walks ``count`` pages with no
-    test of its own a page — a page DMA costs the scalar core what a branch
-    does — and masks a token's score by ``keep`` alone."""
+    pages that hold a token the row's query attends, in context order, then
+    the last of them again in every column up to ``columns``. → (``pages [B,
+    columns]`` int32 physical ids; ``keep [B, 1, columns x Bk]`` float32 in
+    that order, with what ``positions``, ``kv_lens`` and ``window`` hide
+    already taken out; ``count [B]``: the pages to fetch). One stable sort
+    of ``[B, M]`` words a call (a page's ``keep`` rides it as a bit mask):
+    the kernel then walks ``count`` pages with no test of its own a page — a
+    page DMA costs the scalar core what a branch does — and masks a token's
+    score by ``keep`` alone. The tail is what lets the kernel start a whole
+    group of copies wherever the row's pages end (its one wait a group
+    counts on that) without ever reading a page the selection dropped."""
     b, m = block_tables.shape
     if block_size > 32:
         raise ValueError(
@@ -158,9 +170,15 @@ def _selected_pages(
         ((bits == 0).astype(jnp.int32), block_tables.astype(jnp.int32), bits),
         dimension=1, is_stable=True, num_keys=1,
     )
-    keep = ((bits[:, :, None] >> slots) & 1).astype(jnp.float32)
-    return (pages, keep.reshape(b, 1, m * block_size),
-            jnp.sum(bits != 0, axis=1, dtype=jnp.int32))
+    count = jnp.sum(bits != 0, axis=1, dtype=jnp.int32)
+    last = jnp.take_along_axis(
+        pages, jnp.maximum(count - 1, 0)[:, None], axis=1)
+    tail = ((0, 0), (0, columns - m))
+    pages = jnp.where(
+        jnp.arange(columns, dtype=jnp.int32)[None, :] < count[:, None],
+        jnp.pad(pages, tail), last)
+    keep = ((jnp.pad(bits, tail)[:, :, None] >> slots) & 1).astype(jnp.float32)
+    return pages, keep.reshape(b, 1, columns * block_size), count
 
 
 def _quantize_token_rows(x: jax.Array, axes) -> Tuple[jax.Array, jax.Array]:
@@ -387,63 +405,55 @@ def _decode_kernel(
                         for c in copies:
                             c.wait()
 
+    # the table the walk reads, a page a column in the order of the walk: the
+    # block table, or under a selection the pages that hold a token the
+    # query attends. The wrapper pads it to whole groups with the page the
+    # walk repeats past its end (the table's last column, masked by
+    # ``kv_lens``; the row's last fetched page, where ``keep`` holds zeros),
+    # so a start reads its page and clamps nothing
+    walk_ref = pages_ref if selected else bt_ref
+
     def group_dma(s, j, slot, wait):
-        """Start, or wait for, the page DMAs of group j of sequence s into
-        buffer slot: the next ``gp`` pages of its table, or under a selection
-        of the pages it attends a token of. Reads go through the ALIASED
-        output refs so they observe the token writes above (the written
-        scales of an int8 pool like its data pages)."""
-        if selected:
-            row = jnp.clip(s, 0, batch - 1)
-            # past the row's last page: that page again (``keep`` holds
-            # zeros there), never one the selection dropped
-            last = jnp.maximum(lax.div(lens_ref[row], block_size) - 1, 0)
+        """Start the page DMAs of group j of sequence s into buffer slot
+        (the next ``gp`` pages of its walk), or wait for the slot to be
+        whole. Reads go through the ALIASED output refs so they observe the
+        token writes above (the written scales of an int8 pool like its
+        data pages)."""
+        pools = [(ko_hbm, kbuf, sems.at[0, slot]),
+                 (vo_hbm, vbuf, sems.at[1, slot])]
+        if quantized:
+            pools += [(kso_hbm, ksbuf, ssems.at[0, slot]),
+                      (vso_hbm, vsbuf, ssems.at[1, slot])]
+        if wait:
+            # ONE wait a pool: every copy of the group signals the pool's
+            # semaphore of this slot, and a wait draws the byte count of its
+            # destination, here the whole slot (the source only gives the
+            # descriptor its shape). That count is what was signalled
+            # BECAUSE a group always starts exactly ``gp`` whole-page copies
+            # a pool, whatever the row holds (``walk_ref`` above): a wait for
+            # more bytes than were signalled hangs the chip
+            for hbm, buf, sem in pools:
+                pltpu.make_async_copy(
+                    hbm.at[0, pl.ds(0, gp)], buf.at[slot], sem).wait()
+            return
+        row = jnp.clip(s, 0, batch - 1)
+        base = j * gp
 
-        def copies(p):
-            at = j * gp + p
-            if selected:
-                page = pages_ref[row, jnp.minimum(at, last)]
-            else:
-                idx = jnp.minimum(at, max_pages - 1)   # clamp, mask later
-                page = bt_ref[jnp.clip(s, 0, batch - 1), idx]
-            # a semaphore a page; under a selection one a pool and slot,
-            # which every copy of the group signals and every wait draws
-            # its own bytes from (a group of 2,048 tokens would hold more
-            # semaphores than the core has): the slot is whole once all
-            # are drawn
-            sem = 0 if selected else p
+        def start(p):
+            page = walk_ref[row, base + p]
             # whole-page slice [Hkv, Bk, D]: contiguous, tiling-safe
-            out = [
+            for hbm, buf, sem in pools:
                 pltpu.make_async_copy(
-                    ko_hbm.at[layer, page], kbuf.at[slot, p],
-                    sems.at[0, slot, sem]),
-                pltpu.make_async_copy(
-                    vo_hbm.at[layer, page], vbuf.at[slot, p],
-                    sems.at[1, slot, sem]),
-            ]
-            if quantized:
-                out += [
-                    pltpu.make_async_copy(
-                        kso_hbm.at[layer, page], ksbuf.at[slot, p],
-                        ssems.at[0, slot, sem]),
-                    pltpu.make_async_copy(
-                        vso_hbm.at[layer, page], vsbuf.at[slot, p],
-                        ssems.at[1, slot, sem]),
-                ]
-            return out
+                    hbm.at[layer, page], buf.at[slot, p], sem).start()
 
-        # static unroll: G paired page DMAs. Under a selection, whose group
-        # is up to 128 pages wide, a rolled loop over runs of a few
+        # static unroll: G page starts a pool. Under a selection, whose
+        # group is up to 128 pages wide, a rolled loop over runs of a few
         step = _SELECTED_UNROLL if selected and gp % _SELECTED_UNROLL == 0 \
             else gp
 
         def run(c, carry):
             for p in range(step):
-                for dma in copies(c * step + p):
-                    if wait:
-                        dma.wait()
-                    else:
-                        dma.start()
+                start(c * step + p)
             return carry
 
         if step == gp:
@@ -626,18 +636,29 @@ def _call_decode_kernel(
     else:
         n_stage = 1
     selected = keep is not None
-    lens = kv_lens
-    if selected:
-        # the walk is over the pages the selection kept a token of
-        pages, keep, count = _selected_pages(
-            keep, block_tables, positions, kv_lens, block_size, window)
-        lens = count * block_size
+    # a group is never wider than the pool: the kernel's one wait a group
+    # takes its shape from a group's worth of the pool's pages
     gp = _pages_per_group(
-        block_size, hkv, d, k_pool.dtype.itemsize, m,
+        block_size, hkv, d, k_pool.dtype.itemsize, min(m, n),
         staging_pages=2 * n_stage, scale_page_bytes=scale_page_bytes,
         selected=selected,
     )
     max_groups = -(-m // gp)
+    # the table the kernel walks is whole groups wide, its tail the page a
+    # group's copies repeat past the row's end: the kernel starts ``gp``
+    # copies a group whatever the row holds and clamps no index
+    columns = max_groups * gp
+    lens = kv_lens
+    block_tables = block_tables.astype(jnp.int32)
+    if selected:
+        # the walk is over the pages the selection kept a token of
+        pages, keep, count = _selected_pages(
+            keep, block_tables, positions, kv_lens, block_size, window,
+            columns)
+        lens = count * block_size
+    else:
+        block_tables = jnp.pad(
+            block_tables, ((0, 0), (0, columns - m)), mode="edge")
 
     in_specs = [
         pl.BlockSpec(
@@ -666,7 +687,7 @@ def _call_decode_kernel(
         pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     scalars = [
-        block_tables.astype(jnp.int32),
+        block_tables,
         lens.astype(jnp.int32),
         positions.astype(jnp.int32),
         write_positions.astype(jnp.int32),
@@ -679,11 +700,8 @@ def _call_decode_kernel(
         # the block table, which the fused write still reads
         scalars.append(pages)
         # a group's slice of the row's selection rides the grid like q
-        gsz = gp * block_size
-        keep = jnp.pad(keep, (
-            (0, 0), (0, 0), (0, max_groups * gsz - keep.shape[2])))
         in_specs.append(pl.BlockSpec(
-            (1, 1, gsz), lambda i, j, *_refs: (i, 0, j),
+            (1, 1, gp * block_size), lambda i, j, *_refs: (i, 0, j),
             memory_space=pltpu.VMEM,
         ))
     if quantized:
@@ -699,10 +717,10 @@ def _call_decode_kernel(
             pltpu.VMEM((2, gp, block_size, d), jnp.bfloat16),    # ksbuf
             pltpu.VMEM((2, gp, block_size, d), jnp.bfloat16),    # vsbuf
         ]
-    n_sems = 1 if selected else gp
-    scratch += [pltpu.SemaphoreType.DMA((2, 2, n_sems))]         # sems
+    # a semaphore a pool and slot: every copy of a group signals it
+    scratch += [pltpu.SemaphoreType.DMA((2, 2))]                 # sems
     if quantized:
-        scratch += [pltpu.SemaphoreType.DMA((2, 2, n_sems))]     # ssems
+        scratch += [pltpu.SemaphoreType.DMA((2, 2))]             # ssems
     scratch += [
         pltpu.SemaphoreType.DMA((4 if quantized else 2, b)),     # wsems
         pltpu.VMEM((n_stage, hkv, block_size, d), k_pool.dtype),

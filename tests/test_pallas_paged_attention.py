@@ -544,3 +544,147 @@ def test_page_write_reads_tokens_from_a_packed_axis():
         token_index=True)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
+
+
+# ---------------------------------------------------------------------------
+# one wait a pool and slot for a group's pages: every form that shares it
+# ---------------------------------------------------------------------------
+
+from distributed_gpu_inference_tpu.ops import (  # noqa: E402
+    paged_attention_pallas as pp,
+)
+
+# three rows over a table of 32 pages walked in groups of 8 (128 tokens): no
+# context is a multiple of the group, so every row's last group is partly
+# past its end
+_F_ROWS, _F_PAGES, _F_BK, _F_HKV, _F_NH, _F_D, _F_GROUP = 3, 32, 16, 2, 4, 128, 128
+_F_LENS = (300, 77, 500)
+DECODE_FORMS = {
+    # name: (pool dtype, window, selection, lens)
+    "dense": ("bf16", None, None, _F_LENS),
+    # rows 0 and 2 start their walk at groups 1 and 3
+    "window_starts_past_group_0": ("bf16", 60, None, _F_LENS),
+    # rows 0 and 2 fetch 11 and 17 pages: both walks end inside a group
+    "selection_ends_mid_group": ("bf16", None, (11, 3, 17), _F_LENS),
+    # an inactive row, and a row whose selection holds nothing
+    "a_row_with_no_page": ("bf16", None, (5, 0, 0), (300, 0, 77)),
+    "int8_pool": ("int8", None, None, _F_LENS),
+    "fp8_pool": ("fp8", None, None, _F_LENS),
+}
+
+
+def _form_keep(pages_kept, lens, rng):
+    """``keep [B, 1, J]``: a token or two in ``pages_kept[r]`` of row r's
+    pages, its last page (the query's own) first among them."""
+    keep = np.zeros((_F_ROWS, 1, _F_PAGES * _F_BK), np.float32)
+    for r, n in enumerate(pages_kept):
+        if not n:
+            continue
+        last = (lens[r] - 1) // _F_BK
+        chosen = np.append(rng.choice(last, n - 1, replace=False), last)
+        for page in chosen:
+            top = min(_F_BK, lens[r] - page * _F_BK)
+            keep[r, 0, page * _F_BK + rng.choice(top, min(2, top),
+                                                 replace=False)] = 1
+    return keep
+
+
+def _form_walked(tables, lens, window, keep):
+    """The pool pages a walk may read: under a selection those that hold a
+    kept token, else every column of the row's live groups (the columns past
+    its end within the last group are read and masked)."""
+    gp = _F_GROUP // _F_BK
+    walked = set()
+    for r, n in enumerate(lens):
+        if keep is not None:
+            hit = keep[r, 0].reshape(_F_PAGES, _F_BK).any(-1)
+            walked.update(tables[r, hit].tolist())
+        elif n:
+            first = 0 if window is None else max(n - window, 0) // _F_GROUP
+            cols = range(first * gp, min(-(-n // _F_GROUP) * gp, _F_PAGES))
+            walked.update(tables[r, list(cols)].tolist())
+    return sorted(walked)
+
+
+def decode_form(name, interpret=True):
+    """→ (the kernel's output over pools whose every page outside the walk
+    is poisoned, the XLA reference's over the clean pools)."""
+    dtype, window, kept, lens = DECODE_FORMS[name]
+    rng = np.random.default_rng(50)
+    lens = np.asarray(lens, np.int32)
+    n = 1 + _F_ROWS * _F_PAGES
+    tables = (1 + rng.permutation(_F_ROWS * _F_PAGES)).reshape(
+        _F_ROWS, _F_PAGES).astype(np.int32)
+    shape = (n, _F_HKV, _F_BK, _F_D)
+    kf = rng.standard_normal(shape, np.float32)
+    vf = rng.standard_normal(shape, np.float32)
+    q = jnp.asarray(rng.standard_normal((_F_ROWS, 1, _F_NH, _F_D), np.float32),
+                    jnp.bfloat16)
+    keep = None if kept is None else _form_keep(kept, lens, rng)
+    walked = _form_walked(tables, lens, window, keep)
+
+    def poisoned(pool, fill):
+        out = np.full(pool.shape, fill, np.asarray(pool).dtype)
+        out[walked] = np.asarray(pool)[walked]
+        return jnp.asarray(out)
+
+    scales = bad_scales = {}
+    if dtype == "int8":
+        (k, ks), (v, vs) = pp.quantize_kv_pool(kf), pp.quantize_kv_pool(vf)
+        scales = {"k_scale": ks, "v_scale": vs}
+        bad_scales = {"k_scale": poisoned(ks, np.nan),
+                      "v_scale": poisoned(vs, np.nan)}
+        k_bad, v_bad = poisoned(k, 127), poisoned(v, 127)
+    else:
+        to = jnp.float8_e4m3fn if dtype == "fp8" else jnp.bfloat16
+        k, v = jnp.asarray(kf, to), jnp.asarray(vf, to)
+        k_bad, v_bad = poisoned(k, np.nan), poisoned(v, np.nan)
+    args = (jnp.asarray(tables), jnp.asarray(lens - 1)[:, None],
+            jnp.asarray(lens), _F_BK)
+    sel = {} if keep is None else {"keep": jnp.asarray(keep)}
+    want = paged_attention_xla(q, k, v, *args, window=window, **scales, **sel)
+    got = pp.paged_attention_pallas(
+        q, k_bad, v_bad, *args, window=window, interpret=interpret,
+        **bad_scales, **sel)
+    return np.asarray(got, np.float32), np.asarray(want, np.float32)
+
+
+@pytest.mark.parametrize("name", DECODE_FORMS)
+def test_one_wait_a_group_in_every_form_of_the_walk(name, monkeypatch):
+    """Dense, behind a window, under a selection, with an empty row, over
+    int8 and fp8 pools: a group's pages are started a page at a time and
+    waited for once a pool, the walk reads no page outside it (NaN there
+    would reach the output), and the result is the reference's."""
+    monkeypatch.setattr(pp, "_GROUP_TOKENS", _F_GROUP)
+    monkeypatch.setattr(pp, "_SELECTED_GROUP_TOKENS", _F_GROUP)
+    got, want = decode_form(name)
+    assert not np.isnan(got).any()
+    np.testing.assert_allclose(got, want, rtol=3e-2, atol=3e-2)
+
+
+def test_the_selected_table_ends_in_the_rows_last_fetched_page():
+    """What the kernel's one wait a group rests on: past a row's fetched
+    pages its table holds the last of them again, up to the walk's whole
+    groups, so a group always starts its full count of copies and none is
+    of a page the selection dropped."""
+    rng = np.random.default_rng(3)
+    lens = np.asarray([300, 0, 77], np.int32)
+    kept = (11, 0, 3)
+    keep = _form_keep(kept, lens, rng)
+    tables = (1 + rng.permutation(_F_ROWS * _F_PAGES)).reshape(
+        _F_ROWS, _F_PAGES).astype(np.int32)
+    columns = 40                      # five groups of 8 over 32 pages
+    pages, laid, count = pp._selected_pages(
+        jnp.asarray(keep), jnp.asarray(tables), jnp.asarray(lens - 1),
+        jnp.asarray(lens), _F_BK, None, columns)
+    pages, laid, count = map(np.asarray, (pages, laid, count))
+    assert pages.shape == (_F_ROWS, columns)
+    assert laid.shape == (_F_ROWS, 1, columns * _F_BK)
+    assert count.tolist() == list(kept)
+    for r, n in enumerate(kept):
+        hit = keep[r, 0].reshape(_F_PAGES, _F_BK).any(-1)
+        assert pages[r, :n].tolist() == tables[r, hit].tolist()
+        assert laid[r, 0, :n * _F_BK].sum() == keep[r].sum()
+        assert not laid[r, 0, n * _F_BK:].any()
+        if n:
+            assert (pages[r, n:] == pages[r, n - 1]).all()

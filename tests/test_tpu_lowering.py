@@ -141,6 +141,80 @@ def test_fused_decode_kernel_compiles(v5e, model, block, quantized):
     ).compile()
 
 
+def _mosaic_text(lowered):
+    """The Mosaic module of the one Pallas kernel a lowered program calls,
+    as text (the call carries it as MLIR bytecode)."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    (body,) = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                         lowered.as_text())
+    ctx = jax_mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return str(ir.Module.parse(base64.b64decode(body)))
+
+
+# the forms of the decode kernel's walk that share the one wait a group:
+# name: (pool dtype, window, under a selection, tokens of a narrow and of
+# the served group)
+DECODE_WALKS = {
+    "dense": (jnp.bfloat16, None, False, (256, 512)),
+    "window": (jnp.bfloat16, 4096, False, (256, 512)),
+    "selection": (jnp.bfloat16, None, True, (512, 2048)),
+    "int8": (jnp.int8, None, False, (256, 512)),
+    "fp8": (jnp.float8_e4m3fn, None, False, (256, 512)),
+}
+
+
+@pytest.mark.parametrize("walk", DECODE_WALKS)
+def test_decode_kernel_waits_once_a_group_at_any_group_width(v5e, walk,
+                                                             monkeypatch):
+    """``dgi_paged_decode`` at the sparse model's served shape (8 rows of
+    24,576 positions, 4 K/V heads) compiles in every form of its walk, and a
+    wider group adds page starts but no wait: one a pool for a group's
+    pages, beside the fused write's two a pool and row."""
+    from distributed_gpu_inference_tpu.ops import paged_attention_pallas as pp
+
+    dtype, window, selected, widths = DECODE_WALKS[walk]
+    cfg = get_model_config(KEYE)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    block, layers, m = 16, 2, KEYE_CTX // 16
+    quantized = dtype == jnp.int8
+    pool = sds((layers, 1 + BATCH * m, cfg.num_kv_heads, block,
+                cfg.head_dim), dtype)
+    scale = sds((layers, 1 + BATCH * m, block, cfg.head_dim),
+                jnp.bfloat16) if quantized else None
+    new = sds((BATCH, 1, cfg.num_kv_heads, cfg.head_dim),
+              jnp.bfloat16 if quantized else dtype)
+    keep = sds((BATCH, 1, KEYE_CTX), jnp.float32) if selected else None
+    waits, starts = [], []
+    for tokens in widths:
+        monkeypatch.setattr(
+            pp, "_SELECTED_GROUP_TOKENS" if selected else "_GROUP_TOKENS",
+            tokens)
+        # a function of its own a width: jit would hand back the first trace
+        fn = functools.partial(
+            paged_decode_attention_fused, block_size=block, window=window)
+        lowered = jax.jit(fn).lower(
+            sds((BATCH, 1, cfg.num_heads, cfg.head_dim), jnp.bfloat16),
+            new, new, pool, pool, sds((), jnp.int32),
+            sds((BATCH, m), jnp.int32), sds((BATCH, 1), jnp.int32),
+            sds((BATCH,), jnp.int32), k_scale=scale, v_scale=scale,
+            keep=keep)
+        assert _kernels(lowered) == {"dgi_paged_decode"}
+        lowered.compile()
+        text = _mosaic_text(lowered)
+        waits.append(text.count("tpu.wait_dma2"))
+        starts.append(text.count("tpu.enqueue_dma"))
+    pools = 4 if quantized else 2
+    assert waits == [2 * pools * BATCH + pools] * 2, (waits, starts)
+    if not selected:      # a selection's starts sit in a rolled loop
+        assert starts[0] < starts[1], starts
+
+
 @pytest.mark.parametrize("block", [16, 32])
 @pytest.mark.parametrize("model", MODELS + ("olmoe-1b-7b",))
 def test_page_write_kernel_compiles(v5e, model, block):
