@@ -81,8 +81,10 @@ _STALL_FACTOR = 10.0
 _SCAN_REASONS = ("amortise", "raised_waiting", "capped_by_budget")
 # why a scan was read back before the next round went out behind it, counted
 # as ``chain_breaks_<reason>`` (``ContinuousBatcher._chain_break``)
+# (``round``: what was read was a ragged round that had gone out behind the
+# scan, which is always read before the next round goes out)
 _CHAIN_BREAKS = ("admission", "row_end_waiting", "row_end", "signal",
-                 "pressure", "idle")
+                 "pressure", "idle", "round")
 
 
 def _mean(mean: float, sample: float) -> float:
@@ -363,6 +365,10 @@ class ContinuousBatcher:
         # idle since the last scan that was read, for ``_retune``
         self._unread_steps: Optional[int] = None
         self._unread_reason = ""
+        # the ragged round the last call left unread behind the scan it
+        # read (``TPUEngine.ragged_round``): what its stamp will say (the
+        # round's number, its pieces, its cause), None when none is
+        self._unread_round: Optional[Tuple[int, int, str]] = None
         self._exposed_s = self._cost_s = 0.0
         # the last round's stamp, made where it returned on the engine
         # thread: what the streams' snapshots of that round carry
@@ -397,7 +403,10 @@ class ContinuousBatcher:
             # fresh admissions, and those of them bound while the scan
             # before their round was still unread on the device
             "ragged_admissions": 0, "admissions_ahead": 0,
-            "ragged_rounds": 0,
+            # ragged rounds, and those of them dispatched behind an unread
+            # scan (the call brought that scan's tokens back and left the
+            # round's on the device for the next read)
+            "ragged_rounds": 0, "ragged_rounds_chained": 0,
             "budgeted_rounds": 0, "budget_skipped_admissions": 0,
             "preemptions": 0, "resumes": 0, "preemption_block_pressure": 0,
             "preempted_too_often": 0,
@@ -1372,23 +1381,36 @@ class ContinuousBatcher:
         return False
 
     def _chain_break(self) -> Optional[str]:
-        """Why the scan left unread must be read before the loop goes on,
-        or None: the next round is another scan over its rows, which goes
-        out behind it, and the loop's work of this round (deliver, admit,
-        both hops to the engine thread, the engine's build and upload) runs
-        while the device does. Read from what the batcher holds now:
+        """Why what was left unread must be read before the loop goes on,
+        or None: the next round is another scan over the unread scan's
+        rows, which goes out behind it, and the loop's work of this round
+        (deliver, admit, both hops to the engine thread, the engine's build
+        and upload) runs while the device does. Read from what the batcher
+        holds now:
 
+        ``round``: what is unread is a ragged round (it went out behind
+        the scan its call read): always read next, whatever else waits;
         ``signal``: a cancel, interrupt or deadline wants a slot, or an
         out-of-band engine call read the scan or waits for the thread;
         ``pressure``: the pool froze a row, or resumes are held;
         ``admission``: a request waits and a slot is free: the next round
-        is not a scan;
+        is not a scan. Its admission pass runs beside the unread scan, and
+        where that leaves a piece to run and nothing for the read to
+        settle, so does the round (``_run``): no read in between;
         ``row_end_waiting``: a request waits and a row's budget ends inside
         the unread scan: a slot coming free is an admission, not a scan
         (``_choose_steps`` never runs a raised scan past that step either);
         ``idle``: no row has a step left after it.
         (``row_end``, in ``_deliver``: a row was found finished.) An
         arrival so waits for at most the scan that went out as it came."""
+        if self._unread_round is not None:
+            return "round"
+        return self._scan_break()
+
+    def _scan_break(self) -> Optional[str]:
+        """``_chain_break`` of an unread scan; of an unread round, what
+        else wants the engine beside its read (``admission``: an arrival
+        may be bound beside it too)."""
         eng = self.engine
         if not eng.scan_unread or self._foreign:
             return "signal"
@@ -1404,6 +1426,17 @@ class ContinuousBatcher:
         if not eng.decode_budgets().any():
             return "idle"
         return None
+
+    def _round_goes_behind(self) -> bool:
+        """After the pass ahead of an unread scan's read: the round that
+        carries what it admitted goes out behind that scan too (the engine
+        reads the scan inside that call), unless the pass stopped at
+        something that needs the read (a request still waits beside a free
+        slot: a resume, or a prompt the pool cannot hold before the read
+        gives blocks back): then the old order."""
+        return bool(self._ragged) and not (
+            self._heap and self.engine.free_slots()) and bool(
+            getattr(self.engine, "supports_scan_ahead", False))
 
     def _scan_measured(self, steps: Optional[int],
                        emitted: Dict[int, List[int]], gap_s: float,
@@ -1470,10 +1503,11 @@ class ContinuousBatcher:
     def _engine_round(self) -> Optional[Tuple[int, float, float]]:
         """One engine round on the worker thread. Returns what a scan
         measured for ``_retune`` (``_scan_measured``: the scan whose tokens
-        came back, which is the one before the scan this round dispatched
-        where the engine leaves scans unread) and None after a ragged
-        round, which is as long as the prompt tokens it admits, whatever the
-        level, and so says nothing about how long a scan should be. (The
+        came back, which is the one before the dispatch of this round where
+        the engine leaves dispatches unread) and None after a ragged round
+        that read itself, which is as long as the prompt tokens it admits,
+        whatever the level, and so says nothing about how long a scan
+        should be. (The
         gap *before* a scan, not the one after: a row that ends in a scan is followed by
         its slot's next admission, which is no cost of a round; that gap
         falls to the ragged round it precedes. On the v5e, charged to the
@@ -1482,7 +1516,13 @@ class ContinuousBatcher:
         Admissions in flight dispatch ONE ``engine.ragged_round``: every
         active decode slot advances one token and every admission advances
         one prefill chunk in the same invocation — build-ragged-batch →
-        dispatch → commit, no competing prefill dispatch. With no
+        dispatch → commit, no competing prefill dispatch. Where a scan is
+        unread the engine puts the round BEHIND it and the call brings back
+        that scan's tokens, not the round's (``engine.round_unread``): the
+        stamp made here is then the scan's, ``_scan_measured`` gets it as a
+        chained scan's, and the round's stamp waits in ``_unread_round``
+        for its read (``_collect_round``), which is what the loop does
+        next. With no
         admission in flight a ragged round degenerates to pure decode, so
         the multi-step scan (horizon amortization of the host RTT) is the
         better dispatch for the identical math and runs instead — left
@@ -1509,7 +1549,8 @@ class ContinuousBatcher:
         behind, self._unread_steps = self._unread_steps, None
         # what the stamp says of the tokens this call brings back: its own
         # round's, or those of the scan it went out behind
-        read, pieces, cause = steps, 0, "scan"
+        kind, read, pieces, cause = "ragged" if ragged else "scan", steps, \
+            0, "scan"
         if behind is not None and not (
                 self.engine.scan_unread and (ahead or ragged)):
             # an out-of-band engine call read it since the loop looked, or
@@ -1519,41 +1560,57 @@ class ContinuousBatcher:
         try:
             with flight.span("dgi.batcher.round", st,
                              None if ragged else f"scan_s_t{steps}",
-                             round=n, kind="ragged" if ragged else "scan",
+                             round=n, kind=kind,
                              steps=steps, level=self._levels[self._level],
                              reason=reason, queue_depth=len(self._heap),
-                             chained=int(behind is not None and not ragged)):
+                             chained=int(behind is not None
+                                         and not self._foreign)) as sp:
+                exposed = -self._host_exposed_s(engine_stats)
+                cost = gap - self._host_phases_s(engine_stats)
+                wait = -engine_stats.get("round_readback_s", 0.0)
                 if ragged:
                     adms = [adm for adm, _ in self._ragged]
                     caps = self._prefill_chunk_caps(adms)
                     pieces = len(adms) if caps is None else sum(
                         caps.get(adm.slot, 0) > 0 for adm in adms)
                     cause = "ragged_2plus" if pieces > 1 else "ragged_1"
-                    self.engine.ragged_round(adms, caps)
+                    if behind is not None and self._foreign:
+                        # an out-of-band call waits for the thread: nothing
+                        # is left unread in front of it
+                        self.engine.collect_scan()
+                    emitted = self.engine.ragged_round(adms, caps)
                     st["ragged_rounds"] += 1
-                    # (the engine reads an unread scan first: none is left
-                    # where ``_chain_break`` saw the admission come)
-                    st["chain_breaks_admission"] += behind is not None
-                    return None
-                st[f"scans_t{steps}"] = st.get(f"scans_t{steps}", 0) + 1
-                st[f"scans_{reason}"] += 1
-                st["scans_chained"] += behind is not None
-                # a scan read by the call that made it although the engine
-                # could leave it: an out-of-band call waits for the thread
-                st["chain_breaks_signal"] += can and not ahead
-                exposed = -self._host_exposed_s(engine_stats)
-                cost = gap - self._host_phases_s(engine_stats)
-                wait = -engine_stats.get("round_readback_s", 0.0)
-                if ahead:
-                    emitted, back = \
-                        self.engine.decode_multi(steps, ahead=True), behind
-                    read, came = back or 0, self._unread_reason
-                    if self.engine.scan_unread:
-                        self._unread_steps = steps
-                        self._unread_reason = reason
+                    if behind is None or not getattr(
+                            self.engine, "round_unread", False):
+                        # (the engine read the unread scan first: no room
+                        # to reserve behind it, or a caller waited)
+                        st["chain_breaks_admission"] += behind is not None
+                        sp.set(chained=0)
+                        return None
+                    # the round went out behind the scan and is unread:
+                    # what came back, and is stamped, is the scan
+                    st["ragged_rounds_chained"] += 1
+                    self._unread_round = (n, pieces, cause)
+                    back, came = behind, self._unread_reason
+                    kind, read, pieces, cause = "scan", behind, 0, "scan"
                 else:
-                    emitted, back = self.engine.decode_multi(steps), steps
-                    came = reason
+                    st[f"scans_t{steps}"] = st.get(f"scans_t{steps}", 0) + 1
+                    st[f"scans_{reason}"] += 1
+                    st["scans_chained"] += behind is not None
+                    # a scan read by the call that made it although the
+                    # engine could leave it: an out-of-band call waits for
+                    # the thread
+                    st["chain_breaks_signal"] += can and not ahead
+                    if ahead:
+                        emitted, back = self.engine.decode_multi(
+                            steps, ahead=True), behind
+                        read, came = back or 0, self._unread_reason
+                        if self.engine.scan_unread:
+                            self._unread_steps = steps
+                            self._unread_reason = reason
+                    else:
+                        emitted, back = self.engine.decode_multi(steps), steps
+                        came = reason
                 if came == "raised_waiting":
                     cause = "scan_raised"
                 exposed += self._host_exposed_s(engine_stats)
@@ -1567,14 +1624,16 @@ class ContinuousBatcher:
         finally:
             self._round_end = time.perf_counter()
             self._ready = flight.RoundStamp(
-                time.monotonic(), n, "ragged" if ragged else "scan", read,
-                pieces, cause)
+                time.monotonic(), n, kind, read, pieces, cause)
 
     def _collect_round(self, why: str
                        ) -> Optional[Tuple[int, float, float]]:
-        """Read the unread scan back on the worker thread, because the next
+        """Read what is unread back on the worker thread, because the next
         round does not go out behind it (``why``: ``_chain_break``), and
-        return what it measured for ``_retune``."""
+        return what a scan measured for ``_retune``. A round left unread
+        (``round``) is stamped here as the ragged round it is, with its
+        pieces and cause, so that a stream's wait across it names it; its
+        time says nothing about how long a scan should be."""
         t0 = time.perf_counter()
         st = self.stats
         gap = 0.0
@@ -1586,17 +1645,20 @@ class ContinuousBatcher:
         st[f"chain_breaks_{why}"] += 1
         engine_stats = getattr(self.engine, "stats", None) or {}
         steps, self._unread_steps = self._unread_steps, None
+        rnd, self._unread_round = self._unread_round, None
         try:
             if not self.engine.scan_unread:
                 return None     # the engine read it for another call
             with flight.span("dgi.batcher.round", round=self._round,
-                             kind="collect", steps=steps, reason=why,
+                             kind="collect", steps=steps or 1, reason=why,
                              queue_depth=len(self._heap), chained=0):
                 exposed = -self._host_exposed_s(engine_stats)
                 cost = gap - self._host_phases_s(engine_stats)
                 emitted = self.engine.collect_scan()
                 exposed += self._host_exposed_s(engine_stats)
                 cost += self._host_phases_s(engine_stats)
+            if rnd is not None:
+                return None
             call = time.perf_counter() - t0
             return self._scan_measured(
                 steps, emitted, gap, call, exposed, cost, True,
@@ -1605,7 +1667,8 @@ class ContinuousBatcher:
             self._round_end = time.perf_counter()
             self._ready = flight.RoundStamp(
                 time.monotonic(), self._round, "collect", steps or 0, 0,
-                "other")
+                "other") if rnd is None else flight.RoundStamp(
+                time.monotonic(), rnd[0], "ragged", 1, rnd[1], rnd[2])
 
     def _retune(self, steps: int, scan_s: float, host_s: float) -> None:
         """Keep the rule's measured times — ``s``, a scan's time per step,
@@ -1669,19 +1732,25 @@ class ContinuousBatcher:
         loop = asyncio.get_running_loop()
         latch_until = 0.0
         while True:
-            if self._unread_steps is not None:
+            behind_scan = False
+            if self._unread_steps is not None \
+                    or self._unread_round is not None:
                 # a scan is unread on the device. While the next round is
                 # another scan over its rows the loop goes straight on to
                 # dispatch it behind that one (nothing below touches the
                 # engine then); if not, the scan is read and delivered
-                # first and the loop is the one it always was
+                # first and the loop is the one it always was. (A round
+                # left unread is read here, always.)
                 why = self._chain_break()
-                if why == "admission":
+                if why == "admission" or (
+                        why == "round" and self._scan_break() == "admission"):
                     # a request waits, a slot is free and nothing else wants
                     # the engine: the pass that would follow the read runs
-                    # now, while that scan still runs on the device
+                    # now, while that dispatch still runs on the device
                     await self._admission_pass(ahead=True)
-                if why is not None:
+                    behind_scan = why == "admission" \
+                        and self._round_goes_behind()
+                if why is not None and not behind_scan:
                     try:
                         await self._deliver(await loop.run_in_executor(
                             self._exec, self._collect_round, why))
@@ -1689,35 +1758,39 @@ class ContinuousBatcher:
                         raise
                     except Exception as e:
                         await self._fail_in_flight(e)
-            # idle = no batcher-OWNED work. Deliberately not engine.num_active:
-            # a foreign slot (PD sequence retained/adopted between stages,
-            # awaiting its decode job) must neither keep this loop spinning
-            # nor be decoded/finished behind its owner's back — it joins the
-            # batch only through adopt_slot().
-            if not self._heap and not self._slot_items \
-                    and not self._ragged:
-                self._wake.clear()
-                if self._stopping:
-                    return
-                self._round_end = None      # parked: the next gap is idle
-                await self._wake.wait()
-                # admission latch: give co-arriving requests a window to form
-                # a batch (reference max_wait trigger :177-199)
-                latch_until = time.time() + self.cfg.max_wait_ms / 1000.0
-            while time.time() < latch_until and \
-                    len(self._heap) < len(self.engine.slots):
-                await asyncio.sleep(0.001)
-            await self._admission_pass()
-            if not self._slot_items and not self._ragged:
-                # no batcher-owned slot decodes: no frozen slot of OURS is
-                # waiting on freed blocks, so resumes may flow immediately
-                # (foreign slots are left untouched for their owner)
-                self._resume_hold = False
-                if self._heap:
-                    # deferred (pressured) work with an idle engine: yield
-                    # briefly instead of hot-spinning the admission loop
+            if not behind_scan:
+                # idle = no batcher-OWNED work. Deliberately not
+                # engine.num_active: a foreign slot (PD sequence
+                # retained/adopted between stages, awaiting its decode job)
+                # must neither keep this loop spinning nor be
+                # decoded/finished behind its owner's back — it joins the
+                # batch only through adopt_slot().
+                if not self._heap and not self._slot_items \
+                        and not self._ragged:
+                    self._wake.clear()
+                    if self._stopping:
+                        return
+                    self._round_end = None  # parked: the next gap is idle
+                    await self._wake.wait()
+                    # admission latch: give co-arriving requests a window
+                    # to form a batch (reference max_wait trigger :177-199)
+                    latch_until = time.time() + self.cfg.max_wait_ms / 1000.0
+                while time.time() < latch_until and \
+                        len(self._heap) < len(self.engine.slots):
                     await asyncio.sleep(0.001)
-                continue
+                await self._admission_pass()
+                if not self._slot_items and not self._ragged:
+                    # no batcher-owned slot decodes: no frozen slot of OURS
+                    # is waiting on freed blocks, so resumes may flow
+                    # immediately (foreign slots are left untouched for
+                    # their owner)
+                    self._resume_hold = False
+                    if self._heap:
+                        # deferred (pressured) work with an idle engine:
+                        # yield briefly instead of hot-spinning the
+                        # admission loop
+                        await asyncio.sleep(0.001)
+                    continue
             try:
                 measured = await loop.run_in_executor(
                     self._exec, self._engine_round
@@ -1735,9 +1808,11 @@ class ContinuousBatcher:
         out. ``ahead``: ``_chain_break`` said ``admission`` of an unread
         scan, and the pass runs before that scan's read instead of after it
         — the same pass in the same order, and the one after the read stays
-        for what this one left (``_admit``). Its seconds are no cost of the
-        scan it ran beside (``_engine_round``), whose read counts them in
-        its gap: they are taken out here."""
+        for what this one left (``_admit``); so of an unread round, before
+        whose read an arrival is bound the same way. Its seconds are no
+        cost of the scan it ran beside (``_engine_round``), whose read
+        counts them in its gap: they are taken out here (a round's read
+        counts none)."""
         t0 = time.perf_counter()
         engine_stats = getattr(self.engine, "stats", None) or {}
         cut = engine_stats.get("prefix_hit_tokens_cut_by_window", 0)
@@ -1761,7 +1836,7 @@ class ContinuousBatcher:
             # a higher-priority arrival preempts the lowest-priority
             # victim
             await self._check_pressure()
-        if ahead:
+        if ahead and self._unread_round is None:
             self._cost_s -= time.perf_counter() - t0
 
     async def _deliver(self, measured: Optional[Tuple[int, float, float]]
@@ -1776,6 +1851,26 @@ class ContinuousBatcher:
             # only what a scan measured steers the scan level
             if measured:
                 self._retune(*measured)
+            if (self._unread_steps is not None
+                    or self._unread_round is not None) and (
+                    self.engine.pressure_pending or any(
+                        s is not None and s.finish_reason is not None
+                        and i in self._slot_items
+                        for i, s in enumerate(self.engine.slots))):
+                # finishing a slot and preempting one take the engine,
+                # which reads what is unread first: read it here, where
+                # its tokens are counted, its stamp is made and its times
+                # steer the level — after the streams have what this round
+                # brought (the finished rows' too: their response waits for
+                # the read). What follows is then that read's.
+                self._notify_observers(finished=True)
+                more = await loop.run_in_executor(
+                    self._exec, self._collect_round,
+                    "round" if self._unread_round is not None
+                    else "pressure" if self.engine.pressure_pending
+                    else "row_end")
+                if more:
+                    self._retune(*more)
             # admission-chunk rounds on the timeline: one bounded
             # note per in-flight traced admission per round
             # (saturates at the per-request event cap on
@@ -1797,23 +1892,6 @@ class ContinuousBatcher:
                 self.stats["admitted"] += 1
                 self._note_first_token(item, adm.slot,
                                        round=self._round)
-            if self._unread_steps is not None and (
-                    self.engine.pressure_pending or any(
-                        s is not None and s.finish_reason is not None
-                        and i in self._slot_items
-                        for i, s in enumerate(self.engine.slots))):
-                # finishing a slot and preempting one take the engine,
-                # which reads the unread scan first: read it here, where
-                # its tokens are counted and its times steer the level —
-                # after the streams have what this round brought (the
-                # finished rows' too: their response waits for the read)
-                self._notify_observers(finished=True)
-                more = await loop.run_in_executor(
-                    self._exec, self._collect_round,
-                    "pressure" if self.engine.pressure_pending
-                    else "row_end")
-                if more:
-                    self._retune(*more)
             for i, s in enumerate(list(self.engine.slots)):
                 if s is not None and s.finish_reason is not None \
                         and i in self._slot_items:
@@ -1853,7 +1931,7 @@ class ContinuousBatcher:
         loop = asyncio.get_running_loop()
         self.stats["engine_errors"] = self.stats.get("engine_errors", 0) + 1
         # whatever was unread went with the engine's device state
-        self._unread_steps = None
+        self._unread_steps = self._unread_round = None
         # mid-prefill admissions aren't in _slot_items yet —
         # release their slots and resolve their futures here or
         # the callers hang until timeout

@@ -29,7 +29,7 @@ import time
 import uuid
 import zlib
 from dataclasses import dataclass, field, replace
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -256,6 +256,27 @@ class _UnreadScan:
     steps: np.ndarray
     emitted: jax.Array
     moe: List[jax.Array]
+
+
+@dataclass
+class _UnreadRound:
+    """A plain ragged round that was dispatched BEHIND an unread scan and
+    whose tokens the host has not read back (``ragged_round``): beside
+    ``_UnreadScan``, the other thing ``TPUEngine._unread`` may hold.
+    ``active_mask`` is every row the dispatch holds (decode rows and
+    pieces), ``kept`` the decode rows the host gave it, of which the device
+    ran those it still found live behind the scan (``live``); ``ready`` the
+    admissions' pieces, ``tp`` the packed length; a decode row takes one
+    step of it."""
+
+    active_mask: np.ndarray
+    kept: List[int]
+    ready: List[Tuple["ChunkedAdmission", List[int], bool]]
+    toks: jax.Array
+    live: Optional[jax.Array]
+    moe: List[jax.Array]
+    tp: int = 0
+    num_steps: int = 1
 
 
 @dataclass
@@ -629,12 +650,13 @@ class TPUEngine:
         # a dispatch of its own in front of the round's.
         self._dev_core: Optional[Dict[str, jax.Array]] = None
         self._core_dirty = True
-        # The one scan whose tokens are still on the device (``decode_multi``
-        # with ``ahead``): the host mirrors lag by it until ``collect_scan``,
-        # which every other entry that reads or writes slots, pool or
-        # mirrors calls first. The device orders its own work; only the
-        # host's view lags.
-        self._unread: Optional[_UnreadScan] = None
+        # The one dispatch whose tokens are still on the device: a scan
+        # (``decode_multi`` with ``ahead``) or the ragged round that went
+        # out behind one (``ragged_round``). The host mirrors lag by it
+        # until ``collect_scan``, which every other entry that reads or
+        # writes slots, pool or mirrors calls first. The device orders its
+        # own work; only the host's view lags.
+        self._unread: Optional[Union[_UnreadScan, _UnreadRound]] = None
         # whether the scan read last was still running when the host came
         # for it: then the chip had work up to the read, and the time since
         # the read before is that scan's own
@@ -683,6 +705,8 @@ class TPUEngine:
             "prefill_tokens": 0, "prefill_calls": 0, "decode_calls": 0,
             "preemptions": 0, "resumes": 0, "kv_pressure_events": 0,
             "ragged_rounds": 0,
+            # of them, those dispatched behind an unread scan
+            "ragged_rounds_chained": 0,
             # the batcher's two round calls (docs/observability.md, "Round
             # spans and counters"): calls, what a ragged rectangle held,
             # and the host's seconds in each phase of a round
@@ -1193,6 +1217,57 @@ class TPUEngine:
         self._chain_sched_fn = jax.jit(
             chain_sched, out_shardings=replicated
         )
+
+        def merge_core(core, ci, cf, keep):
+            # The device core after an admission that ran beside an unread
+            # scan: the host's rows for every column, except ``last`` and
+            # ``lens`` of that scan's rows (``keep``), where the mirrors
+            # lag and the device's own are the current ones.
+            host = unpack_core(ci, cf)
+            host["last"] = jnp.where(keep, core["last"], host["last"])
+            host["lens"] = jnp.where(keep, core["lens"], host["lens"])
+            return host
+
+        self._merge_core_fn = jax.jit(merge_core, out_shardings=replicated)
+
+        def chain_round(core, tok_at, lens_last, flag, dec_ends):
+            # The decode rows of a ragged round dispatched BEHIND a scan the
+            # host has not read, from where that scan leaves the device
+            # core, as ``chain_sched`` takes a chained scan's: ``dec_ends``
+            # holds each decode row's place on the packed axis (-1: no
+            # decode row) and the committed length at which it runs out of
+            # budget. A row the scan ended is out of the round (a pad at
+            # row b, position -1, not sampled, so the round leaves its core
+            # alone); the others feed ``last`` at ``lens``. The pieces'
+            # entries are the host's. One program a packed length, beside
+            # the round graphs and not in them.
+            dec, ends = dec_ends[:, 0], dec_ends[:, 1]
+            live, _ = chain_sched(core, ends)
+            decodes = dec >= 0
+            live = live & decodes
+            b, tp = flag.shape[0], tok_at.shape[1]
+            rows = jnp.arange(b, dtype=jnp.int32)
+            entry = jnp.stack([
+                jnp.where(live, core["last"], 0),
+                jnp.where(live, core["lens"], -1),
+                jnp.where(live, rows, b),
+                jnp.zeros_like(rows),
+            ])
+            tok_at = tok_at.at[:, jnp.where(decodes, dec, tp)].set(
+                entry, mode="drop")
+            fed = jnp.where(live, jnp.stack([core["lens"] + 1, dec]), 0)
+            lens_last = jnp.where(decodes, fed, lens_last)
+            flag = jnp.where(decodes, live.astype(flag.dtype), flag)
+            return tok_at, lens_last, flag, live
+
+        self._chain_round_fn = jax.jit(chain_round, out_shardings=replicated)
+        # On a mesh a round's packed batch is placed replicated before the
+        # round takes it, as ``chain_round`` hands it back: a host array
+        # there and a placed one here are different argument shardings,
+        # and each round graph would compile twice.
+        self._place_round_fn = None if replicated is None else jax.jit(
+            lambda tok_at, lens_last: (tok_at, lens_last),
+            out_shardings=replicated)
 
         # --- sampling fused into the serving graphs. ``mode`` is static:
         # "greedy" compiles an argmax-only epilogue (no [B, V] sort in the
@@ -1840,14 +1915,21 @@ class TPUEngine:
         cf = np.stack([self._temps, self._top_ps], axis=1).astype(np.float32)
         return ci, cf
 
-    def _sync_core(self) -> Dict[str, jax.Array]:
+    def _sync_core(self, keep: Optional[np.ndarray] = None
+                   ) -> Dict[str, jax.Array]:
         """Upload host slot mirrors to device — only when a host-initiated
         change (admission / adopt / error recovery) made them stale. Decode
         rounds advance the device copy in-graph, so steady-state serving
-        never re-uploads."""
+        never re-uploads. ``keep``: the rows of a scan that is unread, whose
+        ``last`` and ``lens`` the mirrors lag behind: those stay the
+        device's (``merge_core``), every other entry is the host's."""
         if self._core_dirty or self._dev_core is None:
             ci, cf = self._pack_core()
-            self._dev_core = self._unpack_core_fn(ci, cf)
+            if keep is not None and self._dev_core is not None:
+                self._dev_core = self._merge_core_fn(
+                    self._dev_core, ci, cf, keep)
+            else:
+                self._dev_core = self._unpack_core_fn(ci, cf)
             self._core_dirty = False
         return self._dev_core
 
@@ -1878,8 +1960,11 @@ class TPUEngine:
         first real round finds it. With a scan length comes
         ``chain_sched``, the small program that schedules a scan
         dispatched behind an unread one (one program for every length),
-        which is also run once here so that it is in memory. Plain
-        (non-speculative) engines; call while no round is in flight."""
+        which is also run once here so that it is in memory; with the
+        round graphs, where scans come too, the two that put a round
+        behind an unread scan: ``merge_core`` and, a packed length,
+        ``chain_round[Tp=...]``, run once as well. Plain (non-speculative)
+        engines; call while no round is in flight."""
         self.collect_scan()
         b = len(self.slots)
         core = self._sync_core()
@@ -1896,15 +1981,29 @@ class TPUEngine:
             out["chain_sched"] = self._chain_sched_fn.lower(core, budgets)
             self._chain_sched_fn(core, budgets)
         most, below = b * max(map(int, ragged_widths), default=0), 0
+        chains = bool(out)      # a round goes out behind a scan, if any
         for tp in self._ragged_ladder():
             if most <= below:       # the rung below takes every such round
                 break
+            tok_d, lens_d = self._round_batch(
+                np.zeros((4, tp), np.int32), np.zeros((2, b), np.int32))
             out[f"ragged_round[Tp={tp}]"] = self._ragged_round_fn.lower(
-                self.params, self.kv, np.zeros((4, tp), np.int32), tables,
-                jnp.zeros((2, b), jnp.int32), core, budgets, "greedy",
-                self._ragged_shape(tp)[1],
+                self.params, self.kv, tok_d, tables, lens_d, core, budgets,
+                "greedy", self._ragged_shape(tp)[1],
             )
+            if chains:
+                patch = (core, np.zeros((4, tp), np.int32),
+                         np.zeros((2, b), np.int32), budgets,
+                         np.zeros((b, 2), np.int32))
+                out[f"chain_round[Tp={tp}]"] = \
+                    self._chain_round_fn.lower(*patch)
+                self._chain_round_fn(*patch)
             below = tp
+        if chains and below:
+            ci, cf = self._pack_core()
+            keep = np.zeros((b,), bool)
+            out["merge_core"] = self._merge_core_fn.lower(core, ci, cf, keep)
+            self._merge_core_fn(core, ci, cf, keep)
         return out
 
     def _scan_kv(self, num_steps: int) -> llama.KVPools:
@@ -2598,7 +2697,7 @@ class TPUEngine:
             part = piece[lo:lo + cap]
             last = is_last and lo + len(part) == len(piece)
             self._count_kda_ragged(None, [len(part)])
-            _tp, operands, round_mode, s_w = self._pack_ragged(
+            _tp, operands, round_mode, s_w, *_ = self._pack_ragged(
                 [], [(slot, off + lo, part, last, mode)])
             try:
                 self.kv, self._dev_core, toks, *_ = self._ragged_round_fn(
@@ -2763,15 +2862,33 @@ class TPUEngine:
         cap; a cap <= 0 skips the admission this round entirely (no row,
         no reservation — it retries next round). Chunked prefill is
         chunk-width-invariant, so any cap schedule yields byte-identical
-        outputs; caps only shape WHEN prefill work lands."""
-        self.collect_scan()
+        outputs; caps only shape WHEN prefill work lands.
+
+        Where a plain scan is unread at entry (``decode_multi(...,
+        ahead=True)`` left it, its admission ran beside it), the round goes
+        out BEHIND it: its decode rows' token, position and liveness are
+        taken on the device from where the scan leaves the core
+        (``chain_round``), the scan is then read and committed, and the
+        call returns THE SCAN'S tokens and leaves the round unread, for
+        ``collect_scan`` (``round_unread``). The build, the dispatch and
+        whatever the caller does with the scan's tokens so run while the
+        device does. The engine decides that from what it holds: the core
+        on the device and room to reserve behind the scan; short of either,
+        and wherever nothing is unread, the call reads what is unread and
+        then its own round, as it always did (a scan's tokens first)."""
+        prev = self._unread
+        if not (isinstance(prev, _UnreadScan) and self._dev_core is not None
+                and self.cfg.speculative is None):
+            prev = None
+        out = {} if prev is not None else self.collect_scan()
         st = self.stats
         st["rounds"] += 1
         with flight.span("dgi.engine.ragged_round", round=st["rounds"],
                          steps=1) as sp:
             if self.cfg.speculative is not None:
                 return self._spec_ragged_round(admissions, chunk_caps, sp)
-            return self._plain_ragged_round(admissions, chunk_caps, sp)
+            return self._merge(out, self._plain_ragged_round(
+                admissions, chunk_caps, sp, prev))
 
     def _ragged_chunk_cap(self) -> int:
         """The most prompt tokens one admission runs in one ragged round."""
@@ -2783,8 +2900,10 @@ class TPUEngine:
         graph each: a quarter and a half of a piece for decode rows beside
         a short piece, one full piece beside every other row decoding, two
         of those, and every row a full piece (the ``[B, S]`` rectangle at
-        its widest). On the v5e a round costs ~21 ms whatever it holds
-        (PERF.md section 5), so finer rungs at the short end buy little."""
+        its widest). On the v5e a round of the 7B dense model costs 17.6 /
+        21.7 / 35.4 ms at the 64 / 128 / 264 rungs (PERF.md section 5, PR
+        28): about what it holds from the third rung on, while the two
+        short rungs sit on a floor, so finer rungs there buy little."""
         b, cap = len(self.slots), self._ragged_chunk_cap()
         one = -(-(cap + b - 1) // 8) * 8
         return sorted({min(max(t, 8), b * cap)
@@ -2856,7 +2975,7 @@ class TPUEngine:
 
     def _ragged_admission_rows(
         self, admissions: Sequence[ChunkedAdmission], chunk_cap: int,
-        chunk_caps: Optional[Dict[int, int]] = None,
+        chunk_caps: Optional[Dict[int, int]] = None, behind: bool = False,
     ) -> Tuple[List[Tuple[ChunkedAdmission, List[int], bool]], int]:
         """Slice each in-flight admission's next chunk row for a ragged
         round, pre-reserving the sampled first token's block for FINAL
@@ -2865,8 +2984,11 @@ class TPUEngine:
         the plain and spec ragged rounds so the retry contract cannot
         drift. ``chunk_caps`` tightens (never widens) the per-admission
         slice — the scheduler's per-round prefill budget; a cap <= 0
-        drops the admission from this round. Returns (ready rows, max
-        chunk width)."""
+        drops the admission from this round. ``behind``: the round is
+        built behind an unread scan, whose read the pool still lacks, so
+        ``OutOfBlocksError`` is raised (nothing kept of the failed
+        reservation, no pressure signalled) for the caller to read the
+        scan first. Returns (ready rows, max chunk width)."""
         ready: List[Tuple[ChunkedAdmission, List[int], bool]] = []
         width = 1
         for adm in admissions:
@@ -2888,21 +3010,27 @@ class TPUEngine:
                             )
                 except OutOfBlocksError:
                     self.manager.trim_reserved(s.seq_id)
+                    if behind:
+                        raise
                     self._signal_pressure("admission", requests=1)
                     continue
-            if not self._extend_window(adm.slot, adm.off + len(piece)):
+            if not self._extend_window(adm.slot, adm.off + len(piece),
+                                       behind):
                 continue    # the window kind is dry: the piece waits
             ready.append((adm, piece, is_last))
             width = max(width, len(piece))
         return ready, width
 
-    def _extend_window(self, slot: int, upto: int) -> bool:
+    def _extend_window(self, slot: int, upto: int,
+                       behind: bool = False) -> bool:
         """Pages per layer kind: the window kind's blocks for the positions
         below ``upto`` that a forward pass is about to write for ``slot``
         (``PagedKVCacheManager.extend_window``; the full kind's came with
         the prompt). False where the window pool cannot give them: pressure
         is signalled and nothing was taken, the step-boundary rule of
-        every other reservation. One kind of pages: True, nothing done."""
+        every other reservation (``behind`` an unread scan it is raised
+        instead: ``_ragged_admission_rows``). One kind of pages: True,
+        nothing done."""
         if not self._window_blocks:
             return True
         s = self.slots[slot]
@@ -2912,6 +3040,8 @@ class TPUEngine:
                 self._block_tables[slot] = self.manager.block_table_for(
                     s.seq_id, self.cfg.max_blocks_per_seq)
         except OutOfBlocksError:
+            if behind:
+                raise
             self._signal_pressure("admission", requests=1)
             return False
         return True
@@ -2960,6 +3090,7 @@ class TPUEngine:
     def _plain_ragged_round(
         self, admissions: Sequence[ChunkedAdmission],
         chunk_caps: Optional[Dict[int, int]], sp: flight.span,
+        prev: Optional[_UnreadScan] = None,
     ) -> Dict[int, List[int]]:
         """ONE device dispatch serving a ragged row batch: every active
         decode slot advances one token AND every in-flight admission
@@ -2976,60 +3107,121 @@ class TPUEngine:
         {slot: [token]} for every row that sampled. Admissions are mutated
         in place; ``adm.done`` flips when the first token lands. ``sp`` is
         the round's open span (``ragged_round``): it gets what the round
-        held, and the four phases nest inside it."""
-        with flight.span("dgi.engine.ragged_round.build", self.stats,
+        held, and the four phases nest inside it.
+
+        ``prev``: the scan that is unread. The round is built and
+        dispatched behind it, ``prev`` is read and committed, what the
+        round held is counted on the mirrors that commit made current, and
+        the call returns ``prev``'s tokens: the readback and commit of the
+        round itself are ``collect_scan``'s (``_collect_ragged``)."""
+        st = self.stats
+        out: Dict[int, List[int]] = {}
+        # the build and the dispatch cost the chip nothing while the scan
+        # still runs on it (a poll)
+        hidden = prev is not None and not prev.emitted.is_ready()
+        t0 = time.perf_counter()
+        with flight.span("dgi.engine.ragged_round.build", st,
                          "round_build_s"):
-            built = self._build_plain_ragged(admissions, chunk_caps, sp)
+            try:
+                built = self._build_plain_ragged(admissions, chunk_caps, prev)
+            except OutOfBlocksError:
+                # no room to reserve the round BEHIND the unread scan: read
+                # it first and hand back what the attempt took, then the
+                # build is the one a round that follows a read makes
+                # (freeze the row, signal the pressure)
+                out = self.collect_scan()
+                for s in self.slots:
+                    if s is not None and not s.prefilling:
+                        self.manager.trim_reserved(s.seq_id)
+                prev, hidden = None, False
+                built = self._build_plain_ragged(admissions, chunk_caps, None)
         if built is None:
-            return {}
-        kept, ready, operands, mode, width = built
-        with flight.span("dgi.engine.ragged_round.dispatch", self.stats,
+            return self._merge(out, self.collect_scan())
+        rnd, operands, mode, width = built
+        sp.set(chained=int(prev is not None))
+        if prev is None:
+            self._count_plain_ragged(sp, rnd, rnd.kept)
+        with flight.span("dgi.engine.ragged_round.dispatch", st,
                          "round_dispatch_s"):
             try:
-                self.kv, self._dev_core, toks, *moe = self._ragged_round_fn(
-                    self.params, self.kv, *operands, mode, width,
-                )
+                self.kv, self._dev_core, rnd.toks, *rnd.moe = \
+                    self._ragged_round_fn(
+                        self.params, self.kv, *operands, mode, width,
+                    )
+            except Exception:
+                self._unread = None
+                self._invalidate_device_state()
+                raise
+        if prev is None:
+            return self._merge(out, self._collect_ragged(rnd, sp))
+        if not hidden:
+            st["round_host_exposed_s"] += time.perf_counter() - t0
+        st["ragged_rounds_chained"] += 1
+        self._unread = rnd
+        # (the scan's own counters go where a scan's span is: a round's
+        # span holds a round's)
+        out = self._collect(prev, None)
+        self._count_plain_ragged(
+            sp, rnd, [i for i in rnd.kept if (s := self.slots[i]) is not None
+                      and s.finish_reason is None])
+        return out
+
+    def _collect_ragged(self, rnd: _UnreadRound, sp: Optional[flight.span]
+                        ) -> Dict[int, List[int]]:
+        """Readback and commit of one dispatched plain ragged round, for
+        the decode rows the device ran and every piece."""
+        st = self.stats
+        with flight.span("dgi.engine.ragged_round.readback", st,
+                         "round_readback_s"):
+            # the wait for the device; the experts' counters come with it
+            try:
+                toks, live, moe = jax.device_get(
+                    (rnd.toks, rnd.live, rnd.moe))
             except Exception:
                 self._invalidate_device_state()
                 raise
-        with flight.span("dgi.engine.ragged_round.readback", self.stats,
-                         "round_readback_s"):
-            # the wait for the device; the experts' counters come with it
-            toks, moe = jax.device_get((toks, moe))
         self._count_moe(sp, "ragged", moe)
-        with flight.span("dgi.engine.ragged_round.commit", self.stats,
+        with flight.span("dgi.engine.ragged_round.commit", st,
                          "round_commit_s"):
-            self.stats["ragged_rounds"] += 1
+            kept = rnd.kept if live is None else \
+                [i for i in rnd.kept if live[i]]
+            st["ragged_rounds"] += 1
             if kept:
-                self.stats["decode_calls"] += 1
-            if ready:
+                st["decode_calls"] += 1
+            if rnd.ready:
                 # ONE device dispatch served every admission row — the
                 # counter means device calls everywhere else (wave
                 # admission asserts one per bucket), so it must not scale
                 # with the row count
-                self.stats["prefill_calls"] += 1
+                st["prefill_calls"] += 1
             out: Dict[int, List[int]] = {}
             for i in kept:
                 self._kv_lens[i] += 1   # the fed token's KV is now committed
                 tok = int(toks[i])
                 out[i] = [tok]
                 self._record_token(i, tok, device_synced=True)
-            self._commit_ragged_admissions(ready, toks, out)
+            self._commit_ragged_admissions(rnd.ready, toks, out)
         return out
 
     def _build_plain_ragged(
         self, admissions: Sequence[ChunkedAdmission],
-        chunk_caps: Optional[Dict[int, int]], sp: flight.span,
-    ) -> Optional[Tuple[List[int], List[Any], Tuple[Any, ...], str, int]]:
+        chunk_caps: Optional[Dict[int, int]],
+        prev: Optional[_UnreadScan] = None,
+    ) -> Optional[Tuple[_UnreadRound, Tuple[Any, ...], str, int]]:
         """The host's half of a plain ragged round before the dispatch:
         block reservation, the packed token batch, pending pool ops, the
-        uploads. None when no row is left to run."""
+        uploads. None when no row is left to run. Behind an unread scan
+        ``prev`` a decode row reserves ``prev``'s steps and its own from
+        the committed length, which lags by ``prev`` (as a chained scan
+        does, ``_build_decode_multi``), and the device says which decode
+        rows run (``chain_round``); a reservation the pool cannot hold
+        raises ``OutOfBlocksError`` there, a piece's too, for the caller to
+        read ``prev`` first."""
         admissions = [a for a in admissions if not a.done]
         for adm in admissions:
             s = self.slots[adm.slot]
             if s is None or s.seq_id != adm.seq_id:
                 raise RuntimeError("ragged admission slot was freed")
-        b = len(self.slots)
         chunk_cap = self._ragged_chunk_cap()
 
         # --- decode rows: pre-reserve each pending token's block exactly
@@ -3040,12 +3232,17 @@ class TPUEngine:
         for i, s in enumerate(self.slots):
             if s is None or s.finish_reason is not None or s.prefilling:
                 continue
-            if len(self.manager.seq_tokens[s.seq_id]) >= self.cfg.max_seq_len:
+            ahead = int(prev.steps[i]) if prev is not None \
+                and prev.active_mask[i] else 0
+            cur = len(self.manager.seq_tokens[s.seq_id])
+            if cur + ahead >= self.cfg.max_seq_len:
                 kept.append(i)      # length-finish triggers in _record_token
                 continue
             try:
-                added = self.manager.reserve_tokens(s.seq_id, 1)
+                added = self.manager.reserve_tokens(s.seq_id, ahead + 1)
             except OutOfBlocksError:
+                if prev is not None:
+                    raise
                 self.manager.trim_reserved(s.seq_id)
                 self._block_tables[i] = self.manager.block_table_for(
                     s.seq_id, self.cfg.max_blocks_per_seq
@@ -3062,15 +3259,26 @@ class TPUEngine:
 
         # --- admission chunk rows: shared slicing + final-chunk
         # pending-block pre-reservation (``_ragged_admission_rows``)
-        ready, _ = self._ragged_admission_rows(admissions, chunk_cap,
-                                               chunk_caps)
+        ready, _ = self._ragged_admission_rows(
+            admissions, chunk_cap, chunk_caps, behind=prev is not None)
         if not kept and not ready:
             return None
 
         self._apply_pending()
-        tp, operands, mode, s_w = self._pack_ragged(
+        tp, operands, mode, s_w, rows, live = self._pack_ragged(
             kept, [(adm.slot, adm.off, piece, is_last, adm.mode)
-                   for adm, piece, is_last in ready])
+                   for adm, piece, is_last in ready], prev)
+        return _UnreadRound(rows, kept, ready, None, live, [], tp=tp), \
+            operands, mode, s_w
+
+    def _count_plain_ragged(self, sp: flight.span, rnd: _UnreadRound,
+                            kept: Sequence[int]) -> None:
+        """What a plain round held, onto its span and into the counters:
+        the decode rows ``kept`` that ran, each at its committed length,
+        and the pieces. Taken where the mirrors are
+        current: before the dispatch, or behind an unread scan once that
+        scan is committed (the rows it ended are then known and out)."""
+        ready, tp = rnd.ready, rnd.tp
         self._count_ragged(sp, tp, tp, len(kept), len(kept), ready)
         if "mla_pairs_ragged" in self.stats:
             # a decode row's token sees its cache and itself; query j of a
@@ -3102,20 +3310,28 @@ class TPUEngine:
         if self._state_rows:
             self._count_kda_ragged(
                 sp, [1] * len(kept) + [len(piece) for _, piece, _ in ready])
-        return kept, ready, operands, mode, s_w
 
     def _pack_ragged(
         self, kept: Sequence[int],
         pieces: Sequence[Tuple[int, int, Sequence[int], bool, str]],
-    ) -> Tuple[int, Tuple[Any, ...], str, int]:
+        prev: Optional[_UnreadScan] = None,
+    ) -> Tuple[int, Tuple[Any, ...], str, int, np.ndarray,
+               Optional[jax.Array]]:
         """The operands of a plain ragged round that holds the decode rows
         ``kept`` (each its pending token) and ``pieces`` (slot, offset,
         tokens, whether the piece samples, its sampling mode): the round's
         live tokens on one axis, row after row, as token id, position, row
         and column in the rectangle attention sees (padding: row b, which
         every scatter drops, at position -1). Returns the packed length,
-        the operands behind ``params`` and ``kv``, the round's mode and the
-        rectangle's width."""
+        the operands behind ``params`` and ``kv``, the round's mode, the
+        rectangle's width, the rows the round holds and, behind ``prev``,
+        the device's word on which decode rows run.
+
+        Behind an unread scan ``prev`` the mirrors lag for its rows: the
+        device core keeps their ``last`` and ``lens`` through an upload
+        (``_sync_core``), and the decode rows' entries are written on the
+        device from that core (``chain_round``), which also says which of
+        them the scan left live."""
         b = len(self.slots)
         tp, s_w = self._ragged_shape(
             len(kept) + sum(len(piece) for _, _, piece, _, _ in pieces))
@@ -3146,10 +3362,33 @@ class TPUEngine:
             sample_flag[sl] = 1 if samples else 0
             if piece_mode != "greedy":
                 mode = "mixed"
-        core = self._sync_core()
+        core = self._sync_core(None if prev is None else prev.active_mask)
         tables, _act, flag_d = self._sched_arrays(row_mask, sample_flag)
-        return tp, (tok_at, tables, jnp.asarray(lens_last), core,
-                    flag_d), mode, s_w
+        if prev is None:
+            tok_d, lens_d = self._round_batch(tok_at, lens_last)
+            return tp, (tok_d, tables, lens_d, core, flag_d), mode, s_w, \
+                row_mask, None
+        # each decode row's place on the packed axis (they come first, in
+        # order) and the committed length at which it runs out of budget,
+        # as ``_build_decode_multi`` gives a chained scan's
+        dec_ends = np.zeros((b, 2), np.int32)
+        dec_ends[:, 0] = -1
+        rows = np.asarray(kept, np.int64)
+        dec_ends[rows, 0] = np.arange(len(rows))
+        dec_ends[rows, 1] = (self._kv_lens + self._host_budgets())[rows]
+        tok_d, lens_d, flag_d, live = self._chain_round_fn(
+            core, tok_at, lens_last, flag_d, dec_ends)
+        return tp, (tok_d, tables, lens_d, core, flag_d), mode, s_w, \
+            row_mask, live
+
+    def _round_batch(self, tok_at: np.ndarray, lens_last: np.ndarray
+                     ) -> Tuple[Any, Any]:
+        """A plain round's packed batch as the round graph takes it from
+        the host: as it is on one device, placed replicated on a mesh
+        (``_place_round_fn``)."""
+        if self._place_round_fn is not None:
+            return self._place_round_fn(tok_at, lens_last)
+        return tok_at, jnp.asarray(lens_last)
 
     def _spec_ragged_round(
         self, admissions: Sequence[ChunkedAdmission],
@@ -3758,8 +3997,15 @@ class TPUEngine:
 
     @property
     def scan_unread(self) -> bool:
-        """A scan's tokens are still on the device: the host mirrors lag."""
+        """A dispatch's tokens are still on the device (a scan's, or those
+        of the round that went out behind one): the host mirrors lag."""
         return self._unread is not None
+
+    @property
+    def round_unread(self) -> bool:
+        """What is unread is a ragged round (``ragged_round`` behind a
+        scan): the next thing the round loop does is read it."""
+        return isinstance(self._unread, _UnreadRound)
 
     def scan_ends_row(self) -> bool:
         """A row of the unread scan reaches its budget inside it: its slot
@@ -3772,25 +4018,34 @@ class TPUEngine:
 
     def collect_scan(self, sp: Optional[flight.span] = None
                      ) -> Dict[int, List[int]]:
-        """THE collect point: read the unread scan back and commit it, so
+        """THE collect point: read what is unread back and commit it, so
         that the host mirrors (``_last_tokens``, ``_kv_lens``, the slots'
-        ``generated`` and ``finish_reason``, the manager's token lists) are
-        current. ``{}`` when nothing is unread. Every entry that reads or
-        writes slots, pool or mirrors calls it first; the round loop calls
-        it when its next round is not a scan over the same rows.
+        ``generated`` and ``finish_reason``, the manager's token lists, and
+        after a round the admissions' offsets) are current. What is unread
+        is a scan (``decode_multi(..., ahead=True)``) or the ragged round
+        that went out behind one (``ragged_round``), never both: the call
+        that dispatched the round read the scan. ``{}`` when nothing is
+        unread. Every entry that reads or writes slots, pool or mirrors
+        calls it first; the round loop calls it when its next round is not
+        a scan over the same rows, and always after a round left unread.
 
         One exception: ``submit_chunked_start`` binds a slot that is free
-        and outside the unread scan's rows without reading it
+        and outside the unread dispatch's rows without reading it
         (``_collect_unless_free``; its docstring says why nothing it
         touches is stale), so an arrival is admitted while the scan before
         its round still runs. The engine decides that from what it holds;
-        a slot inside the scan's rows is read first like everything else."""
-        scan, self._unread = self._unread, None
-        return {} if scan is None else self._collect(scan, sp)
+        a slot inside the dispatch's rows is read first like everything
+        else."""
+        unread, self._unread = self._unread, None
+        if unread is None:
+            return {}
+        if isinstance(unread, _UnreadRound):
+            return self._collect_ragged(unread, sp)
+        return self._collect(unread, sp)
 
     def _collect_unless_free(self, slot: int) -> None:
         """``collect_scan`` unless ``slot`` is free and no row of the unread
-        scan: the exception of ``collect_scan``'s rule."""
+        dispatch: the exception of ``collect_scan``'s rule."""
         prev = self._unread
         if prev is not None and (
                 self.slots[slot] is not None or prev.active_mask[slot]):
@@ -3804,10 +4059,12 @@ class TPUEngine:
         are the previous scan's."""
         st = self.stats
         out: Dict[int, List[int]] = {}
+        is_scan = isinstance(self._unread, _UnreadScan)
         if self._unread is not None and not (
-                ahead and not self._core_dirty
+                ahead and is_scan and not self._core_dirty
                 and self._dev_core is not None):
-            out = self.collect_scan(sp)
+            # (a round left unread is read first: its span is not a scan's)
+            out = self.collect_scan(sp if is_scan else None)
         prev = self._unread
         # the build and the dispatch cost the chip nothing while a scan of
         # ours still runs on it (a poll)

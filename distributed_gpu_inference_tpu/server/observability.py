@@ -90,6 +90,7 @@ class Metrics:
                 "batcher_scan_step_ms", "batcher_round_host_ms",
                 "batcher_scan_reasons", "batcher_scan_row_steps_masked",
                 "batcher_scans_chained", "batcher_chain_breaks",
+                "batcher_ragged_rounds_chained",
                 "batcher_admissions", "batcher_admissions_ahead",
                 "batcher_stream_longest_wait",
                 "batcher_stream_longest_wait_seconds",
@@ -309,8 +310,16 @@ class Metrics:
             "row_end_waiting (a request waits and a row's budget ends in "
             "the scan), row_end (a row was found finished), signal "
             "(cancel, interrupt, deadline, an out-of-band engine call), "
-            "pressure (KV pool), idle (no row has a step left)",
+            "pressure (KV pool), idle (no row has a step left), round "
+            "(what was read was a ragged round that had gone out behind "
+            "its scan)",
             ["worker", "reason"], registry=r)
+        self.batcher_ragged_rounds_chained = Counter(
+            "batcher_ragged_rounds_chained_total",
+            "Ragged rounds dispatched while the scan before was still "
+            "unread on the device (the round's build and dispatch, and the "
+            "delivery of that scan's tokens, ran beside the device's work)",
+            ["worker"], registry=r)
         # ahead / admissions is the share of arrivals whose admission cost
         # the decoding rows nothing
         self.batcher_admissions = Counter(
@@ -929,6 +938,9 @@ class MetricsCollector:
                 metric = self.metrics.batcher_scans.labels(worker, key[7:])
             elif key == "scans_chained":
                 metric = self.metrics.batcher_scans_chained.labels(worker)
+            elif key == "ragged_rounds_chained":
+                metric = self.metrics.batcher_ragged_rounds_chained.labels(
+                    worker)
             elif key == "ragged_admissions":
                 metric = self.metrics.batcher_admissions.labels(worker)
             elif key == "admissions_ahead":

@@ -511,10 +511,12 @@ class Worker:
             # (scans_<reason>), the row-steps they ran past a row's end,
             # the scans dispatched behind an unread one (scans_chained)
             # and why the others were read first (chain_breaks_<reason>);
-            # fresh admissions and those that ran beside an unread scan
+            # fresh admissions and those that ran beside an unread scan,
+            # the ragged rounds that went out behind one
             for k in s:
                 if k in ("between_rounds", "scan_row_steps_masked",
-                         "ragged_admissions", "admissions_ahead") \
+                         "ragged_admissions", "admissions_ahead",
+                         "ragged_rounds_chained") \
                         or k.startswith(("scans_", "chain_breaks_")):
                     out[k] = out.get(k, 0) + int(s[k] or 0)
                 elif k.startswith("longest_wait_s_"):

@@ -222,7 +222,10 @@ def test_mid_chain_events_break_the_chain_within_a_scan(engines, what):
     if what == "arrival":
         assert extra.ok and len(extra.token_ids) == 3
         assert len(resp.token_ids) == 90
-        assert stats["chain_breaks_admission"] >= 1
+        # (its round went out behind the scan that was out, or after its
+        # read)
+        assert stats["chain_breaks_admission"] \
+            + stats["ragged_rounds_chained"] >= 1
         # the scan that was out when it came, and at most the one whose
         # dispatch was under way
         assert seen["admitted_at"] - seen["sent_at"] <= 2
@@ -740,7 +743,8 @@ def test_streams_with_admissions_ahead_are_the_same_bytes(
     assert stats["ragged_admissions"] == base["ragged_admissions"] == 5
     assert base["admissions_ahead"] == 0
     assert 1 <= stats["admissions_ahead"] <= 3
-    assert stats["chain_breaks_admission"] >= stats["admissions_ahead"]
+    assert stats["chain_breaks_admission"] + stats["chain_breaks_round"] \
+        >= stats["admissions_ahead"]
     assert _scans(stats) == stats["scans_chained"] + _breaks(stats)
     assert eng.manager.get_stats()["free_blocks"] == eng.num_blocks - 1
     assert eng.free_slots() == list(range(len(eng.slots)))
@@ -1028,3 +1032,466 @@ def test_admissions_ahead_reach_get_stats_and_the_planes_metrics(engines):
         pytest.skip("prometheus_client is absent: the metrics are no-ops")
     assert 'batcher_admissions_total{worker="w1"} 14.0' in text
     assert 'batcher_admissions_ahead_total{worker="w1"} 9.0' in text
+
+
+# --------------------------------------------------------------------- #
+# (10) a ragged round BEHIND the unread scan (PR 51): the round that
+#      carries an arrival's piece goes out before the scan in flight is
+#      read; the call brings the scan's tokens back and leaves the round's
+#      on the device for the next read
+# --------------------------------------------------------------------- #
+
+# dense, routed, latent (MLA), hybrid (state rows), mixed window and full
+# layers (pages per kind), indexed (learned sparse attention)
+ROUND_MODELS = {
+    "dense": ("llama3-tiny", {}, {}),
+    "routed": ("olmoe-tiny", {}, {}),
+    "latent": ("openpangu-ultra-moe-tiny", {"held_experts": (2, 4)},
+               {"block_size": 16}),
+    "hybrid": ("kimi-linear-tiny", {},
+               {"block_size": 16, "quantization": "int8"}),
+    "mixed-window": ("laguna-tiny", {},
+                     {"block_size": 16, "quantization": "int8"}),
+    "indexed": ("keye-vl-tiny", {},
+                {"block_size": 16, "quantization": "int8"}),
+}
+_round_engines = {}
+
+
+def _round_engine(kind, fresh=False, **kw):
+    """One engine a model kind for the module (a fresh one where a test
+    changes the pool)."""
+    if fresh or kw or kind not in _round_engines:
+        name, model_kw, engine_kw = ROUND_MODELS[kind]
+        cfg = dict(max_batch_size=4, max_seq_len=256, dtype="float32",
+                   prefill_buckets=(16, 32, 64), ragged_chunk=32,
+                   multi_step=4, enable_prefix_cache=False)
+        cfg.update(engine_kw)
+        cfg.update(kw)
+        eng = TPUEngine(
+            get_model_config(name, **(model_kw or {"dtype": "float32"})),
+            EngineConfig(**cfg), seed=0)
+        if fresh or kw:
+            return eng
+        _round_engines[kind] = eng
+    return _round_engines[kind]
+
+
+def _drive(eng, first, later, steps, chained):
+    """A schedule with no clock in it, on the engine itself: ``first`` are
+    admitted at once; scans of ``steps`` go out one behind the other; after
+    the ``k``-th, ``later[k]`` is bound beside it while it is unread and
+    its pieces ride the rounds that follow. ``chained``: each round finds
+    the scan unread (the new order); else the caller reads the scan first
+    (the old one). Returns what there is to compare."""
+    counted = ("ragged_positions_live", "prefill_tokens", "decode_calls",
+               "generated_tokens", "mla_pairs_ragged",
+               "mla_context_tokens_ragged", "attn_pairs_ragged_full",
+               "attn_pairs_ragged_window", "index_pairs_ragged",
+               "index_selected_pairs_ragged", "kda_tokens_ragged",
+               "kda_chunks_ragged", "moe_assignments_ragged",
+               "moe_assignments_scan", "mla_context_tokens_scan",
+               "index_context_tokens_scan", "kda_row_steps_scan")
+    before = {k: eng.stats[k] for k in counted if k in eng.stats}
+    chained0 = eng.stats["ragged_rounds_chained"]
+    free = eng.manager.get_stats()["free_blocks"]
+    order, flying, rounds = [], [], []
+    for r in first:
+        flying.append(eng.submit_chunked_start(r))
+        order.append(flying[-1].slot)
+    scans = 0
+    while True:
+        if flying:
+            was_unread = eng.scan_unread
+            if not chained:
+                eng.collect_scan()
+            back = eng.ragged_round(flying)
+            unread = eng.round_unread
+            assert unread == (chained and was_unread)
+            rounds.append((unread, back, eng.collect_scan()))
+            flying = [a for a in flying if not a.done]
+        elif eng.decode_budgets().any():
+            eng.decode_multi(steps, ahead=True)
+            scans += 1
+            if scans in later:
+                assert eng.scan_unread
+                flying.append(eng.submit_chunked_start(later[scans]))
+                order.append(flying[-1].slot)
+                assert eng.scan_unread      # bound beside it
+        else:
+            eng.collect_scan()
+            break
+    seen = {
+        "kv_lens": [int(eng._kv_lens[i]) for i in order],
+        "managed": [list(eng.manager.seq_tokens[eng.slots[i].seq_id])
+                    for i in order],
+        "counted": {k: eng.stats[k] - v for k, v in before.items()},
+        "chained": eng.stats["ragged_rounds_chained"] - chained0,
+        "rounds": rounds,
+    }
+    done = [eng.finish_slot(i) for i in order]
+    seen["tokens"] = [r.token_ids for r in done]
+    seen["reasons"] = [r.finish_reason for r in done]
+    assert eng.manager.get_stats()["free_blocks"] == free
+    assert not eng.scan_unread
+    return seen
+
+
+def _schedule(temp, stop=(), budgets=(40, 7)):
+    """Two rows decode; an arrival of one piece comes beside the second
+    scan, one of three pieces beside the fourth. ``budgets``: the second
+    row's ends inside the scan its first arrival meets."""
+    first = [_req(PROMPTS[0], budgets[0], temp, seed=21, stop=stop),
+             _req(PROMPTS[1], budgets[1], temp, seed=22, stop=stop)]
+    later = {2: _req(PROMPTS[2], 9, temp, seed=23, stop=stop),
+             4: _req(list(range(100, 170)), 6, temp, seed=24, stop=stop)}
+    return first, later
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("steps", [1, 4])
+@pytest.mark.parametrize("kind", list(ROUND_MODELS))
+def test_a_round_behind_the_unread_scan_is_the_same_bytes(kind, steps, temp):
+    eng = _round_engine(kind)
+    want = _drive(eng, *_schedule(temp), steps, chained=False)
+    got = _drive(eng, *_schedule(temp), steps, chained=True)
+    assert got["tokens"] == want["tokens"]
+    assert got["reasons"] == want["reasons"]
+    assert [len(t) for t in got["tokens"]] == [40, 7, 9, 6]
+    # the mirrors and the manager's lists end where the old order's do
+    assert got["kv_lens"] == want["kv_lens"]
+    assert got["managed"] == want["managed"]
+    # what the rounds and scans held, as the benchmark's readers count it
+    assert got["counted"] == want["counted"]
+    # the first piece of each arrival went out behind a scan, the long
+    # prompt's other two followed their own round's read
+    assert want["chained"] == 0 and got["chained"] == 2
+    assert [r[0] for r in got["rounds"]].count(True) == 2
+    for unread, back, own in got["rounds"]:
+        if unread:
+            # the call brought the scan's tokens, the read the round's: a
+            # step a decoding row, nothing for a piece that is not the last
+            assert back and all(1 <= len(t) <= steps for t in back.values())
+            assert all(len(t) == 1 for t in own.values())
+
+
+@pytest.mark.parametrize("end", ["stop", "budget"])
+@pytest.mark.parametrize("kind", ["dense", "routed", "hybrid"])
+def test_a_row_that_ends_inside_the_unread_scan_is_not_advanced_by_the_round(
+        kind, end):
+    """The round is built on mirrors that still show the row decoding; the
+    device finds it ended (its budget, or a stop id as its last token) and
+    leaves it out: no token, no core advance, no KV written past its end."""
+    eng = _round_engine(kind)
+    stop, budgets = (), (40, 7)
+    if end == "stop":
+        ref = _drive(eng, *_schedule(0.0, budgets=(40, 30)), 4,
+                     chained=False)
+        # the second row's sixth token, which scan 2 emits at its first
+        # step (token 0 came with the prompt, scan 1 brought 1-4)
+        stop, budgets = (ref["tokens"][1][5],), (40, 30)
+        assert stop[0] not in ref["tokens"][1][:5]
+    want = _drive(eng, *_schedule(0.0, stop, budgets), 4, chained=False)
+    got = _drive(eng, *_schedule(0.0, stop, budgets), 4, chained=True)
+    assert got["tokens"] == want["tokens"]
+    assert got["reasons"] == want["reasons"]
+    assert got["reasons"][1] == ("stop" if end == "stop" else "length")
+    assert got["kv_lens"] == want["kv_lens"]
+    assert got["managed"] == want["managed"]
+    assert got["counted"] == want["counted"]
+    # the first chained round met the row ended inside scan 2: the scan's
+    # tokens hold its last, the round's read nothing of it
+    unread, back, own = next(r for r in got["rounds"] if r[0])
+    assert 1 in back and 1 not in own and 0 in own
+
+
+def test_a_round_behind_a_scan_runs_the_graph_the_warm_up_lowered():
+    """The operands a chained round hands ``ragged_round`` come from
+    ``chain_round``; the round graph is the one every other round runs
+    (no second program a packed length), and the small programs are in
+    memory once ``lower_serving_graphs`` has run."""
+    eng = _round_engine("dense", fresh=True)
+    graphs = eng.lower_serving_graphs([1, 4], [16, 32])
+    rungs = [k for k in graphs if k.startswith("ragged_round[")]
+    assert rungs and "merge_core" in graphs
+    assert [k for k in graphs if k.startswith("chain_round[")] == [
+        k.replace("ragged_round", "chain_round") for k in rungs]
+    assert eng._chain_round_fn._cache_size() == len(rungs)
+    assert eng._merge_core_fn._cache_size() == 1
+    for name in graphs:
+        if name.startswith(("chain_round", "merge_core", "chain_sched")):
+            assert "kernel_name" not in graphs[name].as_text()
+    from distributed_gpu_inference_tpu.utils.device import compile_log
+
+    for lowered in graphs.values():
+        lowered.compile()               # as the worker's warm-up does
+    plain = _round_engine("dense", fresh=True)
+    want = _drive(plain, *_schedule(0.0), 4, chained=False)
+    log = compile_log()
+    mark = len(log.rows)
+    got = _drive(eng, *_schedule(0.0), 4, chained=True)
+    assert got["tokens"] == want["tokens"] and got["chained"] == 2
+    # nothing was traced or compiled for the chained rounds: not the small
+    # programs, not a round graph a second time for their operands
+    assert eng._chain_round_fn._cache_size() == len(rungs)
+    assert eng._merge_core_fn._cache_size() == 1
+    assert not [r["fn"] for r in log.rows[mark:]
+                if "round" in r["fn"] or "decode_multi" in r["fn"]
+                or "merge_core" in r["fn"]]
+    # without a scan length nothing chains: the round graphs alone
+    assert list(plain.lower_serving_graphs([], [16])) == [
+        k for k in plain.lower_serving_graphs([4], [16])
+        if k.startswith("ragged_round[")]
+
+
+def test_out_of_blocks_behind_the_scan_reads_first_and_signals_as_before():
+    # 16 tokens a block; two rows of 15-token prompts fill a block each
+    # with their pending token, two 16-step scans take each to the end of
+    # its third, and the pool (pad + 8) has two blocks left: the arrival
+    # takes one, and behind the second scan the round's two decode rows
+    # need a fourth block each
+    def make():
+        return _engine("llama3-tiny", max_batch_size=3, num_blocks=9,
+                       max_seq_len=64, multi_step=16)
+
+    def run(eng, chained):
+        a = eng.submit(_req(range(3, 18), 40))
+        b = eng.submit(_req(range(50, 65), 40))
+        assert eng.decode_multi(16, ahead=True) == {}
+        assert eng.decode_multi(16, ahead=True)     # reads scan 1
+        adm = eng.submit_chunked_start(_req(PROMPTS[2], 3))
+        assert eng.scan_unread and eng.take_pressure() is None
+        if not chained:
+            eng.collect_scan()
+        out = eng.ragged_round([adm])
+        # no room behind the scan: it was read first, the round read
+        # itself, one decode row froze and the pressure is the pool's word
+        assert not eng.scan_unread
+        pressure = eng.take_pressure()
+        assert pressure is not None and pressure.source == "decode"
+        frozen = pressure.slots
+        assert adm.done and len(out[adm.slot]) == 1
+        for i in (a, b):
+            assert (i in frozen) == (len(out.get(i, [])) in (0, 16))
+        state = {i: (list(eng.slots[i].generated), int(eng._kv_lens[i]))
+                 for i in (a, b, adm.slot)}
+        for i in (a, b, adm.slot):
+            eng.finish_slot(i)
+        return state, len(frozen), eng.stats["ragged_rounds_chained"]
+
+    free = make().manager.get_stats()["free_blocks"]
+    eng = make()
+    want, want_frozen, _ = run(make(), chained=False)
+    got, got_frozen, n = run(eng, chained=True)
+    assert got == want and got_frozen == want_frozen >= 1 and n == 0
+    assert eng.manager.get_stats()["free_blocks"] == free
+
+
+def test_under_a_mesh_a_round_behind_a_scan_compiles_nothing_anew(
+        cpu_devices):
+    """``chain_round`` and ``merge_core`` hand their values back placed as
+    the round graph takes the host's (replicated): the round graphs and
+    the uploads compile no more often than in an engine that reads every
+    scan first, and the tokens are the same."""
+    from jax.sharding import Mesh
+
+    def make():
+        return TPUEngine(
+            get_model_config("llama3-tiny", dtype="float32"),
+            EngineConfig(max_batch_size=4, max_seq_len=256, dtype="float32",
+                         prefill_buckets=(16, 32, 64), ragged_chunk=32,
+                         multi_step=4, enable_prefix_cache=False),
+            mesh=Mesh(np.array(cpu_devices[:2]), ("model",)), seed=0)
+
+    from distributed_gpu_inference_tpu.utils.device import compile_log
+
+    # (no row ends inside a scan an arrival meets: a round built on the
+    # lagging mirrors may take a longer rung than one built after the read)
+    plan = lambda: _schedule(0.0, budgets=(40, 30))  # noqa: E731
+    plain, eng = make(), make()
+    want = _drive(plain, *plan(), 4, chained=False)
+    # the engine's own rounds in the old order first, so that every round
+    # graph the schedule reaches is compiled; then the same in the new one
+    assert _drive(eng, *plan(), 4, chained=False)["tokens"] \
+        == want["tokens"]
+    sizes = {fn: getattr(eng, fn)._cache_size()
+             for fn in ("_ragged_round_fn", "_decode_multi_fn",
+                        "_unpack_sched_fn", "_unpack_core_fn")}
+    log = compile_log()
+    mark = len(log.rows)
+    got = _drive(eng, *plan(), 4, chained=True)
+    assert got["tokens"] == want["tokens"] and got["chained"] == 2
+    assert sorted({r["fn"] for r in log.rows[mark:]}) == [
+        "jit(chain_round)", "jit(merge_core)"]
+    for fn, size in sizes.items():
+        assert getattr(eng, fn)._cache_size() == size, fn
+
+
+# the loop's side: it asks for the round behind the scan and delivers the
+# scan's tokens while the device runs the round
+
+def _read_first(engine):
+    """The old order on an engine instance: every ``ragged_round`` finds
+    the scan read (the control of the loop's tests)."""
+    real = engine.ragged_round
+
+    def ragged_round(admissions=(), chunk_caps=None):
+        engine.collect_scan()
+        return real(admissions, chunk_caps)
+
+    engine.ragged_round = ragged_round
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8], ids=["greedy", "seeded"])
+@pytest.mark.parametrize("steps", [1, 4])
+@pytest.mark.parametrize("model", ["dense", "olmoe"])
+def test_streams_with_rounds_behind_the_scan_are_the_same_bytes(
+        engines, model, steps, temp):
+    eng = engines[model]
+    _read_first(eng)
+    try:
+        want, base, _ = _serve_arriving(eng, _long(temp), _arrivals(temp),
+                                        steps)
+    finally:
+        del eng.ragged_round
+    got, stats, _ = _serve_arriving(eng, _long(temp), _arrivals(temp), steps)
+    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+    assert [r.finish_reason for r in got] == [r.finish_reason for r in want]
+    assert [len(r.token_ids) for r in got] == [100, 80, 7, 5, 9]
+    # the control's engine chained no round, whatever the loop asked for
+    assert base["ragged_rounds_chained"] == 0 == base["chain_breaks_round"]
+    # each arrival met a scan in flight: its first piece went out behind it
+    # (the 35-token prompt's second piece followed its own round's read),
+    # and every such round was read next, once
+    assert 1 <= stats["ragged_rounds_chained"] <= 3
+    assert stats["chain_breaks_round"] == stats["ragged_rounds_chained"]
+    assert stats["ragged_rounds_chained"] <= stats["admissions_ahead"]
+    assert stats["ragged_rounds"] == base["ragged_rounds"]
+    assert _scans(stats) == stats["scans_chained"] + _breaks(stats)
+    assert eng.manager.get_stats()["free_blocks"] == eng.num_blocks - 1
+    assert eng.free_slots() == list(range(len(eng.slots)))
+
+
+def test_the_scans_tokens_carry_the_scans_stamp_and_the_rounds_the_rounds(
+        engines):
+    """A stream that decodes across an arrival: the call that dispatches
+    the round brings the scan's tokens (stamp ``scan``), the read that
+    follows brings the round's (``ragged``, one piece, ``ragged_1``), both
+    under the round's number; the arrival's first token comes with the
+    round's read. ``batcher.longest_wait_piece_share`` reads these."""
+    eng = engines["dense"]
+    seen = {"long": [], "new": []}
+
+    def watch(who):
+        def observer(snap):
+            seen[who].append((len(snap), snap.round))
+        return observer
+
+    async def go():
+        b = ContinuousBatcher(eng, BatcherConfig(
+            max_wait_ms=1, adaptive=False, multi_step=1, max_multi_step=4))
+        b.start()
+        long = asyncio.ensure_future(
+            b.submit(_req(PROMPTS[0], 60), observer=watch("long")))
+        await _mid_chain(b)
+        new = await b.submit(_req(PROMPTS[2], 4), observer=watch("new"))
+        resp = await long
+        stats = b.get_stats()
+        await b.stop()
+        return resp, new, stats
+
+    resp, new, stats = asyncio.run(go())
+    assert len(resp.token_ids) == 60 and len(new.token_ids) == 4
+    assert stats["ragged_rounds_chained"] == 1 == stats["chain_breaks_round"]
+    stamps = [st for _, st in seen["long"]]
+    at = next(i for i, st in enumerate(stamps) if st.kind == "ragged" and i)
+    scan, ragged = stamps[at - 1], stamps[at]
+    assert (scan.kind, scan.steps, scan.pieces, scan.cause) \
+        == ("scan", 1, 0, "scan")
+    assert (ragged.kind, ragged.steps, ragged.pieces, ragged.cause) \
+        == ("ragged", 1, 1, "ragged_1")
+    assert scan.round == ragged.round and scan.ready < ragged.ready
+    # each brought the stream one token: the scan's, then the round's
+    assert seen["long"][at][0] - seen["long"][at - 1][0] == 1
+    assert seen["long"][at - 1][0] - seen["long"][at - 2][0] == 1
+    # the arrival's first token came with the round's read
+    assert seen["new"][0][0] == 1 and seen["new"][0][1] == ragged
+
+
+def test_an_arrival_during_an_unread_round_is_bound_beside_it(engines):
+    """``_collect_unless_free``'s rule holds for a round left unread as for
+    a scan: a slot that is free and no row of the dispatch is bound without
+    reading it; the round is still read next, and the arrival's own round
+    follows that read."""
+    eng = engines["dense"]
+    late = {}
+
+    async def go():
+        b = ContinuousBatcher(eng, BatcherConfig(
+            max_wait_ms=1, adaptive=False, multi_step=1, max_multi_step=4))
+        deliver = b._deliver
+
+        async def deliver_then_arrive(measured):
+            await deliver(measured)
+            if b._unread_round is not None and "work" not in late:
+                # the round's call has returned, its read has not begun
+                late["at"] = dict(b.stats)
+                late["work"] = asyncio.ensure_future(
+                    b.submit(_req(list(range(20, 31)), 5)))
+                await asyncio.sleep(0)      # it is in the queue now
+                assert eng.round_unread and len(b._heap) == 1
+        b._deliver = deliver_then_arrive
+        real_start = eng.submit_chunked_start
+
+        def start(request, slot=None):
+            beside = eng.round_unread
+            adm = real_start(request, slot)
+            late.setdefault("beside", []).append(beside and eng.round_unread)
+            return adm
+        eng.submit_chunked_start = start
+        b.start()
+        long = asyncio.ensure_future(b.submit(_req(PROMPTS[0], 60)))
+        await _mid_chain(b)
+        new = await b.submit(_req(PROMPTS[2], 4))
+        out = [await long, new, await late["work"]]
+        stats = b.get_stats()
+        await b.stop()
+        return out, stats
+
+    try:
+        out, stats = asyncio.run(go())
+    finally:
+        del eng.submit_chunked_start
+    assert [len(r.token_ids) for r in out] == [60, 4, 5]
+    # the third request was bound while the round was unread, and left it so
+    assert late["beside"][-1] is True
+    assert stats["admissions_ahead"] == late["at"]["admissions_ahead"] + 1
+    # its own round followed the read: one round went out behind a scan
+    assert stats["ragged_rounds_chained"] == 1 == stats["chain_breaks_round"]
+    assert stats["ragged_admissions"] == 3
+    assert eng.free_slots() == list(range(len(eng.slots)))
+
+
+def test_a_caller_waiting_for_the_thread_keeps_the_round_read_first(engines):
+    """``BatcherServing.run_exclusive`` counts on nothing being unread
+    while a caller waits (``_foreign``): the round's call reads the scan
+    first and then itself."""
+    eng = engines["dense"]
+    tasks = []
+
+    def before(b, i):
+        async def release():
+            while b.stats["ragged_admissions"] < 3:
+                await asyncio.sleep(0.0005)
+            while b._ragged:
+                await asyncio.sleep(0.0005)
+            b._foreign -= 1
+        b._foreign += 1
+        tasks.append(asyncio.ensure_future(release()))
+
+    got, stats, _ = _serve_arriving(
+        eng, [_req(PROMPTS[0], 60), _req(PROMPTS[1], 50)],
+        [_req(PROMPTS[2], 6)], before=before)
+    assert [len(r.token_ids) for r in got] == [60, 50, 6]
+    assert stats["ragged_rounds_chained"] == 0 == stats["chain_breaks_round"]
+    assert not eng.scan_unread

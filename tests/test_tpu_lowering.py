@@ -546,7 +546,7 @@ def test_mesh_engine_traces_no_pallas_kernel(tpu_dispatch, cpu_devices):
     # what chip_smoke.py reads the implementations from
     graphs = eng.lower_serving_graphs([4], [16])
     assert set(graphs) == {"decode_multi[T=4]", "ragged_round[Tp=64]",
-                           "chain_sched"}
+                           "chain_sched", "chain_round[Tp=64]", "merge_core"}
     for lowered in graphs.values():
         assert _kernels(lowered) == set()
     out = eng.generate([
@@ -560,6 +560,62 @@ def test_mesh_engine_traces_no_pallas_kernel(tpu_dispatch, cpu_devices):
     # slot state carried from the last round have the same (replicated)
     # sharding, so the round graph does not compile a second time
     assert eng._decode_multi_fn._cache_size() == 1
+
+
+def test_a_round_behind_a_scan_takes_the_round_graphs_as_they_are():
+    """PR 51: a ragged round may go out behind an unread scan. What that
+    takes is BESIDE the nine round graphs (four scan lengths, five packed
+    lengths at the chat cells' geometry), not in them: the small programs
+    ``chain_round[Tp]`` (one a packed length) and ``merge_core``, which
+    ``lower_serving_graphs`` lowers and runs and which hold no Pallas call.
+    A round graph lowered from the operands a chained round hands it (the
+    packed batch, the flags and the core as those programs return them) is
+    the text the warm-up lowers from the host's, letter for letter, so the
+    program a chained round runs is the one every other round runs. (The
+    nine texts of this geometry were compared with the parent commit's when
+    this was written, dense and routed: identical.)"""
+    from distributed_gpu_inference_tpu.runtime.engine import (
+        EngineConfig,
+        TPUEngine,
+    )
+
+    eng = TPUEngine(
+        get_model_config("llama3-tiny", dtype="float32"),
+        EngineConfig(max_batch_size=8, max_seq_len=512, dtype="float32",
+                     prefill_buckets=(16, 32, 64, 128, 256),
+                     ragged_chunk=256, multi_step=4,
+                     enable_prefix_cache=False), seed=0)
+    graphs = eng.lower_serving_graphs([1, 4, 16, 64], [16, 32, 64, 128, 256])
+    rounds = [k for k in graphs
+              if k.startswith(("decode_multi[", "ragged_round["))]
+    rungs = [int(k[len("ragged_round[Tp="):-1]) for k in rounds
+             if k.startswith("ragged_round[")]
+    assert len(rounds) == 9 and rungs == [64, 128, 264, 528, 2048]
+    small = sorted(set(graphs) - set(rounds))
+    assert small == sorted(["chain_sched", "merge_core"]
+                           + [f"chain_round[Tp={tp}]" for tp in rungs])
+    for name in small:
+        assert _kernels(graphs[name]) == set(), name
+        # a few hundred scalar-sized operations, nothing of the model
+        assert len(graphs[name].as_text()) < 20_000, name
+    # run once each, so that none compiles inside a request
+    assert eng._chain_round_fn._cache_size() == len(rungs)
+    assert eng._merge_core_fn._cache_size() == 1
+    assert eng._chain_sched_fn._cache_size() == 1
+    b = len(eng.slots)
+    core = eng._sync_core()
+    ci, cf = eng._pack_core()
+    tables, _, flag = eng._sched_arrays(np.zeros((b,), bool),
+                                        np.zeros((b,), np.int32))
+    merged = eng._merge_core_fn(core, ci, cf, np.zeros((b,), bool))
+    for tp in rungs:
+        tok_at, lens_last, flag_d, _live = eng._chain_round_fn(
+            merged, np.zeros((4, tp), np.int32), np.zeros((2, b), np.int32),
+            flag, np.zeros((b, 2), np.int32))
+        chained = eng._ragged_round_fn.lower(
+            eng.params, eng.kv, tok_at, tables, lens_last, merged, flag_d,
+            "greedy", eng._ragged_shape(tp)[1])
+        assert chained.as_text() == graphs[f"ragged_round[Tp={tp}]"].as_text()
 
 
 # --------------------------------------------------------------------- #
