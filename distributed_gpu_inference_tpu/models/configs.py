@@ -53,12 +53,28 @@ class ModelConfig:
     index_topk: int = 0
     index_num_heads: int = 0
     index_head_dim: int = 0
+    # the indexer layer by layer (a latent-attention model's: models/mla.py):
+    # ``"full"`` = the layer has an indexer and computes its queries'
+    # selection, ``"shared"`` = it has none and attends the selection of the
+    # last full layer before it. Empty: every layer full.
+    index_types: Tuple[str, ...] = ()
+    # what the index queries are projected from: the layer's normed input
+    # (``"hidden"``) or its query latent ``c_q`` (``"q_latent"``: needs
+    # ``q_lora_rank``); index keys and head weights read the normed input
+    index_query_input: str = "hidden"
+    # values of an index head (query and key) that are rotated, its first;
+    # 0: all ``index_head_dim``
+    index_rope_dims: int = 0
+    # a latent layer's rope values (and its indexer's) rotate by adjacent
+    # pairs (2i, 2i + 1), not by halves (i, i + d/2)
+    rope_interleave: bool = False
     # Latent attention (MLA): the cache holds one ``kv_lora_rank`` latent and
     # ``qk_rope_head_dim`` rope values a token a layer instead of per-head K
     # and V (models/mla.py). 0 = the K/V layout. No layer of such a model
     # reads ``head_dim`` (a query/key head is ``qk_head_dim``, nope + rope,
-    # its default here): it may carry that, or the ``hidden_size //
-    # num_heads`` a published config states, and nothing else.
+    # its default here): it may carry that, or the value a published config
+    # states (``hidden_size // num_heads``, or ``qk_nope_head_dim``), and
+    # nothing else.
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -147,16 +163,12 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: an indexer needs index_topk, "
                     "index_num_heads and an even index_head_dim")
-            if self.kv_lora_rank:
-                raise ValueError(
-                    f"{self.name}: an indexer over latent pages "
-                    "(kv_lora_rank) is not built: the index-key pool lies "
-                    "beside K/V pages (models/llama.py)")
             if self.sliding_window is not None:
                 raise ValueError(
                     f"{self.name}: an indexer with sliding_window is not "
                     "built: pages that left the window are released, the "
                     "selection reads every cached token")
+        self._check_index_layers()
         if self.router_scoring is None:
             object.__setattr__(
                 self, "router_scoring",
@@ -202,13 +214,15 @@ class ModelConfig:
                     "held_experts or routed_scaling_factor without "
                     "num_experts: no expert layer would read them")
         elif self.head_dim not in (self.qk_head_dim,
-                                   self.hidden_size // self.num_heads):
+                                   self.hidden_size // self.num_heads,
+                                   self.qk_nope_head_dim):
             raise ValueError(
                 f"{self.name}: head_dim {self.head_dim} on a latent-attention "
                 f"model, whose query/key head is {self.qk_head_dim} wide "
                 "(qk_nope_head_dim + qk_rope_head_dim): no layer reads it, "
-                "so it carries that or the published hidden_size // "
-                "num_heads")
+                "so it carries that, or the value a published config states "
+                "under the key: hidden_size // num_heads, or "
+                "qk_nope_head_dim")
         kda = (self.kda_num_heads, self.kda_head_dim, self.kda_conv_kernel)
         if self.full_attn_layers:
             if not all(kda) or self.kda_conv_kernel < 2:
@@ -240,6 +254,66 @@ class ModelConfig:
                     f"held_experts {self.held_experts} outside the "
                     f"{self.num_experts} routed experts"
                 )
+
+    def _check_index_layers(self) -> None:
+        """What the indexer's description must state, and which of its
+        fields only the latent-attention model reads: refused here, when
+        the configuration is made, each with its reason."""
+        latent_only = [name for name, default in (
+            ("index_types", ()), ("index_query_input", "hidden"),
+            ("index_rope_dims", 0), ("rope_interleave", False),
+        ) if getattr(self, name) != default]
+        if latent_only and not self.kv_lora_rank:
+            raise ValueError(
+                f"{self.name}: {', '.join(latent_only)} without "
+                "kv_lora_rank: only the latent-attention model "
+                "(models/mla.py) reads them")
+        if not self.kv_lora_rank:
+            return
+        kv_only = [name for name in (
+            "qk_norm", "qk_norm_per_head", "attention_bias")
+            if getattr(self, name)]
+        if kv_only:
+            raise ValueError(
+                f"{self.name}: {', '.join(kv_only)} over latent pages "
+                "(kv_lora_rank): the K/V recipe's (models/llama.py), which "
+                "no latent layer reads")
+        indexed = [name for name in (
+            "index_types", "index_rope_dims") if getattr(self, name)] + (
+            ["index_query_input"] if self.index_query_input != "hidden"
+            else [])
+        if indexed and not self.index_topk:
+            raise ValueError(
+                f"{self.name}: {', '.join(indexed)} without index_topk: no "
+                "indexer would read them")
+        if not self.index_topk:
+            return
+        if self.full_attn_layers:
+            raise ValueError(
+                f"{self.name}: an indexer on a hybrid of linear and latent "
+                "attention (full_attn_layers) is not built: the selection "
+                "is carried from latent layer to latent layer")
+        if self.index_query_input not in ("hidden", "q_latent") or (
+                self.index_query_input == "q_latent"
+                and not self.q_lora_rank):
+            raise ValueError(
+                f"{self.name}: index_query_input "
+                f"{self.index_query_input!r}; use 'hidden', or 'q_latent' "
+                "with q_lora_rank")
+        if self.index_rope_dims % 2 or \
+                self.index_rope_dims > self.index_head_dim:
+            raise ValueError(
+                f"{self.name}: index_rope_dims {self.index_rope_dims} is not "
+                f"an even part of index_head_dim {self.index_head_dim}")
+        kinds = self.index_types
+        if kinds and (len(kinds) != self.num_layers
+                      or set(kinds) - {"full", "shared"}
+                      or kinds[0] != "full"):
+            raise ValueError(
+                f"{self.name}: index_types names 'full' or 'shared' for "
+                f"each of the {self.num_layers} layers, the first of them "
+                "'full' (a shared layer borrows the selection of the full "
+                "layer before it)")
 
     def _check_layer_types(self) -> None:
         """What a per-layer description of attention must state, and what a
@@ -355,6 +429,19 @@ class ModelConfig:
             or self.held_experts is not None or self.head_gate)
 
     @property
+    def index_kinds(self) -> Tuple[str, ...]:
+        """``"full"`` or ``"shared"`` for each layer of a model with an
+        indexer (empty without one)."""
+        if not self.index_topk:
+            return ()
+        return tuple(self.index_types) or ("full",) * self.num_layers
+
+    @property
+    def num_index_layers(self) -> int:
+        """Layers that hold an indexer: the index-key pool's layer axis."""
+        return self.index_kinds.count("full")
+
+    @property
     def layer_kinds(self) -> Tuple[str, ...]:
         """``"kda"`` or ``"mla"`` for each layer of a latent-attention
         model, in layer order."""
@@ -446,16 +533,19 @@ class ModelConfig:
         if not self.index_topk:
             return 0
         di = self.index_head_dim
-        return self.hidden_size * (
-            self.index_num_heads * di + di + self.index_num_heads) + 2 * di
+        q_in = self.q_lora_rank if self.index_query_input == "q_latent" \
+            else self.hidden_size
+        return q_in * self.index_num_heads * di + self.hidden_size * (
+            di + self.index_num_heads) + 2 * di
 
     def param_bytes(self, dtype_bytes: int = 2) -> int:
         return self.num_params * dtype_bytes
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
         if self.latent_kv:
-            return self.num_cache_layers * (
-                self.kv_lora_rank + self.qk_rope_head_dim) * dtype_bytes
+            return (self.num_cache_layers * (
+                self.kv_lora_rank + self.qk_rope_head_dim)
+                + self.num_index_layers * self.index_head_dim) * dtype_bytes
         return self.num_layers * (
             2 * self.num_kv_heads * self.head_dim
             + (self.index_head_dim if self.index_topk else 0)) * dtype_bytes
@@ -499,6 +589,8 @@ class ModelConfig:
             )
             norms = (4 if self.sandwich_norm else 2) * h \
                 + self.q_lora_rank + self.kv_lora_rank
+            if self.index_kinds and self.index_kinds[layer] == "full":
+                attn += self.index_params
         if layer < self.first_k_dense or not self.num_experts:
             mlp = 3 * h * self.intermediate_size
         else:
@@ -752,6 +844,52 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
         num_experts_per_tok=8, norm_topk_prob=True,
         routed_scaling_factor=2.5,
         held_experts=(0, 16), sandwich_norm=True,
+    ),
+    # GLM-5.2 -- latent attention (MLA, rope by adjacent pairs) under a
+    # lightning indexer that keeps ``index_topk`` cached tokens a query: a
+    # ``full`` layer projects its index queries from the query latent and
+    # computes the selection, the ``shared`` layers behind it (three in
+    # four) attend the same selection and hold no indexer (IndexShare);
+    # the index keys lie in a pool beside the latent pages, a layer a full
+    # layer. Leading dense layers, then sigmoid-routed experts with a
+    # selection bias beside a shared one (models/mla.py,
+    # ops/index_select.py). ``head_dim`` carries the published 192
+    # (qk_nope_head_dim), which no layer reads. One multi-token-prediction
+    # layer is published and not loaded.
+    "glm-5.2-tiny": _llama(  # test-scale: a dense full layer, two periods
+        "glm-5.2-tiny", vocab_size=512, hidden_size=64, num_layers=9,
+        num_heads=4, num_kv_heads=4, intermediate_size=96, head_dim=16,
+        max_position_embeddings=1024, rope_theta=10000.0,
+        kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=24, first_k_dense=1,
+        moe_intermediate_size=32, n_shared_experts=1, num_experts=8,
+        num_experts_per_tok=3, norm_topk_prob=True,
+        routed_scaling_factor=2.5, held_experts=(0, 2),
+        router_selection_bias=True, rope_interleave=True,
+        index_topk=8, index_num_heads=2, index_head_dim=16,
+        index_types=("full",) + ("shared", "shared", "shared", "full") * 2,
+        index_query_input="q_latent", index_rope_dims=8,
+    ),
+    # one chip's share of the published model where 16 chips share each
+    # layer: published layers 2-10 (the last leading dense layer, indexer
+    # full, and two whole periods shared, shared, shared, full of expert
+    # layers), 16 of the 256 routed experts, an eighth of the vocabulary;
+    # every width as published
+    # (benchmark/configs/glm-5.2-ep16-9l-int8.json)
+    "glm-5.2-ep16-9l": _llama(
+        "glm-5.2-ep16-9l", vocab_size=19360, hidden_size=6144,
+        num_layers=9, num_heads=64, num_kv_heads=64,
+        intermediate_size=12288, head_dim=192,
+        max_position_embeddings=24576, rope_theta=8000000.0,
+        rms_norm_eps=1e-5, kv_lora_rank=512, q_lora_rank=2048,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        first_k_dense=1, moe_intermediate_size=2048, n_shared_experts=1,
+        num_experts=256, num_experts_per_tok=8, norm_topk_prob=True,
+        routed_scaling_factor=2.5, held_experts=(0, 16),
+        router_selection_bias=True, rope_interleave=True,
+        index_topk=2048, index_num_heads=32, index_head_dim=128,
+        index_types=("full",) + ("shared", "shared", "shared", "full") * 2,
+        index_query_input="q_latent", index_rope_dims=64,
     ),
     # Kimi-Linear — three gated delta-rule (KDA) layers to one latent (MLA)
     # layer that rotates nothing, no query low-rank, a dense first layer,

@@ -866,8 +866,10 @@ def _index_plan(
     rope_positions: jax.Array,   # [B, S], or [1, Tp] of a packed chunk
     packing: Optional["Packing"], block_size: int,
 ) -> _IndexPlan:
+    # (a latent model's indexer may rotate part of a head: models/mla.py)
     cos, sin = _rope_angles(jnp.maximum(rope_positions, 0),
-                            cfg.index_head_dim, cfg.rope_theta)
+                            cfg.index_rope_dims or cfg.index_head_dim,
+                            cfg.rope_theta)
     if packing is None:
         scatter = _page_scatter_indices(
             num_blocks, block_tables, positions, block_size)

@@ -33,6 +33,16 @@ batcher and the cache manager serve this model as they serve the others):
   same ``kv`` dict. The latent pool then has a layer for each latent layer
   only, and the scans run over ``layer_units``: one period of the pattern,
   repeated, and the odd ends.
+- **A model with an indexer** (``cfg.index_topk``: learned sparse
+  attention, ``ops/index_select.py``) attends a selection ``S_t`` of each
+  query's cached tokens. A ``full`` layer (``cfg.index_kinds``) holds the
+  indexer (stacks ``ix_dense_layers`` / ``ix_layers``), writes its index
+  keys into the pool ``"ki"`` (a layer a full layer, addressed by the
+  latent pages' block table), computes ``keep`` and hands it on in the
+  layer scan's carry; a ``shared`` layer holds none and attends the carry
+  (IndexShare). On the kernel path a scan step's carry also holds the
+  row's selected pages laid out for the decode kernel, built once a full
+  layer and walked again by the shared layers behind it.
 - **The expert layer computes the chip's share**: sigmoid scores over all
   published experts, top-k kept, normalised and scaled as published; pairs that fall on experts held elsewhere are routed nowhere,
   and nothing stands in for them.
@@ -50,6 +60,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from distributed_gpu_inference_tpu.models.configs import ModelConfig
+from distributed_gpu_inference_tpu.models.llama import (
+    INDEX_KEYS,         # the index-key pool's name in the ``kv`` dict
+    INDEX_SCAN_KEYS,    # a scan's index keys in context order
+)
 from distributed_gpu_inference_tpu.ops.quantization import (
     matmul as qmm,
     matmul_stacked,
@@ -83,23 +97,30 @@ def pool_width(cfg: ModelConfig) -> int:
 
 
 _KDA = "kda_"
+_IX = "ix_"
 
 
 def group_of(cfg: ModelConfig, layer: int) -> str:
     """The parameter stack layer ``layer`` (0-based) lies in: by its MLP
-    (``dense_layers`` / ``layers``) and, for a gated delta-rule layer, the
-    prefix ``kda_``."""
+    (``dense_layers`` / ``layers``), for a gated delta-rule layer the
+    prefix ``kda_`` and for a latent layer that holds an indexer (a
+    ``full`` one) the prefix ``ix_``."""
     lead = cfg.first_k_dense if cfg.num_experts else 0
     name = "dense_layers" if layer < lead else "layers"
-    return _KDA + name if cfg.layer_kinds[layer] == "kda" else name
+    if cfg.layer_kinds[layer] == "kda":
+        return _KDA + name
+    if cfg.index_kinds and cfg.index_kinds[layer] == "full":
+        return _IX + name
+    return name
 
 
 def layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, int], ...]:
     """(params key, layers) of the homogeneous stacks: the latent layers'
-    in layer order, then the gated delta-rule layers'."""
+    in layer order (those with an indexer ahead of those without), then
+    the gated delta-rule layers'."""
     names = [group_of(cfg, li) for li in range(cfg.num_layers)]
-    order = ("dense_layers", "layers", _KDA + "dense_layers",
-             _KDA + "layers")
+    order = (_IX + "dense_layers", "dense_layers", _IX + "layers", "layers",
+             _KDA + "dense_layers", _KDA + "layers")
     return tuple((g, names.count(g)) for g in order if g in names)
 
 
@@ -107,10 +128,15 @@ def layer_units(cfg: ModelConfig
                 ) -> Tuple[Tuple[int, Tuple[Tuple[str, int], ...]], ...]:
     """The forward pass as ``(repeat, runs)`` units in layer order, ``runs``
     the ``(params key, layers)`` of a unit's homogeneous stretches. A model
-    of one cache is one unit; a hybrid is cut after every latent layer and
-    equal neighbours merge, so that a repeated period is traced once."""
+    of one cache is one unit; a hybrid is cut after every latent layer, a
+    model whose layers share selections after every layer that computes
+    one, and equal neighbours merge, so that a repeated period is traced
+    once."""
     names = [group_of(cfg, li) for li in range(cfg.num_layers)]
     cuts = [li for li in cfg.full_attn_layers if li < cfg.num_layers]
+    if "shared" in cfg.index_kinds:
+        cuts = [li + 1 for li, kind in enumerate(cfg.index_kinds)
+                if kind == "full" and li + 1 < cfg.num_layers]
     units: list = []
     for lo, hi in zip([0] + cuts, cuts + [cfg.num_layers]):
         runs: list = []
@@ -131,7 +157,8 @@ def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]
     kind: ``q`` a matmul weight (quantized where the engine quantizes),
     ``d`` a dense bf16 weight, ``n`` a norm vector; float32 vectors drawn
     as the family draws them: ``a`` (``A_log``), ``t`` (``dt_bias``), ``b``
-    (the router's selection bias)."""
+    (the router's selection bias); ``z`` a vector drawn around zero (a
+    LayerNorm's bias)."""
     h, nh = cfg.hidden_size, cfg.num_heads
     if group.startswith(_KDA):
         kh, kd, taps = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_conv_kernel
@@ -174,6 +201,19 @@ def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]
             "wo": ((nh * dv, h), nh * dv, "q"),
             "mlp_norm": ((h,), 0, "n"),
         })
+        if group.startswith(_IX):
+            # the indexer: queries from the query latent or the normed
+            # input, ONE key a token and the heads' weights from the normed
+            # input (narrow: bf16, as the K/V recipe's), the key's LayerNorm
+            hi, di = cfg.index_num_heads, cfg.index_head_dim
+            q_in = rq if cfg.index_query_input == "q_latent" else h
+            spec.update({
+                "wqi": ((q_in, hi * di), q_in, "q"),
+                "wki": ((h, di), h, "d"),
+                "ww": ((h, hi), h, "d"),
+                "ki_norm": ((di,), 0, "n"),
+                "ki_bias": ((di,), 0, "z"),
+            })
     if cfg.sandwich_norm:
         spec["post_attn_norm"] = ((h,), 0, "n")
         spec["post_mlp_norm"] = ((h,), 0, "n")
@@ -226,6 +266,8 @@ def draw_leaf(key: jax.Array, shape: tuple, fan_in: int, kind: str
         return 0.1 * x
     if kind == "n":
         return 1.0 + _NORM_SPREAD * x
+    if kind == "z":
+        return _NORM_SPREAD * x
     return x * (fan_in ** -0.5)
 
 
@@ -283,13 +325,23 @@ def init_kv_pools(cfg: ModelConfig, num_blocks: int, block_size: int = 16,
     """The latent paged pool ``[L, N, Bk, W]``, ``L`` the latent layers;
     block 0 is the pad block. A one-byte float dtype (fp8) stores narrower
     rows; int8 with scales is not built. A hybrid model's dict also holds
-    the state pool of ``state_rows`` sequences (``models/kda.py``)."""
+    the state pool of ``state_rows`` sequences (``models/kda.py``), a
+    model with an indexer's the index-key pool ``"ki"`` ``[L_full, N, Bk,
+    lanes]`` (``ops/index_select.py``)."""
     dtype = jnp.dtype(dtype or cfg.dtype)
     if dtype == jnp.int8:
         raise NotImplementedError("int8 latent pools (scaled) are not built")
     pools = {POOL: jnp.zeros(
         (cfg.num_cache_layers, num_blocks, block_size, pool_width(cfg)),
         dtype)}
+    if cfg.index_topk:
+        # one index key a token a FULL layer, addressed by the latent
+        # pages' block table: a page copy, a prefix hit and a resume bring it
+        from distributed_gpu_inference_tpu.ops.index_select import pool_lanes
+
+        pools[INDEX_KEYS] = jnp.zeros(
+            (cfg.num_index_layers, num_blocks, block_size,
+             pool_lanes(cfg.index_head_dim)), dtype)
     if cfg.num_kda_layers:
         from distributed_gpu_inference_tpu.models import kda
 
@@ -332,6 +384,8 @@ def latent_attention_xla(
     positions: jax.Array,      # [B, S] int32, -1 = pad
     kv_lens: jax.Array,        # [B]
     form: str,                 # "expanded" | "absorbed"
+    keep: Optional[jax.Array] = None,   # [B, S, J] float32 > 0: the cached
+                               # tokens each query attends (a selection)
 ) -> jax.Array:
     """Both forms of the one attention, in float32 → [B, S, Nh, dv]."""
     f32 = jnp.float32
@@ -342,6 +396,8 @@ def latent_attention_xla(
     w_uk, w_uv = w_uk.astype(f32), w_uv.astype(f32)
     scale = cfg.qk_head_dim ** -0.5
     visible = _visible(positions, kv_lens, ctx.shape[1])
+    if keep is not None:
+        visible &= keep > 0
     rope_scores = jnp.einsum("bshr,bjr->bhsj", q_r, k_r)
     if form == "expanded":
         k_n = jnp.einsum("bjc,hcd->bjhd", c, w_uk)
@@ -405,21 +461,96 @@ def _experts(
 # ---------------------------------------------------------------------------
 
 
-def _latent_attention(
-    cfg: ModelConfig, block_size: int, x: jax.Array, lp: Dict[str, Any],
-    proj, pool: jax.Array, pool_layer, *, block_tables, write_positions,
-    kv_lens, cos, sin, kernels, unpack, tiles, write_plan,
-) -> Tuple[jax.Array, jax.Array]:
-    """A latent layer's attention over ``x`` (normed) → (``concat(o)``
-    before ``W_o``, the pool with the layer's rows written)."""
-    from distributed_gpu_inference_tpu.models.llama import (
-        apply_rope, rms_norm,
+def rope(cfg: ModelConfig, x: jax.Array, cos: jax.Array, sin: jax.Array
+         ) -> jax.Array:
+    """Rotation of the first ``2 x cos.shape[-1]`` values of every head of
+    ``x [B, S, H, D]``. By adjacent pairs where ``cfg.rope_interleave``:
+    the pairs ``(2i, 2i + 1)`` are brought side by halves and rotated
+    there, so what comes out is the pairwise rotation with its values in
+    that order -- a permutation both sides of every dot product share
+    (queries and cached keys alike), which leaves the products as they
+    are."""
+    from distributed_gpu_inference_tpu.models.llama import apply_rope
+
+    if cfg.rope_interleave:
+        rot = 2 * cos.shape[-1]
+        x = jnp.concatenate(
+            [x[..., 0:rot:2], x[..., 1:rot:2], x[..., rot:]], axis=-1)
+    return apply_rope(x, cos, sin)
+
+
+def index_inputs(cfg: ModelConfig, lp: Dict[str, Any], x: jax.Array,
+                 c_q: Optional[jax.Array], proj, index
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """What a full layer's indexer makes of the layer's normed input ``x``
+    and query latent ``c_q``: rotated index queries ``[B, S, Hi, Di]``
+    (from ``c_q`` where ``cfg.index_query_input`` says so), the chunk's
+    index keys ``[B, S, Di]`` (LayerNorm with bias, then rotated) and the
+    heads' weights ``[B, S, Hi]`` float32, scaled by ``(Hi x Di) ** -0.5``.
+    The first ``cfg.index_rope_dims`` values of a head are rotated."""
+    from distributed_gpu_inference_tpu.models.llama import layer_norm
+
+    b, s, _ = x.shape
+    hi, di = cfg.index_num_heads, cfg.index_head_dim
+    q_in = c_q if cfg.index_query_input == "q_latent" else x
+    qi = rope(cfg, proj(q_in, "wqi").reshape(b, s, hi, di), index.cos,
+              index.sin)
+    kin = layer_norm(proj(x, "wki"), lp["ki_norm"], lp["ki_bias"],
+                     cfg.rms_norm_eps)
+    kin = rope(cfg, kin[:, :, None, :], index.cos, index.sin)[:, :, 0]
+    wts = proj(x, "ww").astype(jnp.float32) * (hi * di) ** -0.5
+    return qi, kin, wts
+
+
+def selection_state(keep: jax.Array, *, kernels: bool, tiles, unpack,
+                    block_tables, positions, kv_lens, block_size: int
+                    ) -> Dict[str, Any]:
+    """A selection ``keep [B, S, J]`` as the layers that attend it take it
+    (the layer that computed it and those that share it): the carry of the
+    layer scan. The XLA forms take ``keep`` itself; the packed kernel its
+    rows by query tile; a scan step's kernel the rows' selected pages
+    (``ops/mla_attention_pallas.selected_walk``: the page list is the part
+    of a shared selection a kernel can feel, built once a full layer).
+    ``fetched``: for a one-token chunk, the cached tokens of the pages that
+    hold a selected token (the kernel's rule, whichever form ran)."""
+    from distributed_gpu_inference_tpu.ops import mla_attention_pallas as mla_k
+    from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+        fetched_tokens,
     )
 
+    state: Dict[str, Any] = {}
+    step = keep.shape[1] == 1 and unpack is None
+    if step:
+        state["fetched"] = fetched_tokens(keep, block_size)
+    if kernels and tiles is not None:
+        state["keep_tiles"] = mla_k.keep_for_tiles(keep, tiles, unpack[2])
+    elif kernels and step:
+        state["walk"] = mla_k.selected_walk(
+            keep, block_tables, positions[:, 0], kv_lens, block_size)
+    else:
+        state["keep"] = keep
+    return state
+
+
+def _latent_attention(
+    cfg: ModelConfig, block_size: int, x: jax.Array, lp: Dict[str, Any],
+    proj, kv: Dict[str, jax.Array], pool_layer, *, block_tables,
+    write_positions, kv_lens, cos, sin, kernels, unpack, tiles, write_plan,
+    index=None, index_layer=None, sel=None, scored=False,
+) -> Tuple[jax.Array, Dict[str, jax.Array], Any]:
+    """A latent layer's attention over ``x`` (normed) → (``concat(o)``
+    before ``W_o``, the pools with the layer's rows written, the selection
+    the layer attended: its own if it holds an indexer, else ``sel`` as it
+    came). ``scored``: the layer holds an indexer (its stack's name says
+    so; the quantized ``wqi`` may ride ``stacked``, not ``lp``)."""
+    from distributed_gpu_inference_tpu.models.llama import rms_norm
+
+    pool = kv[POOL]
     b, s, _ = x.shape
     nh, rkv = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     eps = cfg.rms_norm_eps
+    c_q = None
     if cfg.q_lora_rank:
         c_q = rms_norm(proj(x, "wq_a"), lp["q_a_norm"], eps)
         q = proj(c_q, "wq_b").reshape(b, s, nh, dn + dr)
@@ -430,22 +561,49 @@ def _latent_attention(
     if cfg.mla_use_nope:        # the "rope" dims as they are
         q_n, q_r, k_r = q[..., :dn], q[..., dn:], ckr[..., rkv:]
     else:
-        q_n, q_r = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
-        k_r = apply_rope(ckr[..., None, rkv:], cos, sin)[..., 0, :]
+        q_n, q_r = q[..., :dn], rope(cfg, q[..., dn:], cos, sin)
+        k_r = rope(cfg, ckr[..., None, rkv:], cos, sin)[..., 0, :]
     pad = jnp.zeros((b, s, pool.shape[-1] - rkv - dr), c.dtype)
     new_rows = jnp.concatenate([c, k_r, pad], axis=-1).astype(pool.dtype)
     positions = write_positions
-    if unpack is not None and not kernels:
+
+    def rectangle(t_):
+        """A packed chunk's ``[1, Tp, ...]`` as the ``[B, S]`` rectangle."""
+        if unpack is None:
+            return t_
+        return jnp.take(t_[0], unpack[0], axis=0, mode="fill", fill_value=0)
+
+    if not kernels:
         # the XLA path attends over the [B, S] rectangle; the kernels
         # take the packed axis as it is (page write) or as query tiles
-        to_rect, tok_row, tok_col = unpack
-
-        def rectangle(t_):
-            return jnp.take(t_[0], to_rect, axis=0, mode="fill",
-                            fill_value=0)
-
         q_n, q_r, new_rows = (rectangle(q_n), rectangle(q_r),
                               rectangle(new_rows))
+    if scored:
+        from distributed_gpu_inference_tpu.ops import index_select
+
+        # the selection is computed over the rectangle on either path; the
+        # chunk's keys are written from the axis they are on
+        qi, kin, wts = index_inputs(cfg, lp, x, c_q, proj, index)
+        qi_r, wts_r = rectangle(qi), rectangle(wts)
+
+        with jax.named_scope("dgi_index"):
+            ki_pool = index_select.write_index_keys(
+                kv[INDEX_KEYS], kin.reshape(-1, cfg.index_head_dim),
+                index_layer, *index.scatter)
+            kv = {**kv, INDEX_KEYS: ki_pool}
+            scan_keys = kv.get(INDEX_SCAN_KEYS)
+            if scan_keys is not None:
+                scan_keys = index_select.append_scan_keys(
+                    scan_keys, kin[:, 0], index_layer, positions[:, 0])
+                kv[INDEX_SCAN_KEYS] = scan_keys
+            keep = index_select.select(
+                qi_r, wts_r, ki_pool, index_layer, block_tables, positions,
+                kv_lens, cfg.index_topk, kernels=kernels,
+                scan_keys=scan_keys)
+            sel = selection_state(
+                keep, kernels=kernels, tiles=tiles, unpack=unpack,
+                block_tables=block_tables, positions=positions,
+                kv_lens=kv_lens, block_size=block_size)
     if kernels:
         from distributed_gpu_inference_tpu.ops import (
             mla_attention_pallas as mla_k,
@@ -462,13 +620,18 @@ def _latent_attention(
                        pool.dtype)], axis=-1)
         common = dict(scale=cfg.qk_head_dim ** -0.5, latent=rkv)
         if tiles is not None:
+            picked = {} if sel is None else {
+                "keep_tiles": sel["keep_tiles"]}
             u = mla_k.latent_paged_attention_packed(
                 q_cat[0], tiles, pool, pool_layer, block_tables,
-                kv_lens, block_size, **common)[None]
+                kv_lens, block_size, **common, **picked)[None]
         else:
+            picked = {} if sel is None else (
+                {"walk": sel["walk"]} if "walk" in sel
+                else {"keep": sel["keep"]})
             u = mla_k.latent_paged_attention(
                 q_cat, pool, pool_layer, block_tables, positions,
-                kv_lens, block_size, decode=s == 1, **common)
+                kv_lens, block_size, decode=s == 1, **common, **picked)
         attn = jnp.einsum("bshc,hcd->bshd", u, lp["w_uv"],
                           preferred_element_type=jnp.float32)
     else:
@@ -487,23 +650,26 @@ def _latent_attention(
         attn = latent_attention_xla(
             cfg, q_n, q_r, lp["w_uk"], lp["w_uv"], ctx, positions,
             kv_lens, "absorbed" if positions.shape[1] == 1
-            else "expanded",
+            else "expanded", keep=None if sel is None else sel["keep"],
         )
         if unpack is not None:
             attn = attn.at[unpack[1], unpack[2]].get(mode="fill",
                                                      fill_value=0)
-    return attn.astype(x.dtype).reshape(b, s, nh * cfg.v_head_dim), pool
+    return (attn.astype(x.dtype).reshape(b, s, nh * cfg.v_head_dim),
+            {**kv, POOL: pool}, sel)
 
 
 def _layer_step(
     cfg: ModelConfig, block_size: int, hidden: jax.Array,
-    kv: Dict[str, jax.Array], lp: Dict[str, Any], *, linear: bool,
-    layer_idx, cache_layer, stacked, pallas, kernels, kda_kernels,
-    kda_plan, rope_positions, moe_live, emit_routing, **latent,
+    kv: Dict[str, jax.Array], sel, lp: Dict[str, Any], *, linear: bool,
+    layer_idx, cache_layer, index_layer, scored, stacked, pallas, kernels,
+    kda_kernels, kda_plan, rope_positions, moe_live, emit_routing, **latent,
 ):
     """One layer: ``layer_idx`` its place in its parameter stack,
     ``cache_layer`` its place in its cache (the latent pool, or for a
-    ``linear`` layer the state pool)."""
+    ``linear`` layer the state pool), ``index_layer`` its place in the
+    index-key pool if it holds an indexer; ``sel`` the selection the last
+    such layer computed (``selection_state``), handed on."""
     from distributed_gpu_inference_tpu.models.llama import _mlp, rms_norm
 
     eps = cfg.rms_norm_eps
@@ -522,10 +688,10 @@ def _layer_step(
                 cfg, x, lp, proj, kv, cache_layer, plan=kda_plan,
                 positions=rope_positions, kernels=kda_kernels)
         else:
-            attn, pool = _latent_attention(
-                cfg, block_size, x, lp, proj, kv[POOL], cache_layer,
-                kernels=kernels, **latent)
-            kv = {**kv, POOL: pool}
+            attn, kv, sel = _latent_attention(
+                cfg, block_size, x, lp, proj, kv, cache_layer,
+                kernels=kernels, index_layer=index_layer, sel=sel,
+                scored=scored, **latent)
             attn = proj(attn, "wo").astype(hidden.dtype)
         if "post_attn_norm" in lp:
             attn = rms_norm(attn, lp["post_attn_norm"], eps)
@@ -543,7 +709,7 @@ def _layer_step(
         if "post_mlp_norm" in lp:
             out = rms_norm(out, lp["post_mlp_norm"], eps)
         hidden = hidden + out
-    return hidden, kv, stats, routing if emit_routing else None
+    return hidden, kv, sel, stats, routing if emit_routing else None
 
 
 def forward_chunk(
@@ -614,6 +780,22 @@ def forward_chunk(
             page_bytes=pool.shape[2] * pool.shape[3] * pool.dtype.itemsize,
             token_index=to_rect, num_tokens=tp,
         )
+    index = sel = None
+    if cfg.index_topk:
+        # the indexer's view of the chunk, the same for every full layer;
+        # the carried selection starts empty (the first layer is full)
+        index = llama._index_plan(cfg, pool.shape[1], block_tables,
+                                  positions, rope_positions, packing,
+                                  block_size)
+        state = functools.partial(
+            selection_state, kernels=kernels, tiles=tiles, unpack=unpack,
+            block_tables=block_tables, positions=positions, kv_lens=kv_lens,
+            block_size=block_size)
+        sel = jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(state, jax.ShapeDtypeStruct(
+                (*positions.shape, block_tables.shape[1] * block_size),
+                jnp.float32)))
     hidden = llama.embed_tokens(params, token_ids, cfg)
     cos = sin = None
     if not cfg.mla_use_nope:
@@ -630,27 +812,31 @@ def forward_chunk(
         kda_kernels=kda_kernels, kda_plan=kda_plan,
         rope_positions=rope_positions, unpack=unpack, tiles=tiles,
         moe_live=rope_positions >= 0, emit_routing=collect_routing,
-        write_plan=write_plan,
+        write_plan=write_plan, index=index,
     )
-    # where each stack and each cache stands, in layers
+    # where each stack, each cache and the index-key pool stand, in layers
     at_w = {group: 0 for group in split}
     at_c = {False: 0, True: 0}
+    at_i = 0
     moe = None
+    fetched = None
     routes = []
 
-    def run(carry, group, scanned, n, w0, c0):
+    def run(carry, group, scanned, n, w0, c0, i0):
         """``n`` layers of one stack (``scanned``: their leaves that ride
-        the scan): weights from ``w0``, cache layers from ``c0`` (scalars,
-        traced inside a repeated unit)."""
+        the scan): weights from ``w0``, cache layers from ``c0``, index-key
+        layers from ``i0`` (scalars, traced inside a repeated unit)."""
         stacked = split[group][1]
         linear = group.startswith(_KDA)
 
         def body(c, xs):
             j, lp = xs
-            hidden_, kv_, stats, routing = step(
-                c[0], c[1], lp, linear=linear, layer_idx=w0 + j,
-                cache_layer=c0 + j, stacked=stacked)
-            return (hidden_, kv_), (stats, routing)
+            hidden_, kv_, sel_, stats, routing = step(
+                c[0], c[1], c[2], lp, linear=linear, layer_idx=w0 + j,
+                cache_layer=c0 + j, index_layer=i0 + j,
+                scored=group.startswith(_IX), stacked=stacked)
+            took = None if sel_ is None else sel_.get("fetched")
+            return (hidden_, kv_, sel_), (stats, routing, took)
 
         return lax.scan(body, carry, (jnp.arange(n, dtype=jnp.int32), scanned))
 
@@ -663,65 +849,79 @@ def forward_chunk(
                 repeat, n // repeat, *a.shape[1:])
         return jax.tree.map(cut, split[group][0])
 
-    def add_stats(stats):
+    def add_stats(stats, took):
         """A run's routed-expert counters, summed over its layers, onto
-        the pass's."""
-        nonlocal moe
+        the pass's; likewise what its layers' selections fetched."""
+        nonlocal moe, fetched
         if stats is not None:
             sums = {name: jnp.sum(v) for name, v in stats.items()}
             moe = sums if moe is None else {
                 name: moe[name] + v for name, v in sums.items()}
+        if took is not None:
+            fetched = jnp.sum(took) + (0 if fetched is None else fetched)
 
-    carry = (hidden, kv)
+    def indexed(group):
+        return int(group.startswith(_IX))
+
+    carry = (hidden, kv, sel)
     for repeat, runs in layer_units(cfg):
         if repeat == 1:
             for group, n in runs:
                 linear = group.startswith(_KDA)
-                carry, (stats, routing) = run(
+                carry, (stats, routing, took) = run(
                     carry, group, leaves(group, at_w[group], n), n,
-                    jnp.int32(at_w[group]), jnp.int32(at_c[linear]))
+                    jnp.int32(at_w[group]), jnp.int32(at_c[linear]),
+                    jnp.int32(at_i))
                 at_w[group] += n
                 at_c[linear] += n
-                add_stats(stats)
+                at_i += n * indexed(group)
+                add_stats(stats, took)
                 if routing is not None:
                     routes.append(routing)
             continue
         # a repeated period: one scan over its repeats, its runs inside
         w_lo = {g: at_w[g] for g, _ in runs}
         c_lo = dict(at_c)
+        i_lo = at_i
         per_c = {lin: sum(n for g, n in runs if g.startswith(_KDA) == lin)
                  for lin in (False, True)}
+        per_i = sum(n * indexed(g) for g, n in runs)
         xs = {g: leaves(g, w_lo[g], repeat * n, repeat) for g, n in runs}
 
         def period(c, px):
             p_, lp_ = px
             outs = []
             seen = {False: 0, True: 0}
+            seen_i = 0
             for g, n in runs:
                 lin = g.startswith(_KDA)
                 c, out = run(c, g, lp_[g], n, w_lo[g] + p_ * n,
-                             c_lo[lin] + p_ * per_c[lin] + seen[lin])
+                             c_lo[lin] + p_ * per_c[lin] + seen[lin],
+                             i_lo + p_ * per_i + seen_i)
                 seen[lin] += n
+                seen_i += n * indexed(g)
                 outs.append(out)
             return c, outs
 
         carry, outs = lax.scan(
             period, carry, (jnp.arange(repeat, dtype=jnp.int32), xs))
         period_routes = []
-        for (g, n), (stats, routing) in zip(runs, outs):
+        for (g, n), (stats, routing, took) in zip(runs, outs):
             at_w[g] += repeat * n
             at_c[g.startswith(_KDA)] += repeat * n
-            add_stats(stats)
+            at_i += repeat * n * indexed(g)
+            add_stats(stats, took)
             if routing is not None:
                 period_routes.append(routing)       # [repeat, n, T, k]
         if period_routes:
             r_ = jnp.concatenate(period_routes, axis=1)
             routes.append(r_.reshape(-1, *r_.shape[2:]))
-    hidden, new_kv = carry
+    hidden, new_kv, _ = carry
     routing = jnp.concatenate(routes, axis=0) if routes else None
     if not with_logits:
         return llama.ChunkOutput(hidden=hidden, kv=new_kv, logits=None,
-                                 moe=moe, routing=routing)
+                                 moe=moe, routing=routing,
+                                 index_fetched=fetched)
     if last_only and packing is not None:
         logits_in = jnp.take(hidden[0], packing.last, axis=0)[:, None]
     elif last_only:
@@ -733,7 +933,7 @@ def forward_chunk(
     with jax.named_scope("dgi_head"):
         logits = llama.project_logits(cfg, params, logits_in)
     return llama.ChunkOutput(hidden=hidden, kv=new_kv, logits=logits,
-                             moe=moe, routing=routing)
+                             moe=moe, routing=routing, index_fetched=fetched)
 
 
 def _split_group(layers: Dict[str, Any], pallas: bool):

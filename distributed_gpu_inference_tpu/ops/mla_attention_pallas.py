@@ -20,6 +20,15 @@ slice as a custom-call operand is a copy of the layer).
   memory-bound decode kernel nor the compute-bound prefill kernel.
   The walk over live page groups, the double-buffered page DMAs and the
   per-query causal mask are the ragged K/V kernel's.
+- **Under a learned selection** (``keep``: ``ops/index_select.py``) the same
+  kernel masks a cached token by its query's ``keep`` as well. A scan step
+  (``dgi_mla_decode_selected``) walks a row's SELECTED pages only: the
+  caller lays them out once for a layer that computes a selection
+  (:func:`selected_walk`: the K/V decode kernel's ``_selected_pages``) and
+  hands the same walk to every layer that shares it, so the kernel reads
+  the pages that hold a selected token and not the row's whole cache. A
+  round (``dgi_mla_ragged_selected``) walks the rows' pages as it does
+  without one, every query of a tile under its own ``keep``.
 """
 
 from __future__ import annotations
@@ -42,6 +51,9 @@ _NEG_INF = -1e30
 DECODE_KERNEL_NAME = "dgi_mla_decode"
 RAGGED_KERNEL_NAME = "dgi_mla_ragged"
 WRITE_KERNEL_NAME = "dgi_mla_write"
+# the same kernels under a selection: a device trace tells the selected
+# walk from the dense one
+_SELECTED = "_selected"
 # ceiling on (heads) x (query tile): the rows of the score tile and of the
 # float32 accumulator a grid cell carries (1024 x 512 x 4 B = 2 MiB)
 _HEAD_ROWS = 1024
@@ -173,14 +185,17 @@ def _attention_kernel(
     q_ref,         # [1, Nh*T, W] this tile's absorbed queries, head-major
     pos_ref,       # [1, Nh*T, 1] int32 per-query positions (-1 = pad)
     pool_hbm,      # [L, N, Bk, W]
-    out_ref,       # [1, Nh*T, latent]
-    buf,           # [2, G, Bk, W] page staging
-    sems,          # DMA [2, G]
-    m_scr, l_scr,  # [Nh*T, 1] float32 softmax state
-    acc_scr,       # [Nh*T, latent] float32
-    *, rows: int, block_size: int, pages_per_group: int,
-    max_pages: int, scale: float, latent: int,
+    *rest,         # [keep_ref [1, T, gsz] float32 (> 0: attended),]
+                   # out_ref [1, Nh*T, latent], buf [2, G, Bk, W] page
+                   # staging, sems DMA [2, G], m_scr, l_scr [Nh*T, 1]
+                   # float32 softmax state, acc_scr [Nh*T, latent] float32
+    rows: int, block_size: int, pages_per_group: int,
+    max_pages: int, scale: float, latent: int, selected: bool, heads: int,
 ):
+    keep_ref = None
+    if selected:
+        keep_ref, *rest = rest
+    out_ref, buf, sems, m_scr, l_scr, acc_scr = rest
     r = pl.program_id(0)
     i = pl.program_id(1)
     gp = pages_per_group
@@ -270,6 +285,14 @@ def _attention_kernel(
         col = i * gsz + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
         pos = pos_ref[0]                                       # [Nh*T, 1]
         valid = (col < kv_len) & (col <= pos)
+        if selected:
+            # the tile's T queries repeat once a head, as their positions do
+            kept = keep_ref[0] > 0                              # [T, gsz]
+            if kept.shape[0] == 1:
+                kept = jnp.broadcast_to(kept, valid.shape)
+            elif heads > 1:
+                kept = jnp.concatenate([kept] * heads, axis=0)
+            valid &= kept
         scores = jnp.where(valid, scores, _NEG_INF)
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
@@ -297,11 +320,12 @@ def _q_tile(s: int, nh: int) -> int:
 
 def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
                   block_tables, kv_lens, *, block_size, scale, latent, name,
-                  interpret):
+                  interpret, keep_tiles=None):
     """The kernel over query tiles: ``q_tiles [R, T, Nh, W]`` (``T``
     consecutive queries of ONE sequence a tile, ``tile_seq [R]`` says
     which), ``pos_tiles [R, T]`` their positions (-1 = no query) →
-    ``[R, T, Nh, latent]``."""
+    ``[R, T, Nh, latent]``. ``keep_tiles [R, T, J]`` float32: a query
+    attends only the columns of its tile's walk where it is > 0."""
     rows, t, nh, w = q_tiles.shape
     m = block_tables.shape[1]
     # [R, T, Nh, W] → [R, Nh*T, W], the query index fastest inside a head
@@ -315,11 +339,30 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
         return pl.BlockSpec((1, nh * t, width), lambda i, j, *_refs: (i, 0, 0),
                             memory_space=pltpu.VMEM)
 
+    max_groups = -(-m // gp)
+    gsz = gp * block_size
+    in_specs = [tile_spec(w), tile_spec(1),
+                pl.BlockSpec(memory_space=pltpu.HBM)]
+    operands = [q_r, pos_q, pool]
+    selected = keep_tiles is not None
+    if selected:
+        keep_r = jnp.pad(keep_tiles.astype(jnp.float32), (
+            (0, 0), (0, 0), (0, max_groups * gsz - keep_tiles.shape[2])))
+
+        def keep_block(i, j, _bt, lens, seq, qmax, *_refs):
+            # a cell past the tile's last live group names that group's
+            # block again, so nothing of a dead cell is fetched
+            needed = jnp.minimum(qmax[i] + 1, lens[seq[i]])
+            live = jnp.minimum(pl.cdiv(needed, gsz), max_groups)
+            return i, 0, jnp.clip(j, 0, jnp.maximum(live - 1, 0))
+
+        in_specs.append(pl.BlockSpec((1, t, gsz), keep_block,
+                                     memory_space=pltpu.VMEM))
+        operands.append(keep_r)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(rows, -(-m // gp)),
-        in_specs=[tile_spec(w), tile_spec(1),
-                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        grid=(rows, max_groups),
+        in_specs=in_specs,
         out_specs=tile_spec(latent),
         scratch_shapes=[
             pltpu.VMEM((2, gp, block_size, w), pool.dtype),
@@ -332,6 +375,7 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
     kernel = functools.partial(
         _attention_kernel, rows=rows, block_size=block_size,
         pages_per_group=gp, max_pages=m, scale=scale, latent=latent,
+        selected=selected, heads=nh,
     )
     out = pl.pallas_call(
         kernel,
@@ -341,13 +385,13 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name=name,
+        name=name + _SELECTED if selected else name,
     )(
         block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
         tile_seq.astype(jnp.int32), jnp.max(pos_r, axis=1),
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
         jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
-        q_r, pos_q, pool,
+        *operands,
     )
     return out.reshape(rows, nh, t, latent).transpose(0, 2, 1, 3)
 
@@ -369,20 +413,46 @@ def latent_paged_attention(
     latent: int,
     decode: bool = False,
     interpret: bool = False,
+    keep: jax.Array | None = None,    # [B, S, M * Bk] float32 > 0: the
+                                      # cached tokens each query attends
+    walk: "SelectedWalk | None" = None,
+                                      # S == 1: the rows' selected pages
+                                      # (:func:`selected_walk`), in place
+                                      # of ``keep``
 ) -> jax.Array:
     """Absorbed attention of ``S`` queries a row against the row's cached
     latents → ``[B, S, Nh, latent]`` (the caller lifts it through ``W_UV``).
     Masking is the XLA form's (``models/mla.latent_attention_xla``): a query
     at position p sees cached positions ``j <= p`` inside ``kv_lens``, a
-    padded query gives zeros. ``decode`` only names the kernel."""
+    padded query gives zeros. ``decode`` only names the kernel. Under a
+    selection a one-token row walks its selected pages alone (``walk``, or
+    built here from ``keep``); a longer row walks its pages under ``keep``."""
     b, s, nh, w = q.shape
     _check(pool, block_size, w, latent, interpret)
+    if s == 1 and (keep is not None or walk is not None):
+        if walk is None:
+            walk = selected_walk(keep, block_tables, positions[:, 0],
+                                 kv_lens, block_size)
+        # the walk IS the table: ``count`` pages, every column of which
+        # the query sees unless ``keep`` says otherwise
+        fetched = walk.count * block_size
+        out = _attend_tiles(
+            q.reshape(b, 1, nh, w),
+            jnp.where(positions >= 0, fetched[:, None] - 1, -1),
+            jnp.arange(b, dtype=jnp.int32), pool, layer_idx, walk.pages,
+            fetched, block_size=block_size, scale=scale, latent=latent,
+            interpret=interpret, keep_tiles=walk.keep,
+            name=DECODE_KERNEL_NAME if decode else RAGGED_KERNEL_NAME,
+        )
+        return out.reshape(b, 1, nh, latent)
     t = _q_tile(s, nh)
     s_pad = -(-s // t) * t
     if s_pad != s:
         q = jnp.pad(q, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
         positions = jnp.pad(
             positions, ((0, 0), (0, s_pad - s)), constant_values=-1)
+        if keep is not None:
+            keep = jnp.pad(keep, ((0, 0), (0, s_pad - s), (0, 0)))
     qt = s_pad // t
     out = _attend_tiles(
         q.reshape(b * qt, t, nh, w), positions.reshape(b * qt, t),
@@ -390,8 +460,44 @@ def latent_paged_attention(
         block_tables, kv_lens, block_size=block_size, scale=scale,
         latent=latent, interpret=interpret,
         name=DECODE_KERNEL_NAME if decode else RAGGED_KERNEL_NAME,
+        keep_tiles=None if keep is None
+        else keep.reshape(b * qt, t, keep.shape[2]),
     )
     return out.reshape(b, s_pad, nh, latent)[:, :s]
+
+
+class SelectedWalk(NamedTuple):
+    """A scan step's rows under a selection, laid out for the kernel
+    (:func:`selected_walk`): built once by a layer that computes a
+    selection and walked again, as it is, by the layers that share it."""
+
+    pages: jax.Array    # [B, columns] int32 the row's selected pages, in
+                        # context order, the last of them repeated
+    keep: jax.Array     # [B, 1, columns x Bk] float32 in that order
+    count: jax.Array    # [B] int32 pages to fetch
+
+
+def walk_columns(table_width: int, block_size: int) -> int:
+    """Columns of a walk over a table ``table_width`` pages wide: the
+    kernel's whole page groups."""
+    gp = max(1, min(_GROUP_TOKENS // block_size, table_width))
+    return -(-table_width // gp) * gp
+
+
+def selected_walk(keep: jax.Array, block_tables: jax.Array,
+                  positions: jax.Array, kv_lens: jax.Array,
+                  block_size: int) -> SelectedWalk:
+    """``keep [B, 1, J]`` of one-token rows (``positions [B]``) → the pages
+    that hold a token a row's query attends, and ``keep`` in their order
+    (``ops/paged_attention_pallas._selected_pages``: one stable sort of
+    ``[B, M]`` words)."""
+    from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+        _selected_pages,
+    )
+
+    return SelectedWalk(*_selected_pages(
+        keep, block_tables, positions, kv_lens, block_size, None,
+        walk_columns(block_tables.shape[1], block_size)))
 
 
 def _check(pool, block_size, w, latent, interpret):
@@ -452,12 +558,15 @@ def latent_paged_attention_packed(
     scale: float,
     latent: int,
     interpret: bool = False,
+    keep_tiles: jax.Array | None = None,   # [R, T, M * Bk] float32 > 0:
+                                           # what each tile's queries attend
 ) -> jax.Array:
     """:func:`latent_paged_attention` for a packed round, without the
     rectangle: the queries are gathered straight into their tiles and the
     result back onto the packed axis → ``[Tp, Nh, latent]``. At ``Tp`` 264
     on 8 sequences of width 256 that is 41 tiles where the rectangle has
-    256, most of them empty."""
+    256, most of them empty. ``keep_tiles``: a selection, a row a query of
+    a tile (:func:`keep_for_tiles`)."""
     tp, nh, w = q.shape
     _check(pool, block_size, w, latent, interpret)
     rows, t = tiles.token.shape
@@ -466,7 +575,19 @@ def latent_paged_attention_packed(
     out = _attend_tiles(
         q_tiles, tiles.pos, tiles.seq, pool, layer_idx, block_tables,
         kv_lens, block_size=block_size, scale=scale, latent=latent,
-        name=RAGGED_KERNEL_NAME, interpret=interpret,
+        name=RAGGED_KERNEL_NAME, interpret=interpret, keep_tiles=keep_tiles,
     )
     return jnp.take(out.reshape(rows * t, nh, latent), tiles.slot, axis=0,
                     mode="fill", fill_value=0)
+
+
+def keep_for_tiles(keep: jax.Array, tiles: PackedTiles, col: jax.Array
+                   ) -> jax.Array:
+    """A round's selection ``keep [B, S, J]`` (the rectangle's) as the
+    packed kernel takes it, ``[R, T, J]``: the row of each tile's query
+    (``col [Tp]`` its column in the rectangle); a tile position that holds
+    no query attends nothing."""
+    tp = col.shape[0]
+    safe = jnp.minimum(tiles.token, tp - 1)
+    rows = keep[tiles.seq[:, None], col[safe]]              # [R, T, J]
+    return jnp.where((tiles.token < tp)[..., None], rows, 0.0)

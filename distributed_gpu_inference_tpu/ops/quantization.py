@@ -217,8 +217,11 @@ def quantize_params(
     # and a hybrid one a pair more (the gated delta-rule layers)
     # and a K/V model of mixed attention kinds its full layers' (models/
     # llama.py group_of)
+    # and a latent model with an indexer its full layers', which hold one
+    # (models/mla.py group_of)
     for group in ("layers", "dense_layers", "kda_layers",
-                  "kda_dense_layers", "full_layers", "full_dense_layers"):
+                  "kda_dense_layers", "full_layers", "full_dense_layers",
+                  "ix_layers", "ix_dense_layers"):
         if group in params:
             out[group] = _quantize_group(params[group], mode, consume)
     return out

@@ -731,8 +731,11 @@ class TPUEngine:
             # what a cached token is: per-head K and V, or one latent;
             # hybrid: latent pages beside a state row a sequence
             # kv+index: K/V pages and an index key a token beside them
+            # latent+index: latent pages and an index key a token a
+            # layer that holds an indexer beside them
             "kv_layout": "hybrid" if self._state_rows
-            else "latent" if self.model_cfg.latent_kv
+            else ("latent+index" if self.model_cfg.index_topk
+                  else "latent") if self.model_cfg.latent_kv
             else "kv+index" if self.model_cfg.index_topk else "kv",
         }
         if self.model_cfg.index_topk:
@@ -752,6 +755,12 @@ class TPUEngine:
                 # passes topk inside it not at all (host arithmetic at a
                 # scan's commit, the device's own condition)
                 "index_key_gathers_scan": 0,
+                # layer calls that computed a selection from their own
+                # indexer, and layer calls that attended the selection of
+                # the layer before them (``ModelConfig.index_kinds``:
+                # shared stays 0 where every layer holds an indexer), a
+                # scan step and a ragged round each counting its layers
+                "index_layers_scored": 0, "index_layers_shared": 0,
             })
             if self.model_cfg.num_experts and self.mesh is None:
                 # what the decode kernel fetched for those selections: the
@@ -2955,9 +2964,10 @@ class TPUEngine:
         for off, m in segments:
             seen, selected, _ = _index_work(off, m, self.model_cfg.index_topk)
             pairs, kept, ctx = pairs + seen, kept + selected, ctx + off + m
-        sp.set(index_context_tokens=ctx)
+        sp.set(index_context_tokens=ctx, index_selected_tokens=kept)
         self.stats["index_pairs_ragged"] += pairs
         self.stats["index_selected_pairs_ragged"] += kept
+        self._count_index_layers(sp, 1)
 
     def _count_moe(self, sp: Optional[flight.span], kind: str,
                    moe: Sequence[np.ndarray]) -> None:
@@ -4154,7 +4164,8 @@ class TPUEngine:
             fetched = int(moe[0][-1]) // self.model_cfg.num_layers
             st["index_fetched_tokens_scan"] += fetched
             if sp is not None:
-                sp.set(index_fetched_tokens=fetched)
+                sp.set(index_fetched_tokens=fetched,
+                       index_fetched_pages=fetched // self.cfg.block_size)
         if self._state_rows:
             # a live row's step went through every linear-attention layer
             steps = int((emitted >= 0).sum()) * self.model_cfg.num_kda_layers
@@ -4164,7 +4175,7 @@ class TPUEngine:
         with flight.span("dgi.engine.decode_multi.commit", st,
                          "round_commit_s"):
             out: Dict[int, List[int]] = {}
-            index_ctx, index_most = 0, -1
+            index_ctx, index_kept, index_most = 0, 0, -1
             attn_ctx = [0, 0]
             for i, s in enumerate(self.slots):
                 if not scan.active_mask[i] or s is None:
@@ -4184,8 +4195,10 @@ class TPUEngine:
                     st["mla_context_tokens_scan"] += (
                         n * int(self._kv_lens[i]) + n * (n + 1) // 2)
                 if "index_row_steps_scan" in st:
-                    index_ctx += self._count_index_scan(
+                    seen, selected = self._count_index_scan(
                         int(self._kv_lens[i]), len(toks))
+                    index_ctx, index_kept = index_ctx + seen, \
+                        index_kept + selected
                     if toks:    # a row the device found live
                         index_most = max(index_most, int(self._kv_lens[i]))
                 # each emitted token corresponds to one scan step that fed
@@ -4202,15 +4215,18 @@ class TPUEngine:
                 commit = toks if s.finish_reason is None else toks[:-1]
                 self.manager.commit_tokens(s.seq_id, commit)
                 self._maybe_release_window(i)
+        if "index_layers_scored" in st:
+            self._count_index_layers(sp, scan.num_steps)
         if index_ctx and sp is not None:
-            sp.set(index_context_tokens=index_ctx)
+            sp.set(index_context_tokens=index_ctx,
+                   index_selected_tokens=index_kept)
         if attn_ctx[0] and sp is not None:
             sp.set(attn_full_context_tokens=attn_ctx[0],
                    attn_window_context_tokens=attn_ctx[1])
         # (its storage exists where a table can pass topk at all)
         if self._scan_keys is not None and index_most >= 0 and \
                 index_most + scan.num_steps > self.model_cfg.index_topk:
-            st["index_key_gathers_scan"] += self.model_cfg.num_layers
+            st["index_key_gathers_scan"] += self.model_cfg.num_index_layers
         if self._unread is None:
             # nothing went out behind it: the chip waited for this commit
             st["round_host_exposed_s"] += time.perf_counter() - t0
@@ -4235,10 +4251,22 @@ class TPUEngine:
             * self.manager.window_resident_blocks(seq_id)
         return full, windowed
 
-    def _count_index_scan(self, cached: int, n: int) -> int:
+    def _count_index_layers(self, sp: Optional[flight.span], passes: int
+                            ) -> None:
+        """``passes`` forward passes (a scan's steps, or a round: one) into
+        the counters of the layers that scored and of those that shared."""
+        kinds = self.model_cfg.index_kinds
+        scored = passes * kinds.count("full")
+        shared = passes * kinds.count("shared")
+        self.stats["index_layers_scored"] += scored
+        self.stats["index_layers_shared"] += shared
+        if sp is not None:
+            sp.set(index_layers_scored=scored, index_layers_shared=shared)
+
+    def _count_index_scan(self, cached: int, n: int) -> Tuple[int, int]:
         """A row's ``n`` scan steps over ``cached`` tokens: step ``t``
         attended its cache and the token it wrote. Returns the context
-        tokens counted."""
+        tokens counted and those of them the selections kept."""
         st = self.stats
         seen, selected, dense = _index_work(cached, n,
                                             self.model_cfg.index_topk)
@@ -4246,7 +4274,7 @@ class TPUEngine:
         st["index_dense_rows_scan"] += dense
         st["index_context_tokens_scan"] += seen
         st["index_selected_tokens_scan"] += selected
-        return seen
+        return seen, selected
 
     def _build_decode_multi(self, num_steps: int,
                             prev: Optional[_UnreadScan] = None

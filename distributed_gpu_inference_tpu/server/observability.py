@@ -425,8 +425,9 @@ class Metrics:
             "worker_kv_layout",
             "1 for what the worker's cache holds a token: kv (per-head K "
             "and V pages), kv+index (those and an indexer's key beside "
-            "them), latent (one compressed latent and its rope key) or "
-            "hybrid (latent pages in some layers, a fixed-size state row "
+            "them), latent (one compressed latent and its rope key), "
+            "latent+index (those and an index key a layer that holds an "
+            "indexer) or hybrid (latent pages in some layers, a fixed-size state row "
             "a sequence in the others)", ["worker", "layout"],
             registry=r)
         # a model with an indexer (learned sparse attention): its index-key
@@ -457,6 +458,12 @@ class Metrics:
                 ("key_gathers_scan", "Layer-gathers of index keys into "
                  "context order the scans issued: the layers once a scan "
                  "that scores, none for a scan under topk"),
+                ("layers_scored", "Layer calls (a scan step's and a ragged "
+                 "round's) that computed a selection from their own "
+                 "indexer"),
+                ("layers_shared", "Layer calls that attended the selection "
+                 "of the layer before them (shared / (scored + shared) = "
+                 "the share that borrowed)"),
             )
         }
         self.worker_mla = {
@@ -891,7 +898,8 @@ class MetricsCollector:
                     1.0 if name == path else 0.0)
         layout = stats.get("kv_layout")
         if isinstance(layout, str):
-            for name in ("kv", "kv+index", "latent", "hybrid"):
+            for name in ("kv", "kv+index", "latent", "latent+index",
+                         "hybrid"):
                 self.metrics.worker_kv_layout.labels(worker, name).set(
                     1.0 if name == layout else 0.0)
         for key, gauge in (

@@ -328,7 +328,8 @@ class Worker:
                 core = getattr(eng, "engine", None)
                 if self.config.role != "hybrid" and core is not None and \
                         getattr(core, "stats", {}).get("kv_layout") in (
-                            "latent", "hybrid", "kv+index", "kv+window"):
+                            "latent", "latent+index", "hybrid", "kv+index",
+                            "kv+window"):
                     # a prefill / decode role hands K/V pages to a peer
                     # (runtime/kv_handoff.py require_kv_pages): refused
                     # here, where the worker is configured

@@ -1004,3 +1004,82 @@ def test_mixed_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch, tp,
         assert f"{dt}[{held},{hid},{mi}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes \
         < (3 if tp == 2048 else 1) * 1024 ** 3
+
+
+# --------------------------------------------------------------------- #
+# (g) the indexer on latent pages, its selection shared layer to layer:
+# GLM-5.2's share at its published widths and the served 24,576 positions
+# --------------------------------------------------------------------- #
+
+GLM = "glm-5.2-ep16-9l"
+GLM_CTX = 24576
+
+
+@pytest.mark.parametrize("tp,s", [(None, 1), (264, 256), (2048, 256)],
+                         ids=["scan-step", "Tp264", "Tp2048"])
+def test_sparse_latent_model_graphs_compile_and_read_no_pool_densely(
+        v5e, tpu_dispatch, tp, s):
+    """A decode step and the packed round at two rungs, 24,576 positions a
+    row. The latent kernels under their ``_selected`` names (no dense walk
+    is left in the graph), the selection in its own kernels (three full
+    layers of nine: one traced period), latent pages and index keys written
+    and read in place in their stacked pools; no ``[B, S, heads, J]`` score
+    tensor of either the indexer's 32 heads or attention's 64; at decode no
+    gather of a row's latent pages (the XLA form's dense read)."""
+    cfg = get_model_config(GLM)
+    lowered = _forward_chunk_lowered(cfg, s, None, v5e, tp=tp, ctx=GLM_CTX)
+    found = _kernels(lowered)
+    want = {"dgi_mla_write", "dgi_mla_decode_selected", "dgi_moe_gmm_step",
+            "dgi_index_score_step", "dgi_index_threshold_step"} \
+        if tp is None else {
+        "dgi_mla_write", "dgi_mla_ragged_selected", "dgi_moe_gmm",
+        "dgi_index_score", "dgi_index_threshold"}
+    assert want <= found and found <= want | {"dgi_qmm"}, found
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    blocks = 1 + BATCH * (GLM_CTX // 16)
+    for whole, layer in ((f"[9,{blocks},16,640]", f"[{blocks},16,640]"),
+                         (f"[3,{blocks},16,128]", f"[{blocks},16,128]")):
+        assert whole in text
+        assert layer not in text.replace(whole, "")
+    rows = s if tp is None else 256
+    for heads in (cfg.index_num_heads, cfg.num_heads):
+        assert f"[{BATCH},{rows},{heads},{GLM_CTX}]" not in text
+        assert f"[{BATCH},{heads},{rows},{GLM_CTX}]" not in text
+    # a row's cached latents in context order: what the XLA form gathers
+    assert f"[{BATCH},{GLM_CTX},640]" not in text
+    for line in text.splitlines():
+        if " sort(" in line:
+            assert str(GLM_CTX) not in line, line
+    held, hid, mi = cfg.num_held_experts, cfg.hidden_size, \
+        cfg.moe_intermediate_size
+    for dt in ("bf16", "f32"):
+        assert f"{dt}[{held},{hid},{mi}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (3 if tp == 2048 else 1) * 1024 ** 3
+
+
+def test_sparse_latent_scan_carries_a_layer_of_keys_a_full_layer(
+        v5e, tpu_dispatch):
+    """The T=4 scan at 24,576 positions a row: the scan's keys are ``[3, B,
+    J, 128]`` (a layer a FULL layer), gathered ahead of the step loop, the
+    score kernel reads them as the loop carries them, and the storage comes
+    back aliased with the two pools."""
+    cfg = get_model_config(GLM)
+    lowered = _indexed_scan_lowered(cfg, 4, v5e, ctx=GLM_CTX)
+    assert _kernels(lowered) == {
+        "dgi_index_score_step", "dgi_index_threshold_step", "dgi_mla_write",
+        "dgi_mla_decode_selected", "dgi_moe_gmm_step", "dgi_qmm"}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    keys = f"bf16[3,{BATCH},{GLM_CTX},128]"
+    calls = [ln for ln in text.splitlines()
+             if "dgi_index_score_step" in ln and "custom-call(" in ln]
+    assert calls
+    for ln in calls:
+        assert keys in ln.split("operand_layout_constraints")[1], ln
+    stats = compiled.memory_analysis()
+    blocks = 1 + BATCH * (GLM_CTX // 16)
+    assert stats.alias_size_in_bytes >= 3 * BATCH * GLM_CTX * 128 * 2 \
+        + 9 * blocks * 16 * 640 * 2 + 3 * blocks * 16 * 128 * 2
+    assert stats.temp_size_in_bytes < 256 * 1024 ** 2
