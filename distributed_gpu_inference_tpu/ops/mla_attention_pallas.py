@@ -29,6 +29,23 @@ slice as a custom-call operand is a copy of the layer).
   the pages that hold a selected token and not the row's whole cache. A
   round (``dgi_mla_ragged_selected``) walks the rows' pages as it does
   without one, every query of a tile under its own ``keep``.
+- **The step's selected walk has a form of its own** (``walk``, a static
+  flag of the one kernel body; ``ops/paged_attention_pallas._decode_kernel``
+  has the same under a selection). Its groups are ``_WALK_GROUP_TOKENS``
+  (2,048) wide, the one constant the table's padding reads too: a row's
+  ~900 scattered pages are 8 grid steps where 512-token groups take 29. A
+  group's copies all signal ONE semaphore of their slot and the kernel
+  waits once a slot, for the slot's bytes: safe because the table is whole
+  groups wide and repeats the row's last fetched page past its end
+  (``_selected_pages``), so a group always starts exactly its full count of
+  whole-page copies and a start clamps nothing. The starts sit in a rolled
+  loop over runs of ``_SELECTED_UNROLL``: a worker re-traces and re-lowers
+  every round graph at every start, and 128 starts a site unrolled in
+  Python are set-up time every cell with this kernel would pay. The dense
+  forms and the round's are deliberately left as they were (a wait and a
+  start a page of a 32-page group): openPangu's and Kimi-Linear's graphs
+  trace this body too, a faster dense walk bought them nothing end to end
+  and cost their ``setup_s`` a bound (PERF.md section 6, PRs 53-54).
 """
 
 from __future__ import annotations
@@ -43,6 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+    _SELECTED_UNROLL,
     PageWritePlan,
 )
 
@@ -63,6 +81,10 @@ _HEAD_ROWS = 1024
 _VMEM_LIMIT_BYTES = 40 * 1024 * 1024
 # tokens a page group stages (two slots of [group, W] in the pool dtype)
 _GROUP_TOKENS = 512
+# tokens of a group of a scan step's walk over its selected pages: the one
+# width both :func:`walk_columns` (the table's padding) and the kernel (the
+# copies a group starts) read, so the two cannot disagree
+_WALK_GROUP_TOKENS = 2048
 
 
 def _page_write_kernel(page_ref, kind_ref, slots_ref, layer_ref,
@@ -187,11 +209,17 @@ def _attention_kernel(
     pool_hbm,      # [L, N, Bk, W]
     *rest,         # [keep_ref [1, T, gsz] float32 (> 0: attended),]
                    # out_ref [1, Nh*T, latent], buf [2, G, Bk, W] page
-                   # staging, sems DMA [2, G], m_scr, l_scr [Nh*T, 1]
-                   # float32 softmax state, acc_scr [Nh*T, latent] float32
+                   # staging, sems DMA [2, G] ([2] in the ``walk`` form),
+                   # m_scr, l_scr [Nh*T, 1] float32 softmax state, acc_scr
+                   # [Nh*T, latent] float32
     rows: int, block_size: int, pages_per_group: int,
     max_pages: int, scale: float, latent: int, selected: bool, heads: int,
+    walk: bool,
 ):
+    """``walk``: ``bt_ref`` is a scan step's table of selected pages, padded
+    to whole groups (:func:`selected_walk`). Static, and read in Python
+    only: with it off the kernel's equations are what they were before the
+    flag (every other configuration's graphs trace this body too)."""
     keep_ref = None
     if selected:
         keep_ref, *rest = rest
@@ -213,25 +241,58 @@ def _attention_kernel(
     ng_r = num_groups(r)
     live = i < ng_r
 
-    def page_copy(s_, j, slot, p):
-        idx = jnp.minimum(j * gp + p, max_pages - 1)
-        page = bt_ref[seq_ref[jnp.clip(s_, 0, rows - 1)], idx]
-        return pltpu.make_async_copy(
-            pool_hbm.at[layer, page], buf.at[slot, p], sems.at[slot, p])
+    if walk:
+        # ``ops/paged_attention_pallas._decode_kernel``'s walk under a
+        # selection. The table is whole groups wide and holds, past a row's
+        # fetched pages, the last of them again, so a start reads its page
+        # and clamps nothing, and a group always starts exactly ``gp``
+        # whole-page copies, each signalling the ONE semaphore of its slot
+        run = _SELECTED_UNROLL if gp % _SELECTED_UNROLL == 0 else gp
 
-    def start_dma(s_, j, slot):
-        def body(p, carry):
-            page_copy(s_, j, slot, p).start()
-            return carry
+        def start_dma(s_, j, slot):
+            row = seq_ref[jnp.clip(s_, 0, rows - 1)]
+            base = j * gp
 
-        lax.fori_loop(0, gp, body, 0, unroll=True)
+            # a rolled loop over runs of a few starts: unrolled whole, 128
+            # descriptors a site are traced and lowered in Python for every
+            # scan graph at every start of a worker (PERF.md section 6)
+            def body(c, carry):
+                for p in range(run):
+                    pltpu.make_async_copy(
+                        pool_hbm.at[layer, bt_ref[row, base + c * run + p]],
+                        buf.at[slot, c * run + p], sems.at[slot]).start()
+                return carry
 
-    def wait_dma(s_, j, slot):
-        def body(p, carry):
-            page_copy(s_, j, slot, p).wait()
-            return carry
+            lax.fori_loop(0, gp // run, body, 0)
 
-        lax.fori_loop(0, gp, body, 0, unroll=True)
+        def wait_dma(s_, j, slot):
+            # ONE wait: it draws the byte count of its destination, the
+            # whole slot (the source only shapes the descriptor), which is
+            # what the group's ``gp`` copies signalled. A wait for more
+            # bytes than were signalled hangs the chip
+            pltpu.make_async_copy(
+                pool_hbm.at[0, pl.ds(0, gp)], buf.at[slot],
+                sems.at[slot]).wait()
+    else:
+        def page_copy(s_, j, slot, p):
+            idx = jnp.minimum(j * gp + p, max_pages - 1)
+            page = bt_ref[seq_ref[jnp.clip(s_, 0, rows - 1)], idx]
+            return pltpu.make_async_copy(
+                pool_hbm.at[layer, page], buf.at[slot, p], sems.at[slot, p])
+
+        def start_dma(s_, j, slot):
+            def body(p, carry):
+                page_copy(s_, j, slot, p).start()
+                return carry
+
+            lax.fori_loop(0, gp, body, 0, unroll=True)
+
+        def wait_dma(s_, j, slot):
+            def body(p, carry):
+                page_copy(s_, j, slot, p).wait()
+                return carry
+
+            lax.fori_loop(0, gp, body, 0, unroll=True)
 
     def next_chunk(s_, j):
         """Grid-order successor of live chunk (s_, j): the next group of the
@@ -320,12 +381,13 @@ def _q_tile(s: int, nh: int) -> int:
 
 def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
                   block_tables, kv_lens, *, block_size, scale, latent, name,
-                  interpret, keep_tiles=None):
+                  interpret, keep_tiles=None, walk=False):
     """The kernel over query tiles: ``q_tiles [R, T, Nh, W]`` (``T``
     consecutive queries of ONE sequence a tile, ``tile_seq [R]`` says
     which), ``pos_tiles [R, T]`` their positions (-1 = no query) →
     ``[R, T, Nh, latent]``. ``keep_tiles [R, T, J]`` float32: a query
-    attends only the columns of its tile's walk where it is > 0."""
+    attends only the columns of its tile's walk where it is > 0. ``walk``:
+    ``block_tables`` is a :class:`SelectedWalk`'s ``pages``."""
     rows, t, nh, w = q_tiles.shape
     m = block_tables.shape[1]
     # [R, T, Nh, W] → [R, Nh*T, W], the query index fastest inside a head
@@ -333,7 +395,10 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
         .astype(pool.dtype)
     pos_r = pos_tiles.astype(jnp.int32)
     pos_q = jnp.tile(pos_r, (1, nh))[:, :, None]
-    gp = max(1, min(_GROUP_TOKENS // block_size, m))
+    gp = _pages_per_group(m, block_size, walk)
+    if walk and m % gp:
+        raise ValueError(
+            f"a walk of {m} columns is not whole groups of {gp} pages")
 
     def tile_spec(width):
         return pl.BlockSpec((1, nh * t, width), lambda i, j, *_refs: (i, 0, 0),
@@ -366,7 +431,7 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
         out_specs=tile_spec(latent),
         scratch_shapes=[
             pltpu.VMEM((2, gp, block_size, w), pool.dtype),
-            pltpu.SemaphoreType.DMA((2, gp)),
+            pltpu.SemaphoreType.DMA((2,) if walk else (2, gp)),
             pltpu.VMEM((nh * t, 1), jnp.float32),
             pltpu.VMEM((nh * t, 1), jnp.float32),
             pltpu.VMEM((nh * t, latent), jnp.float32),
@@ -375,7 +440,7 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
     kernel = functools.partial(
         _attention_kernel, rows=rows, block_size=block_size,
         pages_per_group=gp, max_pages=m, scale=scale, latent=latent,
-        selected=selected, heads=nh,
+        selected=selected, heads=nh, walk=walk,
     )
     out = pl.pallas_call(
         kernel,
@@ -441,7 +506,7 @@ def latent_paged_attention(
             jnp.where(positions >= 0, fetched[:, None] - 1, -1),
             jnp.arange(b, dtype=jnp.int32), pool, layer_idx, walk.pages,
             fetched, block_size=block_size, scale=scale, latent=latent,
-            interpret=interpret, keep_tiles=walk.keep,
+            interpret=interpret, keep_tiles=walk.keep, walk=True,
             name=DECODE_KERNEL_NAME if decode else RAGGED_KERNEL_NAME,
         )
         return out.reshape(b, 1, nh, latent)
@@ -477,10 +542,17 @@ class SelectedWalk(NamedTuple):
     count: jax.Array    # [B] int32 pages to fetch
 
 
+def _pages_per_group(table_width: int, block_size: int, walk: bool) -> int:
+    """Pages a group stages of a table ``table_width`` pages wide: the
+    rows' block tables, or (``walk``) a scan step's selected pages."""
+    tokens = _WALK_GROUP_TOKENS if walk else _GROUP_TOKENS
+    return max(1, min(tokens // block_size, table_width))
+
+
 def walk_columns(table_width: int, block_size: int) -> int:
     """Columns of a walk over a table ``table_width`` pages wide: the
     kernel's whole page groups."""
-    gp = max(1, min(_GROUP_TOKENS // block_size, table_width))
+    gp = _pages_per_group(table_width, block_size, True)
     return -(-table_width // gp) * gp
 
 

@@ -376,28 +376,116 @@ def _xla_absorbed(q, pool, layer, tables, positions, lens, keep, rkv, dr,
     return jnp.einsum("bhsj,bjc->bshc", p, ctx[..., :rkv])
 
 
-def test_the_decode_kernel_walks_the_selected_pages(monkeypatch):
-    """``dgi_mla_decode_selected`` in interpret mode: three rows (one of
-    them idle) over several page groups, against the XLA form under the
-    same ``keep``; the walk lists the pages that hold a kept token."""
-    monkeypatch.setattr(mla_k, "_GROUP_TOKENS", 16)
-    mc, q, pool, tables, positions, lens, keep, rkv, dr = _kernel_case(
-        0, 3, 12, [45, 0, 30])
+# a scan step's walk over its selected pages, by where the rows' walks end
+# against the kernel's groups (16 pages here: two runs of the rolled start
+# loop). name: (pages a row holds a kept token in, the rows' lengths)
+_W_PAGES, _W_GROUP = 48, 64
+WALK_FORMS = {
+    "ends_mid_group": ((21, 5, 37), (190, 60, 170)),
+    "fills_a_group_exactly": ((16, 32, 16), (100, 192, 65)),
+    "spans_several_groups": ((48, 33, 17), (192, 150, 120)),
+    "an_idle_row": ((21, 0, 16), (190, 0, 90)),
+    "a_row_that_kept_its_own_page_alone": ((1, 0, 40), (3, 0, 180)),
+}
+
+
+def _walk_case(name):
+    """Queries, a pool, tables and a selection that holds a token or two
+    in the named count of each row's pages (the query's own page among
+    them); → the operands, and the pool pages the rows' walks list."""
+    kept, lens = WALK_FORMS[name]
+    mc, q, pool, tables, positions, lens, _, rkv, dr = _kernel_case(
+        54, 3, _W_PAGES, lens)
+    rng = np.random.default_rng(7)
+    keep = np.zeros((3, 1, _W_PAGES * BLOCK), np.float32)
+    walked = set()
+    for r, n in enumerate(kept):
+        if not n:
+            continue
+        last = (int(lens[r]) - 1) // BLOCK
+        chosen = np.append(rng.choice(last, n - 1, replace=False), last)
+        walked.update(np.asarray(tables)[r, chosen].tolist())
+        for page in chosen:
+            top = min(BLOCK, int(lens[r]) - page * BLOCK)
+            keep[r, 0, page * BLOCK + rng.choice(
+                top, min(2, top), replace=False)] = 1
+        keep[r, 0, int(lens[r]) - 1] = 1     # a query attends itself
+    return (q, pool, tables, positions, lens, jnp.asarray(keep), rkv, dr,
+            sorted(walked))
+
+
+@pytest.mark.parametrize("given", ["keep", "walk"])
+@pytest.mark.parametrize("name", WALK_FORMS)
+def test_the_decode_kernel_walks_the_selected_pages(name, given,
+                                                    monkeypatch):
+    """``dgi_mla_decode_selected`` in interpret mode, the group narrowed to
+    16 pages: rows whose walks end inside a group, fill one exactly, span
+    several, and an idle row. A group's pages are started in a rolled loop
+    and waited for ONCE a slot; every pool page outside the rows' walks is
+    NaN (a page read past a walk's end would reach the output); the result
+    is the XLA form's under the same ``keep``, from ``keep`` or from the
+    walk a full layer hands the layers that share its selection."""
+    monkeypatch.setattr(mla_k, "_WALK_GROUP_TOKENS", _W_GROUP)
+    q, pool, tables, positions, lens, keep, rkv, dr, walked = _walk_case(
+        name)
     scale = 0.17
     want = _xla_absorbed(q, pool, 1, tables, positions, lens, keep, rkv, dr,
                          scale)
+    bad = np.full(pool.shape, np.nan, np.float32)
+    bad[:, walked] = np.asarray(pool)[:, walked]
     walk = mla_k.selected_walk(keep, tables, positions[:, 0], lens, BLOCK)
-    col = np.arange(keep.shape[2])[None]
-    seen = (col <= np.asarray(positions)) & (np.asarray(keep[:, 0]) > 0)
-    pages = seen.reshape(3, 12, BLOCK).any(-1)
-    assert np.array_equal(np.asarray(walk.count), pages.sum(1))
-    assert int(walk.count[1]) == 0 and int(walk.count[0]) < 12
-    for kw in ({"keep": keep}, {"walk": walk}):
-        got = mla_k.latent_paged_attention(
+    assert np.asarray(walk.count).tolist() == list(WALK_FORMS[name][0])
+    kw = {"keep": keep} if given == "keep" else {"walk": walk}
+    # the jitted entry would hand back a trace made at another group width
+    got = np.asarray(mla_k.latent_paged_attention.__wrapped__(
+        q, jnp.asarray(bad), jnp.int32(1), tables, positions, lens, BLOCK,
+        scale=scale, latent=rkv, decode=True, interpret=True, **kw))
+    assert np.isfinite(got).all()
+    assert np.abs(got - np.asarray(want)).max() < 1e-5
+    for r, n in enumerate(WALK_FORMS[name][0]):
+        if not n:
+            assert np.abs(got[r]).max() == 0
+
+
+@pytest.mark.parametrize("name", WALK_FORMS)
+def test_the_walk_the_kernel_is_handed_ends_in_the_rows_last_fetched_page(
+        name, monkeypatch):
+    """What the kernel's one wait a slot rests on: the table is whole
+    groups of the kernel's OWN width, and past a row's fetched pages it
+    holds the last of them again (never a page the selection dropped) with
+    ``keep`` zero there, so a group always starts its full count of
+    whole-page copies."""
+    monkeypatch.setattr(mla_k, "_WALK_GROUP_TOKENS", _W_GROUP)
+    q, pool, tables, positions, lens, keep, *_ = _walk_case(name)
+    walk = mla_k.selected_walk(keep, tables, positions[:, 0], lens, BLOCK)
+    pages, laid, count = map(np.asarray, walk)
+    gp = mla_k._pages_per_group(pages.shape[1], BLOCK, True)
+    assert gp == _W_GROUP // BLOCK and pages.shape[1] % gp == 0
+    assert pages.shape[1] == mla_k.walk_columns(_W_PAGES, BLOCK)
+    assert laid.shape == (3, 1, pages.shape[1] * BLOCK)
+    for r, n in enumerate(count):
+        hit = np.asarray(keep)[r, 0].reshape(_W_PAGES, BLOCK).any(-1)
+        assert n == hit.sum()
+        assert pages[r, :n].tolist() == np.asarray(tables)[r, hit].tolist()
+        assert laid[r, 0, :n * BLOCK].sum() == np.asarray(keep)[r].sum()
+        assert not laid[r, 0, n * BLOCK:].any()
+        if n:
+            assert (pages[r, n:] == pages[r, n - 1]).all()
+
+
+def test_a_walk_that_is_not_whole_groups_is_refused(monkeypatch):
+    """A table the kernel's group does not divide would leave a group's
+    copies short of what its one wait draws: refused where it is traced."""
+    monkeypatch.setattr(mla_k, "_WALK_GROUP_TOKENS", _W_GROUP)
+    q, pool, tables, positions, lens, keep, rkv, *_ = _walk_case(
+        "ends_mid_group")
+    walk = mla_k.selected_walk(keep, tables, positions[:, 0], lens, BLOCK)
+    short = mla_k.SelectedWalk(walk.pages[:, :-3], walk.keep[..., :-3 * BLOCK],
+                               walk.count)
+    with pytest.raises(ValueError, match="whole groups"):
+        mla_k.latent_paged_attention.__wrapped__(
             q, pool, jnp.int32(1), tables, positions, lens, BLOCK,
-            scale=scale, latent=rkv, decode=True, interpret=True, **kw)
-        assert np.abs(np.asarray(got - want)).max() < 1e-5
-        assert np.abs(np.asarray(got[1])).max() == 0
+            scale=0.17, latent=rkv, decode=True, interpret=True, walk=short)
 
 
 def test_the_round_kernels_mask_each_query_by_its_own_selection(monkeypatch):
@@ -439,6 +527,7 @@ def test_forward_chunk_through_the_kernels_matches_the_xla_path(monkeypatch):
         pl, "pallas_call",
         lambda *a, **kw: real(*a, **{**kw, "interpret": True}))
     monkeypatch.setattr(mla_k, "_GROUP_TOKENS", 16)
+    monkeypatch.setattr(mla_k, "_WALK_GROUP_TOKENS", 32)
     mc = get_model_config(MODEL, dtype="float32", kv_lora_rank=128)
     params = llama.init_params(mc, jax.random.PRNGKey(2), jnp.float32)
     tables = _tables(2, 16)

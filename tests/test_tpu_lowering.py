@@ -626,33 +626,46 @@ PANGU = "openpangu-ultra-moe-718b-ep16"
 PANGU_CTX = 4096        # the cell serves 4096 positions
 
 
+def _latent_kernel_lowered(v5e, model, ctx, s, selected):
+    """The absorbed kernel alone at a configuration's served shape, ``s``
+    queries a row, under a selection or not."""
+    from distributed_gpu_inference_tpu.ops import mla_attention_pallas as mk
+
+    cfg = get_model_config(model)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    w, m = mla.pool_width(cfg), ctx // 16
+    # the function under ``jit``: the jitted entry would hand back a trace
+    # made at another group width
+    fn = functools.partial(
+        mk.latent_paged_attention.__wrapped__, block_size=16,
+        scale=cfg.qk_head_dim ** -0.5, latent=cfg.kv_lora_rank,
+        decode=s == 1)
+    return jax.jit(fn).lower(
+        sds((BATCH, s, cfg.num_heads, w), jnp.bfloat16),
+        sds((cfg.num_cache_layers, 1 + BATCH * m, 16, w), jnp.bfloat16),
+        sds((), jnp.int32), sds((BATCH, m), jnp.int32),
+        sds((BATCH, s), jnp.int32), sds((BATCH,), jnp.int32),
+        keep=sds((BATCH, s, ctx), jnp.float32) if selected else None)
+
+
 @pytest.mark.parametrize("s", [1, 16, 256])
 def test_latent_kernels_compile(v5e, s):
     """The absorbed kernel (128 heads against 640-lane pool rows) and the
     one-pool page write, at the served geometry: a scan step, a narrow and
     a full rectangle."""
-    from distributed_gpu_inference_tpu.models import mla
     from distributed_gpu_inference_tpu.ops import mla_attention_pallas as mk
 
     cfg = get_model_config(PANGU)
     sds = _on(SingleDeviceSharding(v5e[0]))
-    w, blocks = mla.pool_width(cfg), 1 + 12 * (PANGU_CTX // 16)
+    w, blocks = mla.pool_width(cfg), 1 + BATCH * (PANGU_CTX // 16)
     pool = sds((cfg.num_layers, blocks, 16, w), jnp.bfloat16)
     tables = sds((BATCH, PANGU_CTX // 16), jnp.int32)
-
-    def attend(q, pool, layer, tables, pos, lens):
-        return mk.latent_paged_attention(
-            q, pool, layer, tables, pos, lens, 16, scale=cfg.head_dim ** -0.5,
-            latent=cfg.kv_lora_rank, decode=s == 1)
 
     def write(rows, pool, layer, tables, pos):
         plan = page_write_plan(tables, pos, 16, page_bytes=16 * w * 2)
         return mk.write_latent_pages_in_place(rows, pool, layer, plan)
 
-    lowered = jax.jit(attend).lower(
-        sds((BATCH, s, cfg.num_heads, w), jnp.bfloat16), pool,
-        sds((), jnp.int32), tables, sds((BATCH, s), jnp.int32),
-        sds((BATCH,), jnp.int32))
+    lowered = _latent_kernel_lowered(v5e, PANGU, PANGU_CTX, s, False)
     assert _kernels(lowered) == {
         "dgi_mla_decode" if s == 1 else "dgi_mla_ragged"}
     lowered.compile()
@@ -1013,6 +1026,54 @@ def test_mixed_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch, tp,
 
 GLM = "glm-5.2-ep16-9l"
 GLM_CTX = 24576
+
+
+def test_selected_latent_step_waits_once_a_slot_at_any_group_width(
+        v5e, monkeypatch):
+    """``dgi_mla_decode_selected`` at GLM-5.2's served shape (8 rows of
+    24,576 positions, 64 heads, 640-lane rows) compiles at a narrow group
+    and at the served one with the SAME Mosaic text but for the width: one
+    wait a slot a site, and page starts that sit in a rolled loop, so a
+    wider group is no more to trace and lower at every start of a worker."""
+    from distributed_gpu_inference_tpu.ops import mla_attention_pallas as mk
+
+    assert mk._WALK_GROUP_TOKENS == 2048
+    waits, starts = [], []
+    for tokens in (512, 2048):
+        monkeypatch.setattr(mk, "_WALK_GROUP_TOKENS", tokens)
+        lowered = _latent_kernel_lowered(v5e, GLM, GLM_CTX, 1, True)
+        assert _kernels(lowered) == {"dgi_mla_decode_selected"}
+        lowered.compile()
+        text = _mosaic_text(lowered)
+        assert f"memref<2x{tokens // 16}x16x640xbf16" in text
+        waits.append(text.count("tpu.wait_dma2"))
+        starts.append(text.count("tpu.enqueue_dma"))
+    # the group in hand; the first group of all and the next group's
+    assert waits == [1, 1], (waits, starts)
+    assert starts == [2 * mk._SELECTED_UNROLL] * 2, (waits, starts)
+
+
+@pytest.mark.parametrize("model,s,selected,name", [
+    (PANGU, 1, False, "dgi_mla_decode"),
+    (PANGU, 256, False, "dgi_mla_ragged"),
+    (GLM, 16, True, "dgi_mla_ragged_selected"),
+], ids=["step", "round", "round-selected"])
+def test_the_other_latent_walks_keep_a_wait_and_a_start_a_page(
+        v5e, model, s, selected, name):
+    """The forms the scan step's selected walk shares its body with, at
+    their served shapes: one wait and one start a page of a 32-page group at
+    each site, per-page semaphores, as before that walk was given a form of
+    its own. Three configurations trace this body in every graph at every
+    start of a worker: a form for one of them is a static branch the others
+    never trace (PERF.md section 6, PR 53)."""
+    ctx = PANGU_CTX if model == PANGU else GLM_CTX
+    lowered = _latent_kernel_lowered(v5e, model, ctx, s, selected)
+    assert _kernels(lowered) == {name}
+    text = _mosaic_text(lowered)
+    assert "memref<2x32x16x640xbf16" in text
+    assert "memref<2x32x!tpu.dma_semaphore" in text
+    assert text.count("tpu.wait_dma2") == 32
+    assert text.count("tpu.enqueue_dma") == 2 * 32
 
 
 @pytest.mark.parametrize("tp,s", [(None, 1), (264, 256), (2048, 256)],
