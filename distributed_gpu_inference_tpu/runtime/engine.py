@@ -22,14 +22,18 @@ bucket; decode compiles once per engine.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import json
+import logging
 import time
 import uuid
 import zlib
 from dataclasses import dataclass, field, replace
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (
+    Any, Dict, Iterator, List, Optional, Sequence, Tuple, Union,
+)
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +63,8 @@ from distributed_gpu_inference_tpu.utils.data_structures import (
     InferenceResponse,
     SamplingParams,
 )
+
+log = logging.getLogger(__name__)
 
 MAX_STOP_IDS = 4
 _COPY_BUCKETS = (1, 2, 4, 8, 16, 32)
@@ -476,363 +482,394 @@ class TPUEngine:
             get_model_config(model_cfg) if isinstance(model_cfg, str) else model_cfg
         )
         self.cfg = engine_cfg or EngineConfig()
-        self.dtype = jnp.dtype(self.cfg.dtype)
-        self.kv_dtype = _resolve_kv_dtype(self.cfg.kv_cache_dtype, self.dtype)
-        if (
-            self.kv_dtype.itemsize == 1
-            and self.cfg.block_size % 32 != 0
-            and jax.default_backend() == "tpu"
-        ):
-            # byte-dtype pool pages tile (32, 128) on TPU: a narrower block
-            # would make page slices non-DMA-able in the Pallas kernel
-            raise ValueError(
-                f"kv_cache_dtype={self.cfg.kv_cache_dtype!r} needs "
-                f"block_size % 32 == 0 on TPU, got {self.cfg.block_size}"
-            )
-        # int8 KV composes with meshes AND spill tiers since round 5:
-        # scale pools shard with their data pools (replicated under TP —
-        # no head axis to shard; block-axis-sharded under seq —
-        # parallel/sharding.py kv_scale_sharding*), the shard_map seq ops
-        # dequantize their local page shards, the quantize amax reduce
-        # over sharded heads lowers to an all-reduce-max (scales stay
-        # bit-identical to a single-chip engine), and evicted pages spill
-        # int8 codes + scale pages as an atomic pair through L2/L3
-        # (runtime/kv_cache.py store_spilled/_probe_spill).
-        self.mesh = mesh
-        self._seq_axis = 1
-        if self.model_cfg.latent_kv:
-            self._refuse_latent(mesh)
-        if self.model_cfg.index_topk:
-            self._refuse_indexed(mesh)
-        if self.model_cfg.described_per_layer:
-            self._refuse_per_layer(mesh)
-        if mesh is not None:
-            sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-            tp = sizes.get("model", 1)
-            self._seq_axis = sizes.get("seq", 1)
-        if mesh is not None:
-            # general mesh validations (ANY mesh, not just seq-sharded)
-            if sizes.get("data", 1) > 1:
-                raise ValueError(
-                    "engine mesh must not carry a data axis (DP is "
-                    "request-level at the scheduler); got "
-                    f"data={sizes['data']}"
-                )
-            if self.model_cfg.num_kv_heads % max(tp, 1):
-                raise ValueError(
-                    f"num_kv_heads {self.model_cfg.num_kv_heads} not "
-                    f"divisible by model axis {tp}"
-                )
-            if self.model_cfg.num_experts and \
-                    self.model_cfg.num_experts % max(tp, 1):
-                raise ValueError(
-                    f"num_experts {self.model_cfg.num_experts} not "
-                    f"divisible by model axis {tp} (EP shards experts)"
-                )
-        if self.cfg.speculative is not None:
-            self.cfg.speculative.validate(self.cfg)
-            if mesh is not None:
-                # the draft head would need its own sharding rules and the
-                # verify chunk its own partitioning; keep the mode
-                # single-chip until that exists
-                raise ValueError(
-                    "speculative decode mode is single-chip: drop the mesh "
-                    "or EngineConfig.speculative"
-                )
-        if self.cfg.kv_seq_sharded:
-            if self._seq_axis <= 1:
-                raise ValueError(
-                    "kv_seq_sharded needs a mesh with a seq axis > 1"
-                )
-            # prefix caching and chunked/continuation admission compose with
-            # sharded pools since round 4: continuation chunks attend prior
-            # context through the shard_map partial-softmax chunk op
-            # (parallel/ring_attention.seq_parallel_paged_chunk_attention);
-            # only sliding-window models stay fenced (below).
-            if self.cfg.resolved_num_blocks() % self._seq_axis:
-                # round the pool UP so the block axis shards evenly
-                blocks = self.cfg.resolved_num_blocks()
-                self.cfg.num_blocks = (
-                    -(-blocks // self._seq_axis) * self._seq_axis
-                )
-        if params is not None:
-            self.params = quantize_params(params, self.cfg.quantization)
-            if mesh is not None:
-                from distributed_gpu_inference_tpu.parallel import sharding as _sh
-
-                self.params = _sh.shard_params(self.params, mesh)
-        else:
-            self.params = self._load_params(checkpoint_path, seed)
-        self.num_blocks = self.cfg.resolved_num_blocks()
-        # a hybrid model's linear-attention layers keep one state row a
-        # slot beside the pages: its size follows from max_batch_size
-        self._state_rows = (
-            self.cfg.max_batch_size if self.model_cfg.num_kda_layers else 0)
-        # pages per layer kind: the sliding layers' pool follows from the
-        # slots and the window, eight windows a slot (a live window with
-        # the piece or the scan horizon being written, a retained prefix
-        # end, what a reply releases before the next hit touches it, and a
-        # third to spare), never more than the full kind's
-        self._window_blocks = 0
-        if self.model_cfg.mixed_attention:
-            self._window_blocks = min(
-                self.num_blocks,
-                1 + self.cfg.max_batch_size * 8 * -(
-                    -self.model_cfg.sliding_window // self.cfg.block_size))
-        self.kv = self._init_kv()
-        # storage for a scan's index keys in context order (a model with an
-        # indexer whose tables can pass topk): a scan of several steps takes
-        # it beside the pools, fills it from the pool and hands it back
-        # (``_scan_kv``). It is never among ``self.kv``: what it holds is
-        # one call's, derived from the pool at every call, and no owner of
-        # pages learns of it. It is kept from call to call because an array
-        # of its size (403 MB at 8 layers x 8 rows x 24,576 positions)
-        # allocated anew inside every scan stalled the device for 1.7-5 s
-        # every few hundred scans (PERF.md section 6, PR 47).
-        self._scan_keys: Optional[jax.Array] = None
-        if self.model_cfg.index_topk:
-            from distributed_gpu_inference_tpu.ops import index_select
-
-            shape = index_select.scan_keys_shape(
-                self.kv[llama.INDEX_KEYS].shape, self.cfg.max_batch_size,
-                self.cfg.max_blocks_per_seq, self.model_cfg.index_topk)
-            if shape is not None:
-                self._scan_keys = jnp.zeros(
-                    shape, self.kv[llama.INDEX_KEYS].dtype)
-        host_store = (
-            HostKVStore(self.cfg.spill_host_blocks)
-            if self.cfg.spill_host_blocks > 0 else None
-        )
-        spill = host_store is not None or self.cfg.spill_remote_store is not None
-        self.manager = PagedKVCacheManager(
-            self.num_blocks,
-            self.cfg.block_size,
-            enable_prefix_cache=self.cfg.enable_prefix_cache,
-            host_store=host_store,
-            remote_store=self.cfg.spill_remote_store,
-            spill_on_evict=spill,
-            kv_dtype=np.dtype(self.kv_dtype),
-            state_rows=self._state_rows,
-            window_blocks=self._window_blocks,
-            window=self.model_cfg.sliding_window
-            if self._window_blocks else None,
-        )
-        self.eos_token_id = eos_token_id
-
-        b, m = self.cfg.max_batch_size, self.cfg.max_blocks_per_seq
-        self.slots: List[Optional[_Slot]] = [None] * b
-        # a block table a layer kind, side by side in a row: the window
-        # kind's columns start at ``_window_col``
-        self._window_col = m if self._window_blocks else 0
-        self._table_cols = m + self._window_col
-        self._block_tables = np.zeros((b, self._table_cols), dtype=np.int32)
-        self._kv_lens = np.zeros((b,), dtype=np.int32)
-        self._last_tokens = np.zeros((b,), dtype=np.int32)
-        self._temps = np.zeros((b,), dtype=np.float32)
-        self._top_ks = np.zeros((b,), dtype=np.int32)
-        self._top_ps = np.ones((b,), dtype=np.float32)
-        self._stop_ids = np.full((b, MAX_STOP_IDS), -1, dtype=np.int32)
-        # One PRNG key per slot: a seeded request's random stream is
-        # independent of which other requests share the batch. Exact token
-        # reproduction additionally requires identical logits — i.e. the
-        # same dtype and the same prefill split (prefix-cache hits change
-        # the suffix bucket, and bf16 reduction order can flip low bits);
-        # greedy requests are robust to those effects, sampled ones are
-        # reproducible given equal numerics.
-        self._slot_keys = np.zeros((b, 2), dtype=np.uint32)
-        self._host_rng = np.random.default_rng(seed + 0x5EED)
-
-        # Device-resident core slot state (sampling params, PRNG keys, stop
-        # ids, last token, committed length). The host numpy mirrors above
-        # stay authoritative for scheduling; their device copies are uploaded
-        # ONLY when a host-initiated change lands (admission, adopt, error
-        # recovery) — never per decode round: every host→device transfer is
-        # a dispatch of its own in front of the round's.
-        self._dev_core: Optional[Dict[str, jax.Array]] = None
-        self._core_dirty = True
-        # The one dispatch whose tokens are still on the device: a scan
-        # (``decode_multi`` with ``ahead``) or the ragged round that went
-        # out behind one (``ragged_round``). The host mirrors lag by it
-        # until ``collect_scan``, which every other entry that reads or
-        # writes slots, pool or mirrors calls first. The device orders its
-        # own work; only the host's view lags.
-        self._unread: Optional[Union[_UnreadScan, _UnreadRound]] = None
-        # whether the scan read last was still running when the host came
-        # for it: then the chip had work up to the read, and the time since
-        # the read before is that scan's own
-        self.scan_read_running = True
-        # the speculative rounds replay their bookkeeping on the host
-        # between dispatches: nothing of theirs can go out ahead
-        self.supports_scan_ahead = self.cfg.speculative is None
-
-        # integrated speculative decoding: EAGLE-style draft head weights +
-        # per-slot last-verified hidden state (device-resident between
-        # rounds, like _dev_core). The hidden starts at zeros for a fresh
-        # slot — the first step then drafts garbage and accepts ~nothing,
-        # which is CORRECT (emission is target-verified regardless of draft
-        # quality) and seeds the real hidden from that verify pass.
-        self._draft_params: Optional[Dict[str, jax.Array]] = None
-        self._dev_spec_h: Optional[jax.Array] = None
-        self._spec_h_zero: set = set()
-        if self.cfg.speculative is not None:
-            sp = self.cfg.speculative
-            self._draft_params = (
-                sp.draft_params if sp.draft_params is not None
-                else init_draft_params(
-                    self.model_cfg, jax.random.PRNGKey(sp.draft_seed),
-                    dtype=self.dtype,
-                )
-            )
-            # acceptance-adaptive draft depth: per-slot EMA of the
-            # ACCEPTED length (host-side — deterministic float arithmetic
-            # over integer accept counts, so same seed → same K
-            # schedule). Fresh slots start optimistic at K and converge.
-            self._spec_k_ema = np.full((b,), float(sp.num_draft_tokens))
-            # oracle-draft fractional-rate accumulator (per slot): a rate
-            # whose K-scaled target is fractional dithers deterministically
-            # (e.g. 2.4 → 2,3,2,3,2 accepted per round)
-            self._spec_oracle_acc = np.zeros((b,))
-            # test hook: set to a list and every dispatch appends its
-            # [(slot, selected_k), ...] — None (default) records nothing
-            self.spec_k_trace: Optional[List[Any]] = None
-
-        self._build_jit_fns()
-        # pending KV-pressure signal (set at step boundaries, consumed by
-        # the scheduler layer via take_pressure)
-        self._pressure: Optional[KVPressure] = None
-        self.stats: Dict[str, Any] = {
-            "requests": 0, "completed": 0, "generated_tokens": 0,
-            "prefill_tokens": 0, "prefill_calls": 0, "decode_calls": 0,
-            "preemptions": 0, "resumes": 0, "kv_pressure_events": 0,
-            "ragged_rounds": 0,
-            # of them, those dispatched behind an unread scan
-            "ragged_rounds_chained": 0,
-            # the batcher's two round calls (docs/observability.md, "Round
-            # spans and counters"): calls, what a ragged rectangle held,
-            # and the host's seconds in each phase of a round
-            "rounds": 0,
-            "ragged_positions_dispatched": 0, "ragged_positions_live": 0,
-            "round_build_s": 0.0, "round_dispatch_s": 0.0,
-            "round_readback_s": 0.0, "round_commit_s": 0.0,
-            # what of a scan's build, dispatch and commit ran while no scan
-            # of the engine's was on the device: all of it where every scan
-            # is read back by the call that made it, little where the next
-            # scan goes out ahead of the readback
-            "round_host_exposed_s": 0.0,
-            # which KV path the multi-token graphs were built with
-            # (in_place / layer_copy): a trace-time fact, from the
-            # predicate forward_chunk itself dispatches on
-            "ragged_kv_path": llama.ragged_kv_path(
-                self.model_cfg,
-                self.cfg.max_blocks_per_seq * self.cfg.block_size,
-                quantized_kv=self.kv_dtype == jnp.int8,
-                pallas=self.mesh is None,
-            ),
-            # what a cached token is: per-head K and V, or one latent;
-            # hybrid: latent pages beside a state row a sequence
-            # kv+index: K/V pages and an index key a token beside them
-            # latent+index: latent pages and an index key a token a
-            # layer that holds an indexer beside them
-            "kv_layout": "hybrid" if self._state_rows
-            else ("latent+index" if self.model_cfg.index_topk
-                  else "latent") if self.model_cfg.latent_kv
-            else "kv+index" if self.model_cfg.index_topk else "kv",
-        }
-        if self.model_cfg.index_topk:
-            # the index-key pool; what the scans' rows selected from (host
-            # arithmetic at a scan's commit: a row-step with ``c`` cached
-            # tokens attends min(c, topk) of them, and one with at most
-            # topk selects nothing); the (query, cached token) pairs the
-            # plain ragged rounds scored and kept (at a round's build)
-            self.stats.update({
-                "index_pool_bytes": int(self.kv[llama.INDEX_KEYS].nbytes),
-                "index_row_steps_scan": 0, "index_context_tokens_scan": 0,
-                "index_selected_tokens_scan": 0, "index_dense_rows_scan": 0,
-                "index_pairs_ragged": 0, "index_selected_pairs_ragged": 0,
-                # layer-gathers of index keys the scans issued: a scan of
-                # several steps lays every layer's keys out once (L), a
-                # single step once a layer (L), and one no row of which
-                # passes topk inside it not at all (host arithmetic at a
-                # scan's commit, the device's own condition)
-                "index_key_gathers_scan": 0,
-                # layer calls that computed a selection from their own
-                # indexer, and layer calls that attended the selection of
-                # the layer before them (``ModelConfig.index_kinds``:
-                # shared stays 0 where every layer holds an indexer), a
-                # scan step and a ragged round each counting its layers
-                "index_layers_scored": 0, "index_layers_shared": 0,
-            })
-            if self.model_cfg.num_experts and self.mesh is None:
-                # what the decode kernel fetched for those selections: the
-                # pages that hold a selected token, whole, a row-step, mean
-                # over the layers (each layer selects its own; counted on
-                # the device, read with the scan's tokens)
-                self.stats["index_fetched_tokens_scan"] = 0
-        if self._window_blocks:
-            # pages per layer kind. Cached tokens the scans' row-steps
-            # attended in a full layer and in a sliding one (at most the
-            # window) and the window-kind tokens their rows held (host
-            # arithmetic at a scan's commit); the (query, key) pairs inside
-            # causal reach, and inside the window, of the plain ragged
-            # rounds (at a round's build)
-            self.stats.update({
-                "kv_layout": "kv+window",
-                "window_pool_blocks": self._window_blocks,
-                "attn_row_steps_scan": 0,
-                "attn_full_context_tokens_scan": 0,
-                "attn_window_context_tokens_scan": 0,
-                "kv_window_resident_tokens_scan": 0,
-                "attn_pairs_ragged_full": 0, "attn_pairs_ragged_window": 0,
-                # the manager's, as they stood at the last admission
-                "prefix_hits_cut_by_window": 0,
-                "prefix_hit_tokens_cut_by_window": 0,
-            })
-        self._moe_names = (
-            _MOE_SHARE_COUNTERS if self.model_cfg.latent_kv
-            or self.model_cfg.held_experts is not None
-            else _MOE_COUNTERS
-        )
-        if self.model_cfg.latent_kv:
-            # cached tokens the scans' rows attended, and the row-steps they
-            # took: what the absorbed decode kernel read (host arithmetic
-            # at a scan's commit); what the absorbed kernel of the plain
-            # ragged rounds held (at a round's build): its (query, cached
-            # token) pairs, and its rows' cached tokens, a row's once
-            self.stats.update({"mla_context_tokens_scan": 0,
-                               "mla_row_steps_scan": 0,
-                               "mla_pairs_ragged": 0,
-                               "mla_context_tokens_ragged": 0})
-        if self._state_rows:
-            from distributed_gpu_inference_tpu.models import kda
-
-            # the state pool; live row x step x KDA layer of the scans (host
-            # arithmetic at a scan's commit); what the ragged rounds handed
-            # the chunk form (at a round's build; every KDA layer takes it
-            # once): live tokens, segments (a row's tokens in a round) and
-            # the 64-token chunks they are cut into
-            self.stats.update({
-                "state_pool_bytes": sum(
-                    int(self.kv[name].nbytes)
-                    for name in (kda.STATE, kda.CONV)),
-                "state_rows": self._state_rows,
-                "kda_row_steps_scan": 0, "kda_tokens_ragged": 0,
-                "kda_segments_ragged": 0, "kda_chunks_ragged": 0,
-            })
-        if self.model_cfg.num_experts:
-            # what the routed expert layers did (models/llama.py
-            # _moe_mlp), summed on the device and read back beside a
-            # round's tokens; scans and ragged rounds apart. They stay 0
-            # under a mesh, where the layer runs dense over the expert axis
-            self.stats.update({
-                f"moe_{name}_{kind}": 0
-                for kind in ("scan", "ragged") for name in self._moe_names
-            })
+        # what the start cost (docs/observability.md, "Start-up"): the
+        # seconds of each phase of the load, the instant each opened, and a
+        # row a graph ``lower_serving_graphs`` lowered. ``get_stats()``
+        # shows it with the sums; nothing after the start writes to it. The
+        # load stays in this body under its ``with``: moved into a helper,
+        # the one Python frame more cost 0.35-0.7 s of a 4 s load on the
+        # v5e's host (PERF.md section 6, PR 55)
+        self._startup: Dict[str, Any] = {"at": {}, "graphs": {}}
         self._compile_log = compile_log()
-        if self.cfg.speculative is not None:
-            self.stats.update({
-                "spec_steps": 0, "spec_slot_steps": 0, "spec_drafted": 0,
-                "spec_accepted": 0, "spec_emitted": 0,
-            })
+        with flight.phase(
+                "dgi.engine.init", self._startup, "init",
+                model=self.model_cfg.name,
+                quantization=str(self.cfg.quantization),
+                chips=1 if mesh is None else int(mesh.devices.size)):
+            self.dtype = jnp.dtype(self.cfg.dtype)
+            self.kv_dtype = _resolve_kv_dtype(self.cfg.kv_cache_dtype,
+                                              self.dtype)
+            if (
+                self.kv_dtype.itemsize == 1
+                and self.cfg.block_size % 32 != 0
+                and jax.default_backend() == "tpu"
+            ):
+                # byte-dtype pool pages tile (32, 128) on TPU: a narrower block
+                # would make page slices non-DMA-able in the Pallas kernel
+                raise ValueError(
+                    f"kv_cache_dtype={self.cfg.kv_cache_dtype!r} needs "
+                    f"block_size % 32 == 0 on TPU, got {self.cfg.block_size}"
+                )
+            # int8 KV composes with meshes AND spill tiers since round 5:
+            # scale pools shard with their data pools (replicated under TP —
+            # no head axis to shard; block-axis-sharded under seq —
+            # parallel/sharding.py kv_scale_sharding*), the shard_map seq ops
+            # dequantize their local page shards, the quantize amax reduce
+            # over sharded heads lowers to an all-reduce-max (scales stay
+            # bit-identical to a single-chip engine), and evicted pages spill
+            # int8 codes + scale pages as an atomic pair through L2/L3
+            # (runtime/kv_cache.py store_spilled/_probe_spill).
+            self.mesh = mesh
+            self._seq_axis = 1
+            if self.model_cfg.latent_kv:
+                self._refuse_latent(mesh)
+            if self.model_cfg.index_topk:
+                self._refuse_indexed(mesh)
+            if self.model_cfg.described_per_layer:
+                self._refuse_per_layer(mesh)
+            if mesh is not None:
+                sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+                tp = sizes.get("model", 1)
+                self._seq_axis = sizes.get("seq", 1)
+            if mesh is not None:
+                # general mesh validations (ANY mesh, not just seq-sharded)
+                if sizes.get("data", 1) > 1:
+                    raise ValueError(
+                        "engine mesh must not carry a data axis (DP is "
+                        "request-level at the scheduler); got "
+                        f"data={sizes['data']}"
+                    )
+                if self.model_cfg.num_kv_heads % max(tp, 1):
+                    raise ValueError(
+                        f"num_kv_heads {self.model_cfg.num_kv_heads} not "
+                        f"divisible by model axis {tp}"
+                    )
+                if self.model_cfg.num_experts and \
+                        self.model_cfg.num_experts % max(tp, 1):
+                    raise ValueError(
+                        f"num_experts {self.model_cfg.num_experts} not "
+                        f"divisible by model axis {tp} (EP shards experts)"
+                    )
+            if self.cfg.speculative is not None:
+                self.cfg.speculative.validate(self.cfg)
+                if mesh is not None:
+                    # the draft head would need its own sharding rules and the
+                    # verify chunk its own partitioning; keep the mode
+                    # single-chip until that exists
+                    raise ValueError(
+                        "speculative decode mode is single-chip: drop the "
+                        "mesh or EngineConfig.speculative"
+                    )
+            if self.cfg.kv_seq_sharded:
+                if self._seq_axis <= 1:
+                    raise ValueError(
+                        "kv_seq_sharded needs a mesh with a seq axis > 1"
+                    )
+                # prefix caching and chunked/continuation admission compose
+                # with sharded pools since round 4: continuation chunks attend
+                # prior context through the shard_map partial-softmax chunk op
+                # (parallel/ring_attention.seq_parallel_paged_chunk_attention);
+                # only sliding-window models stay fenced (below).
+                if self.cfg.resolved_num_blocks() % self._seq_axis:
+                    # round the pool UP so the block axis shards evenly
+                    blocks = self.cfg.resolved_num_blocks()
+                    self.cfg.num_blocks = (
+                        -(-blocks // self._seq_axis) * self._seq_axis
+                    )
+            with flight.phase("dgi.engine.init.params", self._startup,
+                              "params"):
+                if params is not None:
+                    self.params = quantize_params(params,
+                                                  self.cfg.quantization)
+                    if mesh is not None:
+                        from distributed_gpu_inference_tpu.parallel import (
+                            sharding as _sh,
+                        )
+
+                        self.params = _sh.shard_params(self.params, mesh)
+                else:
+                    self.params = self._load_params(checkpoint_path, seed)
+            self.num_blocks = self.cfg.resolved_num_blocks()
+            # a hybrid model's linear-attention layers keep one state row a
+            # slot beside the pages: its size follows from max_batch_size
+            self._state_rows = (
+                self.cfg.max_batch_size if self.model_cfg.num_kda_layers
+                else 0)
+            # pages per layer kind: the sliding layers' pool follows from the
+            # slots and the window, eight windows a slot (a live window with
+            # the piece or the scan horizon being written, a retained prefix
+            # end, what a reply releases before the next hit touches it, and a
+            # third to spare), never more than the full kind's
+            self._window_blocks = 0
+            if self.model_cfg.mixed_attention:
+                self._window_blocks = min(
+                    self.num_blocks,
+                    1 + self.cfg.max_batch_size * 8 * -(
+                        -self.model_cfg.sliding_window // self.cfg.block_size))
+            with flight.phase("dgi.engine.init.kv_pools", self._startup,
+                              "kv_pools"):
+                self.kv = self._init_kv()
+                # storage for a scan's index keys in context order (a model
+                # with an indexer whose tables can pass topk): a scan of
+                # several steps takes it beside the pools, fills it from the
+                # pool and hands it back (``_scan_kv``). It is never among
+                # ``self.kv``: what it holds is one call's, derived from the
+                # pool at every call, and no owner of pages learns of it. It is
+                # kept from call to call because an array of its size (403 MB
+                # at 8 layers x 8 rows x 24,576 positions) allocated anew
+                # inside every scan stalled the device for 1.7-5 s every few
+                # hundred scans (PERF.md section 6, PR 47).
+                self._scan_keys: Optional[jax.Array] = None
+                if self.model_cfg.index_topk:
+                    from distributed_gpu_inference_tpu.ops import index_select
+
+                    shape = index_select.scan_keys_shape(
+                        self.kv[llama.INDEX_KEYS].shape,
+                        self.cfg.max_batch_size, self.cfg.max_blocks_per_seq,
+                        self.model_cfg.index_topk)
+                    if shape is not None:
+                        self._scan_keys = jnp.zeros(
+                            shape, self.kv[llama.INDEX_KEYS].dtype)
+            host_store = (
+                HostKVStore(self.cfg.spill_host_blocks)
+                if self.cfg.spill_host_blocks > 0 else None
+            )
+            spill = (host_store is not None
+                     or self.cfg.spill_remote_store is not None)
+            self.manager = PagedKVCacheManager(
+                self.num_blocks,
+                self.cfg.block_size,
+                enable_prefix_cache=self.cfg.enable_prefix_cache,
+                host_store=host_store,
+                remote_store=self.cfg.spill_remote_store,
+                spill_on_evict=spill,
+                kv_dtype=np.dtype(self.kv_dtype),
+                state_rows=self._state_rows,
+                window_blocks=self._window_blocks,
+                window=self.model_cfg.sliding_window
+                if self._window_blocks else None,
+            )
+            self.eos_token_id = eos_token_id
+
+            b, m = self.cfg.max_batch_size, self.cfg.max_blocks_per_seq
+            self.slots: List[Optional[_Slot]] = [None] * b
+            # a block table a layer kind, side by side in a row: the window
+            # kind's columns start at ``_window_col``
+            self._window_col = m if self._window_blocks else 0
+            self._table_cols = m + self._window_col
+            self._block_tables = np.zeros((b, self._table_cols),
+                                          dtype=np.int32)
+            self._kv_lens = np.zeros((b,), dtype=np.int32)
+            self._last_tokens = np.zeros((b,), dtype=np.int32)
+            self._temps = np.zeros((b,), dtype=np.float32)
+            self._top_ks = np.zeros((b,), dtype=np.int32)
+            self._top_ps = np.ones((b,), dtype=np.float32)
+            self._stop_ids = np.full((b, MAX_STOP_IDS), -1, dtype=np.int32)
+            # One PRNG key per slot: a seeded request's random stream is
+            # independent of which other requests share the batch. Exact token
+            # reproduction additionally requires identical logits — i.e. the
+            # same dtype and the same prefill split (prefix-cache hits change
+            # the suffix bucket, and bf16 reduction order can flip low bits);
+            # greedy requests are robust to those effects, sampled ones are
+            # reproducible given equal numerics.
+            self._slot_keys = np.zeros((b, 2), dtype=np.uint32)
+            self._host_rng = np.random.default_rng(seed + 0x5EED)
+
+            # Device-resident core slot state (sampling params, PRNG keys,
+            # stop ids, last token, committed length). The host numpy mirrors
+            # above stay authoritative for scheduling; their device copies are
+            # uploaded ONLY when a host-initiated change lands (admission,
+            # adopt, error recovery) — never per decode round: every
+            # host→device transfer is a dispatch of its own in front of the
+            # round's.
+            self._dev_core: Optional[Dict[str, jax.Array]] = None
+            self._core_dirty = True
+            # The one dispatch whose tokens are still on the device: a scan
+            # (``decode_multi`` with ``ahead``) or the ragged round that went
+            # out behind one (``ragged_round``). The host mirrors lag by it
+            # until ``collect_scan``, which every other entry that reads or
+            # writes slots, pool or mirrors calls first. The device orders its
+            # own work; only the host's view lags.
+            self._unread: Optional[Union[_UnreadScan, _UnreadRound]] = None
+            # whether the scan read last was still running when the host came
+            # for it: then the chip had work up to the read, and the time since
+            # the read before is that scan's own
+            self.scan_read_running = True
+            # the speculative rounds replay their bookkeeping on the host
+            # between dispatches: nothing of theirs can go out ahead
+            self.supports_scan_ahead = self.cfg.speculative is None
+
+            # integrated speculative decoding: EAGLE-style draft head weights +
+            # per-slot last-verified hidden state (device-resident between
+            # rounds, like _dev_core). The hidden starts at zeros for a fresh
+            # slot — the first step then drafts garbage and accepts ~nothing,
+            # which is CORRECT (emission is target-verified regardless of draft
+            # quality) and seeds the real hidden from that verify pass.
+            self._draft_params: Optional[Dict[str, jax.Array]] = None
+            self._dev_spec_h: Optional[jax.Array] = None
+            self._spec_h_zero: set = set()
+            if self.cfg.speculative is not None:
+                sp = self.cfg.speculative
+                self._draft_params = (
+                    sp.draft_params if sp.draft_params is not None
+                    else init_draft_params(
+                        self.model_cfg, jax.random.PRNGKey(sp.draft_seed),
+                        dtype=self.dtype,
+                    )
+                )
+                # acceptance-adaptive draft depth: per-slot EMA of the
+                # ACCEPTED length (host-side — deterministic float arithmetic
+                # over integer accept counts, so same seed → same K
+                # schedule). Fresh slots start optimistic at K and converge.
+                self._spec_k_ema = np.full((b,), float(sp.num_draft_tokens))
+                # oracle-draft fractional-rate accumulator (per slot): a rate
+                # whose K-scaled target is fractional dithers deterministically
+                # (e.g. 2.4 → 2,3,2,3,2 accepted per round)
+                self._spec_oracle_acc = np.zeros((b,))
+                # test hook: set to a list and every dispatch appends its
+                # [(slot, selected_k), ...] — None (default) records nothing
+                self.spec_k_trace: Optional[List[Any]] = None
+
+            with flight.phase("dgi.engine.init.jit_fns", self._startup,
+                              "jit_fns"):
+                self._build_jit_fns()
+            # pending KV-pressure signal (set at step boundaries, consumed by
+            # the scheduler layer via take_pressure)
+            self._pressure: Optional[KVPressure] = None
+            self.stats: Dict[str, Any] = {
+                "startup": self._startup,
+                "requests": 0, "completed": 0, "generated_tokens": 0,
+                "prefill_tokens": 0, "prefill_calls": 0, "decode_calls": 0,
+                "preemptions": 0, "resumes": 0, "kv_pressure_events": 0,
+                "ragged_rounds": 0,
+                # of them, those dispatched behind an unread scan
+                "ragged_rounds_chained": 0,
+                # the batcher's two round calls (docs/observability.md, "Round
+                # spans and counters"): calls, what a ragged rectangle held,
+                # and the host's seconds in each phase of a round
+                "rounds": 0,
+                "ragged_positions_dispatched": 0, "ragged_positions_live": 0,
+                "round_build_s": 0.0, "round_dispatch_s": 0.0,
+                "round_readback_s": 0.0, "round_commit_s": 0.0,
+                # what of a scan's build, dispatch and commit ran while no scan
+                # of the engine's was on the device: all of it where every scan
+                # is read back by the call that made it, little where the next
+                # scan goes out ahead of the readback
+                "round_host_exposed_s": 0.0,
+                # which KV path the multi-token graphs were built with
+                # (in_place / layer_copy): a trace-time fact, from the
+                # predicate forward_chunk itself dispatches on
+                "ragged_kv_path": llama.ragged_kv_path(
+                    self.model_cfg,
+                    self.cfg.max_blocks_per_seq * self.cfg.block_size,
+                    quantized_kv=self.kv_dtype == jnp.int8,
+                    pallas=self.mesh is None,
+                ),
+                # what a cached token is: per-head K and V, or one latent;
+                # hybrid: latent pages beside a state row a sequence
+                # kv+index: K/V pages and an index key a token beside them
+                # latent+index: latent pages and an index key a token a
+                # layer that holds an indexer beside them
+                "kv_layout": "hybrid" if self._state_rows
+                else ("latent+index" if self.model_cfg.index_topk
+                      else "latent") if self.model_cfg.latent_kv
+                else "kv+index" if self.model_cfg.index_topk else "kv",
+            }
+            if self.model_cfg.index_topk:
+                # the index-key pool; what the scans' rows selected from (host
+                # arithmetic at a scan's commit: a row-step with ``c`` cached
+                # tokens attends min(c, topk) of them, and one with at most
+                # topk selects nothing); the (query, cached token) pairs the
+                # plain ragged rounds scored and kept (at a round's build)
+                self.stats.update({
+                    "index_pool_bytes": int(self.kv[llama.INDEX_KEYS].nbytes),
+                    "index_row_steps_scan": 0, "index_context_tokens_scan": 0,
+                    "index_selected_tokens_scan": 0,
+                    "index_dense_rows_scan": 0,
+                    "index_pairs_ragged": 0, "index_selected_pairs_ragged": 0,
+                    # layer-gathers of index keys the scans issued: a scan of
+                    # several steps lays every layer's keys out once (L), a
+                    # single step once a layer (L), and one no row of which
+                    # passes topk inside it not at all (host arithmetic at a
+                    # scan's commit, the device's own condition)
+                    "index_key_gathers_scan": 0,
+                    # layer calls that computed a selection from their own
+                    # indexer, and layer calls that attended the selection of
+                    # the layer before them (``ModelConfig.index_kinds``:
+                    # shared stays 0 where every layer holds an indexer), a
+                    # scan step and a ragged round each counting its layers
+                    "index_layers_scored": 0, "index_layers_shared": 0,
+                })
+                if self.model_cfg.num_experts and self.mesh is None:
+                    # what the decode kernel fetched for those selections: the
+                    # pages that hold a selected token, whole, a row-step, mean
+                    # over the layers (each layer selects its own; counted on
+                    # the device, read with the scan's tokens)
+                    self.stats["index_fetched_tokens_scan"] = 0
+            if self._window_blocks:
+                # pages per layer kind. Cached tokens the scans' row-steps
+                # attended in a full layer and in a sliding one (at most the
+                # window) and the window-kind tokens their rows held (host
+                # arithmetic at a scan's commit); the (query, key) pairs inside
+                # causal reach, and inside the window, of the plain ragged
+                # rounds (at a round's build)
+                self.stats.update({
+                    "kv_layout": "kv+window",
+                    "window_pool_blocks": self._window_blocks,
+                    "attn_row_steps_scan": 0,
+                    "attn_full_context_tokens_scan": 0,
+                    "attn_window_context_tokens_scan": 0,
+                    "kv_window_resident_tokens_scan": 0,
+                    "attn_pairs_ragged_full": 0, "attn_pairs_ragged_window": 0,
+                    # the manager's, as they stood at the last admission
+                    "prefix_hits_cut_by_window": 0,
+                    "prefix_hit_tokens_cut_by_window": 0,
+                })
+            self._moe_names = (
+                _MOE_SHARE_COUNTERS if self.model_cfg.latent_kv
+                or self.model_cfg.held_experts is not None
+                else _MOE_COUNTERS
+            )
+            if self.model_cfg.latent_kv:
+                # cached tokens the scans' rows attended, and the row-steps
+                # they took: what the absorbed decode kernel read (arithmetic
+                # at a scan's commit); what the absorbed kernel of the plain
+                # ragged rounds held (at a round's build): its (query, cached
+                # token) pairs, and its rows' cached tokens, a row's once
+                self.stats.update({"mla_context_tokens_scan": 0,
+                                   "mla_row_steps_scan": 0,
+                                   "mla_pairs_ragged": 0,
+                                   "mla_context_tokens_ragged": 0})
+            if self._state_rows:
+                from distributed_gpu_inference_tpu.models import kda
+
+                # the state pool; live row x step x KDA layer of the scans
+                # (arithmetic at a scan's commit); what the ragged rounds
+                # handed the chunk form (at a round's build; every KDA layer
+                # takes it once): live tokens, segments (a row's tokens in a
+                # round) and the 64-token chunks they are cut into
+                self.stats.update({
+                    "state_pool_bytes": sum(
+                        int(self.kv[name].nbytes)
+                        for name in (kda.STATE, kda.CONV)),
+                    "state_rows": self._state_rows,
+                    "kda_row_steps_scan": 0, "kda_tokens_ragged": 0,
+                    "kda_segments_ragged": 0, "kda_chunks_ragged": 0,
+                })
+            if self.model_cfg.num_experts:
+                # what the routed expert layers did (models/llama.py
+                # _moe_mlp), summed on the device and read back beside a
+                # round's tokens; scans and ragged rounds apart. They stay 0
+                # under a mesh, where the layer runs dense over the expert axis
+                self.stats.update({
+                    f"moe_{name}_{kind}": 0
+                    for kind in ("scan", "ragged") for name in self._moe_names
+                })
+            if self.cfg.speculative is not None:
+                self.stats.update({
+                    "spec_steps": 0, "spec_slot_steps": 0, "spec_drafted": 0,
+                    "spec_accepted": 0, "spec_emitted": 0,
+                })
 
     def _refuse_latent(self, mesh: Optional[Any]) -> None:
         """A latent-attention model's cache is one pool of latent pages
@@ -1982,13 +2019,16 @@ class TPUEngine:
         )
         out: Dict[str, Any] = {}
         for t in decode_steps:
-            out[f"decode_multi[T={t}]"] = self._decode_multi_fn.lower(
-                self.params, self._scan_kv(int(t)), core, tables, active,
-                budgets, int(t), "greedy",
-            )
+            name = f"decode_multi[T={t}]"
+            with self._lowering(name, "decode_multi", steps=int(t)):
+                out[name] = self._decode_multi_fn.lower(
+                    self.params, self._scan_kv(int(t)), core, tables, active,
+                    budgets, int(t), "greedy",
+                )
         if out:
-            out["chain_sched"] = self._chain_sched_fn.lower(core, budgets)
-            self._chain_sched_fn(core, budgets)
+            with self._lowering("chain_sched", "chain_sched"):
+                out["chain_sched"] = self._chain_sched_fn.lower(core, budgets)
+                self._chain_sched_fn(core, budgets)
         most, below = b * max(map(int, ragged_widths), default=0), 0
         chains = bool(out)      # a round goes out behind a scan, if any
         for tp in self._ragged_ladder():
@@ -1996,24 +2036,57 @@ class TPUEngine:
                 break
             tok_d, lens_d = self._round_batch(
                 np.zeros((4, tp), np.int32), np.zeros((2, b), np.int32))
-            out[f"ragged_round[Tp={tp}]"] = self._ragged_round_fn.lower(
-                self.params, self.kv, tok_d, tables, lens_d, core, budgets,
-                "greedy", self._ragged_shape(tp)[1],
-            )
+            name = f"ragged_round[Tp={tp}]"
+            with self._lowering(name, "ragged_round", positions=tp):
+                out[name] = self._ragged_round_fn.lower(
+                    self.params, self.kv, tok_d, tables, lens_d, core,
+                    budgets, "greedy", self._ragged_shape(tp)[1],
+                )
             if chains:
                 patch = (core, np.zeros((4, tp), np.int32),
                          np.zeros((2, b), np.int32), budgets,
                          np.zeros((b, 2), np.int32))
-                out[f"chain_round[Tp={tp}]"] = \
-                    self._chain_round_fn.lower(*patch)
-                self._chain_round_fn(*patch)
+                name = f"chain_round[Tp={tp}]"
+                with self._lowering(name, "chain_round", positions=tp):
+                    out[name] = self._chain_round_fn.lower(*patch)
+                    self._chain_round_fn(*patch)
             below = tp
         if chains and below:
             ci, cf = self._pack_core()
             keep = np.zeros((b,), bool)
-            out["merge_core"] = self._merge_core_fn.lower(core, ci, cf, keep)
-            self._merge_core_fn(core, ci, cf, keep)
+            with self._lowering("merge_core", "merge_core"):
+                out["merge_core"] = self._merge_core_fn.lower(
+                    core, ci, cf, keep)
+                self._merge_core_fn(core, ci, cf, keep)
+        st = self.get_stats()["startup"]
+        log.info("start-up: init %.2fs (params %.2f, kv pools %.2f, jitted "
+                 "functions %.2f); %d graphs lowered: trace %.2fs, lower "
+                 "%.2fs, backend %.2fs", st["init_s"], st["params_s"],
+                 st["kv_pools_s"], st["jit_fns_s"], len(st["graphs"]),
+                 st["graphs_trace_s"], st["graphs_lower_s"],
+                 st["graphs_backend_s"])
         return out
+
+    @contextlib.contextmanager
+    def _lowering(self, graph: str, kind: str, **attrs: int
+                  ) -> Iterator[None]:
+        """The block that lowers ``graph`` (and runs it, a small program):
+        a ``dgi.engine.lower`` span, the compile log's stage events of the
+        thread labelled with the graph while it is open, and on exit the
+        graph's row of ``stats["startup"]["graphs"]``: ``wall_s`` and, from
+        the labelled events, ``trace_s``, ``lower_s``, ``backend_s``. A
+        ``with`` in the caller's body and no frame round what it times."""
+        row = self._compile_log.label(graph)
+        try:
+            with flight.span("dgi.engine.lower", row, "wall_s", graph=graph,
+                             kind=kind, **attrs):
+                yield
+        finally:
+            self._compile_log.unlabel()
+            self._startup["graphs"][graph] = row
+            log.info("start-up: %s lowered in %.2fs (trace %.2f, lower "
+                     "%.2f, backend %.2f)", graph, row["wall_s"],
+                     row["trace_s"], row["lower_s"], row["backend_s"])
 
     def _scan_kv(self, num_steps: int) -> llama.KVPools:
         """The pools as a scan of ``num_steps`` steps takes them: a scan of
@@ -4564,10 +4637,29 @@ class TPUEngine:
             for name in ("state_binds", "prefix_hits_without_state"):
                 out[name] = out["kv_cache"][name]
         out["active_slots"] = self.num_active
-        # XLA compile requests of the PROCESS (one log for all engines): a
-        # worker with no warm-up compiles inside requests, and this shows it
-        out["compiles"] = self._compile_log.count
-        out["compile_s"] = self._compile_log.seconds
+        # compiles of the PROCESS (one log for all engines), by stage: the
+        # XLA compile requests, those of them that missed the persistent
+        # cache, and the seconds tracing and lowering. A worker with no
+        # warm-up compiles inside requests, and this shows it
+        compiles = self._compile_log
+        out["compiles"] = compiles.count
+        out["compile_s"] = compiles.seconds
+        out["compile_trace_s"] = compiles.trace_s
+        out["compile_lower_s"] = compiles.lower_s
+        out["compile_misses"] = compiles.misses
+        # the start, as the spans of the load and of lower_serving_graphs
+        # left it (a copy: a reader keeps what it read, and the heartbeat
+        # reads while the graphs are lowered), the graphs' sums, and the
+        # process's cache misses as they stand now
+        graphs = {name: dict(row)
+                  for name, row in list(self._startup["graphs"].items())}
+        out["startup"] = {
+            **self._startup, "at": dict(self._startup["at"]),
+            "graphs": graphs, "compile_misses": compiles.misses,
+            **{f"graphs_{stage}_s": sum(g[f"{stage}_s"]
+                                        for g in graphs.values())
+               for stage in ("trace", "lower", "backend")},
+        }
         if self.cfg.speculative is not None:
             drafted = out.get("spec_drafted", 0)
             slot_steps = out.get("spec_slot_steps", 0)

@@ -217,6 +217,46 @@ class span:
             self._note.__exit__(*exc)
 
 
+# the seconds of a start that reach ``/metrics``: the key of
+# ``TPUEngine.get_stats()["startup"]`` (less its ``_s``) and the ``phase``
+# label it has in ``worker_startup_seconds``
+STARTUP_PHASES = {
+    "init": "init", "params": "params", "kv_pools": "kv_pools",
+    "jit_fns": "jit_fns", "load_model": "load_model",
+    "graphs_trace": "graphs_trace", "graphs_lower": "graphs_lower",
+    "graphs_backend": "graphs_backend", "worker_ready": "ready",
+}
+
+
+class phase(span):
+    """``with phase("dgi.engine.init.params", startup, "params", **attrs):``
+
+    A span of a start (docs/observability.md, "Start-up"): the seconds go
+    to ``startup["<phase>_s"]`` and the instant it opened, as
+    ``time.monotonic()``, to ``startup["at"]["<phase>"]``, so that the
+    phases of one start can be laid beside whatever else reads that
+    clock. A start has a few dozen of them; they are always on."""
+
+    __slots__ = ("_phase",)
+
+    def __init__(self, name: str, startup: Dict[str, Any], phase: str,
+                 **attrs: Any) -> None:
+        super().__init__(name, startup, phase + "_s", **attrs)
+        self._phase = phase
+
+    def __enter__(self) -> "phase":
+        self._stats.setdefault("at", {})[self._phase] = time.monotonic()
+        return super().__enter__()
+
+
+def adopt_phases(startup: Dict[str, Any], outer: Dict[str, Any]) -> None:
+    """Put the phases a caller timed round an engine's load (``outer``: the
+    engine did not exist when the first of them opened) beside the
+    engine's own in ``startup``."""
+    startup.setdefault("at", {}).update(outer.get("at", {}))
+    startup.update({k: v for k, v in outer.items() if k != "at"})
+
+
 def _safe_attrs(attrs: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     """JSON-safe scalar attrs only — the wire rides job results and
     heartbeats, and one exotic value must not poison either channel."""

@@ -24,6 +24,8 @@ import logging
 import time
 from typing import Any, Dict, Optional
 
+from ..runtime.flight import STARTUP_PHASES
+
 try:
     from prometheus_client import (
         CollectorRegistry,
@@ -46,6 +48,11 @@ except Exception:  # pragma: no cover
 # worker-side phases (queue wait on an idle batcher, a local handoff),
 # stretching to multi-minute long-context e2e. Module-level so tests and
 # dashboards share one source of truth.
+# the heartbeat's seconds of the worker's compiles, by the ``stage`` each
+# has in ``worker_compile_seconds_total``
+_COMPILE_STAGES = {"compile_s": "backend", "compile_trace_s": "trace",
+                   "compile_lower_s": "lower"}
+
 PHASE_LATENCY_BUCKETS = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0,
@@ -97,7 +104,8 @@ class Metrics:
                 "direct_sse_events", "direct_token_egress_seconds",
                 "direct_egress_stalls", "direct_admit_seconds",
                 "engine_round_seconds", "worker_compiles",
-                "worker_compile_seconds",
+                "worker_compile_seconds", "worker_compile_misses",
+                "worker_startup_seconds",
                 "prefix_route_hits", "prefix_route_spillover",
                 "prefix_summary_entries", "prefix_summary_age",
                 "heartbeat_payload_rejected",
@@ -383,8 +391,25 @@ class Metrics:
             registry=r)
         self.worker_compile_seconds = Counter(
             "worker_compile_seconds_total",
-            "Seconds of the worker's XLA compile requests", ["worker"],
-            registry=r)
+            "Seconds the worker's process spent compiling, by stage (trace "
+            "= a jitted function to a jaxpr, lower = the jaxpr to MLIR, "
+            "backend = the XLA compile requests, cache retrievals among "
+            "them)", ["worker", "stage"], registry=r)
+        # a rise after READY, like worker_compiles_total's, and what the
+        # request that met it paid: the compiler, not a cache retrieval
+        self.worker_compile_misses = Counter(
+            "worker_compile_misses_total",
+            "XLA compile requests of the worker's process that missed the "
+            "persistent compile cache", ["worker"], registry=r)
+        # what the worker's last start cost; graphs_backend high on a
+        # restart = the compile cache was lost
+        self.worker_startup_seconds = Gauge(
+            "worker_startup_seconds",
+            "Seconds of the worker's start, by phase (init = the engine's "
+            "load, with params, kv_pools and jit_fns inside it; load_model; "
+            "graphs_trace / graphs_lower / graphs_backend = the round "
+            "graphs' three stages; ready = the worker's start to READY)",
+            ["worker", "phase"], registry=r)
         # an info gauge (value 1, the fact in the label): which KV path
         # the worker's multi-token round graphs were built with
         self.worker_ragged_kv_path = Gauge(
@@ -908,6 +933,16 @@ class MetricsCollector:
                 ("index_pool_bytes", self.metrics.worker_index_pool_bytes)):
             if key in stats:
                 gauge.labels(worker).set(float(stats[key] or 0.0))
+        # what the worker's last start cost (runtime/flight.py
+        # STARTUP_PHASES: the heartbeat's ``startup_<phase>_s``)
+        for phase in STARTUP_PHASES.values():
+            if f"startup_{phase}_s" in stats:
+                try:
+                    self.metrics.worker_startup_seconds.labels(
+                        worker, phase).set(
+                            float(stats[f"startup_{phase}_s"] or 0.0))
+                except (TypeError, ValueError):
+                    continue
         prev = self._batcher_prev.setdefault(worker, {})
         for key, metric in (
             ("decode_rounds", self.metrics.batcher_decode_rounds),
@@ -938,8 +973,11 @@ class MetricsCollector:
                     worker, key[6:-2])
             elif key == "compiles":
                 metric = self.metrics.worker_compiles.labels(worker)
-            elif key == "compile_s":
-                metric = self.metrics.worker_compile_seconds.labels(worker)
+            elif key in _COMPILE_STAGES:
+                metric = self.metrics.worker_compile_seconds.labels(
+                    worker, _COMPILE_STAGES[key])
+            elif key == "compile_misses":
+                metric = self.metrics.worker_compile_misses.labels(worker)
             elif key == "between_rounds":
                 metric = self.metrics.batcher_round_gaps.labels(worker)
             elif key.startswith("scans_t") and key[7:].isdigit():

@@ -14,7 +14,7 @@ import os
 import threading
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 _CHECKOUT = Path(__file__).resolve().parents[2]
 
@@ -99,14 +99,39 @@ def enable_compile_cache() -> str:
     return directory
 
 
+# the three stages of a compile JAX reports (jax/_src/dispatch.py), each as
+# a start (``record_scalar``) and an end with its seconds
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+
+
 class CompileLog:
-    """Every XLA compile request of the process: how many (``count``), their
-    seconds (``seconds``) and, in ``rows``, each one's jitted function, its
-    seconds and what the persistent cache did with it (``hit`` = loaded,
-    nothing compiled; ``miss`` = compiled and stored). ``jax.monitoring``
-    listeners cannot be taken off again, so a process has one log
-    (:func:`compile_log`); ``rows`` stops growing at :data:`ROWS_KEPT`, the
-    totals do not."""
+    """Every compile of the process, by stage: tracing a jitted function to
+    a jaxpr (``trace_s``), lowering the jaxpr to an MLIR module, the Mosaic
+    kernels' lowering inside it (``lower_s``), and the XLA compile requests:
+    how many (``count``), their seconds (``seconds``) and how many of them
+    missed the persistent cache (``misses``). ``rows`` holds one row a stage
+    event: ``fn`` the jitted function, ``secs``, ``stage`` (``trace`` /
+    ``lower`` / ``backend``), ``graph`` (the label the thread carried,
+    :meth:`label`, else ``fn``) and, a backend row, ``cache``: what the
+    persistent cache did with the request (``hit`` = loaded, nothing
+    compiled; ``miss`` = compiled and stored).
+
+    The events nest: a jitted function traced inside another reports its
+    own trace within the outer one's, and a function a lowering rule traces
+    reports inside the lowering. Only an event with no other open on its
+    thread is counted into ``trace_s`` / ``lower_s``, given a row and added
+    to the thread's label, so a stage's seconds are never counted twice and
+    ``trace_s + lower_s + backend_s`` of a label stay under the wall time
+    of the block that carried it. ``count`` / ``seconds`` count every
+    backend request, as they always have.
+
+    ``jax.monitoring`` listeners cannot be taken off again, so a process
+    has one log (:func:`compile_log`); ``rows`` stops growing at
+    :data:`ROWS_KEPT`, the totals do not."""
 
     ROWS_KEPT = 4096
 
@@ -115,11 +140,34 @@ class CompileLog:
 
         self.count = 0
         self.seconds = 0.0
+        self.trace_s = 0.0
+        self.lower_s = 0.0
+        self.misses = 0
         self.rows: List[Dict[str, Any]] = []
+        # by thread: what the cache did with the request being compiled,
+        # the stage events open, and the label with the row it adds to
         self._outcome: Dict[int, str] = {}
+        self._open: Dict[int, int] = {}
+        self._label: Dict[int, Tuple[str, Dict[str, float]]] = {}
         self._lock = threading.Lock()    # compiles come from any thread
         monitoring.register_event_listener(self._on_event)
+        monitoring.register_scalar_listener(self._on_start)
         monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def label(self, graph: str) -> Dict[str, float]:
+        """From now until :meth:`unlabel`, the calling thread's stage events
+        belong to ``graph``: their seconds are added to the row returned
+        (``trace_s``, ``lower_s``, ``backend_s``) and their rows carry the
+        name. Set and cleared inside one block of the caller's own body:
+        nothing is wrapped round what it measures."""
+        row = {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0}
+        tid = threading.get_ident()
+        self._label[tid] = (graph, row)
+        self._open.pop(tid, None)       # a block starts with nothing open
+        return row
+
+    def unlabel(self) -> None:
+        self._label.pop(threading.get_ident(), None)
 
     def _on_event(self, name: str, **_: Any) -> None:
         if name.endswith("/cache_hits"):
@@ -127,16 +175,40 @@ class CompileLog:
         elif name.endswith("/cache_misses"):
             self._outcome[threading.get_ident()] = "miss"
 
+    def _on_start(self, name: str, _value: Any, **_: Any) -> None:
+        if name in _STAGES:
+            tid = threading.get_ident()
+            self._open[tid] = self._open.get(tid, 0) + 1
+
     def _on_duration(self, name: str, secs: float, **kw: Any) -> None:
-        if name != "/jax/core/compile/backend_compile_duration":
+        stage = _STAGES.get(name)
+        if stage is None:
             return
-        cache = self._outcome.pop(threading.get_ident(), "uncached")
+        tid = threading.get_ident()
+        nested = self._open.get(tid, 1) > 1
+        if nested:
+            self._open[tid] -= 1
+            if stage != "backend":
+                return              # its seconds are inside the outer one's
+        else:
+            self._open.pop(tid, None)
+        fn = str(kw.get("fun_name"))
+        graph, into = self._label.get(tid) or (fn, None)
+        row = {"fn": fn, "secs": secs, "stage": stage, "graph": graph}
+        if into is not None and not nested:
+            into[stage + "_s"] += secs
         with self._lock:
-            self.count += 1
-            self.seconds += secs
+            if stage == "backend":
+                row["cache"] = self._outcome.pop(tid, "uncached")
+                self.count += 1
+                self.seconds += secs
+                self.misses += row["cache"] == "miss"
+            elif stage == "trace":
+                self.trace_s += secs
+            else:
+                self.lower_s += secs
             if len(self.rows) < self.ROWS_KEPT:
-                self.rows.append({"fn": str(kw.get("fun_name")),
-                                  "secs": secs, "cache": cache})
+                self.rows.append(row)
 
 
 _compile_log: Optional[CompileLog] = None

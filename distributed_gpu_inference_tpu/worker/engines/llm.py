@@ -39,6 +39,8 @@ from ...runtime.flight import (
     EGRESS_KEY,
     NULL_TIMELINE,
     Egress,
+    adopt_phases,
+    phase,
     span,
     timeline_for,
 )
@@ -436,145 +438,151 @@ class TPULLMEngine(LLMBaseEngine):
     # -- lifecycle -----------------------------------------------------------
 
     def load_model(self) -> None:
-        model_name = self.config.get("model", "llama3-mini")
-        if self.tokenizer is None:
-            tok_id = self.config.get("tokenizer_id")
-            self.tokenizer = (
-                _load_hf_tokenizer(tok_id) if tok_id else ByteTokenizer()
+        outer: Dict[str, Any] = {}
+        with phase("dgi.llm.load_model", outer, "load_model",
+                   model=str(self.config.get("model", "llama3-mini"))):
+            model_name = self.config.get("model", "llama3-mini")
+            if self.tokenizer is None:
+                tok_id = self.config.get("tokenizer_id")
+                self.tokenizer = (
+                    _load_hf_tokenizer(tok_id) if tok_id else ByteTokenizer()
+                )
+            # KV spill tiers: host-RAM L2 block budget + optional L3 remote
+            # store from a config URL (redis://host:port/db — the real RESP
+            # client in runtime/redis_kv.py; memory:// for single-node tests)
+            from distributed_gpu_inference_tpu.runtime.redis_kv import (
+                remote_store_from_url,
             )
-        # KV spill tiers: host-RAM L2 block budget + optional L3 remote
-        # store from a config URL (redis://host:port/db — the real RESP
-        # client in runtime/redis_kv.py; memory:// for single-node tests)
-        from distributed_gpu_inference_tpu.runtime.redis_kv import (
-            remote_store_from_url,
-        )
 
-        sv = self._serving_config()
-        eng_cfg = EngineConfig(
-            max_batch_size=int(self.config.get("max_batch_size", 8)),
-            # EngineModelConfig names no context length: a worker's file
-            # carries it under ``extra``, as it carries ``tp_size``
-            max_seq_len=int(
-                self.config.get("max_seq_len")
-                or (self.config.get("extra") or {}).get("max_seq_len")
-                or 2048),
-            multi_step=int(self.config.get("multi_step", 16)),
-            enable_prefix_cache=bool(
-                self.config.get("enable_prefix_cache", True)
-            ),
-            quantization=self.config.get("quantization"),
-            # KV-pool storage dtype (int8 | fp8 | None = activation dtype)
-            # — previously engine-API-only; spec verify reads int8 pools
-            # through the ragged kernel's in-kernel dequant since round 8,
-            # so the worker config can finally compose quantized KV with
-            # speculative serving
-            kv_cache_dtype=self.config.get("kv_cache_dtype"),
-            spill_host_blocks=int(self.config.get("kv_spill_host_blocks", 0)),
-            spill_remote_store=remote_store_from_url(
-                self.config.get("kv_remote_url"),
-                ttl_s=float(self.config.get("kv_remote_ttl_s", 3600.0)),
-            ),
-            # long-context pool sizing: the default rule (1.5x batch x
-            # max_blocks_per_seq) assumes every slot can run max_seq_len
-            # deep — at 32k that is mostly pad, so deployments size the
-            # pool for the actual working set instead
-            num_blocks=(int(self.config["num_blocks"])
-                        if self.config.get("num_blocks") else None),
-        )
-        if self.config.get("prefill_buckets"):
-            eng_cfg.prefill_buckets = tuple(
-                sorted(int(w) for w in self.config["prefill_buckets"])
+            sv = self._serving_config()
+            eng_cfg = EngineConfig(
+                max_batch_size=int(self.config.get("max_batch_size", 8)),
+                # EngineModelConfig names no context length: a worker's file
+                # carries it under ``extra``, as it carries ``tp_size``
+                max_seq_len=int(
+                    self.config.get("max_seq_len")
+                    or (self.config.get("extra") or {}).get("max_seq_len")
+                    or 2048),
+                multi_step=int(self.config.get("multi_step", 16)),
+                enable_prefix_cache=bool(
+                    self.config.get("enable_prefix_cache", True)
+                ),
+                quantization=self.config.get("quantization"),
+                # KV-pool storage dtype (int8 | fp8 | None = activation dtype)
+                # — previously engine-API-only; spec verify reads int8 pools
+                # through the ragged kernel's in-kernel dequant since round 8,
+                # so the worker config can finally compose quantized KV with
+                # speculative serving
+                kv_cache_dtype=self.config.get("kv_cache_dtype"),
+                spill_host_blocks=int(
+                    self.config.get("kv_spill_host_blocks", 0)),
+                spill_remote_store=remote_store_from_url(
+                    self.config.get("kv_remote_url"),
+                    ttl_s=float(self.config.get("kv_remote_ttl_s", 3600.0)),
+                ),
+                # long-context pool sizing: the default rule (1.5x batch x
+                # max_blocks_per_seq) assumes every slot can run max_seq_len
+                # deep — at 32k that is mostly pad, so deployments size the
+                # pool for the actual working set instead
+                num_blocks=(int(self.config["num_blocks"])
+                            if self.config.get("num_blocks") else None),
             )
-        # long-context chunk width: per-round knob, so load-time config is
-        # just the initial value (remote pushes can retune it live)
-        if sv.get("ragged_chunk"):
-            eng_cfg.ragged_chunk = int(sv["ragged_chunk"])
-        # speculative decoding (EngineConfig.speculative): every decode
-        # round runs fused draft→verify→accept steps committing 1..K+1
-        # tokens per slot. Greedy outputs stay byte-identical; sampled
-        # requests ride the same graph at one token per step.
-        if self.config.get("speculative_decode"):
-            from ...runtime.speculative import SpecDecodeConfig
+            if self.config.get("prefill_buckets"):
+                eng_cfg.prefill_buckets = tuple(
+                    sorted(int(w) for w in self.config["prefill_buckets"])
+                )
+            # long-context chunk width: per-round knob, so load-time config is
+            # just the initial value (remote pushes can retune it live)
+            if sv.get("ragged_chunk"):
+                eng_cfg.ragged_chunk = int(sv["ragged_chunk"])
+            # speculative decoding (EngineConfig.speculative): every decode
+            # round runs fused draft→verify→accept steps committing 1..K+1
+            # tokens per slot. Greedy outputs stay byte-identical; sampled
+            # requests ride the same graph at one token per step.
+            if self.config.get("speculative_decode"):
+                from ...runtime.speculative import SpecDecodeConfig
 
-            try:
-                oracle = self.config.get("spec_oracle_accept")
-                eng_cfg.speculative = SpecDecodeConfig(
-                    num_draft_tokens=int(
-                        self.config.get("spec_num_draft_tokens", 4)
-                    ),
-                    # acceptance-adaptive draft depth (per-slot EMA
-                    # selects K from a static set — one compiled graph)
-                    adaptive=bool(self.config.get("spec_adaptive", False)),
-                    adaptive_min_k=int(
-                        self.config.get("spec_adaptive_min_k", 1)
-                    ),
-                    # bench-only oracle draft: force the acceptance rate
-                    # (fraction of drafted tokens) — real cost, forced
-                    # decision; outputs are garbage, pair with ignore_eos
-                    oracle_accept_rate=(
-                        None if oracle is None else float(oracle)
-                    ),
-                )
-                eng_cfg.speculative.validate(eng_cfg)
-            except (ValueError, TypeError) as exc:
-                raise EngineLoadError(
-                    f"speculative_decode config invalid: {exc}"
-                ) from exc
-        # first-class TP: tp_size > 1 builds a model-axis mesh over local
-        # devices (the reference forwarded tensor_parallel_size to vLLM;
-        # here the engine itself shards, llm_vllm.py:56 / SURVEY §2.2)
-        mesh = None
-        tp = int(self.config.get("tp_size") or
-                 (self.config.get("extra") or {}).get("tp_size") or 1)
-        if tp > 1:
-            import jax
+                try:
+                    oracle = self.config.get("spec_oracle_accept")
+                    eng_cfg.speculative = SpecDecodeConfig(
+                        num_draft_tokens=int(
+                            self.config.get("spec_num_draft_tokens", 4)
+                        ),
+                        # acceptance-adaptive draft depth (per-slot EMA
+                        # selects K from a static set — one compiled graph)
+                        adaptive=bool(self.config.get("spec_adaptive", False)),
+                        adaptive_min_k=int(
+                            self.config.get("spec_adaptive_min_k", 1)
+                        ),
+                        # bench-only oracle draft: force the acceptance rate
+                        # (fraction of drafted tokens) — real cost, forced
+                        # decision; outputs are garbage, pair with ignore_eos
+                        oracle_accept_rate=(
+                            None if oracle is None else float(oracle)
+                        ),
+                    )
+                    eng_cfg.speculative.validate(eng_cfg)
+                except (ValueError, TypeError) as exc:
+                    raise EngineLoadError(
+                        f"speculative_decode config invalid: {exc}"
+                    ) from exc
+            # first-class TP: tp_size > 1 builds a model-axis mesh over local
+            # devices (the reference forwarded tensor_parallel_size to vLLM;
+            # here the engine itself shards, llm_vllm.py:56 / SURVEY §2.2)
+            mesh = None
+            tp = int(self.config.get("tp_size") or
+                     (self.config.get("extra") or {}).get("tp_size") or 1)
+            if tp > 1:
+                import jax
 
-            from ...parallel.mesh import MeshPlan, make_mesh
+                from ...parallel.mesh import MeshPlan, make_mesh
 
-            devices = jax.local_devices()  # only addressable chips: a mesh
-            # over another process's devices would fail or diverge per host
-            if len(devices) < tp:
-                raise EngineLoadError(
-                    f"tp_size={tp} but only {len(devices)} local devices"
-                )
-            mesh = make_mesh(MeshPlan(model=tp), devices[:tp],
-                             keep_trivial_axes=False)
-        try:
-            self.engine = TPUEngine(
-                model_name,
-                eng_cfg,
-                checkpoint_path=self.config.get("checkpoint_path"),
-                mesh=mesh,
-            )
-        except ValueError as exc:
-            # invalid mesh/model combination must drop the task type, not
-            # kill worker startup (load_engines catches EngineLoadError)
-            raise EngineLoadError(str(exc)) from exc
-        if self.engine.model_cfg.latent_kv:
-            # peers pull K/V pages (runtime/kv_handoff.py): not this cache's
-            self.kv_migrate_enabled = False
-        if eng_cfg.speculative is not None and \
-                int(self.config.get("spec_distill_steps", 0)) > 0:
-            # optional on-load draft distillation against the engine's own
-            # target weights; a random head is still correct, just ~0
-            # acceptance, so failures here must not kill the task type
+                devices = jax.local_devices()  # only addressable chips: a mesh
+                # over another process's devices would fail or diverge per host
+                if len(devices) < tp:
+                    raise EngineLoadError(
+                        f"tp_size={tp} but only {len(devices)} local devices"
+                    )
+                mesh = make_mesh(MeshPlan(model=tp), devices[:tp],
+                                 keep_trivial_axes=False)
             try:
-                self.engine.distill_draft(
-                    steps=int(self.config["spec_distill_steps"])
+                self.engine = TPUEngine(
+                    model_name,
+                    eng_cfg,
+                    checkpoint_path=self.config.get("checkpoint_path"),
+                    mesh=mesh,
                 )
-            except Exception as exc:  # noqa: BLE001 — optax absent, OOM, ...
-                raise EngineLoadError(
-                    f"speculative draft distillation failed: {exc}"
-                ) from exc
-        if str(sv["mode"]) == "batcher":
-            try:
-                self.serving = BatcherServing(
-                    self.engine, self._batcher_config(sv)
-                )
-            except (ValueError, RuntimeError) as exc:
-                raise EngineLoadError(
-                    f"batcher serving config invalid: {exc}"
-                ) from exc
+            except ValueError as exc:
+                # invalid mesh/model combination must drop the task type, not
+                # kill worker startup (load_engines catches EngineLoadError)
+                raise EngineLoadError(str(exc)) from exc
+            if self.engine.model_cfg.latent_kv:
+                # peers pull K/V pages (runtime/kv_handoff.py), not this
+                # cache's
+                self.kv_migrate_enabled = False
+            if eng_cfg.speculative is not None and \
+                    int(self.config.get("spec_distill_steps", 0)) > 0:
+                # optional on-load draft distillation against the engine's own
+                # target weights; a random head is still correct, just ~0
+                # acceptance, so failures here must not kill the task type
+                try:
+                    self.engine.distill_draft(
+                        steps=int(self.config["spec_distill_steps"])
+                    )
+                except Exception as exc:  # noqa: BLE001 — no optax, OOM, ...
+                    raise EngineLoadError(
+                        f"speculative draft distillation failed: {exc}"
+                    ) from exc
+            if str(sv["mode"]) == "batcher":
+                try:
+                    self.serving = BatcherServing(
+                        self.engine, self._batcher_config(sv)
+                    )
+                except (ValueError, RuntimeError) as exc:
+                    raise EngineLoadError(
+                        f"batcher serving config invalid: {exc}"
+                    ) from exc
+        adopt_phases(self.engine.stats["startup"], outer)
         self.loaded = True
 
     def _serving_config(self) -> Dict[str, Any]:

@@ -35,6 +35,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..runtime.flight import STARTUP_PHASES, adopt_phases, phase
 from ..utils.config import WorkerConfig
 from ..utils.data_structures import TpuTopology, WorkerState
 from ..utils.device import ChipSpec, chip_spec
@@ -497,8 +498,16 @@ class Worker:
             # after start they rise only at a shape nothing warmed)
             core = getattr(eng, "engine", None)
             es = core.get_stats() if core is not None else {}
-            for k in ("compiles", "compile_s"):
+            for k in ("compiles", "compile_s", "compile_trace_s",
+                      "compile_lower_s", "compile_misses"):
                 out[k] = max(out.get(k, 0), round(es.get(k, 0), 3))
+            # what the start cost, a phase (the slowest engine's)
+            started = es.get("startup") or {}
+            for k, label in STARTUP_PHASES.items():
+                if k + "_s" in started:
+                    out[f"startup_{label}_s"] = max(
+                        out.get(f"startup_{label}_s", 0),
+                        round(started[k + "_s"], 3))
             for k in ("ragged_kv_path", "kv_layout"):
                 if es.get(k):
                     out[k] = es[k]
@@ -1234,36 +1243,50 @@ class Worker:
 
     def start(self, install_signal_handlers: bool = True,
               block: bool = True) -> None:
-        self.register()
-        self.load_engines()
+        # the worker's way to READY (docs/observability.md, "Start-up")
+        outer: Dict[str, Any] = {}
+        with phase("dgi.worker.start", outer, "worker_ready",
+                   worker=self.config.name):
+            with phase("dgi.worker.register", outer, "worker_register"):
+                self.register()
+            with phase("dgi.worker.load_engines", outer,
+                       "worker_load_engines"):
+                self.load_engines()
+            for eng in self.engines.values():
+                # stream-checkpoint cadence between heartbeats (llm
+                # engine): admission + every checkpoint_interval_tokens
+                if hasattr(eng, "checkpoint_sink"):
+                    eng.checkpoint_sink = self.push_stream_checkpoint
+            if self.config.direct.enabled:
+                from .direct_server import DirectServer
+
+                self._direct = DirectServer(
+                    self, host=self.config.direct.host,
+                    port=self.config.direct.port,
+                )
+                self._direct.start()
+            if self.config.pd_data_plane_url and "llm" in self.engines:
+                # decode-capable PD worker: run a data plane so prefill
+                # peers can push KV handoffs (server/pd_flow.py stage 2)
+                from urllib.parse import urlparse
+
+                from ..comm.data_plane import DataPlaneServer
+
+                llm_eng = self.engines["llm"]
+                port = urlparse(self.config.pd_data_plane_url).port or 8472
+                self._pd_plane = DataPlaneServer(
+                    _PDReceiverShim(llm_eng), port=port,
+                    kv_receiver=llm_eng.kv_receiver,
+                    kv_exporter=getattr(llm_eng, "kv_export", None),
+                )
+                self._pd_plane.start()
         for eng in self.engines.values():
-            # stream-checkpoint cadence between heartbeats (llm engine):
-            # admission + every checkpoint_interval_tokens
-            if hasattr(eng, "checkpoint_sink"):
-                eng.checkpoint_sink = self.push_stream_checkpoint
-        if self.config.direct.enabled:
-            from .direct_server import DirectServer
-
-            self._direct = DirectServer(
-                self, host=self.config.direct.host,
-                port=self.config.direct.port,
-            )
-            self._direct.start()
-        if self.config.pd_data_plane_url and "llm" in self.engines:
-            # decode-capable PD worker: run a data plane so prefill peers
-            # can push KV handoffs (server/pd_flow.py stage 2)
-            from urllib.parse import urlparse
-
-            from ..comm.data_plane import DataPlaneServer
-
-            llm_eng = self.engines["llm"]
-            port = urlparse(self.config.pd_data_plane_url).port or 8472
-            self._pd_plane = DataPlaneServer(
-                _PDReceiverShim(llm_eng), port=port,
-                kv_receiver=llm_eng.kv_receiver,
-                kv_exporter=getattr(llm_eng, "kv_export", None),
-            )
-            self._pd_plane.start()
+            stats = getattr(getattr(eng, "engine", None), "stats", None)
+            if isinstance(stats, dict) and "startup" in stats:
+                adopt_phases(stats["startup"], outer)
+        log.info("ready in %.2fs (register %.2f, engines %.2f)",
+                 outer["worker_ready_s"], outer["worker_register_s"],
+                 outer["worker_load_engines_s"])
         self.state = WorkerState.IDLE
         if install_signal_handlers:
             try:

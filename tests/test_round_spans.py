@@ -478,7 +478,8 @@ def test_worker_ships_round_counters_and_the_plane_counts_their_deltas():
         in text
     assert 'engine_round_seconds_total{phase="build",worker="w1"} 1.0' in text
     assert 'worker_compiles_total{worker="w1"} 10.0' in text
-    assert 'worker_compile_seconds_total{worker="w1"} 1.5' in text
+    assert ('worker_compile_seconds_total{stage="backend",worker="w1"} 1.5'
+            in text)
     assert 'worker_ragged_kv_path{path="in_place",worker="w1"} 1.0' in text
     assert 'worker_ragged_kv_path{path="layer_copy",worker="w1"} 0.0' in text
     # an engine restart re-anchors: totals fall, nothing is subtracted
