@@ -300,10 +300,7 @@ def _decode_kernel(
             page = bt_ref[r, jnp.minimum(safe // block_size, max_pages - 1)]
             return wpos >= 0, page, safe % block_size
 
-        def stage_copies(r, dst_first):
-            valid, page, _ = row_page(r)
-            st = r % n_stage
-
+        def stage_copies(st, page, dst_first):
             def cp(hbm, stage, sem):
                 return pltpu.make_async_copy(
                     hbm.at[layer, page], stage.at[st], sem
@@ -316,7 +313,58 @@ def _decode_kernel(
             if quantized:
                 copies += [cp(kso_hbm, wks_stage, wsems.at[2, st]),
                            cp(vso_hbm, wvs_stage, wsems.at[3, st])]
-            return valid, copies
+            return copies
+
+        def each_row(c0, phase):
+            """One phase of the write over the chunk of rows from ``c0``:
+            ``phase(row, staging page, page, slot)``, a row with a token to
+            write under its own ``pl.when``. The body is traced ONCE, the
+            row an index, and unrolled at lowering, where the index is a
+            constant again: Mosaic gets the program a Python loop over the
+            rows gives it, and Python traces an eighth of it at eight rows.
+            With ``group_dma``'s page starts these loops were most of the
+            seconds a decode graph took to trace (PERF.md section 6, PR
+            56)."""
+
+            def row(st, carry):
+                valid, page, slot = row_page(c0 + st)
+                pl.when(valid)(lambda: phase(c0 + st, st, page, slot))
+                return carry
+
+            lax.fori_loop(0, min(n_stage, batch - c0), row, 0, unroll=True)
+
+        def copies_phase(dst_first, wait):
+            def phase(_r, st, page, _slot):
+                for c in stage_copies(st, page, dst_first):
+                    c.wait() if wait else c.start()
+
+            return phase
+
+        def blend(r, st, _page, slot):
+            sel = lax.broadcasted_iota(
+                jnp.int32, (hkv, block_size, d), 1) == slot
+            if quantized:
+                # quantize the new rows IN-KERNEL through the shared
+                # contract: one scale over the token's whole (Hkv, D) row
+                # block
+                newk = newk_ref[r].astype(jnp.float32)
+                newv = newv_ref[r].astype(jnp.float32)
+                ki, sk = _quantize_token_rows(newk, (0, 1))
+                vi, sv = _quantize_token_rows(newv, (0, 1))
+                sk, sv = sk[0, 0], sv[0, 0]
+                wk_stage[st] = jnp.where(sel, ki[:, None, :], wk_stage[st])
+                wv_stage[st] = jnp.where(sel, vi[:, None, :], wv_stage[st])
+                sel_s = lax.broadcasted_iota(
+                    jnp.int32, (block_size, d), 0) == slot
+                wks_stage[st] = jnp.where(
+                    sel_s, sk.astype(jnp.bfloat16), wks_stage[st])
+                wvs_stage[st] = jnp.where(
+                    sel_s, sv.astype(jnp.bfloat16), wvs_stage[st])
+            else:
+                wk_stage[st] = jnp.where(
+                    sel, newk_ref[r][:, None, :], wk_stage[st])
+                wv_stage[st] = jnp.where(
+                    sel, newv_ref[r][:, None, :], wv_stage[st])
 
         @pl.when((b == 0) & (i == 0))
         def _():
@@ -325,85 +373,11 @@ def _decode_kernel(
             # batch x page geometry; within a chunk the four DMA phases are
             # issued batch-wide before being waited
             for c0 in range(0, batch, n_stage):
-                rows = range(c0, min(c0 + n_stage, batch))
-                for r in rows:  # static unroll over rows
-                    valid, copies = stage_copies(r, dst_first=True)
-
-                    @pl.when(valid)
-                    def _():
-                        for c in copies:
-                            c.start()
-
-                for r in rows:
-                    valid, copies = stage_copies(r, dst_first=True)
-
-                    @pl.when(valid)
-                    def _():
-                        for c in copies:
-                            c.wait()
-
-                for r in rows:
-                    valid, _page, slot = row_page(r)
-                    st = r % n_stage
-
-                    @pl.when(valid)
-                    def _():
-                        sel = (
-                            lax.broadcasted_iota(
-                                jnp.int32, (hkv, block_size, d), 1
-                            )
-                            == slot
-                        )
-                        if quantized:
-                            # quantize the new rows IN-KERNEL through the
-                            # shared contract: one scale over the token's
-                            # whole (Hkv, D) row block
-                            newk = newk_ref[r].astype(jnp.float32)
-                            newv = newv_ref[r].astype(jnp.float32)
-                            ki, sk = _quantize_token_rows(newk, (0, 1))
-                            vi, sv = _quantize_token_rows(newv, (0, 1))
-                            sk, sv = sk[0, 0], sv[0, 0]
-                            wk_stage[st] = jnp.where(
-                                sel, ki[:, None, :], wk_stage[st]
-                            )
-                            wv_stage[st] = jnp.where(
-                                sel, vi[:, None, :], wv_stage[st]
-                            )
-                            sel_s = (
-                                lax.broadcasted_iota(
-                                    jnp.int32, (block_size, d), 0
-                                )
-                                == slot
-                            )
-                            wks_stage[st] = jnp.where(
-                                sel_s, sk.astype(jnp.bfloat16), wks_stage[st]
-                            )
-                            wvs_stage[st] = jnp.where(
-                                sel_s, sv.astype(jnp.bfloat16), wvs_stage[st]
-                            )
-                        else:
-                            wk_stage[st] = jnp.where(
-                                sel, newk_ref[r][:, None, :], wk_stage[st]
-                            )
-                            wv_stage[st] = jnp.where(
-                                sel, newv_ref[r][:, None, :], wv_stage[st]
-                            )
-
-                for r in rows:
-                    valid, copies = stage_copies(r, dst_first=False)
-
-                    @pl.when(valid)
-                    def _():
-                        for c in copies:
-                            c.start()
-
-                for r in rows:
-                    valid, copies = stage_copies(r, dst_first=False)
-
-                    @pl.when(valid)
-                    def _():
-                        for c in copies:
-                            c.wait()
+                each_row(c0, copies_phase(dst_first=True, wait=False))
+                each_row(c0, copies_phase(dst_first=True, wait=True))
+                each_row(c0, blend)
+                each_row(c0, copies_phase(dst_first=False, wait=False))
+                each_row(c0, copies_phase(dst_first=False, wait=True))
 
     # the table the walk reads, a page a column in the order of the walk: the
     # block table, or under a selection the pages that hold a token the
@@ -446,8 +420,8 @@ def _decode_kernel(
                 pltpu.make_async_copy(
                     hbm.at[layer, page], buf.at[slot, p], sem).start()
 
-        # static unroll: G page starts a pool. Under a selection, whose
-        # group is up to 128 pages wide, a rolled loop over runs of a few
+        # G page starts a pool. Under a selection, whose group is up to 128
+        # pages wide, a rolled loop over runs of a few
         step = _SELECTED_UNROLL if selected and gp % _SELECTED_UNROLL == 0 \
             else gp
 
@@ -456,8 +430,13 @@ def _decode_kernel(
                 start(c * step + p)
             return carry
 
+        def one(p, carry):
+            start(p)
+            return carry
+
         if step == gp:
-            run(0, 0)
+            # unrolled whole, at lowering: traced once, as the write's rows
+            lax.fori_loop(0, gp, one, 0, unroll=True)
         else:
             lax.fori_loop(0, gp // step, run, 0)
 

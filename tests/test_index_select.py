@@ -379,7 +379,7 @@ def _primitives(jaxpr, out=None):
 
 
 # (operands of the kernel's call, equations, digest of their primitives)
-DENSE_DIGEST = (12, 1007, "908ac871cf451a25")
+DENSE_DIGEST = (12, 417, "bb456541f8f1c49d")
 
 
 def test_a_call_without_a_selection_keeps_its_group_width_and_its_jaxpr():
@@ -387,8 +387,9 @@ def test_a_call_without_a_selection_keeps_its_group_width_and_its_jaxpr():
     ``keep``: a dense caller's group is the 512 tokens it was (less where
     the staging budget or the table is smaller), and its program is the one
     the dense walk has: the count and a digest of its primitives in order.
-    Taken anew where the dense walk itself changes on purpose (last: one
-    wait a pool and slot for a group's pages, 1,778 equations to 1,007)."""
+    Taken anew where the dense walk itself changes on purpose (last: the
+    write's rows and the walk's page starts traced once and unrolled at
+    lowering, 1,007 equations to 417 at these three rows)."""
     import hashlib
 
     assert pp._pages_per_group(16, 8, 128, 2, 2048) == 32

@@ -605,3 +605,78 @@ def test_the_fused_decode_kernel_under_a_window_shorter_than_the_context(
     np.testing.assert_array_equal(np.asarray(got_v[0]), np.asarray(want_v))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("quantized,hkv,d,stages", [(False, 16, 256, 4),
+                                                    (True, 32, 512, 3)],
+                         ids=["f32", "int8"])
+def test_the_fused_write_in_chunks_of_its_staging_pages(quantized, hkv, d,
+                                                        stages):
+    """Pages so large that half the kernel's VMEM budget stages fewer of
+    them than the batch has rows: the write goes through its five phases a
+    chunk of rows, the last chunk a short one, a row with nothing to write
+    (position -1) inside a chunk. Pools bit-equal to the scatter's (an int8
+    pool: to the rows quantised on the host by the same contract), the
+    attention over them the oracle's."""
+    from distributed_gpu_inference_tpu.models.llama import _write_kv_pages
+    from distributed_gpu_inference_tpu.ops import paged_attention_pallas as pp
+
+    block, lens = 32, [40, 0, 33, 96, 1, 0, 64]
+    b = len(lens)
+    page_bytes = hkv * block * d * (1 if quantized else 4) \
+        + (block * d * 2 if quantized else 0)
+    assert pp._VMEM_BUDGET_BYTES // 2 // (2 * page_bytes) == stages < b
+    q, k_pool, v_pool, tables, positions, kv_lens = _ragged_setup(
+        [(1 if n else 0, n) for n in lens], nh=hkv, hkv=hkv, d=d,
+        block=block, m=3)
+    rng = np.random.default_rng(2)
+    new_k = jnp.asarray(rng.standard_normal((b, 1, hkv, d)), jnp.float32)
+    new_v = jnp.asarray(rng.standard_normal((b, 1, hkv, d)), jnp.float32)
+    if not quantized:
+        got, got_k, got_v = pp.paged_decode_attention_fused(
+            q, new_k, new_v, k_pool[None], v_pool[None], jnp.int32(0),
+            tables, positions, kv_lens, block, interpret=True)
+        want_k = _write_kv_pages(k_pool, new_k, tables, positions, block)
+        want_v = _write_kv_pages(v_pool, new_v, tables, positions, block)
+        np.testing.assert_array_equal(np.asarray(got_k[0]),
+                                      np.asarray(want_k))
+        np.testing.assert_array_equal(np.asarray(got_v[0]),
+                                      np.asarray(want_v))
+        want = paged_attention_xla(q, want_k, want_v, tables, positions,
+                                   kv_lens, block)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        return
+
+    def on_the_host(pool, new):
+        """(int8 pool, scales) with the rows written as the kernel's
+        contract quantises them: one scale a token, stored as bfloat16."""
+        codes, scales = pp.quantize_kv_pool(pool)
+        rows, row_scales = pp._quantize_token_rows(new[:, 0], (1, 2))
+        wide = jnp.broadcast_to(
+            row_scales.astype(jnp.bfloat16), (b, 1, d))[:, None]
+        return (_write_kv_pages(codes, rows[:, None], tables, positions,
+                                block),
+                _write_kv_pages(scales[:, None], wide, tables, positions,
+                                block)[:, 0],
+                codes, scales)
+
+    want_k, want_ks, k8, ks = on_the_host(k_pool, new_k)
+    want_v, want_vs, v8, vs = on_the_host(v_pool, new_v)
+    got, got_k, got_v, got_ks, got_vs = pp.paged_decode_attention_fused(
+        q, new_k, new_v, k8[None], v8[None], jnp.int32(0), tables, positions,
+        kv_lens, block, interpret=True, k_scale=ks[None], v_scale=vs[None])
+    for have, wanted in ((got_k, want_k), (got_v, want_v),
+                         (got_ks, want_ks), (got_vs, want_vs)):
+        np.testing.assert_array_equal(np.asarray(have[0], np.float32),
+                                      np.asarray(wanted, np.float32))
+
+    def real(codes, scales):
+        return codes.astype(jnp.float32) \
+            * scales.astype(jnp.float32)[:, None, :, :]
+
+    want = paged_attention_xla(q, real(want_k, want_ks),
+                               real(want_v, want_vs), tables, positions,
+                               kv_lens, block)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-2, atol=2e-2)

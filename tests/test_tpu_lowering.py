@@ -118,10 +118,8 @@ def test_ragged_kernel_compiles(v5e, model, block, quantized):
         ).compile()
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("block", [16, 32])
-@pytest.mark.parametrize("model", MODELS)
-def test_fused_decode_kernel_compiles(v5e, model, block, quantized):
+def _fused_decode_lowered(v5e, model, block, quantized):
+    """``dgi_paged_decode`` alone at a model's served shape."""
     cfg = get_model_config(model)
     sds = _on(SingleDeviceSharding(v5e[0]))
     layers = 2
@@ -131,14 +129,21 @@ def test_fused_decode_kernel_compiles(v5e, model, block, quantized):
         paged_decode_attention_fused, block_size=block,
         window=cfg.sliding_window,
     )
-    jax.jit(fn).lower(
+    return jax.jit(fn).lower(
         sds((BATCH, 1, cfg.num_heads, cfg.head_dim), jnp.bfloat16),
         new, new, pool, pool, sds((), jnp.int32),
         sds((BATCH, CTX // block), jnp.int32),
         sds((BATCH, 1), jnp.int32),
         sds((BATCH,), jnp.int32),
         k_scale=scale, v_scale=scale,
-    ).compile()
+    )
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("block", [16, 32])
+@pytest.mark.parametrize("model", MODELS)
+def test_fused_decode_kernel_compiles(v5e, model, block, quantized):
+    _fused_decode_lowered(v5e, model, block, quantized).compile()
 
 
 def _mosaic_text(lowered):
@@ -190,7 +195,7 @@ def test_decode_kernel_waits_once_a_group_at_any_group_width(v5e, walk,
     new = sds((BATCH, 1, cfg.num_kv_heads, cfg.head_dim),
               jnp.bfloat16 if quantized else dtype)
     keep = sds((BATCH, 1, KEYE_CTX), jnp.float32) if selected else None
-    waits, starts = [], []
+    waits, starts, ifs = [], [], []
     for tokens in widths:
         monkeypatch.setattr(
             pp, "_SELECTED_GROUP_TOKENS" if selected else "_GROUP_TOKENS",
@@ -209,10 +214,42 @@ def test_decode_kernel_waits_once_a_group_at_any_group_width(v5e, walk,
         text = _mosaic_text(lowered)
         waits.append(text.count("tpu.wait_dma2"))
         starts.append(text.count("tpu.enqueue_dma"))
+        ifs.append(text.count("scf.if"))
     pools = 4 if quantized else 2
     assert waits == [2 * pools * BATCH + pools] * 2, (waits, starts)
-    if not selected:      # a selection's starts sit in a rolled loop
-        assert starts[0] < starts[1], starts
+    # the kernel traces a start a site and a branch a phase (PR 56), and
+    # Mosaic still gets them a page and a row: two sites of a group's page
+    # starts (a selection's: a rolled loop of runs of eight) beside the
+    # write's two a pool and row; the write's five phases a row beside the
+    # eight branches of a cell
+    assert starts == [
+        pools * (2 * (pp._SELECTED_UNROLL if selected else tokens // block)
+                 + 2 * BATCH) for tokens in widths], starts
+    assert ifs == [5 * BATCH + 8] * 2, ifs
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_decode_kernel_unrolls_its_rows_and_page_starts_at_lowering(
+        v5e, quantized):
+    """``dgi_paged_decode`` at Mistral's served shape (8 rows, 32 pages a
+    group): its body's loops over rows and pages are ``fori_loop(...,
+    unroll=True)``, traced once, and the module Mosaic gets holds every copy
+    and branch with a constant index, as it did when Python unrolled them.
+    A loop left rolled here would run the scalar core's descriptors behind
+    a counter (PR 50 read 4 % of the kernel for that)."""
+    text = _mosaic_text(
+        _fused_decode_lowered(v5e, "mistral-7b", 16, quantized))
+    pools = 2 if quantized else 1       # a scale pool beside K and beside V
+    assert {op: text.count(op) for op in (
+        "tpu.enqueue_dma", "tpu.wait_dma2", "scf.if", "scf.for")} == {
+        # 128 of the walk's two ``group_dma`` sites, 32 of the write
+        "tpu.enqueue_dma": 160 * pools,
+        # 2 of the walk's one wait a pool, 32 of the write
+        "tpu.wait_dma2": 34 * pools,
+        "scf.if": 48,
+        # ``next_chunk``'s search for the next row with a group, no other
+        "scf.for": 1,
+    }
 
 
 @pytest.mark.parametrize("block", [16, 32])
