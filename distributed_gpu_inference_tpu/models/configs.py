@@ -136,6 +136,34 @@ class ModelConfig:
     # one learned scalar a head a token, ``sigmoid(x W_g)``, multiplied
     # into the head's attention output before ``W_o``
     head_gate: bool = False
+    # a state-space (Mamba-2 / SSD) mixer BESIDE attention in every layer of
+    # a K/V model: both read the layer's one normed input and their scaled
+    # outputs are summed into the residual (models/ssd.py). ``ssm_num_heads``
+    # heads of ``ssm_head_dim`` over a float32 state of ``ssm_head_dim x
+    # ssm_state_size`` a head, ``B`` / ``C`` shared by the heads of each of
+    # ``ssm_num_groups`` groups, behind a causal depthwise convolution of
+    # ``ssm_conv_kernel`` taps; the chunked form cuts a segment every
+    # ``ssm_chunk_size`` tokens. What a sequence carries is a row of a state
+    # pool beside its K/V pages. 0 heads: no mixer.
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_num_groups: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_chunk_size: int = 0
+    # muP multipliers, as published (the K/V recipe's): on the embedding's
+    # rows, the logits, the keys, attention's input and output, the mixer's
+    # input and output, the MLP's gate and its output, and the mixer's
+    # projected ``z, x, B, C, dt`` in that order
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    ssm_multipliers: Tuple[float, float, float, float, float] = (1.0,) * 5
     dtype: str = "bfloat16"
 
     def __post_init__(self) -> None:
@@ -178,6 +206,7 @@ class ModelConfig:
                 f"{self.name}: router_scoring {self.router_scoring!r}; use "
                 "'softmax' or 'sigmoid'")
         self._check_layer_types()
+        self._check_state_space()
         if not self.kv_lora_rank:
             # read by models/mla.py alone: models/llama.py would drop them
             # without a word (two norms a layer, pages in every layer, a
@@ -315,6 +344,62 @@ class ModelConfig:
                 "'full' (a shared layer borrows the selection of the full "
                 "layer before it)")
 
+    def _check_state_space(self) -> None:
+        """What a state-space mixer beside attention must state, what only
+        it reads, and what is not built with it: refused here, when the
+        configuration is made, each with its reason."""
+        ssm = (self.ssm_num_heads, self.ssm_head_dim, self.ssm_state_size,
+               self.ssm_num_groups, self.ssm_conv_kernel,
+               self.ssm_chunk_size)
+        mixer_only = [name for name, default in (
+            ("ssm_in_multiplier", 1.0), ("ssm_out_multiplier", 1.0),
+            ("ssm_multipliers", (1.0,) * 5),
+        ) if getattr(self, name) != default]
+        multipliers = mixer_only + [name for name, default in (
+            ("embedding_multiplier", 1.0), ("lm_head_multiplier", 1.0),
+            ("key_multiplier", 1.0), ("attention_in_multiplier", 1.0),
+            ("attention_out_multiplier", 1.0),
+            ("mlp_multipliers", (1.0, 1.0)),
+        ) if getattr(self, name) != default]
+        if len(self.mlp_multipliers) != 2 or len(self.ssm_multipliers) != 5:
+            raise ValueError(
+                f"{self.name}: mlp_multipliers is (gate, output) and "
+                "ssm_multipliers (z, x, B, C, dt)")
+        if multipliers and self.kv_lora_rank:
+            raise ValueError(
+                f"{self.name}: {', '.join(multipliers)} over latent pages "
+                "(kv_lora_rank): the K/V recipe's (models/llama.py), which "
+                "no latent layer reads")
+        if not any(ssm):
+            if mixer_only:
+                raise ValueError(
+                    f"{self.name}: {', '.join(mixer_only)} without a "
+                    "mixer (ssm_num_heads): no layer would read them")
+            return
+        if not all(ssm) or self.ssm_conv_kernel < 2 \
+                or self.ssm_num_heads % self.ssm_num_groups:
+            raise ValueError(
+                f"{self.name}: a state-space mixer needs ssm_num_heads, "
+                "ssm_head_dim, ssm_state_size, ssm_num_groups (a divisor "
+                "of the heads), ssm_conv_kernel (>= 2) and ssm_chunk_size")
+        unbuilt = [name for name, on in (
+            ("kv_lora_rank", self.kv_lora_rank),
+            ("layer_types", self.layer_types),
+            ("sliding_window", self.sliding_window is not None),
+            ("num_experts", self.num_experts),
+            ("index_topk", self.index_topk),
+            ("attention_bias", self.attention_bias),
+            ("qk_norm", self.qk_norm or self.qk_norm_per_head),
+            ("norm_offset", self.norm_offset),
+            ("head_gate", self.head_gate),
+        ) if on]
+        if unbuilt:
+            raise ValueError(
+                f"{self.name}: a state-space mixer beside attention with "
+                f"{', '.join(unbuilt)} is not built: the layer is two token "
+                "mixers on one normed input over plain K/V pages of one "
+                "kind and a dense MLP (models/llama.py leaf_specs)")
+
     def _check_layer_types(self) -> None:
         """What a per-layer description of attention must state, and what a
         model of mixed kinds cannot do yet: refused here, when the
@@ -426,7 +511,8 @@ class ModelConfig:
         group, leaves drawn by name."""
         return not self.kv_lora_rank and bool(
             self.layer_types or self.first_k_dense or self.n_shared_experts
-            or self.held_experts is not None or self.head_gate)
+            or self.held_experts is not None or self.head_gate
+            or self.ssm_num_heads)
 
     @property
     def index_kinds(self) -> Tuple[str, ...]:
@@ -457,6 +543,23 @@ class ModelConfig:
         return self.layer_kinds.count("kda") if self.full_attn_layers else 0
 
     @property
+    def num_state_layers(self) -> int:
+        """Layers that carry a row of a state pool: the linear-attention
+        layers of a hybrid of latent attention, every layer of a model with
+        a state-space mixer."""
+        return self.num_layers if self.ssm_num_heads else self.num_kda_layers
+
+    @property
+    def ssm_inner(self) -> int:
+        """The mixer's width ``d_ssm``: heads x head size."""
+        return self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels under the mixer's convolution: ``x | B | C``."""
+        return self.ssm_inner + 2 * self.ssm_num_groups * self.ssm_state_size
+
+    @property
     def num_cache_layers(self) -> int:
         """Layers that write pages: the paged pool's layer axis."""
         return self.num_layers - self.num_kda_layers
@@ -468,8 +571,13 @@ class ModelConfig:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     def state_bytes_per_row(self, conv_bytes: int = 2) -> int:
-        """Bytes of one sequence's state over all KDA layers: a float32
-        ``head_dim x head_dim`` matrix a head and the convolution's tail."""
+        """Bytes of one sequence's state over all its state layers: a
+        float32 matrix a head (``head_dim x head_dim`` of a KDA layer,
+        ``head_dim x state_size`` of a mixer) and the convolution's tail."""
+        if self.ssm_num_heads:
+            return self.num_layers * (
+                4 * self.ssm_inner * self.ssm_state_size
+                + (self.ssm_conv_kernel - 1) * self.ssm_conv_dim * conv_bytes)
         p = self.kda_num_heads * self.kda_head_dim
         return self.num_kda_layers * (
             4 * p * self.kda_head_dim
@@ -558,6 +666,13 @@ class ModelConfig:
         attn = 2 * h * nh * d + 2 * h * self.num_kv_heads * d \
             + (h * nh if self.head_gate else 0)
         norms = 2 * h + (2 * d if self.qk_norm_per_head else 0)
+        if self.ssm_num_heads:
+            # in and out projections, the convolution and its bias, A_log,
+            # D and dt_bias a head, the gated norm
+            p, c, sh = self.ssm_inner, self.ssm_conv_dim, self.ssm_num_heads
+            attn += h * (p + c + sh) + p * h \
+                + c * (self.ssm_conv_kernel + 1) + 3 * sh
+            norms += p
         if layer < self.first_k_dense or not self.num_experts:
             mlp = 3 * h * self.intermediate_size
         else:
@@ -890,6 +1005,46 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
         index_topk=2048, index_num_heads=32, index_head_dim=128,
         index_types=("full",) + ("shared", "shared", "shared", "full") * 2,
         index_query_input="q_latent", index_rope_dims=64,
+    ),
+    # Falcon-H1 -- in EVERY layer a Mamba-2 (SSD) mixer and GQA attention
+    # read the same normed input and their scaled outputs are summed, then
+    # a SwiGLU MLP; muP multipliers on embedding, head, keys, both mixers'
+    # inputs and outputs and the MLP; a row of a state pool beside the K/V
+    # pages (models/llama.py, models/ssd.py)
+    "falcon-h1-tiny": _llama(  # test-scale: chunks of 16, so that a
+        # segment spans several; every multiplier != 1
+        "falcon-h1-tiny", vocab_size=512, hidden_size=256, num_layers=4,
+        num_heads=4, num_kv_heads=2, intermediate_size=384, head_dim=64,
+        max_position_embeddings=1024, rope_theta=1e11, rms_norm_eps=1e-5,
+        ssm_num_heads=4, ssm_head_dim=64, ssm_state_size=32,
+        ssm_num_groups=2, ssm_conv_kernel=4, ssm_chunk_size=16,
+        embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+        key_multiplier=0.011048543456039804, attention_in_multiplier=0.9,
+        attention_out_multiplier=0.0375, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+    ),
+    # one chip of the four-chip host that holds Falcon-H1-34B-Instruct as
+    # four pipeline stages of 18 whole layers with the embedding and head
+    # vocabulary-parallel in four slices: stage 0, layers 0-17, a quarter of
+    # the vocabulary; every width, head count, group and state size as
+    # published (benchmark/configs/falcon-h1-34b-pp4-18l-int8.json)
+    "falcon-h1-34b-pp4-18l": _llama(
+        "falcon-h1-34b-pp4-18l", vocab_size=65280, hidden_size=5120,
+        num_layers=18, num_heads=20, num_kv_heads=4, intermediate_size=21504,
+        head_dim=128, max_position_embeddings=2048, rope_theta=1e11,
+        rms_norm_eps=1e-5,
+        ssm_num_heads=32, ssm_head_dim=128, ssm_state_size=256,
+        ssm_num_groups=2, ssm_conv_kernel=4, ssm_chunk_size=128,
+        embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+        key_multiplier=0.011048543456039804, attention_in_multiplier=1.0,
+        attention_out_multiplier=0.0375, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
     ),
     # Kimi-Linear — three gated delta-rule (KDA) layers to one latent (MLA)
     # layer that rotates nothing, no query low-rank, a dense first layer,

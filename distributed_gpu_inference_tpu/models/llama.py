@@ -118,14 +118,46 @@ def leaf_specs(cfg: ModelConfig, group: str
     128-column tiles: kept in the activation dtype, like the router)."""
     h, d, nkv = cfg.hidden_size, cfg.head_dim, cfg.num_kv_heads
     nh = cfg.heads_of(group_kind(cfg, group))
+
+    def fan(fan_in: int, *multipliers: float):
+        """The fan-in a leaf is drawn at where muP multipliers scale what
+        it makes: a standard deviation of ``fan_in ** -0.5`` over their
+        product, so that under them each sub-block adds a share of the
+        residual that compares with the others' (a dropped branch would
+        otherwise hide inside a tolerance). No multiplier: ``fan_in``."""
+        m = 1.0
+        for x in multipliers:
+            m *= x
+        return fan_in if m == 1.0 else fan_in * m * m
+
+    a_in, m_gate, m_down = cfg.attention_in_multiplier, *cfg.mlp_multipliers
     spec: Dict[str, Tuple[tuple, int, str]] = {
         "attn_norm": ((h,), 0, "n"),
-        "wq": ((h, nh * d), h, "q"),
-        "wk": ((h, nkv * d), h, "q"),
-        "wv": ((h, nkv * d), h, "q"),
-        "wo": ((nh * d, h), nh * d, "q"),
+        "wq": ((h, nh * d), fan(h, a_in), "q"),
+        "wk": ((h, nkv * d), fan(h, a_in, cfg.key_multiplier), "q"),
+        "wv": ((h, nkv * d), fan(h, a_in), "q"),
+        "wo": ((nh * d, h), fan(nh * d, cfg.attention_out_multiplier), "q"),
         "mlp_norm": ((h,), 0, "n"),
     }
+    if cfg.ssm_num_heads:
+        # the mixer beside attention (models/ssd.py): the in projection as
+        # ``z | x | B | C`` (whole 128-column tiles) and the step sizes'
+        # columns (one a head, under a tile: kept in the activation dtype,
+        # like the router); ``A_log = log(1..H)``, ``D = 1`` and ``dt_bias``
+        # as the family draws them
+        p, c, sh = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_num_heads
+        f_in = fan(h, cfg.ssm_in_multiplier)
+        spec.update({
+            "w_in": ((h, p + c), f_in, "q"),
+            "w_dt": ((h, sh), f_in, "d"),
+            "conv": ((cfg.ssm_conv_kernel, c), cfg.ssm_conv_kernel, "d"),
+            "conv_bias": ((c,), 0, "z"),
+            "dt_bias": ((sh,), 0, "t"),
+            "a_log": ((sh,), 0, "r"),
+            "d_skip": ((sh,), 0, "o"),
+            "ssm_norm": ((p,), 0, "n"),
+            "w_out": ((p, h), fan(p, cfg.ssm_out_multiplier), "q"),
+        })
     if cfg.head_gate:
         spec["w_hgate"] = ((h, nh), h, "d")
     if cfg.qk_norm_per_head:
@@ -134,9 +166,9 @@ def leaf_specs(cfg: ModelConfig, group: str
     if group.endswith("dense_layers") or not cfg.num_experts:
         i = cfg.intermediate_size
         spec.update({
-            "w_gate": ((h, i), h, "q"),
+            "w_gate": ((h, i), fan(h, m_gate), "q"),
             "w_up": ((h, i), h, "q"),
-            "w_down": ((i, h), i, "q"),
+            "w_down": ((i, h), fan(i, m_down), "q"),
         })
     else:
         mi, held = cfg.mlp_width, cfg.num_held_experts
@@ -313,7 +345,11 @@ def init_kv_pools(
     A latent-attention model (``cfg.latent_kv``) has one pool and no head
     axis instead: ``{"ckv": [L, N, Bk, latent + rope]}`` (models/mla.py),
     and beside it, where some layers are linear attention, the state pool
-    of ``state_rows`` sequences (models/kda.py)."""
+    of ``state_rows`` sequences (models/kda.py).
+
+    A model with a state-space mixer beside attention (``cfg.ssm_num_heads``)
+    carries, beside ``"k"`` / ``"v"``, the state pool of ``state_rows``
+    sequences: ``"ssm_state"`` and ``"ssm_conv"`` (models/ssd.py)."""
     if cfg.latent_kv:
         from distributed_gpu_inference_tpu.models import mla
 
@@ -340,6 +376,17 @@ def init_kv_pools(
         return pools
     shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size, cfg.head_dim)
     pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if cfg.ssm_num_heads:
+        from distributed_gpu_inference_tpu.models import ssd
+
+        if dtype.itemsize == 1:
+            raise NotImplementedError(
+                f"{cfg.name}: int8 / fp8 K/V pools beside a state pool are "
+                "not built")
+        if state_rows is None:
+            raise ValueError(
+                f"{cfg.name}: the state pool needs its number of rows")
+        pools.update(ssd.init_state_pools(cfg, state_rows, conv_dtype=dtype))
     if cfg.index_topk:
         # one index key a token a layer, addressed by the same block table
         # as K/V: a page copy, a prefix hit and a resume bring it
@@ -529,9 +576,18 @@ def _mlp_act(activation: str):
     )
 
 
-def _mlp(x: jax.Array, proj, activation: str = "silu") -> jax.Array:
-    gate = _mlp_act(activation)(proj(x, "w_gate"))
-    return proj(gate * proj(x, "w_up"), "w_down").astype(x.dtype)
+def _mlp(x: jax.Array, proj, activation: str = "silu",
+         multipliers: Tuple[float, float] = (1.0, 1.0)) -> jax.Array:
+    """``down(act(gate(x)) * up(x))``; ``multipliers`` (muP): on the gate's
+    pre-activation and on the output."""
+    m_gate, m_down = multipliers
+    gate = proj(x, "w_gate")
+    if m_gate != 1.0:
+        gate = gate * jnp.asarray(m_gate, gate.dtype)
+    out = proj(_mlp_act(activation)(gate) * proj(x, "w_up"), "w_down")
+    if m_down != 1.0:
+        out = out * jnp.asarray(m_down, out.dtype)
+    return out.astype(x.dtype)
 
 
 def _moe_mlp(
@@ -1004,6 +1060,9 @@ def _layer_step(
                                   # count and window (None: the model's one)
     cache_delta: Any = 0,         # the layer's place in its kind's pools
                                   # less its place in its parameter stack
+    mixer=None,                   # a state-space mixer beside attention:
+                                  # (its plan of the chunk or None for one
+                                  # token a row, kernels) (``models/ssd``)
 ) -> Tuple[Tuple[jax.Array, jax.Array, jax.Array, jax.Array],
            Tuple[Optional[jax.Array], Optional[Dict[str, jax.Array]],
                  Optional[jax.Array]]]:
@@ -1046,6 +1105,12 @@ def _layer_step(
     keys in context order (``scan_index_keys``): the step's keys are
     appended there too and the selection reads them there, not the pool.
 
+    ``mixer`` (a model with a state-space mixer in every layer): the carry
+    holds the state pool and the tails' as a fifth and a sixth entry. The
+    mixer reads the same normed input as attention, advances its layer of
+    both, and the two outputs enter the residual together, each under its
+    multiplier.
+
     ``unpack``: ``hidden`` is ``[1, Tp, H]``, a round's live tokens packed
     on one axis. q/k/v are gathered into the ``[B, S]`` rectangle (empty
     positions zero, their ``write_positions`` -1) for the page write and
@@ -1054,6 +1119,9 @@ def _layer_step(
     ``in_place`` only q takes the rectangle: the page write gathers K and V
     from the packed axis straight into page-shaped updates."""
     hidden, k_ent, v_ent, layer_idx, *more = carry
+    state_pools = ()
+    if mixer is not None:
+        state_pools, more = tuple(more), ()
     ki_pool, scan_keys = (*more, None, None)[:2]
     kind = kind or cfg.attn_kinds[0]
     window = cfg.sliding_window if kind == "sliding" else None
@@ -1082,9 +1150,25 @@ def _layer_step(
     # the kernels inside carry their own (innermost) names
     with jax.named_scope("dgi_attention"):
         x = rms_norm(hidden, lp["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+        mixed = None
+        if mixer is not None:
+            from distributed_gpu_inference_tpu.models import ssd
+
+            plan, ssd_kernels = mixer
+            with jax.named_scope("dgi_ssd"):
+                mixed, held = ssd.mixer(
+                    cfg, x, lp, proj, dict(zip(ssd.POOLS, state_pools)),
+                    cache_layer, plan=plan, positions=write_positions,
+                    kernels=ssd_kernels)
+            state_pools = tuple(held[name] for name in ssd.POOLS)
+            mixed = mixed * jnp.asarray(cfg.ssm_out_multiplier, mixed.dtype)
+        if cfg.attention_in_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.attention_in_multiplier, x.dtype)
         q = proj(x, "wq")
         k = proj(x, "wk")
         v = proj(x, "wv")
+        if cfg.key_multiplier != 1.0:
+            k = k * jnp.asarray(cfg.key_multiplier, k.dtype)
         if "bq" in lp:  # Qwen2-style attention biases (static at trace time)
             q = q + lp["bq"]
             k = k + lp["bk"]
@@ -1240,7 +1324,13 @@ def _layer_step(
                 proj(x, "w_hgate").astype(jnp.float32))
             attn = (attn.astype(jnp.float32) * gate[..., None]).astype(
                 attn.dtype)
-        hidden = hidden + proj(attn.reshape(b, s, nh * d), "wo").astype(hidden.dtype)
+        attn = proj(attn.reshape(b, s, nh * d), "wo").astype(hidden.dtype)
+        if cfg.attention_out_multiplier != 1.0:
+            attn = attn * jnp.asarray(cfg.attention_out_multiplier,
+                                      attn.dtype)
+        if mixed is not None:
+            attn = attn + mixed
+        hidden = hidden + attn
     with jax.named_scope("dgi_experts" if "w_router" in lp else "dgi_mlp"):
         mlp_in = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps, cfg.norm_offset)
         moe_stats = routing = None
@@ -1251,10 +1341,11 @@ def _layer_step(
             )
             hidden = hidden + moe_out
         else:
-            hidden = hidden + _mlp(mlp_in, proj, cfg.activation)
+            hidden = hidden + _mlp(mlp_in, proj, cfg.activation,
+                                   cfg.mlp_multipliers)
     k_out = (k_pool, k_scale_pool) if quant_kv else k_pool
     v_out = (v_pool, v_scale_pool) if quant_kv else v_pool
-    return (hidden, k_out, v_out, layer_idx + 1,
+    return (hidden, k_out, v_out, layer_idx + 1, *state_pools,
             *(a for a in (ki_pool, scan_keys) if a is not None)), (
         hidden if emit_hidden else None, moe_stats,
         routing if emit_routing else None, fetched,
@@ -1372,6 +1463,23 @@ def forward_chunk(
                 "computed from its own index-key pool")
         index = _index_plan(cfg, kv["k"].shape[1], block_tables, positions,
                             rope_positions, packing, block_size)
+    mixer = None
+    if cfg.ssm_num_heads:
+        from distributed_gpu_inference_tpu.models import ssd
+
+        if (dense_attn_fn is not None or attn_override is not None
+                or collect_layers is not None or "k_scale" in kv):
+            raise NotImplementedError(
+                "a model with a state-space mixer has no sequence-parallel, "
+                "overridden or feature-collecting forward and no int8 pools")
+        rows = kv[ssd.STATE].shape[1]
+        if block_tables.shape[0] != rows:
+            raise ValueError(
+                f"{cfg.name}: {block_tables.shape[0]} batch rows over a "
+                f"state pool of {rows}: a batch row is a state row")
+        mixer = (ssd.chunk_plan(cfg, packing, rope_positions[0], positions,
+                                rows),
+                 ssd.kernels_on(cfg, kv[ssd.STATE].dtype, pallas))
     b, s = token_ids.shape
     hidden = embed_tokens(params, token_ids, cfg)
 
@@ -1436,6 +1544,7 @@ def forward_chunk(
             in_place=in_place,
             index=index,
             kind=kind,
+            mixer=mixer,
         )
 
     steps = {kind: kind_step(kind) for kind in kinds}
@@ -1447,8 +1556,13 @@ def forward_chunk(
         kind: tuple((kv[name], kv[name + "_scale"]) if quant_kv else kv[name]
                     for name in kind_pools(cfg, kind))
         for kind in kinds}
-    extra = () if index is None else tuple(
-        kv[name] for name in (INDEX_KEYS, INDEX_SCAN_KEYS) if name in kv)
+    # what rides the layers beside the pages: an indexer's keys, or a
+    # mixer's state pool and tails
+    extra_names = () if index is None else tuple(
+        name for name in (INDEX_KEYS, INDEX_SCAN_KEYS) if name in kv)
+    if mixer is not None:
+        extra_names = ssd.POOLS
+    extra = tuple(kv[name] for name in extra_names)
     # where each stack and each kind's pools stand, in layers
     at_w = {group: 0 for group, _ in groups}
     at_c = {kind: 0 for kind in kinds}
@@ -1550,7 +1664,7 @@ def forward_chunk(
                 new_kv[name], new_kv[name + "_scale"] = ent
             else:
                 new_kv[name] = ent
-    new_kv.update(zip((INDEX_KEYS, INDEX_SCAN_KEYS), ki_out))
+    new_kv.update(zip(extra_names, ki_out))
     features = (
         jnp.concatenate([layer_hs[i] for i in collect_layers], axis=-1)
         if collect_layers is not None else None
@@ -1655,6 +1769,8 @@ def embed_tokens(
         hidden = hidden * jnp.asarray(
             cfg.hidden_size**0.5, dtype=hidden.dtype
         )
+    if cfg.embedding_multiplier != 1.0:
+        hidden = hidden * jnp.asarray(cfg.embedding_multiplier, hidden.dtype)
     return hidden
 
 
@@ -1669,6 +1785,8 @@ def project_logits(cfg: ModelConfig, params: Params, hidden: jax.Array) -> jax.A
     logits = jnp.einsum(
         "bsh,vh->bsv", normed.astype(jnp.float32), head.astype(jnp.float32)
     )
+    if cfg.lm_head_multiplier != 1.0:
+        logits = logits * cfg.lm_head_multiplier
     if cfg.final_logit_softcap is not None:  # Gemma-2 style soft capping
         cap = cfg.final_logit_softcap
         logits = cap * jnp.tanh(logits / cap)

@@ -80,7 +80,7 @@ _NORM_SPREAD = 0.25
 
 _LANES = 128
 # leaves kept in float32 whatever the model's dtype (``leaf_specs`` kinds)
-_F32_KINDS = "atb"
+_F32_KINDS = "atbro"
 
 
 def latent_width(cfg: ModelConfig) -> int:
@@ -157,8 +157,9 @@ def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]
     kind: ``q`` a matmul weight (quantized where the engine quantizes),
     ``d`` a dense bf16 weight, ``n`` a norm vector; float32 vectors drawn
     as the family draws them: ``a`` (``A_log``), ``t`` (``dt_bias``), ``b``
-    (the router's selection bias); ``z`` a vector drawn around zero (a
-    LayerNorm's bias)."""
+    (the router's selection bias), and the state-space mixer's that are not
+    drawn at all: ``r`` (``A_log = log(1..H)``) and ``o`` (ones); ``z`` a
+    vector drawn around zero (a LayerNorm's or a convolution's bias)."""
     h, nh = cfg.hidden_size, cfg.num_heads
     if group.startswith(_KDA):
         kh, kd, taps = cfg.kda_num_heads, cfg.kda_head_dim, cfg.kda_conv_kernel
@@ -256,6 +257,10 @@ def draw_leaf(key: jax.Array, shape: tuple, fan_in: int, kind: str
     if kind == "a":     # A_log = log U(1, 16), a head
         return jnp.log(jax.random.uniform(
             key, shape, jnp.float32, minval=1.0, maxval=16.0))
+    if kind == "r":     # A_log = log(1..H), the state-space mixer's ramp
+        return jnp.log(jnp.arange(1, shape[0] + 1, dtype=jnp.float32))
+    if kind == "o":     # ones (the mixer's skip D)
+        return jnp.ones(shape, jnp.float32)
     if kind == "t":     # dt_bias: the inverse softplus of a log-uniform dt
         dt = jnp.exp(jax.random.uniform(
             key, shape, jnp.float32, minval=math.log(1e-3),
@@ -751,15 +756,8 @@ def forward_chunk(
                 f"{cfg.name}: {block_tables.shape[0]} batch rows over a "
                 f"state pool of {rows}: a batch row is a state row")
         kda_kernels = kda.kernels_on(cfg, kv[kda.STATE].dtype, pallas)
-        if packing is not None:
-            kda_plan = kda.make_plan(packing.row, packing.col,
-                                     rope_positions[0], rows)
-        elif positions.shape[1] > 1:
-            b_, s_ = positions.shape
-            kda_plan = kda.make_plan(
-                jnp.repeat(jnp.arange(b_, dtype=jnp.int32), s_),
-                jnp.tile(jnp.arange(s_, dtype=jnp.int32), b_),
-                positions.reshape(-1), rows)
+        kda_plan = kda.chunk_plan(packing, rope_positions[0], positions,
+                                  rows)
     write_plan = tiles = None
     if kernels:
         from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (

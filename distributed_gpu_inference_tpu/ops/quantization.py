@@ -49,6 +49,10 @@ QUANT_KEYS = frozenset(
      "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down",
      # gated delta-rule layers (models/kda.py): q|k|v and the low-rank gates
      "wqkv", "w_fa", "w_fb", "w_ga", "w_gb",
+     # a state-space mixer's in (z | x | B | C) and out projections
+     # (models/ssd.py); its step sizes' columns are one a head, under a
+     # tile, and stay in the activation dtype
+     "w_in", "w_out",
      # an indexer's query projection (models/llama.py); its key and head-
      # weight projections are 64 and 16 columns wide, under the int8
      # kernel's 128-column tiles, and stay in the activation dtype
@@ -133,7 +137,7 @@ def matmul(x: jax.Array, w: Any, pallas: bool = True) -> jax.Array:
 STACKED_KEYS = frozenset(
     {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
      "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up", "ws_down",
-     "wqkv", "w_fa", "w_fb", "w_ga", "w_gb", "wqi"}
+     "wqkv", "w_fa", "w_fb", "w_ga", "w_gb", "wqi", "w_in", "w_out"}
 )
 # the MoE expert weights: kept whole for the routed layer's grouped-matmul
 # kernel (ops/moe_gmm_pallas.py), scanned where the layer runs in XLA

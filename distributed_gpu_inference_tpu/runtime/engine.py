@@ -527,6 +527,8 @@ class TPUEngine:
                 self._refuse_indexed(mesh)
             if self.model_cfg.described_per_layer:
                 self._refuse_per_layer(mesh)
+            if self.model_cfg.ssm_num_heads:
+                self._refuse_state_space()
             if mesh is not None:
                 sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
                 tp = sizes.get("model", 1)
@@ -593,8 +595,20 @@ class TPUEngine:
             # a hybrid model's linear-attention layers keep one state row a
             # slot beside the pages: its size follows from max_batch_size
             self._state_rows = (
-                self.cfg.max_batch_size if self.model_cfg.num_kda_layers
+                self.cfg.max_batch_size if self.model_cfg.num_state_layers
                 else 0)
+            # the state layers' counters' family, their pools' names and
+            # their chunk form's length: the delta rule's, or a mixer's
+            self._state_kind = ("", (), 0)
+            if self.model_cfg.ssm_num_heads:
+                from distributed_gpu_inference_tpu.models import ssd
+
+                self._state_kind = ("ssd", ssd.POOLS,
+                                    self.model_cfg.ssm_chunk_size)
+            elif self._state_rows:
+                from distributed_gpu_inference_tpu.models import kda
+
+                self._state_kind = ("kda", (kda.STATE, kda.CONV), kda.CHUNK)
             # pages per layer kind: the sliding layers' pool follows from the
             # slots and the window, eight windows a slot (a live window with
             # the piece or the scan horizon being written, a retained prefix
@@ -770,7 +784,9 @@ class TPUEngine:
                 # kv+index: K/V pages and an index key a token beside them
                 # latent+index: latent pages and an index key a token a
                 # layer that holds an indexer beside them
-                "kv_layout": "hybrid" if self._state_rows
+                # kv+state: K/V pages beside a state row a sequence
+                "kv_layout": "kv+state" if self.model_cfg.ssm_num_heads
+                else "hybrid" if self._state_rows
                 else ("latent+index" if self.model_cfg.index_topk
                       else "latent") if self.model_cfg.latent_kv
                 else "kv+index" if self.model_cfg.index_topk else "kv",
@@ -841,20 +857,22 @@ class TPUEngine:
                                    "mla_pairs_ragged": 0,
                                    "mla_context_tokens_ragged": 0})
             if self._state_rows:
-                from distributed_gpu_inference_tpu.models import kda
-
-                # the state pool; live row x step x KDA layer of the scans
+                # the state pool; live row x step x state layer of the scans
                 # (arithmetic at a scan's commit); what the ragged rounds
-                # handed the chunk form (at a round's build; every KDA layer
-                # takes it once): live tokens, segments (a row's tokens in a
-                # round) and the 64-token chunks they are cut into
+                # handed the chunk form (at a round's build; every state
+                # layer takes it once): live tokens, segments (a row's
+                # tokens in a round) and the chunks they are cut into (64
+                # tokens of the delta rule, ``kda_*``; ``ssm_chunk_size`` of
+                # a state-space mixer, ``ssd_*``)
+                family, pools, _ = self._state_kind
                 self.stats.update({
                     "state_pool_bytes": sum(
-                        int(self.kv[name].nbytes)
-                        for name in (kda.STATE, kda.CONV)),
+                        int(self.kv[name].nbytes) for name in pools),
                     "state_rows": self._state_rows,
-                    "kda_row_steps_scan": 0, "kda_tokens_ragged": 0,
-                    "kda_segments_ragged": 0, "kda_chunks_ragged": 0,
+                    f"{family}_row_steps_scan": 0,
+                    f"{family}_tokens_ragged": 0,
+                    f"{family}_segments_ragged": 0,
+                    f"{family}_chunks_ragged": 0,
                 })
             if self.model_cfg.num_experts:
                 # what the routed expert layers did (models/llama.py
@@ -932,6 +950,28 @@ class TPUEngine:
                 f"activation dtype, not kv_cache_dtype="
                 f"{self.cfg.kv_cache_dtype!r} (int8 / fp8 pools of two "
                 "kinds are not built)")
+
+    def _refuse_state_space(self) -> None:
+        """A model with a state-space mixer beside attention keeps a state
+        row a slot beside its K/V pages (models/ssd.py). A mesh, a sequence
+        axis and a speculative chain are refused with every model described
+        per layer (``_refuse_per_layer``), the handoff wire where a worker
+        is configured for it (``kv_handoff.require_kv_pages``); the tiers
+        that would carry its pages WITHOUT the state that belongs to them,
+        and K/V pools in another dtype than the activations', refuse it
+        here, when the engine is configured."""
+        name = self.model_cfg.name
+        if self.cfg.spill_host_blocks > 0 or \
+                self.cfg.spill_remote_store is not None:
+            raise ValueError(
+                f"{name}: the spill tiers carry K/V pages, not the state "
+                "row that belongs to them")
+        if self.kv_dtype.itemsize != jnp.dtype(self.dtype).itemsize:
+            raise ValueError(
+                f"{name}: K/V pages beside a state pool are served in the "
+                f"activation dtype, not kv_cache_dtype="
+                f"{self.cfg.kv_cache_dtype!r} (int8 / fp8 pools beside a "
+                "state pool are not built)")
 
     def _refuse_indexed(self, mesh: Optional[Any]) -> None:
         """A model with an indexer (learned sparse attention) keeps an
@@ -1926,11 +1966,7 @@ class TPUEngine:
 
             self._unpack_spec_sched_fn = jax.jit(unpack_spec_sched)
 
-        state_pools = ()
-        if self._state_rows:
-            from distributed_gpu_inference_tpu.models import kda
-
-            state_pools = (kda.STATE, kda.CONV)
+        state_pools = self._state_kind[1]
 
         def apply_ops(kv, srcs, dsts):
             # page copies (CoW): dst = -1 entries are dropped. Scale pools
@@ -2778,7 +2814,7 @@ class TPUEngine:
         for lo in range(0, len(piece), cap):
             part = piece[lo:lo + cap]
             last = is_last and lo + len(part) == len(piece)
-            self._count_kda_ragged(None, [len(part)])
+            self._count_state_ragged(None, [len(part)])
             _tp, operands, round_mode, s_w, *_ = self._pack_ragged(
                 [], [(slot, off + lo, part, last, mode)])
             try:
@@ -3015,15 +3051,16 @@ class TPUEngine:
         st["ragged_positions_dispatched"] += positions
         st["ragged_positions_live"] += decode_tokens + live_prompt
 
-    def _count_kda_ragged(self, sp: Optional[flight.span],
-                          segments: Sequence[int]) -> None:
-        """What a packed round hands the chunk form of the linear-attention
-        layers (each of them, once): its segments' tokens, the segments, and
-        the 64-token chunks they are cut into."""
-        from distributed_gpu_inference_tpu.models.kda import CHUNK
-
-        held = {"kda_tokens": sum(segments), "kda_segments": len(segments),
-                "kda_chunks": sum(-(-n // CHUNK) for n in segments)}
+    def _count_state_ragged(self, sp: Optional[flight.span],
+                            segments: Sequence[int]) -> None:
+        """What a packed round hands the chunk form of the state layers
+        (each of them, once): its segments' tokens, the segments, and the
+        chunks they are cut into, under the layers' family (``kda_*`` /
+        ``ssd_*``)."""
+        family, _, chunk = self._state_kind
+        held = {f"{family}_tokens": sum(segments),
+                f"{family}_segments": len(segments),
+                f"{family}_chunks": sum(-(-n // chunk) for n in segments)}
         if sp is not None:
             sp.set(**held)
         for name, v in held.items():
@@ -3391,7 +3428,7 @@ class TPUEngine:
                 sp, [(int(self._kv_lens[i]), 1) for i in kept]
                 + [(adm.off, len(piece)) for adm, piece, _ in ready])
         if self._state_rows:
-            self._count_kda_ragged(
+            self._count_state_ragged(
                 sp, [1] * len(kept) + [len(piece) for _, piece, _ in ready])
 
     def _pack_ragged(
@@ -4240,11 +4277,13 @@ class TPUEngine:
                 sp.set(index_fetched_tokens=fetched,
                        index_fetched_pages=fetched // self.cfg.block_size)
         if self._state_rows:
-            # a live row's step went through every linear-attention layer
-            steps = int((emitted >= 0).sum()) * self.model_cfg.num_kda_layers
-            st["kda_row_steps_scan"] += steps
+            # a live row's step went through every state layer
+            steps = int((emitted >= 0).sum()) \
+                * self.model_cfg.num_state_layers
+            family = self._state_kind[0]
+            st[f"{family}_row_steps_scan"] += steps
             if sp is not None:
-                sp.set(kda_row_steps=steps)
+                sp.set(**{f"{family}_row_steps": steps})
         with flight.span("dgi.engine.decode_multi.commit", st,
                          "round_commit_s"):
             out: Dict[int, List[int]] = {}

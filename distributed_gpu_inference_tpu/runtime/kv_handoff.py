@@ -115,6 +115,11 @@ def require_kv_pages(engine: "TPUEngine") -> None:
             f"{engine.model_cfg.name}: the KV handoff and migration wire "
             "carries one kind's K/V pages under one block table; this "
             "engine keeps pages per layer kind")
+    if getattr(getattr(engine, "model_cfg", None), "ssm_num_heads", 0):
+        raise ValueError(
+            f"{engine.model_cfg.name}: the KV handoff and migration wire "
+            "carries K/V pages, not the state row this engine keeps beside "
+            "them")
 
 
 def export_slot_kv(engine: "TPUEngine", slot: int) -> KVHandoff:

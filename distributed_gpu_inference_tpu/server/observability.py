@@ -531,6 +531,16 @@ class Metrics:
                  "round) the plain ragged rounds handed the chunk form"),
                 ("kda_chunks_ragged", "64-token chunks those segments were "
                  "cut into"),
+                # the same four for a state-space mixer beside attention
+                # (models/ssd.py), whose chunks are ssm_chunk_size tokens
+                ("ssd_row_steps_scan", "Live row x step x layer of the "
+                 "decode scans through the state-space mixer's step kernel"),
+                ("ssd_tokens_ragged", "Live tokens the plain ragged rounds "
+                 "handed the mixer's chunk form, a round's once"),
+                ("ssd_segments_ragged", "Segments (a row's tokens in a "
+                 "round) handed the mixer's chunk form"),
+                ("ssd_chunks_ragged", "Chunks (ssm_chunk_size tokens) those "
+                 "segments were cut into"),
             )
         }
         # cache-aware routing (round 7): hits = placements that landed on
@@ -923,8 +933,8 @@ class MetricsCollector:
                     1.0 if name == path else 0.0)
         layout = stats.get("kv_layout")
         if isinstance(layout, str):
-            for name in ("kv", "kv+index", "latent", "latent+index",
-                         "hybrid"):
+            for name in ("kv", "kv+index", "kv+state", "latent",
+                         "latent+index", "hybrid"):
                 self.metrics.worker_kv_layout.labels(worker, name).set(
                     1.0 if name == layout else 0.0)
         for key, gauge in (

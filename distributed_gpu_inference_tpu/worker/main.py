@@ -330,15 +330,15 @@ class Worker:
                 if self.config.role != "hybrid" and core is not None and \
                         getattr(core, "stats", {}).get("kv_layout") in (
                             "latent", "latent+index", "hybrid", "kv+index",
-                            "kv+window"):
+                            "kv+window", "kv+state"):
                     # a prefill / decode role hands K/V pages to a peer
                     # (runtime/kv_handoff.py require_kv_pages): refused
                     # here, where the worker is configured
                     raise EngineLoadError(
                         f"role {self.config.role!r}: the PD handoff carries "
                         f"K/V pages, {cfg.model} caches latent pages (and, "
-                        "a hybrid model, state rows), index keys beside "
-                        "its K/V pages or pages per layer kind")
+                        "a hybrid model, state rows), index keys or state "
+                        "rows beside its K/V pages, or pages per layer kind")
                 self.engines[task_type] = eng
                 loaded.append(task_type)
             except (EngineLoadError, KeyError) as exc:
@@ -539,7 +539,7 @@ class Worker:
             # and a latent-attention engine's scan counters (mla_*); a
             # hybrid engine's state pool and what its kernels were handed
             for k in es:
-                if k.startswith(("moe_", "mla_", "kda_")) or k in (
+                if k.startswith(("moe_", "mla_", "kda_", "ssd_")) or k in (
                         "state_binds", "prefix_hits_without_state") or (
                         k.startswith("index_") and k != "index_pool_bytes"):
                     out[k] = out.get(k, 0) + int(es[k] or 0)

@@ -392,7 +392,7 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
     kv = jax.eval_shape(
         lambda: llama.init_kv_pools(
             cfg, 1 + BATCH * (ctx // 16), 16,
-            state_rows=BATCH if cfg.num_kda_layers else None,
+            state_rows=BATCH if cfg.num_state_layers else None,
             window_blocks=WINDOW_BLOCKS if kinds > 1 else None)
     )
     if mesh is None:
@@ -806,6 +806,70 @@ def test_hybrid_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch, tp,
     mi, hid = cfg.moe_intermediate_size, cfg.hidden_size
     assert f"[{tokens},{cfg.num_experts}," not in text
     assert f"bf16[{cfg.num_held_experts},{hid},{mi}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
+
+
+# --------------------------------------------------------------------- #
+# (e) a state-space mixer beside attention: the state pool beside K/V pages
+# --------------------------------------------------------------------- #
+
+FALCON = "falcon-h1-34b-pp4-18l"
+
+
+def test_ssd_kernels_compile(v5e):
+    """``dgi_ssd_step`` (eight rows, 32 heads of 128 x 256 float32 in place
+    in the stacked pool, B / C a group) and ``dgi_ssd_chunk`` (the 10
+    chunks of 128 a one-piece round of 264 packed tokens is cut into), at
+    the published widths."""
+    from distributed_gpu_inference_tpu.models import ssd
+    from distributed_gpu_inference_tpu.ops import ssd_pallas
+
+    cfg = get_model_config(FALCON)
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    h, p, n, g = (cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size,
+                  cfg.ssm_num_groups)
+    pool = sds((cfg.num_layers, BATCH, h, p, n), jnp.float32)
+    group = sds((BATCH, g, n), jnp.float32)
+    lowered = jax.jit(ssd_pallas.ssd_step, donate_argnums=(5,)).lower(
+        sds((BATCH, h, p), jnp.float32), group, group,
+        sds((BATCH, h), jnp.float32), sds((h,), jnp.float32), pool,
+        sds((), jnp.int32), sds((BATCH,), bool), sds((BATCH,), bool))
+    assert _kernels(lowered) == {"dgi_ssd_step"}
+    lowered.compile()
+    q = cfg.ssm_chunk_size
+    c = BATCH + 264 // q
+    f32 = lambda *t: sds(t, jnp.float32)                      # noqa: E731
+    ops = ssd.ChunkOperands(
+        c=f32(c, g, q, n), b=f32(c, g, q, n), xdt=f32(c, h, p, q),
+        dlast=f32(c, h), el=f32(c, h, q), intra=f32(c, h, q, p))
+    flags = sds((c,), bool)
+    lowered = jax.jit(ssd_pallas.ssd_chunk_pass, donate_argnums=(1,)).lower(
+        ops, pool, sds((), jnp.int32), sds((c,), jnp.int32), flags, flags,
+        flags)
+    assert _kernels(lowered) == {"dgi_ssd_chunk"}
+    lowered.compile()
+
+
+@pytest.mark.parametrize("tp,s", [(None, 1), (264, 256)],
+                         ids=["scan-step", "Tp264"])
+def test_state_space_model_graphs_compile_and_copy_no_pool(v5e, tpu_dispatch,
+                                                           tp, s):
+    """The pipeline stage at its published widths, all 18 layers: a decode
+    step and the packed round. The state is read and written in place in
+    the stacked pool by the two SSD kernels, the K/V pages by theirs beside
+    it, and no array of a pool layer's shape exists."""
+    cfg = get_model_config(FALCON)
+    lowered = _forward_chunk_lowered(cfg, s, None, v5e, tp=tp)
+    found = _kernels(lowered)
+    want = {"dgi_paged_decode", "dgi_ssd_step", "dgi_qmm"} if tp is None \
+        else {"dgi_paged_write", "dgi_ragged_attention", "dgi_ssd_chunk"}
+    assert found == want, found
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    h, p, n = cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_state_size
+    whole = f"[{cfg.num_layers},{BATCH},{h},{p},{n}]"
+    assert whole in text
+    assert f"[{BATCH},{h},{p},{n}]" not in text.replace(whole, "")
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 1024 ** 3
 
 
