@@ -17,9 +17,10 @@ It fails (exit code != 0, no result line) when any phase fails:
 - the ``llm`` engine did not load, or the plane's worker row does not show
   the devices JAX reports;
 - on one chip, the compiled round graphs do not hold ``dgi_ragged_attention``,
-  ``dgi_paged_decode`` and ``dgi_qmm``; on a mesh, they hold any Pallas
-  call (a ``pallas_call`` has no partitioning rule — the mesh engine serves
-  from the XLA paths and says so here);
+  ``dgi_paged_decode`` and ``dgi_qmm``; on a mesh, they hold ``dgi_qmm``
+  (a bare ``pallas_call`` has no partitioning rule: the mesh engine's
+  projections and experts run the XLA paths) or lack an attention kernel
+  (those run a shard of heads a chip under ``shard_map``);
 - a request is not answered in full by the entry point it was sent to, the
   same greedy prompt differs queued / direct / streamed, a round raised
   (``engine_errors``), or a round graph compiled after set-up.
@@ -184,9 +185,13 @@ def phase_graphs(llm: Any, widths: List[int], mesh: bool,
             f"matmul={MATMUL_KERNEL if MATMUL_KERNEL in found else 'xla'} "
             f"compile={time.monotonic() - t1:.1f}s")
     if mesh:
-        need(not found_all, f"mesh graphs hold Pallas calls {found_all}")
-        say("  mesh engine: dispatch chose the XLA paths because it sees "
-            "the mesh (a pallas_call has no partitioning rule)")
+        attention = set() if dry else set(KERNELS) - {MATMUL_KERNEL}
+        need(found_all & set(KERNELS) == attention,
+             f"mesh graphs hold {sorted(found_all)}, not {sorted(attention)}")
+        say("  mesh engine: attention a shard of heads a chip "
+            f"({eng.stats['decode_attention']}, {eng.stats['ragged_kv_path']}"
+            "), projections and experts on the XLA paths (a bare "
+            "pallas_call has no partitioning rule)")
     elif not dry:
         need(found_all == set(KERNELS),
              f"one-chip graphs hold {sorted(found_all)}, not {KERNELS}")

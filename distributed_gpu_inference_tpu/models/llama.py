@@ -28,6 +28,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from distributed_gpu_inference_tpu.models.configs import ModelConfig
 from distributed_gpu_inference_tpu.ops.attention import paged_attention
@@ -625,14 +626,17 @@ def _moe_mlp(
       the layer's counters beside the output (``moe_gmm.expert_stats``),
       and the experts each token was routed to (``[T, k]``) last.
     - **dense over the expert axis** (``pallas=False``: a GSPMD mesh, which
-      refuses a ``pallas_call``). The combine is an einsum over ``E`` with
+      refuses a bare ``pallas_call``). The combine is an einsum over ``E`` with
       top-k-masked weights: where ``we_*`` shard their E axis over
       ``model`` each chip runs its local experts for all tokens and XLA
       inserts the combine all-reduce — expert parallelism without a
       hand-written all-to-all, at E/k times the active-path FLOPs (a
       constant 4x over two local experts for Mixtral on four chips).
       No counters. The routed form under a mesh (``shard_map`` around the
-      kernel) is the open upgrade.
+      kernel, as the attention kernels have it since PR 58:
+      ``attention_kernels``) is the open upgrade: at a scan step's 8 rows x
+      top-2 of 8 experts nearly every expert receives a pair, so it saves
+      nothing there (ROADMAP S3).
     """
     if pallas or cfg.held_experts is not None or cfg.n_shared_experts:
         # (a share of the experts and a shared expert have the routed form
@@ -817,22 +821,53 @@ def _split_layers(layers: Dict[str, Any], pallas: bool):
 # ---------------------------------------------------------------------------
 
 
-def _use_fused_decode(
-    cfg: ModelConfig, s: int, block_tables: jax.Array, block_size: int
+def attention_kernels(
+    cfg: ModelConfig, quantized_kv: bool, pallas: bool = True, heads=None
 ) -> bool:
-    """Trace-time choice of the fused Pallas write+attention decode path
-    (same dispatch facts as ops.attention.resolve_impl)."""
+    """Whether a graph's attention MAY take the K/V kernels (the backend,
+    the head width and the context then decide, as on one chip:
+    ``resolve_impl``). ``pallas``: the caller allows every kernel of the
+    graph (one chip). ``heads`` (``parallel/sharding.head_shards``: a mesh
+    whose only sharded axis is ``model``): the three attention kernels
+    alone, each a shard of heads a chip inside ``jax.shard_map`` -- the
+    pools, q and the new K/V rows are sharded on their head axis already
+    and pages never cross chips, so a shard's call is the one-chip kernel
+    at ``Hkv / tp`` heads. Not over int8 pools: the fused kernel's
+    per-token amax would reduce over the local heads only and break the
+    scale pools' contract (``kv_scale_sharding``)."""
+    return pallas or (
+        heads is not None and not quantized_kv
+        and cfg.num_kv_heads % heads.size == 0
+    )
+
+
+def decode_attention_path(
+    cfg: ModelConfig, padded_ctx: int, quantized_kv: bool,
+    pallas: bool = True, heads=None,
+) -> str:
+    """Which attention a one-token step's graph is built with -- a
+    trace-time fact beside ``ragged_kv_path``, from what dispatch can see
+    and no knob: ``fused`` (``dgi_paged_decode`` writes the step's rows
+    into the stacked pools and attends, in place: where the decode kernel
+    is taken, ``ops.attention.resolve_impl``, and the caller allows the
+    attention kernels) or ``xla`` (a layer of each pool sliced out,
+    scattered into and written back, and the row's whole padded table
+    gathered). Both step callers, ``forward_chunk`` and
+    ``forward_hidden_chunk``, ask here, and keep ``xla`` for a step that
+    brings its own attention (``dense_attn_fn``, ``attn_override``) or is
+    packed. A K/V model's: a latent model's steps are ``models/mla.py``'s."""
     from distributed_gpu_inference_tpu.ops.attention import resolve_impl
 
-    return s == 1 and resolve_impl(
-        q_seq=s,
-        head_dim=cfg.head_dim,
-        padded_ctx=block_tables.shape[1] * block_size,
-    ) == "pallas"
+    fused = attention_kernels(cfg, quantized_kv, pallas, heads) \
+        and resolve_impl(
+            q_seq=1, head_dim=cfg.head_dim, padded_ctx=padded_ctx
+        ) == "pallas"
+    return "fused" if fused else "xla"
 
 
 def ragged_kv_path(
-    cfg: ModelConfig, padded_ctx: int, quantized_kv: bool, pallas: bool = True
+    cfg: ModelConfig, padded_ctx: int, quantized_kv: bool,
+    pallas: bool = True, heads=None,
 ) -> str:
     """Which KV path a multi-token chunk's graph is built with — a
     trace-time fact, from what dispatch can see and no knob:
@@ -841,13 +876,15 @@ def ragged_kv_path(
       kernel address the stacked pools by layer index, so a round moves
       its tokens. Taken where the ragged kernel is taken
       (``ops.attention.resolve_impl``: a TPU backend, ``head_dim % 128 ==
-      0``, a padded context of at least 512) and the caller allows kernels
-      (``pallas``: no mesh).
+      0``, a padded context of at least 512) and the caller allows the
+      attention kernels (``attention_kernels``: one chip, or a mesh that
+      shards ``model`` alone, where they run a shard of heads a chip).
     - ``layer_copy``: the layer is sliced out of the stack, scattered into
       and written back — pool-sized copies in every layer. Everything
-      else (a mesh, the CPU, a head width the kernels refuse, int8 pools
-      with no in-place scale write), and by these facts alone: both chunk
-      callers, ``forward_chunk`` and ``forward_hidden_chunk``, ask here."""
+      else (a mesh with a ``seq`` axis, the CPU, a head width the kernels
+      refuse, int8 pools with no in-place scale write), and by these facts
+      alone: both chunk callers, ``forward_chunk`` and
+      ``forward_hidden_chunk``, ask here."""
     from distributed_gpu_inference_tpu.ops.attention import resolve_impl
 
     if cfg.latent_kv:
@@ -859,8 +896,40 @@ def ragged_kv_path(
     ragged = resolve_impl(
         q_seq=2, head_dim=cfg.head_dim, padded_ctx=padded_ctx
     ) == "ragged"
-    return "in_place" if pallas and ragged and not quantized_kv \
-        else "layer_copy"
+    return "in_place" if ragged and not quantized_kv and attention_kernels(
+        cfg, quantized_kv, pallas, heads) else "layer_copy"
+
+
+def _fused_decode(block_size: int, window: Optional[int], heads=None,
+                  **sel):
+    """``_layer_step``'s ``fused_decode`` over bf16 pools: (q, the step's k
+    and v rows, k_pool, v_pool, layer_idx, block_tables, positions,
+    kv_lens) → (attn, k_pool, v_pool), the pools written in place. Under
+    ``heads`` (``attention_kernels``) inside ``jax.shard_map`` over
+    ``model``: each chip writes and attends its own heads of the same
+    pages."""
+    from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention_fused,
+    )
+
+    def fused(q, k, v, k_pool, v_pool, layer_idx, tables, positions, lens):
+        return paged_decode_attention_fused(
+            q, k, v, k_pool, v_pool, layer_idx, tables, positions, lens,
+            block_size, window=window, **sel,
+        )
+
+    if heads is None:
+        return fused
+    from distributed_gpu_inference_tpu.parallel.sharding import (
+        CHUNK_HEADS, POOL_HEADS,
+    )
+
+    return jax.shard_map(
+        fused, mesh=heads.mesh,
+        in_specs=(CHUNK_HEADS,) * 3 + (POOL_HEADS,) * 2 + (P(),) * 4,
+        out_specs=(CHUNK_HEADS, POOL_HEADS, POOL_HEADS),
+        check_vma=False,
+    )
 
 
 def _in_place_kv(
@@ -873,18 +942,26 @@ def _in_place_kv(
     token_index: Optional[jax.Array] = None,
     num_tokens: Optional[int] = None,
     kind: Optional[str] = None,
+    pallas: bool = True,
+    heads=None,                 # ``attention_kernels``: a shard of heads a
+                                # chip, or None on one chip
 ):
     """``_layer_step``'s ``in_place`` for a multi-token chunk on the kernel
     path, or None where ``ragged_kv_path`` says ``layer_copy``: the page
-    write plan (the same for every layer of a kind -- ``block_tables`` is
-    the kind's -- so built here, outside the scan) and attention over the
-    kind's stacked pools."""
+    write into the kind's stacked pools (its plan the same for every layer
+    of a kind -- ``block_tables`` is the kind's -- so built here, outside
+    the scan) and attention over them. Under ``heads`` both run inside
+    ``jax.shard_map`` over ``model``, each chip on its own heads of the
+    same pages: the plan is replicated, and its tiles are sized by the
+    SHARD's page."""
     if positions.shape[1] == 1 or ragged_kv_path(
-        cfg, block_tables.shape[1] * block_size, "k_scale" in kv
+        cfg, block_tables.shape[1] * block_size, "k_scale" in kv, pallas,
+        heads,
     ) != "in_place":
         return None
     from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
-        page_write_plan, ragged_paged_attention,
+        PageWritePlan, page_write_plan, ragged_paged_attention,
+        write_kv_pages_in_place,
     )
 
     kind = kind or cfg.attn_kinds[0]
@@ -892,18 +969,40 @@ def _in_place_kv(
     pool = kv[kind_pools(cfg, kind)[0]]
     plan = page_write_plan(
         block_tables, positions, block_size,
-        page_bytes=pool.shape[2] * pool.shape[3] * pool.shape[4]
-        * pool.dtype.itemsize,
+        page_bytes=pool.shape[2] // (1 if heads is None else heads.size)
+        * pool.shape[3] * pool.shape[4] * pool.dtype.itemsize,
         token_index=token_index, num_tokens=num_tokens,
     )
 
-    def attn_stacked(q, k_pool, v_pool, layer_idx, keep=None):
+    def write(k_rows, v_rows, k_pool, v_pool, layer_idx, *where):
+        return write_kv_pages_in_place(
+            k_rows, v_rows, k_pool, v_pool, layer_idx,
+            PageWritePlan(*where, plan.tile))
+
+    def attn(q, k_pool, v_pool, layer_idx, tables, pos, lens, keep=None):
         return ragged_paged_attention(
-            q, k_pool, v_pool, block_tables, positions, kv_lens, block_size,
-            window=window, layer_idx=layer_idx, keep=keep,
+            q, k_pool, v_pool, tables, pos, lens, block_size, window=window,
+            layer_idx=layer_idx, keep=keep,
         )
 
-    return plan, attn_stacked
+    where = tuple(plan[:-1])        # the plan's arrays; its tile is static
+    if heads is not None:
+        from distributed_gpu_inference_tpu.parallel.sharding import (
+            CHUNK_HEADS, POOL_HEADS, ROW_HEADS,
+        )
+
+        pools = (POOL_HEADS, POOL_HEADS)
+        write = jax.shard_map(
+            write, mesh=heads.mesh,
+            in_specs=(ROW_HEADS, ROW_HEADS, *pools) + (P(),) * (1 + len(where)),
+            out_specs=pools, check_vma=False)
+        attn = jax.shard_map(
+            attn, mesh=heads.mesh,
+            in_specs=(CHUNK_HEADS, *pools) + (P(),) * 4,
+            out_specs=CHUNK_HEADS, check_vma=False)
+    return (lambda *rows_pools_layer: write(*rows_pools_layer, *where),
+            lambda *q_pools_layer, **sel: attn(
+                *q_pools_layer, block_tables, positions, kv_lens, **sel))
 
 
 class _IndexPlan(NamedTuple):
@@ -1050,10 +1149,13 @@ def _layer_step(
     moe_live: Optional[jax.Array] = None,  # hidden's tokens the experts
                                   # route (None: all of them)
     emit_routing: bool = False,   # scan-emit the experts of every token
-    in_place=None,                # (page write plan, (q, k_pool, v_pool,
+    in_place=None,                # ((k rows, v rows, k_pool, v_pool,
+                                  # layer_idx) → pools, (q, k_pool, v_pool,
                                   # layer_idx) → attn): a multi-token chunk
                                   # writes and reads the STACKED pools
                                   # (``_in_place_kv``)
+    heads=None,                   # ``fused_decode`` a shard of heads a chip
+                                  # (``attention_kernels``; None: one chip)
     index=None,                   # an indexer's view of the chunk, the same
                                   # for every layer (``_index_plan``)
     kind: Optional[str] = None,   # the layer's attention kind: its head
@@ -1076,11 +1178,14 @@ def _layer_step(
     (ops/paged_attention_pallas). ``in_place`` (S > 1 on the kernel path:
     ``ragged_kv_path``) does the same for a multi-token chunk with two
     kernels: the page write goes into the stacked pools by layer index
-    (``dgi_paged_write``) and the ragged kernel reads them there. The third
-    form — XLA scatter into a dynamically-indexed layer slice, then the
-    write-back — is what a mesh, the CPU and int8 pools take: on a TPU it
-    costs pool-sized HBM copies in every layer (scatter-preferred vs
-    kernel-required layout, plus custom-call operand materialization;
+    (``dgi_paged_write``) and the ragged kernel reads them there. Under a
+    mesh that shards ``model`` alone both forms run a shard of heads a chip
+    (``heads``: ``jax.shard_map`` over ``model``, the same pools in place).
+    The third form — XLA scatter into a dynamically-indexed layer slice,
+    then the write-back — is what a mesh with a ``seq`` axis, the CPU and
+    int8 pools take: on a TPU it costs pool-sized HBM copies in every layer
+    (scatter-preferred vs kernel-required layout, plus custom-call operand
+    materialization;
     round-2 profiling for decode, PERF.md section 5 for the ragged round:
     ~21 ms of a 34 ms Mistral round).
 
@@ -1242,21 +1347,18 @@ def _layer_step(
                         k_scale=k_scale_pool, v_scale=v_scale_pool,
                     )
             else:
-                attn, k_pool, v_pool = paged_decode_attention_fused(
+                attn, k_pool, v_pool = _fused_decode(
+                    block_size, window, heads, **sel
+                )(
                     q, k.astype(k_pool.dtype), v.astype(v_pool.dtype),
                     k_pool, v_pool, cache_layer, block_tables,
-                    write_positions, kv_lens, block_size,
-                    window=window, **sel,
+                    write_positions, kv_lens,
                 )
         elif in_place is not None:
-            from distributed_gpu_inference_tpu.ops.paged_attention_pallas import (
-                write_kv_pages_in_place,
-            )
-
-            plan, attn_stacked = in_place
-            k_pool, v_pool = write_kv_pages_in_place(
+            write, attn_stacked = in_place
+            k_pool, v_pool = write(
                 k.reshape(-1, nkv, d), v.reshape(-1, nkv, d),
-                k_pool, v_pool, cache_layer, plan,
+                k_pool, v_pool, cache_layer,
             )
             attn = attn_stacked(q, k_pool, v_pool, cache_layer, **sel)
         else:
@@ -1379,16 +1481,25 @@ def forward_chunk(
     collect_routing: bool = False,
                           # also return ChunkOutput.routing (MoE models)
     pallas: bool = True,
-                          # gate for EVERY Pallas kernel in the graph (the
-                          # fused decode kernel, the ragged kernel, the int8
-                          # matmul): an engine serving over a GSPMD mesh
-                          # must pass False — a pallas_call has no
-                          # partitioning rule (XLA refuses the graph), and
-                          # the fused kernel's in-VMEM per-token quantize
-                          # amax (int8 pools) would reduce over LOCAL heads
-                          # only, breaking the all-reduce-max scale contract
-                          # (parallel/sharding.py). Dispatch cannot see the
-                          # mesh from inside a trace, so the caller says it.
+                          # gate for every BARE Pallas kernel in the graph
+                          # (the int8 matmul, the routed experts, the
+                          # attention kernels, a recipe's state, latent and
+                          # index kernels): an engine serving over a GSPMD
+                          # mesh must pass False — a bare pallas_call has
+                          # no partitioning rule (XLA refuses the graph).
+                          # Dispatch cannot see the mesh from inside a
+                          # trace, so the caller says it.
+    heads=None,
+                          # with ``pallas=False``, the mesh's head sharding
+                          # (``parallel/sharding.head_shards``; None on one
+                          # chip and under a ``seq`` axis): the attention
+                          # kernels alone run all the same, a shard of
+                          # heads a chip inside ``jax.shard_map`` over
+                          # ``model``, on the stacked pools in place
+                          # (``attention_kernels``: not over int8 pools,
+                          # whose per-token amax would reduce over LOCAL
+                          # heads only and break the all-reduce-max scale
+                          # contract, parallel/sharding.py)
     packing: Optional[Packing] = None,
                           # the chunk's live tokens on one flat axis:
                           # token_ids and positions are [Tp] (padding at
@@ -1425,6 +1536,11 @@ def forward_chunk(
             block_size=block_size, last_only=last_only,
             with_logits=with_logits, collect_routing=collect_routing,
             pallas=pallas, packing=packing,
+        )
+    if pallas and heads is not None:
+        raise ValueError(
+            "pallas=True allows every kernel bare, which is one chip's: a "
+            "caller under a mesh passes pallas=False beside heads"
         )
     unpack = to_rect = tp = None
     if packing is not None:
@@ -1493,10 +1609,11 @@ def forward_chunk(
         window = cfg.sliding_window if kind == "sliding" else None
         cos, sin = rope_tables(cfg, kind, safe_pos)
         in_place = None
-        if pallas and dense_attn_fn is None and attn_override is None:
+        if dense_attn_fn is None and attn_override is None:
             in_place = _in_place_kv(
                 cfg, kv, kind_tables, positions, kv_lens, block_size,
                 token_index=to_rect, num_tokens=tp, kind=kind,
+                pallas=pallas, heads=heads,
             )
         if attn_override is not None:
             # int8 pools: the override receives the layer's scale pools too
@@ -1528,8 +1645,10 @@ def forward_chunk(
             sin=sin,
             attn_fn=attn_fn,
             fused_decode=(
-                pallas
-                and _use_fused_decode(cfg, s, kind_tables, block_size)
+                s == 1
+                and decode_attention_path(
+                    cfg, kind_tables.shape[1] * block_size, quant_kv,
+                    pallas, heads) == "fused"
                 and dense_attn_fn is None
                 and attn_override is None
                 and packing is None
@@ -1545,6 +1664,7 @@ def forward_chunk(
             index=index,
             kind=kind,
             mixer=mixer,
+            heads=heads,
         )
 
     steps = {kind: kind_step(kind) for kind in kinds}
@@ -1741,9 +1861,9 @@ def forward_hidden_chunk(
         cos=cos,
         sin=sin,
         attn_fn=attn_fn,
-        fused_decode=_use_fused_decode(
-            cfg, hidden.shape[1], block_tables, block_size
-        ),
+        fused_decode=hidden.shape[1] == 1 and decode_attention_path(
+            cfg, block_tables.shape[1] * block_size, False
+        ) == "fused",
         kv_lens=kv_lens,
         stacked=stacked,
         in_place=_in_place_kv(

@@ -37,7 +37,9 @@ def pallas_backend() -> bool:
     is an error, not a reason to serve from another path. What this gate
     cannot see is partitioning: a caller whose operands are GSPMD-sharded
     over a mesh must ask for the XLA path itself (``forward_chunk``'s
-    ``pallas=False``), because a ``pallas_call`` has no partitioning rule."""
+    ``pallas=False``), because a bare ``pallas_call`` has no partitioning
+    rule, or run the kernel a shard a chip inside ``jax.shard_map``
+    (``forward_chunk``'s ``heads``: the attention kernels)."""
     if os.environ.get("DGI_DISABLE_PALLAS"):
         return False
     return jax.default_backend() == "tpu"

@@ -31,7 +31,7 @@ Pipeline (``stage``) sharding slices the L axis instead — see
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -85,10 +85,47 @@ def param_shardings(mesh: Mesh) -> Dict[str, Any]:
     }
 
 
+# what an attention kernel's operands are sharded by where it runs a shard of
+# heads a chip (``jax.shard_map`` over ``model``): the pools' spec
+# (:func:`kv_sharding`) and the column-sharded q / k / v's. Everything else
+# (block tables, positions, lengths, the layer index, a write plan) is
+# replicated, ``P()``
+POOL_HEADS = P(None, None, AXIS_MODEL, None, None)   # [L, N, Hkv, Bk, D]
+CHUNK_HEADS = P(None, None, AXIS_MODEL, None)        # [B, S, heads, D]
+ROW_HEADS = P(None, AXIS_MODEL, None)                # [T, heads, D]
+
+
 def kv_sharding(mesh: Mesh) -> NamedSharding:
     """KV pools [L, N, Hkv, Bk, D]: heads sharded over ``model`` so each TP
     shard attends with its own KV heads — pages never cross chips."""
-    return _ns(mesh, None, None, AXIS_MODEL, None, None)
+    return NamedSharding(mesh, POOL_HEADS)
+
+
+class HeadShards(NamedTuple):
+    """The mesh an engine's heads are sharded over and nothing else: what
+    ``models/llama.forward_chunk`` needs to run an attention kernel a shard
+    (``jax.shard_map`` over ``model``, operands by the specs above), as a
+    value it can read inside a trace, where the mesh itself cannot be
+    seen. A shard's call is the one-chip kernel at ``Hkv / size`` KV
+    heads."""
+
+    mesh: Mesh
+
+    @property
+    def size(self) -> int:
+        """Shards of the head axis."""
+        return self.mesh.shape[AXIS_MODEL]
+
+
+def head_shards(mesh: Optional[Mesh]) -> Optional[HeadShards]:
+    """The head sharding of a mesh whose only sharded axis is ``model``;
+    None on one chip and under any other axis (``seq``: the pools' block
+    axis is sharded too and attention goes through
+    ``parallel/ring_attention.py``'s partial-softmax ops)."""
+    if mesh is None or AXIS_MODEL not in mesh.shape or any(
+            n > 1 for axis, n in mesh.shape.items() if axis != AXIS_MODEL):
+        return None
+    return HeadShards(mesh)
 
 
 def kv_sharding_seq(mesh: Mesh) -> NamedSharding:

@@ -57,7 +57,9 @@ from distributed_gpu_inference_tpu.runtime.speculative import (
     draft_apply,
     init_draft_params,
 )
-from distributed_gpu_inference_tpu.utils.device import compile_log
+from distributed_gpu_inference_tpu.utils.device import (
+    compile_log, roomy_stack,
+)
 from distributed_gpu_inference_tpu.utils.data_structures import (
     InferenceRequest,
     InferenceResponse,
@@ -520,6 +522,17 @@ class TPUEngine:
             # int8 codes + scale pages as an atomic pair through L2/L3
             # (runtime/kv_cache.py store_spilled/_probe_spill).
             self.mesh = mesh
+            # what ``forward_chunk`` can see of the mesh inside a trace: its
+            # head sharding where ``model`` is its only sharded axis, for
+            # the attention kernels to run a shard of heads a chip; None on
+            # one chip and under a ``seq`` axis (parallel/sharding.py)
+            self._heads = None
+            if mesh is not None:
+                from distributed_gpu_inference_tpu.parallel.sharding import (
+                    head_shards,
+                )
+
+                self._heads = head_shards(mesh)
             self._seq_axis = 1
             if self.model_cfg.latent_kv:
                 self._refuse_latent(mesh)
@@ -771,14 +784,17 @@ class TPUEngine:
                 # scan goes out ahead of the readback
                 "round_host_exposed_s": 0.0,
                 # which KV path the multi-token graphs were built with
-                # (in_place / layer_copy): a trace-time fact, from the
-                # predicate forward_chunk itself dispatches on
-                "ragged_kv_path": llama.ragged_kv_path(
+                # (in_place / layer_copy) and which attention the scan
+                # graphs hold (fused / xla): trace-time facts, from the
+                # predicates forward_chunk itself dispatches on
+                **{name: path(
                     self.model_cfg,
                     self.cfg.max_blocks_per_seq * self.cfg.block_size,
                     quantized_kv=self.kv_dtype == jnp.int8,
-                    pallas=self.mesh is None,
-                ),
+                    pallas=self.mesh is None, heads=self._heads,
+                ) for name, path in (
+                    ("ragged_kv_path", llama.ragged_kv_path),
+                    ("decode_attention", llama.decode_attention_path))},
                 # what a cached token is: per-head K and V, or one latent;
                 # hybrid: latent pages beside a state row a sequence
                 # kv+index: K/V pages and an index key a token beside them
@@ -1197,14 +1213,17 @@ class TPUEngine:
     def _build_jit_fns(self) -> None:
         cfg, bs = self.model_cfg, self.cfg.block_size
         m = self._table_cols
-        # every Pallas kernel in the serving graphs (fused decode, ragged
-        # attention, int8 matmul) is a custom call with no GSPMD
-        # partitioning rule — XLA refuses a sharded graph that holds one —
-        # so a mesh engine serves from the XLA paths, which partition and
-        # all-reduce. Kernel dispatch sees the backend, not the mesh: the
-        # engine is where the mesh is known, so the engine says it.
+        # a bare Pallas kernel in a serving graph is a custom call with no
+        # GSPMD partitioning rule — XLA refuses a sharded graph that holds
+        # one — so a mesh engine's projections and experts run the XLA
+        # paths, which partition and all-reduce. Its attention runs the
+        # kernels all the same where the mesh shards ``model`` alone: inside
+        # ``jax.shard_map``, a shard of heads a chip on the stacked pools
+        # in place (``llama.attention_kernels``). Kernel dispatch sees the
+        # backend, not the mesh: the engine is where the mesh is known, so
+        # the engine says both.
         fwd = functools.partial(
-            llama.forward_chunk, pallas=self.mesh is None
+            llama.forward_chunk, pallas=self.mesh is None, heads=self._heads
         )
         # what a scan carries beside the pools (a model with an indexer: its
         # rows' index keys in context order, laid out once a scan)
@@ -2046,7 +2065,16 @@ class TPUEngine:
         round graphs, where scans come too, the two that put a round
         behind an unread scan: ``merge_core`` and, a packed length,
         ``chain_round[Tp=...]``, run once as well. Plain (non-speculative)
-        engines; call while no round is in flight."""
+        engines; call while no round is in flight. The tracing and the
+        lowering run with their frames in one chunk of the thread's Python
+        stack (``utils/device.roomy_stack``): no call of theirs sits on a
+        chunk's edge, whichever thread this is and however deep."""
+        return roomy_stack(
+            lambda: self._lower_serving_graphs(decode_steps, ragged_widths))
+
+    def _lower_serving_graphs(
+        self, decode_steps: Sequence[int], ragged_widths: Sequence[int],
+    ) -> Dict[str, Any]:
         self.collect_scan()
         b = len(self.slots)
         core = self._sync_core()

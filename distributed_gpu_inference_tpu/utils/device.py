@@ -10,8 +10,10 @@ something the operator asks for (``JAX_PLATFORMS=cpu`` / ``--platform cpu``).
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
+import types
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
@@ -224,3 +226,46 @@ def compile_log() -> CompileLog:
         if _compile_log is None:
             _compile_log = CompileLog()
         return _compile_log
+
+
+@functools.cache
+def _roomy_frame():
+    """``lambda call: call()`` with 64 Ki local slots nobody uses: a frame
+    of half a MiB. This leans on CPython internals the language does not
+    promise: since 3.11 a frame's locals lie in the thread's data stack,
+    which grows in chunks of 16 KiB, doubled until the frame that asked
+    fits, so half a MiB and a little gets 1 MiB (``Python/pystate.c``
+    ``push_chunk``; read at 3.12, which this repository runs on here and
+    on the chip). On 3.10, which ``requires-python`` still admits, frames
+    are heap objects: there is no edge to avoid and this frame is half a
+    MiB for nothing while it is live (not run there: no 3.10 at hand). A
+    later CPython that lays frames out otherwise makes it a plain call
+    again: ``tests/test_startup_spans.py`` holds the frame's size and
+    depth, the chip's ``startup.graphs_lower_s`` its effect."""
+    def roomy_stack(call):
+        return call()
+
+    return types.FunctionType(
+        roomy_stack.__code__.replace(
+            co_varnames=("call",) + tuple(f"_{i}" for i in range(1 << 16)),
+            co_nlocals=(1 << 16) + 1),
+        globals(), "roomy_stack")
+
+
+def roomy_stack(call):
+    """``call()`` with the frames beneath it in ONE chunk of the thread's
+    Python data stack. CPython keeps a thread's frames in chunks of 16 KiB,
+    maps a new one when a call finds no room in the last and unmaps it when
+    that call returns, so a hot call that happens to sit on a chunk's edge
+    pays an ``mmap`` and a ``munmap`` EVERY time it is made; in a process
+    that holds a TPU those two cost 0.1-0.9 ms. Tracing a serving graph
+    and lowering its kernels is Python a hundred frames deep whose inner
+    calls are made thousands of times a graph: which of them falls on an
+    edge depends on the thread and on every frame above (one more ``with``
+    or helper moved a start by seconds: PERF.md section 7 (64), ROADMAP
+    D7), and the fused decode kernel inside ``shard_map`` lowered in 5.3 s
+    a scan graph where it takes 0.5 s (PERF.md section 6, PR 58). A frame
+    that needs half a MiB makes CPython map a chunk of twice that, and
+    every frame beneath it lies in the room left: no edge to fall on. A
+    stack that outgrows the room gets further chunks, as ever."""
+    return _roomy_frame()(call)

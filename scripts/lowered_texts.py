@@ -14,8 +14,8 @@ file paths and line numbers, which differ between two trees that lower the
 same kernel. ``lower_s`` is this CPU's time to trace and lower, a hint for
 a cell's ``timing.graphs_s`` and never a device metric. The graphs are the
 ones ``tests/test_tpu_lowering.py`` compiles (``_forward_chunk_lowered``):
-a scan step and the packed round at its middle rung, per latent
-configuration (PERF.md section 6, PRs 53-54)."""
+a scan step and the packed round at its middle rung, per one-chip
+configuration (PERF.md section 6, PRs 53-54 and 58)."""
 import base64
 import hashlib
 import json
@@ -53,6 +53,20 @@ GRAPHS = {
     "kimi.Tp264": (lowering.KIMI, 264, 256, lowering.PANGU_CTX),
     "glm.scan-step": (lowering.GLM, None, 1, lowering.GLM_CTX),
     "glm.Tp264": (lowering.GLM, 264, 256, lowering.GLM_CTX),
+    # the K/V recipe's one-chip configurations (PR 58: an edit to
+    # ``models/llama.py``'s attention calls that a mesh alone may trace)
+    "mistral.scan-step": ("mistral-7b", None, 1, lowering.CTX),
+    "mistral.Tp264": ("mistral-7b", 264, 256, lowering.CTX),
+    "qwen.scan-step": ("qwen2.5-7b", None, 1, lowering.CTX),
+    "qwen.Tp264": ("qwen2.5-7b", 264, 256, lowering.CTX),
+    "olmoe.scan-step": ("olmoe-1b-7b", None, 1, lowering.CTX),
+    "olmoe.Tp264": ("olmoe-1b-7b", 264, 256, lowering.CTX),
+    "falcon.scan-step": (lowering.FALCON, None, 1, lowering.CTX),
+    "falcon.Tp264": (lowering.FALCON, 264, 256, lowering.CTX),
+    "keye.scan-step": (lowering.KEYE, None, 1, lowering.KEYE_CTX),
+    "keye.Tp264": (lowering.KEYE, 264, 256, lowering.KEYE_CTX),
+    "laguna.scan-step": (lowering.LAGUNA, None, 1, lowering.LAGUNA_CTX),
+    "laguna.Tp264": (lowering.LAGUNA, 264, 256, lowering.LAGUNA_CTX),
 }
 
 

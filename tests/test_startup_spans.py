@@ -20,7 +20,11 @@ from distributed_gpu_inference_tpu.utils.data_structures import (
     TpuTopology,
     WorkerState,
 )
-from distributed_gpu_inference_tpu.utils.device import CompileLog, compile_log
+from distributed_gpu_inference_tpu.utils.device import (
+    CompileLog,
+    compile_log,
+    roomy_stack,
+)
 
 TRACE = "/jax/core/compile/jaxpr_trace_duration"
 LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
@@ -163,6 +167,61 @@ def test_a_phase_keeps_its_seconds_and_its_start():
 
 
 # --------------------------------------------------------------------- #
+# (a2) the roomy frame the graphs are traced and lowered beneath
+# --------------------------------------------------------------------- #
+
+def _frames_between(call):
+    """How many frames ``call`` finds between itself and this one."""
+    import sys
+
+    here = sys._getframe()
+
+    def count():
+        frame, n = sys._getframe(1), 0
+        while frame is not here:
+            frame, n = frame.f_back, n + 1
+        return n
+
+    return call(count)
+
+
+def test_a_roomy_stack_is_one_frame_of_half_a_mib():
+    """One frame more than a plain call, and that frame's locals alone
+    are past CPython's 16 KiB chunk thirty times over: what is called
+    beneath it lies in the room its chunk leaves."""
+    assert _frames_between(roomy_stack) == _frames_between(lambda f: f()) + 1
+    seen = {}
+
+    def look():
+        import sys
+
+        code = sys._getframe(1).f_code
+        seen.update(name=code.co_name, slots=code.co_nlocals)
+
+    roomy_stack(look)
+    assert seen["name"] == "roomy_stack" and seen["slots"] * 8 >= 1 << 19
+
+
+@pytest.mark.parametrize("where", ["main", "thread", "nested"])
+def test_a_roomy_stack_returns_and_raises_what_the_call_does(where):
+    def run(out):
+        out.append(roomy_stack(lambda: 7))
+        with pytest.raises(ZeroDivisionError):
+            roomy_stack(lambda: 1 // 0)
+
+    out = []
+    if where == "thread":
+        thread = threading.Thread(target=run, args=(out,))
+        thread.start()
+        thread.join()
+    elif where == "nested":
+        roomy_stack(lambda: run(out))
+    else:
+        run(out)
+    assert out == [7]
+
+
+# --------------------------------------------------------------------- #
 # (b) a tiny engine's start
 # --------------------------------------------------------------------- #
 
@@ -177,7 +236,14 @@ def build(params=None):
 
 @pytest.fixture(scope="module")
 def started():
-    """An engine built and its round graphs lowered: what a start leaves."""
+    """An engine built and its round graphs lowered: what a start leaves.
+    The process has one log and its rows stop growing at ``ROWS_KEPT``,
+    which a worker process that ran many files before this one may have
+    passed: the rows it kept are dropped first, so this module's are
+    there to be read whatever ran before."""
+    log = compile_log()
+    with log._lock:
+        del log.rows[:]
     engine, graphs = build()
     return {"engine": engine, "graphs": graphs,
             "startup": engine.get_stats()["startup"]}
@@ -400,3 +466,27 @@ def test_the_heartbeat_carries_the_start_and_the_plane_shows_it(worker):
         assert ('worker_compile_seconds_total{stage="%s",worker="w1"} %s'
                 % (stage_, secs)) in text
     assert 'worker_compile_misses_total{worker="w1"} 13.0' in text
+
+
+def test_the_graphs_are_lowered_beneath_a_roomy_frame(monkeypatch):
+    """``lower_serving_graphs`` traces and lowers inside ``roomy_stack``:
+    the engine's jitted functions are asked to lower with that frame
+    above them."""
+    import sys
+
+    engine, _ = build()
+    seen = []
+    lower = engine._decode_multi_fn.lower
+
+    class Spy:
+        def lower(self, *args, **kwargs):
+            frame = sys._getframe()
+            while frame is not None:
+                seen.append(frame.f_code.co_name)
+                frame = frame.f_back
+            return lower(*args, **kwargs)
+
+    monkeypatch.setattr(engine, "_decode_multi_fn", Spy())
+    graphs = engine.lower_serving_graphs([1], [])
+    assert set(graphs) == {"decode_multi[T=1]", "chain_sched"}
+    assert "roomy_stack" in seen

@@ -376,13 +376,15 @@ def test_expert_step_kernel_compiles(v5e, model, rows):
 # (b) the whole serving graph, one chip and a model=4 mesh
 # --------------------------------------------------------------------- #
 
-def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
+def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX,
+                           quantized_kv=False):
     """``forward_chunk`` for int8 ``cfg`` at [BATCH, s], lowered for one
     device (``mesh=None``) or sharded over ``mesh`` by the engine's own
-    rules, with ``pallas`` set the way ``TPUEngine._build_jit_fns`` sets
-    it. ``tp``: the plain ragged round's form, ``tp`` live tokens packed
-    on one axis with [BATCH, s] the rectangle attention sees. ``ctx``:
-    tokens of context a row's block table and the pool hold."""
+    rules, with ``pallas`` and ``heads`` set the way
+    ``TPUEngine._build_jit_fns`` sets them. ``tp``: the plain ragged
+    round's form, ``tp`` live tokens packed on one axis with [BATCH, s] the
+    rectangle attention sees. ``ctx``: tokens of context a row's block
+    table and the pool hold. ``quantized_kv``: int8 pools."""
     params = jax.eval_shape(
         lambda: quantize_params(
             llama.init_params(cfg, jax.random.PRNGKey(0)), "int8"
@@ -392,6 +394,7 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
     kv = jax.eval_shape(
         lambda: llama.init_kv_pools(
             cfg, 1 + BATCH * (ctx // 16), 16,
+            dtype=jnp.int8 if quantized_kv else None,
             state_rows=BATCH if cfg.num_state_layers else None,
             window_blocks=WINDOW_BLOCKS if kinds > 1 else None)
     )
@@ -402,7 +405,8 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
         rep = one
     else:
         p_sh = sh.prune_rules(sh.param_shardings(mesh), params)
-        kv_sh = jax.tree.map(lambda _: sh.kv_sharding(mesh), kv)
+        kv_sh = {name: sh.kv_scale_sharding(mesh) if name.endswith("_scale")
+                 else sh.kv_sharding(mesh) for name in kv}
         rep = NamedSharding(mesh, P())
     place = lambda tree, shard: jax.tree.map(
         lambda a, s_: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s_),
@@ -413,7 +417,7 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX):
     def step(params, kv, toks, pos, tables, lens, *where):
         out = llama.forward_chunk(
             cfg, params, toks, pos, kv, tables, lens, block_size=16,
-            pallas=mesh is None,
+            pallas=mesh is None, heads=sh.head_shards(mesh),
             packing=llama.Packing(*where, s) if where else None,
         )
         return out.logits, out.kv
@@ -527,13 +531,14 @@ def _collectives(compiled):
 def test_packed_forward_chunk_on_model4_mesh_adds_no_collective(
         v5e, tpu_dispatch, model):
     """Under the mesh the gathers between the packed axis and the
-    rectangle run on replicated activations and head-sharded q/k/v: the
-    compiled program holds the collectives of the rectangle form (the two
-    all-reduces of a layer) and no other."""
+    rectangle run on replicated activations and head-sharded q/k/v, and the
+    attention kernels a shard of heads a chip on pages that never cross
+    chips: the compiled program holds the collectives of the rectangle
+    form (the two all-reduces of a layer) and no other."""
     mesh = Mesh(np.array(v5e).reshape(4), ("model",))
     cfg = get_model_config(model)
     packed = _forward_chunk_lowered(cfg, 256, mesh, v5e, tp=264)
-    assert _kernels(packed) == set()
+    assert _kernels(packed) == ROUND_KERNELS
     want = _collectives(_forward_chunk_lowered(cfg, 256, mesh, v5e).compile())
     assert want and _collectives(packed.compile()) == want
     # the two all-reduces of a layer (attention out, MLP or expert
@@ -542,23 +547,57 @@ def test_packed_forward_chunk_on_model4_mesh_adds_no_collective(
     assert want == ["all-reduce", "all-reduce"]
 
 
+# what a mesh that shards ``model`` alone takes of the kernels: attention,
+# a shard of heads a chip under ``shard_map`` (a bare ``pallas_call`` has no
+# partitioning rule: the projections and experts stay XLA's)
+ROUND_KERNELS = {"dgi_paged_write", "dgi_ragged_attention"}
+
+
+@pytest.mark.parametrize("model", ["mistral-7b", "mixtral-8x7b"])
 @pytest.mark.parametrize("s", [1, 256])
-def test_forward_chunk_compiles_on_model4_mesh(v5e, tpu_dispatch, s):
+def test_forward_chunk_compiles_on_model4_mesh(v5e, tpu_dispatch, s, model):
+    """The attention kernels and no other, and the pools where they lie:
+    no array of a chip's share of a pool layer in the compiled program (no
+    slice out of the stack, no write-back), its temporaries smaller than
+    that share of one layer's K."""
     mesh = Mesh(np.array(v5e).reshape(4), ("model",))
-    lowered = _forward_chunk_lowered(
-        get_model_config("mistral-7b"), s, mesh, v5e
-    )
-    # a pallas_call has no partitioning rule: under a mesh the engine asks
-    # for the XLA paths, and nothing else may slip a kernel in
-    assert _kernels(lowered) == set()
-    lowered.compile()
+    cfg = get_model_config(model)
+    lowered = _forward_chunk_lowered(cfg, s, mesh, v5e)
+    assert _kernels(lowered) == (
+        {"dgi_paged_decode"} if s == 1 else ROUND_KERNELS)
+    compiled = lowered.compile()
+    blocks, hkv = 1 + BATCH * (CTX // 16), cfg.num_kv_heads // 4
+    assert f"[{cfg.num_layers},{blocks},{hkv},16," in compiled.as_text()
+    assert f"[{blocks},{hkv},16,{cfg.head_dim}]" not in compiled.as_text()
+    if s == 1:
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < blocks * hkv * 16 * cfg.head_dim * 2
+
+
+@pytest.mark.parametrize("why,mesh_axes,quantized_kv", [
+    ("int8 pools", (("model", 4),), True),
+    ("a seq axis", (("seq", 2), ("model", 2)), False),
+])
+def test_forward_chunk_keeps_the_xla_paths_on_a_mesh_that(
+        v5e, tpu_dispatch, why, mesh_axes, quantized_kv):
+    """What ``attention_kernels`` refuses under a mesh traces no kernel at
+    all, as every mesh did before."""
+    names, shape = zip(*mesh_axes)
+    mesh = Mesh(np.array(v5e).reshape(shape), names)
+    cfg = get_model_config("mistral-7b")
+    assert sh.head_shards(mesh) is None or quantized_kv
+    for s in (1, 256):
+        assert _kernels(_forward_chunk_lowered(
+            cfg, s, mesh, v5e, quantized_kv=quantized_kv)) == set()
 
 
 def test_mesh_engine_traces_no_pallas_kernel(tpu_dispatch, cpu_devices):
-    """The engine — not the test — decides ``pallas=False`` under a mesh.
-    Geometry chosen so that dispatch WOULD pick every kernel (head_dim 128,
-    512-token tables, tileable int8 projections): a mesh engine that let
-    one through would fail to lower it for the CPU devices it runs on."""
+    """The engine — not the test — decides ``pallas=False`` under a mesh,
+    and over int8 pools hands the attention kernels no heads either
+    (``decode_attention`` says ``xla``). Geometry chosen so that dispatch
+    WOULD pick every kernel (head_dim 128, 512-token tables, tileable int8
+    projections): a mesh engine that let one through would fail to lower
+    it for the CPU devices it runs on."""
     from distributed_gpu_inference_tpu.models.configs import ModelConfig
     from distributed_gpu_inference_tpu.runtime.engine import (
         EngineConfig,
@@ -577,9 +616,11 @@ def test_mesh_engine_traces_no_pallas_kernel(tpu_dispatch, cpu_devices):
     eng = TPUEngine(
         cfg,
         EngineConfig(max_batch_size=2, max_seq_len=512, quantization="int8",
-                     multi_step=4),
+                     multi_step=4, kv_cache_dtype="int8"),
         mesh=mesh,
     )
+    assert (eng.stats["decode_attention"], eng.stats["ragged_kv_path"]) \
+        == ("xla", "layer_copy")
     # what chip_smoke.py reads the implementations from
     graphs = eng.lower_serving_graphs([4], [16])
     assert set(graphs) == {"decode_multi[T=4]", "ragged_round[Tp=64]",
