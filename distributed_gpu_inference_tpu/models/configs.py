@@ -10,7 +10,32 @@ the shard planner, KV pool sizing, and mesh sharding rules all consume it
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
+
+
+class LatentKind(NamedTuple):
+    """What a latent (MLA) layer of one attention kind is made of
+    (``ModelConfig.latent_kind``)."""
+
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    window: Optional[int]   # keys a query attends, itself among them
+    q_scale: float          # on the normed query latent (1.0: none)
+    kv_scale: float         # on the normed KV latent, not on the rope key
+
+    @property
+    def qk(self) -> int:
+        return self.nope + self.rope
+
+    @property
+    def cached(self) -> int:
+        """Values cached a token a layer: the latent and the rope key."""
+        return self.kv_rank + self.rope
 
 
 @dataclass(frozen=True)
@@ -136,6 +161,20 @@ class ModelConfig:
     # one learned scalar a head a token, ``sigmoid(x W_g)``, multiplied
     # into the head's attention output before ``W_o``
     head_gate: bool = False
+    # latent layers described per kind (``layer_types`` over latent pages:
+    # models/mla.py): a sliding layer's own latent ranks and head sizes (0:
+    # the full kind's), beside ``sliding_num_heads`` and
+    # ``sliding_rope_theta``. Its pages lie in a latent pool of their own,
+    # as wide as ITS cached row
+    sliding_kv_lora_rank: int = 0
+    sliding_q_lora_rank: int = 0
+    sliding_qk_nope_head_dim: int = 0
+    sliding_qk_rope_head_dim: int = 0
+    sliding_v_head_dim: int = 0
+    # a constant on a latent layer's two normed latents:
+    # ``sqrt(hidden_size / q_lora_rank)`` on ``c_q``, ``sqrt(hidden_size /
+    # kv_lora_rank)`` on ``c_kv`` (a layer's own ranks), not on the rope key
+    mla_lora_rescale: bool = False
     # a state-space (Mamba-2 / SSD) mixer BESIDE attention in every layer of
     # a K/V model: both read the layer's one normed input and their scaled
     # outputs are summed into the residual (models/ssd.py). ``ssm_num_heads``
@@ -191,7 +230,10 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: an indexer needs index_topk, "
                     "index_num_heads and an even index_head_dim")
-            if self.sliding_window is not None:
+            if self.sliding_window is not None and not (
+                    self.kv_lora_rank and self.mixed_attention):
+                # (latent pages per kind: the indexer is the FULL layers',
+                # whose pages and index keys are never released)
                 raise ValueError(
                     f"{self.name}: an indexer with sliding_window is not "
                     "built: pages that left the window are released, the "
@@ -322,6 +364,11 @@ class ModelConfig:
                 f"{self.name}: an indexer on a hybrid of linear and latent "
                 "attention (full_attn_layers) is not built: the selection "
                 "is carried from latent layer to latent layer")
+        if self.layer_types and self.index_types:
+            raise ValueError(
+                f"{self.name}: index_types beside layer_types over latent "
+                "pages is not built: every full layer holds the indexer, a "
+                "sliding layer none, and no layer borrows a selection")
         if self.index_query_input not in ("hidden", "q_latent") or (
                 self.index_query_input == "q_latent"
                 and not self.q_lora_rank):
@@ -422,25 +469,25 @@ class ModelConfig:
             if "sliding" in kinds and self.sliding_window is None:
                 raise ValueError(
                     f"{self.name}: a sliding layer needs sliding_window")
-            if self.kv_lora_rank:
-                raise ValueError(
-                    f"{self.name}: layer_types over latent pages "
-                    "(kv_lora_rank) is not built: a window is a rule over "
-                    "K/V pages (models/llama.py)")
             if len(set(kinds)) < 2 and sliding_only:
                 raise ValueError(
                     f"{self.name}: {', '.join(sliding_only)} on a model of "
                     "one attention kind: no layer would read them")
-        if self.sliding_num_heads % self.num_kv_heads:
+        self._check_latent_kinds()
+        if not self.kv_lora_rank and \
+                self.sliding_num_heads % self.num_kv_heads:
             raise ValueError(
                 f"{self.name}: sliding_num_heads must be divisible by "
                 "num_kv_heads (K/V heads that differ by kind are not "
                 "built: both pools have num_kv_heads)")
-        if self.mixed_attention and self.index_topk:
+        if self.mixed_attention and self.index_topk \
+                and not self.kv_lora_rank:
             raise ValueError(
-                f"{self.name}: an indexer on a model of mixed attention "
+                f"{self.name}: an indexer on a K/V model of mixed attention "
                 "kinds is not built: the index-key pool follows one block "
-                "table, and pages that left a window are released")
+                "table, and pages that left a window are released (the "
+                "latent recipe, models/mla.py, keeps the index keys under "
+                "the full kind's table)")
         if not 0.0 < self.partial_rotary_factor <= 1.0 or \
                 int(self.head_dim * self.partial_rotary_factor) % 2:
             raise ValueError(
@@ -453,12 +500,52 @@ class ModelConfig:
                 f"{self.name}: rope_yarn is (factor >= 1, original "
                 "positions, beta_fast, beta_slow, attention_factor)")
         if self.kv_lora_rank and (
-                self.partial_rotary_factor != 1.0 or self.rope_yarn
-                or self.head_gate):
+                self.partial_rotary_factor != 1.0 or self.rope_yarn):
             raise ValueError(
-                f"{self.name}: partial_rotary_factor, rope_yarn and "
-                "head_gate are the K/V recipe's (models/llama.py); the "
-                "latent layers would not read them")
+                f"{self.name}: partial_rotary_factor and rope_yarn are the "
+                "K/V recipe's (models/llama.py); the latent layers would "
+                "not read them")
+
+    def _check_latent_kinds(self) -> None:
+        """Latent layers described per kind (``layer_types`` over latent
+        pages), the latent rescale and the gate on latent attention: what
+        only they read, and what is not built with them."""
+        swa = [name for name in (
+            "sliding_kv_lora_rank", "sliding_q_lora_rank",
+            "sliding_qk_nope_head_dim", "sliding_qk_rope_head_dim",
+            "sliding_v_head_dim") if getattr(self, name)]
+        if swa and not (self.kv_lora_rank and self.mixed_attention):
+            raise ValueError(
+                f"{self.name}: {', '.join(swa)} without latent layers of "
+                "two kinds (kv_lora_rank and layer_types): no layer would "
+                "read them")
+        if self.mla_lora_rescale and not (
+                self.kv_lora_rank and self.q_lora_rank):
+            raise ValueError(
+                f"{self.name}: mla_lora_rescale scales the two normed "
+                "latents: it needs kv_lora_rank and q_lora_rank")
+        if not (self.kv_lora_rank and self.layer_types):
+            return
+        if not self.mixed_attention:
+            raise ValueError(
+                f"{self.name}: layer_types of one kind over latent pages is "
+                "not built: leave it out (every layer full), a model of "
+                "sliding latent layers alone has no pool")
+        unbuilt = [name for name, on in (
+            ("full_attn_layers", self.full_attn_layers),
+            ("mla_use_nope", self.mla_use_nope),
+            ("sandwich_norm", self.sandwich_norm),
+        ) if on]
+        if unbuilt:
+            raise ValueError(
+                f"{self.name}: {', '.join(unbuilt)} beside layer_types over "
+                "latent pages is not built: the parameter stacks split by "
+                "attention kind, MLP and indexer alone (models/mla.py)")
+        if bool(self.q_lora_rank) != bool(
+                self.latent_kind("sliding").q_rank):
+            raise ValueError(
+                f"{self.name}: a query low-rank on one attention kind and "
+                "not on the other is not built")
 
     @property
     def latent_kv(self) -> bool:
@@ -504,6 +591,28 @@ class ModelConfig:
                 int(self.head_dim * self.partial_rotary_factor),
                 self.rope_yarn)
 
+    def latent_kind(self, kind: str = "full") -> LatentKind:
+        """Sizes, rotation, window and latent rescale of a latent layer
+        of ``kind`` (a model of one kind: ``"full"``)."""
+        sliding = kind == "sliding" and self.mixed_attention
+
+        def own(name):
+            """The sliding kind's own value where it states one."""
+            return (getattr(self, "sliding_" + name) if sliding else 0) \
+                or getattr(self, name)
+
+        q_rank, kv_rank = own("q_lora_rank"), own("kv_lora_rank")
+        rescale = self.mla_lora_rescale
+        return LatentKind(
+            heads=self.heads_of(kind), q_rank=q_rank, kv_rank=kv_rank,
+            nope=own("qk_nope_head_dim"), rope=own("qk_rope_head_dim"),
+            v=own("v_head_dim"), theta=own("rope_theta"),
+            window=self.sliding_window if kind == "sliding" else None,
+            q_scale=(self.hidden_size / q_rank) ** 0.5
+            if rescale and q_rank else 1.0,
+            kv_scale=(self.hidden_size / kv_rank) ** 0.5 if rescale else 1.0,
+        )
+
     @property
     def described_per_layer(self) -> bool:
         """A K/V model whose layers are described one by one
@@ -520,6 +629,11 @@ class ModelConfig:
         indexer (empty without one)."""
         if not self.index_topk:
             return ()
+        if self.kv_lora_rank and self.layer_types:
+            # latent layers per kind: the full layers hold the indexer, a
+            # sliding layer none (and borrows none)
+            return tuple("full" if kind == "full" else "none"
+                         for kind in self.layer_types)
         return tuple(self.index_types) or ("full",) * self.num_layers
 
     @property
@@ -561,8 +675,18 @@ class ModelConfig:
 
     @property
     def num_cache_layers(self) -> int:
-        """Layers that write pages: the paged pool's layer axis."""
-        return self.num_layers - self.num_kda_layers
+        """Layers that write pages of the (full kind's) latent pool: its
+        layer axis."""
+        return self.num_layers - self.num_kda_layers \
+            - self.num_window_layers
+
+    @property
+    def num_window_layers(self) -> int:
+        """Sliding latent layers: the window kind's latent pool's layer
+        axis (0 for a model of one kind, and for the K/V recipe)."""
+        if not (self.kv_lora_rank and self.mixed_attention):
+            return 0
+        return self.layer_types.count("sliding")
 
     @property
     def qk_head_dim(self) -> int:
@@ -651,6 +775,8 @@ class ModelConfig:
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
         if self.latent_kv:
+            # (a sliding latent layer's tokens stay a window long: they are
+            # the window pool's, not a token's share of the full pool)
             return (self.num_cache_layers * (
                 self.kv_lora_rank + self.qk_rope_head_dim)
                 + self.num_index_layers * self.index_head_dim) * dtype_bytes
@@ -693,17 +819,17 @@ class ModelConfig:
                 + 3 * p * self.kda_conv_kernel + kh + p
             norms = 2 * h + kd
         else:
-            q = (h * self.q_lora_rank
-                 + self.q_lora_rank * nh * self.qk_head_dim
-                 if self.q_lora_rank else h * nh * self.qk_head_dim)
+            k = self.latent_kind(
+                self.layer_types[layer] if self.layer_types else "full")
+            nh = k.heads
+            q = (h * k.q_rank + k.q_rank * nh * k.qk
+                 if k.q_rank else h * nh * k.qk)
             attn = (
-                q + h * (self.kv_lora_rank + self.qk_rope_head_dim)
-                + self.kv_lora_rank * nh
-                * (self.qk_nope_head_dim + self.v_head_dim)
-                + nh * self.v_head_dim * h
+                q + h * k.cached + k.kv_rank * nh * (k.nope + k.v)
+                + nh * k.v * h + (h * nh if self.head_gate else 0)
             )
             norms = (4 if self.sandwich_norm else 2) * h \
-                + self.q_lora_rank + self.kv_lora_rank
+                + k.q_rank + k.kv_rank
             if self.index_kinds and self.index_kinds[layer] == "full":
                 attn += self.index_params
         if layer < self.first_k_dense or not self.num_experts:
@@ -1005,6 +1131,58 @@ MODEL_REGISTRY: Dict[str, ModelConfig] = {
         index_topk=2048, index_num_heads=32, index_head_dim=128,
         index_types=("full",) + ("shared", "shared", "shared", "full") * 2,
         index_query_input="q_latent", index_rope_dims=64,
+    ),
+    # dots3-note-prev -- latent (MLA) layers of two attention kinds: ``full``
+    # layers under a lightning indexer (queries from the query latent, rope
+    # by adjacent pairs) and ``sliding`` layers with a head count, latent
+    # ranks, head sizes and a rotation of their own that attend the last
+    # 513 positions, their pages in a second, wider latent pool; a gate a
+    # head on every layer's attention, a constant rescale on the two normed
+    # latents; a dense first layer, then sigmoid-routed experts with a
+    # selection bias beside a shared one (models/mla.py). The vision and
+    # audio towers and the multi-token-prediction module are not loaded.
+    "dots3-note-tiny": _llama(  # test-scale: a dense full layer, two
+        # periods F,S,S,S; window and index_topk far under a test context
+        "dots3-note-tiny", vocab_size=512, hidden_size=64, num_layers=9,
+        num_heads=4, num_kv_heads=4, intermediate_size=96,
+        max_position_embeddings=1024, rope_theta=500000.0,
+        kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, first_k_dense=1,
+        moe_intermediate_size=32, n_shared_experts=1, num_experts=8,
+        num_experts_per_tok=3, norm_topk_prob=True,
+        routed_scaling_factor=1.0, held_experts=(0, 2),
+        router_selection_bias=True, rope_interleave=True,
+        index_topk=8, index_num_heads=4, index_head_dim=16,
+        index_query_input="q_latent", index_rope_dims=8,
+        layer_types=("full",) + ("full", "sliding", "sliding", "sliding") * 2,
+        sliding_window=9, sliding_num_heads=2, sliding_rope_theta=10000.0,
+        sliding_kv_lora_rank=64, sliding_q_lora_rank=40,
+        sliding_qk_nope_head_dim=24, sliding_qk_rope_head_dim=8,
+        sliding_v_head_dim=16, head_gate=True, mla_lora_rescale=True,
+    ),
+    # one chip's share of the published model where 8 chips share each
+    # layer: published layers 0-8 (the leading dense layer, full, and two
+    # whole periods F,S,S,S of expert layers), 32 of the 256 routed experts,
+    # an eighth of the vocabulary; every width as published
+    # (benchmark/configs/dots3-note-prev-ep8-9l-int8.json)
+    "dots3-note-prev-ep8-9l": _llama(
+        "dots3-note-prev-ep8-9l", vocab_size=19008, hidden_size=5120,
+        num_layers=9, num_heads=128, num_kv_heads=128,
+        intermediate_size=13824, max_position_embeddings=24576,
+        rope_theta=80000000.0, rms_norm_eps=1e-5,
+        kv_lora_rank=512, q_lora_rank=1024, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, first_k_dense=1,
+        moe_intermediate_size=1536, n_shared_experts=1, num_experts=256,
+        num_experts_per_tok=8, norm_topk_prob=True,
+        routed_scaling_factor=1.0, held_experts=(0, 32),
+        router_selection_bias=True, rope_interleave=True,
+        index_topk=2048, index_num_heads=64, index_head_dim=128,
+        index_query_input="q_latent", index_rope_dims=64,
+        layer_types=("full",) + ("full", "sliding", "sliding", "sliding") * 2,
+        sliding_window=513, sliding_num_heads=64, sliding_rope_theta=50000.0,
+        sliding_kv_lora_rank=1024, sliding_q_lora_rank=1024,
+        sliding_qk_nope_head_dim=192, sliding_qk_rope_head_dim=64,
+        sliding_v_head_dim=128, head_gate=True, mla_lora_rescale=True,
     ),
     # Falcon-H1 -- in EVERY layer a Mamba-2 (SSD) mixer and GQA attention
     # read the same normed input and their scaled outputs are summed, then

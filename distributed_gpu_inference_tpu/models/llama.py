@@ -355,7 +355,7 @@ def init_kv_pools(
         from distributed_gpu_inference_tpu.models import mla
 
         return mla.init_kv_pools(cfg, num_blocks, block_size, dtype,
-                                 state_rows)
+                                 state_rows, window_blocks)
     dtype = jnp.dtype(dtype or cfg.dtype)
     if cfg.mixed_attention:
         # pages per layer kind: a pool for the full layers and one for the
@@ -1057,6 +1057,9 @@ def scan_index_keys(
         return kv
     from distributed_gpu_inference_tpu.ops import index_select
 
+    if cfg.latent_kv and cfg.mixed_attention:
+        # a row holds a table a kind; the index keys follow the full kind's
+        block_tables = block_tables[:, :block_tables.shape[1] // 2]
     keys = index_select.gather_scan_keys(
         kv[INDEX_KEYS], block_tables,
         jnp.max(jnp.where(active, lens, 0)) + num_steps, cfg.index_topk,

@@ -43,6 +43,22 @@ batcher and the cache manager serve this model as they serve the others):
   (IndexShare). On the kernel path a scan step's carry also holds the
   row's selected pages laid out for the decode kernel, built once a full
   layer and walked again by the shared layers behind it.
+- **Latent layers of two attention kinds** (``cfg.layer_types`` over
+  latent pages): a ``sliding`` layer has a head count, latent ranks, head
+  sizes and a rotation of its own (``cfg.latent_kind``), attends the last
+  ``cfg.sliding_window`` keys and writes its rows into a SECOND latent
+  pool, ``"ckv_win"``, as wide as its own cached row and a window's worth
+  of pages long, under the window kind's block table (a row of
+  ``block_tables`` holds both tables side by side, the full kind's first:
+  ``runtime/kv_cache._WindowPages``). Its stacks carry the prefix ``sw_``.
+  Where such a model has an indexer the ``full`` layers hold it; the index
+  keys follow the full kind's table and the selection is carried from full
+  layer to full layer, past the sliding layers, which attend their window
+  and no selection.
+- **A gate a head** (``cfg.head_gate``): ``sigmoid(x W_g)`` of the layer's
+  normed input, one value a head, on the head's output before ``W_o``. **The
+  latent rescale** (``cfg.mla_lora_rescale``): a constant on the two normed
+  latents, by the layer's own ranks.
 - **The expert layer computes the chip's share**: sigmoid scores over all
   published experts, top-k kept, normalised and scaled as published; pairs that fall on experts held elsewhere are routed nowhere,
   and nothing stands in for them.
@@ -59,7 +75,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from distributed_gpu_inference_tpu.models.configs import ModelConfig
+from distributed_gpu_inference_tpu.models.configs import (
+    LatentKind,
+    ModelConfig,
+)
 from distributed_gpu_inference_tpu.models.llama import (
     INDEX_KEYS,         # the index-key pool's name in the ``kv`` dict
     INDEX_SCAN_KEYS,    # a scan's index keys in context order
@@ -72,6 +91,9 @@ from distributed_gpu_inference_tpu.ops.quantization import (
 
 Params = Dict[str, Any]
 POOL = "ckv"
+# the sliding kind's latent pool of a model of two attention kinds (the
+# suffix is ``models/llama.WINDOW_POOLS``: no page copy, no prefix of its own)
+POOL_WIN = "ckv_win"
 _NEG_INF = -1e30
 # norm vectors are drawn around one, not set to it: four norms a layer are
 # otherwise interchangeable and a misplaced one is invisible
@@ -83,32 +105,52 @@ _LANES = 128
 _F32_KINDS = "atbro"
 
 
-def latent_width(cfg: ModelConfig) -> int:
-    """Values cached a token a layer: the latent and the rope key."""
-    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+def latent_width(cfg: ModelConfig, kind: str = "full") -> int:
+    """Values cached a token a layer of ``kind``: the latent and the rope
+    key."""
+    return cfg.latent_kind(kind).cached
 
 
-def pool_width(cfg: ModelConfig) -> int:
-    """Lanes of a pool row: ``latent_width`` rounded up to whole 128-lane
-    tiles, the pad lanes zero. A TPU array is tiled in 128 lanes (a
-    576-wide one takes 640 in HBM whatever its shape says), and a page DMA
-    must cover whole tiles, so the pool says what it takes."""
-    return -(-latent_width(cfg) // _LANES) * _LANES
+def pool_width(cfg: ModelConfig, kind: str = "full") -> int:
+    """Lanes of a row of ``kind``'s pool: ``latent_width`` rounded up to
+    whole 128-lane tiles, the pad lanes zero. A TPU array is tiled in 128
+    lanes (a 576-wide one takes 640 in HBM whatever its shape says), and a
+    page DMA must cover whole tiles, so the pool says what it takes."""
+    return -(-latent_width(cfg, kind) // _LANES) * _LANES
 
 
 _KDA = "kda_"
 _IX = "ix_"
+_SW = "sw_"
+
+
+def kind_of(group: str) -> str:
+    """The attention kind of a latent stack, by its name."""
+    return "sliding" if group.startswith(_SW) else "full"
+
+
+def latent_kinds(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The attention kinds of the model's latent layers, the full kind
+    first: each has a pool and a block table of its own."""
+    return ("full", "sliding") if cfg.mixed_attention else ("full",)
+
+
+def pool_of(kind: str) -> str:
+    return POOL_WIN if kind == "sliding" else POOL
 
 
 def group_of(cfg: ModelConfig, layer: int) -> str:
     """The parameter stack layer ``layer`` (0-based) lies in: by its MLP
     (``dense_layers`` / ``layers``), for a gated delta-rule layer the
-    prefix ``kda_`` and for a latent layer that holds an indexer (a
-    ``full`` one) the prefix ``ix_``."""
+    prefix ``kda_``, for a sliding latent layer of a model of two attention
+    kinds ``sw_`` and for a latent layer that holds an indexer (a ``full``
+    one) the prefix ``ix_``."""
     lead = cfg.first_k_dense if cfg.num_experts else 0
     name = "dense_layers" if layer < lead else "layers"
     if cfg.layer_kinds[layer] == "kda":
         return _KDA + name
+    if cfg.mixed_attention and cfg.layer_types[layer] == "sliding":
+        return _SW + name
     if cfg.index_kinds and cfg.index_kinds[layer] == "full":
         return _IX + name
     return name
@@ -120,6 +162,7 @@ def layer_groups(cfg: ModelConfig) -> Tuple[Tuple[str, int], ...]:
     the gated delta-rule layers'."""
     names = [group_of(cfg, li) for li in range(cfg.num_layers)]
     order = (_IX + "dense_layers", "dense_layers", _IX + "layers", "layers",
+             _SW + "dense_layers", _SW + "layers",
              _KDA + "dense_layers", _KDA + "layers")
     return tuple((g, names.count(g)) for g in order if g in names)
 
@@ -130,10 +173,13 @@ def layer_units(cfg: ModelConfig
     the ``(params key, layers)`` of a unit's homogeneous stretches. A model
     of one cache is one unit; a hybrid is cut after every latent layer, a
     model whose layers share selections after every layer that computes
-    one, and equal neighbours merge, so that a repeated period is traced
-    once."""
+    one, a model of two attention kinds before every full layer, and equal
+    neighbours merge, so that a repeated period is traced once."""
     names = [group_of(cfg, li) for li in range(cfg.num_layers)]
     cuts = [li for li in cfg.full_attn_layers if li < cfg.num_layers]
+    if cfg.mixed_attention:
+        cuts = [li for li, kind in enumerate(cfg.layer_types)
+                if kind == "full" and li]
     if "shared" in cfg.index_kinds:
         cuts = [li + 1 for li, kind in enumerate(cfg.index_kinds)
                 if kind == "full" and li + 1 < cfg.num_layers]
@@ -181,14 +227,22 @@ def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]
             "mlp_norm": ((h,), 0, "n"),
         }
     else:
-        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        k = cfg.latent_kind(kind_of(group))
+        nh, dn, dr, dv, rq, rkv = k.heads, k.nope, k.rope, k.v, k.q_rank, \
+            k.kv_rank
         spec = {"attn_norm": ((h,), 0, "n")}
+        # what reads a RESCALED latent is drawn at ``hidden_size ** -0.5``:
+        # the rescale makes up for weights initialised at one deviation
+        # whatever they read (LongCat-Flash, arXiv:2509.01322, scale
+        # correction for MLA: ``a ** 2 x rank = hidden``), which a draw at
+        # the fan-in's is not, so that q, k and v come out at the variance
+        # the other latent models have them
+        fan_q, fan_kv = (h, h) if cfg.mla_lora_rescale else (rq, rkv)
         if rq:
             spec.update({
                 "wq_a": ((h, rq), h, "q"),
                 "q_a_norm": ((rq,), 0, "n"),
-                "wq_b": ((rq, nh * (dn + dr)), rq, "q"),
+                "wq_b": ((rq, nh * (dn + dr)), fan_q, "q"),
             })
         else:       # no query low-rank: one projection, no norm
             spec["wq"] = ((h, nh * (dn + dr)), h, "q")
@@ -197,11 +251,14 @@ def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]
             "kv_a_norm": ((rkv,), 0, "n"),
             # W_kvb split a head into W_UK and W_UV; bf16: the absorbed
             # products are batched over heads, not the int8 kernel's shape
-            "w_uk": ((nh, rkv, dn), rkv, "d"),
-            "w_uv": ((nh, rkv, dv), rkv, "d"),
+            "w_uk": ((nh, rkv, dn), fan_kv, "d"),
+            "w_uv": ((nh, rkv, dv), fan_kv, "d"),
             "wo": ((nh * dv, h), nh * dv, "q"),
             "mlp_norm": ((h,), 0, "n"),
         })
+        if cfg.head_gate:
+            # one column a head: narrow, bf16 (``models/llama.py``'s name)
+            spec["w_hgate"] = ((h, nh), h, "d")
         if group.startswith(_IX):
             # the indexer: queries from the query latent or the normed
             # input, ONE key a token and the heads' weights from the normed
@@ -209,7 +266,8 @@ def leaf_specs(cfg: ModelConfig, group: str) -> Dict[str, Tuple[tuple, int, str]
             hi, di = cfg.index_num_heads, cfg.index_head_dim
             q_in = rq if cfg.index_query_input == "q_latent" else h
             spec.update({
-                "wqi": ((q_in, hi * di), q_in, "q"),
+                "wqi": ((q_in, hi * di),
+                        fan_q if q_in == rq else q_in, "q"),
                 "wki": ((h, di), h, "d"),
                 "ww": ((h, hi), h, "d"),
                 "ki_norm": ((di,), 0, "n"),
@@ -326,8 +384,12 @@ def init_params(
 
 def init_kv_pools(cfg: ModelConfig, num_blocks: int, block_size: int = 16,
                   dtype: Optional[jnp.dtype] = None,
-                  state_rows: Optional[int] = None) -> Dict[str, jax.Array]:
-    """The latent paged pool ``[L, N, Bk, W]``, ``L`` the latent layers;
+                  state_rows: Optional[int] = None,
+                  window_blocks: Optional[int] = None
+                  ) -> Dict[str, jax.Array]:
+    """The latent paged pool ``[L, N, Bk, W]``, ``L`` the latent layers
+    (of a model of two attention kinds: the full ones, and beside it
+    ``"ckv_win"`` ``[L_sliding, window_blocks, Bk, W_sliding]``);
     block 0 is the pad block. A one-byte float dtype (fp8) stores narrower
     rows; int8 with scales is not built. A hybrid model's dict also holds
     the state pool of ``state_rows`` sequences (``models/kda.py``), a
@@ -339,6 +401,20 @@ def init_kv_pools(cfg: ModelConfig, num_blocks: int, block_size: int = 16,
     pools = {POOL: jnp.zeros(
         (cfg.num_cache_layers, num_blocks, block_size, pool_width(cfg)),
         dtype)}
+    if cfg.mixed_attention:
+        # pages per layer kind: the sliding layers' rows, as wide as THEIR
+        # cached row, in a pool a window's worth of blocks long
+        if not window_blocks or window_blocks < 2:
+            raise ValueError(
+                f"{cfg.name}: the sliding kind's pool needs its number of "
+                "blocks")
+        if dtype.itemsize == 1:
+            raise NotImplementedError(
+                f"{cfg.name}: one-byte latent pools of a model of two "
+                "attention kinds are not built")
+        pools[POOL_WIN] = jnp.zeros(
+            (cfg.num_window_layers, window_blocks, block_size,
+             pool_width(cfg, "sliding")), dtype)
     if cfg.index_topk:
         # one index key a token a FULL layer, addressed by the latent
         # pages' block table: a page copy, a prefix hit and a resume bring it
@@ -391,16 +467,23 @@ def latent_attention_xla(
     form: str,                 # "expanded" | "absorbed"
     keep: Optional[jax.Array] = None,   # [B, S, J] float32 > 0: the cached
                                # tokens each query attends (a selection)
+    kind: Optional[LatentKind] = None,  # the layer's kind (None: the
+                               # model's one): its widths and its window
 ) -> jax.Array:
     """Both forms of the one attention, in float32 → [B, S, Nh, dv]."""
     f32 = jnp.float32
-    rkv = cfg.kv_lora_rank
+    kind = kind or cfg.latent_kind()
+    rkv = kind.kv_rank
     c = ctx[..., :rkv].astype(f32)
-    k_r = ctx[..., rkv:rkv + cfg.qk_rope_head_dim].astype(f32)
+    k_r = ctx[..., rkv:rkv + kind.rope].astype(f32)
     q_n, q_r = q_n.astype(f32), q_r.astype(f32)
     w_uk, w_uv = w_uk.astype(f32), w_uv.astype(f32)
-    scale = cfg.qk_head_dim ** -0.5
+    scale = kind.qk ** -0.5
     visible = _visible(positions, kv_lens, ctx.shape[1])
+    if kind.window is not None:
+        # a query at p attends the positions (p - window, p]
+        key_pos = jnp.arange(ctx.shape[1], dtype=jnp.int32)[None, None, :]
+        visible &= key_pos > positions[:, :, None] - kind.window
     if keep is not None:
         visible &= keep > 0
     rope_scores = jnp.einsum("bshr,bjr->bhsj", q_r, k_r)
@@ -428,7 +511,8 @@ def kernels_on(cfg: ModelConfig, padded_ctx: int, pool_dtype,
     return (
         pallas and _attention.pallas_backend()
         and jnp.dtype(pool_dtype).itemsize == 2
-        and cfg.kv_lora_rank % 128 == 0
+        and all(cfg.latent_kind(kind).kv_rank % 128 == 0
+                for kind in latent_kinds(cfg))
         and padded_ctx >= _attention._PALLAS_MIN_PADDED_CTX
     )
 
@@ -541,28 +625,42 @@ def _latent_attention(
     cfg: ModelConfig, block_size: int, x: jax.Array, lp: Dict[str, Any],
     proj, kv: Dict[str, jax.Array], pool_layer, *, block_tables,
     write_positions, kv_lens, cos, sin, kernels, unpack, tiles, write_plan,
-    index=None, index_layer=None, sel=None, scored=False,
+    index=None, index_layer=None, sel=None, scored=False, kind="full",
 ) -> Tuple[jax.Array, Dict[str, jax.Array], Any]:
     """A latent layer's attention over ``x`` (normed) → (``concat(o)``
     before ``W_o``, the pools with the layer's rows written, the selection
     the layer attended: its own if it holds an indexer, else ``sel`` as it
     came). ``scored``: the layer holds an indexer (its stack's name says
-    so; the quantized ``wqi`` may ride ``stacked``, not ``lp``)."""
+    so; the quantized ``wqi`` may ride ``stacked``, not ``lp``).
+    ``kind``: the layer's attention kind; ``block_tables``, ``cos`` /
+    ``sin``, ``tiles`` and ``write_plan`` are its kind's (a model of two
+    kinds hands a dict a kind), its pool ``pool_of(kind)``. A sliding layer
+    attends its window and leaves ``sel`` as it came."""
     from distributed_gpu_inference_tpu.models.llama import rms_norm
 
-    pool = kv[POOL]
+    lk = cfg.latent_kind(kind)
+    windowed = lk.window is not None
+    if cfg.mixed_attention:
+        block_tables, cos, sin, tiles, write_plan = (
+            None if per is None else per[kind]
+            for per in (block_tables, cos, sin, tiles, write_plan))
+    pool_name = pool_of(kind)
+    pool = kv[pool_name]
     b, s, _ = x.shape
-    nh, rkv = cfg.num_heads, cfg.kv_lora_rank
-    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    nh, rkv, dn, dr = lk.heads, lk.kv_rank, lk.nope, lk.rope
     eps = cfg.rms_norm_eps
     c_q = None
-    if cfg.q_lora_rank:
+    if lk.q_rank:
         c_q = rms_norm(proj(x, "wq_a"), lp["q_a_norm"], eps)
+        if lk.q_scale != 1.0:
+            c_q = c_q * jnp.asarray(lk.q_scale, c_q.dtype)
         q = proj(c_q, "wq_b").reshape(b, s, nh, dn + dr)
     else:
         q = proj(x, "wq").reshape(b, s, nh, dn + dr)
     ckr = proj(x, "wkv_a")
     c = rms_norm(ckr[..., :rkv], lp["kv_a_norm"], eps)
+    if lk.kv_scale != 1.0:
+        c = c * jnp.asarray(lk.kv_scale, c.dtype)
     if cfg.mla_use_nope:        # the "rope" dims as they are
         q_n, q_r, k_r = q[..., :dn], q[..., dn:], ckr[..., rkv:]
     else:
@@ -623,17 +721,20 @@ def _latent_attention(
             [q_abs.astype(pool.dtype), q_r.astype(pool.dtype),
              jnp.zeros((*q_r.shape[:3], pool.shape[-1] - rkv - dr),
                        pool.dtype)], axis=-1)
-        common = dict(scale=cfg.qk_head_dim ** -0.5, latent=rkv)
+        common = dict(scale=lk.qk ** -0.5, latent=rkv)
+        if windowed:
+            common["window"] = lk.window
+        attends = None if windowed else sel
         if tiles is not None:
-            picked = {} if sel is None else {
-                "keep_tiles": sel["keep_tiles"]}
+            picked = {} if attends is None else {
+                "keep_tiles": attends["keep_tiles"]}
             u = mla_k.latent_paged_attention_packed(
                 q_cat[0], tiles, pool, pool_layer, block_tables,
                 kv_lens, block_size, **common, **picked)[None]
         else:
-            picked = {} if sel is None else (
-                {"walk": sel["walk"]} if "walk" in sel
-                else {"keep": sel["keep"]})
+            picked = {} if attends is None else (
+                {"walk": attends["walk"]} if "walk" in attends
+                else {"keep": attends["keep"]})
             u = mla_k.latent_paged_attention(
                 q_cat, pool, pool_layer, block_tables, positions,
                 kv_lens, block_size, decode=s == 1, **common, **picked)
@@ -655,24 +756,32 @@ def _latent_attention(
         attn = latent_attention_xla(
             cfg, q_n, q_r, lp["w_uk"], lp["w_uv"], ctx, positions,
             kv_lens, "absorbed" if positions.shape[1] == 1
-            else "expanded", keep=None if sel is None else sel["keep"],
+            else "expanded",
+            keep=None if sel is None or windowed else sel["keep"],
+            kind=lk,
         )
         if unpack is not None:
             attn = attn.at[unpack[1], unpack[2]].get(mode="fill",
                                                      fill_value=0)
-    return (attn.astype(x.dtype).reshape(b, s, nh * cfg.v_head_dim),
-            {**kv, POOL: pool}, sel)
+    if cfg.head_gate:
+        # one learned scalar a head a token, from the layer's normed input,
+        # on the head's output before W_o
+        gate = jax.nn.sigmoid(proj(x, "w_hgate").astype(jnp.float32))
+        attn = attn.astype(jnp.float32) * gate[..., None]
+    return (attn.astype(x.dtype).reshape(b, s, nh * lk.v),
+            {**kv, pool_name: pool}, sel)
 
 
 def _layer_step(
     cfg: ModelConfig, block_size: int, hidden: jax.Array,
     kv: Dict[str, jax.Array], sel, lp: Dict[str, Any], *, linear: bool,
     layer_idx, cache_layer, index_layer, scored, stacked, pallas, kernels,
-    kda_kernels, kda_plan, rope_positions, moe_live, emit_routing, **latent,
+    kda_kernels, kda_plan, rope_positions, moe_live, emit_routing,
+    kind="full", **latent,
 ):
     """One layer: ``layer_idx`` its place in its parameter stack,
-    ``cache_layer`` its place in its cache (the latent pool, or for a
-    ``linear`` layer the state pool), ``index_layer`` its place in the
+    ``cache_layer`` its place in its cache (its kind's latent pool, or for
+    a ``linear`` layer the state pool), ``index_layer`` its place in the
     index-key pool if it holds an indexer; ``sel`` the selection the last
     such layer computed (``selection_state``), handed on."""
     from distributed_gpu_inference_tpu.models.llama import _mlp, rms_norm
@@ -696,7 +805,7 @@ def _layer_step(
             attn, kv, sel = _latent_attention(
                 cfg, block_size, x, lp, proj, kv, cache_layer,
                 kernels=kernels, index_layer=index_layer, sel=sel,
-                scored=scored, **latent)
+                scored=scored, kind=kind, **latent)
             attn = proj(attn, "wo").astype(hidden.dtype)
         if "post_attn_norm" in lp:
             attn = rms_norm(attn, lp["post_attn_norm"], eps)
@@ -731,6 +840,23 @@ def forward_chunk(
     from distributed_gpu_inference_tpu.models import llama
 
     pool = kv[POOL]
+    mixed = cfg.mixed_attention
+    if mixed:
+        # a block table a kind, side by side in a row, the full kind's first
+        m_cols = block_tables.shape[1] // 2
+        tables = {"full": block_tables[:, :m_cols],
+                  "sliding": block_tables[:, m_cols:]}
+        block_tables = tables["full"]
+
+    def per_kind(make):
+        """What a layer's attention kind fixes, built once a graph: a dict
+        a kind for a model of two, else the one kind's (the same operands
+        as ever in the same order)."""
+        if not mixed:
+            return make("full", block_tables, pool)
+        return {kind: make(kind, tables[kind], kv[pool_of(kind)])
+                for kind in latent_kinds(cfg)}
+
     unpack = to_rect = tp = None
     if packing is not None:
         tp = token_ids.shape[0]
@@ -769,15 +895,16 @@ def forward_chunk(
                 packed_tiles,
             )
 
-            tiles = packed_tiles(
+            tiles = per_kind(lambda kind, _t, _p: packed_tiles(
                 packing.row, packing.col, rope_positions[0],
-                block_tables.shape[0], packing.width, cfg.num_heads)
+                block_tables.shape[0], packing.width,
+                cfg.latent_kind(kind).heads))
 
-        write_plan = page_write_plan(
-            block_tables, positions, block_size,
-            page_bytes=pool.shape[2] * pool.shape[3] * pool.dtype.itemsize,
+        write_plan = per_kind(lambda _k, tables_, pool_: page_write_plan(
+            tables_, positions, block_size,
+            page_bytes=pool_.shape[2] * pool_.shape[3] * pool_.dtype.itemsize,
             token_index=to_rect, num_tokens=tp,
-        )
+        ))
     index = sel = None
     if cfg.index_topk:
         # the indexer's view of the chunk, the same for every full layer;
@@ -786,8 +913,10 @@ def forward_chunk(
                                   positions, rope_positions, packing,
                                   block_size)
         state = functools.partial(
-            selection_state, kernels=kernels, tiles=tiles, unpack=unpack,
-            block_tables=block_tables, positions=positions, kv_lens=kv_lens,
+            selection_state, kernels=kernels,
+            tiles=tiles["full"] if mixed and tiles is not None else tiles,
+            unpack=unpack, block_tables=block_tables, positions=positions,
+            kv_lens=kv_lens,
             block_size=block_size)
         sel = jax.tree.map(
             lambda a: jnp.zeros(a.shape, a.dtype),
@@ -797,15 +926,18 @@ def forward_chunk(
     hidden = llama.embed_tokens(params, token_ids, cfg)
     cos = sin = None
     if not cfg.mla_use_nope:
-        cos, sin = llama._rope_angles(
-            jnp.maximum(rope_positions, 0), cfg.qk_rope_head_dim,
-            cfg.rope_theta)
+        angles = per_kind(lambda kind, _t, _p: llama._rope_angles(
+            jnp.maximum(rope_positions, 0), cfg.latent_kind(kind).rope,
+            cfg.latent_kind(kind).theta))
+        cos, sin = ({kind: a[i] for kind, a in angles.items()}
+                    for i in (0, 1)) if mixed else angles
 
     split = {group: _split_group(params[group], pallas)
              for group, _ in layer_groups(cfg)}
     step = functools.partial(
         _layer_step, cfg, block_size,
-        block_tables=block_tables, write_positions=positions,
+        block_tables=tables if mixed else block_tables,
+        write_positions=positions,
         kv_lens=kv_lens, cos=cos, sin=sin, pallas=pallas, kernels=kernels,
         kda_kernels=kda_kernels, kda_plan=kda_plan,
         rope_positions=rope_positions, unpack=unpack, tiles=tiles,
@@ -814,7 +946,7 @@ def forward_chunk(
     )
     # where each stack, each cache and the index-key pool stand, in layers
     at_w = {group: 0 for group in split}
-    at_c = {False: 0, True: 0}
+    at_c = {"full": 0, "sliding": 0, _KDA: 0}
     at_i = 0
     moe = None
     fetched = None
@@ -832,8 +964,11 @@ def forward_chunk(
             hidden_, kv_, sel_, stats, routing = step(
                 c[0], c[1], c[2], lp, linear=linear, layer_idx=w0 + j,
                 cache_layer=c0 + j, index_layer=i0 + j,
-                scored=group.startswith(_IX), stacked=stacked)
-            took = None if sel_ is None else sel_.get("fetched")
+                scored=group.startswith(_IX), stacked=stacked,
+                kind=kind_of(group))
+            # (a sliding layer walks its window, not the carried selection)
+            took = None if sel_ is None or kind_of(group) == "sliding" \
+                else sel_.get("fetched")
             return (hidden_, kv_, sel_), (stats, routing, took)
 
         return lax.scan(body, carry, (jnp.arange(n, dtype=jnp.int32), scanned))
@@ -861,17 +996,22 @@ def forward_chunk(
     def indexed(group):
         return int(group.startswith(_IX))
 
+    def cache_of(group):
+        """The cache a stack's layers write: the state pool, or their
+        attention kind's latent pool."""
+        return _KDA if group.startswith(_KDA) else kind_of(group)
+
     carry = (hidden, kv, sel)
     for repeat, runs in layer_units(cfg):
         if repeat == 1:
             for group, n in runs:
-                linear = group.startswith(_KDA)
+                cache = cache_of(group)
                 carry, (stats, routing, took) = run(
                     carry, group, leaves(group, at_w[group], n), n,
-                    jnp.int32(at_w[group]), jnp.int32(at_c[linear]),
+                    jnp.int32(at_w[group]), jnp.int32(at_c[cache]),
                     jnp.int32(at_i))
                 at_w[group] += n
-                at_c[linear] += n
+                at_c[cache] += n
                 at_i += n * indexed(group)
                 add_stats(stats, took)
                 if routing is not None:
@@ -881,18 +1021,18 @@ def forward_chunk(
         w_lo = {g: at_w[g] for g, _ in runs}
         c_lo = dict(at_c)
         i_lo = at_i
-        per_c = {lin: sum(n for g, n in runs if g.startswith(_KDA) == lin)
-                 for lin in (False, True)}
+        per_c = {cache: sum(n for g, n in runs if cache_of(g) == cache)
+                 for cache in at_c}
         per_i = sum(n * indexed(g) for g, n in runs)
         xs = {g: leaves(g, w_lo[g], repeat * n, repeat) for g, n in runs}
 
         def period(c, px):
             p_, lp_ = px
             outs = []
-            seen = {False: 0, True: 0}
+            seen = dict.fromkeys(at_c, 0)
             seen_i = 0
             for g, n in runs:
-                lin = g.startswith(_KDA)
+                lin = cache_of(g)
                 c, out = run(c, g, lp_[g], n, w_lo[g] + p_ * n,
                              c_lo[lin] + p_ * per_c[lin] + seen[lin],
                              i_lo + p_ * per_i + seen_i)
@@ -906,7 +1046,7 @@ def forward_chunk(
         period_routes = []
         for (g, n), (stats, routing, took) in zip(runs, outs):
             at_w[g] += repeat * n
-            at_c[g.startswith(_KDA)] += repeat * n
+            at_c[cache_of(g)] += repeat * n
             at_i += repeat * n * indexed(g)
             add_stats(stats, took)
             if routing is not None:

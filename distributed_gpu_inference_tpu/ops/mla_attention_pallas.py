@@ -46,6 +46,16 @@ slice as a custom-call operand is a copy of the layer).
   start a page of a 32-page group): openPangu's and Kimi-Linear's graphs
   trace this body too, a faster dense walk bought them nothing end to end
   and cost their ``setup_s`` a bound (PERF.md section 6, PRs 53-54).
+- **A sliding latent layer** (``window``: a model of two attention kinds,
+  ``models/mla.py``) runs the same body over ITS pool (a wider row, its own
+  head count) under the window kind's block table, with the window as a
+  rule of the walk: a tile's first group is the one that holds ``qmin -
+  window + 1`` (an eighth scalar operand, the tile's lowest query position),
+  and a cached position at or under ``p - window`` is masked. Its calls
+  carry names of their own (``dgi_mla_window_decode`` /
+  ``dgi_mla_window_ragged``), so that a device trace tells them from the
+  full layers'. With ``window`` None the kernel's equations and operands
+  are what they were.
 """
 
 from __future__ import annotations
@@ -72,6 +82,9 @@ WRITE_KERNEL_NAME = "dgi_mla_write"
 # the same kernels under a selection: a device trace tells the selected
 # walk from the dense one
 _SELECTED = "_selected"
+# a sliding latent layer's calls (the window kind's pool and table)
+WINDOW_DECODE_KERNEL_NAME = "dgi_mla_window_decode"
+WINDOW_RAGGED_KERNEL_NAME = "dgi_mla_window_ragged"
 # ceiling on (heads) x (query tile): the rows of the score tile and of the
 # float32 accumulator a grid cell carries (1024 x 512 x 4 B = 2 MiB)
 _HEAD_ROWS = 1024
@@ -207,19 +220,27 @@ def _attention_kernel(
     q_ref,         # [1, Nh*T, W] this tile's absorbed queries, head-major
     pos_ref,       # [1, Nh*T, 1] int32 per-query positions (-1 = pad)
     pool_hbm,      # [L, N, Bk, W]
-    *rest,         # [keep_ref [1, T, gsz] float32 (> 0: attended),]
+    *rest,         # (``window``: the whole operand list is shifted by one
+                   # more scalar, ``qmin_ref [R]``, behind ``init_ref``:
+                   # read below)
+                   # [keep_ref [1, T, gsz] float32 (> 0: attended),]
                    # out_ref [1, Nh*T, latent], buf [2, G, Bk, W] page
                    # staging, sems DMA [2, G] ([2] in the ``walk`` form),
                    # m_scr, l_scr [Nh*T, 1] float32 softmax state, acc_scr
                    # [Nh*T, latent] float32
     rows: int, block_size: int, pages_per_group: int,
     max_pages: int, scale: float, latent: int, selected: bool, heads: int,
-    walk: bool,
+    walk: bool, window: int | None = None,
 ):
     """``walk``: ``bt_ref`` is a scan step's table of selected pages, padded
     to whole groups (:func:`selected_walk`). Static, and read in Python
     only: with it off the kernel's equations are what they were before the
     flag (every other configuration's graphs trace this body too)."""
+    qmin_ref = None
+    if window is not None:
+        # an eighth scalar operand: a tile's lowest valid query position
+        qmin_ref, q_ref, pos_ref, pool_hbm, *rest = (
+            q_ref, pos_ref, pool_hbm, *rest)
     keep_ref = None
     if selected:
         keep_ref, *rest = rest
@@ -238,8 +259,18 @@ def _attention_kernel(
         # prefetched DMA un-waited at kernel exit
         return jnp.minimum(pl.cdiv(needed, gsz), max_groups)
 
+    def start_group(s_):
+        """(``window`` only) the group that holds the tile's first visible
+        key, ``max(0, qmin - window + 1)``."""
+        s_ = jnp.clip(s_, 0, rows - 1)
+        return jnp.maximum(qmin_ref[s_] - window + 1, 0) // gsz
+
     ng_r = num_groups(r)
-    live = i < ng_r
+    if window is None:
+        live = i < ng_r
+    else:
+        start_r = start_group(r)
+        live = (i >= start_r) & (i < ng_r)
 
     if walk:
         # ``ops/paged_attention_pallas._decode_kernel``'s walk under a
@@ -303,7 +334,10 @@ def _attention_kernel(
                 return jnp.where(
                     (ss < rows) & (num_groups(ss) == 0), ss + 1, ss)
 
-            return lax.fori_loop(0, rows, step, s_ + 1), jnp.int32(0)
+            ns = lax.fori_loop(0, rows, step, s_ + 1)
+            if window is None:
+                return ns, jnp.int32(0)
+            return ns, jnp.where(ns < rows, start_group(ns), 0)
 
         return lax.cond(
             j + 1 < num_groups(s_), lambda: (s_, j + 1), advance_row)
@@ -330,7 +364,7 @@ def _attention_kernel(
         bidx_ref[0] = 1 - slot
         wait_dma(r, i, slot)
 
-        @pl.when(i == 0)
+        @pl.when(i == (0 if window is None else start_r))
         def _():
             m_scr[...] = jnp.full(m_scr.shape, _NEG_INF, jnp.float32)
             l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -346,6 +380,8 @@ def _attention_kernel(
         col = i * gsz + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
         pos = pos_ref[0]                                       # [Nh*T, 1]
         valid = (col < kv_len) & (col <= pos)
+        if window is not None:
+            valid &= col > pos - window
         if selected:
             # the tile's T queries repeat once a head, as their positions do
             kept = keep_ref[0] > 0                              # [T, gsz]
@@ -381,13 +417,16 @@ def _q_tile(s: int, nh: int) -> int:
 
 def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
                   block_tables, kv_lens, *, block_size, scale, latent, name,
-                  interpret, keep_tiles=None, walk=False):
+                  interpret, keep_tiles=None, walk=False, window=None):
     """The kernel over query tiles: ``q_tiles [R, T, Nh, W]`` (``T``
     consecutive queries of ONE sequence a tile, ``tile_seq [R]`` says
     which), ``pos_tiles [R, T]`` their positions (-1 = no query) →
     ``[R, T, Nh, latent]``. ``keep_tiles [R, T, J]`` float32: a query
     attends only the columns of its tile's walk where it is > 0. ``walk``:
-    ``block_tables`` is a :class:`SelectedWalk`'s ``pages``."""
+    ``block_tables`` is a :class:`SelectedWalk`'s ``pages``. ``window``: a
+    query attends the ``window`` positions up to its own."""
+    if window is not None and (walk or keep_tiles is not None):
+        raise ValueError("a windowed latent layer attends no selection")
     rows, t, nh, w = q_tiles.shape
     m = block_tables.shape[1]
     # [R, T, Nh, W] → [R, Nh*T, W], the query index fastest inside a head
@@ -425,7 +464,7 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
                                      memory_space=pltpu.VMEM))
         operands.append(keep_r)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=7 if window is None else 8,
         grid=(rows, max_groups),
         in_specs=in_specs,
         out_specs=tile_spec(latent),
@@ -440,7 +479,7 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
     kernel = functools.partial(
         _attention_kernel, rows=rows, block_size=block_size,
         pages_per_group=gp, max_pages=m, scale=scale, latent=latent,
-        selected=selected, heads=nh, walk=walk,
+        selected=selected, heads=nh, walk=walk, window=window,
     )
     out = pl.pallas_call(
         kernel,
@@ -456,14 +495,24 @@ def _attend_tiles(q_tiles, pos_tiles, tile_seq, pool, layer_idx,
         tile_seq.astype(jnp.int32), jnp.max(pos_r, axis=1),
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
         jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
+        *(() if window is None else (_tile_qmin(pos_r),)),
         *operands,
     )
     return out.reshape(rows, nh, t, latent).transpose(0, 2, 1, 3)
 
 
+def _tile_qmin(pos_tiles: jax.Array) -> jax.Array:
+    """A tile's lowest valid query position (a tile with none: 0; its walk
+    is empty anyway, ``qmax`` -1)."""
+    big = jnp.iinfo(jnp.int32).max
+    qmin = jnp.min(jnp.where(pos_tiles >= 0, pos_tiles, big), axis=1)
+    return jnp.where(qmin == big, 0, qmin)
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("block_size", "scale", "latent", "decode", "interpret"),
+    static_argnames=("block_size", "scale", "latent", "decode", "interpret",
+                     "window"),
 )
 def latent_paged_attention(
     q: jax.Array,             # [B, S, Nh, W] absorbed queries (q~ ; q_r)
@@ -484,6 +533,8 @@ def latent_paged_attention(
                                       # S == 1: the rows' selected pages
                                       # (:func:`selected_walk`), in place
                                       # of ``keep``
+    window: int | None = None,        # a sliding layer: the positions a
+                                      # query attends, itself among them
 ) -> jax.Array:
     """Absorbed attention of ``S`` queries a row against the row's cached
     latents → ``[B, S, Nh, latent]`` (the caller lifts it through ``W_UV``).
@@ -507,6 +558,7 @@ def latent_paged_attention(
             jnp.arange(b, dtype=jnp.int32), pool, layer_idx, walk.pages,
             fetched, block_size=block_size, scale=scale, latent=latent,
             interpret=interpret, keep_tiles=walk.keep, walk=True,
+            window=window,
             name=DECODE_KERNEL_NAME if decode else RAGGED_KERNEL_NAME,
         )
         return out.reshape(b, 1, nh, latent)
@@ -524,11 +576,19 @@ def latent_paged_attention(
         jnp.arange(b * qt, dtype=jnp.int32) // qt, pool, layer_idx,
         block_tables, kv_lens, block_size=block_size, scale=scale,
         latent=latent, interpret=interpret,
-        name=DECODE_KERNEL_NAME if decode else RAGGED_KERNEL_NAME,
+        name=_kernel_name(decode, window),
         keep_tiles=None if keep is None
         else keep.reshape(b * qt, t, keep.shape[2]),
+        window=window,
     )
     return out.reshape(b, s_pad, nh, latent)[:, :s]
+
+
+def _kernel_name(decode: bool, window) -> str:
+    if window is not None:
+        return WINDOW_DECODE_KERNEL_NAME if decode \
+            else WINDOW_RAGGED_KERNEL_NAME
+    return DECODE_KERNEL_NAME if decode else RAGGED_KERNEL_NAME
 
 
 class SelectedWalk(NamedTuple):
@@ -632,6 +692,7 @@ def latent_paged_attention_packed(
     interpret: bool = False,
     keep_tiles: jax.Array | None = None,   # [R, T, M * Bk] float32 > 0:
                                            # what each tile's queries attend
+    window: int | None = None,             # a sliding layer's window
 ) -> jax.Array:
     """:func:`latent_paged_attention` for a packed round, without the
     rectangle: the queries are gathered straight into their tiles and the
@@ -647,7 +708,8 @@ def latent_paged_attention_packed(
     out = _attend_tiles(
         q_tiles, tiles.pos, tiles.seq, pool, layer_idx, block_tables,
         kv_lens, block_size=block_size, scale=scale, latent=latent,
-        name=RAGGED_KERNEL_NAME, interpret=interpret, keep_tiles=keep_tiles,
+        name=_kernel_name(False, window), interpret=interpret,
+        keep_tiles=keep_tiles, window=window,
     )
     return jnp.take(out.reshape(rows * t, nh, latent), tiles.slot, axis=0,
                     mode="fill", fill_value=0)

@@ -225,7 +225,9 @@ def quantize_params(
     # (models/mla.py group_of)
     for group in ("layers", "dense_layers", "kda_layers",
                   "kda_dense_layers", "full_layers", "full_dense_layers",
-                  "ix_layers", "ix_dense_layers"):
+                  "ix_layers", "ix_dense_layers",
+                  # and one of two attention kinds its sliding layers'
+                  "sw_layers", "sw_dense_layers"):
         if group in params:
             out[group] = _quantize_group(params[group], mode, consume)
     return out

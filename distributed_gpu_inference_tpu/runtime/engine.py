@@ -800,6 +800,9 @@ class TPUEngine:
                 # kv+index: K/V pages and an index key a token beside them
                 # latent+index: latent pages and an index key a token a
                 # layer that holds an indexer beside them
+                # ...+window: pages per layer kind, the sliding kind's in a
+                # pool of their own (K/V pages, or latent pages of a width
+                # of their own)
                 # kv+state: K/V pages beside a state row a sequence
                 "kv_layout": "kv+state" if self.model_cfg.ssm_num_heads
                 else "hybrid" if self._state_rows
@@ -846,7 +849,8 @@ class TPUEngine:
                 # causal reach, and inside the window, of the plain ragged
                 # rounds (at a round's build)
                 self.stats.update({
-                    "kv_layout": "kv+window",
+                    # latent pages of two kinds: ``latent+index+window``
+                    "kv_layout": self.stats["kv_layout"] + "+window",
                     "window_pool_blocks": self._window_blocks,
                     "attn_row_steps_scan": 0,
                     "attn_full_context_tokens_scan": 0,
@@ -4298,8 +4302,10 @@ class TPUEngine:
         t0 = time.perf_counter()
         self._count_moe(sp, "scan", moe)
         if "index_fetched_tokens_scan" in st and moe:
-            # the vector's last entry, summed over the layers
-            fetched = int(moe[0][-1]) // self.model_cfg.num_layers
+            # the vector's last entry, summed over the layers that walk a
+            # selection (every layer but the sliding latent ones)
+            fetched = int(moe[0][-1]) // (
+                self.model_cfg.num_layers - self.model_cfg.num_window_layers)
             st["index_fetched_tokens_scan"] += fetched
             if sp is not None:
                 sp.set(index_fetched_tokens=fetched,

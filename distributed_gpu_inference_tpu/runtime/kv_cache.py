@@ -399,16 +399,26 @@ class _WindowPages:
     ``by_full``): that is how a prefix finds it, since the radix index
     holds the full kind's chain. A block no row holds stays **parked**
     while its partner lives, under the sequence that let go of it, and is
-    taken back when the pool runs dry: the oldest block of the sequence
-    that parks the most (ties: the sequence that parked first). A long cold
-    prompt releases a pool's worth of blocks in a row; taken back that way
-    it eats its own trail and leaves alone the few blocks the other rows
-    parked, which lie where a next turn's prefix ends. A block a prefix hit
-    has used once is **proven**: it is parked apart, oldest first, and taken
-    back only when no other parked block is left or the proven ones pass
-    half the pool (a session's shared prefix ends where it ended before;
-    the blocks a reply released after it are the oldest of their sequence
-    and would go first otherwise). That never touches the full kind."""
+    taken back when the pool runs dry. A block of generated tokens that a
+    running sequence lets go of is not parked at all: a later prompt ends
+    inside this one's prompt or at its end (the next turn), and that end's
+    window is still held when the sequence finishes. Of the parked blocks
+    the asking sequence's own oldest goes first while it parks more than
+    any other: a long cold prompt releases a pool's worth of blocks in a
+    row, eats its own trail and leaves alone the few blocks the other rows
+    parked, which lie where a next turn's prefix ends. Otherwise the
+    sequence that finished first gives its oldest: what an idle sequence
+    left ages out before what a running one parked, whose next request has
+    not come yet. (Taken from whichever sequence parks the most, every
+    sequence that ever parked kept an equal share for good: the remains of
+    warm-up requests held theirs, and a running request's document end was
+    gone before its second request came: PERF.md section 6, PR 59.) With
+    no finished sequence parking, the running one that parks the most
+    gives its oldest. A block a prefix hit has used once is
+    **proven**: it is parked apart, oldest first, and taken back only when
+    no other parked block is left or the proven ones pass half the pool (a
+    session's shared prefix ends where it ended before). That never touches
+    the full kind."""
 
     def __init__(self, num_blocks: int, block_size: int, window: int) -> None:
         if num_blocks < 2:
@@ -420,8 +430,9 @@ class _WindowPages:
         self.ref: Dict[int, int] = {}
         self.partner: Dict[int, int] = {}
         self.by_full: Dict[int, int] = {}
-        # sequence -> the blocks it parked, oldest first; sequences in the
-        # order of their first parked block
+        # sequence -> the blocks it parked, oldest first; running sequences
+        # in the order of their first parked block, finished ones behind
+        # them in the order they finished
         self.parked: "OrderedDict[str, OrderedDict[int, None]]" = \
             OrderedDict()
         self.parked_by: Dict[int, str] = {}
@@ -429,6 +440,8 @@ class _WindowPages:
         self.proven: set = set()
         self.parked_proven: "OrderedDict[int, None]" = OrderedDict()
         self.seq_blocks: Dict[str, List[int]] = {}
+        # sequence -> the blocks that hold its prompt
+        self.prompt_blocks: Dict[str, int] = {}
 
     def first_needed(self, tokens: int) -> int:
         """The first logical block a query at position ``tokens`` (the next
@@ -461,8 +474,10 @@ class _WindowPages:
             del self.parked[owner]
         return True
 
-    def adopt(self, seq_id: str, cached: Sequence[int]) -> None:
+    def adopt(self, seq_id: str, cached: Sequence[int],
+              prompt_blocks: int) -> None:
         """A new sequence's chain: the last window of its hit, shared."""
+        self.prompt_blocks[seq_id] = prompt_blocks
         first = self.first_needed(len(cached) * self.block_size)
         chain = [0] * min(first, len(cached))
         for bid in cached[first:]:
@@ -475,6 +490,15 @@ class _WindowPages:
             chain.append(wid)
         self.seq_blocks[seq_id] = chain
 
+    def finish(self, seq_id: str) -> None:
+        """The sequence lets go of all it holds, and is idle from here."""
+        for wid in self.seq_blocks.pop(seq_id):
+            if wid:
+                self.drop(wid, seq_id)
+        del self.prompt_blocks[seq_id]
+        if seq_id in self.parked:
+            self.parked.move_to_end(seq_id)
+
     def evict(self, wid: int, stats: "KVCacheStats") -> int:
         """Take a parked block back. Its full-kind partner stays as it is:
         the next lookup that needs this block's window finds it gone and is
@@ -485,7 +509,9 @@ class _WindowPages:
         stats.window_blocks_evicted += 1
         return wid
 
-    def evict_one(self, stats: "KVCacheStats") -> int:
+    def evict_one(self, stats: "KVCacheStats",
+                  seq_id: Optional[str] = None) -> int:
+        """Take back one parked block (for ``seq_id``, where one asks)."""
         if self.parked_proven and (
                 not self.parked
                 or len(self.parked_proven) > self.num_blocks // 2):
@@ -495,12 +521,15 @@ class _WindowPages:
                 "window-kind KV pool exhausted: 0 free, 0 parked, all "
                 "others held by active sequences")
         # (``max`` keeps the first of equals: the sequence that parked first)
-        most = max(self.parked.values(), key=len)
-        return self.evict(next(iter(most)), stats)
+        victim = max(self.parked.values(), key=len)
+        if self.parked.get(seq_id) is not victim:
+            victim = next((blocks for sid, blocks in self.parked.items()
+                           if sid not in self.seq_blocks), victim)
+        return self.evict(next(iter(victim)), stats)
 
-    def pop_block(self, stats: "KVCacheStats") -> int:
+    def pop_block(self, stats: "KVCacheStats", seq_id: str) -> int:
         return self.free_list.pop() if self.free_list \
-            else self.evict_one(stats)
+            else self.evict_one(stats, seq_id)
 
     def extend(self, seq_id: str, full_chain: Sequence[int], upto: int,
                stats: "KVCacheStats") -> List[int]:
@@ -511,7 +540,7 @@ class _WindowPages:
         added: List[int] = []
         try:
             while len(chain) + len(added) < need:
-                added.append(self.pop_block(stats))
+                added.append(self.pop_block(stats, seq_id))
         except OutOfBlocksError:
             self.free_list.extend(added)
             raise
@@ -1028,7 +1057,7 @@ class PagedKVCacheManager:
         self.seq_tokens[seq_id] = token_ids
         self.seq_shared_count[seq_id] = len(cached) + len(spill_pages)
         if self.win is not None:
-            self.win.adopt(seq_id, cached)
+            self.win.adopt(seq_id, cached, needed_blocks)
             # the hit's chain starts at its last window: nothing before it
             # is held, as if released
             self.seq_window_front[seq_id] = min(
@@ -1182,8 +1211,9 @@ class PagedKVCacheManager:
                 if not bid:     # before the hit's last window: never held
                     lb += 1
                     continue
+                # (what the sequence generated itself: let go for good)
                 self.stats.window_blocks_retained += self.win.drop(
-                    bid, seq_id)
+                    bid, seq_id, lb < self.win.prompt_blocks[seq_id])
             else:
                 meta = self.metas.get(bid)
                 if meta is not None and meta.decref() == 0:
@@ -1266,9 +1296,7 @@ class PagedKVCacheManager:
                     meta.prefix_hash = hashes[i]
                 self._deactivate_block(bid)
         if self.win is not None:
-            for wid in self.win.seq_blocks.pop(seq_id):
-                if wid:
-                    self.win.drop(wid, seq_id)
+            self.win.finish(seq_id)
 
     def _scrub_pending_for(self, bid: int) -> None:
         """Withdraw staged device ops that reference a block returning to

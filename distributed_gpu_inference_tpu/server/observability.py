@@ -452,7 +452,8 @@ class Metrics:
             "and V pages), kv+index (those and an indexer's key beside "
             "them), latent (one compressed latent and its rope key), "
             "latent+index (those and an index key a layer that holds an "
-            "indexer) or hybrid (latent pages in some layers, a fixed-size state row "
+            "indexer), ...+window (pages per layer kind, the sliding "
+            "kind's in a pool of their own) or hybrid (latent pages in some layers, a fixed-size state row "
             "a sequence in the others)", ["worker", "layout"],
             registry=r)
         # a model with an indexer (learned sparse attention): its index-key
@@ -934,7 +935,8 @@ class MetricsCollector:
         layout = stats.get("kv_layout")
         if isinstance(layout, str):
             for name in ("kv", "kv+index", "kv+state", "latent",
-                         "latent+index", "hybrid"):
+                         "latent+index", "hybrid", "kv+window",
+                         "latent+window", "latent+index+window"):
                 self.metrics.worker_kv_layout.labels(worker, name).set(
                     1.0 if name == layout else 0.0)
         for key, gauge in (

@@ -330,7 +330,8 @@ class Worker:
                 if self.config.role != "hybrid" and core is not None and \
                         getattr(core, "stats", {}).get("kv_layout") in (
                             "latent", "latent+index", "hybrid", "kv+index",
-                            "kv+window", "kv+state"):
+                            "kv+window", "kv+state", "latent+window",
+                            "latent+index+window"):
                     # a prefill / decode role hands K/V pages to a peer
                     # (runtime/kv_handoff.py require_kv_pages): refused
                     # here, where the worker is configured

@@ -169,7 +169,7 @@ def test_registry_and_the_cut():
     (dict(index_topk=8, index_num_heads=2, index_head_dim=16),
      "index"),
     (dict(kv_lora_rank=32, qk_nope_head_dim=8, qk_rope_head_dim=8,
-          v_head_dim=16), "latent pages"),
+          v_head_dim=16), "latent layers would not read"),
     (dict(sliding_window=None), "needs sliding_window"),
     (dict(layer_types=("full", "sliding")), "for each of the 10 layers"),
     (dict(sliding_num_heads=5), "K/V heads that differ by kind"),

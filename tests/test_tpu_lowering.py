@@ -390,7 +390,7 @@ def _forward_chunk_lowered(cfg, s, mesh, devices, tp=None, ctx=CTX,
             llama.init_params(cfg, jax.random.PRNGKey(0)), "int8"
         )
     )
-    kinds = len(cfg.cache_kinds) if not cfg.latent_kv else 1
+    kinds = 2 if cfg.mixed_attention else 1
     kv = jax.eval_shape(
         lambda: llama.init_kv_pools(
             cfg, 1 + BATCH * (ctx // 16), 16,
@@ -1003,8 +1003,11 @@ def _indexed_scan_lowered(cfg, steps, devices, ctx=KEYE_CTX):
         llama.init_params(cfg, jax.random.PRNGKey(0)), "int8"))
     from distributed_gpu_inference_tpu.ops import index_select
 
+    kinds = 2 if cfg.mixed_attention else 1
     kv = jax.eval_shape(
-        lambda: llama.init_kv_pools(cfg, 1 + BATCH * (ctx // 16), 16))
+        lambda: llama.init_kv_pools(
+            cfg, 1 + BATCH * (ctx // 16), 16,
+            window_blocks=WINDOW_BLOCKS if kinds > 1 else None))
     kv[llama.INDEX_SCAN_KEYS] = jax.ShapeDtypeStruct(
         index_select.scan_keys_shape(
             kv[llama.INDEX_KEYS].shape, BATCH, ctx // 16, cfg.index_topk),
@@ -1029,7 +1032,8 @@ def _indexed_scan_lowered(cfg, steps, devices, ctx=KEYE_CTX):
 
     return jax.jit(scan, donate_argnums=(1,)).lower(
         place(params), place(kv), sds((BATCH,), jnp.int32),
-        sds((BATCH,), jnp.int32), sds((BATCH, ctx // 16), jnp.int32),
+        sds((BATCH,), jnp.int32),
+        sds((BATCH, kinds * (ctx // 16)), jnp.int32),
         sds((BATCH,), jnp.bool_))
 
 
@@ -1285,4 +1289,116 @@ def test_sparse_latent_scan_carries_a_layer_of_keys_a_full_layer(
     blocks = 1 + BATCH * (GLM_CTX // 16)
     assert stats.alias_size_in_bytes >= 3 * BATCH * GLM_CTX * 128 * 2 \
         + 9 * blocks * 16 * 640 * 2 + 3 * blocks * 16 * 128 * 2
+    assert stats.temp_size_in_bytes < 256 * 1024 ** 2
+
+
+# --------------------------------------------------------------------- #
+# (h) latent pages of two kinds: dots3-note-prev's share at its published
+# widths and the served 24,576 positions. Full layers (128 heads, 640-lane
+# rows, the indexer's 64 heads) beside sliding layers (64 heads, latent
+# 1,024 in 1,152-lane rows, a window of 513) with a pool a kind.
+# --------------------------------------------------------------------- #
+
+DOTS3 = "dots3-note-prev-ep8-9l"
+DOTS3_CTX = 24576
+# the window kind's pool as the engine sizes it: 8 slots, 8 windows of 33
+DOTS3_WINDOW_BLOCKS = 1 + 8 * 8 * 33
+
+
+@pytest.mark.parametrize("s,name", [
+    (1, "dgi_mla_window_decode"), (16, "dgi_mla_window_ragged"),
+    (256, "dgi_mla_window_ragged")], ids=["step", "tile", "round"])
+def test_the_windowed_latent_kernel_compiles_at_the_published_widths(
+        v5e, s, name):
+    """The absorbed kernel at latent 1,024 / row 1,152 / 64 heads with the
+    window as a rule of the walk (an eighth scalar operand, the tile's
+    lowest query position), and the window pool's page write."""
+    from distributed_gpu_inference_tpu.ops import mla_attention_pallas as mk
+
+    cfg = get_model_config(DOTS3)
+    kind = cfg.latent_kind("sliding")
+    sds = _on(SingleDeviceSharding(v5e[0]))
+    w, m = mla.pool_width(cfg, "sliding"), DOTS3_CTX // 16
+    assert (w, kind.kv_rank, kind.heads, kind.window) == (1152, 1024, 64, 513)
+    pool = sds((cfg.num_window_layers, DOTS3_WINDOW_BLOCKS, 16, w),
+               jnp.bfloat16)
+    fn = functools.partial(
+        mk.latent_paged_attention.__wrapped__, block_size=16,
+        scale=kind.qk ** -0.5, latent=kind.kv_rank, decode=s == 1,
+        window=kind.window)
+    lowered = jax.jit(fn).lower(
+        sds((BATCH, s, kind.heads, w), jnp.bfloat16), pool,
+        sds((), jnp.int32), sds((BATCH, m), jnp.int32),
+        sds((BATCH, s), jnp.int32), sds((BATCH,), jnp.int32))
+    assert _kernels(lowered) == {name}
+    text = _mosaic_text(lowered)
+    assert "memref<2x32x16x1152xbf16" in text
+    lowered.compile()
+
+    def write(rows, pool, layer, tables, pos):
+        plan = page_write_plan(tables, pos, 16, page_bytes=16 * w * 2)
+        return mk.write_latent_pages_in_place(rows, pool, layer, plan)
+
+    jax.jit(write, donate_argnums=(1,)).lower(
+        sds((BATCH * s, w), jnp.bfloat16), pool, sds((), jnp.int32),
+        sds((BATCH, m), jnp.int32), sds((BATCH, s), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("tp,s", [(None, 1), (264, 256), (2048, 256)],
+                         ids=["scan-step", "Tp264", "Tp2048"])
+def test_two_kind_latent_model_graphs_compile_and_copy_no_pool(
+        v5e, tpu_dispatch, tp, s):
+    """A decode step and the packed round at two rungs, 24,576 positions a
+    row. The full layers' latent kernels under their ``_selected`` names,
+    the sliding layers' under names of their own, the selection in its own
+    kernels; each kind's pages and the index keys written and read in place
+    in their stacked pools (no array of a pool layer's shape); no gather of
+    a row's cached latents of either width."""
+    cfg = get_model_config(DOTS3)
+    lowered = _forward_chunk_lowered(cfg, s, None, v5e, tp=tp, ctx=DOTS3_CTX)
+    found = _kernels(lowered)
+    want = {"dgi_mla_write", "dgi_mla_decode_selected",
+            "dgi_mla_window_decode", "dgi_moe_gmm_step",
+            "dgi_index_score_step", "dgi_index_threshold_step"} \
+        if tp is None else {
+        "dgi_mla_write", "dgi_mla_ragged_selected", "dgi_mla_window_ragged",
+        "dgi_moe_gmm", "dgi_index_score", "dgi_index_threshold"}
+    assert want <= found and found <= want | {"dgi_qmm"}, found
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    blocks = 1 + BATCH * (DOTS3_CTX // 16)
+    for whole, layer in (
+            (f"[3,{blocks},16,640]", f"[{blocks},16,640]"),
+            (f"[6,{WINDOW_BLOCKS},16,1152]", f"[{WINDOW_BLOCKS},16,1152]"),
+            (f"[3,{blocks},16,128]", f"[{blocks},16,128]")):
+        assert whole in text
+        assert layer not in text.replace(whole, "")
+    for width in (640, 1152):
+        assert f"[{BATCH},{DOTS3_CTX},{width}]" not in text
+    held, hid, mi = cfg.num_held_experts, cfg.hidden_size, \
+        cfg.moe_intermediate_size
+    for dt in ("bf16", "f32"):
+        assert f"{dt}[{held},{hid},{mi}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (3 if tp == 2048 else 1) * 1024 ** 3
+
+
+def test_two_kind_latent_scan_carries_its_keys_under_the_full_kinds_table(
+        v5e, tpu_dispatch):
+    """The T=4 scan at 24,576 positions a row: the scan's keys are ``[3, B,
+    J, 128]`` (a layer a FULL layer), gathered by the full kind's half of a
+    row's two tables, and the storage comes back aliased with the three
+    pools."""
+    cfg = get_model_config(DOTS3)
+    lowered = _indexed_scan_lowered(cfg, 4, v5e, ctx=DOTS3_CTX)
+    assert _kernels(lowered) == {
+        "dgi_index_score_step", "dgi_index_threshold_step", "dgi_mla_write",
+        "dgi_mla_decode_selected", "dgi_mla_window_decode",
+        "dgi_moe_gmm_step", "dgi_qmm"}
+    compiled = lowered.compile()
+    stats = compiled.memory_analysis()
+    blocks = 1 + BATCH * (DOTS3_CTX // 16)
+    assert stats.alias_size_in_bytes >= 3 * BATCH * DOTS3_CTX * 128 * 2 \
+        + 3 * blocks * 16 * 640 * 2 + 3 * blocks * 16 * 128 * 2 \
+        + 6 * WINDOW_BLOCKS * 16 * 1152 * 2
     assert stats.temp_size_in_bytes < 256 * 1024 ** 2
